@@ -824,10 +824,15 @@ class NativeScheduler:
 
 def make_scheduler(provider, cfg: SchedulerConfig = DEFAULT_CONFIG,
                    prefer_native: bool = True, **kwargs):
-    """Native scheduler when buildable, Python tree otherwise."""
+    """Native scheduler when buildable, Python tree otherwise — and says
+    which (``chip_smoke.py`` prints the line)."""
     if prefer_native and available():
         try:
-            return NativeScheduler(provider, cfg, **kwargs)
+            scheduler = NativeScheduler(provider, cfg, **kwargs)
+            logger.info("scheduler: native (%s, ABI %d)",
+                        os.path.basename(_LIB_PATH), _ABI_VERSION)
+            return scheduler
         except RuntimeError:
             pass
+    logger.info("scheduler: python (native library not built or refused)")
     return Scheduler(provider, cfg, **kwargs)

@@ -69,15 +69,16 @@ class ModelConfig:
     max_lora_slots: int = 4
     max_lora_rank: int = 16
     # Pallas flash-attention for prefill (right-padded batches only).  On by
-    # default: the dispatcher falls back to the XLA reference on CPU or when
-    # shapes miss the tiling constraints.  Validated compiled on v5e —
-    # bf16-tolerance parity, 19-22x over XLA at S=8192 (tools/
-    # onchip_pallas_check.py).
+    # default: the dispatcher takes the XLA reference on CPU or when shapes
+    # miss the tiling constraints, and logs which it took.  Lowers
+    # non-interpreted on v5e ("TPU v5 lite") with parity inside 4 bf16 ulps
+    # at every listed model's head layout (tools/onchip_pallas_check.py,
+    # chip run of PR 21).  Speed against XLA: not measured.
     use_flash_attention: bool = True
     # Pallas cached-decode attention kernel (ops/pallas_decode_attention),
-    # same auto-fallback.  Validated compiled on v5e: parity at bf16
-    # tolerance; DMA-clamping skips cache blocks past each row's length
-    # (2.1x over XLA at S_max=8192, half-full cache).
+    # same dispatch.  Lowers on v5e with parity at the same layouts, bf16
+    # and int8, lane and paged (same run); DMA-clamping skips cache blocks
+    # past each row's length.  Speed against XLA: not measured.
     use_pallas_decode: bool = True
 
     @property

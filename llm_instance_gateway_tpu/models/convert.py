@@ -214,10 +214,17 @@ def load_serving_checkpoint(path: str):
 
     Accepts both layouts: a bare Orbax params tree (preset-config servers)
     or the ``params`` + ``model_config.json`` pair save_hf_as_orbax writes.
+
+    Params come back as HOST numpy arrays: the caller decides what reaches
+    the device and in what form (``ops.quant.quantize_params`` moves one
+    leaf at a time, ``parallel.sharding.shard_pytree`` places shards
+    directly) — a 7B bf16 tree restored straight onto one 16 GB chip would
+    not leave room to quantize it.
     """
     import json
     import os
 
+    import jax
     import orbax.checkpoint as ocp
 
     cfg = None
@@ -227,5 +234,8 @@ def load_serving_checkpoint(path: str):
         with open(cfg_file) as f:
             cfg = ModelConfig(**json.load(f))
         params_path = os.path.join(path, "params")
-    params = ocp.PyTreeCheckpointer().restore(params_path)
+    ckptr = ocp.PyTreeCheckpointer()
+    tree = ckptr.metadata(params_path).item_metadata.tree
+    params = ckptr.restore(params_path, restore_args=jax.tree.map(
+        lambda _: ocp.RestoreArgs(restore_type=np.ndarray), tree))
     return cfg, params

@@ -20,6 +20,7 @@ TPU-first structure:
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -29,15 +30,20 @@ from llm_instance_gateway_tpu.models import lora as lora_lib
 from llm_instance_gateway_tpu.models.configs import ModelConfig
 from llm_instance_gateway_tpu.ops.attention import (
     decode_attention,
+    log_choice,
     prefill_attention,
     xla_chunk_attention,
 )
 from llm_instance_gateway_tpu.ops.layers import apply_rope, rms_norm, swiglu
 from llm_instance_gateway_tpu.ops.quant import (
+    QUANT_TARGETS,
+    constrain,
     expert_matmul,
     expert_mix,
     expert_mix_down,
     matmul as q_matmul,
+    quantize_weight,
+    static_sharding,
 )
 
 Params = dict[str, Any]
@@ -48,46 +54,96 @@ Params = dict[str, Any]
 # ---------------------------------------------------------------------------
 
 
-def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
+@functools.partial(
+    jax.jit, static_argnames=("shape", "fan_in", "dtype", "quant", "sharding"))
+def _draw_leaf(key, *, shape, fan_in, dtype, quant, sharding):
+    """One random weight leaf, ``normal / sqrt(fan_in)`` (``fan_in`` None:
+    the embedding's ``normal * 0.02``).  A stacked ``[L, ...]`` leaf
+    (rank >= 3) is drawn one layer at a time, so the f32 draw and the
+    int8 quantization's f32 view are one layer's, never the stack's.
+    Module-level with static arguments so repeated inits (the test suite)
+    hit jit's cache."""
+    def draw(k, shp):
+        w = jax.random.normal(k, shp, jnp.float32)
+        w = (w * 0.02 if fan_in is None else w / jnp.sqrt(fan_in))
+        w = w.astype(dtype)
+        return quantize_weight(w) if quant else w
+
+    if len(shape) >= 3:
+        out = jax.lax.map(lambda k: draw(k, shape[1:]),
+                          jax.random.split(key, shape[0]))
+    else:
+        out = draw(key, shape)
+    return constrain(out, sharding)
+
+
+def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
+                quantize: bool = False, shardings: Params | None = None,
+                ) -> Params:
+    """Seeded random weights, built so that start-up fits where steady
+    state fits.
+
+    Each leaf is its own program that draws ONE LAYER at a time
+    (``_draw_leaf``): at Qwen2.5-7B widths ``w_gate`` alone is 7.6 GB as an
+    f32 stack.  ``quantize`` int8-quantizes the ``ops.quant`` targets (and
+    ``lm_head``) inside the same per-layer program, so the bf16 tree (15 GB
+    at those widths) never exists either.  ``shardings``
+    (``parallel.sharding.param_shardings``, built with the same
+    ``quantize``) constrains every program's output: on a mesh no leaf is
+    ever whole on one device.
+    """
     hd = cfg.resolved_head_dim
     d, f, v = cfg.d_model, cfg.d_ff, cfg.padded_vocab
     n_l = cfg.n_layers
     keys = iter(jax.random.split(key, 16))
+    dtype = jnp.dtype(dtype)
+    layer_sh = None if shardings is None else shardings["layers"]
 
-    def dense(k, shape, fan_in):
-        return (jax.random.normal(k, shape, jnp.float32) / jnp.sqrt(fan_in)).astype(dtype)
+    def rand(tree_sh, name, shape, fan_in):
+        quant = quantize and (name in QUANT_TARGETS or name == "lm_head")
+        sh = None if tree_sh is None else tree_sh[name]
+        return _draw_leaf(next(keys), shape=shape, fan_in=fan_in,
+                          dtype=dtype, quant=quant,
+                          sharding=static_sharding(sh))
+
+    def const(tree_sh, name, fill, shape):
+        x = jnp.full(shape, fill, dtype)
+        return x if tree_sh is None else jax.device_put(x, tree_sh[name])
 
     layers: Params = {
-        "attn_norm": jnp.ones((n_l, d), dtype),
-        "mlp_norm": jnp.ones((n_l, d), dtype),
-        "wq": dense(next(keys), (n_l, d, cfg.n_heads * hd), d),
-        "wk": dense(next(keys), (n_l, d, cfg.n_kv_heads * hd), d),
-        "wv": dense(next(keys), (n_l, d, cfg.n_kv_heads * hd), d),
-        "wo": dense(next(keys), (n_l, cfg.n_heads * hd, d), cfg.n_heads * hd),
+        "attn_norm": const(layer_sh, "attn_norm", 1, (n_l, d)),
+        "mlp_norm": const(layer_sh, "mlp_norm", 1, (n_l, d)),
+        "wq": rand(layer_sh, "wq", (n_l, d, cfg.n_heads * hd), d),
+        "wk": rand(layer_sh, "wk", (n_l, d, cfg.n_kv_heads * hd), d),
+        "wv": rand(layer_sh, "wv", (n_l, d, cfg.n_kv_heads * hd), d),
+        "wo": rand(layer_sh, "wo", (n_l, cfg.n_heads * hd, d),
+                   cfg.n_heads * hd),
     }
     if cfg.attention_bias:
         # Qwen2-family Q/K/V biases (zero init; checkpoints overwrite).
-        layers["wq_b"] = jnp.zeros((n_l, cfg.n_heads * hd), dtype)
-        layers["wk_b"] = jnp.zeros((n_l, cfg.n_kv_heads * hd), dtype)
-        layers["wv_b"] = jnp.zeros((n_l, cfg.n_kv_heads * hd), dtype)
+        layers["wq_b"] = const(layer_sh, "wq_b", 0, (n_l, cfg.n_heads * hd))
+        layers["wk_b"] = const(layer_sh, "wk_b", 0,
+                               (n_l, cfg.n_kv_heads * hd))
+        layers["wv_b"] = const(layer_sh, "wv_b", 0,
+                               (n_l, cfg.n_kv_heads * hd))
     if cfg.n_experts:
         e = cfg.n_experts
-        layers["router"] = dense(next(keys), (n_l, d, e), d)
-        layers["w_gate"] = dense(next(keys), (n_l, e, d, f), d)
-        layers["w_up"] = dense(next(keys), (n_l, e, d, f), d)
-        layers["w_down"] = dense(next(keys), (n_l, e, f, d), f)
+        layers["router"] = rand(layer_sh, "router", (n_l, d, e), d)
+        layers["w_gate"] = rand(layer_sh, "w_gate", (n_l, e, d, f), d)
+        layers["w_up"] = rand(layer_sh, "w_up", (n_l, e, d, f), d)
+        layers["w_down"] = rand(layer_sh, "w_down", (n_l, e, f, d), f)
     else:
-        layers["w_gate"] = dense(next(keys), (n_l, d, f), d)
-        layers["w_up"] = dense(next(keys), (n_l, d, f), d)
-        layers["w_down"] = dense(next(keys), (n_l, f, d), f)
+        layers["w_gate"] = rand(layer_sh, "w_gate", (n_l, d, f), d)
+        layers["w_up"] = rand(layer_sh, "w_up", (n_l, d, f), d)
+        layers["w_down"] = rand(layer_sh, "w_down", (n_l, f, d), f)
 
     params: Params = {
-        "embed": (jax.random.normal(next(keys), (v, d), jnp.float32) * 0.02).astype(dtype),
+        "embed": rand(shardings, "embed", (v, d), None),
         "layers": layers,
-        "final_norm": jnp.ones((d,), dtype),
+        "final_norm": const(shardings, "final_norm", 1, (d,)),
     }
     if not cfg.tie_embeddings:
-        params["lm_head"] = dense(next(keys), (d, v), d)
+        params["lm_head"] = rand(shardings, "lm_head", (d, v), d)
     return params
 
 
@@ -158,19 +214,21 @@ def _attn_proj(lp, target, x, layer_lora, slot_ids):
 
 def _chunk_attend(cfg: ModelConfig, quant: bool, q, lane_k, lane_v, start):
     """Chunk-vs-lane attention dispatch, shared by the lane and paged
-    chunk-stream paths.  Flash-style kernel (auto XLA fallback off-TPU/odd
-    shapes) unless the lane was dequantized from an int8 cache — an opaque
-    kernel can't fuse the dequant into its reads and would materialize a
-    bf16 copy, so quantized lanes keep the fused XLA path (same reasoning
-    as the decode-path quant gate).  Returns [1, C, H*hd]."""
+    chunk-stream paths.  Flash-style kernel (XLA off-TPU/odd shapes, logged
+    by the dispatcher) unless the lane was dequantized from an int8 cache —
+    an opaque kernel can't fuse the dequant into its reads and would
+    materialize a bf16 copy, so quantized lanes keep the fused XLA path
+    (same reasoning as the decode-path quant gate).  Returns [1, C, H*hd]."""
+    from llm_instance_gateway_tpu.ops import pallas_attention
+
     c = q.shape[1]
     if cfg.use_flash_attention and not quant:
-        from llm_instance_gateway_tpu.ops.pallas_attention import (
-            chunk_attention,
-        )
-
-        return chunk_attention(q, lane_k[None], lane_v[None],
-                               start).reshape(1, c, -1)
+        return pallas_attention.chunk_attention(
+            q, lane_k[None], lane_v[None], start).reshape(1, c, -1)
+    log_choice(
+        "chunk_attend", f"q{tuple(q.shape)} lane{tuple(lane_k.shape)}",
+        "int8 lane: the dequant fuses into the XLA reads" if quant
+        else "use_flash_attention=False")
     return xla_chunk_attention(q, lane_k[None], lane_v[None],
                                start).reshape(1, c, -1)
 

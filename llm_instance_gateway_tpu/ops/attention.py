@@ -13,10 +13,41 @@ XLA versions are the correctness reference and the CPU test path.
 
 from __future__ import annotations
 
+import logging
+
 import jax
 import jax.numpy as jnp
 
+logger = logging.getLogger(__name__)
+
 NEG_INF = -1e30  # large-negative instead of -inf: keeps masked softmax NaN-free
+
+# The one backend whose lowering is Mosaic TPU.  On any other the
+# dispatchers take the XLA reference (and say so).
+TPU_BACKEND = "tpu"
+
+
+def kernel_reason(shape_reasons: list[str], interpret: bool) -> str | None:
+    """Why a dispatcher does NOT take its kernel, or None when it does:
+    the first failed shape gate, else a non-TPU backend (interpret mode —
+    reachable only from tests — stands in for the backend)."""
+    if shape_reasons:
+        return shape_reasons[0]
+    backend = jax.default_backend()
+    if not interpret and backend != TPU_BACKEND:
+        return f"backend={backend}"
+    return None
+
+
+def log_choice(op: str, shape: str, reason: str | None,
+               interpret: bool = False) -> None:
+    """One line per traced program saying which attention implementation
+    it compiled in (dispatchers run at trace time, so a jitted program
+    logs once per compiled shape).  chip_smoke.py echoes these lines."""
+    impl = ("xla" if reason is not None
+            else "pallas-interpret" if interpret else "pallas")
+    logger.info("attention dispatch: op=%s impl=%s shape=%s reason=%s",
+                op, impl, shape, reason or "supported shape on tpu")
 
 
 def xla_chunk_attention(

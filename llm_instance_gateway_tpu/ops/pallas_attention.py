@@ -17,9 +17,11 @@ KV head h // (H/K), so grouped heads share the same streamed K/V tile
 without materialized repetition.  Causal K blocks strictly above the
 diagonal skip their FLOPs via @pl.when.
 
-Use ``flash_attention`` for the auto-dispatching entry: it falls back to the
-XLA reference (``ops.attention.prefill_attention``) when shapes don't meet
-the tiling constraints (tiny test models) or off-TPU.
+Use ``flash_attention`` for the dispatching entry: it takes the XLA
+reference (``ops.attention.prefill_attention``) when shapes don't meet the
+tiling constraints (tiny test models, buckets below ``BLOCK_Q``) or off-TPU,
+and logs which it took per traced program
+(``ops.attention.log_choice``).
 """
 
 from __future__ import annotations
@@ -31,8 +33,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llm_instance_gateway_tpu.ops import pallas_decode_attention
-from llm_instance_gateway_tpu.ops.attention import prefill_attention
+from llm_instance_gateway_tpu.ops.attention import (
+    kernel_reason,
+    log_choice,
+    prefill_attention,
+)
 
 NEG_INF = -1e30
 
@@ -176,9 +181,22 @@ def flash_attention_bhsd(
     )(q, k, v)
 
 
+def shape_reasons(s: int, hd: int, block_q: int = BLOCK_Q,
+                  block_k: int = BLOCK_K) -> list[str]:
+    """Shape gates of the flash kernel that this call misses (empty = ok)."""
+    reasons = []
+    if hd % 128:
+        reasons.append(f"hd={hd} % 128 != 0")
+    if s % block_q or s % block_k:
+        reasons.append(
+            f"s={s} % BLOCK_Q/K={block_q}/{block_k} != 0"
+            + (" (bucket < BLOCK_Q)" if s < block_q else ""))
+    return reasons
+
+
 def supports(s: int, hd: int, block_q: int = BLOCK_Q, block_k: int = BLOCK_K) -> bool:
     """Shape gate for the kernel path (pad upstream or fall back)."""
-    return s % block_q == 0 and s % block_k == 0 and hd % 128 == 0
+    return not shape_reasons(s, hd, block_q, block_k)
 
 
 # ---------------------------------------------------------------------------
@@ -282,22 +300,35 @@ def chunk_attention_pallas(
     return out.transpose(0, 2, 1, 3)
 
 
+def chunk_shape_reasons(c: int, s_max: int, hd: int) -> list[str]:
+    reasons = []
+    if hd % 128:
+        reasons.append(f"hd={hd} % 128 != 0")
+    if c % BLOCK_Q:
+        reasons.append(f"chunk={c} % BLOCK_Q={BLOCK_Q} != 0")
+    if s_max % BLOCK_K:
+        reasons.append(f"s_max={s_max} % BLOCK_K={BLOCK_K} != 0")
+    return reasons
+
+
 def supports_chunk(c: int, s_max: int, hd: int) -> bool:
-    return c % BLOCK_Q == 0 and s_max % BLOCK_K == 0 and hd % 128 == 0
+    return not chunk_shape_reasons(c, s_max, hd)
 
 
 def chunk_attention(
     q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, start,
     interpret: bool = False,
 ) -> jax.Array:
-    """Auto-dispatch for the chunk attend; XLA reference otherwise."""
+    """Dispatch for the chunk attend; XLA reference otherwise."""
     from llm_instance_gateway_tpu.ops.attention import xla_chunk_attention
 
     b, c, h, hd = q.shape
-    if not supports_chunk(c, k_cache.shape[1], hd) or (
-        not interpret
-        and jax.default_backend() not in pallas_decode_attention.TPU_BACKENDS
-    ):
+    reason = kernel_reason(
+        chunk_shape_reasons(c, k_cache.shape[1], hd), interpret)
+    log_choice(
+        "chunk_attend", f"q{tuple(q.shape)} cache{tuple(k_cache.shape)}",
+        reason, interpret)
+    if reason is not None:
         return xla_chunk_attention(q, k_cache, v_cache, start)
     return chunk_attention_pallas(q, k_cache, v_cache, start,
                                   interpret=interpret)
@@ -310,7 +341,7 @@ def flash_attention(
     causal: bool = True,
     interpret: bool = False,
 ) -> jax.Array:
-    """Auto-dispatch: Pallas kernel when shapes allow, XLA reference otherwise.
+    """Dispatch: Pallas kernel when shapes allow, XLA reference otherwise.
 
     NOTE: the kernel path is purely causal — use it for right-padded batches
     (pad tokens trail real ones, so causality alone keeps real positions
@@ -318,10 +349,12 @@ def flash_attention(
     position-based masks must use the XLA path.
     """
     b, s, h, hd = q.shape
-    if not supports(s, hd) or (
-        not interpret
-        and jax.default_backend() not in pallas_decode_attention.TPU_BACKENDS
-    ):
+    reason = kernel_reason(
+        shape_reasons(s, hd), interpret)
+    log_choice(
+        "flash_prefill", f"q{tuple(q.shape)} kv{tuple(k.shape)}", reason,
+        interpret)
+    if reason is not None:
         return prefill_attention(q, k, v)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)

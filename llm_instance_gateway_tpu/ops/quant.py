@@ -20,6 +20,7 @@ the MoE router (tiny, drives f32 top-k), and LoRA buffers stay bf16.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -82,10 +83,49 @@ def expert_mix_down(x: jax.Array, w: Any) -> jax.Array:
     return _expert_einsum("...ef,efd->...ed", None, x, w)
 
 
-def quantize_params(params: dict, quantize_lm_head: bool = True) -> dict:
-    """Return a params tree with the big projections int8-quantized."""
+def static_sharding(sharding: Any) -> Any:
+    """A leaf's sharding (``parallel.sharding.param_shardings``) as a
+    HASHABLE jit static argument: a quantized leaf's ``{"q", "s"}`` pair
+    becomes a tuple; ``constrain`` undoes it inside the program."""
+    return ((sharding["q"], sharding["s"]) if is_quantized(sharding)
+            else sharding)
+
+
+def constrain(out: Any, sharding: Any) -> Any:
+    """Pin a leaf program's output to ``static_sharding(...)`` (None: no
+    mesh) — the leaf is then born sharded, never whole on one device."""
+    if sharding is None:
+        return out
+    if isinstance(sharding, tuple):
+        sharding = dict(zip(("q", "s"), sharding))
+    return jax.lax.with_sharding_constraint(out, sharding)
+
+
+@functools.partial(jax.jit, static_argnames=("sharding",))
+def _quantize_leaf(w, sharding=None):
+    """``quantize_weight`` over a (possibly host-resident) leaf, one LAYER
+    at a time for stacked ``[L, ..., in, out]`` leaves: the f32 view the
+    quantizer needs is one layer's, not the stack's (7.6 GB for Qwen2.5-7B's
+    ``w_gate``)."""
+    out = jax.lax.map(quantize_weight, w) if w.ndim >= 3 else quantize_weight(w)
+    return constrain(out, sharding)
+
+
+def quantize_params(params: dict, quantize_lm_head: bool = True,
+                    shardings: dict | None = None) -> dict:
+    """Return a params tree with the big projections int8-quantized.
+
+    Leaves may be host numpy arrays (``convert.load_serving_checkpoint``):
+    each target moves to the device, is quantized and — with ``shardings``
+    (``parallel.sharding.param_shardings(..., quantized=True)``) — lands
+    sharded, one leaf at a time, so the dense tree never has to fit."""
     out = dict(params)
     layers = dict(params["layers"])
+    layer_sh = None if shardings is None else shardings["layers"]
+
+    def quantized(w, sh):
+        return _quantize_leaf(w, sharding=static_sharding(sh))
+
     for name in QUANT_TARGETS:
         w = layers.get(name)
         if w is None or is_quantized(w):
@@ -95,10 +135,11 @@ def quantize_params(params: dict, quantize_lm_head: bool = True) -> dict:
         # the last axis) — expert weights are exactly where Mixtral's
         # HBM-bound decode spends its weight bandwidth.  The router stays
         # dense (a tiny [d, E] matmul whose f32 logits drive top-k).
-        layers[name] = quantize_weight(w)
+        layers[name] = quantized(w, layer_sh and layer_sh[name])
     out["layers"] = layers
     if quantize_lm_head and "lm_head" in params and not is_quantized(params["lm_head"]):
-        out["lm_head"] = quantize_weight(params["lm_head"])
+        out["lm_head"] = quantized(params["lm_head"],
+                                   shardings and shardings["lm_head"])
     return out
 
 

@@ -38,7 +38,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 SERVE_WORKER = r"""
 import os, sys
 import jax
-jax.config.update("jax_platforms", "cpu")
 sys.path.insert(0, os.environ["GRAFT_REPO"])
 
 from llm_instance_gateway_tpu.parallel.mesh import (
@@ -117,7 +116,10 @@ def run_two_process(worker_src: str, n_local: int = 4,
     try:
         for pid in (0, 1):
             env = dict(os.environ)
-            env.pop("JAX_PLATFORMS", None)
+            # The workers are virtual-CPU processes by construction; named
+            # in the environment so that, launched from a process that
+            # holds a chip, they never go near it.
+            env["JAX_PLATFORMS"] = "cpu"
             env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
             env["GRAFT_REPO"] = REPO
             env["XLA_FLAGS"] = (

@@ -58,8 +58,9 @@ def _ring_attention_local(
     m = jnp.full((b, n_kv, g, s_loc), NEG_INF, jnp.float32)
     l = jnp.zeros((b, n_kv, g, s_loc), jnp.float32)
     o = jnp.zeros((b, n_kv, g, s_loc, hd), jnp.float32)
-    if varying_axes and hasattr(jax.lax, "pvary"):
-        m, l, o = (jax.lax.pvary(x, varying_axes) for x in (m, l, o))
+    if varying_axes:
+        m, l, o = (jax.lax.pcast(x, varying_axes, to="varying")
+                   for x in (m, l, o))
 
     perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
 

@@ -138,26 +138,47 @@ def activation_specs() -> dict[str, Any]:
     }
 
 
-def shard_pytree(tree: Any, specs: Any, mesh: Mesh) -> Any:
-    """device_put a pytree with NamedShardings from a matching spec pytree.
+def _leaf_shardings(mesh: Mesh, spec: P, quantized: bool):
+    """NamedSharding(s) for one param leaf.  A weight-only-int8 leaf
+    (``ops.quant`` ``{"q", "s"}``) carries ONE spec for the original dense
+    array: ``q`` takes it verbatim and the per-output-channel scale takes
+    the spec minus its contracted (second-to-last) axis."""
+    if not quantized:
+        return NamedSharding(mesh, spec)
+    axes = tuple(spec)
+    scale_spec = P(*(axes[:-2] + axes[-1:])) if len(axes) >= 2 else spec
+    return {"q": NamedSharding(mesh, spec),
+            "s": NamedSharding(mesh, scale_spec)}
 
-    Weight-only-int8 leaves (``ops.quant`` ``{"q", "s"}`` dicts) carry ONE
-    spec for the original dense array: ``q`` takes it verbatim and the
-    per-output-channel scale takes the spec minus its contracted
-    (second-to-last) axis — so ``--quantize int8`` composes with serve
-    meshes for dense AND expert-stack weights."""
+
+def param_shardings(cfg: ModelConfig, mesh: Mesh,
+                    quantized: bool = False) -> dict[str, Any]:
+    """``param_specs`` as a NamedSharding pytree — what
+    ``transformer.init_params`` / ``quant.quantize_params`` take so every
+    leaf is BORN sharded.  ``quantized`` gives the ``ops.quant`` targets
+    (and ``lm_head``) their ``{"q", "s"}`` pair."""
+    from llm_instance_gateway_tpu.ops.quant import QUANT_TARGETS
+
+    specs = param_specs(cfg)
+    out: dict[str, Any] = {
+        k: _leaf_shardings(mesh, v, quantized and k == "lm_head")
+        for k, v in specs.items() if k != "layers"}
+    out["layers"] = {
+        k: _leaf_shardings(mesh, v, quantized and k in QUANT_TARGETS)
+        for k, v in specs["layers"].items()}
+    return out
+
+
+def shard_pytree(tree: Any, specs: Any, mesh: Mesh) -> Any:
+    """device_put a pytree with NamedShardings from a matching spec pytree
+    (a no-op for leaves already placed that way; host numpy leaves go
+    straight to their shards).  Weight-only-int8 leaves split their one
+    spec as ``_leaf_shardings`` says — so ``--quantize int8`` composes with
+    serve meshes for dense AND expert-stack weights."""
     from llm_instance_gateway_tpu.ops.quant import is_quantized
 
     def place(x, s):
-        if is_quantized(x):
-            axes = tuple(s)
-            scale_spec = P(*(axes[:-2] + axes[-1:])) if len(axes) >= 2 else s
-            return {
-                "q": jax.device_put(x["q"], NamedSharding(mesh, s)),
-                "s": jax.device_put(x["s"],
-                                    NamedSharding(mesh, scale_spec)),
-            }
-        return jax.device_put(x, NamedSharding(mesh, s))
+        return jax.device_put(x, _leaf_shardings(mesh, s, is_quantized(x)))
 
     return jax.tree.map(place, tree, specs, is_leaf=is_quantized)
 

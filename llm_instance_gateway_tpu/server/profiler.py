@@ -45,9 +45,9 @@ import time
 from llm_instance_gateway_tpu.lockwitness import witness_lock
 from llm_instance_gateway_tpu.tracing import Histogram
 
-# Dispatch walls are ~100µs (tiny CPU models) to ~100ms (remote TPU
-# tunnels); gaps run µs to ms.  One shared edge set keeps the two
-# families comparable on a dashboard.
+# Dispatch walls run from ~100µs (tiny CPU models) to hundreds of ms (a
+# fused multi-step block of a large model); gaps run µs to ms.  One shared
+# edge set keeps the two families comparable on a dashboard.
 DISPATCH_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3,
                     5e-3, 1e-2, 2.5e-2, 5e-2, 0.1, 0.25, 1.0)
 

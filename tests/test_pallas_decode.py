@@ -70,6 +70,36 @@ class TestDecodeKernel:
         np.testing.assert_allclose(np.asarray(ref), np.asarray(got), rtol=1e-6)
 
 
+    def test_wide_kv_rows_take_a_shorter_block(self):
+        """On v5e [512, K*hd=4096] bf16 K+V tiles, double-buffered, exhaust
+        VMEM (llama2-7b / gemma-7b, chip run of PR 21); int8 rows of the
+        same width, and every GQA layout, keep 512."""
+        assert pda._pick_block(2048, 4096 * 2) == 256   # MHA 32x128 bf16
+        assert pda._pick_block(2048, 4096 * 1) == 512   # same, int8
+        assert pda._pick_block(2048, 4 * 128 * 2) == 512  # qwen2.5-7b
+        assert pda._pick_block(2048) == 512              # divisibility only
+        assert pda._pick_block(2048, 1 << 20) == 0
+        assert pda.shape_reasons(2048, 128, 1 << 20) == [
+            "kv row of 1048576 B: no S-block fits VMEM"]
+
+    def test_dispatch_says_what_it_chose_and_why(self, caplog):
+        import logging
+
+        q, k, v, lengths = make_inputs(hd=16, s=64)
+        with caplog.at_level(logging.INFO,
+                             logger="llm_instance_gateway_tpu.ops.attention"):
+            pda.decode_attention(q, k, v, lengths)
+            pda.decode_attention(q, k, v, lengths, interpret=True)
+            q2, k2, v2, l2 = make_inputs(hd=128, s=128)
+            pda.decode_attention(q2, k2, v2, l2)
+            pda.decode_attention(q2, k2, v2, l2, interpret=True)
+        lines = [r.getMessage() for r in caplog.records]
+        assert "impl=xla" in lines[0] and "hd=16 % 128 != 0" in lines[0]
+        assert "impl=xla" in lines[1]  # a shape gate beats interpret mode
+        assert "impl=xla" in lines[2] and "reason=backend=cpu" in lines[2]
+        assert "impl=pallas-interpret" in lines[3]
+
+
 class TestPagedDecodeKernel:
     """Direct paged kernel: the block table rides the scalar prefetch and
     tiles DMA straight from the pool — parity against gather-then-attend

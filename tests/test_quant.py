@@ -96,14 +96,28 @@ class TestQuantizedMoE:
         tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                                     cfg.vocab_size)
         positions = jnp.broadcast_to(jnp.arange(16), (2, 16))
-        ref, *_ = transformer.prefill(cfg, params, tokens, positions)
+        # The reference is the DEQUANTIZED tree, not the original one: a
+        # random tiny router sits on near-ties, so the original weights'
+        # top-k flips under any perturbation and logits then differ by
+        # tens of percent for 8 seeds in 10 (measured) — that is routing,
+        # not the expert matmuls this test is about.  Same weights -> same
+        # routing; what is left is the int8 execution path itself.
+        def dequantized(w):
+            return (w["q"].astype(jnp.float32) * w["s"][..., None, :]
+                    if is_quantized(w) else w)
+
+        deq = jax.tree.map(dequantized, qp, is_leaf=is_quantized)
+        ref, *_ = transformer.prefill(cfg, deq, tokens, positions)
         got, *_ = transformer.prefill(cfg, qp, tokens, positions)
-        # Per-channel int8 through a 2-layer MoE (two quantized matmuls
-        # per expert plus the gate mix) lands ~2-3% max relative error on
-        # a random tiny model; bound it at 4%.
-        scale = float(jnp.max(jnp.abs(ref)))
-        err = float(jnp.max(jnp.abs(got - ref))) / scale
-        assert err < 0.04, err
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-4)
+        # ... and the quantizer's own error on the expert stacks: half a
+        # step of 1/127 of each output channel's max.
+        for name in ("w_gate", "w_up", "w_down"):
+            w = params["layers"][name]
+            step = jnp.max(jnp.abs(w), axis=-2, keepdims=True) / 127.0
+            assert float(jnp.max(jnp.abs(deq["layers"][name] - w) / step)) \
+                <= 0.5 + 1e-3
 
     def test_quantized_on_mesh_dense_and_moe(self):
         """--quantize int8 + --mesh composes: quantized {q,s} leaves carry

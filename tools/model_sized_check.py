@@ -43,41 +43,22 @@ N_DEVICES = 8
 
 
 def _ensure_cpu_mesh() -> None:
-    """Pin CPU + 8 virtual devices, re-execing if a backend already exists
-    (same approach as __graft_entry__.dryrun_multichip)."""
-    import re
-    import subprocess
-
-    if os.environ.get("_LIG_MODEL_SIZED_CHILD") == "1":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        return
-    # Cheap path: if this interpreter can already see enough CPU devices
-    # (e.g. XLA_FLAGS was set by the caller / conftest), skip the re-exec.
+    """Pin the CPU platform with 8 virtual devices.  Must be this process's
+    first use of JAX (the count is an XLA flag read at backend start-up);
+    no child process is involved."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={N_DEVICES}"
+        ).strip()
     import jax
 
-    try:
-        jax.config.update("jax_platforms", "cpu")
-        if jax.device_count() >= N_DEVICES:
-            return
-    except RuntimeError:
-        pass  # backend already initialized differently: re-exec below
-    inherited = re.sub(
-        r"--xla_force_host_platform_device_count=\d+", "",
-        os.environ.get("XLA_FLAGS", ""),
-    ).strip()
-    env = dict(
-        os.environ,
-        XLA_FLAGS=(
-            f"{inherited} --xla_force_host_platform_device_count={N_DEVICES}"
-        ).strip(),
-        _LIG_MODEL_SIZED_CHILD="1",
-    )
-    env.pop("JAX_PLATFORMS", None)
-    proc = subprocess.run([sys.executable, os.path.abspath(__file__),
-                           *sys.argv[1:]], env=env, timeout=3600)
-    raise SystemExit(proc.returncode)
+    jax.config.update("jax_platforms", "cpu")
+    if jax.device_count() < N_DEVICES:
+        raise SystemExit(
+            f"model_sized_check needs {N_DEVICES} virtual CPU devices and "
+            f"found {jax.device_count()}: run it in a fresh process (an "
+            "XLA_FLAGS device count set too low wins over this one)")
 
 
 def model_sized_config():
