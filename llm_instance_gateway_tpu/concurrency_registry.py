@@ -474,9 +474,18 @@ CLASSES = (
                         writers=("note_dispatch",)),
             SharedField("_prev_active", OWNER_PRIVATE,
                         writers=("note_dispatch",)),
-            SharedField("padding_tokens", MONOTONIC,
-                        writers=("note_padding",)),
-        )),
+            SharedField("_split_mark", OWNER_PRIVATE,
+                        writers=("note_dispatch",)),
+            SharedField("_open", SWAP_PUBLISHED,
+                        writers=("_push", "_switch", "_pop"),
+                        note="(innermost open phase, last charge time), "
+                             "one tuple swapped per phase transition; "
+                             "phase_seconds() copies the per-phase dict "
+                             "between two reads of it, lock-free"),
+        ),
+        note="the phase stack (_stack, _anns, _phase_s) is mutated in "
+             "place by the engine thread alone; _phase_s never gains a "
+             "key after construction"),
     SharedClass(
         f"{PKG}/server/kv_ledger.py", "KvLedger", ENGINE_STEP,
         fields=(

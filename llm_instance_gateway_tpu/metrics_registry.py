@@ -32,6 +32,25 @@ class Family:
     surface: str
 
 
+# The closed label set of tpu:engine_phase_seconds_total: every phase the
+# engine thread can be in (server/profiler.py's phase stack, the
+# ``engine.<phase>`` annotations of a profiler trace) and whether the thread
+# is then blocked on the chip ("device") or doing the host's work ("host").
+ENGINE_PHASES = (
+    ("admit", "host"),
+    ("prefill.stage", "host"),
+    ("prefill.wait", "device"),
+    ("prefill.emit", "host"),
+    ("decode.plan", "host"),
+    ("decode.stage", "host"),
+    ("decode.wait", "device"),
+    ("decode.readback", "host"),
+    ("decode.emit", "host"),
+    ("decode.account", "host"),
+    ("idle", "host"),
+    ("other", "host"),
+)
+
 GATEWAY_FAMILIES = (
     Family("gateway_requests_total", "counter", ("model",),
            "Requests admitted past body parsing, by model.",
@@ -390,6 +409,14 @@ SERVER_FAMILIES = (
            "Engine-thread gap between consecutive dispatches (kind=host "
            "= step-loop overhead the ROADMAP item-2 levers amortize; "
            "kind=idle = the gap contained a no-work wait).",
+           SERVER_SURFACE),
+    Family("tpu:engine_phase_seconds_total", "counter", ("phase", "on"),
+           "Engine-thread seconds by phase (self time; the phases tile the "
+           "thread's wall): admit | prefill.stage/.wait/.emit | "
+           "decode.plan/.stage/.wait/.readback/.emit/.account | idle | "
+           "other; on=device where the thread is blocked on the chip, "
+           "on=host elsewhere (metrics_registry.ENGINE_PHASES; the same "
+           "names are engine.<phase> annotations in a profiler trace).",
            SERVER_SURFACE),
     Family("tpu:kv_blocks_total", "gauge", (),
            "KV block budget the ledger accounts: pool blocks + parked "

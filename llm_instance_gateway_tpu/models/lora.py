@@ -115,6 +115,7 @@ def unload_adapter(bufs: dict[str, Any], cfg, slot: int) -> dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("lora")
 def lora_delta(
     x: jax.Array,          # [B, S, d_in] or [B, d_in]
     a: jax.Array,          # [n_slots, d_in, r]

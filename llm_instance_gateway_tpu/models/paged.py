@@ -36,19 +36,20 @@ import jax.numpy as jnp
 from llm_instance_gateway_tpu.models import lora as lora_lib
 from llm_instance_gateway_tpu.models.configs import ModelConfig
 from llm_instance_gateway_tpu.models.transformer import (
+    _attn_out,
     _attn_proj,
     _chunk_attend,
+    _embed,
     _kv_dequantize,
     _kv_quantize,
+    _lm_head,
     _mlp,
-    _project,
 )
 from llm_instance_gateway_tpu.ops.attention import (
     decode_attention,
     gather_pool_rows,
 )
 from llm_instance_gateway_tpu.ops.layers import apply_rope, rms_norm
-from llm_instance_gateway_tpu.ops.quant import matmul as q_matmul
 
 Params = dict[str, Any]
 
@@ -90,6 +91,7 @@ _gather_rows = gather_pool_rows  # canonical def: ops.attention (shared with
                                  # the paged kernel's fallback and tooling)
 
 
+@jax.named_scope("attn.kv_update")
 def _pool_update(pools: tuple, k: jax.Array, v: jax.Array,
                  phys_block: jax.Array, offset: jax.Array) -> tuple:
     """Scatter freshly-computed bf16 K/V into the layer's pool tuple at
@@ -148,9 +150,7 @@ def decode_step_paged(
     block = cache["k"].shape[2]
     tables = cache["tables"]
 
-    h = params["embed"][tokens]
-    if cfg.embedding_scale:
-        h = h * jnp.sqrt(cfg.d_model).astype(h.dtype)
+    h = _embed(cfg, params, tokens)
 
     per_layer_lora = None
     if lora_bufs is not None:
@@ -178,23 +178,25 @@ def decode_step_paged(
         q = apply_rope(q[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
         k = apply_rope(k[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
         pools = _pool_update(tuple(pools), k, v, phys_block, offset)
-        if cfg.use_pallas_decode:
-            from llm_instance_gateway_tpu.ops.pallas_decode_attention import (
-                paged_decode_attention,
-            )
+        with jax.named_scope("attn.core"):
+            if cfg.use_pallas_decode:
+                from llm_instance_gateway_tpu.ops.pallas_decode_attention import (
+                    paged_decode_attention,
+                )
 
-            # DIRECT paged kernel: the block table rides the scalar
-            # prefetch and each tile DMAs straight from the pool — no
-            # gathered copy of the live cache materializes in HBM (the
-            # old read paid gather write + kernel read).  int8 pools
-            # stream half the bytes again, scales on the same
-            # indirection; its auto-dispatch gathers + falls back off-TPU.
-            attn = paged_decode_attention(
-                q, pools[0], pools[1], tables, lengths, *pools[2:])
-        else:
-            attn = decode_attention(
-                q, *_pool_rows(pools, tables, h.dtype), lengths)
-        h = h + _project(attn.reshape(b, -1), lp["wo"], layer_lora, "o", slot_ids)
+                # DIRECT paged kernel: the block table rides the scalar
+                # prefetch and each tile DMAs straight from the pool — no
+                # gathered copy of the live cache materializes in HBM (the
+                # old read paid gather write + kernel read).  int8 pools
+                # stream half the bytes again, scales on the same
+                # indirection; its auto-dispatch gathers + falls back
+                # off-TPU.
+                attn = paged_decode_attention(
+                    q, pools[0], pools[1], tables, lengths, *pools[2:])
+            else:
+                attn = decode_attention(
+                    q, *_pool_rows(pools, tables, h.dtype), lengths)
+        h = h + _attn_out(lp, attn.reshape(b, -1), layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
         return h, pools
@@ -204,8 +206,7 @@ def decode_step_paged(
         xs = xs + (cache["k_scale"], cache["v_scale"])
     h, carry = jax.lax.scan(layer_fn, h, xs)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = q_matmul(h, head).astype(jnp.float32)
+    logits = _lm_head(cfg, params, h)
     new_cache = {"k": carry[0], "v": carry[1], "tables": tables,
                  "length": lengths}
     if quant:
@@ -252,9 +253,7 @@ def extend_step_paged(
     )  # [B, C]
     offset = positions % block
 
-    h = params["embed"][tokens]  # [B, C, D]
-    if cfg.embedding_scale:
-        h = h * jnp.sqrt(cfg.d_model).astype(h.dtype)
+    h = _embed(cfg, params, tokens)  # [B, C, D]
 
     per_layer_lora = None
     if lora_bufs is not None:
@@ -275,19 +274,21 @@ def extend_step_paged(
         q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
         k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
         pools = _pool_update(tuple(pools), k, v, phys_block, offset)
-        # Quantized pools dequant at the gathered view: XLA fuses the
-        # multiply into the attention reads, so HBM still streams int8.
-        k_rows, v_rows = _pool_rows(pools, tables, h.dtype)
-        qg = q.reshape(b, c, cfg.n_kv_heads, cfg.q_per_kv, hd)
-        logits = jnp.einsum(
-            "bikgh,bjkh->bkgij", qg, k_rows,
-            preferred_element_type=jnp.float32,
-        ) / jnp.sqrt(hd).astype(jnp.float32)
-        mask = jnp.arange(s_max)[None, None, :] <= positions[:, :, None]
-        logits = jnp.where(mask[:, None, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(h.dtype)
-        attn = jnp.einsum("bkgij,bjkh->bikgh", probs, v_rows).reshape(b, c, -1)
-        h = h + _project(attn, lp["wo"], layer_lora, "o", slot_ids)
+        with jax.named_scope("attn.core"):
+            # Quantized pools dequant at the gathered view: XLA fuses the
+            # multiply into the attention reads, so HBM still streams int8.
+            k_rows, v_rows = _pool_rows(pools, tables, h.dtype)
+            qg = q.reshape(b, c, cfg.n_kv_heads, cfg.q_per_kv, hd)
+            logits = jnp.einsum(
+                "bikgh,bjkh->bkgij", qg, k_rows,
+                preferred_element_type=jnp.float32,
+            ) / jnp.sqrt(hd).astype(jnp.float32)
+            mask = jnp.arange(s_max)[None, None, :] <= positions[:, :, None]
+            logits = jnp.where(mask[:, None, None], logits, -1e30)
+            probs = jax.nn.softmax(logits, axis=-1).astype(h.dtype)
+            attn = jnp.einsum(
+                "bkgij,bjkh->bikgh", probs, v_rows).reshape(b, c, -1)
+        h = h + _attn_out(lp, attn, layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
         return h, pools
@@ -297,8 +298,7 @@ def extend_step_paged(
         xs = xs + (cache["k_scale"], cache["v_scale"])
     h, carry = jax.lax.scan(layer_fn, h, xs)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = q_matmul(h, head).astype(jnp.float32)
+    logits = _lm_head(cfg, params, h)
     new_cache = {"k": carry[0], "v": carry[1], "tables": tables,
                  "length": positions[:, -1] + 1}
     if quant:
@@ -306,6 +306,7 @@ def extend_step_paged(
     return logits, new_cache
 
 
+@jax.named_scope("kv.insert")
 def insert_prefill_paged(
     cache: Params,
     k_prompt: jax.Array,   # [L, 1, S_bucket, Kh, hd] from prefill
@@ -398,9 +399,7 @@ def prefill_with_cache_paged(
     )  # [C]
     offset = positions % block
 
-    h = params["embed"][tokens][None]
-    if cfg.embedding_scale:
-        h = h * jnp.sqrt(cfg.d_model).astype(h.dtype)
+    h = _embed(cfg, params, tokens)[None]
     pos2d = positions[None]
 
     quant = "k_scale" in cache
@@ -420,7 +419,7 @@ def prefill_with_cache_paged(
         # Flash-style chunk attend over the gathered lane view (shared
         # dispatch incl. the quant gate: transformer._chunk_attend).
         attn = _chunk_attend(cfg, quant, q, lane_k, lane_v, positions[0])
-        h = h + _project(attn, lp["wo"], layer_lora, "o", slot_ids)
+        h = h + _attn_out(lp, attn, layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
         return h, pools
@@ -430,9 +429,8 @@ def prefill_with_cache_paged(
         xs = xs + (cache["k_scale"], cache["v_scale"])
     h, carry = jax.lax.scan(layer_fn, h, xs)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     last_h = jax.lax.dynamic_index_in_dim(h[0], last_index, 0, keepdims=False)
-    last_logits = q_matmul(last_h, head).astype(jnp.float32)
+    last_logits = _lm_head(cfg, params, last_h)
     length_vec = cache["length"].at[row].set(lane_end)
     new_cache = {"k": carry[0], "v": carry[1], "tables": tables,
                  "length": length_vec}

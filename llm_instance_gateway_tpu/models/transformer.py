@@ -16,6 +16,11 @@ TPU-first structure:
 - bfloat16 params/activations, f32 softmax/norms, f32 logits.
 - Multi-LoRA deltas (``models.lora``) apply to every projection with per-row
   slot ids, so one decode batch multiplexes adapters + base model.
+- Every block sits in a ``jax.named_scope`` (embed, attn.qkv, attn.rope,
+  attn.kv_update, attn.core, attn.out, mlp, moe.route / .dispatch /
+  .experts / .fallback, lora, lm_head, kv.insert): the scope is in each
+  compiled operation's name, so a device trace says which line of this file
+  an operation belongs to.
 """
 
 from __future__ import annotations
@@ -202,6 +207,7 @@ def _project(x, w, layer_lora, target, slot_ids):
     return out
 
 
+@jax.named_scope("attn.qkv")
 def _attn_proj(lp, target, x, layer_lora, slot_ids):
     """Q/K/V projection with the optional attention bias (Qwen2-family:
     ``attention_bias`` adds learned biases to q/k/v only).  The bias keys
@@ -212,6 +218,29 @@ def _attn_proj(lp, target, x, layer_lora, slot_ids):
     return out if b is None else out + b
 
 
+@jax.named_scope("attn.out")
+def _attn_out(lp, attn, layer_lora, slot_ids):
+    """The attention block's output projection."""
+    return _project(attn, lp["wo"], layer_lora, "o", slot_ids)
+
+
+@jax.named_scope("embed")
+def _embed(cfg: ModelConfig, params: Params, tokens):
+    """Token embeddings; the activation dtype follows the param dtype."""
+    h = params["embed"][tokens]
+    if cfg.embedding_scale:
+        h = h * jnp.sqrt(cfg.d_model).astype(h.dtype)
+    return h
+
+
+@jax.named_scope("lm_head")
+def _lm_head(cfg: ModelConfig, params: Params, h):
+    """Output head matmul (tied or separate) to f32 logits."""
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    return q_matmul(h, head).astype(jnp.float32)
+
+
+@jax.named_scope("attn.core")
 def _chunk_attend(cfg: ModelConfig, quant: bool, q, lane_k, lane_v, start):
     """Chunk-vs-lane attention dispatch, shared by the lane and paged
     chunk-stream paths.  Flash-style kernel (XLA off-TPU/odd shapes, logged
@@ -236,9 +265,11 @@ def _chunk_attend(cfg: ModelConfig, quant: bool, q, lane_k, lane_v, start):
 def _mlp(cfg: ModelConfig, lp: Params, x, layer_lora, slot_ids):
     if cfg.n_experts:
         return _moe_mlp(cfg, lp, x)
-    gate = _project(x, lp["w_gate"], layer_lora, "gate", slot_ids)
-    up = _project(x, lp["w_up"], layer_lora, "up", slot_ids)
-    return _project(swiglu(gate, up, cfg.gelu_mlp), lp["w_down"], layer_lora, "down", slot_ids)
+    with jax.named_scope("mlp"):
+        gate = _project(x, lp["w_gate"], layer_lora, "gate", slot_ids)
+        up = _project(x, lp["w_up"], layer_lora, "up", slot_ids)
+        return _project(swiglu(gate, up, cfg.gelu_mlp), lp["w_down"],
+                        layer_lora, "down", slot_ids)
 
 
 def _moe_mlp(cfg: ModelConfig, lp: Params, x):
@@ -307,19 +338,24 @@ def _moe_capacity(cfg: ModelConfig, t: int) -> int:
 
 def _moe_dense(cfg: ModelConfig, lp: Params, x):
     """Compute every expert; mix by renormalized top-k gates."""
-    router_logits = (x @ lp["router"]).astype(jnp.float32)  # [..., E]
-    e = cfg.n_experts
-    topv, topi = jax.lax.top_k(router_logits, cfg.n_experts_per_token)
-    gates = jax.nn.softmax(topv, axis=-1)  # renormalize over selected experts
-    # Scatter gate weights back to a dense [..., E] mix vector.
-    dense_gates = jnp.sum(
-        jax.nn.one_hot(topi, e, dtype=jnp.float32) * gates[..., None], axis=-2
-    )  # [..., E]
-    hidden = expert_mix(x, lp["w_gate"])
-    up = expert_mix(x, lp["w_up"])
-    act = swiglu(hidden, up, cfg.gelu_mlp)
-    per_expert = expert_mix_down(act, lp["w_down"])
-    return jnp.einsum("...ed,...e->...d", per_expert, dense_gates.astype(x.dtype))
+    with jax.named_scope("moe.route"):
+        router_logits = (x @ lp["router"]).astype(jnp.float32)  # [..., E]
+        e = cfg.n_experts
+        topv, topi = jax.lax.top_k(router_logits, cfg.n_experts_per_token)
+        # Renormalize over the selected experts.
+        gates = jax.nn.softmax(topv, axis=-1)
+        # Scatter gate weights back to a dense [..., E] mix vector.
+        dense_gates = jnp.sum(
+            jax.nn.one_hot(topi, e, dtype=jnp.float32) * gates[..., None],
+            axis=-2,
+        )  # [..., E]
+    with jax.named_scope("moe.experts"):
+        hidden = expert_mix(x, lp["w_gate"])
+        up = expert_mix(x, lp["w_up"])
+        act = swiglu(hidden, up, cfg.gelu_mlp)
+        per_expert = expert_mix_down(act, lp["w_down"])
+        return jnp.einsum("...ed,...e->...d", per_expert,
+                          dense_gates.astype(x.dtype))
 
 
 def _moe_grouped(cfg: ModelConfig, lp: Params, x):
@@ -345,43 +381,50 @@ def _moe_grouped(cfg: ModelConfig, lp: Params, x):
     t = xf.shape[0]
     e, k = cfg.n_experts, cfg.n_experts_per_token
 
-    router_logits = (xf @ lp["router"]).astype(jnp.float32)  # [T, E]
-    topv, topi = jax.lax.top_k(router_logits, k)
-    gates = jax.nn.softmax(topv, axis=-1)  # [T, k]
+    with jax.named_scope("moe.route"):
+        router_logits = (xf @ lp["router"]).astype(jnp.float32)  # [T, E]
+        topv, topi = jax.lax.top_k(router_logits, k)
+        gates = jax.nn.softmax(topv, axis=-1)  # [T, k]
 
     cap = _moe_capacity(cfg, t)
 
-    flat_expert = topi.reshape(-1)  # [T*k]
-    flat_assign = jax.nn.one_hot(flat_expert, e, dtype=jnp.int32)  # [T*k, E]
-    # Position of each assignment within its expert's capacity tile.
-    pos = jnp.sum((jnp.cumsum(flat_assign, axis=0) - 1) * flat_assign, axis=-1)
-    kept = pos < cap  # [T*k]
-    # Overflowed assignments clip onto the last tile row with a zeroed
-    # contribution — collisions there add 0, and the combine gather masks
-    # them out the same way.
-    flat_idx = flat_expert * cap + jnp.clip(pos, 0, cap - 1)  # [T*k]
-    keep_col = kept[:, None].astype(xf.dtype)
+    with jax.named_scope("moe.dispatch"):
+        flat_expert = topi.reshape(-1)  # [T*k]
+        flat_assign = jax.nn.one_hot(flat_expert, e, dtype=jnp.int32)
+        # Position of each assignment within its expert's capacity tile.
+        pos = jnp.sum(
+            (jnp.cumsum(flat_assign, axis=0) - 1) * flat_assign, axis=-1)
+        kept = pos < cap  # [T*k]
+        # Overflowed assignments clip onto the last tile row with a zeroed
+        # contribution — collisions there add 0, and the combine gather
+        # masks them out the same way.
+        flat_idx = flat_expert * cap + jnp.clip(pos, 0, cap - 1)  # [T*k]
+        keep_col = kept[:, None].astype(xf.dtype)
 
-    xk = jnp.repeat(xf, k, axis=0)  # [T*k, D] (token order matches topi)
-    x_e = (
-        jnp.zeros((e * cap, d), xf.dtype)
-        .at[flat_idx].add(xk * keep_col)
-        .reshape(e, cap, d)
-    )
-    hidden = expert_matmul(x_e, lp["w_gate"])
-    up = expert_matmul(x_e, lp["w_up"])
-    act = swiglu(hidden, up, cfg.gelu_mlp)
-    out_e = expert_matmul(act, lp["w_down"])
-    gathered = out_e.reshape(e * cap, d)[flat_idx] * keep_col  # [T*k, D]
-    y = jnp.sum(
-        gathered.reshape(t, k, d) * gates.astype(xf.dtype)[..., None], axis=1
-    )
+        xk = jnp.repeat(xf, k, axis=0)  # [T*k, D] (token order: topi's)
+        x_e = (
+            jnp.zeros((e * cap, d), xf.dtype)
+            .at[flat_idx].add(xk * keep_col)
+            .reshape(e, cap, d)
+        )
+    with jax.named_scope("moe.experts"):
+        hidden = expert_matmul(x_e, lp["w_gate"])
+        up = expert_matmul(x_e, lp["w_up"])
+        act = swiglu(hidden, up, cfg.gelu_mlp)
+        out_e = expert_matmul(act, lp["w_down"])
+    with jax.named_scope("moe.dispatch"):  # the way back: gather + mix
+        gathered = out_e.reshape(e * cap, d)[flat_idx] * keep_col  # [T*k, D]
+        y = jnp.sum(
+            gathered.reshape(t, k, d) * gates.astype(xf.dtype)[..., None],
+            axis=1,
+        )
 
     if cfg.moe_exact_fallback:
-        overflow = jnp.any(~kept)
-        y = jax.lax.cond(
-            overflow, lambda op: _moe_dense(cfg, lp, op), lambda _: y, xf
-        )
+        with jax.named_scope("moe.fallback"):
+            overflow = jnp.any(~kept)
+            y = jax.lax.cond(
+                overflow, lambda op: _moe_dense(cfg, lp, op), lambda _: y, xf
+            )
     return y.reshape(orig_shape)
 
 
@@ -415,17 +458,20 @@ def prefill_layer(
     v = _attn_proj(lp, "v", hn, layer_lora, slot_ids).reshape(b, s, cfg.n_kv_heads, hd)
     q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
     k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
-    if attention_fn is not None:
-        attn = attention_fn(q, k, v, positions)
-    elif cfg.use_flash_attention:
-        # Right-padded batches: causal tiling alone keeps real positions
-        # exact (pallas_attention.flash_attention docstring).
-        from llm_instance_gateway_tpu.ops.pallas_attention import flash_attention
+    with jax.named_scope("attn.core"):
+        if attention_fn is not None:
+            attn = attention_fn(q, k, v, positions)
+        elif cfg.use_flash_attention:
+            # Right-padded batches: causal tiling alone keeps real positions
+            # exact (pallas_attention.flash_attention docstring).
+            from llm_instance_gateway_tpu.ops.pallas_attention import (
+                flash_attention,
+            )
 
-        attn = flash_attention(q, k, v)
-    else:
-        attn = prefill_attention(q, k, v, positions)
-    h = h + _project(attn.reshape(b, s, -1), lp["wo"], layer_lora, "o", slot_ids)
+            attn = flash_attention(q, k, v)
+        else:
+            attn = prefill_attention(q, k, v, positions)
+    h = h + _attn_out(lp, attn.reshape(b, s, -1), layer_lora, slot_ids)
     hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
     return h, (k, v)
@@ -449,9 +495,7 @@ def prefill(
     b, s = tokens.shape
     if slot_ids is None:
         slot_ids = jnp.full((b,), -1, jnp.int32)
-    h = params["embed"][tokens]  # activation dtype follows param dtype
-    if cfg.embedding_scale:
-        h = h * jnp.sqrt(cfg.d_model).astype(h.dtype)
+    h = _embed(cfg, params, tokens)
 
     per_layer_lora = None
     if lora_bufs is not None:
@@ -468,14 +512,58 @@ def prefill(
     xs = (params["layers"], per_layer_lora)
     h, (k_all, v_all) = jax.lax.scan(layer_fn, h, xs)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = q_matmul(h, head).astype(jnp.float32)
+    logits = _lm_head(cfg, params, h)
     return logits, k_all, v_all
 
 
 # ---------------------------------------------------------------------------
 # Decode step
 # ---------------------------------------------------------------------------
+
+
+@jax.named_scope("attn.core")
+def _decode_attend(cfg: ModelConfig, attention_fn, q, k_cache, v_cache,
+                   k_scale, v_scale, lengths, dtype):
+    """One layer's cached attention over the lanes just written: which
+    implementation reads them.  ``k_scale`` None is a bf16 cache."""
+    if k_scale is None:
+        if attention_fn is not None:
+            return attention_fn(q, k_cache, v_cache, lengths)
+        if cfg.use_pallas_decode:
+            from llm_instance_gateway_tpu.ops.pallas_decode_attention import (
+                decode_attention as pallas_decode,
+            )
+
+            return pallas_decode(q, k_cache, v_cache, lengths)
+        return decode_attention(q, k_cache, v_cache, lengths)
+    if getattr(attention_fn, "quant_aware", False):
+        # Quant-aware override (sharded_attention.make_cached_decode_quant):
+        # raw int8 + scales go in; each shard's kernel dequantizes in VMEM,
+        # so HBM streams int8 even under the mesh — kernel win and
+        # bandwidth win together.
+        return attention_fn(q, k_cache, v_cache, k_scale, v_scale, lengths)
+    if attention_fn is not None:
+        # Opaque override without quant awareness: hand it the dequantized
+        # view.  NOTE — such an override cannot fuse the dequant into its
+        # reads and materializes a full bf16 cache; the engine only
+        # installs quant_aware wrappers on quantized lanes for exactly that
+        # reason.
+        return attention_fn(
+            q, _kv_dequantize(k_cache, k_scale, dtype),
+            _kv_dequantize(v_cache, v_scale, dtype), lengths)
+    if cfg.use_pallas_decode:
+        from llm_instance_gateway_tpu.ops.pallas_decode_attention import (
+            decode_attention_quant,
+        )
+
+        # int8-aware kernel: dequantizes in VMEM at the MXU feed, so HBM
+        # streams half the bytes of the bf16 kernel (auto XLA fallback
+        # off-TPU / unsupported shapes).
+        return decode_attention_quant(
+            q, k_cache, v_cache, k_scale, v_scale, lengths)
+    return decode_attention(
+        q, _kv_dequantize(k_cache, k_scale, dtype),
+        _kv_dequantize(v_cache, v_scale, dtype), lengths)
 
 
 def decode_step(
@@ -506,9 +594,7 @@ def decode_step(
     b = tokens.shape[0]
     if slot_ids is None:
         slot_ids = jnp.full((b,), -1, jnp.int32)
-    h = params["embed"][tokens]  # [B, D]; activation dtype follows param dtype
-    if cfg.embedding_scale:
-        h = h * jnp.sqrt(cfg.d_model).astype(h.dtype)
+    h = _embed(cfg, params, tokens)  # [B, D]
 
     per_layer_lora = None
     if lora_bufs is not None:
@@ -537,59 +623,22 @@ def decode_step(
         v = _attn_proj(lp, "v", hn, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
         q = apply_rope(q[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
         k = apply_rope(k[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
-        if quant:
-            kq, ks = _kv_quantize(k)
-            vq, vs = _kv_quantize(v)
-            k_cache = k_cache.at[batch_idx, write_pos].set(kq)
-            v_cache = v_cache.at[batch_idx, write_pos].set(vq)
-            k_scale = k_scale.at[batch_idx, write_pos].set(ks)
-            v_scale = v_scale.at[batch_idx, write_pos].set(vs)
-            if getattr(attention_fn, "quant_aware", False):
-                # Quant-aware override (sharded_attention.make_cached_
-                # decode_quant): raw int8 + scales go in; each shard's
-                # kernel dequantizes in VMEM, so HBM streams int8 even
-                # under the mesh — kernel win and bandwidth win together.
-                attn = attention_fn(
-                    q, k_cache, v_cache, k_scale, v_scale, lengths)
-            elif attention_fn is not None:
-                # Opaque override without quant awareness: hand it the
-                # dequantized view.  NOTE — such an override cannot fuse
-                # the dequant into its reads and materializes a full bf16
-                # cache; the engine only installs quant_aware wrappers on
-                # quantized lanes for exactly that reason.
-                attn = attention_fn(
-                    q, _kv_dequantize(k_cache, k_scale, h.dtype),
-                    _kv_dequantize(v_cache, v_scale, h.dtype), lengths)
-            elif cfg.use_pallas_decode:
-                from llm_instance_gateway_tpu.ops.pallas_decode_attention import (
-                    decode_attention_quant,
-                )
-
-                # int8-aware kernel: dequantizes in VMEM at the MXU feed,
-                # so HBM streams half the bytes of the bf16 kernel (auto
-                # XLA fallback off-TPU / unsupported shapes).
-                attn = decode_attention_quant(
-                    q, k_cache, v_cache, k_scale, v_scale, lengths)
+        with jax.named_scope("attn.kv_update"):
+            if quant:
+                kq, ks = _kv_quantize(k)
+                vq, vs = _kv_quantize(v)
+                k_cache = k_cache.at[batch_idx, write_pos].set(kq)
+                v_cache = v_cache.at[batch_idx, write_pos].set(vq)
+                k_scale = k_scale.at[batch_idx, write_pos].set(ks)
+                v_scale = v_scale.at[batch_idx, write_pos].set(vs)
+                carry_out = (k_cache, v_cache, k_scale, v_scale)
             else:
-                attn = decode_attention(
-                    q, _kv_dequantize(k_cache, k_scale, h.dtype),
-                    _kv_dequantize(v_cache, v_scale, h.dtype), lengths)
-            carry_out = (k_cache, v_cache, k_scale, v_scale)
-        else:
-            k_cache = k_cache.at[batch_idx, write_pos].set(k)
-            v_cache = v_cache.at[batch_idx, write_pos].set(v)
-            if attention_fn is not None:
-                attn = attention_fn(q, k_cache, v_cache, lengths)
-            elif cfg.use_pallas_decode:
-                from llm_instance_gateway_tpu.ops.pallas_decode_attention import (
-                    decode_attention as pallas_decode,
-                )
-
-                attn = pallas_decode(q, k_cache, v_cache, lengths)
-            else:
-                attn = decode_attention(q, k_cache, v_cache, lengths)
-            carry_out = (k_cache, v_cache)
-        h = h + _project(attn.reshape(b, -1), lp["wo"], layer_lora, "o", slot_ids)
+                k_cache = k_cache.at[batch_idx, write_pos].set(k)
+                v_cache = v_cache.at[batch_idx, write_pos].set(v)
+                carry_out = (k_cache, v_cache)
+        attn = _decode_attend(cfg, attention_fn, q, k_cache, v_cache,
+                              k_scale, v_scale, lengths, h.dtype)
+        h = h + _attn_out(lp, attn.reshape(b, -1), layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
         return h, carry_out
@@ -599,8 +648,7 @@ def decode_step(
         xs = xs + (cache["k_scale"], cache["v_scale"])
     h, carry = jax.lax.scan(layer_fn, h, xs)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = q_matmul(h, head).astype(jnp.float32)
+    logits = _lm_head(cfg, params, h)
     new_cache = {"k": carry[0], "v": carry[1], "length": lengths}
     if quant:
         new_cache["k_scale"], new_cache["v_scale"] = carry[2], carry[3]
@@ -635,9 +683,7 @@ def extend_step(
     s_max = cache["k"].shape[2]
     if slot_ids is None:
         slot_ids = jnp.full((b,), -1, jnp.int32)
-    h = params["embed"][tokens]  # [B, C, D]
-    if cfg.embedding_scale:
-        h = h * jnp.sqrt(cfg.d_model).astype(h.dtype)
+    h = _embed(cfg, params, tokens)  # [B, C, D]
 
     per_layer_lora = None
     if lora_bufs is not None:
@@ -664,32 +710,37 @@ def extend_step(
             b, c, cfg.n_kv_heads, hd)
         q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
         k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
-        if quant:
-            kq, ks = _kv_quantize(k)
-            vq, vs = _kv_quantize(v)
-            k_cache = k_cache.at[batch_idx, write_pos].set(kq)
-            v_cache = v_cache.at[batch_idx, write_pos].set(vq)
-            k_scale = k_scale.at[batch_idx, write_pos].set(ks)
-            v_scale = v_scale.at[batch_idx, write_pos].set(vs)
-            k_read = _kv_dequantize(k_cache, k_scale, h.dtype)
-            v_read = _kv_dequantize(v_cache, v_scale, h.dtype)
-            carry_out = (k_cache, v_cache, k_scale, v_scale)
-        else:
-            k_cache = k_cache.at[batch_idx, write_pos].set(k)
-            v_cache = v_cache.at[batch_idx, write_pos].set(v)
-            k_read, v_read = k_cache, v_cache
-            carry_out = (k_cache, v_cache)
-        # [B,C,K,G,hd] x [B,S,K,hd] -> [B,K,G,C,S]; mask j <= position_i.
-        qg = q.reshape(b, c, cfg.n_kv_heads, cfg.q_per_kv, hd)
-        logits = jnp.einsum(
-            "bikgh,bjkh->bkgij", qg, k_read,
-            preferred_element_type=jnp.float32,
-        ) / jnp.sqrt(hd).astype(jnp.float32)
-        mask = jnp.arange(s_max)[None, None, :] <= positions[:, :, None]
-        logits = jnp.where(mask[:, None, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(h.dtype)
-        attn = jnp.einsum("bkgij,bjkh->bikgh", probs, v_read).reshape(b, c, -1)
-        h = h + _project(attn, lp["wo"], layer_lora, "o", slot_ids)
+        with jax.named_scope("attn.kv_update"):
+            if quant:
+                kq, ks = _kv_quantize(k)
+                vq, vs = _kv_quantize(v)
+                k_cache = k_cache.at[batch_idx, write_pos].set(kq)
+                v_cache = v_cache.at[batch_idx, write_pos].set(vq)
+                k_scale = k_scale.at[batch_idx, write_pos].set(ks)
+                v_scale = v_scale.at[batch_idx, write_pos].set(vs)
+                carry_out = (k_cache, v_cache, k_scale, v_scale)
+            else:
+                k_cache = k_cache.at[batch_idx, write_pos].set(k)
+                v_cache = v_cache.at[batch_idx, write_pos].set(v)
+                carry_out = (k_cache, v_cache)
+        with jax.named_scope("attn.core"):
+            if quant:
+                k_read = _kv_dequantize(k_cache, k_scale, h.dtype)
+                v_read = _kv_dequantize(v_cache, v_scale, h.dtype)
+            else:
+                k_read, v_read = k_cache, v_cache
+            # [B,C,K,G,hd] x [B,S,K,hd] -> [B,K,G,C,S]; mask j <= position_i.
+            qg = q.reshape(b, c, cfg.n_kv_heads, cfg.q_per_kv, hd)
+            logits = jnp.einsum(
+                "bikgh,bjkh->bkgij", qg, k_read,
+                preferred_element_type=jnp.float32,
+            ) / jnp.sqrt(hd).astype(jnp.float32)
+            mask = jnp.arange(s_max)[None, None, :] <= positions[:, :, None]
+            logits = jnp.where(mask[:, None, None], logits, -1e30)
+            probs = jax.nn.softmax(logits, axis=-1).astype(h.dtype)
+            attn = jnp.einsum(
+                "bkgij,bjkh->bikgh", probs, v_read).reshape(b, c, -1)
+        h = h + _attn_out(lp, attn, layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
         return h, carry_out
@@ -699,8 +750,7 @@ def extend_step(
         xs = xs + (cache["k_scale"], cache["v_scale"])
     h, carry = jax.lax.scan(layer_fn, h, xs)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = q_matmul(h, head).astype(jnp.float32)
+    logits = _lm_head(cfg, params, h)
     new_cache = {"k": carry[0], "v": carry[1],
                  "length": positions[:, -1] + 1}
     if quant:
@@ -745,9 +795,7 @@ def prefill_with_cache(
     if lora_bufs is not None:
         per_layer_lora, _ = lora_lib.stack_for_scan(lora_bufs)
 
-    h = params["embed"][tokens][None]  # [1, C, D]
-    if cfg.embedding_scale:
-        h = h * jnp.sqrt(cfg.d_model).astype(h.dtype)
+    h = _embed(cfg, params, tokens)[None]  # [1, C, D]
     pos2d = positions[None]  # [1, C]
     quant = "k_scale" in cache
 
@@ -765,13 +813,18 @@ def prefill_with_cache(
         q = apply_rope(q, pos2d, cfg.rope_theta, cfg.rope_scaling)
         k = apply_rope(k, pos2d, cfg.rope_theta, cfg.rope_scaling)
         # Scatter the chunk's K/V into the slot's lane at absolute positions.
+        with jax.named_scope("attn.kv_update"):
+            if quant:
+                kq, ks = _kv_quantize(k[0])
+                vq, vs = _kv_quantize(v[0])
+                k_cache = k_cache.at[slot, positions].set(kq)
+                v_cache = v_cache.at[slot, positions].set(vq)
+                k_scale = k_scale.at[slot, positions].set(ks)
+                v_scale = v_scale.at[slot, positions].set(vs)
+            else:
+                k_cache = k_cache.at[slot, positions].set(k[0])
+                v_cache = v_cache.at[slot, positions].set(v[0])
         if quant:
-            kq, ks = _kv_quantize(k[0])
-            vq, vs = _kv_quantize(v[0])
-            k_cache = k_cache.at[slot, positions].set(kq)
-            v_cache = v_cache.at[slot, positions].set(vq)
-            k_scale = k_scale.at[slot, positions].set(ks)
-            v_scale = v_scale.at[slot, positions].set(vs)
             lane_k = _kv_dequantize(
                 jax.lax.dynamic_index_in_dim(k_cache, slot, 0, keepdims=False),
                 jax.lax.dynamic_index_in_dim(k_scale, slot, 0, keepdims=False),
@@ -782,8 +835,6 @@ def prefill_with_cache(
                 h.dtype)
             carry_out = (k_cache, v_cache, k_scale, v_scale)
         else:
-            k_cache = k_cache.at[slot, positions].set(k[0])
-            v_cache = v_cache.at[slot, positions].set(v[0])
             # Chunk queries vs the whole lane, masked to index <= q position.
             lane_k = jax.lax.dynamic_index_in_dim(k_cache, slot, 0, keepdims=False)
             lane_v = jax.lax.dynamic_index_in_dim(v_cache, slot, 0, keepdims=False)
@@ -792,7 +843,7 @@ def prefill_with_cache(
         # K blocks past the chunk's reach elide their DMAs — bandwidth
         # tracks the prompt's progress, not S_max (_chunk_attend).
         attn = _chunk_attend(cfg, quant, q, lane_k, lane_v, positions[0])
-        h = h + _project(attn, lp["wo"], layer_lora, "o", slot_ids)
+        h = h + _attn_out(lp, attn, layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
         return h, carry_out
@@ -802,9 +853,8 @@ def prefill_with_cache(
         xs = xs + (cache["k_scale"], cache["v_scale"])
     h, carry = jax.lax.scan(layer_fn, h, xs)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     last_h = jax.lax.dynamic_index_in_dim(h[0], last_index, 0, keepdims=False)
-    last_logits = q_matmul(last_h, head).astype(jnp.float32)
+    last_logits = _lm_head(cfg, params, last_h)
     length_vec = cache["length"].at[slot].set(lane_end)
     out_cache = {"k": carry[0], "v": carry[1], "length": length_vec}
     if quant:
@@ -812,6 +862,7 @@ def prefill_with_cache(
     return last_logits, out_cache
 
 
+@jax.named_scope("kv.insert")
 def insert_prefill(
     cache: Params,
     k_prompt: jax.Array,  # [L, 1, S, K, hd] from prefill

@@ -49,6 +49,7 @@ def rope_frequencies(head_dim: int, theta: float,
     return scaled
 
 
+@jax.named_scope("attn.rope")
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
                scaling: tuple | None = None) -> jax.Array:
     """Rotary position embedding.

@@ -178,6 +178,7 @@ def flash_attention_bhsd(
                                  "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
 
 
@@ -296,6 +297,7 @@ def chunk_attention_pallas(
                                  "arbitrary"),
         ),
         interpret=interpret,
+        name="chunk_attention",
     )(off, qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
 

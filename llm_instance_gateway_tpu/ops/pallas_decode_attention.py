@@ -182,6 +182,7 @@ def _pallas_decode_call(q, k_cache, v_cache, scales, lengths,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="decode_attention_int8" if quant else "decode_attention",
     )(*operands)
     return out.reshape(b, n_heads, hd)
 
@@ -334,6 +335,8 @@ def paged_decode_attention_pallas(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=("paged_decode_attention_int8" if quant
+              else "paged_decode_attention"),
     )(*operands)
     return out.reshape(b, n_heads, hd)
 

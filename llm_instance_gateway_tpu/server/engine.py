@@ -52,7 +52,7 @@ from llm_instance_gateway_tpu.server.sampling import (
     stop_suffix_hit,
 )
 from llm_instance_gateway_tpu.server.kv_ledger import KvLedger
-from llm_instance_gateway_tpu.server.profiler import StepProfiler
+from llm_instance_gateway_tpu.server.profiler import NO_PHASE, StepProfiler
 from llm_instance_gateway_tpu.server.usage import UsageTracker, owner_key
 from llm_instance_gateway_tpu.tracing import LATENCY_BUCKETS, Histogram
 
@@ -80,6 +80,33 @@ class EngineDraining(RuntimeError):
     analogous single-condition translation)."""
 
 
+def _named(name: str, fn, *bound):
+    """``functools.partial(fn, *bound)`` under a stable ``__name__``: jit
+    names the compiled program after it (``jit_<name>`` in a device trace;
+    a bare partial is ``jit__unknown``), and a partial keeps the signature
+    that ``donate_argnames`` / ``static_argnames`` are resolved against."""
+    part = functools.partial(fn, *bound)
+    part.__name__ = part.__qualname__ = name
+    return part
+
+
+def _in_phase(name: str, hand_over: bool = False):
+    """The decorated engine-thread method runs as phase ``name`` of the
+    step profiler's stack (server/profiler.py).  With ``hand_over`` it gets
+    the open phase as its ``ph`` argument and renames it as it goes
+    (``ph.to("decode.stage")``): a run of phases in one method."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(self, *args, **kwargs):
+            with self._phase(name) as ph:
+                if hand_over:
+                    kwargs["ph"] = ph
+                return fn(self, *args, **kwargs)
+        return wrapped
+    return deco
+
+
+@jax.named_scope("logprobs")
 def _logprob_info(logits, sampled, valid_vocab: int):
     """(sampled-token logprob, top-K logprobs, top-K ids) from raw logits.
 
@@ -734,7 +761,8 @@ class Engine:
         # from the engine loop, so the dispatch/host-sync/idle attribution
         # tiles the engine thread's wall.
         self.profiler: StepProfiler | None = (
-            StepProfiler() if self.cfg.step_profile else None)
+            StepProfiler(annotate=jax.profiler.TraceAnnotation)
+            if self.cfg.step_profile else None)
         # KV economy ledger (server/kv_ledger.py): block lifecycle,
         # per-prefix reuse, fragmentation.  Own lock; charged at the
         # allocator/prefix/park sites, state-recounted on the KV sync,
@@ -755,35 +783,41 @@ class Engine:
                 transformer.decode_step, attention_fn=self._decode_attn_fn)
         else:
             step_fn = transformer.decode_step
-        self._jit_prefill = jax.jit(functools.partial(
-            self._prefill_impl, model_cfg, self._prefill_attn_fn))
-        self._jit_prefill_many = jax.jit(
-            functools.partial(self._prefill_many_impl, model_cfg,
-                              self._prefill_attn_fn))
+        # Every program is jitted under a stable name (_named): a device
+        # trace then says jit_decode_block, jit_prefill, ... and not
+        # jit__unknown_<fingerprint>.
+        self._jit_prefill = jax.jit(_named(
+            "prefill", self._prefill_impl, model_cfg, self._prefill_attn_fn))
+        self._jit_prefill_many = jax.jit(_named(
+            "prefill_many", self._prefill_many_impl, model_cfg,
+            self._prefill_attn_fn))
         self._jit_decode = jax.jit(
-            functools.partial(self._decode_impl, model_cfg, step_fn),
+            _named("decode_block", self._decode_impl, model_cfg, step_fn),
             donate_argnames=("cache", "counts"),
             static_argnames=("n_steps", "penalized"),
         )
         # Insert donates the cache too: without donation every admission would
         # copy the full multi-GB decode cache.
         self._jit_insert = jax.jit(
-            paged_lib.insert_prefill_paged if self.paged
-            else transformer.insert_prefill,
+            _named("insert_prefill",
+                   paged_lib.insert_prefill_paged if self.paged
+                   else transformer.insert_prefill),
             donate_argnames=("cache",),
         )
         # Chunked prefill for prompts beyond the largest bucket: one
         # chunk-sized program streams the prompt into the cache lane.
         self._jit_chunk = jax.jit(
-            functools.partial(
+            _named(
+                "prefill_chunk",
                 paged_lib.prefill_with_cache_paged if self.paged
                 else transformer.prefill_with_cache,
                 model_cfg,
             ),
             donate_argnames=("cache",),
         )
-        def _sample_one(logits, key, t, k, p, seed, pos, bias_ids,
-                        bias_vals):
+
+        def sample_one(logits, key, t, k, p, seed, pos, bias_ids,
+                       bias_vals):
             tok = sample(
                 logits[None], key, jnp.full((1,), t, jnp.float32),
                 jnp.full((1,), k, jnp.int32), jnp.full((1,), p, jnp.float32),
@@ -796,7 +830,7 @@ class Engine:
                 logits[None], tok, model_cfg.vocab_size)
             return tok[0], (lp[0], top_v[0], top_i[0])
 
-        self._jit_sample_one = jax.jit(_sample_one)
+        self._jit_sample_one = jax.jit(sample_one)
 
         if self._spec:
             if mesh is not None and mesh.size > 1 and (
@@ -832,16 +866,18 @@ class Engine:
             self.spec_cycles = 0
             self.spec_emitted = 0
 
-            def _draft_prefill(params, tokens, positions):
+            def draft_prefill(params, tokens, positions):
                 _, k, v = transformer.prefill(draft_cfg, params, tokens,
                                               positions)
                 return k, v
 
-            self._jit_draft_prefill = jax.jit(_draft_prefill)
+            self._jit_draft_prefill = jax.jit(draft_prefill)
             self._jit_draft_insert = jax.jit(
-                transformer.insert_prefill, donate_argnames=("cache",))
+                _named("draft_insert", transformer.insert_prefill),
+                donate_argnames=("cache",))
             self._jit_spec_block = jax.jit(
-                functools.partial(self._spec_block_impl, model_cfg, draft_cfg),
+                _named("spec_block", self._spec_block_impl, model_cfg,
+                       draft_cfg),
                 donate_argnames=("cache", "draft_cache"),
                 static_argnames=("n_cycles", "k_steps"))
 
@@ -964,8 +1000,9 @@ class Engine:
             # ring; a completed suffix deactivates the row exactly like
             # EOS (the stop's tail tokens are emitted, later steps are
             # invalid).  Frozen rows keep their history untouched.
-            hist = stop_hist_update(hist, sampled, valid)
-            hit_stop = valid & stop_suffix_hit(hist, stop_ids, stop_lens)
+            with jax.named_scope("stops"):
+                hist = stop_hist_update(hist, sampled, valid)
+                hit_stop = valid & stop_suffix_hit(hist, stop_ids, stop_lens)
             remaining = jnp.where(valid, remaining - 1, remaining)
             remaining = jnp.where(hit_eos | hit_stop, 0, remaining)
             next_tokens = jnp.where(active, sampled, tokens)
@@ -1804,6 +1841,17 @@ class Engine:
     def _lora_buffers(self):
         return self.lora.buffers if self.lora is not None else None
 
+    def _phase(self, name: str):
+        """The engine thread's phase from here to the end of the ``with``
+        (server/profiler.py); one shared no-op with the profiler off."""
+        p = self.profiler
+        return NO_PHASE if p is None else p.phase(name)
+
+    def _enqueue(self, name: str):
+        """Trace-only span round a jitted call inside a ``*.stage`` phase."""
+        p = self.profiler
+        return NO_PHASE if p is None else p.annotation(name)
+
     def _loop(self) -> None:
         while self._running:
             # 1) Drain admissions: fill EVERY free slot before decoding (a
@@ -1841,11 +1889,15 @@ class Engine:
                     self._fail_all_slots(e)
                 did_work = True
             if not did_work:
-                if self.profiler is not None:
-                    self.profiler.note_idle()
-                with self._work:
-                    self._work.wait(timeout=0.05)
+                self._wait_for_work()
 
+    def _wait_for_work(self) -> None:
+        if self.profiler is not None:
+            self.profiler.note_idle()
+        with self._phase("idle"), self._work:
+            self._work.wait(timeout=0.05)
+
+    @_in_phase("admit")
     def _admit_and_insert(self, pipelined: bool) -> bool:
         """Admission for both loops: drain decode_wait into freed slots,
         direct-prefill into free slots, prefill AHEAD when slots are full.
@@ -2090,18 +2142,23 @@ class Engine:
                          if self.lora is not None else -1)
             first_token, k, v, lp_info = self._bucket_prefill(
                 req, n, lora_slot)
-            req.handoff = kv_transfer.export_handoff(
-                req, k, v, n, int(first_token),
-                lp_info=tuple(np.asarray(a) for a in lp_info),
-                quantize=getattr(req, "_handoff_quantize", None))
-            req.t_first_token = time.time()
-            self._record_ttft(req)
-            self._finish(req, "handoff")
+            with self._phase("prefill.wait"):
+                # The token, its logprobs and the prompt's KV come to the
+                # host here: the thread waits for the prefill program.
+                req.handoff = kv_transfer.export_handoff(
+                    req, k, v, n, int(first_token),
+                    lp_info=tuple(np.asarray(a) for a in lp_info),
+                    quantize=getattr(req, "_handoff_quantize", None))
+            with self._phase("prefill.emit"):
+                req.t_first_token = time.time()
+                self._record_ttft(req)
+                self._finish(req, "handoff")
         except Exception as e:  # engine must survive a poison request
             logger.exception("handoff prefill failed for %s", req.request_id)
             req.error = str(e)
             self._finish(req, "error")
 
+    @_in_phase("prefill.stage")
     def _handoff_device_kv(self, handoff):
         """Handoff KV -> device arrays shaped like a bucketed prefill's
         output (``[L, 1, pad_to, Kh, hd]``), so the existing insert seams
@@ -2157,6 +2214,7 @@ class Engine:
             req.error = str(e)
             self._finish(req, "error")
 
+    @_in_phase("prefill.stage")
     def _activate_slot_pipelined(self, slot_idx: int, req: Request,
                                  lora_slot: int, n: int, first_token,
                                  lp_info) -> None:
@@ -2407,6 +2465,7 @@ class Engine:
                 next_extra_tok, next_extra_pos, next_has_extra,
                 cache, draft_cache)
 
+    @_in_phase("prefill.stage")
     def _draft_admit(self, slot_idx: int, prompt_tokens: list[int]) -> None:
         """Mirror a freshly admitted prompt into the draft model's lane so
         the slot can speculate.  Rows admitted through paths the draft
@@ -2478,8 +2537,10 @@ class Engine:
             for i in range(self.cfg.decode_slots)
         ]
 
-    def _do_spec_step(self) -> None:
-        """Sync-loop speculative dispatch: one fused block of cycles."""
+    @_in_phase("decode.plan", hand_over=True)
+    def _do_spec_step(self, ph) -> None:
+        """Sync-loop speculative dispatch: one fused block of cycles, in
+        the phases of ``_do_decode_step``."""
         k = self.cfg.speculative_k
         n_cycles = self._spec_cycles_per_sync()
         # Paged: every position a cycle can write (accepted or rejected)
@@ -2487,33 +2548,37 @@ class Engine:
         self._paged_ensure_decode(
             n_cycles * (k + 1), pipelined=False,
             per_row_steps=self._spec_row_steps(n_cycles, k))
+        ph.to("decode.stage")
         t0 = time.perf_counter()
-        (toks, valid, lps, top_v, top_i, _next_tok, _next_pos, _next_rem,
-         next_etok, next_epos, next_has, self.cache, self.draft_cache) = (
-            self._jit_spec_block(
-                self.params, self.draft_params, self._lora_buffers(),
-                self.cache, self.draft_cache,
-                jnp.asarray(self._slot_tokens),
-                jnp.asarray(self._slot_positions),
-                jnp.asarray(self._slot_remaining),
-                jnp.asarray(self._spec_extra_tok),
-                jnp.asarray(self._spec_extra_pos),
-                jnp.asarray(self._spec_has_extra),
-                jnp.asarray(self._spec_ok),
-                jnp.asarray(self._slot_temp), jnp.asarray(self._slot_topk),
-                jnp.asarray(self._slot_topp), self._next_key(),
-                jnp.asarray(self._slot_lora), self._eos_for_device,
-                jnp.asarray(self._slot_seed),
-                n_cycles=n_cycles, k_steps=k))
-        toks_np = np.asarray(toks)  # [T, B]
-        valid_np = np.asarray(valid)
-        lps_np = np.asarray(lps)
-        top_v_np = np.asarray(top_v)
-        top_i_np = np.asarray(top_i)
-        etok_np = np.asarray(next_etok)
-        epos_np = np.asarray(next_epos)
-        ehas_np = np.asarray(next_has)
+        args = (
+            self.params, self.draft_params, self._lora_buffers(),
+            self.cache, self.draft_cache,
+            jnp.asarray(self._slot_tokens),
+            jnp.asarray(self._slot_positions),
+            jnp.asarray(self._slot_remaining),
+            jnp.asarray(self._spec_extra_tok),
+            jnp.asarray(self._spec_extra_pos),
+            jnp.asarray(self._spec_has_extra),
+            jnp.asarray(self._spec_ok),
+            jnp.asarray(self._slot_temp), jnp.asarray(self._slot_topk),
+            jnp.asarray(self._slot_topp), self._next_key(),
+            jnp.asarray(self._slot_lora), self._eos_for_device,
+            jnp.asarray(self._slot_seed),
+        )
+        with self._enqueue("engine.decode.enqueue"):
+            (toks, valid, lps, top_v, top_i, _next_tok, _next_pos,
+             _next_rem, next_etok, next_epos, next_has, self.cache,
+             self.draft_cache) = self._jit_spec_block(
+                *args, n_cycles=n_cycles, k_steps=k)
+        ph.to("decode.wait")
+        outs = jax.block_until_ready(
+            (toks, valid, lps, top_v, top_i, next_etok, next_epos, next_has))
+        ph.to("decode.readback")
+        # [T, B] each, then the draft's catch-up triple [B]
+        (toks_np, valid_np, lps_np, top_v_np, top_i_np,
+         etok_np, epos_np, ehas_np) = map(np.asarray, outs)
         step_s = time.perf_counter() - t0
+        ph.to("decode.emit")
         n_tokens = 0
         self.spec_cycles += n_cycles
         t_steps = toks_np.shape[0]
@@ -2562,6 +2627,7 @@ class Engine:
             self._spec_extra_tok[i] = etok_np[i]
             self._spec_extra_pos[i] = epos_np[i]
             self._spec_has_extra[i] = bool(ehas_np[i])
+        ph.to("decode.account")
         self.spec_emitted += n_tokens
         if self.usage is not None:
             self.usage.charge_decode(step_s, owners, tok_by_owner)
@@ -2638,27 +2704,14 @@ class Engine:
         try:
             self._sync_tables()
             c = n - reused
-            bucket = self._bucket(c)
-            self._note_padding(bucket - c)
-            tokens = np.zeros((bucket,), np.int32)
-            tokens[:c] = req.prompt_tokens[reused:]
-            positions = reused + np.arange(bucket, dtype=np.int32)
-            last_logits, self.cache = self._jit_chunk(
-                self.params, self.cache,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.int32(slot_idx), jnp.int32(n), jnp.int32(c - 1),
-                lora_bufs=self._lora_buffers(),
-                lora_slot=jnp.int32(lora_slot),
-            )
+            self._note_padding(self._bucket(c) - c)
+            last_logits = self._chunk_dispatch(
+                req.prompt_tokens[reused:], reused, self._bucket(c),
+                slot_idx, n, lora_slot)
             self._prefix_register_row(slot_idx, req.prompt_tokens,
                                       req.adapter,
                                       hashes=self._prefix_hashes_for(req))
-            sp = req.sampling
-            first_token, lp_info = self._jit_sample_one(
-                last_logits, self._next_key(), jnp.float32(sp.temperature),
-                jnp.int32(sp.top_k), jnp.float32(sp.top_p),
-                jnp.int32(_seed_i32(sp.seed)),
-                jnp.int32(n - 1), *map(jnp.asarray, _bias_arrays(sp)))
+            first_token, lp_info = self._sample_first(req, last_logits, n)
         except BaseException:
             # Defensive: _paged_can_admit gated this admission (matched
             # blocks excluded from avail when pinned out of the evictable
@@ -2678,6 +2731,7 @@ class Engine:
         padded = -(-n // self._ring_pad) * self._ring_pad
         return padded <= self.cfg.max_seq_len
 
+    @_in_phase("prefill.stage")
     def _ring_prefill(self, req: Request, n: int, lora_slot: int):
         """One sequence-parallel prefill program over the mesh ring.
 
@@ -2702,15 +2756,39 @@ class Engine:
             lora_bufs=self._lora_buffers(),
             slot_ids=jnp.full((1,), lora_slot, jnp.int32),
         )
-        first_token, lp_info = self._jit_sample_one(
-            logits[0, n - 1], self._next_key(),
-            jnp.float32(sp.temperature), jnp.int32(sp.top_k),
-            jnp.float32(sp.top_p),
-            jnp.int32(_seed_i32(sp.seed)),
-            jnp.int32(n - 1), *map(jnp.asarray, _bias_arrays(sp)),
-        )
+        first_token, lp_info = self._sample_first(req, logits[0, n - 1], n)
         return first_token, k, v, lp_info
 
+    @_in_phase("prefill.stage")
+    def _chunk_dispatch(self, piece, start: int, chunk: int, slot_idx: int,
+                        lane_end: int, lora_slot: int):
+        """Stage one prompt piece, padded to ``chunk`` positions from
+        ``start``, and enqueue the chunk program on lane ``slot_idx``;
+        returns the logits after the piece's last real token."""
+        c = len(piece)
+        tokens = np.zeros((chunk,), np.int32)
+        tokens[:c] = piece
+        positions = start + np.arange(chunk, dtype=np.int32)
+        args = (self.params, self.cache,
+                jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.int32(slot_idx), jnp.int32(lane_end), jnp.int32(c - 1))
+        with self._enqueue("engine.prefill.enqueue"):
+            last_logits, self.cache = self._jit_chunk(
+                *args, lora_bufs=self._lora_buffers(),
+                lora_slot=jnp.int32(lora_slot))
+        return last_logits
+
+    @_in_phase("prefill.stage")
+    def _sample_first(self, req: Request, last_logits, n: int):
+        """Enqueue the one-row sampler over a prompt's last logits."""
+        sp = req.sampling
+        return self._jit_sample_one(
+            last_logits, self._next_key(), jnp.float32(sp.temperature),
+            jnp.int32(sp.top_k), jnp.float32(sp.top_p),
+            jnp.int32(_seed_i32(sp.seed)),
+            jnp.int32(n - 1), *map(jnp.asarray, _bias_arrays(sp)))
+
+    @_in_phase("prefill.stage")
     def _bucket_prefill(self, req: Request, n: int, lora_slot: int):
         """Pad a bucketable prompt and run the jitted prefill.
         Returns (first_token device scalar, k, v, lp_info)."""
@@ -2721,7 +2799,7 @@ class Engine:
         tokens[0, :n] = req.prompt_tokens
         positions = np.zeros((1, bucket), np.int32)
         positions[0, :n] = np.arange(n)
-        return self._jit_prefill(
+        args = (
             self.params, self._lora_buffers(),
             jnp.asarray(tokens), jnp.asarray(positions),
             jnp.int32(n), jnp.int32(lora_slot),
@@ -2730,7 +2808,10 @@ class Engine:
             jnp.int32(_seed_i32(sp.seed)),
             *map(jnp.asarray, _bias_arrays(sp)),
         )
+        with self._enqueue("engine.prefill.enqueue"):
+            return self._jit_prefill(*args)
 
+    @_in_phase("prefill.stage")
     def _bucket_prefill_many(self, reqs, ns, lora_slots):
         """One [P, bucket] prefill over same-bucket prompts.
         Returns (first_tokens [P] device, k [L,P,S,...], v, lp_infos)."""
@@ -2743,7 +2824,7 @@ class Engine:
             tokens[i, :n] = req.prompt_tokens
             positions[i, :n] = np.arange(n)
         sps = [r.sampling for r in reqs]
-        return self._jit_prefill_many(
+        args = (
             self.params, self._lora_buffers(),
             jnp.asarray(tokens), jnp.asarray(positions),
             jnp.asarray(ns, jnp.int32), jnp.asarray(lora_slots, jnp.int32),
@@ -2756,6 +2837,8 @@ class Engine:
             *(jnp.asarray(np.stack(arrs))
               for arrs in zip(*(_bias_arrays(sp) for sp in sps))),
         )
+        with self._enqueue("engine.prefill.enqueue"):
+            return self._jit_prefill_many(*args)
 
     def _collect_followers(self, first_req, limit: int) -> list:
         """Pull same-bucket followers of ``first_req`` for one batched
@@ -2803,7 +2886,8 @@ class Engine:
                             lp_info=lp_info, k=k, v=v, n=n,
                             lora_slot=lora_slot)
         if not pipelined:
-            tok = int(first_token)
+            with self._phase("prefill.wait"):
+                tok = int(first_token)
             w.first_token_host = tok
             if self._emit_first_token(req, tok, w.lp_info):
                 w.lp_info = None
@@ -2886,9 +2970,11 @@ class Engine:
                 lp_rows = [(_Row(hb, 1, i), _Row(hb, 2, i), _Row(hb, 3, i))
                            for i in range(len(live))]
             else:
-                toks = np.asarray(first_tokens)
-                lps_h, top_vs_h, top_is_h = (
-                    np.asarray(lps), np.asarray(top_vs), np.asarray(top_is))
+                with self._phase("prefill.wait"):
+                    toks = np.asarray(first_tokens)
+                    lps_h, top_vs_h, top_is_h = (
+                        np.asarray(lps), np.asarray(top_vs),
+                        np.asarray(top_is))
                 tok_rows = [int(t) for t in toks]
                 lp_rows = [(lps_h[i], top_vs_h[i], top_is_h[i])
                            for i in range(len(live))]
@@ -2963,6 +3049,7 @@ class Engine:
                 req.error = str(e)
                 self._finish(req, "error")
 
+    @_in_phase("prefill.stage")
     def _insert_prompt_kv(self, k, v, slot_idx: int, n: int,
                           skip_leading_blocks: int = 0) -> None:
         """Write a bucketed prefill's KV into the cache (lane or paged).
@@ -2972,9 +3059,10 @@ class Engine:
         prefix-reuse composition, where those blocks are already mapped
         from the cache and must not be re-scattered."""
         if not self.paged:
-            self.cache = self._jit_insert(
-                self.cache, k, v, jnp.int32(slot_idx), jnp.int32(n)
-            )
+            with self._enqueue("engine.prefill.enqueue"):
+                self.cache = self._jit_insert(
+                    self.cache, k, v, jnp.int32(slot_idx), jnp.int32(n)
+                )
             return
         try:
             self._paged_ensure(slot_idx, n)
@@ -3074,6 +3162,7 @@ class Engine:
             self._paged_free_row(st.slot_idx)
         self._finish(st.request, reason)
 
+    @_in_phase("admit")
     def _stream_step(self, pipelined: bool) -> None:
         """Dispatch ONE chunk of ONE in-flight stream — the round-robin
         cursor rotates across lanes, so N concurrent long prompts advance
@@ -3096,20 +3185,12 @@ class Engine:
         start = st.next_start
         piece = prompt[start:start + chunk]
         c = len(piece)
-        tokens = np.zeros((chunk,), np.int32)
-        tokens[:c] = piece
-        positions = start + np.arange(chunk, dtype=np.int32)
         try:
             if self.paged:
                 self._paged_ensure(st.slot_idx, start + c)
                 self._sync_tables()
-            st.last_logits, self.cache = self._jit_chunk(
-                self.params, self.cache,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.int32(st.slot_idx), jnp.int32(start + c), jnp.int32(c - 1),
-                lora_bufs=self._lora_buffers(),
-                lora_slot=jnp.int32(st.lora_slot),
-            )
+            st.last_logits = self._chunk_dispatch(
+                piece, start, chunk, st.slot_idx, start + c, st.lora_slot)
         except Exception as e:  # engine must survive a poison request
             logger.exception("chunk stream failed for %s", req.request_id)
             req.error = str(e)
@@ -3125,15 +3206,8 @@ class Engine:
         self._streams.remove(st)
         self._reserved_slots.discard(st.slot_idx)
         slot_idx = st.slot_idx
-        sp = req.sampling
         try:
-            first_token, lp_info = self._jit_sample_one(
-                st.last_logits, self._next_key(),
-                jnp.float32(sp.temperature), jnp.int32(sp.top_k),
-                jnp.float32(sp.top_p),
-                jnp.int32(_seed_i32(sp.seed)),
-                jnp.int32(n - 1), *map(jnp.asarray, _bias_arrays(sp)),
-            )
+            first_token, lp_info = self._sample_first(req, st.last_logits, n)
             if pipelined:
                 try:
                     first_token.copy_to_host_async()
@@ -3142,7 +3216,9 @@ class Engine:
                 self._activate_slot_pipelined(
                     slot_idx, req, st.lora_slot, n, first_token, lp_info)
                 return
-            if self._emit_first_token(req, int(first_token), lp_info):
+            with self._phase("prefill.wait"):
+                tok = int(first_token)
+            if self._emit_first_token(req, tok, lp_info):
                 if self.paged:  # finished at prefill; free the lane's blocks
                     self._paged_free_row(slot_idx)
                 return
@@ -3159,6 +3235,7 @@ class Engine:
             if self.paged and self.slots[slot_idx] is None:
                 self._paged_free_row(slot_idx)
 
+    @_in_phase("prefill.emit")
     def _register_slot(self, slot_idx: int, slot: _Slot) -> None:
         sp = slot.request.sampling
         self.slots[slot_idx] = slot
@@ -3241,13 +3318,10 @@ class Engine:
                 n_steps=len(req.prompt_tokens))
 
     def _note_padding(self, pad_tokens: int) -> None:
-        """Bucket/ring padding tokens prefilled and thrown away: counted
-        by the usage tracker's pool-waste counter AND the step profiler's
-        snapshot (both optional)."""
+        """Bucket/ring padding tokens prefilled and thrown away: the
+        usage tracker's pool-waste counter."""
         if self.usage is not None:
             self.usage.charge_padding(pad_tokens)
-        if self.profiler is not None:
-            self.profiler.note_padding(pad_tokens)
 
     def _kv_ledger_sync(self) -> None:
         """Recount the KV ledger's block states from allocator ground
@@ -3313,6 +3387,7 @@ class Engine:
             req.output_top_logprobs.append(
                 {int(top_i[j]): float(top_v[j]) for j in range(kk)})
 
+    @_in_phase("prefill.emit")
     def _emit_first_token(self, req: Request, tok: int,
                           lp_info=None) -> bool:
         """Record the prefill's first sampled token (TTFT, stream, counters);
@@ -3341,7 +3416,9 @@ class Engine:
         try:
             slot_idx, first_token, n, lora_slot, lp_info = (
                 self._prefill_common(req))
-            if self._emit_first_token(req, int(first_token), lp_info):
+            with self._phase("prefill.wait"):
+                tok = int(first_token)
+            if self._emit_first_token(req, tok, lp_info):
                 return  # finished at prefill; the finally frees its blocks
             self._register_slot(
                 slot_idx, _Slot(request=req, lora_slot=lora_slot, position=n)
@@ -3407,13 +3484,16 @@ class Engine:
                     self._pending_budget_zero.append(i)
         self._sync_tables()
 
-    def _do_decode_step(self) -> None:
+    @_in_phase("decode.plan", hand_over=True)
+    def _do_decode_step(self, ph) -> None:
+        """One sync-loop decode dispatch; ``ph`` is the open phase, renamed
+        as the step goes: plan, stage, wait, readback, emit, account."""
         n_steps = self._plan_steps()
         self._paged_ensure_decode(n_steps, pipelined=False)
+        ph.to("decode.stage")
         t0 = time.perf_counter()
         counts_arg, penalized = self._penalty_dispatch_args()
-        (step_tokens, step_valid, step_lps, step_top_v, step_top_i,
-         _, _, _, _, counts_out, self.cache) = self._jit_decode(
+        args = (
             self.params, self._lora_buffers(), self.cache,
             jnp.asarray(self._slot_tokens), jnp.asarray(self._slot_positions),
             jnp.asarray(self._slot_lora),
@@ -3428,16 +3508,21 @@ class Engine:
             jnp.asarray(self._slot_stop_ids),
             jnp.asarray(self._slot_stop_lens),
             jnp.asarray(self._sync_stop_hist()),
-            n_steps=n_steps, penalized=penalized,
         )
+        with self._enqueue("engine.decode.enqueue"):
+            (step_tokens, step_valid, step_lps, step_top_v, step_top_i,
+             _, _, _, _, counts_out, self.cache) = self._jit_decode(
+                *args, n_steps=n_steps, penalized=penalized)
         if penalized:
             self._dev_counts = counts_out
-        toks_np = np.asarray(step_tokens)  # [n_steps, B]
-        valid_np = np.asarray(step_valid)
-        lps_np = np.asarray(step_lps)
-        top_v_np = np.asarray(step_top_v)
-        top_i_np = np.asarray(step_top_i)
+        ph.to("decode.wait")
+        outs = jax.block_until_ready(
+            (step_tokens, step_valid, step_lps, step_top_v, step_top_i))
+        ph.to("decode.readback")
+        # [n_steps, B] each
+        toks_np, valid_np, lps_np, top_v_np, top_i_np = map(np.asarray, outs)
         step_s = time.perf_counter() - t0
+        ph.to("decode.emit")
         n_tokens = 0
         # Attribution: owners captured BEFORE the loop clears finished
         # slots (they were all resident for this dispatch's wall).
@@ -3481,6 +3566,7 @@ class Engine:
             req.stream_event.set()
             if not finished:
                 self._slot_positions[i] = slot.position
+        ph.to("decode.account")
         if self.usage is not None:
             self.usage.charge_decode(step_s, owners, tok_by_owner)
             self._usage_sync_kv()
@@ -3558,10 +3644,7 @@ class Engine:
                 did_work = True
             inflight = block
             if not did_work:
-                if self.profiler is not None:
-                    self.profiler.note_idle()
-                with self._work:
-                    self._work.wait(timeout=0.05)
+                self._wait_for_work()
         if inflight is not None:
             try:
                 self._process_block(inflight, current=None)
@@ -3606,41 +3689,43 @@ class Engine:
             if self.paged and slot_idx is not None and not registered:
                 self._paged_free_row(slot_idx)  # don't strand a slot-less row
 
-    def _dispatch_block(self) -> dict:
+    @_in_phase("decode.plan", hand_over=True)
+    def _dispatch_block(self, ph) -> dict:
         # _stops_active: same speculative exclusion as the sync loop —
         # only plain blocks evaluate the stop automata.
         if self._spec and not self._stops_active and any(
             s is not None and self._spec_ok[i] and self._slot_temp[i] <= 0.0
             for i, s in enumerate(self.slots)
         ):
-            return self._dispatch_spec_block()
+            return self._dispatch_spec_block(ph)
         n_steps = self._plan_steps()
         self._paged_ensure_decode(n_steps, pipelined=True)
+        ph.to("decode.stage")
         if self._pending_budget_zero:
             idxs = jnp.asarray(self._pending_budget_zero, jnp.int32)
             self._dev_remaining = self._dev_remaining.at[idxs].set(0)
             self._pending_budget_zero.clear()
         counts_arg, penalized = self._penalty_dispatch_args()
-        (toks, valid, lps, top_v, top_i, next_tokens, next_positions,
-         next_remaining, next_hist, counts_out, self.cache) = (
-            self._jit_decode(
-                self.params, self._lora_buffers(), self.cache,
-                self._dev_tokens, self._dev_positions,
-                jnp.asarray(self._slot_lora),
-                jnp.asarray(self._slot_temp), jnp.asarray(self._slot_topk),
-                jnp.asarray(self._slot_topp), self._next_key(),
-                self._dev_remaining, self._eos_for_device,
-                jnp.asarray(self._slot_seed),
-                jnp.asarray(self._slot_presence),
-                jnp.asarray(self._slot_frequency), counts_arg,
-                jnp.asarray(self._slot_bias_ids),
-                jnp.asarray(self._slot_bias_vals),
-                jnp.asarray(self._slot_stop_ids),
-                jnp.asarray(self._slot_stop_lens),
-                self._dev_stop_hist,
-                n_steps=n_steps, penalized=penalized,
-            )
+        args = (
+            self.params, self._lora_buffers(), self.cache,
+            self._dev_tokens, self._dev_positions,
+            jnp.asarray(self._slot_lora),
+            jnp.asarray(self._slot_temp), jnp.asarray(self._slot_topk),
+            jnp.asarray(self._slot_topp), self._next_key(),
+            self._dev_remaining, self._eos_for_device,
+            jnp.asarray(self._slot_seed),
+            jnp.asarray(self._slot_presence),
+            jnp.asarray(self._slot_frequency), counts_arg,
+            jnp.asarray(self._slot_bias_ids),
+            jnp.asarray(self._slot_bias_vals),
+            jnp.asarray(self._slot_stop_ids),
+            jnp.asarray(self._slot_stop_lens),
+            self._dev_stop_hist,
         )
+        with self._enqueue("engine.decode.enqueue"):
+            (toks, valid, lps, top_v, top_i, next_tokens, next_positions,
+             next_remaining, next_hist, counts_out, self.cache) = (
+                self._jit_decode(*args, n_steps=n_steps, penalized=penalized))
         if penalized:
             self._dev_counts = counts_out
         self._dev_tokens = next_tokens
@@ -3663,7 +3748,7 @@ class Engine:
             "t0": time.perf_counter(),
         }
 
-    def _dispatch_spec_block(self) -> dict:
+    def _dispatch_spec_block(self, ph) -> dict:
         """Pipelined speculative dispatch: same block contract as the plain
         path — flattened [T, B] outputs plus device carries — so
         ``_process_block`` consumes it unchanged.  The draft-extra triple
@@ -3676,13 +3761,12 @@ class Engine:
         self._paged_ensure_decode(
             n_cycles * (k + 1), pipelined=True,
             per_row_steps=self._spec_row_steps(n_cycles, k))
+        ph.to("decode.stage")
         if self._pending_budget_zero:
             idxs = jnp.asarray(self._pending_budget_zero, jnp.int32)
             self._dev_remaining = self._dev_remaining.at[idxs].set(0)
             self._pending_budget_zero.clear()
-        (toks, valid, lps, top_v, top_i, next_tokens, next_positions,
-         next_remaining, next_etok, next_epos, next_has,
-         self.cache, self.draft_cache) = self._jit_spec_block(
+        args = (
             self.params, self.draft_params, self._lora_buffers(),
             self.cache, self.draft_cache,
             self._dev_tokens, self._dev_positions, self._dev_remaining,
@@ -3692,7 +3776,12 @@ class Engine:
             jnp.asarray(self._slot_topp), self._next_key(),
             jnp.asarray(self._slot_lora), self._eos_for_device,
             jnp.asarray(self._slot_seed),
-            n_cycles=n_cycles, k_steps=k)
+        )
+        with self._enqueue("engine.decode.enqueue"):
+            (toks, valid, lps, top_v, top_i, next_tokens, next_positions,
+             next_remaining, next_etok, next_epos, next_has,
+             self.cache, self.draft_cache) = self._jit_spec_block(
+                *args, n_cycles=n_cycles, k_steps=k)
         self._dev_tokens = next_tokens
         self._dev_positions = next_positions
         self._dev_remaining = next_remaining
@@ -3717,12 +3806,16 @@ class Engine:
             "spec": True,
         }
 
-    def _process_block(self, blk: dict, current: dict | None) -> None:
-        toks_np = np.asarray(blk["toks"])  # overlaps with `current` computing
-        valid_np = np.asarray(blk["valid"])
-        lps_np = np.asarray(blk["lps"])
-        top_v_np = np.asarray(blk["top_v"])
-        top_i_np = np.asarray(blk["top_i"])
+    @_in_phase("decode.wait", hand_over=True)
+    def _process_block(self, blk: dict, current: dict | None, ph) -> None:
+        """Materialise and walk block ``blk`` while ``current`` computes:
+        wait (for the block in flight), readback, emit, account."""
+        outs = jax.block_until_ready(
+            (blk["toks"], blk["valid"], blk["lps"], blk["top_v"],
+             blk["top_i"]))
+        ph.to("decode.readback")
+        toks_np, valid_np, lps_np, top_v_np, top_i_np = map(np.asarray, outs)
+        ph.to("decode.emit")
         n_tokens = 0
         n_pending = 0  # prefill first-tokens materialized in this block
         # Attribution owners = every row resident at DISPATCH time (they
@@ -3748,17 +3841,20 @@ class Engine:
             pending = getattr(slot, "pending_first", None)
             if pending is not None:
                 pending_tok, pending_lp = pending
-                tok0 = int(np.asarray(pending_tok))
+                with self._phase("prefill.wait"):
+                    tok0 = int(np.asarray(pending_tok))
                 slot.pending_first = None
-                req.t_first_token = time.time()
-                req.output_tokens.append(tok0)
-                if pending_lp is not None:
-                    lp0, tv0, ti0 = pending_lp
-                    self._store_logprobs(req, np.asarray(lp0),
-                                         np.asarray(tv0), np.asarray(ti0))
+                with self._phase("prefill.emit"):
+                    req.t_first_token = time.time()
+                    req.output_tokens.append(tok0)
+                    if pending_lp is not None:
+                        lp0, tv0, ti0 = pending_lp
+                        self._store_logprobs(
+                            req, np.asarray(lp0), np.asarray(tv0),
+                            np.asarray(ti0))
+                    self._record_ttft(req)
                 n_tokens += 1
                 n_pending += 1
-                self._record_ttft(req)
                 if self._is_finished(req, tok0):
                     finished = True
             if not finished:
@@ -3794,6 +3890,7 @@ class Engine:
                     self._pending_budget_zero.append(i)
                 if current is not None and current["rows"][i] is slot:
                     current["rows"][i] = None  # its lane in-flight is garbage
+        ph.to("decode.account")
         step_s = time.perf_counter() - blk["t0"]
         if blk.get("spec"):
             # First tokens come from prefill, not speculation.

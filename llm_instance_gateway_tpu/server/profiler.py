@@ -28,12 +28,28 @@ run is the baseline every ROADMAP item-2 lever gets measured against.
 Per-dispatch records (wall, gap, batch occupancy, step count, net slot
 churn) ride ``/debug/profile`` for timeline views.
 
+The three buckets say nothing about what happens INSIDE a dispatch, where
+the device trace shows the chip idle for a fifth of the time.  So the same
+recorder keeps a second, finer account: a **phase stack** driven from the
+engine thread (``with profiler.phase("decode.stage") as ph: ...
+ph.to("decode.wait")``).  Every transition charges the time since the
+last one to the innermost open phase (self time: a child's time is not
+its parent's), so the phases tile the thread's wall by construction —
+``tpu:engine_phase_seconds_total{phase,on}``, the label set of
+``metrics_registry.ENGINE_PHASES``.  Each open phase is also a
+``jax.profiler.TraceAnnotation`` named ``engine.<phase>`` on the engine
+thread's line of the profiler's host plane, on the clock the device
+trace is taken on: ``tools/profile_report.py --xplane`` reads which phase
+the thread was in during each hole of the device's timeline.
+
 The recorder sits on the engine thread's hottest path, so it follows the
 usage tracker's budget discipline: ``note_dispatch`` is a few float ops
 + two histogram observes + a bounded-deque append per DISPATCH (not per
 token), behind the ``EngineConfig.step_profile`` off-switch that exists
 for the bench A/B (``step_profile_ratio`` <= 1.05), not for production
-use.
+use.  A phase transition is one clock read, one dict add and one tuple,
+plus the annotation (which tests an atomic flag when no trace is being
+taken); no lock.
 """
 
 from __future__ import annotations
@@ -43,6 +59,7 @@ import os
 import time
 
 from llm_instance_gateway_tpu.lockwitness import witness_lock
+from llm_instance_gateway_tpu.metrics_registry import ENGINE_PHASES
 from llm_instance_gateway_tpu.tracing import Histogram
 
 # Dispatch walls run from ~100µs (tiny CPU models) to hundreds of ms (a
@@ -58,6 +75,54 @@ GAP_IDLE = "idle"
 # more; JSON payloads stay bounded).
 SNAPSHOT_RECORDS = 256
 
+# phase -> "device" where the thread is blocked on the chip, else "host".
+PHASE_ON = dict(ENGINE_PHASES)
+PHASE_OTHER = "other"  # the bottom of the stack: what no phase covers
+_ANNOTATION = {name: "engine." + name for name in PHASE_ON}
+# The parts of a decode dispatch a /debug/profile record carries.
+_SPLIT = (("stage_s", "decode.stage"), ("wait_s", "decode.wait"),
+          ("readback_s", "decode.readback"), ("emit_s", "decode.emit"))
+
+
+class _NoScope:
+    """What ``phase()`` stands for where there is no profiler: one shared
+    object, nothing measured."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def to(self, name: str) -> None:
+        pass
+
+
+NO_PHASE = _NoScope()
+
+
+class _Scope:
+    """One open phase of the engine thread; ``to`` renames it in place, so
+    that a run of phases one after another is one ``with``."""
+
+    __slots__ = ("_prof", "_name")
+
+    def __init__(self, prof: "StepProfiler", name: str):
+        self._prof, self._name = prof, name
+
+    def __enter__(self):
+        self._prof._push(self._name)
+        return self
+
+    def __exit__(self, *exc):
+        self._prof._pop()
+        return False
+
+    def to(self, name: str) -> None:
+        self._prof._switch(name)
+
 
 class StepProfiler:
     """Bounded per-dispatch timeline recorder for one engine.
@@ -67,7 +132,12 @@ class StepProfiler:
     locking pattern).
     """
 
-    def __init__(self, capacity: int | None = None, clock=time.perf_counter):
+    def __init__(self, capacity: int | None = None, clock=time.perf_counter,
+                 annotate=None):
+        """``annotate(name)`` makes the context manager that puts an open
+        phase into the profiler's trace (the engine passes
+        ``jax.profiler.TraceAnnotation``); None keeps this module off
+        JAX."""
         if capacity is None:
             capacity = int(os.environ.get("LIG_PROFILE_CAPACITY", "2048"))
         self.capacity = max(1, capacity)
@@ -92,23 +162,94 @@ class StepProfiler:
         self.dispatch_seconds: dict[str, float] = {}
         self.dispatches: dict[str, int] = {}
         self.gap_seconds: dict[str, float] = {GAP_HOST: 0.0, GAP_IDLE: 0.0}
-        self.padding_tokens = 0
         self.wall_hist: dict[str, Histogram] = {}
         self.gap_hist: dict[str, Histogram] = {
             GAP_HOST: Histogram(DISPATCH_BUCKETS),
             GAP_IDLE: Histogram(DISPATCH_BUCKETS),
         }
+        # The phase stack.  Seconds per phase, every key there from the
+        # start so that the scrape thread's copy never sees the dict grow.
+        self._annotate = annotate
+        self._phase_s: dict[str, float] = dict.fromkeys(PHASE_ON, 0.0)
+        self._stack: list[str] = []
+        self._anns: list = []
+        # (innermost open phase, when it was last charged), swapped whole
+        # on every transition: the scrape thread adds the open stretch to
+        # its copy and sees a torn pair never.  None until the first phase.
+        self._open: tuple[str, float] | None = None
+        # _SPLIT's phase totals at the last decode record.
+        self._split_mark = (0.0,) * len(_SPLIT)
+
+    # -- the phase stack (engine thread) ------------------------------------
+    def phase(self, name: str) -> _Scope:
+        """``with profiler.phase(name) as ph:`` — ``name`` is the thread's
+        phase until the block ends or ``ph.to(other)`` renames it."""
+        return _Scope(self, name)
+
+    def annotation(self, name: str):
+        """A span in the trace alone, no counter (the jitted call inside
+        a ``*.stage`` phase: an enqueue that blocks shows there)."""
+        return NO_PHASE if self._annotate is None else self._annotate(name)
+
+    def _charge(self) -> float:
+        now = self._clock()
+        if self._open is None:
+            self._stack.append(PHASE_OTHER)
+        else:
+            name, since = self._open
+            self._phase_s[name] += now - since
+        return now
+
+    def _trace(self, name: str | None) -> None:
+        """Leave the innermost annotation (``name`` None) or enter one."""
+        if self._annotate is None:
+            return
+        if name is None:
+            self._anns.pop().__exit__(None, None, None)
+        else:
+            ann = self._annotate(name)
+            ann.__enter__()
+            self._anns.append(ann)
+
+    def _push(self, name: str) -> None:
+        label = _ANNOTATION[name]  # KeyError: not one of ENGINE_PHASES
+        now = self._charge()
+        self._stack.append(name)
+        self._open = (name, now)
+        self._trace(label)
+
+    def _switch(self, name: str) -> None:
+        label = _ANNOTATION[name]
+        now = self._charge()
+        self._stack[-1] = name
+        self._open = (name, now)
+        self._trace(None)
+        self._trace(label)
+
+    def _pop(self) -> None:
+        now = self._charge()
+        self._stack.pop()
+        self._open = (self._stack[-1], now)
+        self._trace(None)
+
+    def phase_seconds(self) -> dict[str, float]:
+        """Seconds per phase up to now, the open stretch included (any
+        thread).  The engine thread swaps ``_open`` after it has charged:
+        a copy taken between two reads of the same ``_open`` is whole."""
+        for _ in range(8):
+            mark = self._open
+            out = dict(self._phase_s)
+            if mark is self._open:
+                break
+        if mark is not None:
+            out[mark[0]] += max(0.0, self._clock() - mark[1])
+        return out
 
     # -- engine-thread mutators ---------------------------------------------
     def note_idle(self) -> None:
         """The loop is about to wait for work: the next inter-dispatch gap
         is queue idleness, not step-loop overhead."""
         self._idle_pending = True
-
-    def note_padding(self, pad_tokens: int) -> None:
-        if pad_tokens > 0:
-            with self._lock:
-                self.padding_tokens += pad_tokens
 
     def note_dispatch(self, phase: str, t0: float | None, wall_s: float,
                       active: int = 0, total_slots: int = 0,
@@ -120,11 +261,23 @@ class StepProfiler:
         wall was measured on a different clock (prefill): the wall is
         recorded but excluded from gap math, and subtracted from the next
         gap so prefill compute is never misattributed as host-sync.
+
+        A decode or spec record also carries what the phase stack charged
+        to ``decode.stage`` / ``.wait`` / ``.readback`` / ``.emit`` since
+        the last such record: in the sync loop exactly this dispatch's
+        (stage + wait + readback is its wall); in the pipelined loop the
+        stage is the next block's, staged before this one was awaited.
         """
         if wall_s < 0.0:
             wall_s = 0.0
         gap = 0.0
         gap_kind = ""
+        split = None
+        if phase != "prefill":
+            totals = tuple(self._phase_s[p] for _, p in _SPLIT)
+            split = tuple(round(t - m, 9)
+                          for t, m in zip(totals, self._split_mark))
+            self._split_mark = totals
         with self._lock:
             self.dispatch_seconds[phase] = (
                 self.dispatch_seconds.get(phase, 0.0) + wall_s)
@@ -149,7 +302,7 @@ class StepProfiler:
             self._prev_active = active
             self._ring.append((self._seq, phase, round(wall_s, 9),
                                round(gap, 9), gap_kind, active, total_slots,
-                               n_steps, churn))
+                               n_steps, churn, split))
 
     # -- export (any thread) -------------------------------------------------
     def attribution(self) -> dict:
@@ -162,6 +315,8 @@ class StepProfiler:
             idle = self.gap_seconds[GAP_IDLE]
             by_phase = dict(self.dispatch_seconds)
             n = sum(self.dispatches.values())
+        phases = self.phase_seconds()
+        thread_s = sum(phases.values())
         total = dispatch + host + idle
         if total > 0:
             # The largest bucket absorbs the rounding remainder so the
@@ -184,46 +339,58 @@ class StepProfiler:
             "dispatch_seconds_by_phase": {
                 k: round(v, 6) for k, v in sorted(by_phase.items())},
             "shares": shares,
+            # The finer account: the engine thread's whole wall by phase.
+            "thread_seconds": round(thread_s, 6),
+            "phases": {
+                name: {"on": PHASE_ON[name], "seconds": round(sec, 6),
+                       "share": round(sec / thread_s, 6) if thread_s else 0.0}
+                for name, sec in phases.items()},
         }
 
     def hist_state(self) -> dict:
         """The small copy-out ``Engine.metrics_snapshot()`` embeds — the
         ``tpu:dispatch_wall_seconds`` / ``tpu:dispatch_gap_seconds``
-        exposition source (server/metrics.py)."""
+        / ``tpu:engine_phase_seconds_total`` exposition source
+        (server/metrics.py)."""
         with self._lock:
-            return {
+            out = {
                 "wall": {p: h.state()
                          for p, h in sorted(self.wall_hist.items())},
                 "gap": {k: h.state()
                         for k, h in sorted(self.gap_hist.items())},
             }
+        out["phases"] = self.phase_seconds()
+        return out
 
     def snapshot(self) -> dict:
         """The full ``/debug/profile`` payload: attribution summary,
         histogram states, and the newest per-dispatch records."""
         with self._lock:
             records = list(self._ring)[-SNAPSHOT_RECORDS:]
-            padding = self.padding_tokens
         return {
             "capacity": self.capacity,
             "seq": self._seq,
-            "padding_tokens": padding,
             "attribution": self.attribution(),
             "hist": self.hist_state(),
             "records": [
                 {"seq": seq, "phase": phase, "wall_s": wall, "gap_s": gap,
                  **({"gap_kind": kind} if kind else {}),
                  "active": active, "slots": slots, "n_steps": n_steps,
-                 "slot_churn": churn}
+                 "slot_churn": churn,
+                 **(dict(zip((k for k, _ in _SPLIT), split))
+                    if split else {})}
                 for (seq, phase, wall, gap, kind, active, slots, n_steps,
-                     churn) in records],
+                     churn, split) in records],
         }
 
 
 def render_profile(hist: dict) -> list[str]:
     """Exposition lines for one ``StepProfiler.hist_state()`` payload
     (the server/metrics.py render seam)."""
-    from llm_instance_gateway_tpu.tracing import render_histogram
+    from llm_instance_gateway_tpu.tracing import (
+        escape_label,
+        render_histogram,
+    )
 
     lines: list[str] = []
     first = True
@@ -236,4 +403,11 @@ def render_profile(hist: dict) -> list[str]:
         lines += render_histogram("tpu:dispatch_gap_seconds", state,
                                   {"kind": kind}, type_line=first)
         first = False
+    phases = hist.get("phases")
+    if phases:
+        lines.append("# TYPE tpu:engine_phase_seconds_total counter")
+        lines += [
+            f'tpu:engine_phase_seconds_total{{phase="{escape_label(name)}",'
+            f'on="{escape_label(on)}"}} {phases.get(name, 0.0):.6f}'
+            for name, on in PHASE_ON.items()]
     return lines

@@ -73,6 +73,7 @@ def stop_suffix_hit(hist: jax.Array, stop_ids: jax.Array,
     return jnp.any(matched & (stop_lens > 0), axis=-1)
 
 
+@jax.named_scope("sample")
 def sample(
     logits: jax.Array,        # [B, V] f32
     key: jax.Array,
@@ -116,7 +117,8 @@ def sample(
     scaled = logits / safe_t
 
     # Top-k: mask everything below the k-th largest.  Fixed-shape sort.
-    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]  # [B, V]
+    with jax.named_scope("sample.topk_sort"):
+        sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]  # [B, V]
     k_idx = jnp.clip(jnp.where(top_k > 0, top_k, v) - 1, 0, v - 1)
     kth = jnp.take_along_axis(sorted_desc, k_idx[:, None], axis=-1)  # [B,1]
     masked = jnp.where(scaled >= kth, scaled, NEG_INF)

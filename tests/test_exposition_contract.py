@@ -147,6 +147,10 @@ def server_snapshot() -> dict:
     prof.note_dispatch("decode", 0.15, 0.1, active=2, total_slots=4)
     prof.note_idle()
     prof.note_dispatch("spec", 0.5, 0.1, active=2, total_slots=4)
+    # ... and one phase of each kind, so tpu:engine_phase_seconds_total
+    # renders its whole closed label set (zero-valued series included).
+    with prof.phase("decode.stage") as ph:
+        ph.to("decode.wait")
     return {
         "profile": prof.hist_state(),
         "model_name": HOSTILE,
@@ -268,6 +272,11 @@ def test_server_render_contract():
     gap_kinds = {s.labels["kind"]: s.value
                  for s in families["tpu:dispatch_gap_seconds_count"]}
     assert gap_kinds == {"host": 1, "idle": 1}
+    from llm_instance_gateway_tpu.metrics_registry import ENGINE_PHASES
+
+    phase_on = {(s.labels["phase"], s.labels["on"])
+                for s in families["tpu:engine_phase_seconds_total"]}
+    assert phase_on == set(ENGINE_PHASES)
     # Decode fast-path families (adaptive dispatch + stream lanes).
     assert families["tpu:stream_lanes"][0].value == 2
     assert families["tpu:stream_lanes_active"][0].value == 1

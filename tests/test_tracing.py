@@ -1,7 +1,10 @@
 """Tracing substrate unit tests: span ring, sampling, wire format,
 histogram exposition (llm_instance_gateway_tpu/tracing.py)."""
 
+import functools
 import json
+
+import pytest
 
 from llm_instance_gateway_tpu import tracing
 from llm_instance_gateway_tpu.utils import prom_parse
@@ -188,3 +191,128 @@ class TestHistogramRender:
         # The parser unescapes back to the original hostile value — the
         # exposition stayed well-formed.
         assert fams["f_seconds_bucket"][0].labels["model"] == hostile
+
+
+# ---------------------------------------------------------------------------
+# Names the device trace can show: every model block sits in a
+# jax.named_scope, so a compiled operation's name says which block it is.
+# ---------------------------------------------------------------------------
+
+DENSE_SCOPES = ("embed", "attn.qkv", "attn.rope", "attn.core", "attn.out",
+                "mlp", "lora", "lm_head")
+MOE_SCOPES = ("moe.route", "moe.dispatch", "moe.experts", "moe.fallback")
+STEP_SCOPES = ("sample", "sample.topk_sort", "logprobs", "stops")
+
+
+@functools.lru_cache(maxsize=None)
+def lowered_text(which: str) -> str:
+    """The lowered program text, with locations, of one model function at
+    a tiny preset (traced once per function for the whole module)."""
+    import jax
+    import jax.numpy as jnp
+
+    from llm_instance_gateway_tpu.models import lora as lora_lib
+    from llm_instance_gateway_tpu.models import paged, transformer
+    from llm_instance_gateway_tpu.models.configs import (
+        TINY_MOE_TEST,
+        TINY_TEST,
+    )
+
+    moe = which.endswith("_moe")
+    cfg = TINY_MOE_TEST if moe else TINY_TEST
+    params = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0),
+                                        dtype=jnp.float32))
+    bufs = None if moe else jax.eval_shape(
+        lambda: lora_lib.init_lora_buffers(cfg, dtype=jnp.float32))
+    b, s = 16, 8
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    if which.startswith("prefill"):
+        fn = functools.partial(transformer.prefill, cfg)
+        args = (params, i32((b, s)), i32((b, s)), bufs, i32((b,)))
+    elif which.startswith("decode_step_paged"):
+        cache = jax.eval_shape(lambda: paged.init_paged_cache(
+            cfg, b, max_len=32, n_blocks=80, block=8, dtype=jnp.float32))
+        fn = functools.partial(paged.decode_step_paged, cfg)
+        args = (params, cache, i32((b,)), i32((b,)), bufs, i32((b,)))
+    elif which.startswith("decode_step"):
+        cache = jax.eval_shape(lambda: transformer.init_decode_cache(
+            cfg, b, 32, dtype=jnp.float32))
+        fn = functools.partial(transformer.decode_step, cfg)
+        args = (params, cache, i32((b,)), i32((b,)), bufs, i32((b,)))
+    else:
+        raise KeyError(which)
+    return jax.jit(fn).lower(*args).as_text(debug_info=True)
+
+
+def has_scope(text: str, scope: str) -> bool:
+    """``scope`` is a whole component of some operation's name (inside a
+    scan body the name starts at the body: ``"attn.qkv/dot_general"``)."""
+    return f"/{scope}/" in text or f'"{scope}/' in text
+
+
+class TestNamedScopes:
+    @pytest.mark.parametrize("scope", DENSE_SCOPES + ("attn.kv_update",))
+    def test_decode_step_names_its_blocks(self, scope):
+        assert has_scope(lowered_text("decode_step"), scope)
+
+    @pytest.mark.parametrize("scope", DENSE_SCOPES)
+    def test_prefill_names_its_blocks(self, scope):
+        assert has_scope(lowered_text("prefill"), scope)
+
+    @pytest.mark.parametrize("scope", MOE_SCOPES)
+    @pytest.mark.parametrize("which", ["decode_step_moe", "prefill_moe"])
+    def test_moe_names_its_blocks(self, which, scope):
+        text = lowered_text(which)
+        assert has_scope(text, scope)
+        assert not has_scope(text, "mlp")  # the dense block is not traced
+
+    @pytest.mark.parametrize("scope", ("attn.kv_update", "attn.core",
+                                       "attn.out", "embed", "lm_head"))
+    def test_paged_decode_step_names_its_blocks(self, scope):
+        assert has_scope(lowered_text("decode_step_paged"), scope)
+
+    @pytest.mark.parametrize("scope", STEP_SCOPES)
+    def test_decode_block_names_sampling_and_stops(self, scope):
+        """The engine's own program: the sampler, the full-vocabulary
+        sort inside it, the logprobs and the stop automata."""
+        import jax
+        import jax.numpy as jnp
+
+        from llm_instance_gateway_tpu.server.engine import Engine
+        from llm_instance_gateway_tpu.server.sampling import (
+            STOP_LEN,
+            STOP_SEQS,
+        )
+
+        text = _decode_block_text(jax, jnp, Engine, STOP_LEN, STOP_SEQS)
+        assert has_scope(text, scope)
+        assert "jit(decode_block)" in text
+
+
+@functools.lru_cache(maxsize=None)
+def _decode_block_text(jax, jnp, Engine, stop_len, stop_seqs) -> str:
+    from llm_instance_gateway_tpu.models import transformer
+    from llm_instance_gateway_tpu.models.configs import TINY_TEST
+    from llm_instance_gateway_tpu.server.engine import _named
+
+    cfg, b = TINY_TEST, 2
+    params = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0),
+                                        dtype=jnp.float32))
+    cache = jax.eval_shape(lambda: transformer.init_decode_cache(
+        cfg, b, 32, dtype=jnp.float32))
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+    fn = jax.jit(
+        _named("decode_block", Engine._decode_impl, cfg,
+               transformer.decode_step),
+        static_argnames=("n_steps", "penalized"))
+    return fn.lower(
+        params, None, cache, i32((b,)), i32((b,)), i32((b,)),
+        f32((b,)), i32((b,)), f32((b,)), jax.random.PRNGKey(0),
+        i32((b,)), jnp.int32(-1), i32((b,)), f32((b,)), f32((b,)),
+        i32((b, 1)), i32((b, 4)), f32((b, 4)),
+        i32((b, stop_seqs, stop_len)), i32((b, stop_seqs)),
+        i32((b, stop_len)), n_steps=1, penalized=False,
+    ).as_text(debug_info=True)

@@ -9,8 +9,20 @@ thread's wall went:
   the tracked engine-thread timeline, so they sum to 100%);
 - per-phase dispatch walls (prefill vs decode vs spec) with counts and
   mean wall per dispatch;
+- the engine thread's whole wall by phase (admit, prefill.stage / .wait /
+  .emit, decode.plan / .stage / .wait / .readback / .emit / .account, idle,
+  other; ``tpu:engine_phase_seconds_total``), and per decode dispatch its
+  stage / wait / readback / emit parts;
 - a recent-dispatch summary from the record ring (mean batch occupancy,
   mean steps per dispatch, slot churn).
+
+With ``--xplane FILE.xplane.pb`` (a ``jax.profiler`` trace of a live
+replica) it reads the trace instead: the holes in the device's timeline (the
+union of the TPU plane's "XLA Ops") against the ``engine.<phase>``
+annotations the engine thread wrote into the same trace — for the ten
+longest holes and for all of them, which phase the thread was in — and the
+device time of the largest operations with the ``jax.named_scope`` each
+belongs to.
 
 This is the evidence layer for the ROADMAP item-2 decode levers: every
 "amortize the step loop" change must move the host-sync share DOWN on
@@ -20,11 +32,13 @@ Usage:
   python tools/profile_report.py http://localhost:8000/debug/profile
   python tools/profile_report.py PROFILE_BASELINE.json
   python tools/profile_report.py dump.json --json
+  python tools/profile_report.py --xplane trace/plugins/profile/*/*.xplane.pb
 """
 
 from __future__ import annotations
 
 import argparse
+import bisect
 import json
 import os
 import sys
@@ -118,6 +132,367 @@ def record_summary(profile: dict) -> dict:
     }
 
 
+def thread_phase_rows(profile: dict) -> list[dict]:
+    """The engine thread's wall by phase, largest first (empty for a
+    payload from before the phase stack)."""
+    phases = (profile.get("attribution") or {}).get("phases") or {}
+    rows = [{"phase": name, "on": rec.get("on", ""),
+             "seconds": round(float(rec.get("seconds", 0.0)), 6),
+             "share_pct": round(100.0 * float(rec.get("share", 0.0)), 3)}
+            for name, rec in phases.items()]
+    return sorted(rows, key=lambda r: -r["seconds"])
+
+
+def decode_split(profile: dict) -> dict:
+    """Mean parts of the recent decode dispatches, in ms."""
+    records = [r for r in profile.get("records") or []
+               if r.get("phase") == "decode" and "stage_s" in r]
+    if not records:
+        return {}
+    out = {"dispatches": len(records)}
+    for key in ("wall_s", "stage_s", "wait_s", "readback_s", "emit_s"):
+        out[key[:-2] + "_ms"] = round(
+            1e3 * sum(r[key] for r in records) / len(records), 4)
+    return out
+
+
+# -- a device trace against the engine thread's annotations -----------------
+
+ANNOTATION_PREFIX = "engine."
+NO_ANNOTATION = "other"  # the bottom of the phase stack is not annotated
+# The jax.named_scope names the model code uses (models/transformer.py,
+# models/paged.py, models/lora.py, server/sampling.py, server/engine.py).
+SCOPES = frozenset((
+    "embed", "attn.qkv", "attn.rope", "attn.kv_update", "attn.core",
+    "attn.out", "mlp", "moe.route", "moe.dispatch", "moe.experts",
+    "moe.fallback", "lora", "lm_head", "sample", "sample.topk_sort",
+    "logprobs", "stops", "kv.insert"))
+
+
+def union(intervals: list) -> list:
+    """Merged [start, end] pairs of possibly overlapping intervals."""
+    merged: list[list[float]] = []
+    for s, e in sorted(intervals):
+        if merged and s <= merged[-1][1]:
+            merged[-1][1] = max(merged[-1][1], e)
+        else:
+            merged.append([s, e])
+    return merged
+
+
+def self_time_segments(events: list) -> list:
+    """Properly nested ``(name, start, duration)`` spans of one thread ->
+    non-overlapping ``(start, end, name)`` pieces of each span's SELF time
+    (a child's time is taken out of its parent), sorted by start."""
+    out: list[tuple[float, float, str]] = []
+    stack: list[list] = []  # [name, end, covered up to]
+
+    def close(upto: float) -> None:
+        while stack and stack[-1][1] <= upto:
+            name, end, cursor = stack.pop()
+            if cursor < end:
+                out.append((cursor, end, name))
+            if stack:
+                stack[-1][2] = max(stack[-1][2], end)
+
+    for name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
+        close(start)
+        if stack and stack[-1][2] < start:
+            out.append((stack[-1][2], start, stack[-1][0]))
+            stack[-1][2] = start
+        stack.append([name, start + dur, start])
+    close(float("inf"))
+    return sorted(out)
+
+
+def gaps_by_phase(ops: list, annotations: list, top: int = 10) -> dict:
+    """Which phase the engine thread was in while the device sat idle.
+
+    ``ops`` are the device's operations as ``(start_ns, duration_ns)``,
+    ``annotations`` the engine thread's ``engine.*`` spans as ``(name,
+    start_ns, duration_ns)`` on the same clock.  A gap is a hole in the
+    union of ``ops``; each instant of it goes to the innermost annotation
+    open then (``other`` where none is).  Pure: lists in, a dict out."""
+    busy = union([(s, s + d) for s, d in ops if d > 0])
+    if not busy:
+        return {"error": "no device operation in the trace"}
+    segs = self_time_segments(
+        [(n[len(ANNOTATION_PREFIX):] if n.startswith(ANNOTATION_PREFIX)
+          else n, s, d) for n, s, d in annotations])
+    starts = [s for s, _, _ in segs]
+
+    def split(g0: float, g1: float) -> dict[str, float]:
+        parts: dict[str, float] = {}
+        covered = 0.0
+        i = max(0, bisect.bisect_right(starts, g0) - 1)
+        while i < len(segs) and segs[i][0] < g1:
+            s, e, name = segs[i]
+            o = min(e, g1) - max(s, g0)
+            if o > 0:
+                parts[name] = parts.get(name, 0.0) + o
+                covered += o
+            i += 1
+        if g1 - g0 - covered > 0:
+            parts[NO_ANNOTATION] = (parts.get(NO_ANNOTATION, 0.0)
+                                    + g1 - g0 - covered)
+        return parts
+
+    gaps = [(s1 - e0, e0, s1) for (_, e0), (s1, _) in zip(busy, busy[1:])]
+    total: dict[str, float] = {}
+    for _, g0, g1 in gaps:
+        for name, ns in split(g0, g1).items():
+            total[name] = total.get(name, 0.0) + ns
+    window = busy[-1][1] - busy[0][0]
+    idle = sum(g for g, _, _ in gaps)
+    longest = sorted(gaps, reverse=True)[:top]
+    return {
+        "window_s": window / 1e9,
+        "idle_s": idle / 1e9,
+        "idle_pct": 100.0 * idle / window if window else 0.0,
+        "n_gaps": len(gaps),
+        "total_ms": {n: v / 1e6 for n, v in sorted(
+            total.items(), key=lambda kv: -kv[1])},
+        "longest": [
+            {"at_s": (g0 - busy[0][0]) / 1e9, "gap_ms": g / 1e6,
+             "phases_ms": {n: v / 1e6 for n, v in sorted(
+                 split(g0, g1).items(), key=lambda kv: -kv[1])}}
+            for g, g0, g1 in longest],
+    }
+
+
+def op_scope(stat_values: list) -> tuple[str, str, str]:
+    """(program, scope, where) of one device operation from its string
+    stats: XLA keeps ``jit(<program>)/.../<scope>/.../<primitive>`` as the
+    operation's name in the source program.  ``scope`` is the chain of
+    ``jax.named_scope`` names on that path, outermost first
+    (``attn.qkv/lora``, ``moe.fallback/moe.experts``), ``where`` the path's
+    last three components
+    (what to go by where no scope is: the layer scan's own slicing has
+    none).  All empty where no stat has such a path (a copy the compiler
+    put in)."""
+    for v in stat_values:
+        if not isinstance(v, str) or not v.startswith("jit("):
+            continue
+        parts = v.rstrip(":").split("/")
+        found = [part for part in parts if part in SCOPES]
+        return (parts[0][4:].split(")", 1)[0], "/".join(found),
+                "/".join(parts[1:][-3:]))
+    return "", "", ""
+
+
+def ops_by_scope(op_events: list, top: int = 25) -> dict:
+    """Device time by operation and by scope.  ``op_events`` are ``(name,
+    duration_ns, [stat values])`` of the "XLA Ops" line.  Operations nest
+    (a ``while`` contains its body), so sums pass the busy time; read
+    rows, not the total."""
+    by_op: dict[tuple[str, str, str, str], float] = {}
+    by_scope: dict[str, float] = {}
+    for name, dur, stats in op_events:
+        key = (name, *op_scope(stats))
+        by_op[key] = by_op.get(key, 0.0) + dur
+        scope = key[2] or "(none)"
+        by_scope[scope] = by_scope.get(scope, 0.0) + dur
+    return {
+        "ops": [{"op": n, "program": p, "scope": sc, "where": w,
+                 "device_ms": d / 1e6}
+                for (n, p, sc, w), d in sorted(
+                    by_op.items(), key=lambda kv: -kv[1])[:top]],
+        "scopes_ms": {sc: d / 1e6 for sc, d in sorted(
+            by_scope.items(), key=lambda kv: -kv[1])},
+    }
+
+
+# -- reading an .xplane.pb ---------------------------------------------------
+# The file is a serialized XSpace (tsl/profiler/protobuf/xplane.proto).
+# jax.profiler.ProfileData reads events but not the per-operation metadata
+# where XLA keeps an operation's source name (the "tf_op" stat, e.g.
+# jit(decode_block)/while/body/attn.qkv/dot_general), so the few fields
+# needed are read from the wire format here.  Field numbers, from the .proto:
+# XSpace.planes=1; XPlane.name=2 .lines=3 .event_metadata=4 .stat_metadata=5;
+# XLine.name=2 .timestamp_ns=3 .events=4; XEvent.metadata_id=1 .offset_ps=2
+# .duration_ps=3; XEventMetadata.name=2 .stats=5; XStat.metadata_id=1
+# .str_value=5 .ref_value=7; XStatMetadata.name=2; a map entry is key=1,
+# value=2.
+
+
+def _varint(buf, i: int) -> tuple[int, int]:
+    val = shift = 0
+    while True:
+        byte = buf[i]
+        i += 1
+        val |= (byte & 0x7F) << shift
+        if byte < 0x80:
+            return val, i
+        shift += 7
+
+
+def _fields(buf):
+    """(field number, value) pairs of one protobuf message: an int for a
+    varint or fixed field, a memoryview for a length-delimited one."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        field, wire = key >> 3, key & 7
+        if wire == 0:
+            val, i = _varint(buf, i)
+            yield field, val
+        elif wire == 2:
+            size, i = _varint(buf, i)
+            yield field, buf[i:i + size]
+            i += size
+        elif wire in (1, 5):
+            width = 8 if wire == 1 else 4
+            yield field, int.from_bytes(buf[i:i + width], "little")
+            i += width
+        else:
+            raise ValueError(f"wire type {wire} at byte {i}: not an XSpace")
+
+
+def _text(view) -> str:
+    return bytes(view).decode("utf-8", "replace")
+
+
+def _map_entry(view) -> tuple[int, memoryview]:
+    key, value = 0, memoryview(b"")
+    for field, val in _fields(view):
+        if field == 1:
+            key = val
+        elif field == 2:
+            value = val
+    return key, value
+
+
+def _plane(view) -> dict:
+    """One XPlane: its name, its lines as ``(name, [(metadata id, start_ns,
+    duration_ns)])`` and, per event-metadata id, the operation's name and
+    its "tf_op" (source name) stat."""
+    name, lines, ev_meta, stat_names = "", [], {}, {}
+    for field, val in _fields(view):
+        if field == 2:
+            name = _text(val)
+        elif field == 3:
+            lines.append(val)
+        elif field == 4:
+            key, em = _map_entry(val)
+            ev_meta[key] = em
+        elif field == 5:
+            key, sm = _map_entry(val)
+            stat_names[key] = next(
+                (_text(v) for f, v in _fields(sm) if f == 2), "")
+    tf_op = {k for k, n in stat_names.items() if n == "tf_op"}
+    meta: dict[int, tuple[str, str]] = {}
+    for key, em in ev_meta.items():
+        op_name = source = ""
+        for field, val in _fields(em):
+            if field == 2:
+                op_name = _text(val)
+            elif field == 5:
+                stat = dict(_fields(val))
+                if stat.get(1) in tf_op:
+                    ref = stat.get(7)  # a string shared through the stats
+                    source = (_text(stat[5]) if 5 in stat
+                              else stat_names.get(ref, ""))
+        meta[key] = (op_name, source)
+    out_lines = []
+    for ln in lines:
+        lname, t0_ns, events = "", 0, []
+        for field, val in _fields(ln):
+            if field == 2:
+                lname = _text(val)
+            elif field == 3:
+                t0_ns = val
+            elif field == 4:
+                ev = dict(_fields(val))
+                events.append((ev.get(1, 0),
+                               t0_ns + ev.get(2, 0) / 1e3,
+                               ev.get(3, 0) / 1e3))
+        out_lines.append((lname, events))
+    return {"name": name, "lines": out_lines, "meta": meta}
+
+
+def read_xplane(path: str) -> dict:
+    """The lists ``gaps_by_phase`` and ``ops_by_scope`` take, from a
+    ``jax.profiler`` trace: the first TPU plane's operations and programs,
+    and the host thread that wrote the most ``engine.*`` annotations."""
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    planes = [_plane(v) for field, v in _fields(space) if field == 1]
+    device = sorted((p for p in planes
+                     if p["name"].startswith("/device:TPU:")),
+                    key=lambda p: p["name"])
+    ops: list = []
+    op_events: list = []
+    modules: list = []
+    if device:
+        meta = device[0]["meta"]
+        for lname, events in device[0]["lines"]:
+            if lname == "XLA Ops":
+                for mid, start, dur in events:
+                    op_name, source = meta.get(mid, ("", ""))
+                    ops.append((start, dur))
+                    op_events.append((op_name.split(" = ")[0].lstrip("%"),
+                                      dur, [source]))
+            elif lname == "XLA Modules":
+                modules = sorted({meta.get(mid, ("", ""))[0]
+                                  for mid, _, _ in events})
+    annotations: list = []
+    thread = ""
+    for p in planes:
+        if p["name"] != "/host:CPU":
+            continue
+        for lname, events in p["lines"]:
+            evs = [(p["meta"].get(mid, ("", ""))[0], start, dur)
+                   for mid, start, dur in events]
+            evs = [e for e in evs if e[0].startswith(ANNOTATION_PREFIX)]
+            if len(evs) > len(annotations):
+                annotations, thread = evs, lname
+    return {"plane": device[0]["name"] if device else "", "ops": ops,
+            "op_events": op_events, "modules": modules,
+            "annotations": annotations, "thread": thread}
+
+
+def render_xplane(trace: dict, top: int = 10) -> str:
+    table = gaps_by_phase(trace["ops"], trace["annotations"], top)
+    if "error" in table:
+        return "error: " + table["error"]
+    counts: dict[str, int] = {}
+    for name, _, _ in trace["annotations"]:
+        counts[name] = counts.get(name, 0) + 1
+    out = [
+        f"DEVICE IDLE GAP -> ENGINE PHASE ({trace['plane']}; engine thread "
+        f"{trace['thread']!r}, {len(trace['annotations'])} annotations)",
+        f"window {table['window_s']:.3f}s, idle {table['idle_s']:.3f}s "
+        f"({table['idle_pct']:.1f}%) in {table['n_gaps']} gaps",
+        "",
+        "All gaps, by the phase the engine thread was in:",
+        _table([{"phase": n, "idle_ms": round(v, 3),
+                 "share_pct": round(100.0 * v / (1e3 * table["idle_s"]), 1)
+                 if table["idle_s"] else 0.0}
+                for n, v in table["total_ms"].items()],
+               ("phase", "idle_ms", "share_pct")),
+        "",
+        f"The {len(table['longest'])} longest gaps:",
+        _table([{"at_s": round(g["at_s"], 4), "gap_ms": round(g["gap_ms"], 3),
+                 "phases": ", ".join(f"{n} {v:.2f}"
+                                     for n, v in g["phases_ms"].items())}
+                for g in table["longest"]], ("at_s", "gap_ms", "phases")),
+        "",
+        "Annotations on the engine thread: " + ", ".join(
+            f"{n} x{c}" for n, c in sorted(counts.items())),
+        "Programs on the device: " + ", ".join(trace["modules"]),
+    ]
+    scoped = ops_by_scope(trace["op_events"])
+    out += ["", "Device time by operation (operations nest):",
+            _table([{"op": r["op"], "program": r["program"] or "-",
+                     "scope": r["scope"] or "-", "where": r["where"] or "-",
+                     "device_ms": round(r["device_ms"], 3)}
+                    for r in scoped["ops"]],
+                   ("op", "program", "scope", "where", "device_ms")),
+            "", "Device time by scope (operations nest): " + ", ".join(
+                f"{sc} {ms:.1f}" for sc, ms in scoped["scopes_ms"].items())]
+    return "\n".join(out)
+
+
 def _table(rows: list[dict], headers: tuple) -> str:
     if not rows:
         return "(no samples)"
@@ -169,6 +544,16 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
         _table(phase_rows(profile), ("phase", "dispatches", "wall_s",
                                      "mean_ms")),
     ]
+    thread = thread_phase_rows(profile)
+    if thread:
+        out += ["", "Engine thread by phase (self time; tiles "
+                f"{profile['attribution'].get('thread_seconds', 0)}s of "
+                "the thread's wall):",
+                _table(thread, ("phase", "on", "seconds", "share_pct"))]
+    split = decode_split(profile)
+    if split:
+        out += ["", "Recent decode dispatch, mean parts: " + ", ".join(
+            f"{k}={v}" for k, v in split.items())]
     delta = host_sync_delta(profile, previous)
     if delta:
         out += ["", "Host-sync share vs previous baseline: "
@@ -179,9 +564,6 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
     if summary:
         out += ["", "Recent decode dispatches: " + ", ".join(
             f"{k}={v}" for k, v in summary.items())]
-    padding = profile.get("padding_tokens")
-    if padding:
-        out += ["", f"Prefill padding tokens (cumulative): {padding}"]
     return "\n".join(out)
 
 
@@ -189,8 +571,13 @@ def main(argv: list[str] | None = None) -> int:
     parser = argparse.ArgumentParser(
         description="dispatch / host-sync / idle attribution table from a "
                     "/debug/profile payload")
-    parser.add_argument("source",
+    parser.add_argument("source", nargs="?",
                         help="file path, http(s) URL, or - for stdin")
+    parser.add_argument("--xplane", metavar="FILE.xplane.pb",
+                        help="read a jax.profiler trace instead: device "
+                             "idle gaps against the engine thread's "
+                             "engine.<phase> annotations, and device time "
+                             "by named scope")
     parser.add_argument("--pod",
                         help="which pod's snapshot to render when the "
                              "source is a black-box dump holding several")
@@ -203,6 +590,18 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument("--json", action="store_true",
                         help="emit the attribution + phase rows as JSON")
     args = parser.parse_args(argv)
+    if args.xplane:
+        trace = read_xplane(args.xplane)
+        if args.json:
+            print(json.dumps({
+                "gaps": gaps_by_phase(trace["ops"], trace["annotations"]),
+                "ops": ops_by_scope(trace["op_events"]),
+                "modules": trace["modules"], "thread": trace["thread"]}))
+        else:
+            print(render_xplane(trace))
+        return 0
+    if not args.source:
+        parser.error("a /debug/profile source or --xplane is needed")
     try:
         doc = load(args.source)
         profile = extract_profile(doc, pod=args.pod)
@@ -216,6 +615,8 @@ def main(argv: list[str] | None = None) -> int:
         print(json.dumps({
             "attribution": attribution_rows(profile),
             "phases": phase_rows(profile),
+            "thread_phases": thread_phase_rows(profile),
+            "decode_split": decode_split(profile),
             "summary": record_summary(profile),
             **({"host_sync_delta": host_sync_delta(profile, previous)}
                if previous else {}),
