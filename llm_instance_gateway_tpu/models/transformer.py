@@ -521,49 +521,105 @@ def prefill(
 # ---------------------------------------------------------------------------
 
 
-@jax.named_scope("attn.core")
-def _decode_attend(cfg: ModelConfig, attention_fn, q, k_cache, v_cache,
-                   k_scale, v_scale, lengths, dtype):
-    """One layer's cached attention over the lanes just written: which
-    implementation reads them.  ``k_scale`` None is a bf16 cache."""
-    if k_scale is None:
-        if attention_fn is not None:
-            return attention_fn(q, k_cache, v_cache, lengths)
-        if cfg.use_pallas_decode:
-            from llm_instance_gateway_tpu.ops.pallas_decode_attention import (
-                decode_attention as pallas_decode,
-            )
+# The cached programs (decode_step, extend_step, prefill_with_cache) keep
+# the stacked cache in ONE buffer for the whole step: it is the layer
+# loop's CARRY, each layer writes its new rows into it with one scatter at
+# [layer, row, position], and attention reads that layer out of it.  A
+# cache scanned as xs/ys cannot be the donated buffer: XLA then copies the
+# whole cache twice a step and every layer's slice out and back in (device
+# trace, PR 24: 25-31 ms of a 61 ms step; the structure is held by
+# tests/test_models.py::test_layer_scan_carries_the_cache).
 
-            return pallas_decode(q, k_cache, v_cache, lengths)
-        return decode_attention(q, k_cache, v_cache, lengths)
-    if getattr(attention_fn, "quant_aware", False):
+
+def _kv_carry(cache: Params) -> tuple:
+    """The stacked cache arrays that ride the layer loop: (k, v)
+    [L, B, S, K, hd], plus (k_scale, v_scale) [L, B, S, K] of an int8
+    cache."""
+    kv = (cache["k"], cache["v"])
+    if "k_scale" in cache:
+        kv += (cache["k_scale"], cache["v_scale"])
+    return kv
+
+
+def _cache_of(kv: tuple, length: jax.Array) -> Params:
+    return dict(zip(("k", "v", "k_scale", "v_scale"), kv), length=length)
+
+
+@jax.named_scope("attn.kv_update")
+def _write_kv(kv: tuple, at: tuple, k: jax.Array, v: jax.Array) -> tuple:
+    """Write one layer's new rows into the stacked cache where it lies.
+    ``at`` = (layer, rows, positions), the scatter address of ``k``/``v``'s
+    leading dims; an address out of bounds drops its update (how an
+    inactive row writes nothing).  An int8 cache quantizes here."""
+    new = (k, v)
+    if len(kv) == 4:
+        (kq, ks), (vq, vs) = _kv_quantize(k), _kv_quantize(v)
+        new = (kq, vq, ks, vs)
+    return tuple(x.at[at].set(n) for x, n in zip(kv, new))
+
+
+def _layer_arrays(kv: tuple, layer) -> tuple:
+    """One layer of every array of the carry: slices XLA may materialise,
+    for the attention paths that must be right and need not be fast.  The
+    decode kernel takes the carry itself (``_decode_attend``)."""
+    return tuple(
+        jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False) for x in kv)
+
+
+def _layer_view(kv: tuple, layer, dtype) -> tuple[jax.Array, jax.Array]:
+    """One layer's (k, v) [B, S, K, hd] out of the carry, dequantized."""
+    k, v, *scales = _layer_arrays(kv, layer)
+    if scales:
+        return (_kv_dequantize(k, scales[0], dtype),
+                _kv_dequantize(v, scales[1], dtype))
+    return k, v
+
+
+def _scan_cached_layers(params: Params, cache: Params,
+                        lora_bufs: Params | None, h: jax.Array, layer_fn):
+    """The cached programs' layer loop.  xs: the stacked layer params, the
+    LoRA stack and the layer index; carry: the activations and the stacked
+    cache.  ``layer_fn(h, kv, layer, lp, layer_lora) -> (h, kv)``."""
+    per_layer_lora = None
+    if lora_bufs is not None:
+        per_layer_lora, _ = lora_lib.stack_for_scan(lora_bufs)
+
+    def body(carry, xs):
+        lp, ll, layer = xs
+        layer_lora = None if ll is None else {**ll, "scale": lora_bufs["scale"]}
+        return layer_fn(*carry, layer, lp, layer_lora), None
+
+    xs = (params["layers"], per_layer_lora, jnp.arange(cache["k"].shape[0]))
+    (h, kv), _ = jax.lax.scan(body, (h, _kv_carry(cache)), xs)
+    return h, kv
+
+
+@jax.named_scope("attn.core")
+def _decode_attend(cfg: ModelConfig, attention_fn, q, kv, layer, lengths):
+    """One layer's cached attention over the lanes just written: which
+    implementation reads them.  The kernel reads ``layer`` of the stacked
+    carry in place; everything else gets that layer's view."""
+    quant = len(kv) == 4
+    if attention_fn is None and cfg.use_pallas_decode:
+        from llm_instance_gateway_tpu.ops import pallas_decode_attention as pda
+
+        # The int8-aware kernel dequantizes in VMEM at the MXU feed, so HBM
+        # streams half the bytes of the bf16 kernel.  (Both fall back to
+        # XLA by themselves off-TPU and on unsupported shapes.)
+        attend = pda.decode_attention_quant if quant else pda.decode_attention
+        return attend(q, *kv, lengths, layer=layer)
+    if quant and getattr(attention_fn, "quant_aware", False):
         # Quant-aware override (sharded_attention.make_cached_decode_quant):
         # raw int8 + scales go in; each shard's kernel dequantizes in VMEM,
         # so HBM streams int8 even under the mesh — kernel win and
         # bandwidth win together.
-        return attention_fn(q, k_cache, v_cache, k_scale, v_scale, lengths)
-    if attention_fn is not None:
-        # Opaque override without quant awareness: hand it the dequantized
-        # view.  NOTE — such an override cannot fuse the dequant into its
-        # reads and materializes a full bf16 cache; the engine only
-        # installs quant_aware wrappers on quantized lanes for exactly that
-        # reason.
-        return attention_fn(
-            q, _kv_dequantize(k_cache, k_scale, dtype),
-            _kv_dequantize(v_cache, v_scale, dtype), lengths)
-    if cfg.use_pallas_decode:
-        from llm_instance_gateway_tpu.ops.pallas_decode_attention import (
-            decode_attention_quant,
-        )
-
-        # int8-aware kernel: dequantizes in VMEM at the MXU feed, so HBM
-        # streams half the bytes of the bf16 kernel (auto XLA fallback
-        # off-TPU / unsupported shapes).
-        return decode_attention_quant(
-            q, k_cache, v_cache, k_scale, v_scale, lengths)
-    return decode_attention(
-        q, _kv_dequantize(k_cache, k_scale, dtype),
-        _kv_dequantize(v_cache, v_scale, dtype), lengths)
+        return attention_fn(q, *_layer_arrays(kv, layer), lengths)
+    # XLA attention, or an override without quant awareness: the (dequantized)
+    # view.  NOTE — an opaque override cannot fuse the dequant into its reads
+    # and materializes a full bf16 cache; the engine only installs
+    # quant_aware wrappers on quantized lanes for exactly that reason.
+    k_cache, v_cache = _layer_view(kv, layer, q.dtype)
+    return (attention_fn or decode_attention)(q, k_cache, v_cache, lengths)
 
 
 def decode_step(
@@ -592,13 +648,10 @@ def decode_step(
     under a GSPMD mesh.
     """
     b = tokens.shape[0]
+    hd = cfg.resolved_head_dim
     if slot_ids is None:
         slot_ids = jnp.full((b,), -1, jnp.int32)
     h = _embed(cfg, params, tokens)  # [B, D]
-
-    per_layer_lora = None
-    if lora_bufs is not None:
-        per_layer_lora, _ = lora_lib.stack_for_scan(lora_bufs)
 
     lengths = positions + 1
     batch_idx = jnp.arange(b)
@@ -607,52 +660,25 @@ def decode_step(
     # out of bounds, so inactive rows' updates are dropped whole.
     write_pos = (positions if active is None
                  else jnp.where(active, positions, s_max))
-    quant = "k_scale" in cache
 
-    def layer_fn(h, xs):
-        if quant:
-            lp, ll, k_cache, v_cache, k_scale, v_scale = xs
-        else:
-            lp, ll, k_cache, v_cache = xs
-            k_scale = v_scale = None
-        layer_lora = None if ll is None else {**ll, "scale": lora_bufs["scale"]}
+    def layer_fn(h, kv, layer, lp, layer_lora):
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        hd = cfg.resolved_head_dim
         q = _attn_proj(lp, "q", hn, layer_lora, slot_ids).reshape(b, cfg.n_heads, hd)
         k = _attn_proj(lp, "k", hn, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
         v = _attn_proj(lp, "v", hn, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
         q = apply_rope(q[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
         k = apply_rope(k[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
-        with jax.named_scope("attn.kv_update"):
-            if quant:
-                kq, ks = _kv_quantize(k)
-                vq, vs = _kv_quantize(v)
-                k_cache = k_cache.at[batch_idx, write_pos].set(kq)
-                v_cache = v_cache.at[batch_idx, write_pos].set(vq)
-                k_scale = k_scale.at[batch_idx, write_pos].set(ks)
-                v_scale = v_scale.at[batch_idx, write_pos].set(vs)
-                carry_out = (k_cache, v_cache, k_scale, v_scale)
-            else:
-                k_cache = k_cache.at[batch_idx, write_pos].set(k)
-                v_cache = v_cache.at[batch_idx, write_pos].set(v)
-                carry_out = (k_cache, v_cache)
-        attn = _decode_attend(cfg, attention_fn, q, k_cache, v_cache,
-                              k_scale, v_scale, lengths, h.dtype)
+        kv = _write_kv(kv, (layer, batch_idx, write_pos), k, v)
+        attn = _decode_attend(cfg, attention_fn, q, kv, layer, lengths)
         h = h + _attn_out(lp, attn.reshape(b, -1), layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
-        return h, carry_out
+        return h, kv
 
-    xs = (params["layers"], per_layer_lora, cache["k"], cache["v"])
-    if quant:
-        xs = xs + (cache["k_scale"], cache["v_scale"])
-    h, carry = jax.lax.scan(layer_fn, h, xs)
+    h, kv = _scan_cached_layers(params, cache, lora_bufs, h, layer_fn)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     logits = _lm_head(cfg, params, h)
-    new_cache = {"k": carry[0], "v": carry[1], "length": lengths}
-    if quant:
-        new_cache["k_scale"], new_cache["v_scale"] = carry[2], carry[3]
-    return logits, new_cache
+    return logits, _cache_of(kv, lengths)
 
 
 def extend_step(
@@ -685,22 +711,11 @@ def extend_step(
         slot_ids = jnp.full((b,), -1, jnp.int32)
     h = _embed(cfg, params, tokens)  # [B, C, D]
 
-    per_layer_lora = None
-    if lora_bufs is not None:
-        per_layer_lora, _ = lora_lib.stack_for_scan(lora_bufs)
-
     batch_idx = jnp.arange(b)[:, None]  # [B, 1] broadcast over C
     write_pos = (positions if active is None
                  else jnp.where(active[:, None], positions, s_max))
-    quant = "k_scale" in cache
 
-    def layer_fn(h, xs):
-        if quant:
-            lp, ll, k_cache, v_cache, k_scale, v_scale = xs
-        else:
-            lp, ll, k_cache, v_cache = xs
-            k_scale = v_scale = None
-        layer_lora = None if ll is None else {**ll, "scale": lora_bufs["scale"]}
+    def layer_fn(h, kv, layer, lp, layer_lora):
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         q = _attn_proj(lp, "q", hn, layer_lora, slot_ids).reshape(
             b, c, cfg.n_heads, hd)
@@ -710,25 +725,9 @@ def extend_step(
             b, c, cfg.n_kv_heads, hd)
         q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
         k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
-        with jax.named_scope("attn.kv_update"):
-            if quant:
-                kq, ks = _kv_quantize(k)
-                vq, vs = _kv_quantize(v)
-                k_cache = k_cache.at[batch_idx, write_pos].set(kq)
-                v_cache = v_cache.at[batch_idx, write_pos].set(vq)
-                k_scale = k_scale.at[batch_idx, write_pos].set(ks)
-                v_scale = v_scale.at[batch_idx, write_pos].set(vs)
-                carry_out = (k_cache, v_cache, k_scale, v_scale)
-            else:
-                k_cache = k_cache.at[batch_idx, write_pos].set(k)
-                v_cache = v_cache.at[batch_idx, write_pos].set(v)
-                carry_out = (k_cache, v_cache)
+        kv = _write_kv(kv, (layer, batch_idx, write_pos), k, v)
         with jax.named_scope("attn.core"):
-            if quant:
-                k_read = _kv_dequantize(k_cache, k_scale, h.dtype)
-                v_read = _kv_dequantize(v_cache, v_scale, h.dtype)
-            else:
-                k_read, v_read = k_cache, v_cache
+            k_read, v_read = _layer_view(kv, layer, h.dtype)
             # [B,C,K,G,hd] x [B,S,K,hd] -> [B,K,G,C,S]; mask j <= position_i.
             qg = q.reshape(b, c, cfg.n_kv_heads, cfg.q_per_kv, hd)
             logits = jnp.einsum(
@@ -743,19 +742,12 @@ def extend_step(
         h = h + _attn_out(lp, attn, layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
-        return h, carry_out
+        return h, kv
 
-    xs = (params["layers"], per_layer_lora, cache["k"], cache["v"])
-    if quant:
-        xs = xs + (cache["k_scale"], cache["v_scale"])
-    h, carry = jax.lax.scan(layer_fn, h, xs)
+    h, kv = _scan_cached_layers(params, cache, lora_bufs, h, layer_fn)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     logits = _lm_head(cfg, params, h)
-    new_cache = {"k": carry[0], "v": carry[1],
-                 "length": positions[:, -1] + 1}
-    if quant:
-        new_cache["k_scale"], new_cache["v_scale"] = carry[2], carry[3]
-    return logits, new_cache
+    return logits, _cache_of(kv, positions[:, -1] + 1)
 
 
 def prefill_with_cache(
@@ -788,24 +780,12 @@ def prefill_with_cache(
     """
     c = tokens.shape[0]
     hd = cfg.resolved_head_dim
-    s_max = cache["k"].shape[2]
     slot_ids = jnp.full((1,), lora_slot, jnp.int32)
-
-    per_layer_lora = None
-    if lora_bufs is not None:
-        per_layer_lora, _ = lora_lib.stack_for_scan(lora_bufs)
-
     h = _embed(cfg, params, tokens)[None]  # [1, C, D]
     pos2d = positions[None]  # [1, C]
     quant = "k_scale" in cache
 
-    def layer_fn(h, xs):
-        if quant:
-            lp, ll, k_cache, v_cache, k_scale, v_scale = xs
-        else:
-            lp, ll, k_cache, v_cache = xs  # caches: [B, S, K, hd] (layer)
-            k_scale = v_scale = None
-        layer_lora = None if ll is None else {**ll, "scale": lora_bufs["scale"]}
+    def layer_fn(h, kv, layer, lp, layer_lora):
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         q = _attn_proj(lp, "q", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_heads, hd)
         k = _attn_proj(lp, "k", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
@@ -813,32 +793,12 @@ def prefill_with_cache(
         q = apply_rope(q, pos2d, cfg.rope_theta, cfg.rope_scaling)
         k = apply_rope(k, pos2d, cfg.rope_theta, cfg.rope_scaling)
         # Scatter the chunk's K/V into the slot's lane at absolute positions.
-        with jax.named_scope("attn.kv_update"):
-            if quant:
-                kq, ks = _kv_quantize(k[0])
-                vq, vs = _kv_quantize(v[0])
-                k_cache = k_cache.at[slot, positions].set(kq)
-                v_cache = v_cache.at[slot, positions].set(vq)
-                k_scale = k_scale.at[slot, positions].set(ks)
-                v_scale = v_scale.at[slot, positions].set(vs)
-            else:
-                k_cache = k_cache.at[slot, positions].set(k[0])
-                v_cache = v_cache.at[slot, positions].set(v[0])
-        if quant:
-            lane_k = _kv_dequantize(
-                jax.lax.dynamic_index_in_dim(k_cache, slot, 0, keepdims=False),
-                jax.lax.dynamic_index_in_dim(k_scale, slot, 0, keepdims=False),
-                h.dtype)
-            lane_v = _kv_dequantize(
-                jax.lax.dynamic_index_in_dim(v_cache, slot, 0, keepdims=False),
-                jax.lax.dynamic_index_in_dim(v_scale, slot, 0, keepdims=False),
-                h.dtype)
-            carry_out = (k_cache, v_cache, k_scale, v_scale)
-        else:
-            # Chunk queries vs the whole lane, masked to index <= q position.
-            lane_k = jax.lax.dynamic_index_in_dim(k_cache, slot, 0, keepdims=False)
-            lane_v = jax.lax.dynamic_index_in_dim(v_cache, slot, 0, keepdims=False)
-            carry_out = (k_cache, v_cache)
+        kv = _write_kv(kv, (layer, slot, positions), k[0], v[0])
+        # Chunk queries vs the whole lane, masked to index <= q position:
+        # the one lane [S, K, hd] of this layer is sliced out of the carry.
+        lane_k, lane_v = _layer_view(
+            tuple(jax.lax.dynamic_index_in_dim(x, slot, 1, keepdims=False)
+                  for x in kv), layer, h.dtype)
         # Flash-style chunk attend: no [C, S_max] logits materialize, and
         # K blocks past the chunk's reach elide their DMAs — bandwidth
         # tracks the prompt's progress, not S_max (_chunk_attend).
@@ -846,20 +806,13 @@ def prefill_with_cache(
         h = h + _attn_out(lp, attn, layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
-        return h, carry_out
+        return h, kv
 
-    xs = (params["layers"], per_layer_lora, cache["k"], cache["v"])
-    if quant:
-        xs = xs + (cache["k_scale"], cache["v_scale"])
-    h, carry = jax.lax.scan(layer_fn, h, xs)
+    h, kv = _scan_cached_layers(params, cache, lora_bufs, h, layer_fn)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     last_h = jax.lax.dynamic_index_in_dim(h[0], last_index, 0, keepdims=False)
     last_logits = _lm_head(cfg, params, last_h)
-    length_vec = cache["length"].at[slot].set(lane_end)
-    out_cache = {"k": carry[0], "v": carry[1], "length": length_vec}
-    if quant:
-        out_cache["k_scale"], out_cache["v_scale"] = carry[2], carry[3]
-    return last_logits, out_cache
+    return last_logits, _cache_of(kv, cache["length"].at[slot].set(lane_end))
 
 
 @jax.named_scope("kv.insert")

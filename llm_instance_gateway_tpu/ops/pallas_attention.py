@@ -263,29 +263,37 @@ def chunk_attention_pallas(
     n_kv = k_cache.shape[2]
     g = h // n_kv
     scale = float(1.0 / (hd ** 0.5))
-    qt = q.transpose(0, 2, 1, 3)          # [B, H, C, hd]
-    kt = k_cache.transpose(0, 2, 1, 3)    # [B, K, S, hd]
-    vt = v_cache.transpose(0, 2, 1, 3)
+    # Heads as lane columns: in the flat [B, 1, S, K*hd] view a head is the
+    # hd-wide column block its index map names, so neither the queries nor
+    # the lane are transposed to a heads-major copy.  The lane is flattened
+    # HERE, next to the pallas_call that pins its layout: a transpose of a
+    # slice of the layer loop's carry makes XLA lay the whole stacked cache
+    # out heads-major, and convert it on the way in and out (compiled for
+    # the v5e, PR 25).
+    qf = q.reshape(b, 1, c, h * hd)
+    kf = k_cache.reshape(b, 1, s_max, n_kv * hd)
+    vf = v_cache.reshape(b, 1, s_max, n_kv * hd)
     off = jnp.asarray(start, jnp.int32).reshape(1)
+
+    def q_index(bi, hi, qi, kb, off):
+        return (bi, 0, qi, hi)
 
     def kv_index(bi, hi, qi, kb, off, g=g):
         last = (off[0] + qi * block_q + block_q - 1) // block_k
-        return (bi, hi // g, jnp.minimum(kb, last), 0)
+        return (bi, 0, jnp.minimum(kb, last), hi // g)
 
     out = pl.pallas_call(
         functools.partial(_chunk_kernel, scale=scale),
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,  # chunk start: masking + DMA clamping
             grid=(b, h, c // block_q, s_max // block_k),
             in_specs=[
-                pl.BlockSpec((1, 1, block_q, hd),
-                             lambda bi, hi, qi, kb, off: (bi, hi, qi, 0)),
+                pl.BlockSpec((1, 1, block_q, hd), q_index),
                 pl.BlockSpec((1, 1, block_k, hd), kv_index),
                 pl.BlockSpec((1, 1, block_k, hd), kv_index),
             ],
-            out_specs=pl.BlockSpec((1, 1, block_q, hd),
-                                   lambda bi, hi, qi, kb, off: (bi, hi, qi, 0)),
+            out_specs=pl.BlockSpec((1, 1, block_q, hd), q_index),
             scratch_shapes=[
                 pltpu.VMEM((block_q, 128), jnp.float32),  # m (lane-padded)
                 pltpu.VMEM((block_q, 128), jnp.float32),  # l
@@ -298,8 +306,8 @@ def chunk_attention_pallas(
         ),
         interpret=interpret,
         name="chunk_attention",
-    )(off, qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)
+    )(off, qf, kf, vf)
+    return out.reshape(b, c, h, hd)
 
 
 def chunk_shape_reasons(c: int, s_max: int, hd: int) -> list[str]:

@@ -4,8 +4,11 @@ Decode attention reads the whole static KV cache every step — the HBM-bound
 inner loop of serving.  The XLA reference (``ops.attention.decode_attention``)
 materializes [B, K, G, S] logits between two einsums; this kernel streams the
 cache in blocks with the online-softmax recurrence, keeping per-program state
-in VMEM: one grid cell per (batch row, KV head) computes that head group's
-output for the row's single query token.
+in VMEM: one grid cell per (batch row, S-block) computes every head's
+contribution for the row's single query token.  The cache is the STACKED
+[L, B, S, K, hd] array of the model's layer loop, read in place: the layer
+index rides the scalar prefetch, and the tiles are taken from the cache's
+rows view (``_rows``), which costs no copy.
 
 Length masking is exact (positions >= length contribute nothing), matching
 the engine's garbage-tail cache contract.  ``decode_attention`` is the
@@ -34,22 +37,29 @@ NEG_INF = -1e30
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *refs,
-                   block_s: int, scale: float, quant: bool):
-    # q_ref: [1, K, G, hd]; k_ref/v_ref: [1, block_s, K*hd] — ALL heads of
-    # one S-tile per grid step (head fusion keeps the grid small; what a
-    # per-head grid would cost on the chip: not measured);
+                   block_s: int, n_kv: int, scale: float, quant: bool):
+    # q_ref: [1, H, hd]; k_ref/v_ref: [1, block_s*K, hd] — one S-tile of the
+    # cache in its ROWS view: row s*K + kh is position s of kv head kh, which
+    # is how the [.., S, K, hd] cache lies in HBM (``_rows``), so the tile
+    # arrives by one straight DMA and is read as whole vregs.  All heads go
+    # through the MXU together (what a per-head grid would cost on the
+    # chip: not measured): the [H, block_s*K] logits hold every query head
+    # against every row, and the mask keeps the columns of its own kv head.
     # len_ref: [B] (SMEM, scalar-prefetched).  The S-block axis is the
     # innermost grid dim with "arbitrary" semantics: online-softmax state
     # rides f32 VMEM scratch across the sweep, like the prefill flash kernel.
-    # ``quant``: K/V tiles arrive int8 with per-(position, kv-head) f32
-    # scale columns (ks_ref/vs_ref: [1, block_s, n_kv]); dequantization
-    # happens in VMEM right before the MXU feed, so HBM streams half the
-    # bytes of the bf16 variant — decode's actual bound.
+    # ``quant``: K/V tiles arrive int8 with per-row f32 scales as lane
+    # vectors (ks_ref/vs_ref: [1, 1, block_s*K]); int8 is exact in the
+    # compute dtype, so the tiles feed the MXU as they are and the scales
+    # multiply the logits' and the probabilities' columns — HBM streams
+    # half the bytes of the bf16 variant, decode's actual bound.
     if quant:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
         o_ref, m_scr, l_scr, acc_scr = refs
-    n_kv, g, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+    n_heads = q_ref.shape[1]
+    g = n_heads // n_kv
+    rows = block_s * n_kv
     bi = pl.program_id(0)
     sb = pl.program_id(1)
     n_sb = pl.num_programs(1)
@@ -66,53 +76,100 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *refs,
     # the index map revisits the last live tile); the straddling block masks.
     @pl.when(start < length)
     def _compute():
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, (g, block_s), 1)
-        live = pos < length
-        for kh in range(n_kv):  # unrolled: static head offsets into the tile
-            q = q_ref[0, kh]  # [G, hd]
-            k = k_ref[0, :, kh * hd:(kh + 1) * hd]
-            v = v_ref[0, :, kh * hd:(kh + 1) * hd]
-            if quant:
-                k = (k.astype(jnp.float32) * ks_ref[0, :, kh:kh + 1]).astype(q.dtype)
-                v = (v.astype(jnp.float32) * vs_ref[0, :, kh:kh + 1]).astype(q.dtype)
-            # else: K/V stay in their storage dtype — the MXU consumes bf16
-            # directly with f32 accumulation; an explicit astype of every
-            # tile would be VPU work for nothing.
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [G, BS] f32
-            s = jnp.where(live, s, NEG_INF)
-            m_prev = m_scr[kh, :, :1]
-            l_prev = l_scr[kh, :, :1]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_scr[kh] = jnp.broadcast_to(
-                l_prev * corr + p.sum(axis=-1, keepdims=True),
-                l_scr.shape[1:])
-            acc_scr[kh] = acc_scr[kh] * corr + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_scr[kh] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+        q = q_ref[0]  # [H, hd]
+        k = k_ref[0]  # [rows, hd]
+        v = v_ref[0]
+        if quant:
+            k = k.astype(q.dtype)
+            v = v.astype(q.dtype)
+        # else: K/V stay in their storage dtype — the MXU consumes bf16
+        # directly with f32 accumulation; an explicit astype of every
+        # tile would be VPU work for nothing.
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [H, rows] f32
+        if quant:
+            s = s * ks_ref[0]
+        row = jax.lax.broadcasted_iota(jnp.int32, (n_heads, rows), 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, (n_heads, rows), 0)
+        own = (row % n_kv == head // g) & (start + row // n_kv < length)
+        s = jnp.where(own, s, NEG_INF)
+        m_prev = m_scr[:, :1]
+        l_prev = l_scr[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        # Every head owns the block's first position (start < length), so
+        # m_new is finite and the columns masked out come to exactly 0.
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[...] = jnp.broadcast_to(
+            l_prev * corr + p.sum(axis=-1, keepdims=True), l_scr.shape)
+        if quant:
+            p = p * vs_ref[0]
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
 
     @pl.when(sb == n_sb - 1)
     def _finalize():
         # Rows with length == 0 never accumulate (l stays 0) and emit zeros;
         # the engine treats such slots as garbage either way.
         o_ref[0] = (
-            acc_scr[...] / jnp.maximum(l_scr[:, :, :1], 1e-30)
+            acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
         ).astype(o_ref.dtype)
+
+
+def _indexed_kernel(len_ref, index_ref, *rest, **kw):
+    # The second scalar-prefetch operand (the lane kernel's layer index, the
+    # paged kernel's block table) is consumed by the index maps, not the
+    # body: only the DMA source moves.
+    del index_ref
+    _decode_kernel(len_ref, *rest, **kw)
+
+
+def _layer_view(x: jax.Array, layer) -> jax.Array:
+    """One layer of a stacked array, for the XLA references (a slice XLA
+    may materialise: the fallbacks must be right, not fast)."""
+    if layer is None:
+        return x
+    return jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False)
+
+
+def _rows(x: jax.Array) -> jax.Array:
+    """[.., S, K, hd] -> [.., S*K, hd]: the cache as rows of one head's
+    vector.  XLA tiles the two minor dims, (K, hd) before and (rows, hd)
+    after, and with hd a multiple of 128 both tilings put row s*K + kh at
+    the same address: the reshape is a bitcast, no byte moves (compiled
+    for the v5e: bf16 K = 4 ``T(4,128)(2,1)`` -> ``T(8,128)(2,1)``).  The
+    flat [.., S, K*hd] view this kernel used before is a relayout there:
+    a copy of the layer's cache per call (device trace, PR 24)."""
+    return x.reshape(*x.shape[:-3], x.shape[-3] * x.shape[-2], x.shape[-1])
+
+
+def _scale_rows(x: jax.Array) -> jax.Array:
+    """[.., S, K] scales -> [.., 1, S*K]: a lane vector in the order of
+    ``_rows``.  A relayout (XLA stores such an array S-minor on the v5e),
+    of an array 1/hd the size of the cache."""
+    return x.reshape(*x.shape[:-2], 1, x.shape[-2] * x.shape[-1])
+
+
+def _scratch(n_heads: int, hd: int) -> list:
+    return [
+        pltpu.VMEM((n_heads, 128), jnp.float32),  # m (lane-padded)
+        pltpu.VMEM((n_heads, 128), jnp.float32),  # l
+        pltpu.VMEM((n_heads, hd), jnp.float32),   # o accumulator
+    ]
 
 
 # The pipeline double-buffers the K and the V tile: 2 operands x 2 buffers
 # x block_s x (K*hd*itemsize) has to sit in scoped VMEM (16 MiB on v5e)
-# beside the scratch and the int8 path's dequantized head tiles.  On v5e
-# [512, 4096] int8 tiles (8 MiB) lower and [512, 4096] bf16 tiles (16 MiB)
-# exhaust VMEM (tools/onchip_pallas_check.py, chip run of PR 21: llama2-7b
-# and gemma-7b, the MHA layouts with K*hd = 4096) — so wide rows take a
-# shorter S-block.
+# beside the scratch, the [H, block_s*K] f32 logits and the int8 path's
+# converted tiles.  On v5e [512, 4096] int8 tiles (8 MiB) lower and
+# [512, 4096] bf16 tiles (16 MiB) exhaust VMEM (tools/onchip_pallas_check.py,
+# chip run of PR 21: llama2-7b and gemma-7b, the MHA layouts with
+# K*hd = 4096) — so wide rows take a shorter S-block.
 _KV_TILES_VMEM_BUDGET = 8 << 20
 
 
@@ -125,58 +182,63 @@ def _pick_block(s_max: int, row_bytes: int = 0) -> int:
     return 0
 
 
-def _pallas_decode_call(q, k_cache, v_cache, scales, lengths,
+def _pallas_decode_call(q, k_all, v_all, scales, lengths, layer,
                         block_s: int | None, interpret: bool) -> jax.Array:
-    """Shared pallas_call builder for the bf16 and int8 variants —
-    ``scales`` is None (bf16) or (k_scale, v_scale) [B, S, n_kv] f32."""
+    """Shared pallas_call builder for the bf16 and int8 variants, over the
+    STACKED cache [L, B, S, K, hd] and a layer index: the index rides the
+    scalar prefetch into the tiles' index map, so the kernel reads the
+    layer where it lies and no slice of the cache is materialised.  With
+    ``layer`` None the arrays are one layer's, a stack of one (a leading 1
+    is free).  ``scales`` is None (bf16) or (k_scale, v_scale) f32, stacked
+    like the cache: only that layer's reach the kernel, as lane vectors — a
+    relayout kept to one layer of an array 1/hd the size of the cache."""
+    if scales is not None:
+        scales = [_layer_view(s, layer) for s in scales]
+    if layer is None:
+        k_all, v_all, layer = k_all[None], v_all[None], 0
     b, n_heads, hd = q.shape
-    s_max, n_kv = k_cache.shape[1], k_cache.shape[2]
-    g = n_heads // n_kv
-    scale = float(1.0 / (hd ** 0.5))
+    s_max, n_kv = k_all.shape[2], k_all.shape[3]
     if block_s is None:
         block_s = _pick_block(s_max,
-                              n_kv * hd * jnp.dtype(k_cache.dtype).itemsize)
-    qg = q.reshape(b, n_kv, g, hd)
-    # Mosaic requires the last two block dims be (8k, 128k)-aligned or full;
-    # a [1, S, 1, hd] head slice of the 4-D cache violates that.  View the
-    # cache as [B, S, K*hd] instead — contiguous, so the reshape is free —
-    # and slice heads as static lane columns inside the kernel.
-    k2 = k_cache.reshape(b, s_max, n_kv * hd)
-    v2 = v_cache.reshape(b, s_max, n_kv * hd)
+                              n_kv * hd * jnp.dtype(k_all.dtype).itemsize)
+    rows = block_s * n_kv
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def kv_index(bi, sb, lens, block_s=block_s):
+    def q_index(bi, sb, lens, lay):
+        return (bi, 0, 0)
+
+    def kv_index(bi, sb, lens, lay, block_s=block_s):
         # Clamp dead S-blocks (start >= length) to the last live tile so
         # Pallas elides their HBM->VMEM copies: short rows in a long cache
         # cost bandwidth proportional to their length, not to S_max.
         last = jnp.maximum(lens[bi] - 1, 0) // block_s
-        return (bi, jnp.minimum(sb, last), 0)
+        return (lay[0], bi, jnp.minimum(sb, last), 0)
+
+    def scale_index(bi, sb, lens, lay):
+        return (bi, 0, kv_index(bi, sb, lens, lay)[2])
 
     quant = scales is not None
     in_specs = [
-        pl.BlockSpec((1, n_kv, g, hd), lambda bi, sb, lens: (bi, 0, 0, 0)),
-        pl.BlockSpec((1, block_s, n_kv * hd), kv_index),
-        pl.BlockSpec((1, block_s, n_kv * hd), kv_index),
+        pl.BlockSpec((1, n_heads, hd), q_index),
+        pl.BlockSpec((None, 1, rows, hd), kv_index),
+        pl.BlockSpec((None, 1, rows, hd), kv_index),
     ]
-    operands = [lengths, qg, k2, v2]
+    operands = [lengths, layer, q, _rows(k_all), _rows(v_all)]
     if quant:
-        in_specs += [pl.BlockSpec((1, block_s, n_kv), kv_index)] * 2
-        operands += list(scales)
-    kernel = functools.partial(_decode_kernel, block_s=block_s, scale=scale,
-                               quant=quant)
-    out = pl.pallas_call(
+        in_specs += [pl.BlockSpec((1, 1, rows), scale_index)] * 2
+        operands += [_scale_rows(s) for s in scales]
+    kernel = functools.partial(_indexed_kernel, block_s=block_s, n_kv=n_kv,
+                               scale=float(1.0 / (hd ** 0.5)), quant=quant)
+    return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, n_heads, hd), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,  # lengths: drives masking + DMA clamping
+            # lengths: masking + DMA clamping; layer: which of the stack
+            num_scalar_prefetch=2,
             grid=(b, s_max // block_s),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, n_kv, g, hd),
-                                   lambda bi, sb, lens: (bi, 0, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((n_kv, g, 128), jnp.float32),  # m (lane-padded)
-                pltpu.VMEM((n_kv, g, 128), jnp.float32),  # l
-                pltpu.VMEM((n_kv, g, hd), jnp.float32),   # o accumulator
-            ],
+            out_specs=pl.BlockSpec((1, n_heads, hd), q_index),
+            scratch_shapes=_scratch(n_heads, hd),
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
@@ -184,33 +246,34 @@ def _pallas_decode_call(q, k_cache, v_cache, scales, lengths,
         interpret=interpret,
         name="decode_attention_int8" if quant else "decode_attention",
     )(*operands)
-    return out.reshape(b, n_heads, hd)
 
 
 def decode_attention_pallas(
     q: jax.Array,        # [B, n_heads, hd]
-    k_cache: jax.Array,  # [B, S, n_kv, hd]
+    k_cache: jax.Array,  # [B, S, n_kv, hd], or [L, B, S, n_kv, hd] + layer
     v_cache: jax.Array,
     lengths: jax.Array,  # [B] int32
+    layer=None,          # scalar int32: which layer of a stacked cache
     block_s: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    return _pallas_decode_call(q, k_cache, v_cache, None, lengths,
+    return _pallas_decode_call(q, k_cache, v_cache, None, lengths, layer,
                                block_s, interpret)
 
 
 def decode_attention_quant_pallas(
     q: jax.Array,        # [B, n_heads, hd]
-    k_cache: jax.Array,  # [B, S, n_kv, hd] int8
+    k_cache: jax.Array,  # [B, S, n_kv, hd] int8, or stacked + layer
     v_cache: jax.Array,
-    k_scale: jax.Array,  # [B, S, n_kv] f32
+    k_scale: jax.Array,  # [B, S, n_kv] f32, or stacked
     v_scale: jax.Array,
     lengths: jax.Array,  # [B] int32
+    layer=None,
     block_s: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     return _pallas_decode_call(q, k_cache, v_cache, (k_scale, v_scale),
-                               lengths, block_s, interpret)
+                               lengths, layer, block_s, interpret)
 
 
 def shape_reasons(s_max: int, hd: int, row_bytes: int = 0) -> list[str]:
@@ -231,7 +294,7 @@ def supports(s_max: int, hd: int, row_bytes: int = 0) -> bool:
 
 
 def _row_bytes(k_cache: jax.Array) -> int:
-    return k_cache.shape[2] * k_cache.shape[3] * k_cache.dtype.itemsize
+    return k_cache.shape[-2] * k_cache.shape[-1] * k_cache.dtype.itemsize
 
 
 # ---------------------------------------------------------------------------
@@ -239,18 +302,11 @@ def _row_bytes(k_cache: jax.Array) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _paged_kernel(len_ref, tab_ref, *rest, block_s, scale, quant):
-    # Same body as the lane kernel — the logical S-block index (grid dim 1)
-    # drives masking exactly as before; only the DMA source moved.  The
-    # table ref is consumed by the index maps, not the body.
-    del tab_ref
-    _decode_kernel(len_ref, *rest, block_s=block_s, scale=scale, quant=quant)
-
-
 def paged_shape_reasons(block: int, hd: int, dtype) -> list[str]:
-    """The pool tile is one physical block: [1, block, K*hd].  Sublane dim
-    = block, so it must divide the dtype's packed tiling: (8, 128) f32,
-    (16, 128) bf16, (32, 128) int8."""
+    """The pool tile is one physical block in its rows view: [1, block*K,
+    hd].  The gate stays on ``block``, the tile's sublane dim at K = 1: it
+    must divide the dtype's packed tiling, (8, 128) f32, (16, 128) bf16,
+    (32, 128) int8."""
     sublane = {4: 8, 2: 16, 1: 32}.get(jnp.dtype(dtype).itemsize, 32)
     reasons = []
     if hd % 128:
@@ -294,11 +350,10 @@ def paged_decode_attention_pallas(
     n_kv = k_pool.shape[2]
     block = k_pool.shape[1]
     m = tables.shape[1]
-    g = n_heads // n_kv
-    scale = float(1.0 / (hd ** 0.5))
-    qg = q.reshape(b, n_kv, g, hd)
-    k2 = k_pool.reshape(k_pool.shape[0], block, n_kv * hd)
-    v2 = v_pool.reshape(v_pool.shape[0], block, n_kv * hd)
+    rows = block * n_kv
+
+    def q_index(bi, sb, lens, tabs):
+        return (bi, 0, 0)
 
     def kv_index(bi, sb, lens, tabs, block=block):
         last = jnp.maximum(lens[bi] - 1, 0) // block
@@ -306,30 +361,27 @@ def paged_decode_attention_pallas(
 
     quant = k_scale is not None
     in_specs = [
-        pl.BlockSpec((1, n_kv, g, hd), lambda bi, sb, lens, tabs: (bi, 0, 0, 0)),
-        pl.BlockSpec((1, block, n_kv * hd), kv_index),
-        pl.BlockSpec((1, block, n_kv * hd), kv_index),
+        pl.BlockSpec((1, n_heads, hd), q_index),
+        pl.BlockSpec((1, rows, hd), kv_index),
+        pl.BlockSpec((1, rows, hd), kv_index),
     ]
-    operands = [lengths, tables, qg, k2, v2]
+    operands = [lengths, tables, q, _rows(k_pool), _rows(v_pool)]
     if quant:
-        in_specs += [pl.BlockSpec((1, block, n_kv), kv_index)] * 2
-        operands += [k_scale, v_scale]
-    kernel = functools.partial(_paged_kernel, block_s=block, scale=scale,
-                               quant=quant)
-    out = pl.pallas_call(
+        in_specs += [pl.BlockSpec((1, 1, rows), kv_index)] * 2
+        operands += [_scale_rows(k_scale), _scale_rows(v_scale)]
+    # Same body as the lane kernel — the logical S-block index (grid dim 1)
+    # drives masking exactly as there; the table routes the DMA.
+    kernel = functools.partial(_indexed_kernel, block_s=block, n_kv=n_kv,
+                               scale=float(1.0 / (hd ** 0.5)), quant=quant)
+    return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, n_heads, hd), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # lengths (masking) + tables (DMA routing)
             grid=(b, m),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, n_kv, g, hd),
-                                   lambda bi, sb, lens, tabs: (bi, 0, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((n_kv, g, 128), jnp.float32),  # m (lane-padded)
-                pltpu.VMEM((n_kv, g, 128), jnp.float32),  # l
-                pltpu.VMEM((n_kv, g, hd), jnp.float32),   # o accumulator
-            ],
+            out_specs=pl.BlockSpec((1, n_heads, hd), q_index),
+            scratch_shapes=_scratch(n_heads, hd),
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
@@ -338,7 +390,6 @@ def paged_decode_attention_pallas(
         name=("paged_decode_attention_int8" if quant
               else "paged_decode_attention"),
     )(*operands)
-    return out.reshape(b, n_heads, hd)
 
 
 def paged_decode_attention(
@@ -375,36 +426,43 @@ def paged_decode_attention(
 
 def decode_attention(
     q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, lengths: jax.Array,
-    interpret: bool = False,
+    layer=None, interpret: bool = False,
 ) -> jax.Array:
-    """Dispatch: Pallas kernel when shapes allow, XLA reference otherwise."""
-    s_max, hd = k_cache.shape[1], k_cache.shape[3]
+    """Dispatch: Pallas kernel when shapes allow, XLA reference otherwise.
+    With ``layer`` the caches are the stacked [L, B, S, K, hd] arrays and
+    the kernel reads that layer in place."""
+    s_max, hd = k_cache.shape[-3], k_cache.shape[-1]
     reason = kernel_reason(
         shape_reasons(s_max, hd, _row_bytes(k_cache)), interpret)
     log_choice("decode", f"q{tuple(q.shape)} cache{tuple(k_cache.shape)}",
                reason, interpret)
     if reason is not None:
-        return xla_decode(q, k_cache, v_cache, lengths)
-    return decode_attention_pallas(q, k_cache, v_cache, lengths, interpret=interpret)
+        return xla_decode(q, _layer_view(k_cache, layer),
+                          _layer_view(v_cache, layer), lengths)
+    return decode_attention_pallas(q, k_cache, v_cache, lengths, layer,
+                                   interpret=interpret)
 
 
 def decode_attention_quant(
     q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     k_scale: jax.Array, v_scale: jax.Array, lengths: jax.Array,
-    interpret: bool = False,
+    layer=None, interpret: bool = False,
 ) -> jax.Array:
     """int8-KV dispatch: the quantized kernel streams half the HBM
     bytes AND skips the logits materialization; unsupported shapes / CPU
     dequantize and take the XLA reference."""
-    s_max, hd = k_cache.shape[1], k_cache.shape[3]
+    s_max, hd = k_cache.shape[-3], k_cache.shape[-1]
     reason = kernel_reason(
         shape_reasons(s_max, hd, _row_bytes(k_cache)), interpret)
     log_choice("decode_int8",
                f"q{tuple(q.shape)} cache{tuple(k_cache.shape)}",
                reason, interpret)
     if reason is not None:
+        k_cache, v_cache, k_scale, v_scale = (
+            _layer_view(x, layer) for x in (k_cache, v_cache, k_scale, v_scale))
         deq = k_cache.astype(q.dtype) * k_scale[..., None].astype(q.dtype)
         dev = v_cache.astype(q.dtype) * v_scale[..., None].astype(q.dtype)
         return xla_decode(q, deq, dev, lengths)
     return decode_attention_quant_pallas(
-        q, k_cache, v_cache, k_scale, v_scale, lengths, interpret=interpret)
+        q, k_cache, v_cache, k_scale, v_scale, lengths, layer,
+        interpret=interpret)

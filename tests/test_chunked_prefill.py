@@ -12,7 +12,11 @@ from llm_instance_gateway_tpu.server.engine import Engine, EngineConfig, Request
 CFG = TINY_TEST
 
 
-def test_prefill_with_cache_matches_monolithic():
+@pytest.mark.parametrize("chunk", [4, 8, 32], ids=lambda c: f"chunk{c}")
+def test_prefill_with_cache_matches_monolithic(chunk):
+    """N chunks (six of 4, three of 8 with a padded last one, one padded
+    chunk of 32) equal one prefill: last logits, the lane's K/V in every
+    layer of the stacked cache, its length; other lanes untouched."""
     params = transformer.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
     prompt = list(np.random.RandomState(0).randint(1, 250, size=23))
     n = len(prompt)
@@ -21,9 +25,8 @@ def test_prefill_with_cache_matches_monolithic():
     positions = jnp.arange(n)[None]
     ref_logits, ref_k, ref_v = transformer.prefill(CFG, params, tokens, positions)
 
-    # Chunked: 8-token chunks (last chunk padded), slot 1 of a 2-lane cache.
+    # Chunked (last chunk padded), slot 1 of a 2-lane cache.
     cache = transformer.init_decode_cache(CFG, 2, 64, dtype=jnp.float32)
-    chunk = 8
     for start in range(0, n, chunk):
         piece = prompt[start:start + chunk]
         c = len(piece)
@@ -40,10 +43,11 @@ def test_prefill_with_cache_matches_monolithic():
         rtol=2e-4, atol=2e-4,
     )
     # The lane's cached K/V for real positions match too.
-    np.testing.assert_allclose(
-        np.asarray(cache["k"][:, 1, :n]), np.asarray(ref_k[:, 0]),
-        rtol=2e-4, atol=2e-4,
-    )
+    for name, ref in (("k", ref_k), ("v", ref_v)):
+        np.testing.assert_allclose(
+            np.asarray(cache[name][:, 1, :n]), np.asarray(ref[:, 0]),
+            rtol=2e-4, atol=2e-4,
+        )
     assert int(cache["length"][1]) == n
     # Other lanes untouched.
     assert float(jnp.abs(cache["k"][:, 0]).sum()) == 0.0

@@ -278,14 +278,18 @@ class TestQuantizedEngine:
 
 
 class TestQuantPallasKernel:
-    def test_interpret_parity_with_dequant_xla(self):
+    @pytest.mark.parametrize("heads,kv", [(4, 2), (28, 4), (32, 8)])
+    @pytest.mark.parametrize("layer", [None, 1])
+    def test_interpret_parity_with_dequant_xla(self, heads, kv, layer):
         """The int8-aware decode kernel (interpret mode) matches the
-        dequantize-then-XLA reference at f32 tolerance."""
+        dequantize-then-XLA reference at f32 tolerance: over one layer's
+        arrays, and over layer 1 of stacked int8 caches and scales (the
+        layer loop's carry; the other layers hold poison)."""
         from llm_instance_gateway_tpu.ops import pallas_decode_attention as pda
         from llm_instance_gateway_tpu.ops.attention import (
             decode_attention as xla_decode)
 
-        b, heads, kv, hd, s = 3, 4, 2, 128, 512
+        b, hd, s = 3, 128, 512
         keys = jax.random.split(jax.random.PRNGKey(5), 3)
         q = jax.random.normal(keys[0], (b, heads, hd), jnp.float32)
         kf = jax.random.normal(keys[1], (b, s, kv, hd), jnp.float32)
@@ -301,8 +305,12 @@ class TestQuantPallasKernel:
         want = xla_decode(q, transformer._kv_dequantize(kq, ks, jnp.float32),
                           transformer._kv_dequantize(vq, vs, jnp.float32),
                           lengths)
+        operands = (kq, vq, ks, vs)
+        if layer is not None:
+            operands = tuple(
+                jnp.stack([jnp.full_like(x, 100), x]) for x in operands)
         got = pda.decode_attention_quant_pallas(
-            q, kq, vq, ks, vs, lengths, block_s=128, interpret=True)
+            q, *operands, lengths, layer=layer, block_s=128, interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 

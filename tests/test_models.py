@@ -7,6 +7,8 @@ correctness contract the serving engine relies on (JetStream-style
 prefill -> insert -> generate).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -228,3 +230,198 @@ class TestVocabPadding:
                          jnp.array([2.0]), jnp.array([0]), jnp.array([1.0]),
                          valid_vocab=valid)
             assert int(tok[0]) < valid
+
+
+# ---------------------------------------------------------------------------
+# The cached programs against a plain reference (PR 25)
+# ---------------------------------------------------------------------------
+
+
+def _ref_cached_forward(cfg, params, layers, tokens, positions, lanes, writes):
+    """Plain reference for decode_step / extend_step / prefill_with_cache.
+
+    The cache is a per-layer LIST of dicts of [B, S, K, hd] arrays (int8
+    with [B, S, K] scales when quantized), each updated with ``.at[].set``;
+    attention is a masked f32 softmax over the row's whole lane.  ``tokens``
+    and ``positions`` are [R, C] (C new tokens for each of R rows),
+    ``lanes`` [R] the cache lane of each row, ``writes`` [R] whether the
+    row may write.  Returns (logits [R, C, V], the new list)."""
+    from llm_instance_gateway_tpu.ops.layers import apply_rope, rms_norm
+
+    r_n, c = tokens.shape
+    hd = cfg.resolved_head_dim
+    none = jnp.full((r_n,), -1, jnp.int32)
+    h = transformer._embed(cfg, params, tokens)
+    out_layers = []
+    for l, lc in enumerate(layers):
+        lp = jax.tree.map(lambda x: x[l], params["layers"])
+        hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps,
+                      plus_one=cfg.norm_plus_one)
+        q, k, v = (transformer._attn_proj(lp, t, hn, None, none).reshape(
+            r_n, c, n, hd) for t, n in (("q", cfg.n_heads),
+                                        ("k", cfg.n_kv_heads),
+                                        ("v", cfg.n_kv_heads)))
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+        lc = dict(lc)
+        for r in range(r_n):
+            if not writes[r]:
+                continue
+            at = (lanes[r], positions[r])
+            if "k_scale" in lc:
+                (kq, ks), (vq, vs) = (transformer._kv_quantize(k[r]),
+                                      transformer._kv_quantize(v[r]))
+                lc["k"] = lc["k"].at[at].set(kq)
+                lc["v"] = lc["v"].at[at].set(vq)
+                lc["k_scale"] = lc["k_scale"].at[at].set(ks)
+                lc["v_scale"] = lc["v_scale"].at[at].set(vs)
+            else:
+                lc["k"] = lc["k"].at[at].set(k[r])
+                lc["v"] = lc["v"].at[at].set(v[r])
+        out_layers.append(lc)
+        attn = []
+        for r in range(r_n):
+            lane_k, lane_v = lc["k"][lanes[r]], lc["v"][lanes[r]]
+            if "k_scale" in lc:
+                lane_k = lane_k.astype(jnp.float32) * lc["k_scale"][lanes[r]][..., None]
+                lane_v = lane_v.astype(jnp.float32) * lc["v_scale"][lanes[r]][..., None]
+            qg = q[r].reshape(c, cfg.n_kv_heads, cfg.q_per_kv, hd)
+            s = jnp.einsum("ikgh,jkh->kgij", qg, lane_k) / np.sqrt(hd)
+            seen = jnp.arange(lane_k.shape[0])[None] <= positions[r][:, None]
+            p = jax.nn.softmax(jnp.where(seen[None, None], s, -1e30), axis=-1)
+            attn.append(jnp.einsum("kgij,jkh->ikgh", p, lane_v).reshape(c, -1))
+        h = h + transformer._attn_out(lp, jnp.stack(attn), None, none)
+        hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps,
+                       plus_one=cfg.norm_plus_one)
+        h = h + transformer._mlp(cfg, lp, hn2, None, none)
+    h = rms_norm(h, params["final_norm"], cfg.norm_eps,
+                 plus_one=cfg.norm_plus_one)
+    return transformer._lm_head(cfg, params, h), out_layers
+
+
+def _random_cache(cfg, b, s, quant, seed=7):
+    """A stacked cache with every cell filled (an arbitrary history)."""
+    cache = transformer.init_decode_cache(cfg, b, s, dtype=jnp.float32,
+                                          quantized=quant)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    k = jax.random.normal(keys[0], cache["k"].shape, jnp.float32)
+    v = jax.random.normal(keys[1], cache["v"].shape, jnp.float32)
+    if quant:
+        (cache["k"], cache["k_scale"]), (cache["v"], cache["v_scale"]) = (
+            transformer._kv_quantize(k), transformer._kv_quantize(v))
+    else:
+        cache["k"], cache["v"] = k, v
+    return cache
+
+
+def _assert_cache_equals_list(cache, layers):
+    for name in ("k", "v", "k_scale", "v_scale"):
+        if name not in cache:
+            assert name not in layers[0]
+            continue
+        want = np.stack([np.asarray(lc[name]) for lc in layers])
+        got = np.asarray(cache[name])
+        if got.dtype == np.int8:
+            # The same quantizer in another fusion: at most one step apart.
+            assert np.max(np.abs(got.astype(np.int32) - want)) <= 1, name
+        else:
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5,
+                                       err_msg=name)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("program", ["decode", "extend", "chunk"])
+def test_cached_programs_match_plain_reference(program, quant):
+    """decode_step, extend_step and prefill_with_cache (the stacked cache as
+    the layer loop's carry, written in place) give the logits and the cache
+    contents of the per-layer-list reference; an inactive row writes
+    nothing."""
+    cfg = TINY_TEST
+    params = make_model(cfg)
+    b, s = 3, 32
+    cache = _random_cache(cfg, b, s, quant)
+    before = jax.tree.map(np.asarray, cache)
+    layers = [{n: cache[n][l] for n in cache if n != "length"}
+              for l in range(cfg.n_layers)]
+    active = np.array([True, True, False])
+    if program == "chunk":
+        c = 4
+        tokens = random_tokens(cfg, 1, c, seed=3)
+        positions = 9 + jnp.arange(c)[None]
+        got, new = jax.jit(transformer.prefill_with_cache, static_argnums=0)(
+            cfg, params, cache, tokens[0], positions[0], jnp.int32(1),
+            jnp.int32(13), jnp.int32(c - 1))
+        want, want_layers = _ref_cached_forward(
+            cfg, params, layers, tokens, positions, [1], [True])
+        want, rows = want[0, c - 1], slice(None)
+        assert new["length"].tolist() == [0, 13, 0]
+        untouched = [0, 2]
+    else:
+        c = 1 if program == "decode" else 3
+        tokens = random_tokens(cfg, b, c, seed=3)
+        positions = jnp.asarray([5, 9, 3])[:, None] + jnp.arange(c)[None]
+        if program == "decode":
+            got, new = jax.jit(transformer.decode_step, static_argnums=0)(
+                cfg, params, cache, tokens[:, 0], positions[:, 0],
+                active=jnp.asarray(active))
+            got = got[:, None]
+        else:
+            got, new = jax.jit(transformer.extend_step, static_argnums=0)(
+                cfg, params, cache, tokens, positions,
+                active=jnp.asarray(active))
+        want, want_layers = _ref_cached_forward(
+            cfg, params, layers, tokens, positions, range(b), active)
+        rows = active
+        untouched = [2]
+    scale = float(np.max(np.abs(np.asarray(want))))
+    np.testing.assert_allclose(np.asarray(got)[rows], np.asarray(want)[rows],
+                               rtol=2e-4, atol=2e-4 * scale)
+    _assert_cache_equals_list(new, want_layers)
+    for name in ("k", "v", "k_scale", "v_scale"):
+        if name in new:  # a lane that may not write is bit for bit as it was
+            np.testing.assert_array_equal(
+                np.asarray(new[name])[:, untouched], before[name][:, untouched])
+
+
+def _scans(jaxpr):
+    """Every scan equation of a jaxpr, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("program", ["decode", "extend", "chunk"])
+def test_layer_scan_carries_the_cache(program, quant):
+    """Structure, not timing: the layer scan takes no array of the cache's
+    shape as ``xs`` (a scanned input is sliced per layer, a scanned output
+    stacked: with a donated cache that cost two whole-cache copies and a
+    slice and a write-back per layer, PERF.md §6 PR 25) and carries every
+    one of them."""
+    cfg = TINY_TEST
+    params = make_model(cfg)
+    b, s = 3, 32
+    cache = transformer.init_decode_cache(cfg, b, s, dtype=jnp.float32,
+                                          quantized=quant)
+    i32 = functools.partial(jnp.zeros, dtype=jnp.int32)
+    if program == "decode":
+        fn, args = transformer.decode_step, (i32((b,)), i32((b,)))
+    elif program == "extend":
+        fn, args = transformer.extend_step, (i32((b, 2)), i32((b, 2)))
+    else:
+        fn = transformer.prefill_with_cache
+        args = (i32((4,)), i32((4,)), i32(()), i32(()), i32(()))
+    jaxpr = jax.make_jaxpr(functools.partial(fn, cfg))(params, cache, *args)
+    cache_shapes = sorted(
+        (v.shape, v.dtype) for n, v in cache.items() if n != "length")
+    (scan,) = [e for e in _scans(jaxpr.jaxpr)
+               if e.params["length"] == cfg.n_layers]
+    n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+    avals = [(v.aval.shape, v.aval.dtype) for v in scan.invars]
+    carry, xs = avals[n_consts:n_consts + n_carry], avals[n_consts + n_carry:]
+    ys = [(v.aval.shape, v.aval.dtype) for v in scan.outvars[n_carry:]]
+    assert sorted(a for a in carry if a in cache_shapes) == cache_shapes
+    assert not [a for a in xs + avals[:n_consts] if a in cache_shapes]
+    assert not [a for a in xs + ys if a[0][1:] == cache["k"].shape[1:]]

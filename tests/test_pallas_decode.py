@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from llm_instance_gateway_tpu.ops.attention import decode_attention as xla_decode
 from llm_instance_gateway_tpu.ops import pallas_decode_attention as pda
@@ -17,23 +18,78 @@ def make_inputs(b=4, h=8, kv=2, hd=128, s=256, seed=0):
     return q, k, v, lengths
 
 
+# (query heads, kv heads): qwen2.5-7b's K = 4 at G = 7, mixtral's K = 8, and
+# the small layout the file has always used.
+HEAD_LAYOUTS = [(8, 2), (28, 4), (32, 8)]
+
+
+def stack_at(x, layer, n_layers=3):
+    """``x`` as layer ``layer`` of a stacked cache whose other layers are
+    poison: reading the wrong layer is an O(1000) error."""
+    return jnp.stack([x if l == layer else jnp.full_like(x, 1e3)
+                      for l in range(n_layers)])
+
+
 class TestDecodeKernel:
-    def test_matches_reference(self):
-        q, k, v, lengths = make_inputs()
+    @pytest.mark.parametrize("h,kv", HEAD_LAYOUTS)
+    @pytest.mark.parametrize("layer", [None, 0, 2])
+    def test_matches_reference(self, h, kv, layer):
+        """One layer's [B, S, K, hd] cache, and layer ``layer`` of a stacked
+        [L, B, S, K, hd] cache read where it lies, equal the XLA reference
+        on that layer's slice."""
+        q, k, v, lengths = make_inputs(h=h, kv=kv)
         ref = xla_decode(q, k, v, lengths)
-        got = pda.decode_attention_pallas(q, k, v, lengths, interpret=True)
+        if layer is not None:
+            k, v = stack_at(k, layer), stack_at(v, layer)
+        got = pda.decode_attention_pallas(
+            q, k, v, lengths, layer=None if layer is None else jnp.int32(layer),
+            interpret=True)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
                                    rtol=2e-5, atol=2e-5)
 
-    def test_length_masking_exact(self):
+    @pytest.mark.parametrize("h,kv", HEAD_LAYOUTS)
+    def test_dispatcher_reads_a_layer_of_the_stack(self, h, kv):
+        """``decode_attention(layer=)``: the kernel (interpret) and the XLA
+        fallback (this backend) both read layer 1 of the stack."""
+        q, k, v, lengths = make_inputs(h=h, kv=kv, seed=11)
+        ref = xla_decode(q, k, v, lengths)
+        ks, vs = stack_at(k, 1), stack_at(v, 1)
+        for interpret in (True, False):
+            got = jax.jit(lambda q, ks, vs, lay: pda.decode_attention(
+                q, ks, vs, lengths, layer=lay, interpret=interpret))(
+                    q, ks, vs, jnp.int32(1))
+            np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
+                                       rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("layer", [None, 1])
+    def test_length_masking_exact(self, layer):
         # Garbage beyond each row's length must not perturb the output.
         q, k, v, lengths = make_inputs(seed=3)
         k_poisoned = k.at[:, -32:].set(1e3)
         v_poisoned = v.at[:, -32:].set(-1e3)
         short = jnp.minimum(lengths, k.shape[1] - 32)
         ref = xla_decode(q, k, v, short)
+        if layer is not None:
+            k_poisoned = stack_at(k_poisoned, layer)
+            v_poisoned = stack_at(v_poisoned, layer)
         got = pda.decode_attention_pallas(q, k_poisoned, v_poisoned, short,
-                                          interpret=True)
+                                          layer=layer, interpret=True)
+        np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("h,kv", [(28, 4), (32, 8)])
+    def test_short_rows_in_a_long_cache(self, h, kv):
+        """Rows of 1..40 tokens in a 1024-position cache, four S-blocks of
+        256: the dead blocks (clamped to the last live tile, never
+        computed) hold poison."""
+        q, k, v, _ = make_inputs(b=3, h=h, kv=kv, s=1024, seed=9)
+        lengths = jnp.asarray([1, 17, 40], jnp.int32)
+        ref = xla_decode(q, k, v, lengths)
+        k = k.at[:, 256:].set(1e3)
+        v = v.at[:, 256:].set(-1e3)
+        got = pda.decode_attention_pallas(
+            q, stack_at(k, 1, 2), stack_at(v, 1, 2), lengths,
+            layer=jnp.int32(1), block_s=256, interpret=True)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
                                    rtol=2e-5, atol=2e-5)
 

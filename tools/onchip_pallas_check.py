@@ -105,18 +105,26 @@ def case_decode(h, n_kv, hd, s_max, quant, b=16):
     kf = jax.random.normal(kk, (b, s_max, n_kv, hd), jnp.float32)
     vf = jax.random.normal(kv, (b, s_max, n_kv, hd), jnp.float32)
     lengths = _lengths(b, s_max)
+
+    def stacked(x):
+        # The kernel reads layer 1 of a stack of two (layer 0 is poison):
+        # the layer index rides the index map, as in the model's layer loop.
+        return jnp.stack([jnp.full_like(x, 100), x])
+
     if quant:
         k8, ks = _kv_quantize(kf)
         v8, vs = _kv_quantize(vf)
-        out = jax.jit(lambda *a: pdec.decode_attention_quant_pallas(
-            *a, interpret=False))(q, k8, v8, ks, vs, lengths)
+        out = jax.jit(lambda q, *a: pdec.decode_attention_quant_pallas(
+            q, *map(stacked, a), lengths, layer=jnp.int32(1),
+            interpret=False))(q, k8, v8, ks, vs)
         ref = jax.jit(lambda q, k8, v8, ks, vs, l: xla_att.decode_attention(
             q, _kv_dequantize(k8, ks, q.dtype),
             _kv_dequantize(v8, vs, q.dtype), l))(q, k8, v8, ks, vs, lengths)
         return out, ref, TOL_INT8
     kc, vc = kf.astype(DTYPE), vf.astype(DTYPE)
-    out = jax.jit(lambda *a: pdec.decode_attention_pallas(
-        *a, interpret=False))(q, kc, vc, lengths)
+    out = jax.jit(lambda q, kc, vc: pdec.decode_attention_pallas(
+        q, stacked(kc), stacked(vc), lengths, layer=jnp.int32(1),
+        interpret=False))(q, kc, vc)
     ref = jax.jit(xla_att.decode_attention)(q, kc, vc, lengths)
     return out, ref, TOL_BF16
 
