@@ -466,6 +466,10 @@ CLASSES = (
         f"{PKG}/server/profiler.py", "StepProfiler", ENGINE_STEP,
         fields=(
             SharedField("_seq", MONOTONIC, writers=("note_dispatch",)),
+            SharedField("moe", LOCK_GUARDED, writers=("note_moe",),
+                        note="a sparse model's routing counts: the engine "
+                             "thread adds at the decode readback, the "
+                             "scrape reads a copy under the lock"),
             SharedField("_last_end", OWNER_PRIVATE,
                         writers=("note_dispatch",)),
             SharedField("_idle_pending", OWNER_PRIVATE,
@@ -562,6 +566,11 @@ CLASSES = (
                         writers=("_paged_alloc_block", "_kv_ledger_sync"),
                         note="eviction tally drained into ONE aggregated "
                              "kv_evict journal event per ledger sync"),
+            SharedField("_moe_pending", OWNER_PRIVATE,
+                        writers=("_moe_keep", "_moe_drain"),
+                        note="device arrays of prefill programs' routing "
+                             "counts, drained into the next decode "
+                             "readback"),
             SharedField("_dev_counts", OWNER_PRIVATE,
                         writers=("_count_first_token", "_counts",
                                  "_dispatch_block", "_do_decode_step",

@@ -365,6 +365,16 @@ SERVER_FAMILIES = (
            "Fused decode steps per dispatch — the adaptive multi-step "
            "planner's decision record (buckets land on its power-of-two "
            "choices; EngineConfig.adaptive_steps).", SERVER_SURFACE),
+    Family("tpu:moe_layer_steps_total", "counter", (),
+           "Sparse layers run, one per layer per decode step or prefill "
+           "program (0 for a dense model).", SERVER_SURFACE),
+    Family("tpu:moe_assignments_total", "counter", (),
+           "Token-to-expert assignments of live rows, summed over "
+           "layer-steps.", SERVER_SURFACE),
+    Family("tpu:moe_experts_touched_total", "counter", (),
+           "Experts with at least one live assignment, summed over "
+           "layer-steps: over tpu:moe_layer_steps_total, the experts a "
+           "layer reads.", SERVER_SURFACE),
     Family("tpu:prefill_seconds", "histogram", ("model", "role"),
            "Prefill compute latency.", SERVER_SURFACE),
     Family("tpu:handoff_seconds", "histogram", ("model", "role"),

@@ -4,7 +4,8 @@ The reference's PoC pools serve Llama-2-7b + LoRA on vLLM
 (``examples/poc/manifests/vllm/vllm-lora-deployment.yaml:23-60``); the
 BASELINE.json milestone configs call for Gemma-2B, Llama-3-8B and a
 Mixtral-8x7B + Gemma-7B mixed pool.  These dataclasses cover all of them with
-one decoder family (RoPE + GQA + RMSNorm + gated MLP, optionally MoE).
+one decoder family (RoPE + GQA + RMSNorm + gated MLP, optionally MoE;
+Qwen2's QKV bias and OLMoE's QK-norm and gate rule are flags).
 
 All dims are chosen/padded TPU-first: head_dim and d_model multiples of 128
 (MXU lane width), d_ff multiples of 128, vocab padded to 128 so the final
@@ -49,21 +50,14 @@ class ModelConfig:
     # MoE (Mixtral): 0 experts = dense.
     n_experts: int = 0
     n_experts_per_token: int = 2
-    # Grouped MoE dispatch (GShard-style capacity scatter) runs whenever it
-    # beats dense all-experts on expert-rows — prefill AND batched decode;
-    # expert capacity = tokens*k/E * this factor (large tiles round to a
-    # multiple of 8; decode-sized tiles keep the exact ceiling).  With
-    # ``moe_exact_fallback`` a batch whose routing overflows any expert's
-    # capacity recomputes via the dense all-experts path inside a lax.cond
-    # — bit-exact results always, at grouped+dense cost for that batch, so
-    # exact mode enforces >= 2.0x headroom at every tile size to keep the
-    # double-pay rare (a 16-slot Mixtral decode then computes ~2x the
-    # dropless-ideal t*k expert-rows — still half the dense path's E/k=4x).
-    # Set False for GShard token-dropping (overflowed assignments
-    # contribute zero): the standard serving trade, where this factor
-    # applies as-is and the same decode computes ~1.25x dropless-ideal.
-    moe_capacity_factor: float = 1.25
-    moe_exact_fallback: bool = True
+    # The router's gate rule.  True (Mixtral): the k chosen experts' weights
+    # are the softmax over the chosen logits, i.e. renormalised to sum to 1.
+    # False (OLMoE, ``norm_topk_prob`` false): the weights are the softmax
+    # over ALL experts, taken as they are.
+    norm_topk_prob: bool = True
+    # OLMoE: RMSNorm on the whole projected q and k vectors (all heads
+    # together), after the projection and before the split into heads.
+    qk_norm: bool = False
     # LoRA serving slots (compile-time constants: resizing reshapes buffers
     # and recompiles, so they mirror vLLM's --max-loras / max rank flags).
     max_lora_slots: int = 4
@@ -76,7 +70,9 @@ class ModelConfig:
     # chip run of PR 21).  Speed against XLA: not measured.
     use_flash_attention: bool = True
     # Pallas cached-decode attention kernel (ops/pallas_decode_attention),
-    # same dispatch.  Lowers on v5e with parity at the same layouts, bf16
+    # same dispatch; also switches a sparse model's grouped expert matmul
+    # (ops/pallas_moe) between its kernel and its XLA tiles, so that a mesh,
+    # which turns this off, partitions plain XLA.  Lowers on v5e with parity at the same layouts, bf16
     # and int8, lane and paged (same run); DMA-clamping skips cache blocks
     # past each row's length.  Speed against XLA: not measured.
     use_pallas_decode: bool = True
@@ -213,6 +209,27 @@ QWEN2_5_7B = ModelConfig(
     max_seq_len=32_768,
 )
 
+# allenai/OLMoE-1B-7B-0125-Instruct: MHA, 64 experts of width 1024, top-8,
+# gates not renormalised, QK-norm (unconditional in modeling_olmoe.py).
+OLMOE_1B_7B = ModelConfig(
+    name="olmoe-1b-7b",
+    vocab_size=50_304,
+    d_model=2048,
+    n_layers=16,
+    n_heads=16,
+    n_kv_heads=16,
+    d_ff=1024,
+    head_dim=128,
+    rope_theta=10_000.0,
+    norm_eps=1e-5,
+    n_experts=64,
+    n_experts_per_token=8,
+    norm_topk_prob=False,
+    qk_norm=True,
+    max_seq_len=4096,
+)
+
 TINY_TEST = LLAMA3_8B.tiny()
 TINY_MOE_TEST = MIXTRAL_8X7B.tiny()
 TINY_QWEN_TEST = QWEN2_5_7B.tiny()
+TINY_OLMOE_TEST = OLMOE_1B_7B.tiny()  # keeps 64 experts, top-8

@@ -26,20 +26,24 @@ from llm_instance_gateway_tpu.models.configs import LLAMA3_8B, ModelConfig
 
 
 def config_from_hf(hf_config) -> ModelConfig:
-    """ModelConfig from a transformers Llama/Gemma/Mixtral config object.
+    """ModelConfig from a transformers Llama/Gemma/Mixtral/Qwen2/OLMoE
+    config object.
 
     Mapped: Llama (incl. llama3-type rope_scaling), Gemma (embedding scale,
-    (1+w) norm, tanh-GeLU), Mixtral (expert stacks + router).  Loud
+    (1+w) norm, tanh-GeLU), Mixtral (expert stacks + router), OLMoE (the
+    same stacks under its own names, QK-norm, ``norm_topk_prob``).  Loud
     rejections instead of silent wrong math for everything else: unknown
     model types, non-llama3 rope_scaling types, and sliding-window attention
     (our decoder attends the full causal context).
     """
     model_type = getattr(hf_config, "model_type", "llama")
-    if model_type not in ("llama", "gemma", "mixtral", "qwen2"):
+    if model_type not in ("llama", "gemma", "mixtral", "qwen2", "olmoe"):
         raise NotImplementedError(
             f"HF model_type {model_type!r} not supported by the converter "
-            "(llama, gemma, mixtral, qwen2 are)"
+            "(llama, gemma, mixtral, qwen2, olmoe are)"
         )
+    if model_type == "olmoe" and getattr(hf_config, "clip_qkv", None):
+        raise NotImplementedError("OLMoE clip_qkv is not implemented")
     scaling_kwargs = {}
     rope_scaling = getattr(hf_config, "rope_scaling", None)
     if rope_scaling:
@@ -101,9 +105,16 @@ def config_from_hf(hf_config) -> ModelConfig:
         gelu_mlp=gemma,
         # Mixtral MoE (parity-tested against MixtralForCausalLM; top-k
         # routing normalizations are algebraically identical).
-        n_experts=getattr(hf_config, "num_local_experts", 0)
-        if model_type == "mixtral" else 0,
+        n_experts={"mixtral": getattr(hf_config, "num_local_experts", 0),
+                   "olmoe": getattr(hf_config, "num_experts", 0),
+                   }.get(model_type, 0),
         n_experts_per_token=getattr(hf_config, "num_experts_per_tok", 2),
+        # OLMoE: the gates are the full softmax's unless norm_topk_prob
+        # (Mixtral's rule is the renormalised one), and q and k are
+        # RMS-normed whole, unconditionally (modeling_olmoe.py).
+        norm_topk_prob=(model_type != "olmoe"
+                        or bool(getattr(hf_config, "norm_topk_prob", False))),
+        qk_norm=(model_type == "olmoe"),
         # Qwen2-family: learned Q/K/V biases (parity-tested against
         # Qwen2ForCausalLM; Qwen2 puts NO bias on o_proj).
         attention_bias=(model_type == "qwen2"),
@@ -149,22 +160,30 @@ def params_from_hf_state_dict(cfg: ModelConfig, state_dict, dtype=jnp.bfloat16):
         layers["wq_b"] = stack_raw("model.layers.{}.self_attn.q_proj.bias")
         layers["wk_b"] = stack_raw("model.layers.{}.self_attn.k_proj.bias")
         layers["wv_b"] = stack_raw("model.layers.{}.self_attn.v_proj.bias")
+    if cfg.qk_norm:
+        layers["q_norm"] = stack_raw("model.layers.{}.self_attn.q_norm.weight")
+        layers["k_norm"] = stack_raw("model.layers.{}.self_attn.k_norm.weight")
     if cfg.n_experts:
-        # Mixtral expert naming: w1=gate, w3=up, w2=down (each [f, d] or
-        # [d, f] in HF's [out, in]); stacked here as [L, E, in, out].
+        # Mixtral: block_sparse_moe.experts.N.{w1=gate, w3=up, w2=down};
+        # OLMoE: mlp.experts.N.{gate,up,down}_proj (each [f, d] or [d, f]
+        # in HF's [out, in]); stacked here as [L, E, in, out].
+        if "model.layers.0.mlp.gate.weight" in state_dict:
+            moe, names = "mlp", ("gate_proj", "up_proj", "down_proj")
+        else:
+            moe, names = "block_sparse_moe", ("w1", "w3", "w2")
+
         def stack_experts(w_name):
             return jnp.stack([
                 jnp.stack([
-                    t(f"model.layers.{i}.block_sparse_moe.experts.{e}.{w_name}.weight")
+                    t(f"model.layers.{i}.{moe}.experts.{e}.{w_name}.weight")
                     for e in range(cfg.n_experts)
                 ])
                 for i in range(cfg.n_layers)
             ])
 
-        layers["router"] = stack("model.layers.{}.block_sparse_moe.gate.weight")
-        layers["w_gate"] = stack_experts("w1")
-        layers["w_up"] = stack_experts("w3")
-        layers["w_down"] = stack_experts("w2")
+        layers["router"] = stack("model.layers.{}." + moe + ".gate.weight")
+        layers["w_gate"], layers["w_up"], layers["w_down"] = (
+            stack_experts(n) for n in names)
     else:
         layers["w_gate"] = stack("model.layers.{}.mlp.gate_proj.weight")
         layers["w_up"] = stack("model.layers.{}.mlp.up_proj.weight")

@@ -1,4 +1,6 @@
-"""Mixtral family entry points (8x7B MoE): top-2 routed experts per token.
+"""Sparse-expert entry points: Mixtral-8x7B (8 experts, top-2, renormalised
+gates) and OLMoE-1B-7B (64 experts, top-8, gates as the full softmax gives
+them, QK-norm).
 
 BASELINE.json's criticality-tiered mixed pool pairs Mixtral-8x7B with
 Gemma-7B on v5e-32.  The MoE MLP lives in ``transformer._moe_mlp``; expert
@@ -9,9 +11,15 @@ mesh's expert/tensor axes.
 from __future__ import annotations
 
 from llm_instance_gateway_tpu.models import transformer
-from llm_instance_gateway_tpu.models.configs import MIXTRAL_8X7B, TINY_MOE_TEST
+from llm_instance_gateway_tpu.models.configs import (
+    MIXTRAL_8X7B,
+    OLMOE_1B_7B,
+    TINY_MOE_TEST,
+    TINY_OLMOE_TEST,
+)
 
-CONFIGS = {"mixtral-8x7b": MIXTRAL_8X7B, "mixtral-tiny": TINY_MOE_TEST}
+CONFIGS = {"mixtral-8x7b": MIXTRAL_8X7B, "mixtral-tiny": TINY_MOE_TEST,
+           "olmoe-1b-7b": OLMOE_1B_7B, "olmoe-tiny": TINY_OLMOE_TEST}
 
 init_params = transformer.init_params
 init_decode_cache = transformer.init_decode_cache

@@ -42,8 +42,12 @@ from llm_instance_gateway_tpu.models.transformer import (
     _embed,
     _kv_dequantize,
     _kv_quantize,
+    _layer_lp,
+    _layer_xs,
     _lm_head,
     _mlp,
+    _n_layers,
+    _tallied,
 )
 from llm_instance_gateway_tpu.ops.attention import (
     decode_attention,
@@ -167,14 +171,17 @@ def decode_step_paged(
     offset = positions % block
     quant = "k_scale" in cache
 
+    scanned, stacks = _layer_xs(params["layers"])
+
     def layer_fn(h, xs):
-        lp, ll, *pools = xs
+        lp, ll, layer, *pools = xs
+        lp = _layer_lp(lp, stacks, layer)
         layer_lora = None if ll is None else {**ll, "scale": lora_bufs["scale"]}
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         hd = cfg.resolved_head_dim
-        q = _attn_proj(lp, "q", hn, layer_lora, slot_ids).reshape(b, cfg.n_heads, hd)
-        k = _attn_proj(lp, "k", hn, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
-        v = _attn_proj(lp, "v", hn, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
+        q = _attn_proj(cfg, lp, "q", hn, layer_lora, slot_ids).reshape(b, cfg.n_heads, hd)
+        k = _attn_proj(cfg, lp, "k", hn, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
+        v = _attn_proj(cfg, lp, "v", hn, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
         q = apply_rope(q[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
         k = apply_rope(k[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
         pools = _pool_update(tuple(pools), k, v, phys_block, offset)
@@ -198,20 +205,21 @@ def decode_step_paged(
                     q, *_pool_rows(pools, tables, h.dtype), lengths)
         h = h + _attn_out(lp, attn.reshape(b, -1), layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
-        return h, pools
+        y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=active)
+        return h + y, (pools, tally)
 
-    xs = (params["layers"], per_layer_lora, cache["k"], cache["v"])
+    xs = (scanned, per_layer_lora, jnp.arange(_n_layers(params)),
+          cache["k"], cache["v"])
     if quant:
         xs = xs + (cache["k_scale"], cache["v_scale"])
-    h, carry = jax.lax.scan(layer_fn, h, xs)
+    h, (carry, tally) = jax.lax.scan(layer_fn, h, xs)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     logits = _lm_head(cfg, params, h)
     new_cache = {"k": carry[0], "v": carry[1], "tables": tables,
                  "length": lengths}
     if quant:
         new_cache["k_scale"], new_cache["v_scale"] = carry[2], carry[3]
-    return logits, new_cache
+    return logits, _tallied(cache, new_cache, tally)
 
 
 def extend_step_paged(
@@ -254,6 +262,7 @@ def extend_step_paged(
     offset = positions % block
 
     h = _embed(cfg, params, tokens)  # [B, C, D]
+    live = None if active is None else jnp.broadcast_to(active[:, None], (b, c))
 
     per_layer_lora = None
     if lora_bufs is not None:
@@ -261,15 +270,18 @@ def extend_step_paged(
 
     quant = "k_scale" in cache
 
+    scanned, stacks = _layer_xs(params["layers"])
+
     def layer_fn(h, xs):
-        lp, ll, *pools = xs
+        lp, ll, layer, *pools = xs
+        lp = _layer_lp(lp, stacks, layer)
         layer_lora = None if ll is None else {**ll, "scale": lora_bufs["scale"]}
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        q = _attn_proj(lp, "q", hn, layer_lora, slot_ids).reshape(
+        q = _attn_proj(cfg, lp, "q", hn, layer_lora, slot_ids).reshape(
             b, c, cfg.n_heads, hd)
-        k = _attn_proj(lp, "k", hn, layer_lora, slot_ids).reshape(
+        k = _attn_proj(cfg, lp, "k", hn, layer_lora, slot_ids).reshape(
             b, c, cfg.n_kv_heads, hd)
-        v = _attn_proj(lp, "v", hn, layer_lora, slot_ids).reshape(
+        v = _attn_proj(cfg, lp, "v", hn, layer_lora, slot_ids).reshape(
             b, c, cfg.n_kv_heads, hd)
         q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
         k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
@@ -290,20 +302,21 @@ def extend_step_paged(
                 "bkgij,bjkh->bikgh", probs, v_rows).reshape(b, c, -1)
         h = h + _attn_out(lp, attn, layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
-        return h, pools
+        y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live)
+        return h + y, (pools, tally)
 
-    xs = (params["layers"], per_layer_lora, cache["k"], cache["v"])
+    xs = (scanned, per_layer_lora, jnp.arange(_n_layers(params)),
+          cache["k"], cache["v"])
     if quant:
         xs = xs + (cache["k_scale"], cache["v_scale"])
-    h, carry = jax.lax.scan(layer_fn, h, xs)
+    h, (carry, tally) = jax.lax.scan(layer_fn, h, xs)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     logits = _lm_head(cfg, params, h)
     new_cache = {"k": carry[0], "v": carry[1], "tables": tables,
                  "length": positions[:, -1] + 1}
     if quant:
         new_cache["k_scale"], new_cache["v_scale"] = carry[2], carry[3]
-    return logits, new_cache
+    return logits, _tallied(cache, new_cache, tally)
 
 
 @jax.named_scope("kv.insert")
@@ -401,16 +414,20 @@ def prefill_with_cache_paged(
 
     h = _embed(cfg, params, tokens)[None]
     pos2d = positions[None]
+    live = (jnp.arange(c) <= last_index)[None]  # the final chunk's padding
 
     quant = "k_scale" in cache
 
+    scanned, stacks = _layer_xs(params["layers"])
+
     def layer_fn(h, xs):
-        lp, ll, *pools = xs
+        lp, ll, layer, *pools = xs
+        lp = _layer_lp(lp, stacks, layer)
         layer_lora = None if ll is None else {**ll, "scale": lora_bufs["scale"]}
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        q = _attn_proj(lp, "q", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_heads, hd)
-        k = _attn_proj(lp, "k", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
-        v = _attn_proj(lp, "v", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
+        q = _attn_proj(cfg, lp, "q", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_heads, hd)
+        k = _attn_proj(cfg, lp, "k", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
+        v = _attn_proj(cfg, lp, "v", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
         q = apply_rope(q, pos2d, cfg.rope_theta, cfg.rope_scaling)
         k = apply_rope(k, pos2d, cfg.rope_theta, cfg.rope_scaling)
         pools = _pool_update(tuple(pools), k[0], v[0], phys_block, offset)
@@ -421,13 +438,14 @@ def prefill_with_cache_paged(
         attn = _chunk_attend(cfg, quant, q, lane_k, lane_v, positions[0])
         h = h + _attn_out(lp, attn, layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
-        return h, pools
+        y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live)
+        return h + y, (pools, tally)
 
-    xs = (params["layers"], per_layer_lora, cache["k"], cache["v"])
+    xs = (scanned, per_layer_lora, jnp.arange(_n_layers(params)),
+          cache["k"], cache["v"])
     if quant:
         xs = xs + (cache["k_scale"], cache["v_scale"])
-    h, carry = jax.lax.scan(layer_fn, h, xs)
+    h, (carry, tally) = jax.lax.scan(layer_fn, h, xs)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     last_h = jax.lax.dynamic_index_in_dim(h[0], last_index, 0, keepdims=False)
     last_logits = _lm_head(cfg, params, last_h)
@@ -436,4 +454,4 @@ def prefill_with_cache_paged(
                  "length": length_vec}
     if quant:
         new_cache["k_scale"], new_cache["v_scale"] = carry[2], carry[3]
-    return last_logits, new_cache
+    return last_logits, _tallied(cache, new_cache, tally)

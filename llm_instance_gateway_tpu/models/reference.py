@@ -1,0 +1,151 @@
+"""The plain reference forward: the architecture's equations in ``jax.numpy``
+and float32, nothing else.  What the serving path is checked against
+(``tests/test_reference_parity.py`` at tiny size on the CPU;
+``benchmark/reference_check.py`` at the published widths on the chip).
+
+No cache, no kernels, no batching, no scan: one sequence, a Python loop over
+layers, every expert computed for every token and mixed by the gate rule.
+It imports nothing from ``transformer.py``, ``ops/`` or ``lora.py``, so a
+fault there cannot hide in both.  It runs under
+``jax.default_matmul_precision("highest")``: on a TPU a float32 matmul is
+computed in bf16 passes otherwise.
+
+The equations, for a pre-norm decoder block on ``x`` [S, D] at positions
+0..S-1 (``RMSNorm_w(z) = z / sqrt(mean(z^2) + eps) * w``):
+
+    x_n = RMSNorm(x)
+    q = x_n Wq (+ bq),  k = x_n Wk (+ bk),  v = x_n Wv (+ bv)      Qwen2: biases
+    q = RMSNorm_q(q),   k = RMSNorm_k(k)    over the WHOLE vector  OLMoE: QK-norm
+    q, k = RoPE(q), RoPE(k)     per head, rotate-half, full head width, theta
+    a = softmax(q k^T / sqrt(hd) + causal mask) v     kv head = q head // group
+    x = x + a Wo
+    h_n = RMSNorm(x)
+    dense:   x = x + (silu(h_n Wg) * (h_n Wu)) Wd
+    sparse:  p = softmax(h_n Wr) over all E, in float32; the k largest p are
+             the experts; weights p_i as they are (OLMoE, norm_topk_prob
+             false) or p_i / sum of the chosen (Mixtral);
+             x = x + sum_i w_i * (silu(h_n Wg_i) * (h_n Wu_i)) Wd_i
+    logits = RMSNorm(x) W_head
+
+A LoRA adapter adds ``scale * (z A) B`` to a projection of ``z``.
+
+Departures from the published descriptions, each on purpose:
+
+- one layer's weights at a time: ``params`` is the program's stacked tree,
+  and a layer is sliced (and an int8 leaf dequantised) inside the loop, so
+  the float32 copy of a 7 B model never exists;
+- an int8 leaf ``{"q", "s"}`` is read as the weight ``q * s`` (per output
+  channel): the reference checks the program's arithmetic on the weights it
+  serves, not the quantisation's distance from some bf16 original;
+- every expert is computed for every token and the unchosen get weight 0,
+  instead of a dispatch: the same sum.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+F32 = jnp.float32
+
+
+def _weight(leaf, layer: int | None = None):
+    """A leaf of the program's tree as a float32 matrix: one layer of a
+    stacked leaf, an int8 ``{"q", "s"}`` pair dequantised."""
+    if isinstance(leaf, dict):
+        q, s = leaf["q"], leaf["s"]
+        if layer is not None:
+            q, s = q[layer], s[layer]
+        return q.astype(F32) * s.astype(F32)[..., None, :]
+    return (leaf if layer is None else leaf[layer]).astype(F32)
+
+
+def _rms_norm(z, w, eps):
+    return z * jax.lax.rsqrt(jnp.mean(z * z, axis=-1, keepdims=True) + eps) * w
+
+
+def _rope(z, theta):
+    """z [S, heads, hd] at positions 0..S-1: rotate-half over the full head."""
+    s, _, hd = z.shape
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd))
+    ang = jnp.arange(s, dtype=F32)[:, None] * inv  # [S, hd/2]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    z1, z2 = z[..., : hd // 2], z[..., hd // 2:]
+    return jnp.concatenate([z1 * cos - z2 * sin, z2 * cos + z1 * sin], axis=-1)
+
+
+def _lora(z, lora, layer, target):
+    if lora is None:
+        return 0.0
+    bufs, slot = lora
+    a = bufs[f"{target}_a"][layer, slot].astype(F32)
+    b = bufs[f"{target}_b"][layer, slot].astype(F32)
+    return bufs["scale"][slot].astype(F32) * ((z @ a) @ b)
+
+
+def _attention(cfg, lp, layer, x_n, lora):
+    s = x_n.shape[0]
+    hd = cfg.head_dim or cfg.d_model // cfg.n_heads
+    proj = {}
+    for t in ("q", "k", "v"):
+        z = x_n @ _weight(lp[f"w{t}"], layer) + _lora(x_n, lora, layer, t)
+        if cfg.attention_bias:
+            z = z + lp[f"w{t}_b"][layer].astype(F32)
+        if cfg.qk_norm and t != "v":
+            z = _rms_norm(z, lp[f"{t}_norm"][layer].astype(F32), cfg.norm_eps)
+        proj[t] = z
+    q = _rope(proj["q"].reshape(s, cfg.n_heads, hd), cfg.rope_theta)
+    k = _rope(proj["k"].reshape(s, cfg.n_kv_heads, hd), cfg.rope_theta)
+    v = proj["v"].reshape(s, cfg.n_kv_heads, hd)
+    group = cfg.n_heads // cfg.n_kv_heads
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    scores = jnp.einsum("ihd,jhd->hij", q, k) / jnp.sqrt(F32(hd))
+    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+    probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
+    a = jnp.einsum("hij,jhd->ihd", probs, v).reshape(s, -1)
+    return a @ _weight(lp["wo"], layer) + _lora(a, lora, layer, "o")
+
+
+def _gated(z, wg, wu, wd):
+    return (jax.nn.silu(z @ wg) * (z @ wu)) @ wd
+
+
+def _mlp(cfg, lp, layer, h_n, lora):
+    if not cfg.n_experts:
+        gate = h_n @ _weight(lp["w_gate"], layer) + _lora(h_n, lora, layer, "gate")
+        up = h_n @ _weight(lp["w_up"], layer) + _lora(h_n, lora, layer, "up")
+        act = jax.nn.silu(gate) * up
+        return act @ _weight(lp["w_down"], layer) + _lora(act, lora, layer, "down")
+    p = jax.nn.softmax(h_n @ lp["router"][layer].astype(F32), axis=-1)  # [S, E]
+    kth = jnp.sort(p, axis=-1)[:, -cfg.n_experts_per_token][:, None]
+    w = jnp.where(p >= kth, p, 0.0)
+    if cfg.norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    wg, wu, wd = (_weight(lp[n], layer) for n in ("w_gate", "w_up", "w_down"))
+    y = jnp.zeros_like(h_n)
+    for e in range(cfg.n_experts):
+        y = y + w[:, e: e + 1] * _gated(h_n, wg[e], wu[e], wd[e])
+    return y
+
+
+def forward(cfg, params, tokens, lora=None):
+    """Logits [S, V] (float32) of one sequence ``tokens`` [S] at positions
+    0..S-1.  ``params``: the program's tree (``transformer.init_params``
+    layout; int8 leaves allowed).  ``lora``: None, or ``(buffers, slot)``,
+    the serving LoRA buffers and the slot whose adapter this sequence uses.
+    """
+    if (cfg.tie_embeddings or cfg.embedding_scale or cfg.norm_plus_one
+            or cfg.gelu_mlp or cfg.rope_scaling_factor):
+        raise NotImplementedError(
+            "the reference covers the Llama/Qwen2/Mixtral/OLMoE block; the "
+            f"Gemma conventions and rope scaling of {cfg.name} are not in it")
+    lp = params["layers"]
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"][tokens].astype(F32)
+        for layer in range(cfg.n_layers):
+            x_n = _rms_norm(x, lp["attn_norm"][layer].astype(F32), cfg.norm_eps)
+            x = x + _attention(cfg, lp, layer, x_n, lora)
+            h_n = _rms_norm(x, lp["mlp_norm"][layer].astype(F32), cfg.norm_eps)
+            x = x + _mlp(cfg, lp, layer, h_n, lora)
+        x = _rms_norm(x, params["final_norm"].astype(F32), cfg.norm_eps)
+        return x @ _weight(params["lm_head"])

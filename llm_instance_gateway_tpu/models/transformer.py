@@ -1,9 +1,9 @@
-"""Core decoder-only transformer: one implementation, three families.
+"""Core decoder-only transformer: one implementation, five families.
 
 Covers Llama-3 (RoPE+GQA+SwiGLU), Gemma (tied embeddings, sqrt(d) embedding
-scale, GeLU gate, (1+w) RMSNorm, shared KV head) and Mixtral (top-k MoE MLP)
-via ``ModelConfig`` flags — the families the pool configs in BASELINE.json
-serve.
+scale, GeLU gate, (1+w) RMSNorm, shared KV head), Qwen2 (QKV bias), Mixtral
+(top-k MoE MLP) and OLMoE (QK-norm, 64 experts top-8, gates not
+renormalised) via ``ModelConfig`` flags.
 
 TPU-first structure:
 - Parameters are stacked over layers (``[n_layers, ...]`` leaves) and the
@@ -18,7 +18,7 @@ TPU-first structure:
   slot ids, so one decode batch multiplexes adapters + base model.
 - Every block sits in a ``jax.named_scope`` (embed, attn.qkv, attn.rope,
   attn.kv_update, attn.core, attn.out, mlp, moe.route / .dispatch /
-  .experts / .fallback, lora, lm_head, kv.insert): the scope is in each
+  .experts, lora, lm_head, kv.insert): the scope is in each
   compiled operation's name, so a device trace says which line of this file
   an operation belongs to.
 """
@@ -40,12 +40,10 @@ from llm_instance_gateway_tpu.ops.attention import (
     xla_chunk_attention,
 )
 from llm_instance_gateway_tpu.ops.layers import apply_rope, rms_norm, swiglu
+from llm_instance_gateway_tpu.ops import pallas_moe
 from llm_instance_gateway_tpu.ops.quant import (
     QUANT_TARGETS,
     constrain,
-    expert_matmul,
-    expert_mix,
-    expert_mix_down,
     matmul as q_matmul,
     quantize_weight,
     static_sharding,
@@ -131,6 +129,10 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
                                (n_l, cfg.n_kv_heads * hd))
         layers["wv_b"] = const(layer_sh, "wv_b", 0,
                                (n_l, cfg.n_kv_heads * hd))
+    if cfg.qk_norm:
+        layers["q_norm"] = const(layer_sh, "q_norm", 1, (n_l, cfg.n_heads * hd))
+        layers["k_norm"] = const(layer_sh, "k_norm", 1,
+                                 (n_l, cfg.n_kv_heads * hd))
     if cfg.n_experts:
         e = cfg.n_experts
         layers["router"] = rand(layer_sh, "router", (n_l, d, e), d)
@@ -208,14 +210,21 @@ def _project(x, w, layer_lora, target, slot_ids):
 
 
 @jax.named_scope("attn.qkv")
-def _attn_proj(lp, target, x, layer_lora, slot_ids):
-    """Q/K/V projection with the optional attention bias (Qwen2-family:
-    ``attention_bias`` adds learned biases to q/k/v only).  The bias keys
-    exist in the layer params iff the config declares them, so bias-free
-    models trace exactly the code they always did."""
+def _attn_proj(cfg: ModelConfig, lp, target, x, layer_lora, slot_ids):
+    """Q/K/V projection: the LoRA delta, the optional attention bias
+    (Qwen2-family, q/k/v only) and the optional QK-norm (OLMoE: RMSNorm
+    over the WHOLE projected q or k vector, before the split into heads).
+    The bias and norm leaves exist in the layer params iff the config
+    declares them, so models without them trace exactly the code they
+    always did."""
     out = _project(x, lp[f"w{target}"], layer_lora, target, slot_ids)
     b = lp.get(f"w{target}_b")
-    return out if b is None else out + b
+    if b is not None:
+        out = out + b
+    norm = lp.get(f"{target}_norm")
+    if norm is not None:
+        out = rms_norm(out, norm, cfg.norm_eps)
+    return out
 
 
 @jax.named_scope("attn.out")
@@ -262,170 +271,146 @@ def _chunk_attend(cfg: ModelConfig, quant: bool, q, lane_k, lane_v, start):
                                start).reshape(1, c, -1)
 
 
-def _mlp(cfg: ModelConfig, lp: Params, x, layer_lora, slot_ids):
+def _mlp(cfg: ModelConfig, lp: Params, x, layer_lora, slot_ids, live=None):
+    """The block's MLP.  Returns ``(y, tally)``: ``tally`` is None for a
+    dense model and the sparse layer's routing counts (``_moe_mlp``) for a
+    sparse one.  ``live`` (bool, ``x``'s leading dims) marks the rows that
+    hold a request; a dense MLP has no use for it."""
     if cfg.n_experts:
-        return _moe_mlp(cfg, lp, x)
+        return _moe_mlp(cfg, lp, x, live)
     with jax.named_scope("mlp"):
         gate = _project(x, lp["w_gate"], layer_lora, "gate", slot_ids)
         up = _project(x, lp["w_up"], layer_lora, "up", slot_ids)
         return _project(swiglu(gate, up, cfg.gelu_mlp), lp["w_down"],
-                        layer_lora, "down", slot_ids)
+                        layer_lora, "down", slot_ids), None
 
 
-def _moe_mlp(cfg: ModelConfig, lp: Params, x):
-    """Top-k mixture-of-experts MLP (Mixtral style).
+# The sparse layer's routing counts, one int32 vector a layer-step:
+# (layer-steps, live assignments, experts with at least one).  They sum over
+# layers and steps; the engine reads them back with the step.
+MOE_TALLY = ("layer_steps", "assignments", "experts_touched")
+_EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
-    Two shape-static strategies, chosen at TRACE time by token count:
 
-    - a batch too small for the capacity tile to beat dense (cap >= T —
-      single-token decode): dense all-experts mix, where the dispatch
-      bookkeeping would be pure overhead and weights (not FLOPs) bound
-      the step anyway;
-    - everything else — batched decode included: GShard-style grouped
-      capacity dispatch (``_moe_grouped``) — per-token FLOPs drop from E
-      to ~k*capacity_factor expert-MLPs, with a dense lax.cond fallback
-      keeping results bit-exact when routing overflows capacity.
+def _moe_mlp(cfg: ModelConfig, lp: Params, x, live=None):
+    """Top-k mixture-of-experts MLP by ONE dropless dispatch, the same code
+    at every E, k and token count (Mixtral 8 top-2, OLMoE 64 top-8; decode
+    batches and 1024-token prefills).
+
+    route: f32 softmax of the router; the k largest are the experts, their
+    weights renormalised over the chosen (``norm_topk_prob``) or taken as
+    the full softmax gives them.  dispatch: each of the ``T*k`` assignments
+    gets a row in a layout grouped by expert, every group padded to whole
+    tiles of ``tm`` rows (``pallas_moe.tile_rows``: from the mean group
+    size, so static), by a one-hot cumsum: no sort, no capacity, nothing
+    dropped, nothing recomputed.  experts: three grouped matmuls, each tile
+    against its own expert's weights (``ops.pallas_moe``); an expert no row
+    chose has no tile and is not read.  ``live`` rows only: a row that
+    holds no request routes nowhere and gets zeros.
+
+    ``lp`` carries either this layer's expert leaves ``[E, in, out]`` or,
+    from the layer loops below, the STACKED leaves ``[L, E, in, out]`` and
+    ``lp["layer"]``: the kernel reads the layer where it lies.
 
     LoRA is not applied to expert weights (matching vLLM, which targets
-    attention + dense MLP only).
-    """
-    t = 1
-    for dim in x.shape[:-1]:
-        t *= dim
-    cap = _moe_capacity(cfg, t)
-    if cap >= t or (cfg.moe_exact_fallback and cap < 8):
-        # Dense all-experts costs t*E expert-rows; grouped costs E*cap.
-        # cap >= t means no FLOP win — and at these token counts decode is
-        # weight-bound anyway (each expert's weights stream from HBM once
-        # either way), so the dispatch bookkeeping would be pure overhead.
-        # Exact mode additionally floors at cap >= 8: a 1-4 row tile is
-        # discreteness-dominated (routine routing collisions overflow it —
-        # the 2x headroom's overflow-rarity argument needs a few rows of
-        # mean load), and every exact-mode overflow pays grouped PLUS
-        # dense, costlier than just staying dense.  Dropping mode keeps
-        # grouped at any tile (overflow drops, the standard serving trade).
-        return _moe_dense(cfg, lp, x)
-    return _moe_grouped(cfg, lp, x)
-
-
-def _moe_capacity(cfg: ModelConfig, t: int) -> int:
-    """Per-expert capacity tile for ``t`` tokens.
-
-    cap ≈ t*k/E * factor.  Dropping mode uses the configured factor as-is
-    (1.25 default — the standard GShard serving trade: a 16-slot Mixtral
-    decode computes ~1.25x the dropless-ideal t*k expert-rows); EXACT mode
-    takes at least 2.0x at every size, because its overflow fallback pays
-    grouped PLUS dense for the batch and a tight tile overflows on routine
-    router imbalance (still ~2x better than the dense path it falls back
-    to).  Small tiles keep the exact ceiling — rounding 5 up to 8 would
-    re-inflate the small-batch win; large tiles round up to a multiple of
-    8 (MXU sublane alignment).
-    """
-    e, k = cfg.n_experts, cfg.n_experts_per_token
-    f = cfg.moe_capacity_factor
-    if cfg.moe_exact_fallback:
-        # The overflow fallback pays grouped PLUS dense (expert weights
-        # streamed twice), so it must stay rare at EVERY tile size — a
-        # 16-slot decode tile at 1.25x mean load would overflow on most
-        # batches.  2.0x puts overflow ~2.7 sigma out under uniform
-        # routing; dropping mode uses the configured factor as-is.
-        f = max(f, 2.0)
-    cap = int(-(-t * k * f // e))
-    if cap >= 16:
-        cap = (cap + 7) // 8 * 8
-    return min(t, cap)
-
-
-def _moe_dense(cfg: ModelConfig, lp: Params, x):
-    """Compute every expert; mix by renormalized top-k gates."""
-    with jax.named_scope("moe.route"):
-        router_logits = (x @ lp["router"]).astype(jnp.float32)  # [..., E]
-        e = cfg.n_experts
-        topv, topi = jax.lax.top_k(router_logits, cfg.n_experts_per_token)
-        # Renormalize over the selected experts.
-        gates = jax.nn.softmax(topv, axis=-1)
-        # Scatter gate weights back to a dense [..., E] mix vector.
-        dense_gates = jnp.sum(
-            jax.nn.one_hot(topi, e, dtype=jnp.float32) * gates[..., None],
-            axis=-2,
-        )  # [..., E]
-    with jax.named_scope("moe.experts"):
-        hidden = expert_mix(x, lp["w_gate"])
-        up = expert_mix(x, lp["w_up"])
-        act = swiglu(hidden, up, cfg.gelu_mlp)
-        per_expert = expert_mix_down(act, lp["w_down"])
-        return jnp.einsum("...ed,...e->...d", per_expert,
-                          dense_gates.astype(x.dtype))
-
-
-def _moe_grouped(cfg: ModelConfig, lp: Params, x):
-    """Grouped capacity dispatch: route tokens TO experts instead of running
-    every expert over every token.
-
-    Each token's k assignments scatter-add into per-expert capacity tiles
-    ([E, C, D], O(T*k*D) data movement — NOT a [T,k,E,C] one-hot einsum,
-    whose T*k*E*C*D cost would swamp the savings); three batched einsums
-    run each expert's MLP over its C-row tile (MXU-shaped, shardable over
-    the ``expert`` mesh axis); a gather + gate-weighted sum combines
-    results.  Expert capacity C ≈ T*k/E * capacity_factor (``_moe_capacity``
-    — exact ceiling for small tiles, multiple of 8 with exact-mode headroom
-    for large ones): expert FLOPs scale with assignments made, not
-    experts*tokens —
-    the E/k inflation of the dense path is gone.  If any expert overflows
-    C, ``moe_exact_fallback`` recomputes the batch densely inside lax.cond
-    (exactness over speed for that batch).
+    attention + dense MLP only).  Returns ``(y, tally)``, ``MOE_TALLY``.
     """
     orig_shape = x.shape
     d = orig_shape[-1]
     xf = x.reshape(-1, d)
     t = xf.shape[0]
     e, k = cfg.n_experts, cfg.n_experts_per_token
+    tm = pallas_moe.tile_rows(t * k, e)
+    n_tiles = pallas_moe.n_tiles(t * k, e, tm)
+    n_rows = n_tiles * tm
+
+    stacks = {name: lp[name] for name in _EXPERT_STACKS}
+    layer = lp.get("layer")
+    if layer is None:  # one layer's leaves: a stack of one
+        stacks, layer = jax.tree.map(lambda a: a[None], stacks), 0
 
     with jax.named_scope("moe.route"):
-        router_logits = (xf @ lp["router"]).astype(jnp.float32)  # [T, E]
+        router_logits = jnp.dot(xf, lp["router"],
+                                preferred_element_type=jnp.float32)  # [T, E]
         topv, topi = jax.lax.top_k(router_logits, k)
-        gates = jax.nn.softmax(topv, axis=-1)  # [T, k]
-
-    cap = _moe_capacity(cfg, t)
+        if cfg.norm_topk_prob:
+            gates = jax.nn.softmax(topv, axis=-1)  # [T, k]
+        else:
+            gates = jnp.exp(topv - jax.nn.logsumexp(
+                router_logits, axis=-1, keepdims=True))
 
     with jax.named_scope("moe.dispatch"):
-        flat_expert = topi.reshape(-1)  # [T*k]
-        flat_assign = jax.nn.one_hot(flat_expert, e, dtype=jnp.int32)
-        # Position of each assignment within its expert's capacity tile.
-        pos = jnp.sum(
-            (jnp.cumsum(flat_assign, axis=0) - 1) * flat_assign, axis=-1)
-        kept = pos < cap  # [T*k]
-        # Overflowed assignments clip onto the last tile row with a zeroed
-        # contribution — collisions there add 0, and the combine gather
-        # masks them out the same way.
-        flat_idx = flat_expert * cap + jnp.clip(pos, 0, cap - 1)  # [T*k]
-        keep_col = kept[:, None].astype(xf.dtype)
+        expert = topi.reshape(-1)  # [T*k], token-major
+        if live is not None:
+            expert = jnp.where(jnp.repeat(live.reshape(-1), k), expert, e)
+        chose = (expert[:, None] == jnp.arange(e)).astype(jnp.int32)  # [T*k, E]
+        sizes = jnp.sum(chose, axis=0)  # [E] rows of each group
+        # Place of an assignment in its group: assignments before it there.
+        rank = jnp.sum((jnp.cumsum(chose, axis=0) - 1) * chose, axis=-1)
+        first_row, tile_expert, n_used = pallas_moe.tile_plan(
+            sizes, tm, n_tiles)
+        # A dead row's address is out of bounds: its scatter is dropped and
+        # its gather reads zeros.
+        row = jnp.where(expert < e,
+                        first_row[jnp.minimum(expert, e - 1)] + rank, n_rows)
+        x_e = jnp.zeros((n_rows, d), xf.dtype).at[row].set(
+            jnp.repeat(xf, k, axis=0), mode="drop")
+        tally = jnp.stack([jnp.ones((), jnp.int32), jnp.sum(sizes),
+                           jnp.sum(sizes > 0)])
 
-        xk = jnp.repeat(xf, k, axis=0)  # [T*k, D] (token order: topi's)
-        x_e = (
-            jnp.zeros((e * cap, d), xf.dtype)
-            .at[flat_idx].add(xk * keep_col)
-            .reshape(e, cap, d)
-        )
     with jax.named_scope("moe.experts"):
-        hidden = expert_matmul(x_e, lp["w_gate"])
-        up = expert_matmul(x_e, lp["w_up"])
-        act = swiglu(hidden, up, cfg.gelu_mlp)
-        out_e = expert_matmul(act, lp["w_down"])
-    with jax.named_scope("moe.dispatch"):  # the way back: gather + mix
-        gathered = out_e.reshape(e * cap, d)[flat_idx] * keep_col  # [T*k, D]
-        y = jnp.sum(
-            gathered.reshape(t, k, d) * gates.astype(xf.dtype)[..., None],
-            axis=1,
-        )
+        gmm = functools.partial(
+            pallas_moe.grouped_matmul, tile_expert=tile_expert, n_used=n_used,
+            layer=layer, tm=tm, use_kernel=cfg.use_pallas_decode)
+        act = swiglu(gmm(x_e, stacks["w_gate"]), gmm(x_e, stacks["w_up"]),
+                     cfg.gelu_mlp)
+        out_e = gmm(act, stacks["w_down"])  # [n_rows, D]
 
-    if cfg.moe_exact_fallback:
-        with jax.named_scope("moe.fallback"):
-            overflow = jnp.any(~kept)
-            y = jax.lax.cond(
-                overflow, lambda op: _moe_dense(cfg, lp, op), lambda _: y, xf
-            )
-    return y.reshape(orig_shape)
+    with jax.named_scope("moe.dispatch"):  # the way back: gather + mix
+        got = out_e.at[row].get(mode="fill", fill_value=0)  # [T*k, D]
+        y = jnp.sum(got.reshape(t, k, d).astype(jnp.float32)
+                    * gates[..., None], axis=1).astype(xf.dtype)
+    return y.reshape(orig_shape), tally
+
+
+def _layer_xs(layers: Params) -> tuple[Params, Params | None]:
+    """A layer loop's share of the stacked layer params: (the leaves the
+    loop scans, the expert stacks it does not).  A sparse model's expert
+    stacks stay whole and ride into the loop's body beside the layer index
+    (``_layer_lp``): scanned, each layer's 3 x [E, in, out] slice is copied
+    out for the kernel on every step (device trace, PR 25)."""
+    if "router" not in layers:
+        return layers, None
+    return ({n: w for n, w in layers.items() if n not in _EXPERT_STACKS},
+            {n: layers[n] for n in _EXPERT_STACKS})
+
+
+def _layer_lp(lp: Params, stacks: Params | None, layer) -> Params:
+    return lp if stacks is None else {**lp, **stacks, "layer": layer}
+
+
+def _n_layers(params: Params) -> int:
+    return params["layers"]["attn_norm"].shape[0]
+
+
+def with_moe_tally(cfg: ModelConfig, cache: Params) -> Params:
+    """``cache`` asking for a sparse model's routing counts (a zeroed
+    ``"moe"`` leaf the cached programs add to, ``_tallied``); a dense
+    model's cache as it is."""
+    if not cfg.n_experts:
+        return cache
+    return {**cache, "moe": jnp.zeros((len(MOE_TALLY),), jnp.int32)}
+
+
+def _tallied(cache: Params, new_cache: Params, tally) -> Params:
+    """A cache that carries a ``"moe"`` leaf (int32, one per ``MOE_TALLY``)
+    gets the program's routing counts added to it; without the leaf nobody
+    asked, and XLA drops the counting."""
+    if "moe" in cache:
+        new_cache["moe"] = cache["moe"] + (
+            0 if tally is None
+            else jnp.sum(tally.reshape(-1, len(MOE_TALLY)), axis=0))
+    return new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -441,8 +426,10 @@ def prefill_layer(
     layer_lora: Params | None = None,
     slot_ids: jax.Array | None = None,  # [B] int32, -1 = base model
     attention_fn=None,
+    live: jax.Array | None = None,  # [B, S] bool — positions of the prompt
 ):
-    """One decoder block over a full sequence.  Returns (h, (k, v)).
+    """One decoder block over a full sequence.  Returns (h, (k, v, tally)),
+    ``tally`` the sparse layer's routing counts (None for a dense model).
 
     The single source of truth for the prefill block: ``prefill`` scans it
     over the stacked layer params, and ``parallel.pipeline`` scans each
@@ -453,9 +440,9 @@ def prefill_layer(
         slot_ids = jnp.full((b,), -1, jnp.int32)
     hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     hd = cfg.resolved_head_dim
-    q = _attn_proj(lp, "q", hn, layer_lora, slot_ids).reshape(b, s, cfg.n_heads, hd)
-    k = _attn_proj(lp, "k", hn, layer_lora, slot_ids).reshape(b, s, cfg.n_kv_heads, hd)
-    v = _attn_proj(lp, "v", hn, layer_lora, slot_ids).reshape(b, s, cfg.n_kv_heads, hd)
+    q = _attn_proj(cfg, lp, "q", hn, layer_lora, slot_ids).reshape(b, s, cfg.n_heads, hd)
+    k = _attn_proj(cfg, lp, "k", hn, layer_lora, slot_ids).reshape(b, s, cfg.n_kv_heads, hd)
+    v = _attn_proj(cfg, lp, "v", hn, layer_lora, slot_ids).reshape(b, s, cfg.n_kv_heads, hd)
     q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
     k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     with jax.named_scope("attn.core"):
@@ -473,8 +460,8 @@ def prefill_layer(
             attn = prefill_attention(q, k, v, positions)
     h = h + _attn_out(lp, attn.reshape(b, s, -1), layer_lora, slot_ids)
     hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-    h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
-    return h, (k, v)
+    y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live)
+    return h + y, (k, v, tally)
 
 
 def prefill(
@@ -485,8 +472,13 @@ def prefill(
     lora_bufs: Params | None = None,
     slot_ids: jax.Array | None = None,  # [B] int32, -1 = base model
     attention_fn=None,       # override: (q, k, v, positions) -> attn output
+    lengths: jax.Array | None = None,  # [B] int32 — true prompt lengths
+    moe_tally: bool = False,
 ):
-    """Full-prompt forward.  Returns (logits [B,S,V] f32, k [L,B,S,K,hd], v).
+    """Full-prompt forward.  Returns (logits [B,S,V] f32, k [L,B,S,K,hd], v),
+    and with ``moe_tally`` a sparse model's routing counts (``MOE_TALLY``,
+    summed over layers) as a fourth.  With ``lengths`` the padding past a
+    prompt's end routes to no expert (its outputs are garbage either way).
 
     ``attention_fn`` swaps the attention implementation — used by
     ``parallel.long_context`` to run ring attention over a sequence-sharded
@@ -501,18 +493,25 @@ def prefill(
     if lora_bufs is not None:
         per_layer_lora, bcast = lora_lib.stack_for_scan(lora_bufs)
 
+    scanned, stacks = _layer_xs(params["layers"])
+    live = (None if lengths is None
+            else jnp.arange(s)[None] < jnp.reshape(lengths, (-1, 1)))
+
     def layer_fn(h, xs):
-        lp, ll = xs
+        lp, ll, layer = xs
         layer_lora = None if ll is None else {**ll, "scale": lora_bufs["scale"]}
         return prefill_layer(
-            cfg, lp, h, positions, layer_lora=layer_lora, slot_ids=slot_ids,
-            attention_fn=attention_fn,
+            cfg, _layer_lp(lp, stacks, layer), h, positions,
+            layer_lora=layer_lora, slot_ids=slot_ids,
+            attention_fn=attention_fn, live=live,
         )
 
-    xs = (params["layers"], per_layer_lora)
-    h, (k_all, v_all) = jax.lax.scan(layer_fn, h, xs)
+    xs = (scanned, per_layer_lora, jnp.arange(_n_layers(params)))
+    h, (k_all, v_all, tally) = jax.lax.scan(layer_fn, h, xs)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     logits = _lm_head(cfg, params, h)
+    if moe_tally:
+        return logits, k_all, v_all, jnp.sum(tally, axis=0)
     return logits, k_all, v_all
 
 
@@ -541,8 +540,11 @@ def _kv_carry(cache: Params) -> tuple:
     return kv
 
 
-def _cache_of(kv: tuple, length: jax.Array) -> Params:
-    return dict(zip(("k", "v", "k_scale", "v_scale"), kv), length=length)
+def _cache_of(cache: Params, kv: tuple, length: jax.Array, tally) -> Params:
+    """The cached programs' new cache: the carry's arrays, the new lengths,
+    and the routing counts if the old cache asked for them."""
+    new = dict(zip(("k", "v", "k_scale", "v_scale"), kv), length=length)
+    return _tallied(cache, new, tally)
 
 
 @jax.named_scope("attn.kv_update")
@@ -579,19 +581,23 @@ def _scan_cached_layers(params: Params, cache: Params,
                         lora_bufs: Params | None, h: jax.Array, layer_fn):
     """The cached programs' layer loop.  xs: the stacked layer params, the
     LoRA stack and the layer index; carry: the activations and the stacked
-    cache.  ``layer_fn(h, kv, layer, lp, layer_lora) -> (h, kv)``."""
+    cache.  ``layer_fn(h, kv, layer, lp, layer_lora) -> (h, kv, tally)``;
+    returns (h, kv, the layers' tallies stacked or None)."""
     per_layer_lora = None
     if lora_bufs is not None:
         per_layer_lora, _ = lora_lib.stack_for_scan(lora_bufs)
+    scanned, stacks = _layer_xs(params["layers"])
 
     def body(carry, xs):
         lp, ll, layer = xs
         layer_lora = None if ll is None else {**ll, "scale": lora_bufs["scale"]}
-        return layer_fn(*carry, layer, lp, layer_lora), None
+        h, kv, tally = layer_fn(*carry, layer, _layer_lp(lp, stacks, layer),
+                                layer_lora)
+        return (h, kv), tally
 
-    xs = (params["layers"], per_layer_lora, jnp.arange(cache["k"].shape[0]))
-    (h, kv), _ = jax.lax.scan(body, (h, _kv_carry(cache)), xs)
-    return h, kv
+    xs = (scanned, per_layer_lora, jnp.arange(cache["k"].shape[0]))
+    (h, kv), tally = jax.lax.scan(body, (h, _kv_carry(cache)), xs)
+    return h, kv, tally
 
 
 @jax.named_scope("attn.core")
@@ -663,22 +669,22 @@ def decode_step(
 
     def layer_fn(h, kv, layer, lp, layer_lora):
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        q = _attn_proj(lp, "q", hn, layer_lora, slot_ids).reshape(b, cfg.n_heads, hd)
-        k = _attn_proj(lp, "k", hn, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
-        v = _attn_proj(lp, "v", hn, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
+        q = _attn_proj(cfg, lp, "q", hn, layer_lora, slot_ids).reshape(b, cfg.n_heads, hd)
+        k = _attn_proj(cfg, lp, "k", hn, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
+        v = _attn_proj(cfg, lp, "v", hn, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
         q = apply_rope(q[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
         k = apply_rope(k[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
         kv = _write_kv(kv, (layer, batch_idx, write_pos), k, v)
         attn = _decode_attend(cfg, attention_fn, q, kv, layer, lengths)
         h = h + _attn_out(lp, attn.reshape(b, -1), layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
-        return h, kv
+        y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=active)
+        return h + y, kv, tally
 
-    h, kv = _scan_cached_layers(params, cache, lora_bufs, h, layer_fn)
+    h, kv, tally = _scan_cached_layers(params, cache, lora_bufs, h, layer_fn)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     logits = _lm_head(cfg, params, h)
-    return logits, _cache_of(kv, lengths)
+    return logits, _cache_of(cache, kv, lengths, tally)
 
 
 def extend_step(
@@ -714,14 +720,15 @@ def extend_step(
     batch_idx = jnp.arange(b)[:, None]  # [B, 1] broadcast over C
     write_pos = (positions if active is None
                  else jnp.where(active[:, None], positions, s_max))
+    live = None if active is None else jnp.broadcast_to(active[:, None], (b, c))
 
     def layer_fn(h, kv, layer, lp, layer_lora):
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        q = _attn_proj(lp, "q", hn, layer_lora, slot_ids).reshape(
+        q = _attn_proj(cfg, lp, "q", hn, layer_lora, slot_ids).reshape(
             b, c, cfg.n_heads, hd)
-        k = _attn_proj(lp, "k", hn, layer_lora, slot_ids).reshape(
+        k = _attn_proj(cfg, lp, "k", hn, layer_lora, slot_ids).reshape(
             b, c, cfg.n_kv_heads, hd)
-        v = _attn_proj(lp, "v", hn, layer_lora, slot_ids).reshape(
+        v = _attn_proj(cfg, lp, "v", hn, layer_lora, slot_ids).reshape(
             b, c, cfg.n_kv_heads, hd)
         q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
         k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
@@ -741,13 +748,13 @@ def extend_step(
                 "bkgij,bjkh->bikgh", probs, v_read).reshape(b, c, -1)
         h = h + _attn_out(lp, attn, layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
-        return h, kv
+        y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live)
+        return h + y, kv, tally
 
-    h, kv = _scan_cached_layers(params, cache, lora_bufs, h, layer_fn)
+    h, kv, tally = _scan_cached_layers(params, cache, lora_bufs, h, layer_fn)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     logits = _lm_head(cfg, params, h)
-    return logits, _cache_of(kv, positions[:, -1] + 1)
+    return logits, _cache_of(cache, kv, positions[:, -1] + 1, tally)
 
 
 def prefill_with_cache(
@@ -784,12 +791,13 @@ def prefill_with_cache(
     h = _embed(cfg, params, tokens)[None]  # [1, C, D]
     pos2d = positions[None]  # [1, C]
     quant = "k_scale" in cache
+    live = (jnp.arange(c) <= last_index)[None]  # the final chunk's padding
 
     def layer_fn(h, kv, layer, lp, layer_lora):
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        q = _attn_proj(lp, "q", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_heads, hd)
-        k = _attn_proj(lp, "k", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
-        v = _attn_proj(lp, "v", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
+        q = _attn_proj(cfg, lp, "q", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_heads, hd)
+        k = _attn_proj(cfg, lp, "k", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
+        v = _attn_proj(cfg, lp, "v", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
         q = apply_rope(q, pos2d, cfg.rope_theta, cfg.rope_scaling)
         k = apply_rope(k, pos2d, cfg.rope_theta, cfg.rope_scaling)
         # Scatter the chunk's K/V into the slot's lane at absolute positions.
@@ -805,14 +813,15 @@ def prefill_with_cache(
         attn = _chunk_attend(cfg, quant, q, lane_k, lane_v, positions[0])
         h = h + _attn_out(lp, attn, layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        h = h + _mlp(cfg, lp, hn2, layer_lora, slot_ids)
-        return h, kv
+        y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live)
+        return h + y, kv, tally
 
-    h, kv = _scan_cached_layers(params, cache, lora_bufs, h, layer_fn)
+    h, kv, tally = _scan_cached_layers(params, cache, lora_bufs, h, layer_fn)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     last_h = jax.lax.dynamic_index_in_dim(h[0], last_index, 0, keepdims=False)
     last_logits = _lm_head(cfg, params, last_h)
-    return last_logits, _cache_of(kv, cache["length"].at[slot].set(lane_end))
+    return last_logits, _cache_of(
+        cache, kv, cache["length"].at[slot].set(lane_end), tally)
 
 
 @jax.named_scope("kv.insert")

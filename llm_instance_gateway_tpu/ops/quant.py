@@ -50,39 +50,6 @@ def matmul(x: jax.Array, w: Any) -> jax.Array:
     return x @ w
 
 
-def _expert_einsum(spec: str, scale_expand: int | None, x, w):
-    """One dequant-einsum for every expert-weight contraction: the
-    per-output-channel scale applies after the contraction (exact — the
-    scaled axis is never contracted), broadcast to the output rank by
-    expanding at ``scale_expand`` when the output carries a capacity axis
-    between the expert and channel axes."""
-    if is_quantized(w):
-        y = jnp.einsum(spec, x, w["q"].astype(x.dtype))
-        s = w["s"].astype(x.dtype)
-        if scale_expand is not None:
-            s = jnp.expand_dims(s, scale_expand)
-        return y * s
-    return jnp.einsum(spec, x, w)
-
-
-def expert_matmul(x: jax.Array, w: Any) -> jax.Array:
-    """Per-expert tile matmul: [E, C, din] x [E, din, dout] -> [E, C, dout]
-    (both grouped-dispatch einsums are this shape, up and down)."""
-    return _expert_einsum("ecd,edf->ecf", -2, x, w)  # s [E, out] -> [E,1,out]
-
-
-def expert_mix(x: jax.Array, w: Any) -> jax.Array:
-    """Dense all-experts up-projection: [..., din] x [E, din, f] ->
-    [..., E, f]."""
-    return _expert_einsum("...d,edf->...ef", None, x, w)
-
-
-def expert_mix_down(x: jax.Array, w: Any) -> jax.Array:
-    """Dense all-experts down-projection: [..., E, f] x [E, f, d] ->
-    [..., E, d] (the e axes align)."""
-    return _expert_einsum("...ef,efd->...ed", None, x, w)
-
-
 def static_sharding(sharding: Any) -> Any:
     """A leaf's sharding (``parallel.sharding.param_shardings``) as a
     HASHABLE jit static argument: a quantized leaf's ``{"q", "s"}`` pair

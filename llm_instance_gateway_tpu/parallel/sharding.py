@@ -42,6 +42,11 @@ def param_specs(cfg: ModelConfig) -> dict[str, Any]:
         layers["wq_b"] = P(None, "tensor")
         layers["wk_b"] = P(None, "tensor")
         layers["wv_b"] = P(None, "tensor")
+    if cfg.qk_norm:
+        # [L, H*hd] norm weights: replicated (the norm reduces over the
+        # whole projected vector, whichever way its columns are sharded).
+        layers["q_norm"] = P(None, None)
+        layers["k_norm"] = P(None, None)
     if cfg.n_experts:
         layers.update(
             {

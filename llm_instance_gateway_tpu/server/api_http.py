@@ -1460,7 +1460,8 @@ class ModelServer:
                 by_device[shard.device.id] += shard.data.nbytes
         cfg = self.engine.model_cfg
         keep = ("name", "vocab_size", "d_model", "n_layers", "n_heads",
-                "n_kv_heads", "d_ff", "attention_bias", "n_experts")
+                "n_kv_heads", "d_ff", "attention_bias", "n_experts",
+                "n_experts_per_token", "norm_topk_prob", "qk_norm")
         return web.json_response({
             "model": self.model_name,
             "platform": devices[0].platform,

@@ -808,13 +808,15 @@ class Engine:
         # chunk-sized program streams the prompt into the cache lane.
         self._jit_chunk = jax.jit(
             _named(
-                "prefill_chunk",
+                "prefill_chunk", self._chunk_impl, model_cfg,
                 paged_lib.prefill_with_cache_paged if self.paged
                 else transformer.prefill_with_cache,
-                model_cfg,
             ),
             donate_argnames=("cache",),
         )
+        # Routing counts of a sparse model's prefill programs, still on the
+        # device: the next decode readback brings them back with its own.
+        self._moe_pending: list = []
 
         def sample_one(logits, key, t, k, p, seed, pos, bias_ids,
                        bias_vals):
@@ -892,9 +894,10 @@ class Engine:
     ):
         """Prefill one padded prompt; sample the first new token."""
         slot_ids = jnp.full((1,), lora_slot, jnp.int32)
-        logits, k, v = transformer.prefill(
+        logits, k, v, *moe = transformer.prefill(
             model_cfg, params, tokens, positions, lora_bufs=lora_bufs,
             slot_ids=slot_ids, attention_fn=attn_fn,
+            lengths=true_len, moe_tally=bool(model_cfg.n_experts),
         )
         last = logits[:, true_len - 1]  # [1, V]
         first_token = sample(
@@ -908,7 +911,8 @@ class Engine:
             bias_ids=bias_ids[None], bias_vals=bias_vals[None],
         )
         lp, top_v, top_i = _logprob_info(last, first_token, model_cfg.vocab_size)
-        return first_token[0], k, v, (lp[0], top_v[0], top_i[0])
+        return (first_token[0], k, v, (lp[0], top_v[0], top_i[0]),
+                moe[0] if moe else None)
 
     @staticmethod
     def _prefill_many_impl(
@@ -918,9 +922,10 @@ class Engine:
         """Prefill P padded same-bucket prompts as one program; sample each
         row's first token (the [P, bucket] generalization of
         ``_prefill_impl`` — per-row lengths, adapters, sampling params)."""
-        logits, k, v = transformer.prefill(
+        logits, k, v, *moe = transformer.prefill(
             model_cfg, params, tokens, positions, lora_bufs=lora_bufs,
             slot_ids=lora_slots, attention_fn=attn_fn,
+            lengths=true_lens, moe_tally=bool(model_cfg.n_experts),
         )
         last = jnp.take_along_axis(
             logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]  # [P, V]
@@ -929,7 +934,15 @@ class Engine:
             seeds=seeds, positions=true_lens - 1,
             bias_ids=bias_ids, bias_vals=bias_vals)
         lp, top_v, top_i = _logprob_info(last, first_tokens, model_cfg.vocab_size)
-        return first_tokens, k, v, (lp, top_v, top_i)
+        return first_tokens, k, v, (lp, top_v, top_i), moe[0] if moe else None
+
+    @staticmethod
+    def _chunk_impl(model_cfg, chunk_fn, params, cache, *args, **kw):
+        """The chunk program; a sparse model's routing counts leave it
+        beside the cache (``transformer._tallied``)."""
+        cache = transformer.with_moe_tally(model_cfg, cache)
+        last_logits, cache = chunk_fn(model_cfg, params, cache, *args, **kw)
+        return last_logits, cache, cache.pop("moe", None)
 
     @staticmethod
     def _decode_impl(
@@ -958,7 +971,10 @@ class Engine:
         merely confirms the match once per dispatch.
 
         Returns (toks [K,B], valid [K,B], logprob triplet, next_tokens,
-        next_positions, next_remaining, next_hist, counts, cache).
+        next_positions, next_remaining, next_hist, counts, cache, moe):
+        ``moe`` the block's routing counts of a sparse model
+        (``transformer.MOE_TALLY``; they ride the cache through the steps),
+        None for a dense one.
         Positions are clamped below max_seq_len so capped slots never write
         out of bounds.
         """
@@ -968,6 +984,7 @@ class Engine:
             max_len = cache["k"].shape[2]
 
         c0 = tokens.shape[0]
+        cache = transformer.with_moe_tally(model_cfg, cache)
 
         def one_step(carry, step_key):
             cache, tokens, positions, remaining, hist, counts = carry
@@ -1023,9 +1040,10 @@ class Engine:
          counts) = carry
         # The token/position/budget/history carries live on device for
         # pipelined dispatch of the following block (no host round-trip).
+        moe = cache.pop("moe", None)
         return (toks, valid, lps, top_v, top_i,
                 next_tokens, next_positions, next_remaining, next_hist,
-                counts, cache)
+                counts, cache, moe)
 
     # ------------------------------------------------------------------
     # public API
@@ -2773,9 +2791,10 @@ class Engine:
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.int32(slot_idx), jnp.int32(lane_end), jnp.int32(c - 1))
         with self._enqueue("engine.prefill.enqueue"):
-            last_logits, self.cache = self._jit_chunk(
+            last_logits, self.cache, moe = self._jit_chunk(
                 *args, lora_bufs=self._lora_buffers(),
                 lora_slot=jnp.int32(lora_slot))
+        self._moe_keep(moe)
         return last_logits
 
     @_in_phase("prefill.stage")
@@ -2809,7 +2828,9 @@ class Engine:
             *map(jnp.asarray, _bias_arrays(sp)),
         )
         with self._enqueue("engine.prefill.enqueue"):
-            return self._jit_prefill(*args)
+            *out, moe = self._jit_prefill(*args)
+        self._moe_keep(moe)
+        return out
 
     @_in_phase("prefill.stage")
     def _bucket_prefill_many(self, reqs, ns, lora_slots):
@@ -2838,7 +2859,26 @@ class Engine:
               for arrs in zip(*(_bias_arrays(sp) for sp in sps))),
         )
         with self._enqueue("engine.prefill.enqueue"):
-            return self._jit_prefill_many(*args)
+            *out, moe = self._jit_prefill_many(*args)
+        self._moe_keep(moe)
+        return out
+
+    def _moe_keep(self, tally) -> None:
+        """Park a program's routing counts (None: a dense model)."""
+        if tally is not None:
+            self._moe_pending.append(tally)
+
+    def _moe_drain(self, tally) -> list:
+        """The routing counts a decode dispatch takes to its readback: its
+        own and those parked since the last one."""
+        self._moe_keep(tally)
+        drained, self._moe_pending = self._moe_pending, []
+        return drained
+
+    def _moe_account(self, fetched: list) -> None:
+        """Book the routing counts a decode readback brought back."""
+        if fetched and self.profiler is not None:
+            self.profiler.note_moe(np.sum(fetched, axis=0))
 
     def _collect_followers(self, first_req, limit: int) -> list:
         """Pull same-bucket followers of ``first_req`` for one batched
@@ -3511,16 +3551,22 @@ class Engine:
         )
         with self._enqueue("engine.decode.enqueue"):
             (step_tokens, step_valid, step_lps, step_top_v, step_top_i,
-             _, _, _, _, counts_out, self.cache) = self._jit_decode(
+             _, _, _, _, counts_out, self.cache, moe) = self._jit_decode(
                 *args, n_steps=n_steps, penalized=penalized)
         if penalized:
             self._dev_counts = counts_out
+        moe = self._moe_drain(moe)
         ph.to("decode.wait")
         outs = jax.block_until_ready(
-            (step_tokens, step_valid, step_lps, step_top_v, step_top_i))
+            (step_tokens, step_valid, step_lps, step_top_v, step_top_i, *moe))
         ph.to("decode.readback")
-        # [n_steps, B] each
-        toks_np, valid_np, lps_np, top_v_np, top_i_np = map(np.asarray, outs)
+        # [n_steps, B] each.  One device_get for the lot: the copies start
+        # together and the thread waits once, where one np.asarray per array
+        # waited in turn (2.3-2.4 ms a step for five on the v5e host; ledger,
+        # PR 25).
+        toks_np, valid_np, lps_np, top_v_np, top_i_np, *moe = (
+            jax.device_get(outs))
+        self._moe_account(moe)
         step_s = time.perf_counter() - t0
         ph.to("decode.emit")
         n_tokens = 0
@@ -3724,20 +3770,22 @@ class Engine:
         )
         with self._enqueue("engine.decode.enqueue"):
             (toks, valid, lps, top_v, top_i, next_tokens, next_positions,
-             next_remaining, next_hist, counts_out, self.cache) = (
+             next_remaining, next_hist, counts_out, self.cache, moe) = (
                 self._jit_decode(*args, n_steps=n_steps, penalized=penalized))
         if penalized:
             self._dev_counts = counts_out
+        moe = self._moe_drain(moe)
         self._dev_tokens = next_tokens
         self._dev_positions = next_positions
         self._dev_remaining = next_remaining
         self._dev_stop_hist = next_hist
-        for arr in (toks, valid, lps, top_v, top_i):
+        for arr in (toks, valid, lps, top_v, top_i, *moe):
             try:
                 arr.copy_to_host_async()
             except AttributeError:
                 pass
         return {
+            "moe": moe,
             "toks": toks,
             "valid": valid,
             "lps": lps,
@@ -3812,9 +3860,12 @@ class Engine:
         wait (for the block in flight), readback, emit, account."""
         outs = jax.block_until_ready(
             (blk["toks"], blk["valid"], blk["lps"], blk["top_v"],
-             blk["top_i"]))
+             blk["top_i"], *blk.get("moe", ())))
         ph.to("decode.readback")
-        toks_np, valid_np, lps_np, top_v_np, top_i_np = map(np.asarray, outs)
+        # One device_get for the lot, as in ``_do_decode_step``.
+        toks_np, valid_np, lps_np, top_v_np, top_i_np, *moe = (
+            jax.device_get(outs))
+        self._moe_account(moe)
         ph.to("decode.emit")
         n_tokens = 0
         n_pending = 0  # prefill first-tokens materialized in this block

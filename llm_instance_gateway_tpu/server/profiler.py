@@ -68,6 +68,8 @@ from llm_instance_gateway_tpu.tracing import Histogram
 DISPATCH_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3,
                     5e-3, 1e-2, 2.5e-2, 5e-2, 0.1, 0.25, 1.0)
 
+# transformer.MOE_TALLY, restated so that this module imports no JAX.
+MOE_COUNTERS = ("layer_steps", "assignments", "experts_touched")
 GAP_HOST = "host"
 GAP_IDLE = "idle"
 
@@ -145,6 +147,9 @@ class StepProfiler:
         self._lock = witness_lock("StepProfiler._lock")
         self._ring: collections.deque = collections.deque(maxlen=self.capacity)
         self._seq = 0
+        # Routing counts of a sparse model's layer-steps (decode and
+        # prefill programs; transformer.MOE_TALLY), zero for a dense one.
+        self.moe = [0] * len(MOE_COUNTERS)
         # End of the previous dispatch on the engine-thread clock; None
         # until the first dispatch (no gap to attribute yet).
         self._last_end: float | None = None
@@ -347,6 +352,15 @@ class StepProfiler:
                 for name, sec in phases.items()},
         }
 
+    def note_moe(self, tally) -> None:
+        """Add the routing counts one readback brought back."""
+        with self._lock:
+            self.moe = [a + int(b) for a, b in zip(self.moe, tally)]
+
+    def moe_state(self) -> dict:
+        with self._lock:
+            return dict(zip(MOE_COUNTERS, self.moe))
+
     def hist_state(self) -> dict:
         """The small copy-out ``Engine.metrics_snapshot()`` embeds — the
         ``tpu:dispatch_wall_seconds`` / ``tpu:dispatch_gap_seconds``
@@ -360,6 +374,7 @@ class StepProfiler:
                         for k, h in sorted(self.gap_hist.items())},
             }
         out["phases"] = self.phase_seconds()
+        out["moe"] = self.moe_state()
         return out
 
     def snapshot(self) -> dict:
@@ -410,4 +425,13 @@ def render_profile(hist: dict) -> list[str]:
             f'tpu:engine_phase_seconds_total{{phase="{escape_label(name)}",'
             f'on="{escape_label(on)}"}} {phases.get(name, 0.0):.6f}'
             for name, on in PHASE_ON.items()]
+    moe = hist.get("moe")
+    if moe:
+        # The families by their literal names: the metric-currency lint
+        # rule looks each registered family up in the code.
+        for family, name in (
+                ("tpu:moe_layer_steps_total", "layer_steps"),
+                ("tpu:moe_assignments_total", "assignments"),
+                ("tpu:moe_experts_touched_total", "experts_touched")):
+            lines += [f"# TYPE {family} counter", f"{family} {moe[name]}"]
     return lines

@@ -95,7 +95,7 @@ def calibrate_from_engine(
         positions = jnp.broadcast_to(jnp.arange(bucket), (1, bucket)).astype(jnp.int32)
 
         def call(bucket=bucket, tokens=tokens, positions=positions):
-            first, k, v, _ = engine._jit_prefill(
+            first, k, v, *_ = engine._jit_prefill(
                 engine.params, engine._lora_buffers(), tokens, positions,
                 jnp.int32(bucket), jnp.int32(-1),
                 jnp.float32(0.0), jnp.int32(0), jnp.float32(1.0),
@@ -140,7 +140,7 @@ def calibrate_from_engine(
                 jax.random.PRNGKey(0), remaining, jnp.int32(-1),
                 n_steps=n_steps,
             )
-            engine.cache = out[-1]  # donated in; reassign the new buffer
+            engine.cache = out[-2]  # donated in; reassign the new buffer
             return out[0]
 
         kv_totals.append(float(b_slots * fill))

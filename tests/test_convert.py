@@ -161,6 +161,62 @@ class TestMixtralParity:
         np.testing.assert_allclose(hf_logits, ours, rtol=3e-4, atol=3e-4)
 
 
+class TestOlmoeParity:
+    """OLMoE's checkpoint names (``self_attn.q_norm`` / ``k_norm``,
+    ``mlp.gate``, ``mlp.experts.N.{gate,up,down}_proj``) onto the stacked
+    leaves, and the two things its config has no key or an unusual value
+    for — the unconditional QK-norm over the whole projected vector and
+    gates that are NOT renormalised — held against ``OlmoeForCausalLM``:
+    by the serving path and by the plain reference."""
+
+    @pytest.fixture(scope="class")
+    def olmoe_and_ours(self):
+        cfg = transformers.OlmoeConfig(
+            vocab_size=144, hidden_size=64, num_hidden_layers=2,
+            num_attention_heads=4, num_key_value_heads=4,
+            intermediate_size=32, num_experts=8, num_experts_per_tok=3,
+            norm_topk_prob=False, max_position_embeddings=128,
+            rms_norm_eps=1e-5, rope_theta=10_000.0,
+            tie_word_embeddings=False,
+        )
+        torch.manual_seed(6)
+        model = transformers.OlmoeForCausalLM(cfg)
+        with torch.no_grad():  # off their init of 1: a silent no-op else
+            for layer in model.model.layers:
+                for norm in (layer.self_attn.q_norm, layer.self_attn.k_norm):
+                    norm.weight.add_(0.3 * torch.randn_like(norm.weight))
+        model.eval()
+        our_cfg, params = from_hf_llama(model, dtype=jnp.float32)
+        return model, our_cfg, params
+
+    def test_config_and_names_mapped(self, olmoe_and_ours):
+        _, cfg, params = olmoe_and_ours
+        assert (cfg.n_experts, cfg.n_experts_per_token) == (8, 3)
+        assert cfg.qk_norm and not cfg.norm_topk_prob
+        layers = params["layers"]
+        assert layers["q_norm"].shape == layers["k_norm"].shape == (2, 64)
+        assert layers["router"].shape == (2, 64, 8)
+        assert layers["w_gate"].shape == layers["w_up"].shape == (2, 8, 64, 32)
+        assert layers["w_down"].shape == (2, 8, 32, 64)
+
+    @pytest.mark.parametrize("which", ["serving", "reference"])
+    def test_logits_match_hf(self, olmoe_and_ours, which):
+        from llm_instance_gateway_tpu.models import reference
+
+        model, cfg, params = olmoe_and_ours
+        ids = np.array([[3, 17, 54, 9, 88, 120, 7, 42, 99, 5]], np.int64)
+        with torch.no_grad():
+            hf_logits = model(torch.from_numpy(ids)).logits.numpy()
+        if which == "serving":
+            ours, *_ = transformer.prefill(
+                cfg, params, jnp.asarray(ids, jnp.int32),
+                jnp.arange(ids.shape[1])[None])
+        else:
+            ours = reference.forward(cfg, params, jnp.asarray(ids[0]))[None]
+        ours = np.asarray(ours)[:, :, : model.config.vocab_size]
+        np.testing.assert_allclose(hf_logits, ours, rtol=3e-4, atol=3e-4)
+
+
 def test_llama3_rope_scaling_mapped():
     cfg = transformers.LlamaConfig(
         vocab_size=64, hidden_size=32, num_hidden_layers=1,

@@ -257,7 +257,7 @@ def _ref_cached_forward(cfg, params, layers, tokens, positions, lanes, writes):
         lp = jax.tree.map(lambda x: x[l], params["layers"])
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps,
                       plus_one=cfg.norm_plus_one)
-        q, k, v = (transformer._attn_proj(lp, t, hn, None, none).reshape(
+        q, k, v = (transformer._attn_proj(cfg, lp, t, hn, None, none).reshape(
             r_n, c, n, hd) for t, n in (("q", cfg.n_heads),
                                         ("k", cfg.n_kv_heads),
                                         ("v", cfg.n_kv_heads)))
@@ -293,7 +293,7 @@ def _ref_cached_forward(cfg, params, layers, tokens, positions, lanes, writes):
         h = h + transformer._attn_out(lp, jnp.stack(attn), None, none)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps,
                        plus_one=cfg.norm_plus_one)
-        h = h + transformer._mlp(cfg, lp, hn2, None, none)
+        h = h + transformer._mlp(cfg, lp, hn2, None, none)[0]
     h = rms_norm(h, params["final_norm"], cfg.norm_eps,
                  plus_one=cfg.norm_plus_one)
     return transformer._lm_head(cfg, params, h), out_layers

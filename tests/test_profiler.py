@@ -723,7 +723,7 @@ class TestXplaneGaps:
             ("fusion.1", 4e6, [path.format("mlp")]),
             ("fusion.1", 2e6, [path.format("mlp")]),
             ("sort.8", 3e6, [path.format("sample/sample.topk_sort")]),
-            ("fusion.9", 2e6, [path.format("moe.fallback/moe.experts")]),
+            ("fusion.9", 2e6, [path.format("moe.dispatch/moe.experts")]),
             ("copy.2", 1e6, []),
         ])
         assert out["ops"][0] == {"op": "fusion.1", "program": "decode_block",
@@ -731,7 +731,7 @@ class TestXplaneGaps:
                                  "device_ms": 6.0}
         assert out["scopes_ms"] == {
             "mlp": 6.0, "sample/sample.topk_sort": 3.0,
-            "moe.fallback/moe.experts": 2.0, "(none)": 1.0}
+            "moe.dispatch/moe.experts": 2.0, "(none)": 1.0}
 
     def test_render_xplane(self):
         out = profile_report.render_xplane({

@@ -80,12 +80,10 @@ class TestQuantizedMoE:
     decode is bound by streaming 8 experts' weights — int8 halves it."""
 
     def test_moe_stacks_quantized_and_forward_close(self):
-        import dataclasses
-
         from llm_instance_gateway_tpu.models.configs import TINY_MOE_TEST
         from llm_instance_gateway_tpu.ops.quant import is_quantized
 
-        cfg = dataclasses.replace(TINY_MOE_TEST, moe_exact_fallback=False)
+        cfg = TINY_MOE_TEST
         params = transformer.init_params(cfg, jax.random.PRNGKey(0),
                                          dtype=jnp.float32)
         qp = quant.quantize_params(params)

@@ -200,7 +200,7 @@ class TestHistogramRender:
 
 DENSE_SCOPES = ("embed", "attn.qkv", "attn.rope", "attn.core", "attn.out",
                 "mlp", "lora", "lm_head")
-MOE_SCOPES = ("moe.route", "moe.dispatch", "moe.experts", "moe.fallback")
+MOE_SCOPES = ("moe.route", "moe.dispatch", "moe.experts")
 STEP_SCOPES = ("sample", "sample.topk_sort", "logprobs", "stops")
 
 

@@ -17,6 +17,7 @@ this tool's job: speed comes from the benchmark's device trace.
 from __future__ import annotations
 
 import os
+import re
 import sys
 import traceback
 
@@ -34,6 +35,7 @@ from llm_instance_gateway_tpu.models.transformer import (
 from llm_instance_gateway_tpu.ops import attention as xla_att
 from llm_instance_gateway_tpu.ops import pallas_attention as flash
 from llm_instance_gateway_tpu.ops import pallas_decode_attention as pdec
+from llm_instance_gateway_tpu.ops import pallas_moe as pmoe
 
 # Parity bound, per element: |kernel - reference| <= TOL * max(1, |reference|).
 # Both sides accumulate in f32 and round the output once to bf16 (half an
@@ -59,6 +61,18 @@ LAYOUTS = (
     ("qwen2.5-7b g=7", 28, 4, 128),
     ("qwen2.5-7b/tensor=4 shard g=7 kv=1", 7, 1, 128),
     ("llama3-8b/tensor=4 shard g=4 kv=2", 8, 2, 128),
+    ("olmoe-1b-7b g=1 kv=16", 16, 16, 128),
+)
+
+# The grouped expert matmul (ops/pallas_moe): (label, E, K, N, assignments),
+# decode- and prefill-sized, at the two sparse models' expert shapes.
+MOE_SHAPES = (
+    ("olmoe gate/up decode 32x8", 64, 2048, 1024, 256),
+    ("olmoe down decode 32x8", 64, 1024, 2048, 256),
+    ("olmoe gate/up prefill 1024x8", 64, 2048, 1024, 8192),
+    ("mixtral gate/up decode 32x2", 8, 4096, 14336, 64),
+    ("mixtral down decode 32x2", 8, 14336, 4096, 64),
+    ("mixtral down prefill 1024x2", 8, 14336, 4096, 2048),
 )
 
 DTYPE = jnp.bfloat16
@@ -174,8 +188,37 @@ def case_chunk(h, n_kv, hd, c, s_max, start):
     return out, ref, TOL_BF16
 
 
+def case_moe(e, k, n, m, quant):
+    """Skewed groups (expert 0 holds a quarter of the rows, one expert
+    none) over layer 1 of a stack of two, against the XLA tiles on the same
+    (int8) weights: both round x to bf16 and accumulate in f32."""
+    from llm_instance_gateway_tpu.ops.quant import quantize_weight
+
+    kw, kx, kg = _keys(5, 3)
+    w = jax.random.normal(kw, (2, e, k, n), DTYPE) / jnp.sqrt(k).astype(DTYPE)
+    w = quantize_weight(w) if quant else w
+    tm = pmoe.tile_rows(m, e)
+    n_tiles = pmoe.n_tiles(m, e, tm)
+    rest = jax.random.randint(kg, (m - m // 4,), 1, max(2, e - 1))
+    sizes = jnp.zeros((e,), jnp.int32).at[0].add(m // 4).at[rest].add(1)
+    _, te, n_used = pmoe.tile_plan(sizes, tm, n_tiles)
+    x = jax.random.normal(kx, (n_tiles * tm, k), DTYPE)
+    out = jax.jit(lambda x, w, te, nu: pmoe.grouped_matmul_pallas(
+        x, w, te, nu, 1, tm=tm))(x, w, te, n_used)
+    ref = jax.jit(lambda x, w, te: pmoe.grouped_matmul_xla(
+        x, w, te, 1, tm=tm))(x, w, te)
+    live = (jnp.arange(n_tiles * tm) < n_used * tm)[:, None]
+    return out * live, ref * live, TOL_BF16
+
+
 def cases():
     """(name, gate reasons, thunk) for every kernel x layout x shape."""
+    for label, e, k, n, m in MOE_SHAPES:
+        for quant in (False, True):
+            yield (f"moe-gmm-{'int8' if quant else 'bf16'} [{label}]",
+                   pmoe.shape_reasons(k, n),
+                   lambda e=e, k=k, n=n, m=m, quant=quant: case_moe(
+                       e, k, n, m, quant))
     for label, h, n_kv, hd in LAYOUTS:
         for s in (128, 1024):
             yield (f"flash s={s} [{label}]", flash.shape_reasons(s, hd),
@@ -208,7 +251,10 @@ def main() -> int:
           f"count={info.count}", flush=True)
     failed = []
     n_pass = n_gated = 0
+    only = re.compile(sys.argv[1]) if len(sys.argv) > 1 else None
     for name, gate, thunk in cases():
+        if only is not None and not only.search(name):
+            continue
         if gate:
             n_gated += 1
             print(f"GATED  {name}: {gate[0]} (dispatcher takes XLA)",

@@ -165,7 +165,7 @@ NO_ANNOTATION = "other"  # the bottom of the phase stack is not annotated
 SCOPES = frozenset((
     "embed", "attn.qkv", "attn.rope", "attn.kv_update", "attn.core",
     "attn.out", "mlp", "moe.route", "moe.dispatch", "moe.experts",
-    "moe.fallback", "lora", "lm_head", "sample", "sample.topk_sort",
+    "lora", "lm_head", "sample", "sample.topk_sort",
     "logprobs", "stops", "kv.insert"))
 
 
@@ -265,7 +265,7 @@ def op_scope(stat_values: list) -> tuple[str, str, str]:
     stats: XLA keeps ``jit(<program>)/.../<scope>/.../<primitive>`` as the
     operation's name in the source program.  ``scope`` is the chain of
     ``jax.named_scope`` names on that path, outermost first
-    (``attn.qkv/lora``, ``moe.fallback/moe.experts``), ``where`` the path's
+    (``attn.qkv/lora``), ``where`` the path's
     last three components
     (what to go by where no scope is: the layer scan's own slicing has
     none).  All empty where no stat has such a path (a copy the compiler
