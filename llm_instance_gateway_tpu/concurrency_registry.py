@@ -470,6 +470,11 @@ CLASSES = (
                         note="a sparse model's routing counts: the engine "
                              "thread adds at the decode readback, the "
                              "scrape reads a copy under the lock"),
+            SharedField("sample_steps", LOCK_GUARDED,
+                        writers=("note_sample_paths",),
+                        note="decode steps by the sampler's path: the "
+                             "engine thread adds at the decode readback, "
+                             "the scrape reads a copy under the lock"),
             SharedField("_last_end", OWNER_PRIVATE,
                         writers=("note_dispatch",)),
             SharedField("_idle_pending", OWNER_PRIVATE,

@@ -51,6 +51,12 @@ ENGINE_PHASES = (
     ("other", "host"),
 )
 
+# The ``path`` label set of ``tpu:sample_steps_total``, cheapest first: what
+# the sampler (server/sampling.py) ran for one decode step, chosen on the
+# device from the live rows' parameters.  ``sample_routed`` returns an index
+# into this tuple.
+SAMPLE_PATHS = ("argmax", "draw", "filtered")
+
 GATEWAY_FAMILIES = (
     Family("gateway_requests_total", "counter", ("model",),
            "Requests admitted past body parsing, by model.",
@@ -375,6 +381,12 @@ SERVER_FAMILIES = (
            "Experts with at least one live assignment, summed over "
            "layer-steps: over tpu:moe_layer_steps_total, the experts a "
            "layer reads.", SERVER_SURFACE),
+    Family("tpu:sample_steps_total", "counter", ("path",),
+           "Decode steps by the sampler's path, as the device took it: "
+           "argmax (no live row samples: no sort, filter or draw) | draw "
+           "(temperature only) | filtered (some live row asks for top-k or "
+           "top-p: the full-vocabulary sort); metrics_registry.SAMPLE_PATHS.",
+           SERVER_SURFACE),
     Family("tpu:prefill_seconds", "histogram", ("model", "role"),
            "Prefill compute latency.", SERVER_SURFACE),
     Family("tpu:handoff_seconds", "histogram", ("model", "role"),

@@ -48,6 +48,7 @@ from llm_instance_gateway_tpu.server.sampling import (
     STOP_SEQS,
     encode_stop_rows,
     sample,
+    sample_routed,
     stop_hist_update,
     stop_suffix_hit,
 )
@@ -970,9 +971,12 @@ class Engine:
         mid-block with zero host round-trips, and the host result walk
         merely confirms the match once per dispatch.
 
-        Returns (toks [K,B], valid [K,B], logprob triplet, next_tokens,
-        next_positions, next_remaining, next_hist, counts, cache, moe):
-        ``moe`` the block's routing counts of a sparse model
+        Returns (toks [K,B], valid [K,B], logprob triplet, paths [K],
+        next_tokens, next_positions, next_remaining, next_hist, counts,
+        cache, moe): ``paths`` the sampler's path of each step (an index
+        into ``metrics_registry.SAMPLE_PATHS``, chosen on the device by the
+        live rows' parameters), ``moe`` the block's routing counts of a
+        sparse model
         (``transformer.MOE_TALLY``; they ride the cache through the steps),
         None for a dense one.
         Positions are clamped below max_seq_len so capped slots never write
@@ -1004,10 +1008,13 @@ class Engine:
                 # [B, V] pass (and take a [B, 1] dummy counts arg).
                 logits = logits - (presence[:, None] * (counts > 0)
                                    + frequency[:, None] * counts)
-            sampled = sample(logits, step_key, temp, topk, topp,
-                             valid_vocab=model_cfg.vocab_size,
-                             seeds=seeds, positions=safe_pos,
-                             bias_ids=bias_ids, bias_vals=bias_vals)
+            # live=active: a freed slot still carries its last request's
+            # sampling parameters, and must not choose the batch's path.
+            sampled, path = sample_routed(
+                logits, step_key, temp, topk, topp,
+                valid_vocab=model_cfg.vocab_size,
+                seeds=seeds, positions=safe_pos,
+                bias_ids=bias_ids, bias_vals=bias_vals, live=active)
             lp, top_v, top_i = _logprob_info(
                 logits, sampled, model_cfg.vocab_size)
             valid = active
@@ -1028,10 +1035,10 @@ class Engine:
                 counts = counts.at[jnp.arange(c0), sampled].add(
                     valid.astype(jnp.int32))
             return (cache, next_tokens, next_positions, remaining, hist,
-                    counts), (sampled, valid, lp, top_v, top_i)
+                    counts), (sampled, valid, lp, top_v, top_i, path)
 
         keys = jax.random.split(key, n_steps)
-        carry, (toks, valid, lps, top_v, top_i) = (
+        carry, (toks, valid, lps, top_v, top_i, paths) = (
             jax.lax.scan(one_step,
                          (cache, tokens, positions, remaining, stop_hist,
                           counts), keys)
@@ -1041,7 +1048,7 @@ class Engine:
         # The token/position/budget/history carries live on device for
         # pipelined dispatch of the following block (no host round-trip).
         moe = cache.pop("moe", None)
-        return (toks, valid, lps, top_v, top_i,
+        return (toks, valid, lps, top_v, top_i, paths,
                 next_tokens, next_positions, next_remaining, next_hist,
                 counts, cache, moe)
 
@@ -2429,7 +2436,7 @@ class Engine:
             first_sampled = sample(
                 logits[:, 0], cycle_key, temp, topk, topp,
                 valid_vocab=model_cfg.vocab_size,
-                seeds=seeds, positions=safe_pos)
+                seeds=seeds, positions=safe_pos, live=active)
             greedy_row = spec_ok & (temp <= 0.0)
             e0 = jnp.where(greedy_row, greedy[:, 0], first_sampled)
             # d_{i+1} must equal the target's greedy continuation g_i.
@@ -2874,6 +2881,12 @@ class Engine:
         self._moe_keep(tally)
         drained, self._moe_pending = self._moe_pending, []
         return drained
+
+    def _sample_account(self, paths) -> None:
+        """Book the sampler's path of each step of a decode block, as the
+        device took it."""
+        if self.profiler is not None:
+            self.profiler.note_sample_paths(paths)
 
     def _moe_account(self, fetched: list) -> None:
         """Book the routing counts a decode readback brought back."""
@@ -3551,21 +3564,24 @@ class Engine:
         )
         with self._enqueue("engine.decode.enqueue"):
             (step_tokens, step_valid, step_lps, step_top_v, step_top_i,
-             _, _, _, _, counts_out, self.cache, moe) = self._jit_decode(
-                *args, n_steps=n_steps, penalized=penalized)
+             paths, _, _, _, _, counts_out, self.cache, moe) = (
+                self._jit_decode(
+                    *args, n_steps=n_steps, penalized=penalized))
         if penalized:
             self._dev_counts = counts_out
         moe = self._moe_drain(moe)
         ph.to("decode.wait")
         outs = jax.block_until_ready(
-            (step_tokens, step_valid, step_lps, step_top_v, step_top_i, *moe))
+            (step_tokens, step_valid, step_lps, step_top_v, step_top_i,
+             paths, *moe))
         ph.to("decode.readback")
         # [n_steps, B] each.  One device_get for the lot: the copies start
         # together and the thread waits once, where one np.asarray per array
         # waited in turn (2.3-2.4 ms a step for five on the v5e host; ledger,
         # PR 25).
-        toks_np, valid_np, lps_np, top_v_np, top_i_np, *moe = (
+        toks_np, valid_np, lps_np, top_v_np, top_i_np, paths_np, *moe = (
             jax.device_get(outs))
+        self._sample_account(paths_np)
         self._moe_account(moe)
         step_s = time.perf_counter() - t0
         ph.to("decode.emit")
@@ -3769,8 +3785,9 @@ class Engine:
             self._dev_stop_hist,
         )
         with self._enqueue("engine.decode.enqueue"):
-            (toks, valid, lps, top_v, top_i, next_tokens, next_positions,
-             next_remaining, next_hist, counts_out, self.cache, moe) = (
+            (toks, valid, lps, top_v, top_i, paths, next_tokens,
+             next_positions, next_remaining, next_hist, counts_out,
+             self.cache, moe) = (
                 self._jit_decode(*args, n_steps=n_steps, penalized=penalized))
         if penalized:
             self._dev_counts = counts_out
@@ -3779,13 +3796,16 @@ class Engine:
         self._dev_positions = next_positions
         self._dev_remaining = next_remaining
         self._dev_stop_hist = next_hist
-        for arr in (toks, valid, lps, top_v, top_i, *moe):
+        # The sampler's paths ride with the routing counts: small arrays
+        # the block's one readback brings back beside the tokens.
+        tail = [paths, *moe]
+        for arr in (toks, valid, lps, top_v, top_i, *tail):
             try:
                 arr.copy_to_host_async()
             except AttributeError:
                 pass
         return {
-            "moe": moe,
+            "tail": tail,
             "toks": toks,
             "valid": valid,
             "lps": lps,
@@ -3860,12 +3880,16 @@ class Engine:
         wait (for the block in flight), readback, emit, account."""
         outs = jax.block_until_ready(
             (blk["toks"], blk["valid"], blk["lps"], blk["top_v"],
-             blk["top_i"], *blk.get("moe", ())))
+             blk["top_i"], *blk.get("tail", ())))
         ph.to("decode.readback")
-        # One device_get for the lot, as in ``_do_decode_step``.
-        toks_np, valid_np, lps_np, top_v_np, top_i_np, *moe = (
+        # One device_get for the lot, as in ``_do_decode_step``.  A plain
+        # block's tail is its sampler paths, then routing counts; a
+        # speculative block has none.
+        toks_np, valid_np, lps_np, top_v_np, top_i_np, *tail = (
             jax.device_get(outs))
-        self._moe_account(moe)
+        if tail:
+            self._sample_account(tail[0])
+            self._moe_account(tail[1:])
         ph.to("decode.emit")
         n_tokens = 0
         n_pending = 0  # prefill first-tokens materialized in this block

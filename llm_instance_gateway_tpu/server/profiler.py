@@ -59,7 +59,10 @@ import os
 import time
 
 from llm_instance_gateway_tpu.lockwitness import witness_lock
-from llm_instance_gateway_tpu.metrics_registry import ENGINE_PHASES
+from llm_instance_gateway_tpu.metrics_registry import (
+    ENGINE_PHASES,
+    SAMPLE_PATHS,
+)
 from llm_instance_gateway_tpu.tracing import Histogram
 
 # Dispatch walls run from ~100µs (tiny CPU models) to hundreds of ms (a
@@ -150,6 +153,9 @@ class StepProfiler:
         # Routing counts of a sparse model's layer-steps (decode and
         # prefill programs; transformer.MOE_TALLY), zero for a dense one.
         self.moe = [0] * len(MOE_COUNTERS)
+        # Decode steps by the path the sampler took on the device
+        # (metrics_registry.SAMPLE_PATHS, in that order).
+        self.sample_steps = [0] * len(SAMPLE_PATHS)
         # End of the previous dispatch on the engine-thread clock; None
         # until the first dispatch (no gap to attribute yet).
         self._last_end: float | None = None
@@ -361,6 +367,17 @@ class StepProfiler:
         with self._lock:
             return dict(zip(MOE_COUNTERS, self.moe))
 
+    def note_sample_paths(self, paths) -> None:
+        """Count the steps of one decode block by the sampler's path:
+        ``paths`` holds one index into ``SAMPLE_PATHS`` per step."""
+        with self._lock:
+            for i in paths:
+                self.sample_steps[int(i)] += 1
+
+    def sample_state(self) -> dict:
+        with self._lock:
+            return dict(zip(SAMPLE_PATHS, self.sample_steps))
+
     def hist_state(self) -> dict:
         """The small copy-out ``Engine.metrics_snapshot()`` embeds — the
         ``tpu:dispatch_wall_seconds`` / ``tpu:dispatch_gap_seconds``
@@ -375,6 +392,7 @@ class StepProfiler:
             }
         out["phases"] = self.phase_seconds()
         out["moe"] = self.moe_state()
+        out["sample_steps"] = self.sample_state()
         return out
 
     def snapshot(self) -> dict:
@@ -434,4 +452,10 @@ def render_profile(hist: dict) -> list[str]:
                 ("tpu:moe_assignments_total", "assignments"),
                 ("tpu:moe_experts_touched_total", "experts_touched")):
             lines += [f"# TYPE {family} counter", f"{family} {moe[name]}"]
+    sample_steps = hist.get("sample_steps")
+    if sample_steps:
+        lines.append("# TYPE tpu:sample_steps_total counter")
+        lines += [
+            f'tpu:sample_steps_total{{path="{escape_label(path)}"}} {n}'
+            for path, n in sample_steps.items()]
     return lines

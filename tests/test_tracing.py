@@ -290,6 +290,62 @@ class TestNamedScopes:
         assert "jit(decode_block)" in text
 
 
+    def test_decode_block_sorts_and_draws_only_inside_a_branch(self):
+        """The sampler's conditional in the decode step's body: the argmax
+        branch hands back a value computed outside it and nothing else;
+        every sort, cumulative sum and random draw of the body sits in
+        one of the other two branches, the sort in the last alone."""
+        import re
+
+        import jax
+        import jax.numpy as jnp
+
+        from llm_instance_gateway_tpu.server.engine import Engine
+        from llm_instance_gateway_tpu.server.sampling import (
+            STOP_LEN,
+            STOP_SEQS,
+        )
+
+        text = _decode_block_text(jax, jnp, Engine, STOP_LEN, STOP_SEQS)
+        lines = text.splitlines()
+        heavy = re.compile(
+            r"stablehlo\.(sort|rng)|call @(sort|cumsum|_gumbel|_uniform"
+            r"|_threefry_fold_in|threefry2x32|random_bits)")
+        # The sampler's switch: the outermost conditional of the function
+        # that calls the sort (a step's body; its helpers are private
+        # functions of their own).
+        sort_call = next(i for i, l in enumerate(lines) if "call @sort" in l)
+        start = max(i for i in range(sort_call)
+                    if lines[i].lstrip().startswith("func.func"))
+        end = next(i for i in range(sort_call, len(lines))
+                   if lines[i].startswith("  }"))
+        case = next(i for i in range(start, sort_call)
+                    if '"stablehlo.case"' in lines[i])
+        pad = " " * (len(lines[case]) - len(lines[case].lstrip()))
+        cuts = [case]
+        for i in range(case + 1, end):
+            if lines[i] == pad + "}, {":
+                cuts.append(i)
+            elif lines[i].startswith(pad + "}) :"):
+                cuts.append(i)
+                break
+        branches = [lines[a + 1:b] for a, b in zip(cuts, cuts[1:])]
+        assert len(branches) == 3  # metrics_registry.SAMPLE_PATHS
+        argmax, draw, filtered = branches
+        assert len(argmax) == 1 and "stablehlo.return" in argmax[0]
+        assert any("call @_gumbel" in l for l in draw)
+        assert not any(re.search(r"call @(sort|cumsum)", l) for l in draw)
+        assert any("call @sort" in l for l in filtered)
+        assert any("call @cumsum" in l for l in filtered)
+        assert any("call @_gumbel" in l for l in filtered)
+        outside = lines[start:case] + lines[cuts[-1]:end]
+        assert not [l for l in outside if heavy.search(l)]
+        # ...and the scope that names the sort is in that branch alone.
+        scoped = re.findall(r'loc\("([^"]*sample\.topk_sort[^"]*)"', text)
+        assert scoped and all("branch_2_fun/sample.topk_sort" in n
+                              for n in scoped)
+
+
 @functools.lru_cache(maxsize=None)
 def _decode_block_text(jax, jnp, Engine, stop_len, stop_seqs) -> str:
     from llm_instance_gateway_tpu.models import transformer
