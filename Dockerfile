@@ -20,7 +20,6 @@ RUN pip install --no-cache-dir \
         optax orbax-checkpoint aiohttp grpcio protobuf pyyaml jsonschema numpy
 
 COPY llm_instance_gateway_tpu/ llm_instance_gateway_tpu/
-COPY bench.py ./
 
 # Pre-build the native scheduler so first pick isn't a compile.
 RUN make -C llm_instance_gateway_tpu/native

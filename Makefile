@@ -2,7 +2,7 @@
 
 IMG ?= gcr.io/PROJECT/tpu-inference-gateway:latest
 
-.PHONY: test test-e2e chaos native native-asan native-tsan bench bench-check loadgen sim sim-check metrics-docs top usage-check lint typecheck docker-build install deploy undeploy fmt
+.PHONY: test test-e2e chaos native native-asan native-tsan loadgen sim sim-check metrics-docs top usage-check lint typecheck docker-build install deploy undeploy fmt
 
 test:            ## unit + integration tests (CPU, virtual 8-device mesh)
 	python -m pytest tests/ -q -m "not e2e"
@@ -30,12 +30,6 @@ chaos:           ## seeded fault-injection scenarios vs the in-process stack
 
 native:          ## build the C++ scheduler hot path
 	$(MAKE) -C llm_instance_gateway_tpu/native
-
-bench:           ## north-star benchmark (one JSON line; runs on the TPU)
-	python bench.py
-
-bench-check:     ## CPU-deterministic microbench gate vs BASELINE_BENCH.json (>20% regression fails)
-	env JAX_PLATFORMS=cpu python tools/bench_check.py
 
 loadgen:         ## gateway load rig (200 fake pods x 5 adapters)
 	python -m llm_instance_gateway_tpu.gateway.loadgen --requests 10000

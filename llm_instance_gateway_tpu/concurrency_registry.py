@@ -614,8 +614,7 @@ CLASSES = (
                         writers=("_loop_pipelined",
                                  "_paged_ensure_decode")),
             SharedField("decode_tps_ema", SWAP_PUBLISHED,
-                        writers=("_do_decode_step", "_do_spec_step",
-                                 "_process_block"),
+                        writers=("_account_dispatch",),
                         note="float rebind; the scrape thread reads it "
                              "lock-free"),
             SharedField("prefix_reused_tokens", MONOTONIC,
@@ -626,8 +625,8 @@ CLASSES = (
             SharedField("spec_emitted", MONOTONIC,
                         writers=("_do_spec_step", "_process_block")),
             SharedField("total_generated", MONOTONIC,
-                        writers=("_do_decode_step", "_do_spec_step",
-                                 "_emit_first_token", "_process_block")),
+                        writers=("_account_dispatch",
+                                 "_emit_first_token")),
             SharedField("total_requests", MONOTONIC,
                         writers=("attach_prefilled", "submit"),
                         domain=DATA_PATH),

@@ -90,9 +90,8 @@ class CapacityConfig:
     # calibrate cleanly and sit between the Prometheus scrape interval
     # and the SLO engine's 1m burn windows (drift still alarms within
     # 2 windows = 60s, far inside any burn horizon); (b) tick tax — the
-    # fold amortizes over min_window_s/obs_tick_s cheap early-returns,
-    # which is what keeps bench.py's capacity_tick_ratio under its 1.05
-    # bar.  0 = fold every call (chaos and unit tests drive virtual
+    # fold amortizes over min_window_s/obs_tick_s cheap early-returns.
+    # 0 = fold every call (chaos and unit tests drive virtual
     # clocks through that).
     min_window_s: float = 30.0
     # Self-calibration: refit from the newest max_fit_windows whenever at
@@ -271,8 +270,7 @@ class CapacityPlanner:
         The per-pod saturation view is NOT built here: the raw rows land
         in ``_prev``/``_rows_old`` and ``_derive_saturation``
         materializes the view lazily when render()/debug_payload() ask —
-        the obs tick pays only the sums (the ``capacity_tick_ratio``
-        bench bound)."""
+        the obs tick pays only the sums."""
         cfg = self.cfg
         row = self._row
         old = self._prev

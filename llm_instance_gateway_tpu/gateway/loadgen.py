@@ -17,7 +17,6 @@ one ran as ``relay_mode``):
   fast/slow ratio in every artifact compares against.
 
 Run:  python -m llm_instance_gateway_tpu.gateway.loadgen --requests 10000
-Also imported by bench.py for the scheduler-throughput component.
 """
 
 from __future__ import annotations
@@ -428,8 +427,7 @@ def run_multi_gateway(requests: int = 20000, gateways: int = 4,
         return lats[min(len(lats) - 1, int(p * len(lats)))]
 
     # Phase 1: throughput.  Baseline and replicas run INTERLEAVED, three
-    # passes each, best wall kept — the same min-over-interleaved
-    # posture as tools/bench_check.py: CPU-noise drift across the run
+    # passes each, best wall kept: CPU-noise drift across the run
     # must not masquerade as (or hide) a scaling regression on either
     # side of the ratio.
     base_front, _, _ = _build_gateway_replica(fixtures, seed, replica=999)

@@ -35,8 +35,7 @@ drift observable, not an assert).
 the log-only invariant requires routing byte-identical with the ledger
 ON), the unsampled path is one ``enabled`` check + one GIL-atomic
 ``itertools.count`` bump, and every record/counterfactual cost rides only
-sampled picks; ``pick_ledger_ratio`` < 1.05 is gated in
-``make bench-check``.
+sampled picks.
 
 Surfaces: ``GET /debug/picks?since=`` (cursor contract of
 ``events.debug_events_payload``), the ``gateway_pick_*`` exposition
@@ -259,8 +258,7 @@ class PickLedger:
         # whose live filter passed its input through unchanged is skipped
         # without a replay — disabling a no-op filter reproduces the live
         # chain exactly, so the replay cost rides only picks a seam
-        # actually narrowed (this is what keeps the amortized
-        # pick_ledger_ratio under its bench gate on a healthy fleet).
+        # actually narrowed.
         cf_rows = []
         steered: list[str] = []
         decisive = ""

@@ -279,9 +279,9 @@ class GatewayProxy:
         # keeps weak ones; see _spawn_release).
         self._release_tasks: set = set()
         self._session: aiohttp.ClientSession | None = None
-        # Data-plane fast path (this PR's tentpole): the zero-copy SSE
-        # relay.  ``fast_relay=False`` keeps the pre-existing line-scanning
-        # relay — the byte-parity oracle the A/B tests compare against.
+        # Data-plane fast path: the zero-copy SSE relay.  ``False`` is the
+        # line-scanning relay, the byte-parity reference of
+        # tests/test_fast_relay.py: a constructor argument with no CLI flag.
         self.fast_relay = fast_relay
         # Preallocated header templates: the per-request mutation copies a
         # template and stamps the request-scoped values instead of
@@ -1194,7 +1194,7 @@ class GatewayProxy:
           appending a chunk *reference* to a bounded tail deque.  The
           final usage chunk and ``[DONE]`` exclusion are parsed ONCE at
           stream end from the raw tail bytes (``final_data_line``).
-        - **slow** (the pre-existing path, kept as the parity oracle):
+        - **slow** (the parity oracle, reachable from tests only):
           SSE lines are re-framed through a byte buffer per chunk so a
           data line split across transport chunks still parses.
 
@@ -1639,10 +1639,6 @@ def main(argv: list[str] | None = None) -> None:
 
     parser = argparse.ArgumentParser(description="TPU-native inference gateway")
     parser.add_argument("--port", type=int, default=8081)
-    parser.add_argument("--no-fast-relay", action="store_true",
-                        help="disable the zero-copy SSE relay fast path "
-                             "(falls back to the line-scanning relay; the "
-                             "A/B axis for byte-parity and perf checks)")
     parser.add_argument("--no-pick-ledger", action="store_true",
                         help="disable the routing decision ledger "
                              "(/debug/picks goes empty; routing itself is "
@@ -1661,7 +1657,6 @@ def main(argv: list[str] | None = None) -> None:
                          fairness_cfg=bootstrap.fairness_from_args(args),
                          placement_cfg=bootstrap.placement_from_args(args),
                          capacity_cfg=bootstrap.capacity_from_args(args),
-                         fast_relay=not args.no_fast_relay,
                          pickledger_cfg=pickledger_mod.PickLedgerConfig(
                              enabled=not args.no_pick_ledger,
                              sample_every=max(1, args.pick_sample_every)),

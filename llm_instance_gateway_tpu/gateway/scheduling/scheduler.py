@@ -233,8 +233,7 @@ def filter_by_placement(
         host_set = frozenset()
     if not slot_set and not host_set:
         return candidates
-    # One pass, both tiers (this filter rides the pick hot path — the
-    # <5% pick_placement_ratio bound in BASELINE_BENCH.json).
+    # One pass, both tiers (this filter rides the pick hot path).
     slot_pref: list = []
     host_pref: list = []
     if name_of is None:

@@ -94,5 +94,5 @@ class LLMRequest:
     # The request's x-lig-trace-id (minted by the transport before
     # scheduling): lets the pick ledger's decision records join the
     # request's trace/span timeline.  Empty for callers without tracing
-    # (sim, bench) — the ledger records it verbatim.
+    # (sim, load rigs) — the ledger records it verbatim.
     trace_id: str = ""

@@ -25,16 +25,13 @@ _IDENT_RE = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
 
 
 def _scan_targets(tree: Tree) -> list[str]:
-    # Same scope ruff scans (pyproject.toml): the package, tools/, tests/,
-    # bench.py — the two layers must agree on what "clean" means, or a
-    # ruff-less host certifies a tree a ruff-ful CI then rejects.
-    files = [f for f in tree.py_files(PKG, "tools", "tests",
-                                      exclude=(f"{PKG}/lint/",))
-             if not f.endswith("_pb2.py")          # generated protobuf
-             and not f.endswith("_pb2_grpc.py")]
-    if tree.exists("bench.py"):
-        files.append("bench.py")
-    return files
+    # Same scope ruff scans (pyproject.toml): the package, tools/, tests/
+    # — the two layers must agree on what "clean" means, or a ruff-less
+    # host certifies a tree a ruff-ful CI then rejects.
+    return [f for f in tree.py_files(PKG, "tools", "tests",
+                                     exclude=(f"{PKG}/lint/",))
+            if not f.endswith("_pb2.py")          # generated protobuf
+            and not f.endswith("_pb2_grpc.py")]
 
 
 def _noqa_lines(src: str) -> set[int]:

@@ -21,10 +21,9 @@ holding A" edge.  Two uses:
 Arming: ``witness_lock(name)`` returns a recording wrapper only when
 ``LIG_LOCK_WITNESS`` is set truthy AT CONSTRUCTION TIME (tests arm it in
 ``tests/conftest.py``); otherwise it returns a plain ``threading.Lock`` /
-``RLock`` — zero overhead in production.  The armed overhead is bounded by
-the ``pick_witness_ratio`` microbench (< 1.05 vs plain locks, committed to
-``BASELINE_BENCH.json``): per acquisition it costs one thread-local list
-append plus, only for a never-seen (held, acquired) pair, one dict insert.
+``RLock`` — zero overhead in production.  Armed, an acquisition costs one
+thread-local list append plus, only for a never-seen (held, acquired)
+pair, one dict insert.
 
 Naming convention: ``"ClassName._lockattr"`` — the SAME identity the
 static analyzer assigns (``concurrency_registry`` declares the classes and
@@ -159,8 +158,7 @@ class _WitnessLock:
     """``threading.Lock`` wrapper recording acquisition order.  API-
     compatible with the subset the tree uses (with-statement, acquire/
     release, locked).  The with-statement path (``__enter__``/``__exit__``)
-    inlines the recording — it brackets every pick-seam acquisition, and
-    the ``pick_witness_ratio`` bench bounds its cost at < 5% of a pick."""
+    inlines the recording — it brackets every pick-seam acquisition."""
 
     __slots__ = ("_lock", "_name")
 

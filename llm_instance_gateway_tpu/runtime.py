@@ -1,7 +1,7 @@
 """Process-level JAX set-up shared by every entry point: where the compile
 cache lives, and which device the process landed on.
 
-Two rules the entry points (``server/api_http.py``, ``bench.py``, the chip
+Two rules the entry points (``server/api_http.py``, the chip
 tools, ``__graft_entry__.py``, ``tests/conftest.py``) all follow:
 
 - **The compile cache is placed from outside.**  ``JAX_COMPILATION_CACHE_DIR``

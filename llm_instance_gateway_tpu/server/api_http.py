@@ -1416,11 +1416,11 @@ class ModelServer:
         dispatch/host-sync/idle attribution summary, wall+gap histogram
         states, and the newest per-dispatch records — what
         ``tools/profile_report.py`` renders and the fleet collector's
-        black-box dumps embed.  404 when ``step_profile`` is off."""
+        black-box dumps embed.  404 from an engine stand-in that has no
+        profiler (every ``Engine`` has one)."""
         profiler = getattr(self.engine, "profiler", None)
         if profiler is None:
-            return _err(404, "step profiler is disabled "
-                             "(EngineConfig.step_profile=False)")
+            return _err(404, "this engine has no step profiler")
         return web.json_response({"model": self.model_name,
                                   "role": self.engine.cfg.role,
                                   **profiler.snapshot()})
@@ -1431,13 +1431,12 @@ class ModelServer:
         histograms, and the lifecycle event ring — what
         ``tools/kv_report.py`` renders, the gateway's ``gateway/kvobs.py``
         duplication index joins, and black-box dumps embed.  404 when the
-        ledger is off (or the engine runs the contiguous-lane cache,
-        which has no block economy)."""
+        engine runs the contiguous-lane cache, which has no block economy
+        and so no ledger."""
         ledger = getattr(self.engine, "kv_ledger", None)
         if ledger is None:
-            return _err(404, "kv ledger is disabled "
-                             "(EngineConfig.kv_ledger=False or non-paged "
-                             "cache)")
+            return _err(404, "no kv ledger: the cache is not paged "
+                             "(--paged-kv-block)")
         self.engine._kv_ledger_sync()
         return web.json_response({"model": self.model_name,
                                   "role": self.engine.cfg.role,
@@ -1551,14 +1550,6 @@ def main(argv=None) -> None:
                              "two <= CEILING) from remaining budgets, "
                              "pending admissions/chunk streams, and SSE "
                              "cadence; 0 = static --decode-steps")
-    parser.add_argument("--no-device-stops", action="store_true",
-                        help="disable the device-side stop-string automata "
-                             "(rows then stop via the host oracle only — "
-                             "the A/B for the decode-lever bench)")
-    parser.add_argument("--no-kv-ledger", action="store_true",
-                        help="disable the KV block-lifecycle ledger "
-                             "(tpu:kv_* families + /debug/kv; the A/B "
-                             "for the kv_ledger_ratio bench)")
     parser.add_argument("--stream-lanes", type=int, default=1,
                         help="concurrent chunk-stream lanes: how many "
                              "long prompts may stream into reserved cache "
@@ -1768,14 +1759,12 @@ def main(argv=None) -> None:
                 or (min(args.max_seq_len, 1024),)),
             decode_steps_per_sync=args.decode_steps,
             adaptive_steps=args.adaptive_steps,
-            device_stops=not args.no_device_stops,
             stream_lanes=args.stream_lanes,
             pipeline_decode=args.pipeline_decode,
             prefill_batch=args.prefill_batch,
             paged_kv_block=args.paged_kv_block,
             paged_kv_blocks=args.paged_kv_blocks,
             prefix_cache=args.prefix_cache,
-            kv_ledger=not args.no_kv_ledger,
             role=args.role,
             handoff_ttl_s=args.handoff_ttl_s,
             speculative_k=args.speculative,

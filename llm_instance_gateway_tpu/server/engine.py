@@ -53,7 +53,7 @@ from llm_instance_gateway_tpu.server.sampling import (
     stop_suffix_hit,
 )
 from llm_instance_gateway_tpu.server.kv_ledger import KvLedger
-from llm_instance_gateway_tpu.server.profiler import NO_PHASE, StepProfiler
+from llm_instance_gateway_tpu.server.profiler import StepProfiler
 from llm_instance_gateway_tpu.server.usage import UsageTracker, owner_key
 from llm_instance_gateway_tpu.tracing import LATENCY_BUCKETS, Histogram
 
@@ -69,6 +69,8 @@ MAX_LOGIT_BIAS = 32  # per-request logit_bias entries (static lanes)
 # Dispatch-size histogram edges for tpu:dispatch_steps: the planner only
 # emits powers of two, so the buckets land exactly on its choices.
 STEP_BUCKETS = (1, 2, 4, 8, 16, 32, 64)
+# Tokens/sec EMA smoothing for the exported throughput gauge.
+TPS_EMA_ALPHA = 0.2
 
 
 class EngineDraining(RuntimeError):
@@ -156,7 +158,8 @@ class EngineConfig:
     # program, so a row whose stop hits mid-block freezes there with zero
     # host round-trips (the host oracle still trims once per dispatch —
     # token parity is structural, not probabilistic).  False = host-only
-    # stop checks (the A/B oracle for the bench and the parity tests).
+    # stop checks: the reference tests/test_decode_levers.py holds the
+    # device automata to, a constructor argument with no CLI flag.
     device_stops: bool = True
     # Concurrent chunk-stream lanes: how many long prompts may stream
     # chunk-by-chunk into reserved cache lanes AT ONCE (fair round-robin,
@@ -170,8 +173,6 @@ class EngineConfig:
     # host readback with compute.  Slot FREEING still lags one block (the
     # frozen row just decodes invalid steps until the host sees the stop).
     pipeline_decode: bool = False
-    # Tokens/sec EMA smoothing for the exported throughput gauge.
-    tps_ema_alpha: float = 0.2
     # Prefill-ahead depth: prompts prefilled while all decode slots are busy
     # wait here (KV held off-cache) and insert the instant a slot frees —
     # the decode batch never idles a slot waiting for a prefill, and the
@@ -214,8 +215,8 @@ class EngineConfig:
     # rows fall back to one verified token per cycle.  Requires
     # ``draft_params``/``draft_cfg`` at Engine construction.  Composes with
     # BOTH engine loops, ``decode_steps_per_sync`` (cycles are fused into
-    # one device-side scan of ceil(steps/(K+1)) cycles per dispatch — the
-    # bench's pipelined fast path included), the paged cache
+    # one device-side scan of ceil(steps/(K+1)) cycles per dispatch, in
+    # the pipelined loop too), the paged cache
     # (extend_step_paged verify), and GSPMD serve meshes (draft replicated)
     # — including all three together on a tensor/expert mesh
     # (parity-tested).  paged + a data mesh is excluded by the engine's
@@ -245,29 +246,6 @@ class EngineConfig:
     # gateway can always fall back to single-hop serving — but it is
     # exported via /metrics and drives the gateway's two-stage routing.
     role: str = "collocated"
-    # Per-adapter capacity attribution (server/usage.py): charge decode
-    # step wall time, tokens, and KV block-seconds to the {adapter} of
-    # each active slot, plus pool-waste observables (batch occupancy,
-    # idle-slot-seconds, prefill padding).  A few dict ops per DISPATCH;
-    # the off switch exists for the bench.py overhead A/B
-    # (usage_attribution_ratio), not for production use.
-    usage_attribution: bool = True
-    # Step-timeline profiler (server/profiler.py): per-dispatch wall /
-    # host-sync gap / idle attribution in a bounded ring, exported as
-    # tpu:dispatch_wall_seconds / tpu:dispatch_gap_seconds and served by
-    # /debug/profile — the evidence layer for the dispatch-bound decode
-    # levers (ROADMAP item 2).  Like usage_attribution, the off switch
-    # exists for the bench A/B (step_profile_ratio <= 1.05), not for
-    # production use.
-    step_profile: bool = True
-    # KV economy ledger (server/kv_ledger.py, paged mode only): per-state
-    # block accounting (free/active/parked/prefix-resident tiling the
-    # budget), a per-prefix reuse table, fragmentation/headroom
-    # histograms, and a bounded lifecycle event ring — exported as the
-    # tpu:kv_* families and served by /debug/kv.  Like the trackers
-    # above, the off switch exists for the bench A/B (kv_ledger_ratio
-    # < 1.05), not for production use.
-    kv_ledger: bool = True
     # Prefix caching (paged mode only): full prompt blocks are
     # content-addressed (chained hashes, vLLM-style) and retained with
     # refcounts after a request finishes; a later prompt sharing the prefix
@@ -754,24 +732,20 @@ class Engine:
         # Capacity attribution (server/usage.py): who is consuming this
         # replica.  Own lock; charged from the engine thread, snapshotted
         # by the scrape thread.
-        self.usage: UsageTracker | None = (
-            UsageTracker(b, kv_block=self._block if self.paged else 1)
-            if self.cfg.usage_attribution else None)
+        self.usage = UsageTracker(
+            b, kv_block=self._block if self.paged else 1)
         # Step-timeline profiler (server/profiler.py): charged at the
         # same dispatch call sites as the usage tracker, plus idle marks
         # from the engine loop, so the dispatch/host-sync/idle attribution
         # tiles the engine thread's wall.
-        self.profiler: StepProfiler | None = (
-            StepProfiler(annotate=jax.profiler.TraceAnnotation)
-            if self.cfg.step_profile else None)
+        self.profiler = StepProfiler(annotate=jax.profiler.TraceAnnotation)
         # KV economy ledger (server/kv_ledger.py): block lifecycle,
         # per-prefix reuse, fragmentation.  Own lock; charged at the
         # allocator/prefix/park sites, state-recounted on the KV sync,
         # snapshotted by the scrape thread.  Paged pool only — the
         # contiguous-lane cache has no block economy to account.
         self.kv_ledger: KvLedger | None = (
-            KvLedger(self._n_blocks, self._block)
-            if self.paged and self.cfg.kv_ledger else None)
+            KvLedger(self._n_blocks, self._block) if self.paged else None)
         # LRU evictions since the last KV sync (journaled as ONE
         # aggregated kv_evict event per sync — eviction storms must not
         # flood the flight recorder's bounded ring).
@@ -1538,13 +1512,11 @@ class Engine:
             "phase_hist": phase_hist,
             # Per-adapter capacity attribution (server/usage.py) — the
             # tpu:adapter_*_total / pool-waste families.
-            **({"usage": self.usage.snapshot()}
-               if self.usage is not None else {}),
+            "usage": self.usage.snapshot(),
             # Step-timeline profiler histogram states (server/profiler.py)
             # — the tpu:dispatch_wall_seconds / tpu:dispatch_gap_seconds
             # families; the full per-dispatch ring rides /debug/profile.
-            **({"profile": self.profiler.hist_state()}
-               if self.profiler is not None else {}),
+            "profile": self.profiler.hist_state(),
             # KV economy ledger (server/kv_ledger.py) — the tpu:kv_*
             # block-lifecycle families; the full payload (event ring,
             # prefix heatmap) rides /debug/kv.  Re-synced here so a
@@ -1564,10 +1536,10 @@ class Engine:
 
     def _kv_ledger_snapshot_key(self) -> dict:
         """``{"kv_ledger": snapshot}`` for ``metrics_snapshot`` (empty
-        when the ledger is off).  The recount reads the allocator's
-        host-side structures lock-free like the paged math above — the
-        same single-writer tolerance, and the ledger's own lock makes
-        the stored counts internally consistent."""
+        on the lane cache, which has no ledger).  The recount reads the
+        allocator's host-side structures lock-free like the paged math
+        above — the same single-writer tolerance, and the ledger's own
+        lock makes the stored counts internally consistent."""
         if self.kv_ledger is None:
             return {}
         self._kv_ledger_sync()
@@ -1868,14 +1840,12 @@ class Engine:
 
     def _phase(self, name: str):
         """The engine thread's phase from here to the end of the ``with``
-        (server/profiler.py); one shared no-op with the profiler off."""
-        p = self.profiler
-        return NO_PHASE if p is None else p.phase(name)
+        (server/profiler.py)."""
+        return self.profiler.phase(name)
 
     def _enqueue(self, name: str):
         """Trace-only span round a jitted call inside a ``*.stage`` phase."""
-        p = self.profiler
-        return NO_PHASE if p is None else p.annotation(name)
+        return self.profiler.annotation(name)
 
     def _loop(self) -> None:
         while self._running:
@@ -1917,8 +1887,7 @@ class Engine:
                 self._wait_for_work()
 
     def _wait_for_work(self) -> None:
-        if self.profiler is not None:
-            self.profiler.note_idle()
+        self.profiler.note_idle()
         with self._phase("idle"), self._work:
             self._work.wait(timeout=0.05)
 
@@ -2654,23 +2623,9 @@ class Engine:
             self._spec_has_extra[i] = bool(ehas_np[i])
         ph.to("decode.account")
         self.spec_emitted += n_tokens
-        if self.usage is not None:
-            self.usage.charge_decode(step_s, owners, tok_by_owner)
-            self._usage_sync_kv()
-        if self.profiler is not None:
-            self.profiler.note_dispatch(
-                "spec", t0, step_s, active=len(owners),
-                total_slots=self.cfg.decode_slots, n_steps=t_steps)
-        with self._lock:
-            self.total_generated += n_tokens
-            inst = n_tokens / step_s if step_s > 0 else 0.0
-            a = self.cfg.tps_ema_alpha
-            self.decode_tps_ema = (1 - a) * self.decode_tps_ema + a * inst
-            # Per-cycle cadence (each verify cycle emits >= 1 token/row).
-            # No dispatch_steps observation: that histogram records the
-            # PLANNER's power-of-two choices, and a spec block's token-row
-            # count (cycles x (K+1)) is not one of them.
-            self.phase_hist["decode_step"].observe(step_s / max(1, n_cycles))
+        # Per-cycle cadence (each verify cycle emits >= 1 token/row).
+        self._account_dispatch("spec", t0, step_s, owners, tok_by_owner,
+                               n_tokens, t_steps, cadence_steps=n_cycles)
 
     def _prefill_common(self, req: Request):
         """Shared admission path: bucketed (or ring sequence-parallel)
@@ -2729,7 +2684,7 @@ class Engine:
         try:
             self._sync_tables()
             c = n - reused
-            self._note_padding(self._bucket(c) - c)
+            self.usage.charge_padding(self._bucket(c) - c)
             last_logits = self._chunk_dispatch(
                 req.prompt_tokens[reused:], reused, self._bucket(c),
                 slot_idx, n, lora_slot)
@@ -2769,7 +2724,7 @@ class Engine:
 
         sp = req.sampling
         padded = -(-n // self._ring_pad) * self._ring_pad
-        self._note_padding(padded - n)
+        self.usage.charge_padding(padded - n)
         tokens = np.zeros((1, padded), np.int32)
         tokens[0, :n] = req.prompt_tokens
         positions = np.broadcast_to(
@@ -2820,7 +2775,7 @@ class Engine:
         Returns (first_token device scalar, k, v, lp_info)."""
         sp = req.sampling
         bucket = self._bucket(n)
-        self._note_padding(bucket - n)
+        self.usage.charge_padding(bucket - n)
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :n] = req.prompt_tokens
         positions = np.zeros((1, bucket), np.int32)
@@ -2845,7 +2800,7 @@ class Engine:
         Returns (first_tokens [P] device, k [L,P,S,...], v, lp_infos)."""
         bucket = self._bucket(max(ns))
         p = len(reqs)
-        self._note_padding(sum(bucket - n for n in ns))
+        self.usage.charge_padding(sum(bucket - n for n in ns))
         tokens = np.zeros((p, bucket), np.int32)
         positions = np.zeros((p, bucket), np.int32)
         for i, (req, n) in enumerate(zip(reqs, ns)):
@@ -2882,15 +2837,9 @@ class Engine:
         drained, self._moe_pending = self._moe_pending, []
         return drained
 
-    def _sample_account(self, paths) -> None:
-        """Book the sampler's path of each step of a decode block, as the
-        device took it."""
-        if self.profiler is not None:
-            self.profiler.note_sample_paths(paths)
-
     def _moe_account(self, fetched: list) -> None:
         """Book the routing counts a decode readback brought back."""
-        if fetched and self.profiler is not None:
+        if fetched:
             self.profiler.note_moe(np.sum(fetched, axis=0))
 
     def _collect_followers(self, first_req, limit: int) -> list:
@@ -3347,8 +3296,7 @@ class Engine:
                 # tpu:prefill_seconds exposition family.
                 self.phase_hist["prefill"].observe(
                     max(0.0, req.t_first_token - req.t_prefill_start))
-        if (self.usage is not None
-                and req.t_prefill_start and req.t_first_token):
+        if req.t_prefill_start and req.t_first_token:
             # Attribution: the prefill's wall charged whole to its owner
             # (grouped prefills charge each rider the shared program wall
             # — per-request compute-seconds, the same accounting the
@@ -3359,8 +3307,6 @@ class Engine:
                 max(0.0, req.t_first_token - req.t_prefill_start),
                 [req.adapter],
                 tokens={owner_key(req.adapter): len(req.prompt_tokens)})
-        if (self.profiler is not None
-                and req.t_prefill_start and req.t_first_token):
             # t0=None: the prefill wall is time.time-stamped, so it can't
             # anchor the perf_counter gap chain — the profiler records
             # the wall and subtracts it from the next gap instead.
@@ -3369,12 +3315,6 @@ class Engine:
                 max(0.0, req.t_first_token - req.t_prefill_start),
                 active=1, total_slots=self.cfg.decode_slots,
                 n_steps=len(req.prompt_tokens))
-
-    def _note_padding(self, pad_tokens: int) -> None:
-        """Bucket/ring padding tokens prefilled and thrown away: the
-        usage tracker's pool-waste counter."""
-        if self.usage is not None:
-            self.usage.charge_padding(pad_tokens)
 
     def _kv_ledger_sync(self) -> None:
         """Recount the KV ledger's block states from allocator ground
@@ -3403,10 +3343,8 @@ class Engine:
         ``decode_wait`` KV at its padded size (the same HBM the
         ``kv_parked_tokens`` gauge counts), and the in-flight chunk
         stream's filled prefix.  The KV ledger's state recount rides the
-        same call sites (it has its own off switch)."""
+        same call sites."""
         self._kv_ledger_sync()
-        if self.usage is None:
-            return
         holdings: list[tuple[str | None, int]] = [
             (s.request.adapter, s.position)
             for s in self.slots if s is not None]
@@ -3415,6 +3353,32 @@ class Engine:
         holdings += [(st.request.adapter, st.next_start)
                      for st in self._streams if st.next_start > 0]
         self.usage.sync_kv(holdings)
+
+    def _account_dispatch(self, kind: str, t0: float, step_s: float,
+                          owners: list, tok_by_owner: dict[str, int],
+                          n_tokens: int, n_steps: int,
+                          cadence_steps: int | None = None) -> None:
+        """End-of-dispatch bookkeeping of every decode dispatch (sync,
+        pipelined, speculative), under the caller's ``decode.account``
+        phase.  ``tpu:decode_step_seconds`` observes ``step_s /
+        cadence_steps`` (``n_steps`` unless given: a sync speculative
+        block passes its verify cycles).  ``tpu:dispatch_steps`` records
+        the PLANNER's power-of-two choices, so only ``kind == "decode"``
+        observes it: a speculative block's token-row count is not one."""
+        self.usage.charge_decode(step_s, owners, tok_by_owner)
+        self._usage_sync_kv()
+        self.profiler.note_dispatch(
+            kind, t0, step_s, active=len(owners),
+            total_slots=self.cfg.decode_slots, n_steps=n_steps)
+        with self._lock:
+            self.total_generated += n_tokens
+            inst = n_tokens / step_s if step_s > 0 else 0.0
+            self.decode_tps_ema = ((1 - TPS_EMA_ALPHA) * self.decode_tps_ema
+                                   + TPS_EMA_ALPHA * inst)
+            self.phase_hist["decode_step"].observe(
+                step_s / max(1, cadence_steps or n_steps))
+            if kind == "decode":
+                self.dispatch_steps_hist.observe(n_steps)
 
     def observe_handoff(self, seconds: float) -> None:
         """Record one handoff-plane operation (serialize on the prefill
@@ -3581,7 +3545,7 @@ class Engine:
         # PR 25).
         toks_np, valid_np, lps_np, top_v_np, top_i_np, paths_np, *moe = (
             jax.device_get(outs))
-        self._sample_account(paths_np)
+        self.profiler.note_sample_paths(paths_np)
         self._moe_account(moe)
         step_s = time.perf_counter() - t0
         ph.to("decode.emit")
@@ -3629,22 +3593,8 @@ class Engine:
             if not finished:
                 self._slot_positions[i] = slot.position
         ph.to("decode.account")
-        if self.usage is not None:
-            self.usage.charge_decode(step_s, owners, tok_by_owner)
-            self._usage_sync_kv()
-        if self.profiler is not None:
-            self.profiler.note_dispatch(
-                "decode", t0, step_s, active=len(owners),
-                total_slots=self.cfg.decode_slots, n_steps=n_steps)
-        with self._lock:
-            self.total_generated += n_tokens
-            inst = n_tokens / step_s if step_s > 0 else 0.0
-            a = self.cfg.tps_ema_alpha
-            self.decode_tps_ema = (1 - a) * self.decode_tps_ema + a * inst
-            # Steady-state cadence: wall per decode step (one token per
-            # active slot per step) — tpu:decode_step_seconds.
-            self.phase_hist["decode_step"].observe(step_s / n_steps)
-            self.dispatch_steps_hist.observe(n_steps)
+        self._account_dispatch("decode", t0, step_s, owners, tok_by_owner,
+                               n_tokens, n_steps)
 
     # ------------------------------------------------------------------
     # pipelined decode: overlap host readback with the next device block
@@ -3888,7 +3838,7 @@ class Engine:
         toks_np, valid_np, lps_np, top_v_np, top_i_np, *tail = (
             jax.device_get(outs))
         if tail:
-            self._sample_account(tail[0])
+            self.profiler.note_sample_paths(tail[0])
             self._moe_account(tail[1:])
         ph.to("decode.emit")
         n_tokens = 0
@@ -3970,31 +3920,12 @@ class Engine:
         if blk.get("spec"):
             # First tokens come from prefill, not speculation.
             self.spec_emitted += n_tokens - n_pending
-        if self.usage is not None:
-            self.usage.charge_decode(step_s, owners, tok_by_owner)
-            self._usage_sync_kv()
-        if self.profiler is not None:
-            # Pipelined blocks overlap: block N+1's dispatch stamp
-            # predates block N's process end, so the profiler's gap math
-            # clamps to ~0 host-sync — exactly what the pipeline buys.
-            self.profiler.note_dispatch(
-                "spec" if blk.get("spec") else "decode", blk["t0"], step_s,
-                active=len(owners), total_slots=self.cfg.decode_slots,
-                n_steps=blk["n_steps"])
-        with self._lock:
-            self.total_generated += n_tokens
-            inst = n_tokens / step_s if step_s > 0 else 0.0
-            a = self.cfg.tps_ema_alpha
-            self.decode_tps_ema = (1 - a) * self.decode_tps_ema + a * inst
-            # Pipelined blocks overlap compute with readback, so step_s is
-            # the block's WALL (dispatch-to-process) — still the honest
-            # per-step cadence the gateway compares across replicas.
-            self.phase_hist["decode_step"].observe(
-                step_s / max(1, blk["n_steps"]))
-            if not blk.get("spec"):
-                # Planner decision record only — spec blocks' token-row
-                # counts are not power-of-two planner choices.
-                self.dispatch_steps_hist.observe(blk["n_steps"])
+        # Pipelined blocks overlap: block N+1's dispatch stamp predates
+        # block N's process end, so the profiler's gap clamps to ~0, and
+        # step_s is the block's WALL (dispatch-to-process).
+        self._account_dispatch("spec" if blk.get("spec") else "decode",
+                               blk["t0"], step_s, owners, tok_by_owner,
+                               n_tokens, blk["n_steps"])
 
     def _is_stop(self, req: Request, tok: int) -> bool:
         """Host stop oracle, evaluated once per emitted token in the

@@ -32,9 +32,8 @@ needs, the way the step profiler preceded the decode levers):
 
 Engine-thread-hot like the usage tracker: every ``note_*`` is a couple of
 dict ops under the ledger's own lock, and the state recount rides the
-existing per-dispatch KV sync.  ``bench.py``'s ``kv_ledger_ratio``
-microbench rides the <1.05 overhead bar; ``EngineConfig.kv_ledger`` is
-the A/B off switch.
+existing per-dispatch KV sync.  The engine builds one exactly when its
+cache is paged.
 """
 
 from __future__ import annotations

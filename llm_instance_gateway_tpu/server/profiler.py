@@ -23,8 +23,7 @@ disjoint buckets:
   ``tpu:dispatch_gap_seconds{kind="idle"}``.
 
 ``tools/profile_report.py`` renders the attribution table (shares of the
-three buckets summing to 100%); the committed ``PROFILE_BASELINE.json``
-run is the baseline every ROADMAP item-2 lever gets measured against.
+three buckets summing to 100%).
 Per-dispatch records (wall, gap, batch occupancy, step count, net slot
 churn) ride ``/debug/profile`` for timeline views.
 
@@ -45,9 +44,7 @@ the thread was in during each hole of the device's timeline.
 The recorder sits on the engine thread's hottest path, so it follows the
 usage tracker's budget discipline: ``note_dispatch`` is a few float ops
 + two histogram observes + a bounded-deque append per DISPATCH (not per
-token), behind the ``EngineConfig.step_profile`` off-switch that exists
-for the bench A/B (``step_profile_ratio`` <= 1.05), not for production
-use.  A phase transition is one clock read, one dict add and one tuple,
+token).  A phase transition is one clock read, one dict add and one tuple,
 plus the annotation (which tests an atomic flag when no trace is being
 taken); no lock.
 """

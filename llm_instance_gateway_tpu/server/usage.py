@@ -27,9 +27,7 @@ capacity to the {adapter} that used it:
   (bucket/ring padding tokens prefilled and thrown away).
 
 The tracker is engine-thread-hot: ``charge_decode`` is a handful of dict
-ops per DISPATCH (not per token), bounded by the <5% attribution-overhead
-bar ``bench.py``'s ``usage_attribution_ratio`` microbench rides on every
-emission.  All methods take the tracker's own lock only — safe to call
+ops per DISPATCH (not per token).  All methods take the tracker's own lock only — safe to call
 from the engine loop and snapshot from the scrape thread.
 """
 

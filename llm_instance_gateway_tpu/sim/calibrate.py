@@ -1,42 +1,34 @@
-"""Fit the simulator's latency model — from the live engine, or from the
-observables the gateway already scrapes.
+"""Fit the simulator's latency model from the observables the gateway
+already scrapes.
 
 The reference calibrated its simulator constants offline against vLLM on
-A100 (``constants.py:1-8``, notebook cells 2 & 5); this module does the same
-against OUR engine on the TPU it will serve from, so retuned scheduler
-thresholds transfer (SURVEY.md §7 step 7: "refit prefill/decode constants to
-TPU continuous batching ... before burning TPU hours").
+A100 (``constants.py:1-8``, notebook cells 2 & 5); this module fits the same
+constants for OUR engine, so retuned scheduler thresholds transfer
+(SURVEY.md §7 step 7: "refit prefill/decode constants to TPU continuous
+batching ... before burning TPU hours").
 
-Two calibration paths:
+``calibrate_from_observables`` fits the constants by least squares from
+per-window means of the histogram families every replica already exports
+(``tpu:prefill_seconds``, ``tpu:decode_step_seconds``,
+``tpu:decode_batch_occupancy``, KV occupancy) — so the capacity twin
+(gateway/capacity.py) self-calibrates from live traffic with **no TPU
+access**.  Each observation window is a dict of window means:
+``{prefill_tokens_mean, prefill_s_mean, kv_tokens_mean, batch_mean,
+decode_step_s_mean}``.
 
-- ``calibrate_from_engine`` times the engine's jitted prefill across bucket
-  lengths (linear fit prefill = c0 + c1 * tokens) and decode blocks across
-  cache fills (least-squares fit decode = c3 + c4 * kv_tokens + c_batch *
-  batch), all including the host dispatch/readback overhead the serving
-  loop actually pays.  Needs a live TPU (or the CPU bench engine).
-- ``calibrate_from_observables`` fits the SAME constants by least squares
-  from per-window means of the histogram families every replica already
-  exports (``tpu:prefill_seconds``, ``tpu:decode_step_seconds``,
-  ``tpu:decode_batch_occupancy``, KV occupancy) — so the capacity twin
-  (gateway/capacity.py) self-calibrates from live traffic with **no TPU
-  access**.  Each observation window is a dict of window means:
-  ``{prefill_tokens_mean, prefill_s_mean, kv_tokens_mean, batch_mean,
-  decode_step_s_mean}``.
+``main`` emits the versioned committed artifact (``TWIN_CALIBRATION.json``,
+format ``lig-twin-calibration/1``) with fit residuals, from deterministic
+windows that ``sim_observables`` draws of a known model; the gateway loads
+it via ``load_calibration``.
 
-Either path can emit the versioned committed artifact
-(``TWIN_CALIBRATION.json``, format ``lig-twin-calibration/1``) with fit
-residuals; the gateway loads it via ``load_calibration``.
-
-Run:  python -m llm_instance_gateway_tpu.sim.calibrate                # engine
-      python -m llm_instance_gateway_tpu.sim.calibrate --source sim \
-          --out TWIN_CALIBRATION.json                       # deterministic fit
+Run:  python -m llm_instance_gateway_tpu.sim.calibrate \
+          --out TWIN_CALIBRATION.json
 """
 
 from __future__ import annotations
 
 import json
 import random
-import time
 
 import numpy as np
 
@@ -57,113 +49,6 @@ _MODEL_ROUND = {
     "decode_per_kv_token_s": 12,
     "decode_per_seq_s": 9,
 }
-
-
-def _time_call(fn, n: int = 5) -> float:
-    """Average seconds per call.  ``fn`` returns a device array; only the
-    final handle is synced, so the n dispatches pipeline and the per-call
-    host round-trip is amortized instead of being paid n times — the fit
-    is after the device-side per-token slope."""
-    np.asarray(fn())  # warm (compile) + sync
-    t0 = time.perf_counter()
-    h = None
-    for _ in range(n):
-        h = fn()
-    np.asarray(h)
-    return (time.perf_counter() - t0) / n
-
-
-def calibrate_from_engine(
-    engine,
-    prefill_lengths: tuple[int, ...] = (64, 128, 256, 384),
-    decode_fills: tuple[int, ...] = (32, 128, 256, 448),
-    repeats: int = 10,
-) -> LatencyModel:
-    import jax
-    import jax.numpy as jnp
-
-    cfg = engine.model_cfg
-    usable = [
-        b for b in prefill_lengths
-        if b in engine.cfg.prefill_buckets and b < engine.cfg.max_seq_len
-    ] or [engine.cfg.prefill_buckets[0]]
-
-    # --- prefill: one padded prompt per bucket, incl. first-token readback.
-    xs, ys = [], []
-    for bucket in usable:
-        tokens = jnp.zeros((1, bucket), jnp.int32)
-        positions = jnp.broadcast_to(jnp.arange(bucket), (1, bucket)).astype(jnp.int32)
-
-        def call(bucket=bucket, tokens=tokens, positions=positions):
-            first, k, v, *_ = engine._jit_prefill(
-                engine.params, engine._lora_buffers(), tokens, positions,
-                jnp.int32(bucket), jnp.int32(-1),
-                jnp.float32(0.0), jnp.int32(0), jnp.float32(1.0),
-                jax.random.PRNGKey(0),
-            )
-            return first
-
-        xs.append(bucket)
-        ys.append(_time_call(call, repeats))
-    if len(xs) >= 2:
-        c1, c0 = np.polyfit(np.asarray(xs, np.float64), np.asarray(ys, np.float64), 1)
-        c1 = max(float(c1), 1e-7)
-        c0 = max(float(c0), 1e-4)
-    else:
-        c0, c1 = ys[0], 1e-6
-
-    # --- decode: the engine always steps ALL slots (lockstep batching), so
-    # batch size is structurally constant and cannot be a regressor — the
-    # varying signal is cache occupancy.  Fit per-step cost against total KV
-    # tokens read (b_slots * fill); attribute the batch-proportional part of
-    # the base cost to per_seq so the sim scales sanely at other slot counts.
-    n_steps = max(1, engine.cfg.decode_steps_per_sync)
-    b_slots = engine.cfg.decode_slots
-    kv_totals, times = [], []
-    for fill in decode_fills:
-        if fill >= engine.cfg.max_seq_len:
-            continue
-        tokens = jnp.zeros((b_slots,), jnp.int32)
-        positions = jnp.full((b_slots,), fill, jnp.int32)
-        slots = jnp.full((b_slots,), -1, jnp.int32)
-        t = jnp.zeros((b_slots,), jnp.float32)
-        k = jnp.zeros((b_slots,), jnp.int32)
-        p = jnp.ones((b_slots,), jnp.float32)
-
-        remaining = jnp.full((b_slots,), 1 << 20, jnp.int32)  # rows stay live
-
-        def call(tokens=tokens, positions=positions, slots=slots, t=t, k=k,
-                 p=p, remaining=remaining):
-            out = engine._jit_decode(
-                engine.params, engine._lora_buffers(), engine.cache,
-                tokens, positions, slots, t, k, p,
-                jax.random.PRNGKey(0), remaining, jnp.int32(-1),
-                n_steps=n_steps,
-            )
-            engine.cache = out[-2]  # donated in; reassign the new buffer
-            return out[0]
-
-        kv_totals.append(float(b_slots * fill))
-        times.append(_time_call(call, repeats) / n_steps)
-    if len(kv_totals) >= 2:
-        c4, c3 = np.polyfit(np.asarray(kv_totals), np.asarray(times), 1)
-        c4 = max(float(c4), 0.0)
-        c3 = max(float(c3), 1e-5)
-    else:
-        c3, c4 = times[0], 0.0
-    # Split the fixed per-step cost: half stays as the step floor, half
-    # scales with batch (a heuristic the fit cannot identify — documented).
-    c_batch = (c3 / 2.0) / b_slots
-    c3 = c3 / 2.0
-
-    return LatencyModel(
-        prefill_min_s=min(ys),
-        prefill_base_s=c0,
-        prefill_per_token_s=c1,
-        decode_base_s=c3,
-        decode_per_kv_token_s=c4,
-        decode_per_seq_s=c_batch,
-    )
 
 
 def calibrate_from_observables(
@@ -289,7 +174,7 @@ def sim_observables(
     Seeded draws of window-mean regressors (prompt tokens, KV occupancy,
     decode batch) pushed through the model's own ``prefill_s``/``decode_s``
     — the ground-truth half of the calibration recovery test, and the
-    source of the committed artifact (``--source sim``).  ``noise`` adds a
+    source of the committed artifact (``main``).  ``noise`` adds a
     seeded relative perturbation to the timing means so the recovery test
     can exercise the 10% tolerance rather than an exact algebraic inverse.
     """
@@ -351,67 +236,24 @@ def load_calibration(path: str) -> tuple[LatencyModel, dict]:
     return model_from_dict(art["model"]), art
 
 
-def _engine_fit() -> tuple[LatencyModel, dict, str]:
-    """Time the bench model's engine ON THE CHIP (fails without a TPU: a
-    CPU timing fitted here would feed the twin a device model that no
-    device produced)."""
-    import jax
-    import jax.numpy as jnp
-    import runpy
-    import os
-
-    from llm_instance_gateway_tpu import runtime
-
-    device = runtime.require_accelerator("sim/calibrate.py --source engine")
-    runtime.configure_compile_cache()
-    bench = runpy.run_path(
-        os.path.join(runtime.CHECKOUT, "bench.py")
-    )
-    from llm_instance_gateway_tpu.models import transformer
-    from llm_instance_gateway_tpu.server.engine import Engine, EngineConfig
-
-    cfg = bench["bench_model_cfg"]()
-    dtype = jnp.bfloat16
-    params = transformer.init_params(cfg, jax.random.PRNGKey(0), dtype=dtype)
-    engine = Engine(
-        cfg, params,
-        EngineConfig(decode_slots=16, max_seq_len=cfg.max_seq_len,
-                     prefill_buckets=(64, 128, 256, 384),
-                     decode_steps_per_sync=8),
-        dtype=dtype,
-    )
-    model = calibrate_from_engine(engine)
-    return (model, {"windows": 0, "note": "engine-timed fit, no residuals"},
-            f"{cfg.name}@{device.device_kind}")
-
-
 def main(argv: list | None = None) -> None:
     import argparse
 
     parser = argparse.ArgumentParser(
         description="fit the simulator LatencyModel and emit the versioned "
                     "calibration artifact the capacity twin loads")
-    parser.add_argument("--source", choices=("engine", "sim"), default="engine",
-                        help="engine: time the bench engine on the chip "
-                        "(fails without a TPU); sim: deterministic "
-                        "observables from a known model through "
-                        "calibrate_from_observables (no TPU)")
     parser.add_argument("--seed", type=int, default=0,
-                        help="seed for --source sim window generation")
+                        help="seed of the observation windows")
     parser.add_argument("--windows", type=int, default=24,
-                        help="observation windows for --source sim")
+                        help="observation windows drawn of V5E_DEFAULT")
     parser.add_argument("--out", default="",
                         help="write the artifact JSON here (e.g. "
                         "TWIN_CALIBRATION.json); default prints to stdout")
     args = parser.parse_args(argv)
 
-    if args.source == "sim":
-        obs = sim_observables(V5E_DEFAULT, seed=args.seed, windows=args.windows)
-        model, residuals = calibrate_from_observables(obs)
-        artifact = calibration_artifact(model, residuals, "sim", seed=args.seed)
-    else:
-        model, residuals, name = _engine_fit()
-        artifact = calibration_artifact(model, residuals, f"engine:{name}")
+    obs = sim_observables(V5E_DEFAULT, seed=args.seed, windows=args.windows)
+    model, residuals = calibrate_from_observables(obs)
+    artifact = calibration_artifact(model, residuals, "sim", seed=args.seed)
 
     if args.out:
         write_calibration(args.out, artifact)

@@ -11,8 +11,8 @@ shape as the real engine's.
 Latency model (BASELINE.md form, TPU-recalibrated):
     T_prefill = max(c_min, c0 + c1 * prompt_tokens)
     T_decode  = c3 + c4 * total_kv_tokens_in_batch + c_batch * batch_size
-Defaults come from measuring this repo's engine on a v5e chip via
-``sim.calibrate`` (see bench.py); the reference's A100 constants
+Defaults are ``V5E_DEFAULT`` below (recorded by an early round, not
+measured on today's engine); the reference's A100 constants
 (``constants.py:1-8``) remain available as ``A100_VLLM`` for comparison.
 """
 

@@ -248,8 +248,7 @@ class Tracer:
 
     The HOT PATH is a single ``deque.append`` of a tuple onto a flat,
     maxlen-bounded ring (GIL-atomic — no lock, no per-trace dict, no
-    eviction bookkeeping): record() sits on the proxy's per-request path
-    and is budgeted at <5% of a scheduler pick (bench.py enforces it).
+    eviction bookkeeping): record() sits on the proxy's per-request path.
     Grouping spans into per-trace JSON happens at EXPORT (/debug/traces),
     which is a debug endpoint and can afford the O(ring) walk.
 

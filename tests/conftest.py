@@ -17,9 +17,9 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # Arm the lock-order witness for the whole suite (lockwitness.py): every
 # lock the concurrency registry wires through witness_lock records its
 # per-thread acquisition order, and tests/test_concurrency.py asserts the
-# observed graph acyclic AND covered by the static lock-order rule.  The
-# overhead is bounded by the committed pick_witness_ratio microbench
-# (< 1.05), so the whole suite can afford to run witnessed.
+# observed graph acyclic AND covered by the static lock-order rule.  An
+# armed acquisition costs a thread-local list append, so the whole suite
+# runs witnessed.
 os.environ.setdefault("LIG_LOCK_WITNESS", "1")
 xla_flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in xla_flags:

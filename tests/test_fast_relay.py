@@ -4,7 +4,7 @@ The fast relay (proxy.py ``fast_relay=True``, the default) writes upstream
 chunks to the client verbatim — no per-chunk decode/split/re-encode — and
 parses the final usage chunk + ``[DONE]`` exclusion ONCE at stream end from
 raw tail bytes.  The pre-existing line-scanning relay is kept as the parity
-oracle (``--no-fast-relay``).  These tests pin chunk-for-chunk equality of
+oracle (``fast_relay=False``).  These tests pin chunk-for-chunk equality of
 everything the client and the metrics plane can observe: status, headers,
 trace-id echo, the relayed byte stream, error terminations, usage
 accounting, and the PR-4 retry interaction.

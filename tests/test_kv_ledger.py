@@ -204,6 +204,10 @@ def make_engine(params, **overrides):
     return eng
 
 
+# make_engine overrides for the contiguous-lane cache (no block economy).
+LANES = dict(paged_kv_block=None, prefix_cache=False)
+
+
 def mk_req(prompt, max_new=4):
     return Request(prompt_tokens=list(prompt), max_new_tokens=max_new,
                    sampling=SamplingParams(temperature=0.0))
@@ -325,11 +329,11 @@ class TestEngineConservation:
             engine.stop()
             pre.stop()
 
-    def test_off_switch_removes_families(self, params):
-        """EngineConfig.kv_ledger=False: no ledger, no tpu:kv_blocks*
-        families — the bench A/B's OFF side (the token-level
+    def test_lane_cache_has_no_ledger_and_no_families(self, params):
+        """The ledger exists exactly when the cache is paged: a lane
+        engine has none, and no tpu:kv_blocks* families (the token-level
         tpu:kv_tokens_* gauges are a separate, older surface)."""
-        engine = make_engine(params, kv_ledger=False)
+        engine = make_engine(params, **LANES)
         try:
             r = engine.generate(mk_req((5, 6, 7)), timeout_s=120)
             assert r.error is None
@@ -382,14 +386,14 @@ def test_api_http_debug_kv_endpoint(params):
     assert isinstance(payload["ring"], list)
 
 
-def test_api_http_debug_kv_404_when_disabled(params):
+def test_api_http_debug_kv_404_on_a_lane_cache(params):
     import asyncio
 
     from aiohttp.test_utils import TestClient, TestServer
 
     from llm_instance_gateway_tpu.server.api_http import ModelServer
 
-    engine = make_engine(params, kv_ledger=False)
+    engine = make_engine(params, **LANES)
 
     async def run():
         server = ModelServer(engine, tokenizer=None, model_name="tiny")
@@ -407,4 +411,4 @@ def test_api_http_debug_kv_404_when_disabled(params):
         body = asyncio.run(run())
     finally:
         engine.stop()
-    assert "disabled" in body["error"]["message"]
+    assert "not paged" in body["error"]["message"]

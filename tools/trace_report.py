@@ -12,10 +12,6 @@ Usage:
   python tools/trace_report.py traces.json --json
   python -m llm_instance_gateway_tpu.gateway.loadgen --requests 2000 \
       --trace-out /tmp/phases.json && python tools/trace_report.py /tmp/phases.json
-
-bench.py invokes the same table-building functions on the handoff
-microbench's requests, so every BENCH emission carries the per-phase
-latency breakdown.
 """
 
 from __future__ import annotations
