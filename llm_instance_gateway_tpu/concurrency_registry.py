@@ -475,6 +475,11 @@ CLASSES = (
                         note="decode steps by the sampler's path: the "
                              "engine thread adds at the decode readback, "
                              "the scrape reads a copy under the lock"),
+            SharedField("stage_ops", LOCK_GUARDED,
+                        writers=("note_stage_ops",),
+                        note="transfers and helper programs of decode "
+                             "staging: the engine thread adds at each "
+                             "dispatch, the scrape reads under the lock"),
             SharedField("_last_end", OWNER_PRIVATE,
                         writers=("note_dispatch",)),
             SharedField("_idle_pending", OWNER_PRIVATE,
@@ -578,8 +583,12 @@ CLASSES = (
                              "readback"),
             SharedField("_dev_counts", OWNER_PRIVATE,
                         writers=("_count_first_token", "_counts",
-                                 "_dispatch_block", "_do_decode_step",
-                                 "_register_slot")),
+                                 "_enqueue_decode", "_register_slot")),
+            SharedField("_counts_dummy", OWNER_PRIVATE,
+                        writers=("_enqueue_decode",),
+                        note="the penalty-free [B, 1] counts argument: "
+                             "donated to each decode block and handed "
+                             "back by it"),
             SharedField("_dev_tokens", OWNER_PRIVATE,
                         writers=("_activate_slot_pipelined",
                                  "_dispatch_block", "_dispatch_spec_block",

@@ -387,6 +387,12 @@ SERVER_FAMILIES = (
            "(temperature only) | filtered (some live row asks for top-k or "
            "top-p: the full-vocabulary sort); metrics_registry.SAMPLE_PATHS.",
            SERVER_SURFACE),
+    Family("tpu:decode_stage_ops_total", "counter", (),
+           "Host-to-device transfers and helper programs the engine issued "
+           "to stage its plain decode dispatches, the decode program's own "
+           "call not counted: over tpu:dispatch_steps_count, the trips "
+           "through JAX's dispatch a decode block costs before its call.",
+           SERVER_SURFACE),
     Family("tpu:prefill_seconds", "histogram", ("model", "role"),
            "Prefill compute latency.", SERVER_SURFACE),
     Family("tpu:handoff_seconds", "histogram", ("model", "role"),

@@ -29,6 +29,7 @@ import dataclasses
 import functools
 import hashlib
 import logging
+import math
 import queue as queue_mod
 import threading
 import time
@@ -71,6 +72,47 @@ MAX_LOGIT_BIAS = 32  # per-request logit_bias entries (static lanes)
 STEP_BUCKETS = (1, 2, 4, 8, 16, 32, 64)
 # Tokens/sec EMA smoothing for the exported throughput gauge.
 TPS_EMA_ALPHA = 0.2
+
+# The per-slot inputs of a decode step as (name, shape of one row, value of
+# an empty row), by dtype.  Each group is ONE flat host buffer: the engine's
+# ``_slot_<name>`` mirrors are views into it, so the ~40 sites that write a
+# row need no dirty mark, and a dispatch uploads two buffers, not fifteen
+# arrays.  ``_slot_views`` is the layout on both sides of the transfer.
+_SLOT_I32 = (
+    ("tokens", (), 0), ("positions", (), 0), ("lora", (), -1),
+    ("topk", (), 0), ("remaining", (), 0), ("seed", (), -1),
+    ("bias_ids", (MAX_LOGIT_BIAS,), -1),
+    ("stop_ids", (STOP_SEQS, STOP_LEN), -1), ("stop_lens", (STOP_SEQS,), 0),
+    ("stop_hist", (STOP_LEN,), -1),
+)
+_SLOT_F32 = (
+    ("temp", (), 0.0), ("topp", (), 1.0), ("presence", (), 0.0),
+    ("frequency", (), 0.0), ("bias_vals", (MAX_LOGIT_BIAS,), 0.0),
+)
+# Host-to-device transfers one staged decode dispatch issues: the two
+# buffers above (tpu:decode_stage_ops_total).
+STAGE_UPLOADS = 2
+
+
+def _slot_views(buf, fields, b: int) -> dict:
+    """``{name: buf's [b, *shape] part}`` for a flat buffer laid out as
+    ``fields``: views of a numpy buffer on the host, static slices of the
+    uploaded copy inside the decode program."""
+    out, at = {}, 0
+    for name, shape, _ in fields:
+        n = b * math.prod(shape)
+        out[name] = buf[at:at + n].reshape((b, *shape))
+        at += n
+    return out
+
+
+def _slot_buffer(fields, b: int, dtype) -> tuple[np.ndarray, dict]:
+    """A host buffer for ``b`` empty rows of ``fields``, and its views."""
+    buf = np.empty((b * sum(math.prod(s) for _, s, _ in fields),), dtype)
+    views = _slot_views(buf, fields, b)
+    for name, _, empty in fields:
+        views[name][...] = empty
+    return buf, views
 
 
 class EngineDraining(RuntimeError):
@@ -642,32 +684,41 @@ class Engine:
             self._ring_pad = max(8 * mesh.shape["sequence"],
                                  max(self.cfg.prefill_buckets))
         self.slots: list[_Slot | None] = [None] * b
-        self._slot_tokens = np.zeros((b,), np.int32)
-        self._slot_positions = np.zeros((b,), np.int32)
-        self._slot_lora = np.full((b,), -1, np.int32)
-        self._slot_temp = np.zeros((b,), np.float32)
-        self._slot_topk = np.zeros((b,), np.int32)
-        self._slot_topp = np.ones((b,), np.float32)
-        self._slot_seed = np.full((b,), -1, np.int32)
-        self._slot_presence = np.zeros((b,), np.float32)
-        self._slot_frequency = np.zeros((b,), np.float32)
-        self._slot_bias_ids = np.full((b, MAX_LOGIT_BIAS), -1, np.int32)
-        self._slot_bias_vals = np.zeros((b, MAX_LOGIT_BIAS), np.float32)
+        # Host mirrors of the decode program's per-slot inputs: views into
+        # two flat buffers (_SLOT_I32 / _SLOT_F32), written row by row where
+        # they always were and uploaded whole by ``_enqueue_decode``.
+        self._slots_i32, i32 = _slot_buffer(_SLOT_I32, b, np.int32)
+        self._slots_f32, f32 = _slot_buffer(_SLOT_F32, b, np.float32)
+        self._slot_tokens = i32["tokens"]
+        self._slot_positions = i32["positions"]
+        self._slot_lora = i32["lora"]
+        self._slot_temp = f32["temp"]
+        self._slot_topk = i32["topk"]
+        self._slot_topp = f32["topp"]
+        self._slot_seed = i32["seed"]
+        self._slot_presence = f32["presence"]
+        self._slot_frequency = f32["frequency"]
+        self._slot_bias_ids = i32["bias_ids"]
+        self._slot_bias_vals = f32["bias_vals"]
         # Generated-token occurrence counts, device-resident (transferring
         # [B, V] per dispatch would swamp the sync loop): rows zero at
         # registration, the decode scan updates them in its carry.
         self._dev_counts = None  # lazy: [B, V_padded] int32 on first use
+        # What a penalty-free dispatch passes instead (``penalized`` is the
+        # program's static flag): donated, and handed back by the program
+        # as the next dispatch's, so no step makes one.
+        self._counts_dummy = jnp.zeros((b, 1), jnp.int32)
         # Per-row token budget for device-side stop (0 = frozen row).
-        self._slot_remaining = np.zeros((b,), np.int32)
+        self._slot_remaining = i32["remaining"]
         self._eos_for_device = jnp.int32(-1 if eos_id is None else eos_id)
         # Device stop-string automata (server/sampling.py): per-row stop
         # suffix lanes (right-aligned, -1 padded) programmed at slot
         # registration, plus the host history scratch the sync loop
         # rebuilds per dispatch (the pipelined loop keeps its history
         # device-resident in the dispatch carry instead).
-        self._slot_stop_ids = np.full((b, STOP_SEQS, STOP_LEN), -1, np.int32)
-        self._slot_stop_lens = np.zeros((b, STOP_SEQS), np.int32)
-        self._slot_stop_hist = np.full((b, STOP_LEN), -1, np.int32)
+        self._slot_stop_ids = i32["stop_ids"]
+        self._slot_stop_lens = i32["stop_lens"]
+        self._slot_stop_hist = i32["stop_hist"]
         # Count of rows with programmed device stop lanes: gates the
         # per-dispatch history rebuild AND excludes speculative dispatch
         # (the spec block does not evaluate the automaton, so its history
@@ -808,6 +859,8 @@ class Engine:
             return tok[0], (lp[0], top_v[0], top_i[0])
 
         self._jit_sample_one = jax.jit(sample_one)
+        self._jit_next_key = jax.jit(_named(
+            "next_key", lambda key: tuple(jax.random.split(key))))
 
         if self._spec:
             if mesh is not None and mesh.size > 1 and (
@@ -921,13 +974,21 @@ class Engine:
 
     @staticmethod
     def _decode_impl(
-        model_cfg, step_fn, params, lora_bufs, cache, tokens, positions,
-        slot_ids, temp, topk, topp, key, remaining, eos_id, seeds,
-        presence, frequency, counts, bias_ids, bias_vals,
-        stop_ids, stop_lens, stop_hist,
+        model_cfg, step_fn, params, lora_bufs, cache, slots_i32, slots_f32,
+        carry, key, eos_id, counts,
         n_steps: int, penalized: bool = False,
     ):
         """``n_steps`` fused decode+sample steps with DEVICE-SIDE stop.
+
+        The per-slot inputs arrive as the engine's two flat buffers
+        (``_SLOT_I32`` / ``_SLOT_F32``) and are taken apart here by static
+        slices.  ``carry`` is None when the host record leads (the sync
+        loop: tokens, positions, budgets and stop history are the
+        buffers'), or the previous block's ``(tokens, positions, remaining,
+        stop_hist)`` outputs (the pipelined loop, whose host record lags
+        the device).  ``key`` is the ENGINE's key: the program splits it as
+        ``Engine._next_key`` does and returns the engine's next one, so the
+        decode step and the prefills still draw from one stream.
 
         Each row carries an activity state: ``remaining`` token budget and an
         implicit frozen flag (remaining <= 0 or EOS emitted).  Frozen rows
@@ -946,11 +1007,11 @@ class Engine:
         merely confirms the match once per dispatch.
 
         Returns (toks [K,B], valid [K,B], logprob triplet, paths [K],
-        next_tokens, next_positions, next_remaining, next_hist, counts,
-        cache, moe): ``paths`` the sampler's path of each step (an index
-        into ``metrics_registry.SAMPLE_PATHS``, chosen on the device by the
-        live rows' parameters), ``moe`` the block's routing counts of a
-        sparse model
+        next carry, next key, counts, cache, moe): ``paths`` the sampler's
+        path of each step (an index into ``metrics_registry.SAMPLE_PATHS``,
+        chosen on the device by the live rows' parameters), ``counts`` the
+        donated input handed back (updated when ``penalized``), ``moe`` the
+        block's routing counts of a sparse model
         (``transformer.MOE_TALLY``; they ride the cache through the steps),
         None for a dense one.
         Positions are clamped below max_seq_len so capped slots never write
@@ -961,7 +1022,19 @@ class Engine:
         else:
             max_len = cache["k"].shape[2]
 
-        c0 = tokens.shape[0]
+        c0 = counts.shape[0]
+        i32 = _slot_views(slots_i32, _SLOT_I32, c0)
+        f32 = _slot_views(slots_f32, _SLOT_F32, c0)
+        slot_ids, topk, seeds = i32["lora"], i32["topk"], i32["seed"]
+        bias_ids, stop_ids = i32["bias_ids"], i32["stop_ids"]
+        stop_lens = i32["stop_lens"]
+        temp, topp, bias_vals = f32["temp"], f32["topp"], f32["bias_vals"]
+        presence, frequency = f32["presence"], f32["frequency"]
+        if carry is None:
+            carry = (i32["tokens"], i32["positions"], i32["remaining"],
+                     i32["stop_hist"])
+        tokens, positions, remaining, stop_hist = carry
+        next_key, key = jax.random.split(key)
         cache = transformer.with_moe_tally(model_cfg, cache)
 
         def one_step(carry, step_key):
@@ -1017,14 +1090,12 @@ class Engine:
                          (cache, tokens, positions, remaining, stop_hist,
                           counts), keys)
         )
-        (cache, next_tokens, next_positions, next_remaining, next_hist,
-         counts) = carry
+        cache, *carry, counts = carry
         # The token/position/budget/history carries live on device for
         # pipelined dispatch of the following block (no host round-trip).
         moe = cache.pop("moe", None)
-        return (toks, valid, lps, top_v, top_i, paths,
-                next_tokens, next_positions, next_remaining, next_hist,
-                counts, cache, moe)
+        return (toks, valid, lps, top_v, top_i, paths, tuple(carry),
+                next_key, counts, cache, moe)
 
     # ------------------------------------------------------------------
     # public API
@@ -1125,15 +1196,16 @@ class Engine:
             p *= 2
         return p
 
-    def _sync_stop_hist(self) -> np.ndarray:
-        """The sync loop's per-dispatch stop-history input: each
-        stop-lane row's last STOP_LEN emitted tokens (right-aligned, -1
-        padded), rebuilt from the request's own output record — the host
-        record IS the history, so a fused block never sees a stale ring.
-        Stop-free batches skip the rebuild and pass the all--1 scratch."""
+    def _sync_stop_hist(self) -> None:
+        """The sync loop's per-dispatch stop-history input, written into
+        its mirror: each stop-lane row's last STOP_LEN emitted tokens
+        (right-aligned, -1 padded), rebuilt from the request's own output
+        record — the host record IS the history, so a fused block never
+        sees a stale ring.  Stop-free batches skip the rebuild and pass the
+        all--1 scratch."""
         hist = self._slot_stop_hist
         if not self._stops_active:
-            return hist  # all -1 by construction: nothing can match
+            return  # all -1 by construction: nothing can match
         hist[:] = -1
         for i, s in enumerate(self.slots):
             if s is None or not self._slot_stop_lens[i].any():
@@ -1141,18 +1213,39 @@ class Engine:
             tail = s.request.output_tokens[-STOP_LEN:]
             if tail:
                 hist[i, STOP_LEN - len(tail):] = tail
-        return hist
 
-    def _penalty_dispatch_args(self):
-        """(counts, penalized) for a decode dispatch: the real buffer only
-        when some active row carries a penalty (static flag -> two compiled
-        variants); otherwise a [B, 1] dummy so penalty-free serving never
-        allocates or streams the [B, V] counts."""
+    def _enqueue_decode(self, n_steps: int, carry=None):
+        """Stage and enqueue one plain decode block, for both loops: the
+        slot mirrors go up as their two buffers and nothing else is made
+        for the call — the engine's key, the penalty-free counts dummy and
+        the cache are each the previous block's output.  ``carry`` as in
+        ``_decode_impl``.  Returns the block's (toks, valid, lps, top_v,
+        top_i, paths), its next carry, and its routing counts with those
+        the prefills left (``_moe_drain``).
+
+        The buffers go as private host copies, never the mirrors
+        themselves: on the CPU backend an upload can alias its numpy
+        source (``_sync_tables``), and the pipelined loop rewrites rows
+        while the block is in flight.  ``counts`` is the real buffer only
+        when some row carries a penalty (static flag -> two compiled
+        variants), so penalty-free serving never allocates or streams
+        [B, V] counts."""
         penalized = bool(self._slot_presence.any()
                          or self._slot_frequency.any())
+        counts = self._counts() if penalized else self._counts_dummy
+        self.profiler.note_stage_ops(STAGE_UPLOADS)
+        with self._enqueue("engine.decode.enqueue"):
+            (*outs, carry, self._rng, counts, self.cache, moe) = (
+                self._jit_decode(
+                    self.params, self._lora_buffers(), self.cache,
+                    self._slots_i32.copy(), self._slots_f32.copy(), carry,
+                    self._rng, self._eos_for_device, counts,
+                    n_steps=n_steps, penalized=penalized))
         if penalized:
-            return self._counts(), True
-        return jnp.zeros((self.cfg.decode_slots, 1), jnp.int32), False
+            self._dev_counts = counts
+        else:
+            self._counts_dummy = counts
+        return outs, carry, self._moe_drain(moe)
 
     def _count_first_token(self, slot_idx: int, tok) -> None:
         """Penalty rows count their prefill-sampled first token too (vLLM
@@ -1832,7 +1925,10 @@ class Engine:
         )
 
     def _next_key(self):
-        self._rng, sub = jax.random.split(self._rng)
+        """A subkey for a program that is not the decode block (which
+        splits the engine's key itself): the split and its unpacking as
+        ONE small program, ``jit_next_key``."""
+        self._rng, sub = self._jit_next_key(self._rng)
         return sub
 
     def _lora_buffers(self):
@@ -3509,35 +3605,10 @@ class Engine:
         self._paged_ensure_decode(n_steps, pipelined=False)
         ph.to("decode.stage")
         t0 = time.perf_counter()
-        counts_arg, penalized = self._penalty_dispatch_args()
-        args = (
-            self.params, self._lora_buffers(), self.cache,
-            jnp.asarray(self._slot_tokens), jnp.asarray(self._slot_positions),
-            jnp.asarray(self._slot_lora),
-            jnp.asarray(self._slot_temp), jnp.asarray(self._slot_topk),
-            jnp.asarray(self._slot_topp), self._next_key(),
-            jnp.asarray(self._slot_remaining), self._eos_for_device,
-            jnp.asarray(self._slot_seed),
-            jnp.asarray(self._slot_presence),
-            jnp.asarray(self._slot_frequency), counts_arg,
-            jnp.asarray(self._slot_bias_ids),
-            jnp.asarray(self._slot_bias_vals),
-            jnp.asarray(self._slot_stop_ids),
-            jnp.asarray(self._slot_stop_lens),
-            jnp.asarray(self._sync_stop_hist()),
-        )
-        with self._enqueue("engine.decode.enqueue"):
-            (step_tokens, step_valid, step_lps, step_top_v, step_top_i,
-             paths, _, _, _, _, counts_out, self.cache, moe) = (
-                self._jit_decode(
-                    *args, n_steps=n_steps, penalized=penalized))
-        if penalized:
-            self._dev_counts = counts_out
-        moe = self._moe_drain(moe)
+        self._sync_stop_hist()
+        outs, _, moe = self._enqueue_decode(n_steps)
         ph.to("decode.wait")
-        outs = jax.block_until_ready(
-            (step_tokens, step_valid, step_lps, step_top_v, step_top_i,
-             paths, *moe))
+        outs = jax.block_until_ready((*outs, *moe))
         ph.to("decode.readback")
         # [n_steps, B] each.  One device_get for the lot: the copies start
         # together and the thread waits once, where one np.asarray per array
@@ -3717,35 +3788,13 @@ class Engine:
             idxs = jnp.asarray(self._pending_budget_zero, jnp.int32)
             self._dev_remaining = self._dev_remaining.at[idxs].set(0)
             self._pending_budget_zero.clear()
-        counts_arg, penalized = self._penalty_dispatch_args()
-        args = (
-            self.params, self._lora_buffers(), self.cache,
-            self._dev_tokens, self._dev_positions,
-            jnp.asarray(self._slot_lora),
-            jnp.asarray(self._slot_temp), jnp.asarray(self._slot_topk),
-            jnp.asarray(self._slot_topp), self._next_key(),
-            self._dev_remaining, self._eos_for_device,
-            jnp.asarray(self._slot_seed),
-            jnp.asarray(self._slot_presence),
-            jnp.asarray(self._slot_frequency), counts_arg,
-            jnp.asarray(self._slot_bias_ids),
-            jnp.asarray(self._slot_bias_vals),
-            jnp.asarray(self._slot_stop_ids),
-            jnp.asarray(self._slot_stop_lens),
-            self._dev_stop_hist,
-        )
-        with self._enqueue("engine.decode.enqueue"):
-            (toks, valid, lps, top_v, top_i, paths, next_tokens,
-             next_positions, next_remaining, next_hist, counts_out,
-             self.cache, moe) = (
-                self._jit_decode(*args, n_steps=n_steps, penalized=penalized))
-        if penalized:
-            self._dev_counts = counts_out
-        moe = self._moe_drain(moe)
-        self._dev_tokens = next_tokens
-        self._dev_positions = next_positions
-        self._dev_remaining = next_remaining
-        self._dev_stop_hist = next_hist
+            self.profiler.note_stage_ops(2)  # the indices up, the scatter
+        (toks, valid, lps, top_v, top_i, paths), carry, moe = (
+            self._enqueue_decode(
+                n_steps, (self._dev_tokens, self._dev_positions,
+                          self._dev_remaining, self._dev_stop_hist)))
+        (self._dev_tokens, self._dev_positions, self._dev_remaining,
+         self._dev_stop_hist) = carry
         # The sampler's paths ride with the routing counts: small arrays
         # the block's one readback brings back beside the tokens.
         tail = [paths, *moe]

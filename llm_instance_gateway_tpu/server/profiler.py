@@ -153,6 +153,10 @@ class StepProfiler:
         # Decode steps by the path the sampler took on the device
         # (metrics_registry.SAMPLE_PATHS, in that order).
         self.sample_steps = [0] * len(SAMPLE_PATHS)
+        # Host-to-device transfers and helper programs the engine issued
+        # to stage its plain decode dispatches (the decode program's own
+        # call not counted).
+        self.stage_ops = 0
         # End of the previous dispatch on the engine-thread clock; None
         # until the first dispatch (no gap to attribute yet).
         self._last_end: float | None = None
@@ -375,6 +379,12 @@ class StepProfiler:
         with self._lock:
             return dict(zip(SAMPLE_PATHS, self.sample_steps))
 
+    def note_stage_ops(self, n: int) -> None:
+        """Count ``n`` transfers or helper programs issued to stage a
+        plain decode dispatch."""
+        with self._lock:
+            self.stage_ops += n
+
     def hist_state(self) -> dict:
         """The small copy-out ``Engine.metrics_snapshot()`` embeds — the
         ``tpu:dispatch_wall_seconds`` / ``tpu:dispatch_gap_seconds``
@@ -386,6 +396,7 @@ class StepProfiler:
                          for p, h in sorted(self.wall_hist.items())},
                 "gap": {k: h.state()
                         for k, h in sorted(self.gap_hist.items())},
+                "stage_ops": self.stage_ops,
             }
         out["phases"] = self.phase_seconds()
         out["moe"] = self.moe_state()
@@ -455,4 +466,7 @@ def render_profile(hist: dict) -> list[str]:
         lines += [
             f'tpu:sample_steps_total{{path="{escape_label(path)}"}} {n}'
             for path, n in sample_steps.items()]
+    if "stage_ops" in hist:
+        lines += ["# TYPE tpu:decode_stage_ops_total counter",
+                  f"tpu:decode_stage_ops_total {hist['stage_ops']}"]
     return lines

@@ -379,11 +379,16 @@ class TestAbandonedHandoffRelease:
     sweep as the backstop) must free it instead of decoding tokens nobody
     will read."""
 
+    # Context of the decode engines whose slots blockers have to hold.
+    DEC_SEQ = 2048
+
     def _parked_attach(self, dec, pre):
         """Fill every decode slot, then attach a handoff so it PARKS in
         decode_wait (the abandoned-work position).  Returns (attached
         request, blockers)."""
-        blockers = [make_req(prompt=(1, 2, 3 + i), max_new=200)
+        # Long enough to outlast the prefill engine's compile and a TTL
+        # sweep however fast a tiny decode step is (``DEC_SEQ``).
+        blockers = [make_req(prompt=(1, 2, 3 + i), max_new=self.DEC_SEQ)
                     for i in range(2)]
         for b in blockers:
             dec.submit(b)
@@ -406,7 +411,7 @@ class TestAbandonedHandoffRelease:
 
     def test_release_request_frees_parked_attach(self):
         pre = make_engine(role="prefill")
-        dec = make_engine(role="decode")
+        dec = make_engine(role="decode", max_seq_len=self.DEC_SEQ)
         try:
             req, blockers = self._parked_attach(dec, pre)
             assert dec.release_request(req.request_id) is True
@@ -431,7 +436,8 @@ class TestAbandonedHandoffRelease:
         import on its own; a NON-handoff parked prefill is never TTL-swept
         (its caller is still waiting on done)."""
         pre = make_engine(role="prefill")
-        dec = make_engine(role="decode", handoff_ttl_s=0.3)
+        dec = make_engine(role="decode", handoff_ttl_s=0.3,
+                          max_seq_len=self.DEC_SEQ)
         try:
             req, blockers = self._parked_attach(dec, pre)
             assert req.done.wait(60)  # swept without any release call
@@ -451,7 +457,7 @@ class TestAbandonedHandoffRelease:
         from llm_instance_gateway_tpu.server.api_http import ModelServer
 
         pre = make_engine(role="prefill")
-        dec = make_engine(role="decode")
+        dec = make_engine(role="decode", max_seq_len=self.DEC_SEQ)
         try:
             req, blockers = self._parked_attach(dec, pre)
             server = ModelServer(dec, tokenizer=None, model_name="m")

@@ -288,11 +288,12 @@ class TestEngineConservation:
         """Conservation holds WHILE handoff-imported KV sits parked in
         decode_wait (the parked state counts block-equivalents held
         outside the pool, growing the budget)."""
-        engine = make_engine(params, decode_slots=2)
+        engine = make_engine(params, decode_slots=2, max_seq_len=2048)
         pre = make_engine(params, role="prefill", stream_lanes=1)
         try:
-            # Occupy both decode slots with long decodes.
-            occupiers = [mk_req(list(range(3, 11)), max_new=40)
+            # Occupy both decode slots with decodes that outlast the
+            # prefill engine's compile however fast a tiny step is.
+            occupiers = [mk_req(list(range(3, 11)), max_new=2000)
                          for _ in range(2)]
             for r in occupiers:
                 engine.submit(r)

@@ -641,6 +641,29 @@ class TestPhaseReport:
         assert split["stage_ms"] + split["wait_ms"] + split["readback_ms"] \
             == pytest.approx(split["wall_ms"])
 
+    def test_report_prints_staging_ops_per_dispatch(self):
+        """``tpu:decode_stage_ops_total`` over the decode dispatches, from
+        the same payload; none for a payload from before the counter."""
+        clock = FakeClock()
+        p = StepProfiler(capacity=8, clock=clock)
+        for _ in range(4):
+            p.note_stage_ops(2)
+            p.note_dispatch("decode", clock.now, 0.01, active=1,
+                            total_slots=4)
+            clock.tick(0.02)
+        p.note_stage_ops(2)  # a budget-zero scatter of the pipelined loop
+        row = profile_report.stage_ops_row(p.snapshot())
+        assert row == {"stage_ops": 10, "decode_dispatches": 4,
+                       "ops_per_dispatch": 2.5}
+        out = profile_report.render_report(p.snapshot())
+        assert "Decode staging:" in out and "2.5" in out
+        assert "tpu:decode_stage_ops_total 10" in render_profile(
+            p.hist_state())
+        old = p.snapshot()
+        del old["hist"]["stage_ops"]
+        assert profile_report.stage_ops_row(old) == {}
+        assert "Decode staging" not in profile_report.render_report(old)
+
 
 class TestXplaneGaps:
     """``--xplane``'s reduction, on a hand-made event list: device busy

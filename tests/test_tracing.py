@@ -280,12 +280,8 @@ class TestNamedScopes:
         import jax.numpy as jnp
 
         from llm_instance_gateway_tpu.server.engine import Engine
-        from llm_instance_gateway_tpu.server.sampling import (
-            STOP_LEN,
-            STOP_SEQS,
-        )
 
-        text = _decode_block_text(jax, jnp, Engine, STOP_LEN, STOP_SEQS)
+        text = _decode_block_text(jax, jnp, Engine)
         assert has_scope(text, scope)
         assert "jit(decode_block)" in text
 
@@ -301,12 +297,8 @@ class TestNamedScopes:
         import jax.numpy as jnp
 
         from llm_instance_gateway_tpu.server.engine import Engine
-        from llm_instance_gateway_tpu.server.sampling import (
-            STOP_LEN,
-            STOP_SEQS,
-        )
 
-        text = _decode_block_text(jax, jnp, Engine, STOP_LEN, STOP_SEQS)
+        text = _decode_block_text(jax, jnp, Engine)
         lines = text.splitlines()
         heavy = re.compile(
             r"stablehlo\.(sort|rng)|call @(sort|cumsum|_gumbel|_uniform"
@@ -347,10 +339,16 @@ class TestNamedScopes:
 
 
 @functools.lru_cache(maxsize=None)
-def _decode_block_text(jax, jnp, Engine, stop_len, stop_seqs) -> str:
+def _decode_block_text(jax, jnp, Engine) -> str:
+    import math
+
     from llm_instance_gateway_tpu.models import transformer
     from llm_instance_gateway_tpu.models.configs import TINY_TEST
-    from llm_instance_gateway_tpu.server.engine import _named
+    from llm_instance_gateway_tpu.server.engine import (
+        _SLOT_F32,
+        _SLOT_I32,
+        _named,
+    )
 
     cfg, b = TINY_TEST, 2
     params = jax.eval_shape(
@@ -358,17 +356,18 @@ def _decode_block_text(jax, jnp, Engine, stop_len, stop_seqs) -> str:
                                         dtype=jnp.float32))
     cache = jax.eval_shape(lambda: transformer.init_decode_cache(
         cfg, b, 32, dtype=jnp.float32))
-    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
-    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
     fn = jax.jit(
         _named("decode_block", Engine._decode_impl, cfg,
                transformer.decode_step),
         static_argnames=("n_steps", "penalized"))
+
+    def flat(fields, dtype):  # the engine's buffer of b rows of fields
+        return jax.ShapeDtypeStruct(
+            (b * sum(math.prod(shape) for _, shape, _ in fields),), dtype)
+
     return fn.lower(
-        params, None, cache, i32((b,)), i32((b,)), i32((b,)),
-        f32((b,)), i32((b,)), f32((b,)), jax.random.PRNGKey(0),
-        i32((b,)), jnp.int32(-1), i32((b,)), f32((b,)), f32((b,)),
-        i32((b, 1)), i32((b, 4)), f32((b, 4)),
-        i32((b, stop_seqs, stop_len)), i32((b, stop_seqs)),
-        i32((b, stop_len)), n_steps=1, penalized=False,
+        params, None, cache, flat(_SLOT_I32, jnp.int32),
+        flat(_SLOT_F32, jnp.float32), None, jax.random.PRNGKey(0),
+        jnp.int32(-1), jax.ShapeDtypeStruct((b, 1), jnp.int32),
+        n_steps=1, penalized=False,
     ).as_text(debug_info=True)

@@ -156,6 +156,20 @@ def decode_split(profile: dict) -> dict:
     return out
 
 
+def stage_ops_row(profile: dict) -> dict:
+    """Host-to-device transfers and helper programs the engine issued to
+    stage its decode dispatches (``tpu:decode_stage_ops_total``), and
+    their mean per decode dispatch; empty for a payload from before the
+    counter."""
+    hist = profile.get("hist") or {}
+    if "stage_ops" not in hist:
+        return {}
+    n = int(((hist.get("wall") or {}).get("decode") or {}).get("count", 0))
+    ops = int(hist["stage_ops"])
+    return {"stage_ops": ops, "decode_dispatches": n,
+            "ops_per_dispatch": round(ops / n, 3) if n else 0.0}
+
+
 # -- a device trace against the engine thread's annotations -----------------
 
 ANNOTATION_PREFIX = "engine."
@@ -553,6 +567,11 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
     if split:
         out += ["", "Recent decode dispatch, mean parts: " + ", ".join(
             f"{k}={v}" for k, v in split.items())]
+    staged = stage_ops_row(profile)
+    if staged:
+        out += ["", "Decode staging:",
+                _table([staged], ("stage_ops", "decode_dispatches",
+                                  "ops_per_dispatch"))]
     delta = host_sync_delta(profile, previous)
     if delta:
         out += ["", "Host-sync share vs previous baseline: "
