@@ -480,6 +480,11 @@ CLASSES = (
                         note="transfers and helper programs of decode "
                              "staging: the engine thread adds at each "
                              "dispatch, the scrape reads under the lock"),
+            SharedField("lora_rows", LOCK_GUARDED,
+                        writers=("note_lora_rows",),
+                        note="adapter rows of the decode steps: the engine "
+                             "thread adds at each dispatch, the scrape "
+                             "reads under the lock"),
             SharedField("_last_end", OWNER_PRIVATE,
                         writers=("note_dispatch",)),
             SharedField("_idle_pending", OWNER_PRIVATE,

@@ -393,6 +393,12 @@ SERVER_FAMILIES = (
            "call not counted: over tpu:dispatch_steps_count, the trips "
            "through JAX's dispatch a decode block costs before its call.",
            SERVER_SURFACE),
+    Family("tpu:lora_rows_total", "counter", (),
+           "Live rows whose LoRA slot is >= 0, summed over the steps of the "
+           "plain decode dispatches: over tpu:dispatch_steps_sum, the rows "
+           "of a step that use what it reads of the adapters (a step reads "
+           "every slot's matrices whether any row does or not).",
+           SERVER_SURFACE),
     Family("tpu:prefill_seconds", "histogram", ("model", "role"),
            "Prefill compute latency.", SERVER_SURFACE),
     Family("tpu:handoff_seconds", "histogram", ("model", "role"),

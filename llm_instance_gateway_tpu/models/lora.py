@@ -14,6 +14,12 @@ alpha/r.  Slot id -1 means "no adapter" (one_hot(-1) == 0 vector -> delta 0),
 which lets base-model and adapter requests share one decode batch — the
 multiplexing the gateway's LoRA-affinity routing assumes.
 
+The delta is computed BY SLOT (``lora_delta``): two matmuls a target over
+all slots at once, each row keeping its own slot's rank block in between.
+A step so reads every slot's matrices once for the batch, whatever its rows
+ask for — including a batch of base rows, which pays the read for nothing
+(``tpu:lora_rows_total`` counts the rows that use it).
+
 Targets: the attention projections q/k/v/o and the MLP gate/up/down, matching
 what vLLM serves for Llama-family adapters.
 """
@@ -123,25 +129,28 @@ def lora_delta(
     scale: jax.Array,      # [n_slots]
     slot_ids: jax.Array,   # [B] int32, -1 = no adapter
 ) -> jax.Array:
-    """Per-row multi-adapter delta: scale[s] * (x @ a[s]) @ b[s], s=slot_ids[row].
+    """Multi-adapter delta: scale[s] * (x @ a[s]) @ b[s], s = slot_ids[row].
 
-    Implemented with a one-hot mix instead of gather: ``one_hot`` rows for
-    slot -1 are all-zero, giving an exact 0 delta for base-model rows, and the
-    mixing contraction is a small matmul the MXU handles natively (n_slots is
-    4-8; the r-rank matmuls dominate and stay tiny).
+    By slot, not by row: the batch goes once through every slot's ``a``
+    (one ``[B, d_in] x [d_in, n_slots * r]`` matmul), a one-hot mask keeps
+    each row's own slot's rank block, and the kept blocks meet every slot's
+    ``b`` in one ``[B, n_slots * r] x [n_slots * r, d_out]`` matmul.  The
+    same ``r`` products per output as the row's own adapter alone, plus
+    exact zeros; ``a`` and ``b`` are each read once for the whole batch.
+    A slot -1 row's mask is all zero, so its delta is exactly 0.
+
+    Do not mix the matrices per row first (``einsum("bs,sir->bir")``): that
+    writes a private ``[B, d_in, r]`` and ``[B, r, d_out]`` copy for every
+    row of every target of every layer, which on a v5e cost a fifth of the
+    3584 x 18944 matmul a rank-16 delta corrects (PERF.md, PR 34).
     """
     n_slots = a.shape[0]
     onehot = jax.nn.one_hot(slot_ids, n_slots, dtype=x.dtype)  # [B, n_slots]
-    a_sel = jnp.einsum("bs,sir->bir", onehot, a)  # [B, d_in, r]
-    b_sel = jnp.einsum("bs,sro->bro", onehot, b)  # [B, r, d_out]
     s_sel = (onehot.astype(jnp.float32) @ scale).astype(x.dtype)  # [B]
-    if x.ndim == 3:
-        mid = jnp.einsum("bsi,bir->bsr", x, a_sel)
-        delta = jnp.einsum("bsr,bro->bso", mid, b_sel)
-        return delta * s_sel[:, None, None]
-    mid = jnp.einsum("bi,bir->br", x, a_sel)
-    delta = jnp.einsum("br,bro->bo", mid, b_sel)
-    return delta * s_sel[:, None]
+    x3 = x.reshape(x.shape[0], -1, x.shape[-1])  # a decode row: one position
+    mid = jnp.einsum("bti,sir->btsr", x3, a) * onehot[:, None, :, None]
+    delta = jnp.einsum("btsr,sro->bto", mid, b) * s_sel[:, None, None]
+    return delta.reshape(*x.shape[:-1], -1)
 
 
 def layer_slice(bufs: dict[str, Any], layer: jax.Array | int) -> dict[str, Any]:

@@ -1234,6 +1234,8 @@ class Engine:
                          or self._slot_frequency.any())
         counts = self._counts() if penalized else self._counts_dummy
         self.profiler.note_stage_ops(STAGE_UPLOADS)
+        self.profiler.note_lora_rows(
+            n_steps * int(np.count_nonzero(self._slot_lora >= 0)))
         with self._enqueue("engine.decode.enqueue"):
             (*outs, carry, self._rng, counts, self.cache, moe) = (
                 self._jit_decode(

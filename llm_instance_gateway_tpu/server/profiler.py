@@ -157,6 +157,9 @@ class StepProfiler:
         # to stage its plain decode dispatches (the decode program's own
         # call not counted).
         self.stage_ops = 0
+        # Live rows whose LoRA slot is >= 0, summed over the steps of the
+        # plain decode dispatches: the rows a step's adapter reads serve.
+        self.lora_rows = 0
         # End of the previous dispatch on the engine-thread clock; None
         # until the first dispatch (no gap to attribute yet).
         self._last_end: float | None = None
@@ -385,6 +388,12 @@ class StepProfiler:
         with self._lock:
             self.stage_ops += n
 
+    def note_lora_rows(self, n: int) -> None:
+        """Count ``n`` adapter rows (LoRA slot >= 0) over the steps of one
+        plain decode dispatch."""
+        with self._lock:
+            self.lora_rows += n
+
     def hist_state(self) -> dict:
         """The small copy-out ``Engine.metrics_snapshot()`` embeds — the
         ``tpu:dispatch_wall_seconds`` / ``tpu:dispatch_gap_seconds``
@@ -397,6 +406,7 @@ class StepProfiler:
                 "gap": {k: h.state()
                         for k, h in sorted(self.gap_hist.items())},
                 "stage_ops": self.stage_ops,
+                "lora_rows": self.lora_rows,
             }
         out["phases"] = self.phase_seconds()
         out["moe"] = self.moe_state()
@@ -469,4 +479,7 @@ def render_profile(hist: dict) -> list[str]:
     if "stage_ops" in hist:
         lines += ["# TYPE tpu:decode_stage_ops_total counter",
                   f"tpu:decode_stage_ops_total {hist['stage_ops']}"]
+    if "lora_rows" in hist:
+        lines += ["# TYPE tpu:lora_rows_total counter",
+                  f"tpu:lora_rows_total {hist['lora_rows']}"]
     return lines

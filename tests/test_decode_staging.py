@@ -183,7 +183,7 @@ class RestagingEngine(Engine):
                        next_hist), self._moe_drain(moe))
 
 
-def build(engine_cls, model: str, pipelined: bool):
+def build(engine_cls, model: str, pipelined: bool, slots: int = SLOTS):
     """An engine of ``engine_cls`` over seeded tiny weights and two
     adapters; same arguments, same weights."""
     cfg = MODELS[model]
@@ -199,7 +199,7 @@ def build(engine_cls, model: str, pipelined: bool):
             for t in ("q", "v")}, alpha=4.0, rank=2)
     return engine_cls(
         cfg, params,
-        EngineConfig(decode_slots=SLOTS, max_seq_len=256,
+        EngineConfig(decode_slots=slots, max_seq_len=256,
                      prefill_buckets=(8,), pipeline_decode=pipelined),
         lora_manager=lora, eos_id=None, dtype=jnp.float32)
 
@@ -394,6 +394,55 @@ def test_steady_dispatch_books_two_ops_and_draws_the_parents_stream(
     text = metrics.render(engine.metrics_snapshot())
     assert f"tpu:decode_stage_ops_total {ops}\n" in text + "\n"
     assert engine.profiler.snapshot()["hist"]["stage_ops"] == ops
+
+
+@pytest.mark.parametrize("pipelined", [False, True],
+                         ids=["sync", "pipelined"])
+def test_adapter_rows_are_booked_from_the_staged_buffer(pipelined):
+    """``tpu:lora_rows_total``: two adapter rows and one base row live
+    book 2 a step, read off the int32 buffer that goes up anyway, so a
+    dispatch still stages with its two uploads and nothing else."""
+    engine = build(Engine, "dense", pipelined, slots=3)
+    booked = []
+    note = engine.profiler.note_lora_rows
+
+    def spy(n):
+        live = [s for s in engine.slots if s is not None]
+        booked.append((n, sum(s.lora_slot >= 0 for s in live), len(live)))
+        note(n)
+
+    engine.profiler.note_lora_rows = spy
+    engine.start()
+    try:
+        reqs = [engine.submit(Request([3, 5, 7], 40, adapter=adapter))
+                for adapter in ("ad-a", None, "ad-b")]
+        for req in reqs:
+            assert req.done.wait(180) and req.error is None, req.error
+    finally:
+        engine.stop()
+    n = engine.profiler.dispatches["decode"]
+    assert len(booked) == n
+    assert all(rows == adapters for rows, adapters, _ in booked)
+    assert sum(1 for b in booked if b == (2, 2, 3)) >= 20
+    hist = engine.profiler.snapshot()["hist"]
+    assert hist["lora_rows"] == sum(rows for rows, _, _ in booked) > 0
+    assert hist["stage_ops"] == STAGE_UPLOADS * n  # the count cost none
+    text = metrics.render(engine.metrics_snapshot())
+    assert f"tpu:lora_rows_total {hist['lora_rows']}\n" in text + "\n"
+
+
+def test_base_rows_book_no_adapter_row():
+    engine = build(Engine, "dense", False)
+    engine.start()
+    try:
+        req = engine.generate(Request([3, 5, 7], 8), timeout_s=180)
+        assert req.error is None, req.error
+    finally:
+        engine.stop()
+    assert engine.profiler.dispatches["decode"] >= 7
+    assert engine.profiler.hist_state()["lora_rows"] == 0
+    assert "tpu:lora_rows_total 0\n" in metrics.render(
+        engine.metrics_snapshot()) + "\n"
 
 
 @pytest.fixture(scope="module")

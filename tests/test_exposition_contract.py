@@ -151,6 +151,7 @@ def server_snapshot() -> dict:
     # renders its whole closed label set (zero-valued series included).
     with prof.phase("decode.stage") as ph:
         ph.to("decode.wait")
+    prof.note_lora_rows(3)  # tpu:lora_rows_total
     return {
         "profile": prof.hist_state(),
         "model_name": HOSTILE,
@@ -277,6 +278,9 @@ def test_server_render_contract():
     phase_on = {(s.labels["phase"], s.labels["on"])
                 for s in families["tpu:engine_phase_seconds_total"]}
     assert phase_on == set(ENGINE_PHASES)
+    # Adapter rows of the decode steps, beside the staging counter.
+    assert families["tpu:lora_rows_total"][0].value == 3
+    assert families["tpu:decode_stage_ops_total"][0].value == 0
     # Decode fast-path families (adaptive dispatch + stream lanes).
     assert families["tpu:stream_lanes"][0].value == 2
     assert families["tpu:stream_lanes_active"][0].value == 1

@@ -163,7 +163,12 @@ class TestLoRA:
                 slot_ids=jnp.array([0], jnp.int32),
             )
             outs.append(np.asarray(logits))
-        np.testing.assert_allclose(outs[0], outs[1], rtol=1e-5)
+        # The padded lanes add exact zeros; what may differ is the order in
+        # which a matmul of another contraction length (slots x r_max) sums
+        # its float32 products: parts in 1e5 of the largest logit, which a
+        # purely relative bound would refuse at logits near 0.
+        np.testing.assert_allclose(outs[0], outs[1], rtol=1e-5,
+                                   atol=1e-5 * np.abs(outs[0]).max())
 
     def test_unload_restores_base(self):
         cfg = TINY_TEST

@@ -664,6 +664,33 @@ class TestPhaseReport:
         assert profile_report.stage_ops_row(old) == {}
         assert "Decode staging" not in profile_report.render_report(old)
 
+    def test_report_prints_adapter_rows_per_dispatch(self):
+        """``tpu:lora_rows_total`` (``note_lora_rows``): in ``/metrics``,
+        in ``/debug/profile``'s ``hist``, and over the decode dispatches
+        in the report; none for a payload from before the counter."""
+        clock = FakeClock()
+        p = StepProfiler(capacity=8, clock=clock)
+        for rows in (2, 0, 3, 1):
+            p.note_lora_rows(rows)
+            p.note_dispatch("decode", clock.now, 0.01, active=3,
+                            total_slots=4)
+            clock.tick(0.02)
+        assert p.snapshot()["hist"]["lora_rows"] == 6
+        row = profile_report.lora_rows_row(p.snapshot())
+        assert row == {"lora_rows": 6, "decode_dispatches": 4,
+                       "rows_per_dispatch": 1.5}
+        out = profile_report.render_report(p.snapshot())
+        assert "Adapter rows in the decode steps:" in out and "1.5" in out
+        lines = render_profile(p.hist_state())
+        assert "# TYPE tpu:lora_rows_total counter" in lines
+        assert "tpu:lora_rows_total 6" in lines
+        old = p.snapshot()
+        del old["hist"]["lora_rows"]
+        assert profile_report.lora_rows_row(old) == {}
+        assert "Adapter rows" not in profile_report.render_report(old)
+        assert not any("lora_rows" in ln
+                       for ln in render_profile(old["hist"]))
+
 
 class TestXplaneGaps:
     """``--xplane``'s reduction, on a hand-made event list: device busy

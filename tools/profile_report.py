@@ -156,18 +156,29 @@ def decode_split(profile: dict) -> dict:
     return out
 
 
-def stage_ops_row(profile: dict) -> dict:
-    """Host-to-device transfers and helper programs the engine issued to
-    stage its decode dispatches (``tpu:decode_stage_ops_total``), and
-    their mean per decode dispatch; empty for a payload from before the
-    counter."""
+def _per_decode_dispatch(profile: dict, key: str, mean_key: str) -> dict:
+    """One counter of ``hist`` and its mean per decode dispatch; empty for
+    a payload from before the counter."""
     hist = profile.get("hist") or {}
-    if "stage_ops" not in hist:
+    if key not in hist:
         return {}
     n = int(((hist.get("wall") or {}).get("decode") or {}).get("count", 0))
-    ops = int(hist["stage_ops"])
-    return {"stage_ops": ops, "decode_dispatches": n,
-            "ops_per_dispatch": round(ops / n, 3) if n else 0.0}
+    total = int(hist[key])
+    return {key: total, "decode_dispatches": n,
+            mean_key: round(total / n, 3) if n else 0.0}
+
+
+def stage_ops_row(profile: dict) -> dict:
+    """Host-to-device transfers and helper programs the engine issued to
+    stage its decode dispatches (``tpu:decode_stage_ops_total``)."""
+    return _per_decode_dispatch(profile, "stage_ops", "ops_per_dispatch")
+
+
+def lora_rows_row(profile: dict) -> dict:
+    """Live rows with a LoRA slot, summed over the decode steps
+    (``tpu:lora_rows_total``); per dispatch is per step where dispatches
+    are one step long."""
+    return _per_decode_dispatch(profile, "lora_rows", "rows_per_dispatch")
 
 
 # -- a device trace against the engine thread's annotations -----------------
@@ -572,6 +583,11 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
         out += ["", "Decode staging:",
                 _table([staged], ("stage_ops", "decode_dispatches",
                                   "ops_per_dispatch"))]
+    adapter_rows = lora_rows_row(profile)
+    if adapter_rows:
+        out += ["", "Adapter rows in the decode steps:",
+                _table([adapter_rows], ("lora_rows", "decode_dispatches",
+                                        "rows_per_dispatch"))]
     delta = host_sync_delta(profile, previous)
     if delta:
         out += ["", "Host-sync share vs previous baseline: "
