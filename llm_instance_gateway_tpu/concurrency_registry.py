@@ -485,6 +485,11 @@ CLASSES = (
                         note="adapter rows of the decode steps: the engine "
                              "thread adds at each dispatch, the scrape "
                              "reads under the lock"),
+            SharedField("latent_positions", LOCK_GUARDED,
+                        writers=("note_latent_positions",),
+                        note="latent cache rows the decode steps read: the "
+                             "engine thread adds at each dispatch, the "
+                             "scrape reads under the lock"),
             SharedField("_last_end", OWNER_PRIVATE,
                         writers=("note_dispatch",)),
             SharedField("_idle_pending", OWNER_PRIVATE,

@@ -393,6 +393,13 @@ SERVER_FAMILIES = (
            "call not counted: over tpu:dispatch_steps_count, the trips "
            "through JAX's dispatch a decode block costs before its call.",
            SERVER_SURFACE),
+    Family("tpu:latent_kv_positions_total", "counter", (),
+           "Cache positions a latent (MLA) model's live rows held, summed "
+           "over the steps of the plain decode dispatches: over "
+           "tpu:dispatch_steps_sum, the latent rows one decode step's "
+           "attention kernel reads per layer. 0 for a model with per-head "
+           "K/V lanes.",
+           SERVER_SURFACE),
     Family("tpu:lora_rows_total", "counter", (),
            "Live rows whose LoRA slot is >= 0, summed over the steps of the "
            "plain decode dispatches: over tpu:dispatch_steps_sum, the rows "

@@ -58,6 +58,32 @@ class ModelConfig:
     # OLMoE: RMSNorm on the whole projected q and k vectors (all heads
     # together), after the projection and before the split into heads.
     qk_norm: bool = False
+    # Latent attention (MLA; GLM-4.7-Flash, ``glm4_moe_lite``):
+    # ``kv_lora_rank`` > 0 makes every layer's keys and values come out of
+    # ONE normed latent of that width plus ONE rope key of
+    # ``qk_rope_head_dim`` shared by all heads, and that pair is all the
+    # cache holds (``latent_width`` numbers a position a layer).  Queries go
+    # through a normed bottleneck of ``q_lora_rank``; a head's query/key is
+    # ``qk_nope_head_dim`` + ``qk_rope_head_dim`` wide (= ``head_dim``), its
+    # value ``v_head_dim``.  ``models/mla.py`` holds the equations.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Sparse layers of that family.  ``first_k_dense`` leading layers keep
+    # the dense MLP of width ``d_ff``; the others route over ``n_experts``
+    # experts of width ``moe_d_ff`` (0: ``d_ff``) and add
+    # ``n_shared_experts`` always-on experts of the same width.
+    # ``router_sigmoid``: scores are sigmoid(logits); the experts are the
+    # top-k of score + a learned selection bias, the gates the UNBIASED
+    # scores (renormalised over the chosen under ``norm_topk_prob``) times
+    # ``routed_scaling_factor``.
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    first_k_dense: int = 0
+    router_sigmoid: bool = False
+    routed_scaling_factor: float = 1.0
     # LoRA serving slots (compile-time constants: resizing reshapes buffers
     # and recompiles, so they mirror vLLM's --max-loras / max rank flags).
     max_lora_slots: int = 4
@@ -80,6 +106,21 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def latent_width(self) -> int:
+        """Numbers a latent cache holds per position per layer (0: a
+        per-head K/V cache)."""
+        return self.kv_lora_rank and self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_lanes(self) -> int:
+        """``latent_width`` padded to whole 128-lane vregs: the cache row."""
+        return pad_to(self.latent_width, 128)
 
     @property
     def q_per_kv(self) -> int:
@@ -228,6 +269,45 @@ OLMOE_1B_7B = ModelConfig(
     qk_norm=True,
     max_seq_len=4096,
 )
+
+# zai-org/GLM-4.7-Flash (``glm4_moe_lite``): latent attention, one dense layer
+# then sparse ones (64 experts of width 1536, top-4 by sigmoid score + bias,
+# gates renormalised and scaled by 1.8, one shared expert).  The
+# multi-token-prediction layer is a draft head and is not part of the forward.
+GLM_4_7_FLASH = ModelConfig(
+    name="glm-4.7-flash",
+    vocab_size=154_880,
+    d_model=2048,
+    n_layers=47,
+    n_heads=20,
+    n_kv_heads=20,
+    d_ff=10_240,
+    head_dim=256,
+    rope_theta=1_000_000.0,
+    norm_eps=1e-5,
+    n_experts=64,
+    n_experts_per_token=4,
+    norm_topk_prob=True,
+    q_lora_rank=768,
+    kv_lora_rank=512,
+    qk_nope_head_dim=192,
+    qk_rope_head_dim=64,
+    v_head_dim=256,
+    moe_d_ff=1536,
+    n_shared_experts=1,
+    first_k_dense=1,
+    router_sigmoid=True,
+    routed_scaling_factor=1.8,
+    max_seq_len=202_752,
+    max_lora_slots=0,  # adapters are not served over latent projections
+)
+
+# The CPU's GLM: 1 dense + 2 sparse layers, 64 experts top-4 kept, the five
+# latent sizes distinct as the model's are (q/k head 24 + 8 = v head 32).
+TINY_GLM_TEST = replace(
+    GLM_4_7_FLASH.tiny(), name="glm-tiny", n_layers=3, n_kv_heads=4,
+    head_dim=32, q_lora_rank=48, kv_lora_rank=40, qk_nope_head_dim=24,
+    qk_rope_head_dim=8, v_head_dim=32, moe_d_ff=96)
 
 TINY_TEST = LLAMA3_8B.tiny()
 TINY_MOE_TEST = MIXTRAL_8X7B.tiny()

@@ -1,6 +1,8 @@
 """Sparse-expert entry points: Mixtral-8x7B (8 experts, top-2, renormalised
 gates) and OLMoE-1B-7B (64 experts, top-8, gates as the full softmax gives
-them, QK-norm).
+them, QK-norm) and GLM-4.7-Flash (latent attention, a leading dense layer,
+64 experts top-4 behind a sigmoid router with a selection bias, a shared
+expert).
 
 BASELINE.json's criticality-tiered mixed pool pairs Mixtral-8x7B with
 Gemma-7B on v5e-32.  The MoE MLP lives in ``transformer._moe_mlp``; expert
@@ -12,14 +14,17 @@ from __future__ import annotations
 
 from llm_instance_gateway_tpu.models import transformer
 from llm_instance_gateway_tpu.models.configs import (
+    GLM_4_7_FLASH,
     MIXTRAL_8X7B,
     OLMOE_1B_7B,
+    TINY_GLM_TEST,
     TINY_MOE_TEST,
     TINY_OLMOE_TEST,
 )
 
 CONFIGS = {"mixtral-8x7b": MIXTRAL_8X7B, "mixtral-tiny": TINY_MOE_TEST,
-           "olmoe-1b-7b": OLMOE_1B_7B, "olmoe-tiny": TINY_OLMOE_TEST}
+           "olmoe-1b-7b": OLMOE_1B_7B, "olmoe-tiny": TINY_OLMOE_TEST,
+           "glm-4.7-flash": GLM_4_7_FLASH, "glm-tiny": TINY_GLM_TEST}
 
 init_params = transformer.init_params
 init_decode_cache = transformer.init_decode_cache

@@ -27,6 +27,22 @@ The equations, for a pre-norm decoder block on ``x`` [S, D] at positions
              x = x + sum_i w_i * (silu(h_n Wg_i) * (h_n Wu_i)) Wd_i
     logits = RMSNorm(x) W_head
 
+Latent attention (GLM-4.7-Flash, ``kv_lora_rank`` > 0), the expanded form:
+
+    c_q = RMSNorm_q(x_n Wqa);  q_h = c_q Wqb,h = [q_nope_h | q_rope_h]
+    [c_kv | k_r] = x_n Wkva;  c = RMSNorm_kv(c_kv);  k_rope = RoPE(k_r), ONE
+    key for all heads;  q_rope_h = RoPE(q_rope_h)
+    [k_nope_h | v_h] = c Wkvb,h
+    a_h = softmax(([q_nope_h | q_rope_h] [k_nope_h | k_rope]^T)
+                  / sqrt(nope + rope) + causal mask) v_h;  x = x + concat(a) Wo
+
+and its sparse layers (the first ``first_k_dense`` layers keep the dense MLP):
+
+    s = sigmoid(h_n Wr) over all E, in float32; the experts are the k
+    largest of s + b (the selection bias); g_i = scale * s_i / (sum of the
+    chosen s + 1e-20): b picks and never weighs;
+    x = x + sum_i g_i E_i(h_n) + S(h_n),  S the shared expert
+
 A LoRA adapter adds ``scale * (z A) B`` to a projection of ``z``.
 
 Departures from the published descriptions, each on purpose:
@@ -106,25 +122,59 @@ def _attention(cfg, lp, layer, x_n, lora):
     return a @ _weight(lp["wo"], layer) + _lora(a, lora, layer, "o")
 
 
+def _latent_attention(cfg, lp, layer, x_n):
+    s = x_n.shape[0]
+    h, nope, rope = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    rank, vd = cfg.kv_lora_rank, cfg.v_head_dim
+    c_q = _rms_norm(x_n @ _weight(lp["wq_down"], layer),
+                    lp["q_latent_norm"][layer].astype(F32), cfg.norm_eps)
+    q = (c_q @ _weight(lp["wq_up"], layer)).reshape(s, h, nope + rope)
+    ckv = x_n @ _weight(lp["wkv_down"], layer)
+    c = _rms_norm(ckv[:, :rank], lp["kv_latent_norm"][layer].astype(F32),
+                  cfg.norm_eps)
+    k_rope = _rope(ckv[:, None, rank:], cfg.rope_theta)  # [S, 1, rope]
+    q_rope = _rope(q[..., nope:], cfg.rope_theta)
+    kv = (c @ _weight(lp["wkv_up"], layer)).reshape(s, h, nope + vd)
+    scores = (jnp.einsum("ihd,jhd->hij", q[..., :nope], kv[..., :nope])
+              + jnp.einsum("ihd,jd->hij", q_rope, k_rope[:, 0])
+              ) / jnp.sqrt(F32(nope + rope))
+    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+    probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
+    a = jnp.einsum("hij,jhd->ihd", probs, kv[..., nope:]).reshape(s, -1)
+    return a @ _weight(lp["wo"], layer)
+
+
 def _gated(z, wg, wu, wd):
     return (jax.nn.silu(z @ wg) * (z @ wu)) @ wd
 
 
 def _mlp(cfg, lp, layer, h_n, lora):
-    if not cfg.n_experts:
+    if "router" not in lp:  # a dense model, or a leading dense layer
         gate = h_n @ _weight(lp["w_gate"], layer) + _lora(h_n, lora, layer, "gate")
         up = h_n @ _weight(lp["w_up"], layer) + _lora(h_n, lora, layer, "up")
         act = jax.nn.silu(gate) * up
         return act @ _weight(lp["w_down"], layer) + _lora(act, lora, layer, "down")
-    p = jax.nn.softmax(h_n @ lp["router"][layer].astype(F32), axis=-1)  # [S, E]
-    kth = jnp.sort(p, axis=-1)[:, -cfg.n_experts_per_token][:, None]
-    w = jnp.where(p >= kth, p, 0.0)
-    if cfg.norm_topk_prob:
+    router = h_n @ lp["router"][layer].astype(F32)  # [S, E]
+    if cfg.router_sigmoid:
+        p = jax.nn.sigmoid(router)
+        pick = p + lp["router_bias"][layer].astype(F32)
+    else:
+        p = pick = jax.nn.softmax(router, axis=-1)
+    kth = jnp.sort(pick, axis=-1)[:, -cfg.n_experts_per_token][:, None]
+    w = jnp.where(pick >= kth, p, 0.0)
+    if cfg.router_sigmoid:
+        if cfg.norm_topk_prob:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w * cfg.routed_scaling_factor
+    elif cfg.norm_topk_prob:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     wg, wu, wd = (_weight(lp[n], layer) for n in ("w_gate", "w_up", "w_down"))
     y = jnp.zeros_like(h_n)
     for e in range(cfg.n_experts):
         y = y + w[:, e: e + 1] * _gated(h_n, wg[e], wu[e], wd[e])
+    if cfg.n_shared_experts:
+        y = y + _gated(h_n, *(_weight(lp[n], layer)
+                              for n in ("ws_gate", "ws_up", "ws_down")))
     return y
 
 
@@ -137,14 +187,24 @@ def forward(cfg, params, tokens, lora=None):
     if (cfg.tie_embeddings or cfg.embedding_scale or cfg.norm_plus_one
             or cfg.gelu_mlp or cfg.rope_scaling_factor):
         raise NotImplementedError(
-            "the reference covers the Llama/Qwen2/Mixtral/OLMoE block; the "
+            "the reference covers the Llama/Qwen2/Mixtral/OLMoE/GLM block; the "
             f"Gemma conventions and rope scaling of {cfg.name} are not in it")
-    lp = params["layers"]
+    if cfg.kv_lora_rank and lora is not None:
+        raise NotImplementedError("no adapter over latent projections")
+    # (stack, index in it) of every layer: leading dense layers, if the
+    # model has them, lie in a stack of their own.
+    n_dense = (params["dense_layers"]["attn_norm"].shape[0]
+               if "dense_layers" in params else 0)
+    stack = ([(params["dense_layers"], i) for i in range(n_dense)]
+             + [(params["layers"], i)
+                for i in range(cfg.n_layers - n_dense)])
     with jax.default_matmul_precision("highest"):
         x = params["embed"][tokens].astype(F32)
-        for layer in range(cfg.n_layers):
+        for lp, layer in stack:
             x_n = _rms_norm(x, lp["attn_norm"][layer].astype(F32), cfg.norm_eps)
-            x = x + _attention(cfg, lp, layer, x_n, lora)
+            x = x + (_latent_attention(cfg, lp, layer, x_n)
+                     if cfg.kv_lora_rank
+                     else _attention(cfg, lp, layer, x_n, lora))
             h_n = _rms_norm(x, lp["mlp_norm"][layer].astype(F32), cfg.norm_eps)
             x = x + _mlp(cfg, lp, layer, h_n, lora)
         x = _rms_norm(x, params["final_norm"].astype(F32), cfg.norm_eps)

@@ -1,9 +1,11 @@
-"""Core decoder-only transformer: one implementation, five families.
+"""Core decoder-only transformer: one implementation, six families.
 
 Covers Llama-3 (RoPE+GQA+SwiGLU), Gemma (tied embeddings, sqrt(d) embedding
 scale, GeLU gate, (1+w) RMSNorm, shared KV head), Qwen2 (QKV bias), Mixtral
-(top-k MoE MLP) and OLMoE (QK-norm, 64 experts top-8, gates not
-renormalised) via ``ModelConfig`` flags.
+(top-k MoE MLP), OLMoE (QK-norm, 64 experts top-8, gates not renormalised)
+and GLM-4.7-Flash (latent attention and a latent cache, ``models/mla.py``;
+a leading dense layer before the sparse ones; a sigmoid router with a
+selection bias and a shared expert) via ``ModelConfig`` flags.
 
 TPU-first structure:
 - Parameters are stacked over layers (``[n_layers, ...]`` leaves) and the
@@ -19,7 +21,8 @@ TPU-first structure:
   multiplexes adapters + base model.
 - Every block sits in a ``jax.named_scope`` (embed, attn.qkv, attn.rope,
   attn.kv_update, attn.core, attn.out, mlp, moe.route / .dispatch /
-  .experts, lora, lm_head, kv.insert): the scope is in each
+  .experts / .shared, lora, lm_head, kv.insert; a latent model's attn.q_latent,
+  attn.kv_latent, attn.absorb, attn.expand): the scope is in each
   compiled operation's name, so a device trace says which line of this file
   an operation belongs to.
 """
@@ -33,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from llm_instance_gateway_tpu.models import lora as lora_lib
+from llm_instance_gateway_tpu.models import mla
 from llm_instance_gateway_tpu.models.configs import ModelConfig
 from llm_instance_gateway_tpu.ops.attention import (
     decode_attention,
@@ -97,11 +101,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     ever whole on one device.
     """
     hd = cfg.resolved_head_dim
-    d, f, v = cfg.d_model, cfg.d_ff, cfg.padded_vocab
-    n_l = cfg.n_layers
-    keys = iter(jax.random.split(key, 16))
+    d, v = cfg.d_model, cfg.padded_vocab
+    keys = iter(jax.random.split(key, 32 if cfg.latent_width else 16))
     dtype = jnp.dtype(dtype)
-    layer_sh = None if shardings is None else shardings["layers"]
 
     def rand(tree_sh, name, shape, fan_in):
         quant = quantize and (name in QUANT_TARGETS or name == "lm_head")
@@ -114,36 +116,70 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
         x = jnp.full(shape, fill, dtype)
         return x if tree_sh is None else jax.device_put(x, tree_sh[name])
 
-    layers: Params = {
-        "attn_norm": const(layer_sh, "attn_norm", 1, (n_l, d)),
-        "mlp_norm": const(layer_sh, "mlp_norm", 1, (n_l, d)),
-        "wq": rand(layer_sh, "wq", (n_l, d, cfg.n_heads * hd), d),
-        "wk": rand(layer_sh, "wk", (n_l, d, cfg.n_kv_heads * hd), d),
-        "wv": rand(layer_sh, "wv", (n_l, d, cfg.n_kv_heads * hd), d),
-        "wo": rand(layer_sh, "wo", (n_l, cfg.n_heads * hd, d),
-                   cfg.n_heads * hd),
-    }
-    if cfg.attention_bias:
-        # Qwen2-family Q/K/V biases (zero init; checkpoints overwrite).
-        layers["wq_b"] = const(layer_sh, "wq_b", 0, (n_l, cfg.n_heads * hd))
-        layers["wk_b"] = const(layer_sh, "wk_b", 0,
-                               (n_l, cfg.n_kv_heads * hd))
-        layers["wv_b"] = const(layer_sh, "wv_b", 0,
-                               (n_l, cfg.n_kv_heads * hd))
-    if cfg.qk_norm:
-        layers["q_norm"] = const(layer_sh, "q_norm", 1, (n_l, cfg.n_heads * hd))
-        layers["k_norm"] = const(layer_sh, "k_norm", 1,
-                                 (n_l, cfg.n_kv_heads * hd))
-    if cfg.n_experts:
-        e = cfg.n_experts
-        layers["router"] = rand(layer_sh, "router", (n_l, d, e), d)
-        layers["w_gate"] = rand(layer_sh, "w_gate", (n_l, e, d, f), d)
-        layers["w_up"] = rand(layer_sh, "w_up", (n_l, e, d, f), d)
-        layers["w_down"] = rand(layer_sh, "w_down", (n_l, e, f, d), f)
-    else:
-        layers["w_gate"] = rand(layer_sh, "w_gate", (n_l, d, f), d)
-        layers["w_up"] = rand(layer_sh, "w_up", (n_l, d, f), d)
-        layers["w_down"] = rand(layer_sh, "w_down", (n_l, f, d), f)
+    def group(layer_sh, n_l: int, sparse: bool) -> Params:
+        """``n_l`` stacked layers of one kind."""
+        layers: Params = {
+            "attn_norm": const(layer_sh, "attn_norm", 1, (n_l, d)),
+            "mlp_norm": const(layer_sh, "mlp_norm", 1, (n_l, d)),
+        }
+        if cfg.latent_width:
+            for name, (shape, fan_in) in mla.leaf_shapes(cfg).items():
+                layers[name] = (
+                    rand(layer_sh, name, (n_l, *shape), fan_in) if fan_in
+                    else const(layer_sh, name, 1, (n_l, *shape)))
+        else:
+            layers["wq"] = rand(layer_sh, "wq", (n_l, d, cfg.n_heads * hd), d)
+            layers["wk"] = rand(layer_sh, "wk",
+                                (n_l, d, cfg.n_kv_heads * hd), d)
+            layers["wv"] = rand(layer_sh, "wv",
+                                (n_l, d, cfg.n_kv_heads * hd), d)
+            layers["wo"] = rand(layer_sh, "wo", (n_l, cfg.n_heads * hd, d),
+                                cfg.n_heads * hd)
+        if cfg.attention_bias:
+            # Qwen2-family Q/K/V biases (zero init; checkpoints overwrite).
+            layers["wq_b"] = const(layer_sh, "wq_b", 0,
+                                   (n_l, cfg.n_heads * hd))
+            layers["wk_b"] = const(layer_sh, "wk_b", 0,
+                                   (n_l, cfg.n_kv_heads * hd))
+            layers["wv_b"] = const(layer_sh, "wv_b", 0,
+                                   (n_l, cfg.n_kv_heads * hd))
+        if cfg.qk_norm:
+            layers["q_norm"] = const(layer_sh, "q_norm", 1,
+                                     (n_l, cfg.n_heads * hd))
+            layers["k_norm"] = const(layer_sh, "k_norm", 1,
+                                     (n_l, cfg.n_kv_heads * hd))
+        if sparse:
+            e, f = cfg.n_experts, cfg.expert_d_ff
+            layers["router"] = rand(layer_sh, "router", (n_l, d, e), d)
+            if cfg.router_sigmoid:
+                # Drawn non-zero (std 0.1 beside scores in (0, 1)): a router
+                # that ignores the selection bias must not pass a test.
+                layers["router_bias"] = rand(layer_sh, "router_bias",
+                                             (n_l, e), 100)
+            layers["w_gate"] = rand(layer_sh, "w_gate", (n_l, e, d, f), d)
+            layers["w_up"] = rand(layer_sh, "w_up", (n_l, e, d, f), d)
+            layers["w_down"] = rand(layer_sh, "w_down", (n_l, e, f, d), f)
+            if cfg.n_shared_experts:
+                fs = cfg.n_shared_experts * f
+                layers["ws_gate"] = rand(layer_sh, "ws_gate", (n_l, d, fs), d)
+                layers["ws_up"] = rand(layer_sh, "ws_up", (n_l, d, fs), d)
+                layers["ws_down"] = rand(layer_sh, "ws_down", (n_l, fs, d),
+                                         fs)
+        else:
+            f = cfg.d_ff
+            layers["w_gate"] = rand(layer_sh, "w_gate", (n_l, d, f), d)
+            layers["w_up"] = rand(layer_sh, "w_up", (n_l, d, f), d)
+            layers["w_down"] = rand(layer_sh, "w_down", (n_l, f, d), f)
+        return layers
+
+    def group_sh(name):
+        return None if shardings is None else shardings[name]
+
+    n_dense = cfg.first_k_dense if cfg.n_experts else 0
+    dense_layers = (group(group_sh("dense_layers"), n_dense, False)
+                    if n_dense else None)
+    layers = group(group_sh("layers"), cfg.n_layers - n_dense,
+                   bool(cfg.n_experts))
 
     params: Params = {
         "embed": rand(shardings, "embed", (v, d), None),
@@ -152,6 +188,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = rand(shardings, "lm_head", (d, v), d)
+    if dense_layers is not None:
+        params["dense_layers"] = dense_layers
     return params
 
 
@@ -164,7 +202,12 @@ def init_decode_cache(
     streaming the KV from HBM, and int8 halves that traffic vs bf16 (the
     JetStream serving trade); the dequantize multiply fuses into the
     attention reads, so HBM sees int8 while the MXU computes in ``dtype``.
-    Scale overhead is 1/(2*head_dim) of the bf16 cache."""
+    Scale overhead is 1/(2*head_dim) of the bf16 cache.  A latent model's
+    cache is ``mla.init_cache``: one row a position under ``k``, no ``v``."""
+    if cfg.latent_width:
+        if quantized:
+            raise ValueError("a latent (MLA) cache has no int8 form")
+        return mla.init_cache(cfg, batch, max_len, dtype)
     hd = cfg.resolved_head_dim
     shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, hd)
     cache = {
@@ -277,7 +320,7 @@ def _mlp(cfg: ModelConfig, lp: Params, x, layer_lora, slot_ids, live=None):
     dense model and the sparse layer's routing counts (``_moe_mlp``) for a
     sparse one.  ``live`` (bool, ``x``'s leading dims) marks the rows that
     hold a request; a dense MLP has no use for it."""
-    if cfg.n_experts:
+    if cfg.n_experts and "router" in lp:  # not a leading dense layer
         return _moe_mlp(cfg, lp, x, live)
     with jax.named_scope("mlp"):
         gate = _project(x, lp["w_gate"], layer_lora, "gate", slot_ids)
@@ -300,7 +343,10 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x, live=None):
 
     route: f32 softmax of the router; the k largest are the experts, their
     weights renormalised over the chosen (``norm_topk_prob``) or taken as
-    the full softmax gives them.  dispatch: each of the ``T*k`` assignments
+    the full softmax gives them.  ``router_sigmoid``: f32 sigmoid scores;
+    the experts are the k largest of score + ``router_bias``, their weights
+    the scores WITHOUT the bias, renormalised over the chosen
+    (``norm_topk_prob``) and scaled by ``routed_scaling_factor``.  dispatch: each of the ``T*k`` assignments
     gets a row in a layout grouped by expert, every group padded to whole
     tiles of ``tm`` rows (``pallas_moe.tile_rows``: from the mean group
     size, so static), by a one-hot cumsum: no sort, no capacity, nothing
@@ -314,7 +360,9 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x, live=None):
     ``lp["layer"]``: the kernel reads the layer where it lies.
 
     LoRA is not applied to expert weights (matching vLLM, which targets
-    attention + dense MLP only).  Returns ``(y, tally)``, ``MOE_TALLY``.
+    attention + dense MLP only).  A model with shared experts adds their
+    gated MLP of every token to the mix, once.  Returns ``(y, tally)``,
+    ``MOE_TALLY``.
     """
     orig_shape = x.shape
     d = orig_shape[-1]
@@ -333,12 +381,22 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x, live=None):
     with jax.named_scope("moe.route"):
         router_logits = jnp.dot(xf, lp["router"],
                                 preferred_element_type=jnp.float32)  # [T, E]
-        topv, topi = jax.lax.top_k(router_logits, k)
-        if cfg.norm_topk_prob:
-            gates = jax.nn.softmax(topv, axis=-1)  # [T, k]
+        if cfg.router_sigmoid:
+            scores = jax.nn.sigmoid(router_logits)
+            _, topi = jax.lax.top_k(
+                scores + lp["router_bias"].astype(jnp.float32), k)
+            gates = jnp.take_along_axis(scores, topi, axis=-1)  # [T, k]
+            if cfg.norm_topk_prob:
+                gates = gates / (jnp.sum(gates, axis=-1, keepdims=True)
+                                 + 1e-20)
+            gates = gates * cfg.routed_scaling_factor
         else:
-            gates = jnp.exp(topv - jax.nn.logsumexp(
-                router_logits, axis=-1, keepdims=True))
+            topv, topi = jax.lax.top_k(router_logits, k)
+            if cfg.norm_topk_prob:
+                gates = jax.nn.softmax(topv, axis=-1)  # [T, k]
+            else:
+                gates = jnp.exp(topv - jax.nn.logsumexp(
+                    router_logits, axis=-1, keepdims=True))
 
     with jax.named_scope("moe.dispatch"):
         expert = topi.reshape(-1)  # [T*k], token-major
@@ -371,6 +429,11 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x, live=None):
         got = out_e.at[row].get(mode="fill", fill_value=0)  # [T*k, D]
         y = jnp.sum(got.reshape(t, k, d).astype(jnp.float32)
                     * gates[..., None], axis=1).astype(xf.dtype)
+    if "ws_gate" in lp:
+        with jax.named_scope("moe.shared"):
+            y = y + q_matmul(
+                swiglu(q_matmul(xf, lp["ws_gate"]), q_matmul(xf, lp["ws_up"]),
+                       cfg.gelu_mlp), lp["ws_down"])
     return y.reshape(orig_shape), tally
 
 
@@ -392,6 +455,56 @@ def _layer_lp(lp: Params, stacks: Params | None, layer) -> Params:
 
 def _n_layers(params: Params) -> int:
     return params["layers"]["attn_norm"].shape[0]
+
+
+def _layer_groups(params: Params) -> list[Params]:
+    """The model's stacks of layers in forward order: one for a homogeneous
+    model; the leading dense layers (``first_k_dense``) and then the sparse
+    ones for a model that has both."""
+    if "dense_layers" in params:
+        return [params["dense_layers"], params["layers"]]
+    return [params["layers"]]
+
+
+def _scan_groups(params: Params, lora_bufs: Params | None, carry, body):
+    """``lax.scan`` over each group of layers in turn, one carry through
+    all.  ``body(carry, layer, lp, layer_lora) -> (carry, ys)``: ``layer``
+    counts through the whole model, ``lp`` is the layer's params with a
+    sparse group's expert stacks beside their index (``_layer_lp``).
+    Returns (carry, [each group's stacked ys])."""
+    per_layer_lora = None
+    if lora_bufs is not None:
+        per_layer_lora, _ = lora_lib.stack_for_scan(lora_bufs)
+    groups = _layer_groups(params)
+    if len(groups) > 1 and lora_bufs is not None:
+        raise NotImplementedError(
+            "LoRA buffers are one stack: not over two kinds of layers")
+    first, ys = 0, []
+    for layers in groups:
+        scanned, stacks = _layer_xs(layers)
+
+        def step(carry, xs, stacks=stacks, first=first):
+            lp, ll, i = xs
+            layer_lora = (None if ll is None
+                          else {**ll, "scale": lora_bufs["scale"]})
+            return body(carry, first + i if first else i,
+                        _layer_lp(lp, stacks, i), layer_lora)
+
+        n = layers["attn_norm"].shape[0]
+        carry, y = jax.lax.scan(
+            step, carry, (scanned, per_layer_lora, jnp.arange(n)))
+        ys.append(y)
+        first += n
+    return carry, ys
+
+
+def _stacked(parts: list):
+    """The groups' stacked outputs as one stack over the model's layers
+    (None where no group has any, as a dense model's tallies)."""
+    parts = [p for p in parts if p is not None]
+    if len(parts) <= 1:
+        return parts[0] if parts else None
+    return jnp.concatenate(parts, axis=0)
 
 
 def with_moe_tally(cfg: ModelConfig, cache: Params) -> Params:
@@ -419,6 +532,16 @@ def _tallied(cache: Params, new_cache: Params, tally) -> Params:
 # ---------------------------------------------------------------------------
 
 
+def _finish_block(cfg: ModelConfig, lp: Params, h, attn, layer_lora,
+                  slot_ids, live, kv):
+    """A latent layer from its attention output on: the output projection,
+    the residual, the MLP.  Returns ``(h, (*kv, tally))``."""
+    h = h + _attn_out(lp, attn, layer_lora, slot_ids)
+    hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
+    y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live)
+    return h + y, (*kv, tally)
+
+
 def prefill_layer(
     cfg: ModelConfig,
     lp: Params,              # one layer's params (leaves without the L dim)
@@ -440,6 +563,11 @@ def prefill_layer(
     if slot_ids is None:
         slot_ids = jnp.full((b,), -1, jnp.int32)
     hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
+    if cfg.latent_width:
+        # "k" is the layer's latent rows, keys and values both; "v" is empty.
+        attn, k = mla.prefill_attend(cfg, lp, hn, positions, attention_fn)
+        return _finish_block(cfg, lp, h, attn, layer_lora, slot_ids, live,
+                             (k, k[..., :0]))
     hd = cfg.resolved_head_dim
     q = _attn_proj(cfg, lp, "q", hn, layer_lora, slot_ids).reshape(b, s, cfg.n_heads, hd)
     k = _attn_proj(cfg, lp, "k", hn, layer_lora, slot_ids).reshape(b, s, cfg.n_kv_heads, hd)
@@ -478,7 +606,8 @@ def prefill(
 ):
     """Full-prompt forward.  Returns (logits [B,S,V] f32, k [L,B,S,K,hd], v),
     and with ``moe_tally`` a sparse model's routing counts (``MOE_TALLY``,
-    summed over layers) as a fourth.  With ``lengths`` the padding past a
+    summed over layers) as a fourth.  A latent model's ``k`` is its latent
+    rows [L,B,S,lanes] and its ``v`` empty [L,B,S,0] (``mla``).  With ``lengths`` the padding past a
     prompt's end routes to no expert (its outputs are garbage either way).
 
     ``attention_fn`` swaps the attention implementation — used by
@@ -490,25 +619,18 @@ def prefill(
         slot_ids = jnp.full((b,), -1, jnp.int32)
     h = _embed(cfg, params, tokens)
 
-    per_layer_lora = None
-    if lora_bufs is not None:
-        per_layer_lora, bcast = lora_lib.stack_for_scan(lora_bufs)
-
-    scanned, stacks = _layer_xs(params["layers"])
     live = (None if lengths is None
             else jnp.arange(s)[None] < jnp.reshape(lengths, (-1, 1)))
 
-    def layer_fn(h, xs):
-        lp, ll, layer = xs
-        layer_lora = None if ll is None else {**ll, "scale": lora_bufs["scale"]}
+    def layer_fn(h, layer, lp, layer_lora):
         return prefill_layer(
-            cfg, _layer_lp(lp, stacks, layer), h, positions,
+            cfg, lp, h, positions,
             layer_lora=layer_lora, slot_ids=slot_ids,
             attention_fn=attention_fn, live=live,
         )
 
-    xs = (scanned, per_layer_lora, jnp.arange(_n_layers(params)))
-    h, (k_all, v_all, tally) = jax.lax.scan(layer_fn, h, xs)
+    h, ys = _scan_groups(params, lora_bufs, h, layer_fn)
+    k_all, v_all, tally = (_stacked(list(part)) for part in zip(*ys))
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     logits = _lm_head(cfg, params, h)
     if moe_tally:
@@ -535,6 +657,8 @@ def _kv_carry(cache: Params) -> tuple:
     """The stacked cache arrays that ride the layer loop: (k, v)
     [L, B, S, K, hd], plus (k_scale, v_scale) [L, B, S, K] of an int8
     cache."""
+    if "v" not in cache:  # a latent cache: its rows are keys and values
+        return (cache["k"],)
     kv = (cache["k"], cache["v"])
     if "k_scale" in cache:
         kv += (cache["k_scale"], cache["v_scale"])
@@ -584,21 +708,13 @@ def _scan_cached_layers(params: Params, cache: Params,
     LoRA stack and the layer index; carry: the activations and the stacked
     cache.  ``layer_fn(h, kv, layer, lp, layer_lora) -> (h, kv, tally)``;
     returns (h, kv, the layers' tallies stacked or None)."""
-    per_layer_lora = None
-    if lora_bufs is not None:
-        per_layer_lora, _ = lora_lib.stack_for_scan(lora_bufs)
-    scanned, stacks = _layer_xs(params["layers"])
-
-    def body(carry, xs):
-        lp, ll, layer = xs
-        layer_lora = None if ll is None else {**ll, "scale": lora_bufs["scale"]}
-        h, kv, tally = layer_fn(*carry, layer, _layer_lp(lp, stacks, layer),
-                                layer_lora)
+    def body(carry, layer, lp, layer_lora):
+        h, kv, tally = layer_fn(*carry, layer, lp, layer_lora)
         return (h, kv), tally
 
-    xs = (scanned, per_layer_lora, jnp.arange(cache["k"].shape[0]))
-    (h, kv), tally = jax.lax.scan(body, (h, _kv_carry(cache)), xs)
-    return h, kv, tally
+    (h, kv), tallies = _scan_groups(
+        params, lora_bufs, (h, _kv_carry(cache)), body)
+    return h, kv, _stacked(tallies)
 
 
 @jax.named_scope("attn.core")
@@ -654,6 +770,9 @@ def decode_step(
     ``ops.sharded_attention`` to run the Pallas decode kernel shard-local
     under a GSPMD mesh.
     """
+    if cfg.latent_width and attention_fn is not None:
+        raise NotImplementedError(
+            "a latent (MLA) cache takes no attention override (--mesh)")
     b = tokens.shape[0]
     hd = cfg.resolved_head_dim
     if slot_ids is None:
@@ -667,6 +786,15 @@ def decode_step(
     # out of bounds, so inactive rows' updates are dropped whole.
     write_pos = (positions if active is None
                  else jnp.where(active, positions, s_max))
+
+    def latent_layer_fn(h, kv, layer, lp, layer_lora):
+        hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
+        attn, kv = mla.decode_attend(
+            cfg, lp, hn, positions, kv, (layer, batch_idx, write_pos),
+            lengths, layer)
+        h, (kv, tally) = _finish_block(cfg, lp, h, attn, layer_lora,
+                                       slot_ids, active, (kv,))
+        return h, kv, tally
 
     def layer_fn(h, kv, layer, lp, layer_lora):
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
@@ -682,7 +810,9 @@ def decode_step(
         y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=active)
         return h + y, kv, tally
 
-    h, kv, tally = _scan_cached_layers(params, cache, lora_bufs, h, layer_fn)
+    h, kv, tally = _scan_cached_layers(
+        params, cache, lora_bufs, h,
+        latent_layer_fn if cfg.latent_width else layer_fn)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     logits = _lm_head(cfg, params, h)
     return logits, _cache_of(cache, kv, lengths, tally)
@@ -711,6 +841,9 @@ def extend_step(
     mid-stream chunk prompt.  Returns (logits [B, C, V] f32, new cache) —
     logits[i] is the next-token distribution AFTER tokens[:, i].
     """
+    if cfg.latent_width:
+        raise NotImplementedError(
+            "extend_step (speculative verify) has no latent (MLA) form")
     b, c = tokens.shape
     hd = cfg.resolved_head_dim
     s_max = cache["k"].shape[2]
@@ -794,6 +927,15 @@ def prefill_with_cache(
     quant = "k_scale" in cache
     live = (jnp.arange(c) <= last_index)[None]  # the final chunk's padding
 
+    def latent_layer_fn(h, kv, layer, lp, layer_lora):
+        hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
+        attn, kv = mla.chunk_attend(
+            cfg, lp, hn, positions, kv, layer, slot,
+            functools.partial(_chunk_attend, cfg, False))
+        h, (kv, tally) = _finish_block(cfg, lp, h, attn, layer_lora,
+                                       slot_ids, live, (kv,))
+        return h, kv, tally
+
     def layer_fn(h, kv, layer, lp, layer_lora):
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         q = _attn_proj(cfg, lp, "q", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_heads, hd)
@@ -817,7 +959,9 @@ def prefill_with_cache(
         y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live)
         return h + y, kv, tally
 
-    h, kv, tally = _scan_cached_layers(params, cache, lora_bufs, h, layer_fn)
+    h, kv, tally = _scan_cached_layers(
+        params, cache, lora_bufs, h,
+        latent_layer_fn if cfg.latent_width else layer_fn)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     last_h = jax.lax.dynamic_index_in_dim(h[0], last_index, 0, keepdims=False)
     last_logits = _lm_head(cfg, params, last_h)
@@ -838,6 +982,10 @@ def insert_prefill(
     padded tail beyond it is garbage but masked by ``cache['length']``.
     """
     k = cache["k"]
+    if "v" not in cache:  # a latent cache: k_prompt [L, 1, S, lanes]
+        k = jax.lax.dynamic_update_slice(
+            k, k_prompt.astype(k.dtype), (0, slot, 0, 0))
+        return {"k": k, "length": cache["length"].at[slot].set(length)}
     v = cache["v"]
     if "k_scale" in cache:
         kq, ks = _kv_quantize(k_prompt)  # [L,1,S,K,hd] -> scales [L,1,S,K]

@@ -121,6 +121,26 @@ def prefill_attention(
     return out.reshape(b, s, n_heads, hd)
 
 
+def latent_decode_attention(
+    q: jax.Array,        # [B, n_heads, lanes] — queries absorbed into latents
+    rows: jax.Array,     # [B, S_max, lanes] — one layer's latent cache rows
+    lengths: jax.Array,  # [B]
+    n_values: int,       # leading columns of a row that are its value
+    scale: float,
+) -> jax.Array:
+    """Single-step attention over a latent (MLA) cache, the XLA form: every
+    head scores against the SAME row of a position (all its columns) and
+    sums the rows' first ``n_values`` columns.  Returns [B, n_heads,
+    n_values].  ``pallas_decode_attention.mla_decode_attention`` is the
+    kernel."""
+    logits = jnp.einsum("bhc,bsc->bhs", q, rows,
+                        preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(rows.shape[1])[None] < lengths[:, None]
+    logits = jnp.where(valid[:, None], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhs,bsc->bhc", probs, rows[..., :n_values])
+
+
 def decode_attention(
     q: jax.Array,        # [B, n_heads, hd] — one new token per sequence
     k_cache: jax.Array,  # [B, S_max, n_kv, hd]

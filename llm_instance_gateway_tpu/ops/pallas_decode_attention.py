@@ -30,6 +30,7 @@ from llm_instance_gateway_tpu.ops.attention import (
     decode_attention as xla_decode,
     gather_pool_rows,
     kernel_reason,
+    latent_decode_attention,
     log_choice,
 )
 
@@ -422,6 +423,143 @@ def paged_decode_attention(
                                       interpret=interpret)
     return decode_attention(q, rows(k_pool), rows(v_pool), lengths,
                             interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) variant: one row a position, keys and values in one tile
+# ---------------------------------------------------------------------------
+
+
+def _mla_kernel(len_ref, layer_ref, q_ref, c_ref, o_ref, m_scr, l_scr,
+                acc_scr, *, block_s: int, n_values: int, scale: float):
+    # q_ref: [1, H, lanes], the absorbed queries; c_ref: [1, block_s, lanes],
+    # one S-tile of the layer's latent rows [c | k_rope | 0].  The tile is
+    # read from HBM once and used twice: all its columns are the keys of
+    # EVERY head (one [H, lanes] x [lanes, block_s] matmul, no head of it
+    # masked away), its first ``n_values`` columns the values.  The same
+    # online-softmax sweep as ``_decode_kernel``.
+    del layer_ref  # consumed by the index maps
+    bi = pl.program_id(0)
+    sb = pl.program_id(1)
+    length = len_ref[bi]
+    start = sb * block_s
+
+    @pl.when(sb == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(start < length)
+    def _compute():
+        q = q_ref[0]
+        s = jax.lax.dot_general(
+            q, c_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [H, block_s]
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < length, s, NEG_INF)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[...] = jnp.broadcast_to(
+            l_scr[:, :1] * corr + p.sum(axis=-1, keepdims=True), l_scr.shape)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p.astype(q.dtype), c_ref[0, :, :n_values],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+
+    @pl.when(sb == pl.num_programs(1) - 1)
+    def _finalize():
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+def _mla_block(s_max: int) -> int:
+    """Positions a tile: 1024 where it divides the lane (a 1.3 MB tile:
+    half the grid steps of 512, of which a decode step of 32 rows x 13
+    layers over 4,096 positions makes 3,328; chosen by that count, other
+    sizes not timed), else the largest of 512/256/128 that does."""
+    for bs in (1024, 512, 256, 128):
+        if s_max % bs == 0:
+            return bs
+    return 0
+
+
+def mla_shape_reasons(s_max: int, lanes: int, n_values: int) -> list[str]:
+    reasons = []
+    if lanes % 128 or n_values % 128:
+        reasons.append(f"row of {lanes} lanes, {n_values} values: not whole "
+                       "128-lane vregs")
+    if not _mla_block(s_max):
+        reasons.append(f"s_max={s_max} % 128 != 0")
+    return reasons
+
+
+def mla_decode_attention_pallas(
+    q: jax.Array,       # [B, H, lanes] absorbed queries
+    rows: jax.Array,    # [L, B, S, lanes] latent cache (or [B, S, lanes])
+    lengths: jax.Array,  # [B] int32
+    n_values: int,      # leading columns of a row that are its value
+    scale: float,
+    layer=None,
+    block_s: int | None = None,
+    interpret: bool = False,
+) -> jax.Array:
+    if layer is None:
+        rows, layer = rows[None], 0
+    b, n_heads, lanes = q.shape
+    s_max = rows.shape[2]
+    block_s = block_s or _mla_block(s_max)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def q_index(bi, sb, lens, lay):
+        return (bi, 0, 0)
+
+    def row_index(bi, sb, lens, lay, block_s=block_s):
+        # Dead S-blocks revisit the last live tile: their DMA is elided.
+        last = jnp.maximum(lens[bi] - 1, 0) // block_s
+        return (lay[0], bi, jnp.minimum(sb, last), 0)
+
+    kernel = functools.partial(_mla_kernel, block_s=block_s,
+                               n_values=n_values, scale=float(scale))
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, n_heads, n_values), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # lengths, layer
+            grid=(b, s_max // block_s),
+            in_specs=[pl.BlockSpec((1, n_heads, lanes), q_index),
+                      pl.BlockSpec((None, 1, block_s, lanes), row_index)],
+            out_specs=pl.BlockSpec((1, n_heads, n_values), q_index),
+            scratch_shapes=_scratch(n_heads, n_values),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+        name="mla_decode_attention",
+    )(lengths, layer, q, rows)
+
+
+def mla_decode_attention(
+    q: jax.Array, rows: jax.Array, lengths: jax.Array, n_values: int,
+    scale: float, layer=None, use_kernel: bool = True,
+    interpret: bool = False,
+) -> jax.Array:
+    """Dispatch for the latent cache: the kernel over the stacked rows and
+    a layer index, the XLA form (``latent_decode_attention``) otherwise."""
+    reason = ("pallas kernels off in the config" if not use_kernel
+              else kernel_reason(
+                  mla_shape_reasons(rows.shape[-2], rows.shape[-1], n_values),
+                  interpret))
+    log_choice("mla_decode", f"q{tuple(q.shape)} cache{tuple(rows.shape)}",
+               reason, interpret)
+    if reason is not None:
+        return latent_decode_attention(q, _layer_view(rows, layer), lengths,
+                                       n_values, scale)
+    return mla_decode_attention_pallas(q, rows, lengths, n_values, scale,
+                                       layer, interpret=interpret)
 
 
 def decode_attention(

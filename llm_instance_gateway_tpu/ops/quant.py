@@ -26,7 +26,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-QUANT_TARGETS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+QUANT_TARGETS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+                 # a latent (MLA) layer's projections, a shared expert's
+                 "wq_down", "wq_up", "wkv_down", "wkv_up",
+                 "ws_gate", "ws_up", "ws_down")
 
 
 def quantize_weight(w: jax.Array) -> dict[str, jax.Array]:
@@ -87,23 +90,28 @@ def quantize_params(params: dict, quantize_lm_head: bool = True,
     (``parallel.sharding.param_shardings(..., quantized=True)``) — lands
     sharded, one leaf at a time, so the dense tree never has to fit."""
     out = dict(params)
-    layers = dict(params["layers"])
-    layer_sh = None if shardings is None else shardings["layers"]
 
     def quantized(w, sh):
         return _quantize_leaf(w, sharding=static_sharding(sh))
 
-    for name in QUANT_TARGETS:
-        w = layers.get(name)
-        if w is None or is_quantized(w):
+    # "dense_layers": the leading dense stack of a model that has one.
+    for group in ("layers", "dense_layers"):
+        if group not in params:
             continue
-        # Dense projections [L, in, out] AND MoE expert stacks
-        # [L, E, in, out] quantize the same way (per-output-channel over
-        # the last axis) — expert weights are exactly where Mixtral's
-        # HBM-bound decode spends its weight bandwidth.  The router stays
-        # dense (a tiny [d, E] matmul whose f32 logits drive top-k).
-        layers[name] = quantized(w, layer_sh and layer_sh[name])
-    out["layers"] = layers
+        layers = dict(params[group])
+        layer_sh = None if shardings is None else shardings[group]
+        for name in QUANT_TARGETS:
+            w = layers.get(name)
+            if w is None or is_quantized(w):
+                continue
+            # Dense projections [L, in, out] AND MoE expert stacks
+            # [L, E, in, out] quantize the same way (per-output-channel
+            # over the last axis) — expert weights are exactly where
+            # Mixtral's HBM-bound decode spends its weight bandwidth.  The
+            # router stays dense (a tiny [d, E] matmul whose f32 logits
+            # drive top-k).
+            layers[name] = quantized(w, layer_sh and layer_sh[name])
+        out[group] = layers
     if quantize_lm_head and "lm_head" in params and not is_quantized(params["lm_head"]):
         out["lm_head"] = quantized(params["lm_head"],
                                    shardings and shardings["lm_head"])

@@ -1460,7 +1460,11 @@ class ModelServer:
         cfg = self.engine.model_cfg
         keep = ("name", "vocab_size", "d_model", "n_layers", "n_heads",
                 "n_kv_heads", "d_ff", "attention_bias", "n_experts",
-                "n_experts_per_token", "norm_topk_prob", "qk_norm")
+                "n_experts_per_token", "norm_topk_prob", "qk_norm",
+                "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim", "moe_d_ff",
+                "n_shared_experts", "first_k_dense", "router_sigmoid",
+                "routed_scaling_factor")
         return web.json_response({
             "model": self.model_name,
             "platform": devices[0].platform,
@@ -1662,6 +1666,11 @@ def main(argv=None) -> None:
     logger.info("compile cache: %s", runtime.configure_compile_cache())
     import dataclasses
     cfg = dataclasses.replace(all_configs[args.model], max_lora_slots=args.max_loras)
+    if cfg.latent_width and args.max_loras > 0:
+        raise SystemExit(
+            f"{args.model} keeps a latent (MLA) KV cache: adapters are not "
+            "served over latent projections (models/lora.py sizes its "
+            "targets from per-head q, k, v); start it with --max-loras 0")
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
 
     tokenizer = load_tokenizer(args.tokenizer)
@@ -1745,8 +1754,10 @@ def main(argv=None) -> None:
         if mesh is None:  # on a mesh the engine replicates the draft
             draft_params = jax.device_put(draft_params)
 
-    lora_manager = LoRAManager(cfg, dtype=dtype, mesh=mesh,
-                               host_cache_slots=args.host_cache_slots)
+    # --max-loras 0: no adapter buffers at all, so no program computes a
+    # delta over zero slots.
+    lora_manager = None if args.max_loras == 0 else LoRAManager(
+        cfg, dtype=dtype, mesh=mesh, host_cache_slots=args.host_cache_slots)
     engine = Engine(
         cfg, params,
         EngineConfig(

@@ -499,6 +499,29 @@ class _ChunkStream:
     last_logits: object = None
 
 
+def _refuse_for_latent(model_cfg, cfg: "EngineConfig", lora_manager,
+                       mesh) -> None:
+    """A latent (MLA) cache is served from contiguous lanes on one device,
+    base model only.  Every other way to hold or move KV assumes per-head
+    K and V arrays; each is refused here by name rather than run wrong."""
+    asked = {
+        "--paged-kv-block (models/paged.py; the prefix cache needs it)":
+            cfg.paged_kv_block is not None or cfg.prefix_cache,
+        "--kv-quantize int8": cfg.kv_cache_quant is not None,
+        "--speculative (extend_step)": cfg.speculative_k > 0,
+        "--role prefill/decode (server/kv_transfer.py)":
+            cfg.role != "collocated",
+        "--mesh": mesh is not None and mesh.size > 1,
+        "--max-loras above 0 (models/lora.py sizes its targets from "
+        "per-head q, k, v)": lora_manager is not None,
+    }
+    for what, on in asked.items():
+        if on:
+            raise ValueError(
+                f"{model_cfg.name} keeps a latent (MLA) KV cache, which "
+                f"{what} does not serve yet")
+
+
 class Engine:
     def __init__(
         self,
@@ -535,6 +558,9 @@ class Engine:
         b = self.cfg.decode_slots
         self.paged = self.cfg.paged_kv_block is not None
         self._kv_quant = self.cfg.kv_cache_quant is not None
+        self._latent = bool(model_cfg.latent_width)
+        if self._latent:
+            _refuse_for_latent(model_cfg, self.cfg, lora_manager, mesh)
         if self.cfg.kv_cache_quant not in (None, "int8"):
             raise ValueError(
                 f"kv_cache_quant={self.cfg.kv_cache_quant!r}: only 'int8' "
@@ -1236,6 +1262,14 @@ class Engine:
         self.profiler.note_stage_ops(STAGE_UPLOADS)
         self.profiler.note_lora_rows(
             n_steps * int(np.count_nonzero(self._slot_lora >= 0)))
+        if self._latent:
+            # Step j of the block reads position + 1 + j rows of a live
+            # row's lane.  From the host record, which the pipelined loop
+            # keeps a block behind the device; a row that stops mid-block
+            # counts on to the block's end.
+            at = [s.position for s in self.slots if s is not None]
+            self.profiler.note_latent_positions(
+                sum(at) * n_steps + len(at) * n_steps * (n_steps + 1) // 2)
         with self._enqueue("engine.decode.enqueue"):
             (*outs, carry, self._rng, counts, self.cache, moe) = (
                 self._jit_decode(

@@ -160,6 +160,10 @@ class StepProfiler:
         # Live rows whose LoRA slot is >= 0, summed over the steps of the
         # plain decode dispatches: the rows a step's adapter reads serve.
         self.lora_rows = 0
+        # Cache rows the latent (MLA) decode kernel had to read: the live
+        # rows' cache lengths, summed over the steps of the plain decode
+        # dispatches.  0 for a model with per-head K/V lanes.
+        self.latent_positions = 0
         # End of the previous dispatch on the engine-thread clock; None
         # until the first dispatch (no gap to attribute yet).
         self._last_end: float | None = None
@@ -394,6 +398,12 @@ class StepProfiler:
         with self._lock:
             self.lora_rows += n
 
+    def note_latent_positions(self, n: int) -> None:
+        """Count ``n`` cache positions a latent model's live rows held over
+        the steps of one plain decode dispatch."""
+        with self._lock:
+            self.latent_positions += n
+
     def hist_state(self) -> dict:
         """The small copy-out ``Engine.metrics_snapshot()`` embeds — the
         ``tpu:dispatch_wall_seconds`` / ``tpu:dispatch_gap_seconds``
@@ -407,6 +417,7 @@ class StepProfiler:
                         for k, h in sorted(self.gap_hist.items())},
                 "stage_ops": self.stage_ops,
                 "lora_rows": self.lora_rows,
+                "latent_positions": self.latent_positions,
             }
         out["phases"] = self.phase_seconds()
         out["moe"] = self.moe_state()
@@ -482,4 +493,8 @@ def render_profile(hist: dict) -> list[str]:
     if "lora_rows" in hist:
         lines += ["# TYPE tpu:lora_rows_total counter",
                   f"tpu:lora_rows_total {hist['lora_rows']}"]
+    if "latent_positions" in hist:
+        lines += ["# TYPE tpu:latent_kv_positions_total counter",
+                  "tpu:latent_kv_positions_total "
+                  f"{hist['latent_positions']}"]
     return lines

@@ -692,6 +692,34 @@ class TestPhaseReport:
                        for ln in render_profile(old["hist"]))
 
 
+    def test_report_prints_latent_rows_per_dispatch(self):
+        """``tpu:latent_kv_positions_total`` (``note_latent_positions``):
+        in ``/metrics`` always, in the report only for a latent model."""
+        clock = FakeClock()
+        p = StepProfiler(capacity=8, clock=clock)
+        for positions in (100, 102, 104, 106):
+            p.note_latent_positions(positions)
+            p.note_dispatch("decode", clock.now, 0.01, active=3,
+                            total_slots=4)
+            clock.tick(0.02)
+        assert p.snapshot()["hist"]["latent_positions"] == 412
+        assert profile_report.latent_positions_row(p.snapshot()) == {
+            "latent_positions": 412, "decode_dispatches": 4,
+            "positions_per_dispatch": 103.0}
+        out = profile_report.render_report(p.snapshot())
+        assert "Latent cache rows read by the decode steps:" in out
+        assert "tpu:latent_kv_positions_total 412" in render_profile(
+            p.hist_state())
+        lanes = StepProfiler(capacity=8, clock=clock)
+        lanes.note_dispatch("decode", clock.now, 0.01, active=3,
+                            total_slots=4)
+        assert profile_report.latent_positions_row(lanes.snapshot()) == {}
+        assert "Latent cache" not in profile_report.render_report(
+            lanes.snapshot())
+        assert "tpu:latent_kv_positions_total 0" in render_profile(
+            lanes.hist_state())
+
+
 class TestXplaneGaps:
     """``--xplane``'s reduction, on a hand-made event list: device busy
     0-10, 14-20 and 30-40; the engine thread in decode.wait 0-9, then
@@ -844,6 +872,7 @@ class TestXplaneGaps:
 
         used = set()
         for rel in ("llm_instance_gateway_tpu/models/transformer.py",
+                    "llm_instance_gateway_tpu/models/mla.py",
                     "llm_instance_gateway_tpu/models/paged.py",
                     "llm_instance_gateway_tpu/models/lora.py",
                     "llm_instance_gateway_tpu/ops/layers.py",

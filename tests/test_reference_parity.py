@@ -27,6 +27,7 @@ from llm_instance_gateway_tpu.models import (
     transformer,
 )
 from llm_instance_gateway_tpu.models.configs import (
+    TINY_GLM_TEST,
     TINY_MOE_TEST,
     TINY_OLMOE_TEST,
     TINY_QWEN_TEST,
@@ -36,7 +37,10 @@ TOL = 1e-4          # of the largest reference logit of the sequence
 S_MAX, N_DECODE = 32, 8
 PROMPTS = (9, 5)    # two lanes of different lengths, decoded together
 CFGS = {"olmoe-tiny": TINY_OLMOE_TEST, "qwen-tiny": TINY_QWEN_TEST,
-        "mixtral-tiny": TINY_MOE_TEST}
+        "mixtral-tiny": TINY_MOE_TEST, "glm-tiny": TINY_GLM_TEST}
+# every norm weight, bias and selection bias a model can have
+_OFF_INIT = ("attn_norm", "mlp_norm", "q_norm", "k_norm", "wq_b", "wk_b",
+             "wv_b", "q_latent_norm", "kv_latent_norm")
 
 
 def make_model(cfg, seed=0):
@@ -45,15 +49,19 @@ def make_model(cfg, seed=0):
     params = transformer.init_params(cfg, jax.random.PRNGKey(seed),
                                      dtype=jnp.float32)
     key = jax.random.PRNGKey(seed + 100)
-    layers = dict(params["layers"])
-    for name in ("attn_norm", "mlp_norm", "q_norm", "k_norm",
-                 "wq_b", "wk_b", "wv_b"):
-        if name in layers:
-            key, k = jax.random.split(key)
-            base = 0.0 if name.endswith("_b") else 1.0
-            layers[name] = base + 0.3 * jax.random.normal(
-                k, layers[name].shape, jnp.float32)
-    return dict(params, layers=layers)
+    out = dict(params)
+    for group in ("dense_layers", "layers"):  # GLM: a dense stack first
+        if group not in params:
+            continue
+        layers = dict(params[group])
+        for name in _OFF_INIT:
+            if name in layers:
+                key, k = jax.random.split(key)
+                base = 0.0 if name.endswith("_b") else 1.0
+                layers[name] = base + 0.3 * jax.random.normal(
+                    k, layers[name].shape, jnp.float32)
+        out[group] = layers
+    return out
 
 
 def make_lora(cfg, slot=1, seed=7):
@@ -156,7 +164,8 @@ def parity_error(cfg, params, system, adapter, system_cfg=None,
     """Reference on (cfg, params); the system on the same unless a planted
     fault hands it something else."""
     seqs = sequences(cfg)
-    bufs = make_lora(cfg)
+    # a latent model serves no adapter: no buffers at all
+    bufs = None if cfg.latent_width else make_lora(cfg)
     # lane 0 on the adapter in slot 1, lane 1 on the base model
     slot_ids = jnp.asarray([1 if adapter else -1, -1], jnp.int32)
     want = reference_logits(cfg, params, seqs, None)
@@ -177,6 +186,11 @@ def model(request):
 @pytest.mark.parametrize("adapter", [False, True], ids=["base", "adapter"])
 def test_lanes_match_reference(model, adapter):
     cfg, params = model
+    if adapter and cfg.latent_width:
+        with pytest.raises(NotImplementedError):
+            reference.forward(cfg, params, jnp.zeros((4,), jnp.int32),
+                              (None, 0))
+        return
     assert parity_error(cfg, params, lane_logits, adapter) < TOL
 
 

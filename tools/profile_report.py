@@ -181,16 +181,27 @@ def lora_rows_row(profile: dict) -> dict:
     return _per_decode_dispatch(profile, "lora_rows", "rows_per_dispatch")
 
 
+def latent_positions_row(profile: dict) -> dict:
+    """Cache positions a latent (MLA) model's live rows held, summed over
+    the decode steps (``tpu:latent_kv_positions_total``); empty for a model
+    with per-head K/V lanes."""
+    if not (profile.get("hist") or {}).get("latent_positions"):
+        return {}
+    return _per_decode_dispatch(profile, "latent_positions",
+                                "positions_per_dispatch")
+
+
 # -- a device trace against the engine thread's annotations -----------------
 
 ANNOTATION_PREFIX = "engine."
 NO_ANNOTATION = "other"  # the bottom of the phase stack is not annotated
 # The jax.named_scope names the model code uses (models/transformer.py,
-# models/paged.py, models/lora.py, server/sampling.py, server/engine.py).
+# models/mla.py, models/paged.py, models/lora.py, server/sampling.py, server/engine.py).
 SCOPES = frozenset((
     "embed", "attn.qkv", "attn.rope", "attn.kv_update", "attn.core",
-    "attn.out", "mlp", "moe.route", "moe.dispatch", "moe.experts",
-    "lora", "lm_head", "sample", "sample.topk_sort",
+    "attn.out", "attn.q_latent", "attn.kv_latent", "attn.absorb",
+    "attn.expand", "mlp", "moe.route", "moe.dispatch", "moe.experts",
+    "moe.shared", "lora", "lm_head", "sample", "sample.topk_sort",
     "logprobs", "stops", "kv.insert"))
 
 
@@ -588,6 +599,11 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
         out += ["", "Adapter rows in the decode steps:",
                 _table([adapter_rows], ("lora_rows", "decode_dispatches",
                                         "rows_per_dispatch"))]
+    latent = latent_positions_row(profile)
+    if latent:
+        out += ["", "Latent cache rows read by the decode steps:",
+                _table([latent], ("latent_positions", "decode_dispatches",
+                                  "positions_per_dispatch"))]
     delta = host_sync_delta(profile, previous)
     if delta:
         out += ["", "Host-sync share vs previous baseline: "
