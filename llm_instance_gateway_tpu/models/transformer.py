@@ -759,8 +759,12 @@ def decode_step(
     """One decode step for every slot.  Returns (logits [B,V] f32, new cache).
 
     Inactive slots decode garbage LOGITS (masked out by the engine) but —
-    with ``active`` given — write NOTHING: their scatter index is pushed
-    out of bounds, where XLA drops the update.  Without the mask a frozen
+    with ``active`` given — write NOTHING and read nothing of their lanes:
+    their scatter index is pushed out of bounds, where XLA drops the
+    update, and the attention takes them at length 0 (a free slot keeps its
+    last request's position, so ``positions + 1`` is never 0 by itself),
+    for which the decode kernels copy no tile and compute no matmul
+    (``ops.pallas_decode_attention.held_tile``).  Without the mask a frozen
     or empty row keeps stomping its lane at a stale position, which is
     fatal once a lane can be mid-chunk-stream for a DIFFERENT request
     while decode dispatches run (the concurrent-lane engine); lockstep
@@ -780,6 +784,9 @@ def decode_step(
     h = _embed(cfg, params, tokens)  # [B, D]
 
     lengths = positions + 1
+    # What the attention reads of each lane: none of a row that sits out.
+    read_lengths = (lengths if active is None
+                    else jnp.where(active, lengths, 0))
     batch_idx = jnp.arange(b)
     s_max = cache["k"].shape[2]
     # Scatter address only — rope/masks keep the true positions.  s_max is
@@ -791,7 +798,7 @@ def decode_step(
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         attn, kv = mla.decode_attend(
             cfg, lp, hn, positions, kv, (layer, batch_idx, write_pos),
-            lengths, layer)
+            read_lengths, layer)
         h, (kv, tally) = _finish_block(cfg, lp, h, attn, layer_lora,
                                        slot_ids, active, (kv,))
         return h, kv, tally
@@ -804,7 +811,7 @@ def decode_step(
         q = apply_rope(q[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
         k = apply_rope(k[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
         kv = _write_kv(kv, (layer, batch_idx, write_pos), k, v)
-        attn = _decode_attend(cfg, attention_fn, q, kv, layer, lengths)
+        attn = _decode_attend(cfg, attention_fn, q, kv, layer, read_lengths)
         h = h + _attn_out(lp, attn.reshape(b, -1), layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=active)

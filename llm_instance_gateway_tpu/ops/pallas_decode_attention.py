@@ -11,7 +11,12 @@ index rides the scalar prefetch, and the tiles are taken from the cache's
 rows view (``_rows``), which costs no copy.
 
 Length masking is exact (positions >= length contribute nothing), matching
-the engine's garbage-tail cache contract.  ``decode_attention`` is the
+the engine's garbage-tail cache contract.  A row of length 0 (a slot that
+does not decode in this step: ``transformer.decode_step`` zeroes the length
+of every row whose ``active`` bit is off) costs no tile and no matmul: its
+grid steps point at the tile the step before them holds (``held_tile``), so
+nothing is copied, nothing is computed, and the row emits zeros.  What it
+still costs is its grid steps themselves.  ``decode_attention`` is the
 dispatching entry: the kernel on a TPU backend for every shape ``supports``
 accepts, the XLA reference otherwise — and it SAYS which, with the reason,
 each time a program is traced (``ops.attention.log_choice``).
@@ -35,6 +40,39 @@ from llm_instance_gateway_tpu.ops.attention import (
 )
 
 NEG_INF = -1e30
+
+
+def live_source(lengths: jax.Array) -> jax.Array:
+    """[B] int32 for ``held_tile``: each row's nearest live row (length > 0)
+    at or before it; for the dead rows that lead, the first live row; 0
+    with no live row.  A live row is its own source.  Built from the
+    lengths alone, so in a layer loop it is loop-invariant."""
+    idx = jnp.arange(lengths.shape[0], dtype=jnp.int32)
+    live = lengths > 0
+    # A running maximum over the live rows' indices; row 0, when dead,
+    # stands in with the first live row's, which then leads the maximum up
+    # to that row.  (The cumulative maximum comes last so that XLA lifts
+    # all of this out of a layer loop: compiled for the v5e, a trailing
+    # select stayed inside it.)
+    first = jnp.argmax(live).astype(jnp.int32)
+    return jax.lax.cummax(
+        jnp.where(live, idx, jnp.where(idx == 0, first, -1)))
+
+
+def held_tile(bi, sb, lens, src, block_s: int):
+    """The index rule of every decode kernel here: (row, S-tile) of the
+    cache that grid step (row ``bi``, S-block ``sb``) holds.  A live row
+    sweeps its own tiles and clamps the blocks past its length to its last
+    live tile; a dead row (length 0) holds its source's last live tile, or,
+    leading, the first live row's first tile.  Either way a step that has
+    nothing to read names the tile of the step before it, and Pallas
+    copies a block only when its index changes: short rows cost bandwidth
+    by their length, not by S_max, and dead rows none."""
+    row = src[bi]
+    last = jnp.maximum(lens[row] - 1, 0) // block_s
+    tile = jnp.where(row == bi, jnp.minimum(sb, last),
+                     jnp.where(row < bi, last, 0))
+    return row, tile
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *refs,
@@ -73,8 +111,9 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *refs,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Blocks entirely past `length` do nothing (their DMA is elided too —
-    # the index map revisits the last live tile); the straddling block masks.
+    # Blocks entirely past `length`, and every block of a row of length 0,
+    # do nothing (their DMA is elided too: ``held_tile`` names the tile the
+    # step before already holds); the straddling block masks.
     @pl.when(start < length)
     def _compute():
         q = q_ref[0]  # [H, hd]
@@ -115,18 +154,19 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *refs,
 
     @pl.when(sb == n_sb - 1)
     def _finalize():
-        # Rows with length == 0 never accumulate (l stays 0) and emit zeros;
-        # the engine treats such slots as garbage either way.
+        # Rows with length == 0 (slots that do not decode in this step) never
+        # accumulate (l stays 0) and emit zeros, not an unwritten buffer;
+        # the engine masks such rows either way.
         o_ref[0] = (
             acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
         ).astype(o_ref.dtype)
 
 
-def _indexed_kernel(len_ref, index_ref, *rest, **kw):
-    # The second scalar-prefetch operand (the lane kernel's layer index, the
-    # paged kernel's block table) is consumed by the index maps, not the
-    # body: only the DMA source moves.
-    del index_ref
+def _indexed_kernel(len_ref, src_ref, index_ref, *rest, **kw):
+    # The second and third scalar-prefetch operands (``live_source``; the
+    # lane kernel's layer index, the paged kernel's block table) are
+    # consumed by the index maps, not the body: only the DMA source moves.
+    del src_ref, index_ref
     _decode_kernel(len_ref, *rest, **kw)
 
 
@@ -205,18 +245,16 @@ def _pallas_decode_call(q, k_all, v_all, scales, lengths, layer,
     rows = block_s * n_kv
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def q_index(bi, sb, lens, lay):
+    def q_index(bi, sb, lens, src, lay):
         return (bi, 0, 0)
 
-    def kv_index(bi, sb, lens, lay, block_s=block_s):
-        # Clamp dead S-blocks (start >= length) to the last live tile so
-        # Pallas elides their HBM->VMEM copies: short rows in a long cache
-        # cost bandwidth proportional to their length, not to S_max.
-        last = jnp.maximum(lens[bi] - 1, 0) // block_s
-        return (lay[0], bi, jnp.minimum(sb, last), 0)
+    def kv_index(bi, sb, lens, src, lay):
+        row, tile = held_tile(bi, sb, lens, src, block_s)
+        return (lay[0], row, tile, 0)
 
-    def scale_index(bi, sb, lens, lay):
-        return (bi, 0, kv_index(bi, sb, lens, lay)[2])
+    def scale_index(bi, sb, lens, src, lay):
+        _, row, tile, _ = kv_index(bi, sb, lens, src, lay)
+        return (row, 0, tile)
 
     quant = scales is not None
     in_specs = [
@@ -224,7 +262,8 @@ def _pallas_decode_call(q, k_all, v_all, scales, lengths, layer,
         pl.BlockSpec((None, 1, rows, hd), kv_index),
         pl.BlockSpec((None, 1, rows, hd), kv_index),
     ]
-    operands = [lengths, layer, q, _rows(k_all), _rows(v_all)]
+    operands = [lengths, live_source(lengths), layer, q,
+                _rows(k_all), _rows(v_all)]
     if quant:
         in_specs += [pl.BlockSpec((1, 1, rows), scale_index)] * 2
         operands += [_scale_rows(s) for s in scales]
@@ -234,8 +273,9 @@ def _pallas_decode_call(q, k_all, v_all, scales, lengths, layer,
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, n_heads, hd), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            # lengths: masking + DMA clamping; layer: which of the stack
-            num_scalar_prefetch=2,
+            # lengths: masking + DMA clamping; their live_source: what a
+            # dead row's steps hold; layer: which of the stack
+            num_scalar_prefetch=3,
             grid=(b, s_max // block_s),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, n_heads, hd), q_index),
@@ -342,7 +382,8 @@ def paged_decode_attention_pallas(
     prefetch (vLLM-PagedAttention's indirection, Pallas-style): the index
     map of each (row, logical-block) grid cell looks up the physical block
     and the DMA streams it straight from the pool, once.  Dead blocks
-    (start >= length) clamp to the row's last live LOGICAL block — whose
+    (start >= length) clamp to the row's last live LOGICAL block, and a
+    row of length 0 holds its source row's (``held_tile``) — whose
     physical index the revisited map returns again, so Mosaic elides their
     copies exactly like the lane kernel.  Composes with int8 pools: scale
     columns ride the same indirection.
@@ -353,12 +394,11 @@ def paged_decode_attention_pallas(
     m = tables.shape[1]
     rows = block * n_kv
 
-    def q_index(bi, sb, lens, tabs):
+    def q_index(bi, sb, lens, src, tabs):
         return (bi, 0, 0)
 
-    def kv_index(bi, sb, lens, tabs, block=block):
-        last = jnp.maximum(lens[bi] - 1, 0) // block
-        return (tabs[bi, jnp.minimum(sb, last)], 0, 0)
+    def kv_index(bi, sb, lens, src, tabs):
+        return (tabs[held_tile(bi, sb, lens, src, block)], 0, 0)
 
     quant = k_scale is not None
     in_specs = [
@@ -366,7 +406,8 @@ def paged_decode_attention_pallas(
         pl.BlockSpec((1, rows, hd), kv_index),
         pl.BlockSpec((1, rows, hd), kv_index),
     ]
-    operands = [lengths, tables, q, _rows(k_pool), _rows(v_pool)]
+    operands = [lengths, live_source(lengths), tables, q,
+                _rows(k_pool), _rows(v_pool)]
     if quant:
         in_specs += [pl.BlockSpec((1, 1, rows), kv_index)] * 2
         operands += [_scale_rows(k_scale), _scale_rows(v_scale)]
@@ -378,7 +419,8 @@ def paged_decode_attention_pallas(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, n_heads, hd), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # lengths (masking) + tables (DMA routing)
+            # lengths (masking), live_source + tables (DMA routing)
+            num_scalar_prefetch=3,
             grid=(b, m),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, n_heads, hd), q_index),
@@ -430,15 +472,15 @@ def paged_decode_attention(
 # ---------------------------------------------------------------------------
 
 
-def _mla_kernel(len_ref, layer_ref, q_ref, c_ref, o_ref, m_scr, l_scr,
-                acc_scr, *, block_s: int, n_values: int, scale: float):
+def _mla_kernel(len_ref, src_ref, layer_ref, q_ref, c_ref, o_ref, m_scr,
+                l_scr, acc_scr, *, block_s: int, n_values: int, scale: float):
     # q_ref: [1, H, lanes], the absorbed queries; c_ref: [1, block_s, lanes],
     # one S-tile of the layer's latent rows [c | k_rope | 0].  The tile is
     # read from HBM once and used twice: all its columns are the keys of
     # EVERY head (one [H, lanes] x [lanes, block_s] matmul, no head of it
     # masked away), its first ``n_values`` columns the values.  The same
     # online-softmax sweep as ``_decode_kernel``.
-    del layer_ref  # consumed by the index maps
+    del src_ref, layer_ref  # consumed by the index maps
     bi = pl.program_id(0)
     sb = pl.program_id(1)
     length = len_ref[bi]
@@ -513,13 +555,13 @@ def mla_decode_attention_pallas(
     block_s = block_s or _mla_block(s_max)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def q_index(bi, sb, lens, lay):
+    def q_index(bi, sb, lens, src, lay):
         return (bi, 0, 0)
 
-    def row_index(bi, sb, lens, lay, block_s=block_s):
-        # Dead S-blocks revisit the last live tile: their DMA is elided.
-        last = jnp.maximum(lens[bi] - 1, 0) // block_s
-        return (lay[0], bi, jnp.minimum(sb, last), 0)
+    def row_index(bi, sb, lens, src, lay):
+        # Dead S-blocks and dead rows revisit a held tile: no DMA.
+        row, tile = held_tile(bi, sb, lens, src, block_s)
+        return (lay[0], row, tile, 0)
 
     kernel = functools.partial(_mla_kernel, block_s=block_s,
                                n_values=n_values, scale=float(scale))
@@ -527,7 +569,7 @@ def mla_decode_attention_pallas(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, n_heads, n_values), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # lengths, layer
+            num_scalar_prefetch=3,  # lengths, their live_source, layer
             grid=(b, s_max // block_s),
             in_specs=[pl.BlockSpec((1, n_heads, lanes), q_index),
                       pl.BlockSpec((None, 1, block_s, lanes), row_index)],
@@ -539,7 +581,7 @@ def mla_decode_attention_pallas(
         ),
         interpret=interpret,
         name="mla_decode_attention",
-    )(lengths, layer, q, rows)
+    )(lengths, live_source(lengths), layer, q, rows)
 
 
 def mla_decode_attention(

@@ -388,6 +388,62 @@ def test_cached_programs_match_plain_reference(program, quant):
                 np.asarray(new[name])[:, untouched], before[name][:, untouched])
 
 
+@pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("cfg", [TINY_TEST, TINY_MOE], ids=lambda c: c.name)
+def test_decode_step_reads_nothing_of_a_slot_that_sits_out(cfg, quant):
+    """The live rows' logits and the cache written are the same whether the
+    other slots are empty (position 0) or hold a finished request's stale
+    position: the attention takes every inactive row at length 0."""
+    params = make_model(cfg)
+    b, s = 5, 32
+    cache = _random_cache(cfg, b, s, quant)
+    tokens = random_tokens(cfg, b, 1, seed=3)[:, 0]
+    active = jnp.asarray([False, True, False, True, False])
+    step = jax.jit(transformer.decode_step, static_argnums=0)
+    live = np.asarray(active)
+    results = []
+    for others in (0, jnp.asarray([17, 0, 30, 0, 4])):
+        positions = jnp.where(active, jnp.asarray([0, 5, 0, 9, 0]), others)
+        logits, new = step(cfg, params, cache, tokens, positions,
+                           active=active)
+        assert bool(jnp.all(jnp.isfinite(logits)))  # dead rows: only finite
+        results.append((np.asarray(logits)[live],
+                        {n: np.asarray(x) for n, x in new.items()
+                         if n != "length"}))
+        # the engine's view of the lanes is untouched by the mask
+        assert new["length"].tolist() == (positions + 1).tolist()
+    (empty_logits, empty_cache), (stale_logits, stale_cache) = results
+    np.testing.assert_array_equal(empty_logits, stale_logits)
+    for name in empty_cache:
+        np.testing.assert_array_equal(empty_cache[name], stale_cache[name])
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["active-None", "active"])
+def test_decode_step_hands_the_attention_length_zero_only_by_active(masked):
+    """Callers that pass no ``active`` get ``positions + 1`` for every row,
+    as ever; with it, the rows that sit out arrive at length 0."""
+    from llm_instance_gateway_tpu.ops.attention import decode_attention
+
+    cfg = TINY_TEST
+    params = make_model(cfg)
+    cache = _random_cache(cfg, 3, 16, False)
+    positions = jnp.asarray([5, 9, 3])
+    active = jnp.asarray([True, False, True]) if masked else None
+    seen = []
+
+    def spy(q, k, v, lengths):
+        seen.append(np.asarray(lengths).tolist())
+        return decode_attention(q, k, v, lengths)
+
+    with jax.disable_jit():
+        _, new = transformer.decode_step(
+            cfg, params, cache, jnp.asarray([1, 2, 3]), positions,
+            attention_fn=spy, active=active)
+    assert seen and all(x == ([6, 0, 4] if masked else [6, 10, 4])
+                        for x in seen)
+    assert new["length"].tolist() == [6, 10, 4]
+
+
 def _scans(jaxpr):
     """Every scan equation of a jaxpr, sub-jaxprs included."""
     for eqn in jaxpr.eqns:

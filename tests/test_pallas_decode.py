@@ -156,6 +156,132 @@ class TestDecodeKernel:
         assert "impl=pallas-interpret" in lines[3]
 
 
+# Which rows decode in a step of six slots: interleaved with dead ones, a
+# dead row first, a dead row last, and no live row at all.
+LIVE_PATTERNS = {
+    "interleaved": [True, False, True, False, False, True],
+    "leading": [False, False, True, True, False, True],
+    "trailing": [True, True, False, True, False, False],
+    "none": [False] * 6,
+}
+# Every slot holds a position, as a freed slot keeps its last request's:
+# one tile, a tile's edge, a straddle, three tiles, the whole lane.
+STALE_LENGTHS = [5, 64, 130, 192, 256, 33]
+
+
+def masked_lengths(pattern):
+    live = np.asarray(LIVE_PATTERNS[pattern])
+    return live, jnp.asarray(np.where(live, STALE_LENGTHS, 0), jnp.int32)
+
+
+class TestRowsThatDoNotDecode:
+    """A row handed over at length 0 (``decode_step``: ``active`` off) costs
+    the kernel no tile and no matmul, and moves no live row's result."""
+
+    @pytest.mark.parametrize("pattern", LIVE_PATTERNS)
+    @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+    @pytest.mark.parametrize("h,kv", [(28, 4), (16, 16)],
+                             ids=["K4-G7", "K16-G1"])
+    def test_live_rows_are_bit_for_bit_the_all_live_kernels(
+            self, h, kv, quant, pattern):
+        from llm_instance_gateway_tpu.models.transformer import (
+            _kv_dequantize, _kv_quantize)
+
+        q, k, v, _ = make_inputs(b=6, h=h, kv=kv, s=256, seed=21)
+        live, lengths = masked_lengths(pattern)
+        stale = jnp.asarray(STALE_LENGTHS, jnp.int32)
+        if quant:
+            (k8, ks), (v8, vs) = _kv_quantize(k), _kv_quantize(v)
+            run = lambda lens: pda.decode_attention_quant_pallas(
+                q, stack_at(k8, 1), stack_at(v8, 1), stack_at(ks, 1),
+                stack_at(vs, 1), lens, layer=jnp.int32(1), block_s=64,
+                interpret=True)
+            k, v = (_kv_dequantize(k8, ks, jnp.float32),
+                    _kv_dequantize(v8, vs, jnp.float32))
+        else:
+            run = lambda lens: pda.decode_attention_pallas(
+                q, stack_at(k, 1), stack_at(v, 1), lens, layer=jnp.int32(1),
+                block_s=64, interpret=True)
+        got, all_live = np.asarray(run(lengths)), np.asarray(run(stale))
+        np.testing.assert_array_equal(got[live], all_live[live])
+        ref = np.asarray(xla_decode(q, k, v, stale))
+        np.testing.assert_allclose(ref[live], got[live], rtol=2e-5, atol=2e-5)
+        assert np.all(np.isfinite(got))
+        np.testing.assert_array_equal(got[~live], 0.0)
+        # the XLA path's dead rows are another garbage, and finite too
+        assert np.all(np.isfinite(np.asarray(xla_decode(q, k, v, lengths))))
+
+    @pytest.mark.parametrize("pattern", LIVE_PATTERNS)
+    def test_walking_the_grid_a_dead_step_holds_the_tile_before_it(
+            self, pattern):
+        """``held_tile`` over the grid in the order Pallas walks it: a live
+        row's indices are the clamp's of always, every other step names
+        the tile of the step before it, so the cache is copied once a live
+        tile and never for a dead row."""
+        block_s, n_sb = 64, 4
+        live, lengths = masked_lengths(pattern)
+        bi = jnp.repeat(jnp.arange(6), n_sb)
+        sb = jnp.tile(jnp.arange(n_sb), 6)
+        row, tile = pda.held_tile(bi, sb, lengths, pda.live_source(lengths),
+                                  block_s)
+        steps = list(zip(np.asarray(row).tolist(), np.asarray(tile).tolist()))
+        lens = np.asarray(lengths)
+        for i, (b, s) in enumerate(zip(bi.tolist(), sb.tolist())):
+            if live[b]:
+                assert steps[i] == (b, min(s, (lens[b] - 1) // block_s))
+            elif i:
+                assert steps[i] == steps[i - 1]
+        if live.any():  # the dead rows that lead hold what the first live row starts on
+            first = int(np.argmax(live))
+            assert steps[0] == (first, 0)
+        else:
+            assert set(steps) == {(0, 0)}
+        copies = 1 + sum(a != b for a, b in zip(steps, steps[1:]))
+        assert copies == max(1, int(sum(-(-lens[live] // block_s))))
+
+    def test_a_caller_that_passes_no_zero_gets_todays_index_map(self):
+        lengths = jnp.asarray(STALE_LENGTHS, jnp.int32)
+        src = pda.live_source(lengths)
+        assert np.asarray(src).tolist() == list(range(6))
+        for b in range(6):
+            for s in range(4):
+                assert tuple(map(int, pda.held_tile(b, s, lengths, src, 64))
+                             ) == (b, min(s, (STALE_LENGTHS[b] - 1) // 64))
+
+    @pytest.mark.parametrize("pattern", LIVE_PATTERNS)
+    def test_paged_and_latent_kernels_share_the_rule(self, pattern):
+        """The paged kernel (the table routes ``held_tile``'s row and tile)
+        and the latent kernel: live rows as with every slot live, dead rows
+        zeros."""
+        from llm_instance_gateway_tpu.ops.attention import (
+            gather_pool_rows, latent_decode_attention)
+
+        live, lengths = masked_lengths(pattern)
+        stale = jnp.asarray(STALE_LENGTHS, jnp.int32)
+        q, k_pool, v_pool, tables, _ = TestPagedDecodeKernel().make_paged(
+            b=6, seed=5)
+        run = lambda lens: np.asarray(pda.paged_decode_attention_pallas(
+            q, k_pool, v_pool, tables, lens, interpret=True))
+        got = run(lengths)
+        np.testing.assert_array_equal(got[live], run(stale)[live])
+        np.testing.assert_array_equal(got[~live], 0.0)
+        ref = np.asarray(xla_decode(q, gather_pool_rows(k_pool, tables),
+                                    gather_pool_rows(v_pool, tables), stale))
+        np.testing.assert_allclose(ref[live], got[live], rtol=2e-5, atol=2e-5)
+
+        kq, kr = jax.random.split(jax.random.PRNGKey(8))
+        ql = jax.random.normal(kq, (6, 4, 256), jnp.float32)
+        rows = jax.random.normal(kr, (6, 256, 256), jnp.float32)
+        run = lambda lens: np.asarray(pda.mla_decode_attention_pallas(
+            ql, stack_at(rows, 1), lens, 128, 0.1, layer=jnp.int32(1),
+            block_s=64, interpret=True))
+        got = run(lengths)
+        np.testing.assert_array_equal(got[live], run(stale)[live])
+        np.testing.assert_array_equal(got[~live], 0.0)
+        ref = np.asarray(latent_decode_attention(ql, rows, stale, 128, 0.1))
+        np.testing.assert_allclose(ref[live], got[live], rtol=2e-5, atol=2e-5)
+
+
 class TestPagedDecodeKernel:
     """Direct paged kernel: the block table rides the scalar prefetch and
     tiles DMA straight from the pool — parity against gather-then-attend

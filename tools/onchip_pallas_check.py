@@ -9,7 +9,10 @@ as GATED with the gate's reason and not run.
 
 Every case runs; the exit code is non-zero if any failed to lower or
 missed parity.  Needs a TPU (fails on any other backend).  Timing is not
-this tool's job: speed comes from the benchmark's device trace.
+this tool's job, speed comes from the benchmark's device trace, with one
+exception: the ``live-rows`` cases also print what the lane decode kernel
+costs a layer at 32 slots when 5 or all 32 of them decode (host clock
+round one program of 400 calls, as a model's layer loop makes them).
 
     python tools/onchip_pallas_check.py            # on the chip
 """
@@ -19,6 +22,7 @@ from __future__ import annotations
 import os
 import re
 import sys
+import time
 import traceback
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -143,6 +147,47 @@ def case_decode(h, n_kv, hd, s_max, quant, b=16):
     return out, ref, TOL_BF16
 
 
+def case_live_rows(h, n_kv, hd, s_max, n_live, b=32, held=150, n_layers=8,
+                   calls=400):
+    """The lane kernel as a decode step of ``b`` slots runs it: ``n_live``
+    rows of ``held`` positions spread over the slots, the others at length
+    0 (``transformer.decode_step``: ``active`` off).  Parity on every row
+    (a dead row's is zeros on both sides), and the time a layer of one
+    program that walks a stacked cache ``calls`` times."""
+    kq, kk, kv = _keys(6, 3)
+    q = jax.random.normal(kq, (b, h, hd), DTYPE)
+    kc = jax.random.normal(kk, (n_layers, b, s_max, n_kv, hd), DTYPE)
+    vc = jax.random.normal(kv, (n_layers, b, s_max, n_kv, hd), DTYPE)
+    live = np.zeros(b, bool)
+    live[np.linspace(0, b - 1, n_live).astype(int)] = True
+    lengths = jnp.asarray(np.where(live, held, 0), jnp.int32)
+
+    @jax.jit
+    def loop(q, kc, vc, lengths):
+        def body(acc, layer):
+            out = pdec.decode_attention_pallas(
+                q + acc.astype(q.dtype), kc, vc, lengths, layer=layer,
+                interpret=False)
+            return out.astype(jnp.float32) * 1e-6, None
+        layers = jnp.arange(calls, dtype=jnp.int32) % n_layers
+        return jax.lax.scan(body, jnp.zeros(q.shape, jnp.float32), layers)[0]
+
+    loop(q, kc, vc, lengths).block_until_ready()
+    times = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        loop(q, kc, vc, lengths).block_until_ready()
+        times.append((time.perf_counter() - t0) / calls * 1e6)
+    print(f"TIME   lane-decode {n_live}/{b} live rows x{held} h={h} "
+          f"kv={n_kv} s_max={s_max}: {min(times):.1f} us a layer "
+          f"(median {sorted(times)[2]:.1f}, {calls} calls a program)",
+          flush=True)
+    out = jax.jit(lambda q, kc, vc: pdec.decode_attention_pallas(
+        q, kc, vc, lengths, layer=jnp.int32(1), interpret=False))(q, kc, vc)
+    ref = jax.jit(xla_att.decode_attention)(q, kc[1], vc[1], lengths)
+    return out, ref * live[:, None, None], TOL_BF16
+
+
 def case_paged(h, n_kv, hd, block, quant, b=16, s_max=2048):
     m = s_max // block
     kq, kk, kv = _keys(3, 3)
@@ -219,6 +264,16 @@ def cases():
                    pmoe.shape_reasons(k, n),
                    lambda e=e, k=k, n=n, m=m, quant=quant: case_moe(
                        e, k, n, m, quant))
+    # Speed 2 of ROADMAP.md: what the rows that do not decode cost the lane
+    # kernel, at the two benchmark models' layouts and lane lengths.
+    for label, h, n_kv, hd, s_max in (("qwen2.5-7b g=7", 28, 4, 128, 2048),
+                                      ("olmoe-1b-7b g=1 kv=16", 16, 16, 128,
+                                       1024)):
+        for n_live in (5, 32):
+            yield (f"live-rows {n_live}/32 x150 [{label}]",
+                   pdec.shape_reasons(s_max, hd, n_kv * hd * 2),
+                   lambda h=h, n_kv=n_kv, hd=hd, s_max=s_max, n_live=n_live:
+                   case_live_rows(h, n_kv, hd, s_max, n_live))
     for label, h, n_kv, hd in LAYOUTS:
         for s in (128, 1024):
             yield (f"flash s={s} [{label}]", flash.shape_reasons(s, hd),
