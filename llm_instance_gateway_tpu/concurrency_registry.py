@@ -494,12 +494,12 @@ CLASSES = (
                         writers=("note_dispatch",)),
             SharedField("_idle_pending", OWNER_PRIVATE,
                         writers=("note_dispatch", "note_idle")),
-            SharedField("_foreign_wall", OWNER_PRIVATE,
-                        writers=("note_dispatch",)),
             SharedField("_prev_active", OWNER_PRIVATE,
                         writers=("note_dispatch",)),
             SharedField("_split_mark", OWNER_PRIVATE,
                         writers=("note_dispatch",)),
+            SharedField("_prefill_mark", OWNER_PRIVATE,
+                        writers=("take_prefill_split",)),
             SharedField("_open", SWAP_PUBLISHED,
                         writers=("_push", "_switch", "_pop"),
                         note="(innermost open phase, last charge time), "

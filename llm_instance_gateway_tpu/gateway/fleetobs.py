@@ -63,7 +63,8 @@ HOP_CHILDREN = (
                             "engine.decode")),
     ("gateway.upstream", ("engine.queue_wait", "engine.prefill",
                           "engine.decode", "handoff.serialize")),
-    ("gateway.stream", ("engine.queue_wait", "engine.prefill",
+    ("gateway.stream", ("server.accept", "engine.queue_wait",
+                        "engine.prefill", "server.first_write",
                         "engine.decode")),
 )
 

@@ -63,6 +63,10 @@ class RequestContext:
     # spending another token per internal attempt.
     fairness_charged: bool = False
     fairness_demoted_to: str | None = None
+    # The standalone proxy's stall clock (tracing.LoopClock.marks) at the
+    # handler's entry: the stream's record says how much loop lag and
+    # stall the request lived through.
+    loop_marks: tuple | None = None
 
 
 class ProcessingError(Exception):

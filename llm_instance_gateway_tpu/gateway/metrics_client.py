@@ -30,12 +30,6 @@ TPU model server (JetStream-style) that wants to join a pool:
 ``tpu:decode_step_seconds``            per-step decode cadence (histogram,
                                        optional; mean feeds
                                        Metrics.decode_step_seconds_mean)
-``tpu:dispatch_wall_seconds``          step-profiler dispatch wall (histogram,
-                                       optional; phase-summed mean feeds
-                                       Metrics.dispatch_wall_seconds_mean)
-``tpu:dispatch_gap_seconds``           inter-dispatch gaps by kind (histogram,
-                                       optional; kind="host" mean feeds
-                                       Metrics.dispatch_host_gap_seconds_mean)
 ``tpu:lora_requests_info``             labels ``running_lora_adapters`` (CSV),
                                        ``max_lora``; gauge value = unix ts of
                                        the snapshot (latest series wins)
@@ -77,9 +71,6 @@ PREFIX_REUSED_METRIC = "tpu:prefix_reused_tokens"
 PREFILL_SECONDS_METRIC = "tpu:prefill_seconds"
 DECODE_STEP_SECONDS_METRIC = "tpu:decode_step_seconds"
 DECODE_BATCH_OCCUPANCY_METRIC = "tpu:decode_batch_occupancy"
-# Step-timeline profiler families (server/profiler.py; optional).
-DISPATCH_WALL_SECONDS_METRIC = "tpu:dispatch_wall_seconds"
-DISPATCH_GAP_SECONDS_METRIC = "tpu:dispatch_gap_seconds"
 # Capacity-attribution families (server/usage.py; all optional).
 ADAPTER_STEP_SECONDS_METRIC = "tpu:adapter_step_seconds_total"
 ADAPTER_TOKENS_METRIC = "tpu:adapter_tokens_total"
@@ -174,28 +165,6 @@ def families_to_metrics(
         if sums and counts:
             setattr(updated, sum_attr, sum(s.value for s in sums))
             setattr(updated, count_attr, sum(s.value for s in counts))
-
-    # Step-timeline profiler means (optional): the wall family sums
-    # ACROSS its phase series (one engine, several phases); the gap mean
-    # reads only kind="host" — idle gaps are queue emptiness, not the
-    # host-sync tax the dispatch-bound levers target.
-    def _multi_series_mean(fam: str, label: str | None = None,
-                           value: str | None = None) -> float | None:
-        total = count = 0.0
-        for s in families.get(fam + "_sum", []):
-            if label is None or s.labels.get(label) == value:
-                total += s.value
-        for s in families.get(fam + "_count", []):
-            if label is None or s.labels.get(label) == value:
-                count += s.value
-        return total / count if count > 0 else None
-
-    v = _multi_series_mean(DISPATCH_WALL_SECONDS_METRIC)
-    if v is not None:
-        updated.dispatch_wall_seconds_mean = v
-    v = _multi_series_mean(DISPATCH_GAP_SECONDS_METRIC, "kind", "host")
-    if v is not None:
-        updated.dispatch_host_gap_seconds_mean = v
 
     # Capacity attribution (optional): every labeled sample folds in, keyed
     # by its (model, adapter[, phase]) labels — replicas expose one model,

@@ -165,6 +165,9 @@ class GatewayProxy:
         # Request tracing (tracing.py): bounded span ring served by
         # /debug/traces; sampling/capacity via LIG_TRACE_* env.
         self.tracer = tracing.Tracer()
+        # This process's stall clock (gateway_loop_*), run from _on_startup.
+        self.loop_clock = tracing.LoopClock()
+        self._loop_clock_task: asyncio.Task | None = None
         # ONE flight recorder per gateway process; every pool's advisor
         # stack journals into it (events carry pod/model attributes).
         self.journal = events_mod.EventJournal()
@@ -350,14 +353,17 @@ class GatewayProxy:
                 total=None, connect=rcfg.connect_timeout_s or None),
             trace_configs=[trace_cfg],
         )
+        self._loop_clock_task = asyncio.get_running_loop().create_task(
+            self.loop_clock.run())
         if self.obs_tick_s > 0:
             self._obs_task = asyncio.get_running_loop().create_task(
                 self._observability_loop())
 
     async def _on_cleanup(self, app) -> None:
-        if self._obs_task is not None:
-            self._obs_task.cancel()
-            self._obs_task = None
+        for task in (self._loop_clock_task, self._obs_task):
+            if task is not None:
+                task.cancel()
+        self._loop_clock_task = self._obs_task = None
         if self._session is not None:
             await self._session.close()
 
@@ -594,8 +600,11 @@ class GatewayProxy:
         self.tracer.annotate(trace_id, model=model, path=path, status=status)
 
     async def handle_completion(self, request: web.Request) -> web.Response:
+        # Taken before the body is read: the read is part of what the hop
+        # adds to first-token time (``pre_s`` of ``gateway.stream``).
+        t_req = time.time()
         body = await request.read()
-        req_ctx = RequestContext()
+        req_ctx = RequestContext(loop_marks=self.loop_clock.marks())
         # Request-scoped tracing: honor an inbound id or mint one; it rides
         # to the replica and back so one id follows the request across the
         # gateway, the scheduler decision, and the model server (SURVEY.md
@@ -605,7 +614,6 @@ class GatewayProxy:
         trace_id = (request.headers.get(tracing.TRACE_HEADER)
                     or tracing.new_trace_id())
         req_ctx.trace_id = trace_id
-        t_req = time.time()
         loop = asyncio.get_running_loop()
         rcfg = self.resilience.cfg
         # Hedging is for non-streaming requests only (two live SSE relays
@@ -1209,8 +1217,15 @@ class GatewayProxy:
 
         ``trace`` = (trace_id, t_req, path, t_up0): streaming is where real
         client-observed TTFT/TPOT live — the first relayed data chunk stamps
-        TTFT, the final chunk closes the stream span and TPOT spreads over
-        the final usage count.
+        TTFT (when its write to the client returned), the final chunk closes
+        the stream span and TPOT spreads over the final usage count.  The
+        ``gateway.stream`` record of a stream that ran to its end carries
+        the hop's parts: ``pre_s`` (``t_req`` -> the POST was issued),
+        ``first_chunk_s`` (POST -> first chunk received), ``ttft_s``
+        (``t_req`` -> first chunk written: what ``gateway_ttft_seconds``
+        observes), ``relay_mean_s`` / ``relay_max_s`` (per chunk: received
+        -> written), ``chunks``, and from the stall clock over the
+        request's life ``loop_lag_s`` (mean lag a tick) and ``stall_s``.
         """
         trace_id, t_req, path, t_up0 = trace or (None, 0.0, "collocated", 0.0)
         rcfg = self.resilience.cfg
@@ -1222,6 +1237,7 @@ class GatewayProxy:
         try:
             pending = await self._bounded(chunks.__anext__(),
                                           rcfg.ttft_timeout_s)
+            t_recv = t_recv0 = time.time()
         except StopAsyncIteration:
             pending = None  # legitimate empty stream: relay it as-is
         except asyncio.TimeoutError:
@@ -1263,12 +1279,12 @@ class GatewayProxy:
         # bytes for the end-of-stream usage parse, trimmed by whole chunks.
         tail: list[bytes] = []
         tail_len = 0
-        t_first = None
+        t_first = None  # the first chunk's write to the client returned
+        n_chunks = 0
+        relay_sum = relay_max = 0.0  # per chunk: received -> written
         try:
             while pending is not None:
                 chunk = pending
-                if t_first is None:
-                    t_first = time.time()
                 if fast:
                     tail.append(chunk)
                     tail_len += len(chunk)
@@ -1292,9 +1308,16 @@ class GatewayProxy:
                     self._client_disconnected(req_ctx, pod, trace_id, t_req,
                                               path, t_up0, t_first)
                     return resp, None
+                now = time.time()
+                if t_first is None:
+                    t_first = now
+                n_chunks += 1
+                relay_sum += now - t_recv
+                relay_max = max(relay_max, now - t_recv)
                 try:
                     pending = await self._bounded(
                         chunks.__anext__(), rcfg.stream_idle_timeout_s)
+                    t_recv = time.time()
                 except StopAsyncIteration:
                     pending = None
         except asyncio.CancelledError:
@@ -1355,8 +1378,24 @@ class GatewayProxy:
         except (json.JSONDecodeError, ValueError):
             pass
         if trace_id:
+            # The hop's share of first-token time and of every token's way
+            # out, all on this process's clock (an empty stream has none).
+            parts = {}
+            if n_chunks:
+                lag0, ticks0, stall0 = req_ctx.loop_marks or (0.0, 0, 0.0)
+                lag1, ticks1, stall1 = self.loop_clock.marks()
+                parts = {
+                    "pre_s": round(t_up0 - t_req, 6),
+                    "first_chunk_s": round(t_recv0 - t_up0, 6),
+                    "ttft_s": round(t_first - t_req, 6),
+                    "relay_mean_s": round(relay_sum / n_chunks, 6),
+                    "relay_max_s": round(relay_max, 6),
+                    "chunks": n_chunks,
+                    "loop_lag_s": round((lag1 - lag0)
+                                        / max(1, ticks1 - ticks0), 6),
+                    "stall_s": round(stall1 - stall0, 6)}
             self.tracer.record(trace_id, "gateway.stream", t_up0, t_end,
-                               pod=pod.name)
+                               pod=pod.name, **parts)
             self._finish_phase(req_ctx, trace_id, path, t_req,
                                t_first=t_first, t_last=t_end)
         return resp, None
@@ -1378,7 +1417,10 @@ class GatewayProxy:
         extra = (self.slo.render() + stack_lines
                  + self.statebus.render()
                  + self.fleet.render()
-                 + self.journal.render_prom("gateway_events_total"))
+                 + self.journal.render_prom("gateway_events_total")
+                 + self.loop_clock.render("gateway_loop_lag_seconds_total",
+                                          "gateway_loop_ticks_total",
+                                          "gateway_loop_stall_seconds_total"))
         if extra:
             text += "\n".join(extra) + "\n"
         return text

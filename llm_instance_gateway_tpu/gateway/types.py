@@ -117,12 +117,6 @@ class Metrics:
     decode_step_seconds_count: float = 0.0
     decode_batch_occupancy_sum: float = 0.0
     decode_batch_occupancy_count: float = 0.0
-    # Step-timeline profiler means (tpu:dispatch_wall_seconds /
-    # tpu:dispatch_gap_seconds{kind="host"} _sum/_count): per-dispatch
-    # device wall and the host-sync tax between dispatches — the
-    # per-replica observables the dispatch-bound roadmap levers move.
-    dispatch_wall_seconds_mean: float = 0.0
-    dispatch_host_gap_seconds_mean: float = 0.0
     # Per-adapter capacity attribution scraped from the replica's
     # tpu:adapter_*_total families (server/usage.py).  Keys:
     # (model, adapter, phase) for step-seconds/tokens, (model, adapter)

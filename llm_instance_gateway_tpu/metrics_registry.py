@@ -300,6 +300,18 @@ GATEWAY_FAMILIES = (
     Family("gateway_events_total", "counter", ("kind",),
            "Flight-recorder events by kind (events.py; the journal itself "
            "is served by /debug/events).", GATEWAY_SURFACE),
+    Family("gateway_loop_lag_seconds_total", "counter", (),
+           "The proxy's stall clock (tracing.LoopClock): a task sleeps 50 ms "
+           "over and over; this sums how late each sleep ended. Over "
+           "gateway_loop_ticks_total, the mean wait of a coroutine that was "
+           "ready for its turn on the event loop.", GATEWAY_SURFACE),
+    Family("gateway_loop_ticks_total", "counter", (),
+           "Sleeps the proxy's stall clock has finished.", GATEWAY_SURFACE),
+    Family("gateway_loop_stall_seconds_total", "counter", (),
+           "Sum of the stall clock's overshoots of 250 ms and more: 0 in a "
+           "sound run; the pause's length where the process, or the whole "
+           "machine (then every process reads the same), stopped.",
+           GATEWAY_SURFACE),
 )
 
 SERVER_FAMILIES = (
@@ -497,6 +509,29 @@ SERVER_FAMILIES = (
     Family("tpu:events_total", "counter", ("kind",),
            "Replica-side flight-recorder events by kind (served by the "
            "replica's /debug/events).", SERVER_SURFACE),
+    Family("tpu:stream_write_lag_seconds_total", "counter", (),
+           "Seconds from the engine thread publishing a token to a stream "
+           "(Request.t_emit) to the SSE chunk's write returning on the "
+           "event loop, summed over the data chunks written: over "
+           "tpu:stream_chunks_total, what a token waits for the consumer's "
+           "pool thread, the loop and the socket.", SERVER_SURFACE),
+    Family("tpu:stream_chunks_total", "counter", (),
+           "SSE data chunks written whose publish time was known (the "
+           "denominator of tpu:stream_write_lag_seconds_total).",
+           SERVER_SURFACE),
+    Family("tpu:loop_lag_seconds_total", "counter", (),
+           "The model server's stall clock (tracing.LoopClock): a task "
+           "sleeps 50 ms over and over; this sums how late each sleep "
+           "ended. Over tpu:loop_ticks_total, the event loop's mean lag: "
+           "what the engine thread and the streams' pool threads cost the "
+           "loop in waits for the interpreter lock.", SERVER_SURFACE),
+    Family("tpu:loop_ticks_total", "counter", (),
+           "Sleeps the model server's stall clock has finished.",
+           SERVER_SURFACE),
+    Family("tpu:loop_stall_seconds_total", "counter", (),
+           "Sum of the stall clock's overshoots of 250 ms and more: 0 in a "
+           "sound run; the pause's length where the process, or the whole "
+           "machine, stopped.", SERVER_SURFACE),
 )
 
 

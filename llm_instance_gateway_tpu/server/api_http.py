@@ -107,6 +107,14 @@ class ModelServer:
         self.lora = lora_manager
         # Per-process span ring served by /debug/traces (tracing.py).
         self.tracer = tracing.Tracer()
+        # This process's stall clock (tpu:loop_*), run by build_app's app.
+        self.loop_clock = tracing.LoopClock()
+        # Emit-to-write lag of the streamed chunks: seconds from the engine
+        # publishing a token (Request.t_emit) to resp.write returning, and
+        # the chunks counted (tpu:stream_write_lag_seconds_total /
+        # tpu:stream_chunks_total).  Written on the loop's thread only.
+        self.stream_write_lag_s = 0.0
+        self.stream_chunks = 0
         # Server-side flight recorder (events.py): admission rejections,
         # handoff failures, drain/role changes — served by /debug/events
         # and counted in the tpu:events_total family on /metrics.
@@ -133,6 +141,7 @@ class ModelServer:
             logger.warning("fault injection armed from %s: %s",
                            faults_path, schedule.describe())
         app = web.Application(middlewares=middlewares)
+        app.cleanup_ctx.append(self._run_loop_clock)
         app.router.add_post("/v1/completions", self.handle_completions)
         app.router.add_post("/v1/chat/completions", self.handle_chat)
         # Cross-engine disaggregation hops (gateway/proxy.py two-hop relay).
@@ -158,6 +167,12 @@ class ModelServer:
         app.router.add_get("/debug/device", self.handle_debug_device)
         app.router.add_get("/health", self.handle_health)
         return app
+
+    async def _run_loop_clock(self, app):
+        task = asyncio.get_running_loop().create_task(self.loop_clock.run())
+        yield
+        task.cancel()
+        await asyncio.gather(task, return_exceptions=True)
 
     # -- tracing helpers ----------------------------------------------------
     @staticmethod
@@ -187,11 +202,14 @@ class ModelServer:
                 spans.append(("engine.decode", start, end))
         return spans
 
-    def _record_spans(self, trace_id: str, spans, status: str = "ok") -> dict:
+    def _record_spans(self, trace_id: str, spans, status: str = "ok",
+                      attrs: dict | None = None) -> dict:
         """Record spans locally and build the response headers that echo the
-        trace id and carry the spans back to the gateway."""
+        trace id and carry the spans back to the gateway.  ``attrs`` maps a
+        span's name to its attributes."""
         for name, s, e in spans:
-            self.tracer.record(trace_id, name, s, e)
+            self.tracer.record(trace_id, name, s, e,
+                               **(attrs or {}).get(name, {}))
         self.tracer.annotate(trace_id, model=self.model_name, status=status)
         headers = {tracing.TRACE_HEADER: trace_id}
         if spans and self.tracer.sampled(trace_id):
@@ -592,7 +610,8 @@ class ModelServer:
                           echo_prefix: str | None = None,
                           submit: bool = True,
                           trace_id: str | None = None,
-                          decode_start: float | None = None):
+                          decode_start: float | None = None,
+                          t_accept: float | None = None):
         """Server-sent-events generation stream (OpenAI stream=true shape).
 
         Tokens appear in ``req.output_tokens`` as the engine decodes (in
@@ -602,6 +621,13 @@ class ModelServer:
         completes.  Submission happens BEFORE headers so saturation is a real
         429 (the gateway's backpressure contract), and the done flag is read
         BEFORE the token count so the final re-diff can't drop a tail.
+
+        Spans of the way in and out (``trace_id`` given): ``server.accept``
+        from ``t_accept`` (the handler's entry) to ``engine.submit``
+        returning, ``server.first_write`` from the engine's first token to
+        the first data chunk's write returning; every data chunk's
+        emit-to-write lag goes to the two stream counters, the request's
+        largest onto its ``engine.decode`` span.
         """
         # Mark the request as SSE-consumed BEFORE submission: the engine's
         # adaptive dispatch planner caps fused steps for streaming rows
@@ -618,11 +644,16 @@ class ModelServer:
             except queue_mod.Full:
                 return self._reject(429, "prefill queue is full",
                                     trace_id, "queue_full")
+            if trace_id and t_accept:
+                self.tracer.record(trace_id, "server.accept", t_accept,
+                                   time.time())
 
         # From here the request occupies engine capacity: ANY exit before
         # completion (disconnect during prepare, write failure, handler
         # cancel, unexpected exception) must release the slot — enforced by
         # the finally below, not by enumerating exception types.
+        chunks = 0  # data chunks written
+        lag_max = 0.0  # the largest emit-to-write lag among them
         try:
             stream_headers = {
                 "Content-Type": "text/event-stream",
@@ -637,8 +668,24 @@ class ModelServer:
             consumed = 0  # tokens already emitted as text
             deadline = time.monotonic() + timeout_s
 
-            async def emit(payload: dict) -> None:
+            async def emit(payload: dict, t_emit: float | None = None) -> None:
+                """Write one SSE chunk; ``t_emit`` marks a data chunk: the
+                ``Request.t_emit`` its tokens were published at (0.0: not
+                known, counted in no lag)."""
+                nonlocal chunks, lag_max
                 await resp.write(f"data: {json.dumps(payload)}\n\n".encode())
+                if t_emit is None:
+                    return
+                now = time.time()
+                chunks += 1
+                if chunks == 1 and trace_id and req.t_first_token:
+                    self.tracer.record(trace_id, "server.first_write",
+                                       req.t_first_token, now)
+                if t_emit:
+                    lag = now - t_emit
+                    self.stream_write_lag_s += lag
+                    self.stream_chunks += 1
+                    lag_max = max(lag_max, lag)
 
             if echo_prefix:
                 # OpenAI echo under streaming: the prompt text leads the
@@ -671,13 +718,18 @@ class ModelServer:
                 self._record_spans(
                     trace_id,
                     self._engine_spans(req, decode_start=decode_start),
-                    status=req.finish_reason or "ok")
+                    status=req.finish_reason or "ok",
+                    attrs={"engine.prefill": req.prefill_attrs,
+                           "engine.decode": {
+                               "chunks": chunks,
+                               "write_lag_max_s": round(lag_max, 6)}})
 
     async def _stream_sse_loop(self, req, model, object_name, make_delta,
                                resp, loop, consumed, deadline, emit):
         while True:
             await loop.run_in_executor(None, req.stream_event.wait, 0.25)
             req.stream_event.clear()
+            t_emit, req.t_emit = req.t_emit, 0.0
             done = req.done.is_set()  # read BEFORE the token count
             n = len(req.output_tokens)
             if n > consumed:
@@ -714,7 +766,7 @@ class ModelServer:
                             "object": object_name,
                             "model": model,
                             "choices": [make_delta(delta, None)],
-                        })
+                        }, t_emit)
                 consumed += clean
             if done:
                 # Final re-diff: anything appended since the last emit (or a
@@ -733,7 +785,7 @@ class ModelServer:
                         "completion_tokens": len(req.output_tokens),
                         "total_tokens": len(req.prompt_tokens) + len(req.output_tokens),
                     },
-                })
+                }, t_emit)
                 await resp.write(b"data: [DONE]\n\n")
                 return resp
             if time.monotonic() > deadline:
@@ -757,6 +809,8 @@ class ModelServer:
         text = ""
         hits: list[tuple[int, str]] = []
 
+        t_emit = 0.0  # Request.t_emit as taken at the last wake
+
         async def send(delta: str, fin: str | None, usage: bool = False):
             payload = {
                 "id": f"cmpl-{req.request_id}",
@@ -771,11 +825,12 @@ class ModelServer:
                     "total_tokens": (len(req.prompt_tokens)
                                      + len(req.output_tokens)),
                 }
-            await emit(payload)
+            await emit(payload, t_emit)
 
         while True:
             await loop.run_in_executor(None, req.stream_event.wait, 0.25)
             req.stream_event.clear()
+            t_emit, req.t_emit = req.t_emit, 0.0
             done = req.done.is_set()  # read BEFORE decoding
             n = len(req.output_tokens)
             if n > consumed:
@@ -816,6 +871,7 @@ class ModelServer:
 
     # -- inference ---------------------------------------------------------
     async def handle_completions(self, request: web.Request) -> web.Response:
+        t_accept = time.time()
         trace_id = self._trace_id_for(request)
         try:
             body = await request.json()
@@ -862,6 +918,7 @@ class ModelServer:
                 "text_completion",
                 lambda delta, fin: {"index": 0, "text": delta, "finish_reason": fin},
                 stops=stops, echo_prefix=prefix, trace_id=trace_id,
+                t_accept=t_accept,
             )
         # best_of candidates decode concurrently (the engine batches them);
         # ranking needs per-token logprobs, so candidates record at least the
@@ -914,7 +971,8 @@ class ModelServer:
             choices.append(choice)
         headers = self._record_spans(
             trace_id, self._engine_spans(reqs[0]),
-            status=reqs[0].finish_reason or "ok")
+            status=reqs[0].finish_reason or "ok",
+            attrs={"engine.prefill": reqs[0].prefill_attrs})
         return web.json_response({
             "id": f"cmpl-{reqs[0].request_id}",
             "object": "text_completion",
@@ -930,6 +988,7 @@ class ModelServer:
         }, headers=headers)
 
     async def handle_chat(self, request: web.Request) -> web.Response:
+        t_accept = time.time()
         trace_id = self._trace_id_for(request)
         try:
             body = await request.json()
@@ -963,7 +1022,7 @@ class ModelServer:
                     "delta": ({"content": delta} if delta else {}),
                     "finish_reason": fin,
                 },
-                stops=stops, trace_id=trace_id,
+                stops=stops, trace_id=trace_id, t_accept=t_accept,
             )
         reqs = [self._make_request(body, list(prompt_tokens), adapter,
                                    logprobs=top_n if lp_flag else None,
@@ -1000,7 +1059,8 @@ class ModelServer:
         completion_tokens = sum(len(r.output_tokens) for r in reqs)
         headers = self._record_spans(
             trace_id, self._engine_spans(reqs[0]),
-            status=reqs[0].finish_reason or "ok")
+            status=reqs[0].finish_reason or "ok",
+            attrs={"engine.prefill": reqs[0].prefill_attrs})
         return web.json_response({
             "id": f"chatcmpl-{reqs[0].request_id}",
             "object": "chat.completion",
@@ -1074,7 +1134,7 @@ class ModelServer:
             trace_id,
             self._engine_spans(req, with_decode=False)
             + [("handoff.serialize", t_ser0, t_ser1)],
-            status="handoff")
+            status="handoff", attrs={"engine.prefill": req.prefill_attrs})
         headers.update({"x-request-id": req.request_id,
                         "x-prefill-ttft-ms": f"{req.ttft_s * 1000:.2f}"})
         return web.Response(
@@ -1370,7 +1430,20 @@ class ModelServer:
         # Flight-recorder counters (server-side twin of the gateway's
         # gateway_events_total family).
         text += "\n".join(self.events.render_prom("tpu:events_total")) + "\n"
+        text += "\n".join(self._render_transport()) + "\n"
         return web.Response(text=text, content_type="text/plain")
+
+    def _render_transport(self) -> list[str]:
+        """What this process measures outside the engine thread: the
+        streams' emit-to-write lag and the event loop's stall clock."""
+        return [
+            "# TYPE tpu:stream_write_lag_seconds_total counter",
+            f"tpu:stream_write_lag_seconds_total {self.stream_write_lag_s:.6f}",
+            "# TYPE tpu:stream_chunks_total counter",
+            f"tpu:stream_chunks_total {self.stream_chunks}",
+            *self.loop_clock.render("tpu:loop_lag_seconds_total",
+                                    "tpu:loop_ticks_total",
+                                    "tpu:loop_stall_seconds_total")]
 
     async def handle_debug_traces(self, request: web.Request) -> web.Response:
         """Recent traces recorded by THIS replica (``?trace_id=`` filter).
@@ -1414,7 +1487,8 @@ class ModelServer:
     async def handle_debug_profile(self, request: web.Request) -> web.Response:
         """The step-timeline profiler's full payload (server/profiler.py):
         dispatch/host-sync/idle attribution summary, wall+gap histogram
-        states, and the newest per-dispatch records — what
+        states, the newest per-dispatch records and this process's
+        ``clock`` pair (``tracing.clock_pair``) — what
         ``tools/profile_report.py`` renders and the fleet collector's
         black-box dumps embed.  404 from an engine stand-in that has no
         profiler (every ``Engine`` has one)."""
@@ -1423,6 +1497,7 @@ class ModelServer:
             return _err(404, "this engine has no step profiler")
         return web.json_response({"model": self.model_name,
                                   "role": self.engine.cfg.role,
+                                  "clock": tracing.clock_pair(),
                                   **profiler.snapshot()})
 
     async def handle_debug_kv(self, request: web.Request) -> web.Response:

@@ -151,6 +151,16 @@ def _in_phase(name: str, hand_over: bool = False):
     return deco
 
 
+def _publish(req: "Request") -> None:
+    """Wake the consumer of ``req``'s stream (engine thread: a token was
+    appended, or the request finished).  ``t_emit`` keeps the oldest stamp
+    the consumer has not taken yet, so a consumer that falls a step behind
+    reads its whole lag."""
+    if not req.t_emit:
+        req.t_emit = time.time()
+    req.stream_event.set()
+
+
 @jax.named_scope("logprobs")
 def _logprob_info(logits, sampled, valid_vocab: int):
     """(sampled-token logprob, top-K logprobs, top-K ids) from raw logits.
@@ -379,11 +389,23 @@ class Request:
     # span boundary from it).  0.0 = never prefilled on THIS engine (e.g.
     # an attached handoff, whose prefill ran on the prefill-role replica).
     t_prefill_start: float = 0.0
+    # The same instant on the step profiler's clock (perf_counter): where
+    # the prefill's wall sits in the engine thread's gap chain.
+    t_prefill_start_pc: float = 0.0
     t_first_token: float = 0.0
     t_done: float = 0.0
+    # What the engine knows of this request's admission, for the transport's
+    # ``engine.prefill`` span: ``prompt_tokens``, ``bucket``, ``rows`` (rows
+    # decoding when it was admitted: the rows it stalls) and, once the
+    # admission is settled, ``stage_s`` / ``wait_s`` / ``emit_s``.
+    prefill_attrs: dict = field(default_factory=dict)
     done: threading.Event = field(default_factory=threading.Event)
     # Incremental consumption point for streaming responses.
     stream_event: threading.Event = field(default_factory=threading.Event)
+    # Wall clock of the oldest token the stream's consumer has not looked at
+    # yet (``Engine._publish`` stamps it, the consumer zeroes it at each
+    # wake): emit-to-write lag is the consumer's ``now - t_emit``.
+    t_emit: float = 0.0
     # Set by the transport when the client went away: the engine frees the
     # slot at the next block boundary instead of decoding to completion.
     cancelled: threading.Event = field(default_factory=threading.Event)
@@ -816,6 +838,9 @@ class Engine:
         # from the engine loop, so the dispatch/host-sync/idle attribution
         # tiles the engine thread's wall.
         self.profiler = StepProfiler(annotate=jax.profiler.TraceAnnotation)
+        # Requests whose first token is out and whose admission's phase
+        # parts are not booked yet (_settle_admissions).
+        self._unsettled: list[Request] = []
         # KV economy ledger (server/kv_ledger.py): block lifecycle,
         # per-prefix reuse, fragmentation.  Own lock; charged at the
         # allocator/prefix/park sites, state-recounted on the KV sync,
@@ -1975,9 +2000,19 @@ class Engine:
         (server/profiler.py)."""
         return self.profiler.phase(name)
 
-    def _enqueue(self, name: str):
-        """Trace-only span round a jitted call inside a ``*.stage`` phase."""
-        return self.profiler.annotation(name)
+    def _enqueue(self, name: str, *reqs: "Request"):
+        """Trace-only span round a jitted call inside a ``*.stage`` phase.
+        Round a prefill program it names the program's requests (their ids,
+        the first's prompt length and bucket), so that an idle gap of the
+        device trace can be matched to the ``engine.prefill`` request
+        span."""
+        if not reqs:
+            return self.profiler.annotation(name)
+        attrs = reqs[0].prefill_attrs
+        return self.profiler.annotation(
+            name, request_id="+".join(r.request_id for r in reqs),
+            prompt_tokens=attrs.get("prompt_tokens", 0),
+            bucket=attrs.get("bucket", 0))
 
     def _loop(self) -> None:
         while self._running:
@@ -2019,6 +2054,7 @@ class Engine:
                 self._wait_for_work()
 
     def _wait_for_work(self) -> None:
+        self._settle_admissions()
         self.profiler.note_idle()
         with self._phase("idle"), self._work:
             self._work.wait(timeout=0.05)
@@ -2727,7 +2763,7 @@ class Engine:
                 req.output_tokens.append(tok)
                 self._store_logprobs(req, lps_np[j, i], top_v_np[j, i],
                                      top_i_np[j, i])
-                req.stream_event.set()  # per-step emission (see decode walk)
+                _publish(req)  # per-step emission (see decode walk)
                 n_tokens += 1
                 slot.position += 1
                 self._slot_tokens[i] = tok
@@ -2743,7 +2779,7 @@ class Engine:
                 key = owner_key(req.adapter)
                 tok_by_owner[key] = (tok_by_owner.get(key, 0)
                                      + n_tokens - row_start)
-            req.stream_event.set()
+            _publish(req)
             if finished:
                 continue
             self._slot_positions[i] = slot.position
@@ -2818,7 +2854,7 @@ class Engine:
             c = n - reused
             self.usage.charge_padding(self._bucket(c) - c)
             last_logits = self._chunk_dispatch(
-                req.prompt_tokens[reused:], reused, self._bucket(c),
+                req, req.prompt_tokens[reused:], reused, self._bucket(c),
                 slot_idx, n, lora_slot)
             self._prefix_register_row(slot_idx, req.prompt_tokens,
                                       req.adapter,
@@ -2872,8 +2908,8 @@ class Engine:
         return first_token, k, v, lp_info
 
     @_in_phase("prefill.stage")
-    def _chunk_dispatch(self, piece, start: int, chunk: int, slot_idx: int,
-                        lane_end: int, lora_slot: int):
+    def _chunk_dispatch(self, req: Request, piece, start: int, chunk: int,
+                        slot_idx: int, lane_end: int, lora_slot: int):
         """Stage one prompt piece, padded to ``chunk`` positions from
         ``start``, and enqueue the chunk program on lane ``slot_idx``;
         returns the logits after the piece's last real token."""
@@ -2884,7 +2920,7 @@ class Engine:
         args = (self.params, self.cache,
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.int32(slot_idx), jnp.int32(lane_end), jnp.int32(c - 1))
-        with self._enqueue("engine.prefill.enqueue"):
+        with self._enqueue("engine.prefill.enqueue", req):
             last_logits, self.cache, moe = self._jit_chunk(
                 *args, lora_bufs=self._lora_buffers(),
                 lora_slot=jnp.int32(lora_slot))
@@ -2921,7 +2957,7 @@ class Engine:
             jnp.int32(_seed_i32(sp.seed)),
             *map(jnp.asarray, _bias_arrays(sp)),
         )
-        with self._enqueue("engine.prefill.enqueue"):
+        with self._enqueue("engine.prefill.enqueue", req):
             *out, moe = self._jit_prefill(*args)
         self._moe_keep(moe)
         return out
@@ -2952,7 +2988,7 @@ class Engine:
             *(jnp.asarray(np.stack(arrs))
               for arrs in zip(*(_bias_arrays(sp) for sp in sps))),
         )
-        with self._enqueue("engine.prefill.enqueue"):
+        with self._enqueue("engine.prefill.enqueue", *reqs):
             *out, moe = self._jit_prefill_many(*args)
         self._moe_keep(moe)
         return out
@@ -3324,7 +3360,8 @@ class Engine:
                 self._paged_ensure(st.slot_idx, start + c)
                 self._sync_tables()
             st.last_logits = self._chunk_dispatch(
-                piece, start, chunk, st.slot_idx, start + c, st.lora_slot)
+                req, piece, start, chunk, st.slot_idx, start + c,
+                st.lora_slot)
         except Exception as e:  # engine must survive a poison request
             logger.exception("chunk stream failed for %s", req.request_id)
             req.error = str(e)
@@ -3439,14 +3476,12 @@ class Engine:
                 max(0.0, req.t_first_token - req.t_prefill_start),
                 [req.adapter],
                 tokens={owner_key(req.adapter): len(req.prompt_tokens)})
-            # t0=None: the prefill wall is time.time-stamped, so it can't
-            # anchor the perf_counter gap chain — the profiler records
-            # the wall and subtracts it from the next gap instead.
             self.profiler.note_dispatch(
-                "prefill", None,
+                "prefill", req.t_prefill_start_pc,
                 max(0.0, req.t_first_token - req.t_prefill_start),
                 active=1, total_slots=self.cfg.decode_slots,
                 n_steps=len(req.prompt_tokens))
+            self._unsettled.append(req)
 
     def _kv_ledger_sync(self) -> None:
         """Recount the KV ledger's block states from allocator ground
@@ -3499,6 +3534,7 @@ class Engine:
         observes it: a speculative block's token-row count is not one."""
         self.usage.charge_decode(step_s, owners, tok_by_owner)
         self._usage_sync_kv()
+        self._settle_admissions()
         self.profiler.note_dispatch(
             kind, t0, step_s, active=len(owners),
             total_slots=self.cfg.decode_slots, n_steps=n_steps)
@@ -3521,10 +3557,31 @@ class Engine:
 
     def _stamp_prefill_start(self, *reqs: Request) -> None:
         """Queue wait ends / prefill compute begins (first stamp wins)."""
-        now = time.time()
+        self._settle_admissions()
+        now, now_pc = time.time(), time.perf_counter()
+        rows = None
         for r in reqs:
             if not r.t_prefill_start:
-                r.t_prefill_start = now
+                r.t_prefill_start, r.t_prefill_start_pc = now, now_pc
+                if rows is None:
+                    rows = sum(s is not None for s in self.slots)
+                n = len(r.prompt_tokens)
+                r.prefill_attrs.update(
+                    prompt_tokens=n, rows=rows,
+                    bucket=self._bucket(min(n, self._max_bucket())))
+
+    def _settle_admissions(self) -> None:
+        """Hand the requests whose first token came since the last call
+        what the phase stack charged to ``prefill.*`` meanwhile (engine
+        thread, outside any prefill phase: before the next admission, in a
+        decode dispatch's accounting, before the loop waits).  Requests
+        admitted by one program share its parts; a prompt streamed in
+        chunks carries its last chunk's, the earlier ones having run
+        between decode blocks."""
+        parts = self.profiler.take_prefill_split()
+        for req in self._unsettled:
+            req.prefill_attrs.update(parts)
+        self._unsettled.clear()
 
     def _store_logprobs(self, req: Request, lp, top_v, top_i) -> None:
         """Record a token's logprob info iff the request asked for it."""
@@ -3547,7 +3604,7 @@ class Engine:
             lp, top_v, top_i = lp_info
             self._store_logprobs(req, np.asarray(lp),
                                  np.asarray(top_v), np.asarray(top_i))
-        req.stream_event.set()
+        _publish(req)
         with self._lock:
             self.total_generated += 1
         self._record_ttft(req)
@@ -3682,7 +3739,7 @@ class Engine:
                 # published to the stream consumer as it lands in the
                 # trim walk, not once per dispatch — an SSE reader wakes
                 # per token instead of per burst.
-                req.stream_event.set()
+                _publish(req)
                 n_tokens += 1
                 slot_tokens += 1
                 slot.position += 1
@@ -3696,7 +3753,7 @@ class Engine:
             if slot_tokens:
                 key = owner_key(req.adapter)
                 tok_by_owner[key] = tok_by_owner.get(key, 0) + slot_tokens
-            req.stream_event.set()
+            _publish(req)
             if not finished:
                 self._slot_positions[i] = slot.position
         ph.to("decode.account")
@@ -3976,7 +4033,7 @@ class Engine:
                     req.output_tokens.append(tok)
                     self._store_logprobs(req, lps_np[k, i], top_v_np[k, i],
                                          top_i_np[k, i])
-                    req.stream_event.set()  # per-step emission (see decode walk)
+                    _publish(req)  # per-step emission (see decode walk)
                     n_tokens += 1
                     row_tokens += 1
                     slot.position += 1
@@ -3989,7 +4046,7 @@ class Engine:
                 if row_tokens:
                     key = owner_key(req.adapter)
                     tok_by_owner[key] = tok_by_owner.get(key, 0) + row_tokens
-            req.stream_event.set()
+            _publish(req)
             if finished:
                 self._finish(req, "stop" if self._is_stop(req, req.output_tokens[-1])
                              else "length")
@@ -4042,5 +4099,5 @@ class Engine:
         # immediately unloads the adapter must not see a stale pin.
         if req.adapter is not None and self.lora is not None:
             self.lora.release(req.adapter)
-        req.stream_event.set()
+        _publish(req)
         req.done.set()

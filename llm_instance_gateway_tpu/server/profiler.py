@@ -84,6 +84,9 @@ _ANNOTATION = {name: "engine." + name for name in PHASE_ON}
 # The parts of a decode dispatch a /debug/profile record carries.
 _SPLIT = (("stage_s", "decode.stage"), ("wait_s", "decode.wait"),
           ("readback_s", "decode.readback"), ("emit_s", "decode.emit"))
+# The parts of an admission an ``engine.prefill`` request span carries.
+_PREFILL_SPLIT = (("stage_s", "prefill.stage"), ("wait_s", "prefill.wait"),
+                  ("emit_s", "prefill.emit"))
 
 
 class _NoScope:
@@ -170,12 +173,6 @@ class StepProfiler:
         # The engine loop found no work since the last dispatch: the next
         # gap contains a wait and is attributed idle, not host-sync.
         self._idle_pending = False
-        # Dispatch wall that happened OFF the engine-thread gap clock
-        # (prefill walls are stamped with time.time in _record_ttft, so
-        # they cannot anchor the perf_counter gap chain; their wall is
-        # subtracted from the next gap instead of double-counting as
-        # host-sync).
-        self._foreign_wall = 0.0
         self._prev_active = 0
         # Cumulative buckets (the attribution table's numerators).
         self.dispatch_seconds: dict[str, float] = {}
@@ -196,8 +193,10 @@ class StepProfiler:
         # on every transition: the scrape thread adds the open stretch to
         # its copy and sees a torn pair never.  None until the first phase.
         self._open: tuple[str, float] | None = None
-        # _SPLIT's phase totals at the last decode record.
+        # _SPLIT's phase totals at the last decode record, and
+        # _PREFILL_SPLIT's at the last ``take_prefill_split``.
         self._split_mark = (0.0,) * len(_SPLIT)
+        self._prefill_mark = (0.0,) * len(_PREFILL_SPLIT)
 
     # -- the phase stack (engine thread) ------------------------------------
     def phase(self, name: str) -> _Scope:
@@ -205,10 +204,14 @@ class StepProfiler:
         phase until the block ends or ``ph.to(other)`` renames it."""
         return _Scope(self, name)
 
-    def annotation(self, name: str):
+    def annotation(self, name: str, **metadata):
         """A span in the trace alone, no counter (the jitted call inside
-        a ``*.stage`` phase: an enqueue that blocks shows there)."""
-        return NO_PHASE if self._annotate is None else self._annotate(name)
+        a ``*.stage`` phase: an enqueue that blocks shows there).
+        ``metadata`` rides the event (``TraceAnnotation`` formats it only
+        while a trace is being taken)."""
+        if self._annotate is None:
+            return NO_PHASE
+        return self._annotate(name, **metadata)
 
     def _charge(self) -> float:
         now = self._clock()
@@ -270,16 +273,28 @@ class StepProfiler:
         is queue idleness, not step-loop overhead."""
         self._idle_pending = True
 
-    def note_dispatch(self, phase: str, t0: float | None, wall_s: float,
+    def take_prefill_split(self) -> dict[str, float]:
+        """What the phase stack charged to ``prefill.stage`` / ``.wait`` /
+        ``.emit`` since the last call (engine thread, between phases of an
+        admission: an open prefill phase's stretch is not in it yet)."""
+        totals = tuple(self._phase_s[p] for _, p in _PREFILL_SPLIT)
+        out = {key: round(t - m, 9) for (key, _), t, m in
+               zip(_PREFILL_SPLIT, totals, self._prefill_mark)}
+        self._prefill_mark = totals
+        return out
+
+    def note_dispatch(self, phase: str, t0: float, wall_s: float,
                       active: int = 0, total_slots: int = 0,
                       n_steps: int = 1) -> None:
         """Record one dispatch.
 
         ``t0`` is the dispatch start on the engine thread's perf_counter
-        clock — it anchors the host-sync gap chain.  ``None`` means the
-        wall was measured on a different clock (prefill): the wall is
-        recorded but excluded from gap math, and subtracted from the next
-        gap so prefill compute is never misattributed as host-sync.
+        clock — it anchors the host-sync gap chain: the gap before a
+        dispatch is its ``t0`` less the end of the one before (a prefill's
+        wall is never host-sync, and the host's time between a prefill and
+        the next decode block is).  A dispatch that began before the last
+        one ended (a pipelined block, a prompt streamed in chunks between
+        decode blocks) has no gap.
 
         A decode or spec record also carries what the phase stack charged
         to ``decode.stage`` / ``.wait`` / ``.readback`` / ``.emit`` since
@@ -305,16 +320,13 @@ class StepProfiler:
             if hist is None:
                 hist = self.wall_hist[phase] = Histogram(DISPATCH_BUCKETS)
             hist.observe(wall_s)
-            if t0 is None:
-                self._foreign_wall += wall_s
-            else:
-                if self._last_end is not None and t0 > self._last_end:
-                    gap = max(0.0, t0 - self._last_end - self._foreign_wall)
-                    gap_kind = GAP_IDLE if self._idle_pending else GAP_HOST
-                    self.gap_seconds[gap_kind] += gap
-                    self.gap_hist[gap_kind].observe(gap)
-                self._foreign_wall = 0.0
-                self._idle_pending = False
+            if self._last_end is not None and t0 > self._last_end:
+                gap = t0 - self._last_end
+                gap_kind = GAP_IDLE if self._idle_pending else GAP_HOST
+                self.gap_seconds[gap_kind] += gap
+                self.gap_hist[gap_kind].observe(gap)
+            self._idle_pending = False
+            if self._last_end is None or t0 + wall_s > self._last_end:
                 self._last_end = t0 + wall_s
             self._seq += 1
             churn = active - self._prev_active

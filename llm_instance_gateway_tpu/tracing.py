@@ -17,6 +17,10 @@ dependency-free substrate both halves use:
   endpoints on the proxy and ``api_http``.  Model servers additionally
   return their spans in a compact ``x-lig-spans`` response header so the
   proxy can merge a request's cross-process timeline into ONE trace.
+- **Stall clock** (``LoopClock``): one task per asyncio process (the
+  proxy, ``api_http``) that sleeps 50 ms and books how late it woke.  A
+  machine pause reads as the same stall in every process, a loop starved
+  of the interpreter lock as lag in one.
 - **Histogram + exposition helper**: the one Prometheus histogram
   implementation (``_bucket``/``le`` lines, cumulative counts, ``+Inf``)
   shared by the gateway families (``gateway_ttft_seconds``,
@@ -33,11 +37,13 @@ ring bounds memory either way.
 
 from __future__ import annotations
 
+import asyncio
 import collections
 import hashlib
 import json
 import os
 import random
+import time
 
 # Header names (lowercase; transports do case-insensitive lookups).
 TRACE_HEADER = "x-lig-trace-id"
@@ -212,6 +218,61 @@ def render_histogram(name: str, hist, labels: dict[str, str] | None = None,
     lines.append(f"{name}_sum{plain} {hist['sum']}")
     lines.append(f"{name}_count{plain} {hist['count']}")
     return lines
+
+
+# ---------------------------------------------------------------------------
+# Stall clock
+# ---------------------------------------------------------------------------
+
+
+class LoopClock:
+    """How late an asyncio loop runs what is due: ``run()`` sleeps
+    ``PERIOD_S`` over and over and books each sleep's overshoot.
+
+    ``lag_s`` / ``ticks`` is the loop's mean lag (every coroutine that was
+    ready waited about that long for its turn); ``stall_s`` sums only the
+    overshoots of ``STALL_S`` and more, so it reads 0 in a sound run and
+    the pause's length where the process, or the whole machine, stopped.
+    Plain attributes written by the one task on the loop's thread; a
+    render reads them from that same thread.
+    """
+
+    PERIOD_S = 0.05
+    STALL_S = 0.25
+
+    def __init__(self, clock=time.perf_counter, sleep=asyncio.sleep):
+        self._clock = clock
+        self._sleep = sleep
+        self.lag_s = 0.0
+        self.ticks = 0
+        self.stall_s = 0.0
+
+    async def run(self) -> None:
+        while True:
+            t0 = self._clock()
+            await self._sleep(self.PERIOD_S)
+            over = max(0.0, self._clock() - t0 - self.PERIOD_S)
+            self.ticks += 1
+            self.lag_s += over
+            if over >= self.STALL_S:
+                self.stall_s += over
+
+    def marks(self) -> tuple[float, int, float]:
+        """(lag_s, ticks, stall_s) now: two of these bracket a stretch."""
+        return self.lag_s, self.ticks, self.stall_s
+
+    def render(self, lag: str, ticks: str, stall: str) -> list[str]:
+        """Exposition lines under the caller's three family names."""
+        return [f"# TYPE {lag} counter", f"{lag} {self.lag_s:.6f}",
+                f"# TYPE {ticks} counter", f"{ticks} {self.ticks}",
+                f"# TYPE {stall} counter", f"{stall} {self.stall_s:.6f}"]
+
+
+def clock_pair() -> dict:
+    """``time.time()`` and ``time.perf_counter()`` read back to back: what
+    lays request spans (wall clock) and the step profiler's records and
+    annotations (perf_counter) of one process on one axis."""
+    return {"time": time.time(), "perf_counter": time.perf_counter()}
 
 
 # ---------------------------------------------------------------------------
@@ -397,12 +458,15 @@ def debug_traces_payload(tracer: Tracer, query) -> dict:
     filter, ``?limit=`` count cap (1..1024, default 64), and the
     incremental cursor ``?since=<seq>`` (the /debug/events contract:
     poll with ``since=next_since`` until ``next_since == seq``) returning
-    only records newer than the cursor, grouped per trace.  One contract
-    for the proxy and api_http endpoints."""
+    only records newer than the cursor, grouped per trace.  ``clock`` is
+    this process's ``clock_pair``.  One contract for the proxy and api_http
+    endpoints."""
+    clock = clock_pair()
     trace_id = query.get("trace_id")
     if trace_id:
         t = tracer.get(trace_id)
-        return {"traces": [t] if t else [], "seq": tracer.seq}
+        return {"traces": [t] if t else [], "seq": tracer.seq,
+                "clock": clock}
     try:
         limit = max(1, min(int(query.get("limit", "64")), 1024))
     except ValueError:
@@ -415,5 +479,6 @@ def debug_traces_payload(tracer: Tracer, query) -> dict:
             since = 0
         rows, next_since = tracer.since(since, limit)
         return {"traces": rows, "seq": tracer.seq,
-                "next_since": next_since}
-    return {"traces": tracer.recent(limit), "seq": tracer.seq}
+                "next_since": next_since, "clock": clock}
+    return {"traces": tracer.recent(limit), "seq": tracer.seq,
+            "clock": clock}

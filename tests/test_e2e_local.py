@@ -115,7 +115,7 @@ spec:
         _teardown_procs(procs)
 
     try:
-        launch(
+        server = launch(
             ["llm_instance_gateway_tpu.server.api_http", "--model", "llama3-tiny",
              "--platform", "cpu", "--port", str(SERVER_PORT), "--decode-slots", "2",
              "--max-seq-len", "128", "--dtype", "float32"],
@@ -135,7 +135,7 @@ spec:
     except Exception:
         teardown()  # startup failure must not orphan the launched processes
         raise
-    yield {"tmp": tmp, "pool": pool}
+    yield {"tmp": tmp, "pool": pool, "server": server}
     teardown()
 
 
@@ -267,3 +267,72 @@ def test_extproc_binary_serves_grpc(stack):
         channel.close()
     finally:
         _teardown_procs([entry])
+
+
+def test_a_paused_replica_reads_as_its_stall_and_not_the_gateways(stack):
+    """The stall clocks (tracing.LoopClock) through the benchmark's own
+    metric files: stop the replica's process for a second under streamed
+    load; ``server.stalled_ms`` reads about the pause, the gateway's clock
+    none of it, and the requests that lived through it say so."""
+    import threading
+
+    sys.path.insert(0, REPO)
+    from benchmark import manifest, readers
+
+    def read(name, ctx):
+        spec = manifest.load_metric(name)
+        return readers.READERS[spec["reader"]](spec["args"], ctx)
+
+    def get(url):
+        with urllib.request.urlopen(url, timeout=10) as resp:
+            return resp.read().decode()
+
+    gw = f"http://127.0.0.1:{GATEWAY_PORT}"
+    srv = f"http://127.0.0.1:{SERVER_PORT}"
+    body = {"model": "llama3-tiny", "prompt": "pause", "max_tokens": 48,
+            "stream": True, "logit_bias": {str(b): 100.0 for b in b"abcd"}}
+    _post_stream = urllib.request.Request(
+        gw + "/v1/completions", data=json.dumps(body).encode(),
+        headers={"Content-Type": "application/json"}, method="POST")
+    with urllib.request.urlopen(_post_stream, timeout=120) as resp:
+        assert resp.read().rstrip().endswith(b"data: [DONE]")  # warm
+    before = get(srv + "/metrics")
+    cursor = json.loads(get(gw + "/debug/traces?limit=1"))["seq"]
+    halt = threading.Event()
+    answered = []
+
+    def offer():
+        while not halt.is_set():
+            with urllib.request.urlopen(_post_stream, timeout=120) as resp:
+                answered.append(resp.read().rstrip().endswith(b"[DONE]"))
+
+    clients = [threading.Thread(target=offer, daemon=True) for _ in range(2)]
+    for c in clients:
+        c.start()
+    time.sleep(0.6)
+    stack["server"].send_signal(signal.SIGSTOP)
+    try:
+        time.sleep(1.0)
+    finally:
+        stack["server"].send_signal(signal.SIGCONT)
+    time.sleep(0.6)
+    halt.set()
+    for c in clients:
+        c.join(timeout=60)
+        assert not c.is_alive()
+    assert answered and all(answered)
+    time.sleep(0.2)  # the clocks' next tick
+    traces = json.loads(
+        get(f"{gw}/debug/traces?since={cursor}&limit=1024"))["traces"]
+    ctx = {"window_s": 3.0, "prom_before": [before],
+           "prom_after": [get(srv + "/metrics")], "gateway_traces": traces}
+    stalled = read("server.stalled_ms", ctx)
+    at_gateway = read("gateway.stalled_ms", ctx)
+    # a second's pause less what was left of the 50 ms sleep it fell into,
+    # plus the machine's own lateness in waking the process
+    assert 900.0 <= stalled <= 2000.0, stalled
+    assert at_gateway is not None and at_gateway <= stalled - 700.0
+    assert read("server.loop_lag_ms", ctx) > 0
+    assert read("gateway.loop_lag_ms", ctx) is not None
+    page = get(gw + "/metrics")
+    assert "gateway_loop_ticks_total" in page

@@ -142,7 +142,7 @@ def server_snapshot() -> dict:
     # phase plus a host gap and an idle gap, so every label value of the
     # tpu:dispatch_* families renders.
     prof = profiler_mod.StepProfiler()
-    prof.note_dispatch("prefill", None, 0.3, active=1, total_slots=4)
+    prof.note_dispatch("prefill", -0.3, 0.3, active=1, total_slots=4)
     prof.note_dispatch("decode", 0.0, 0.1, active=2, total_slots=4)
     prof.note_dispatch("decode", 0.15, 0.1, active=2, total_slots=4)
     prof.note_idle()

@@ -85,30 +85,6 @@ class TestFamiliesToMetrics:
         assert existing.active_adapters == {"x": 1}
         assert "sql-lora" in m.active_adapters
 
-    def test_dispatch_profiler_means(self):
-        """Step-profiler histograms (server/profiler.py): the wall mean
-        sums ACROSS phase series; the gap mean reads kind="host" only —
-        idle gaps are queue emptiness, not the host-sync tax."""
-        text = EXPOSITION + (
-            '# TYPE tpu:dispatch_wall_seconds histogram\n'
-            'tpu:dispatch_wall_seconds_sum{phase="decode"} 2.0\n'
-            'tpu:dispatch_wall_seconds_count{phase="decode"} 10\n'
-            'tpu:dispatch_wall_seconds_sum{phase="prefill"} 1.0\n'
-            'tpu:dispatch_wall_seconds_count{phase="prefill"} 10\n'
-            '# TYPE tpu:dispatch_gap_seconds histogram\n'
-            'tpu:dispatch_gap_seconds_sum{kind="host"} 0.5\n'
-            'tpu:dispatch_gap_seconds_count{kind="host"} 10\n'
-            'tpu:dispatch_gap_seconds_sum{kind="idle"} 100.0\n'
-            'tpu:dispatch_gap_seconds_count{kind="idle"} 2\n')
-        m, errs = families_to_metrics(prom_parse.parse_text(text), Metrics())
-        assert errs == []
-        assert m.dispatch_wall_seconds_mean == pytest.approx(3.0 / 20)
-        assert m.dispatch_host_gap_seconds_mean == pytest.approx(0.05)
-        # Absent families leave the defaults (foreign servers).
-        m2, _ = families_to_metrics(prom_parse.parse_text(EXPOSITION),
-                                    Metrics())
-        assert m2.dispatch_wall_seconds_mean == 0.0
-
 
 class TestProvider:
     def make(self, res=None, err=None, pods=("p1", "p2")):

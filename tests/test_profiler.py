@@ -43,18 +43,31 @@ class TestStepProfiler:
         assert att["idle_seconds"] == pytest.approx(0.5)
         assert sum(att["shares"].values()) == pytest.approx(1.0, abs=1e-6)
 
-    def test_foreign_prefill_wall_never_counts_as_host_sync(self):
-        """Prefill walls are time.time-stamped (no perf_counter anchor):
-        they must subtract from the next gap, not inflate host-sync."""
+    def test_prefill_wall_never_counts_as_host_sync(self):
+        """A prefill sits in the gap chain on the profiler's clock like any
+        dispatch: its wall is dispatch time, and only the host's time on
+        either side of it is host-sync."""
         p = StepProfiler(capacity=16)
         p.note_dispatch("decode", t0=0.0, wall_s=1.0)
-        p.note_dispatch("prefill", t0=None, wall_s=0.3, active=1)
+        p.note_dispatch("prefill", t0=1.2, wall_s=0.3, active=1)
         p.note_dispatch("decode", t0=2.0, wall_s=1.0)
         att = p.attribution()
         assert att["host_sync_seconds"] == pytest.approx(0.7)
         assert att["dispatch_seconds"] == pytest.approx(2.3)
         assert att["dispatch_seconds_by_phase"]["prefill"] == pytest.approx(
             0.3)
+        gaps = [r["gap_s"] for r in p.snapshot()["records"]]
+        assert gaps == pytest.approx([0.0, 0.2, 0.5])
+
+    def test_a_prompt_streamed_between_decode_blocks_has_no_gap(self):
+        """Its wall began before the decode blocks that ran between its
+        chunks: no gap before it, and the chain goes on from its end."""
+        p = StepProfiler(capacity=16)
+        p.note_dispatch("decode", t0=1.0, wall_s=1.0)
+        p.note_dispatch("prefill", t0=0.5, wall_s=1.75, active=1)
+        p.note_dispatch("decode", t0=2.5, wall_s=1.0)
+        assert [r["gap_s"] for r in p.snapshot()["records"]] == (
+            pytest.approx([0.0, 0.0, 0.25]))
 
     def test_pipelined_overlap_clamps_gap_to_zero(self):
         """A pipelined block's dispatch stamp predates the previous
@@ -93,7 +106,7 @@ class TestStepProfiler:
 
     def test_exposition_families_render(self):
         p = StepProfiler()
-        p.note_dispatch("prefill", t0=None, wall_s=0.2, active=1)
+        p.note_dispatch("prefill", t0=-0.3, wall_s=0.2, active=1)
         p.note_dispatch("decode", t0=0.0, wall_s=0.1)
         p.note_idle()
         p.note_dispatch("decode", t0=0.5, wall_s=0.1)
@@ -258,7 +271,7 @@ class TestPhaseStack:
                 clock.tick(0.03125)
                 ph.to("decode.account")
                 p.note_dispatch("decode", t0, wall, active=1, total_slots=2)
-        p.note_dispatch("prefill", None, 0.1, active=1)
+        p.note_dispatch("prefill", clock.now, 0.1, active=1)
         r0, r1, r2 = p.snapshot()["records"]
         assert (r0["stage_s"], r0["wait_s"], r0["readback_s"],
                 r0["emit_s"]) == (0.125, 0.5, 0.0625, 0.03125)
@@ -559,7 +572,7 @@ class TestSameNamesOnEveryLoop:
 class TestProfileReport:
     def payload(self):
         p = StepProfiler(capacity=32)
-        p.note_dispatch("prefill", t0=None, wall_s=0.4, active=1,
+        p.note_dispatch("prefill", t0=0.6, wall_s=0.4, active=1,
                         total_slots=4, n_steps=8)
         p.note_dispatch("decode", t0=1.0, wall_s=0.2, active=2,
                         total_slots=4, n_steps=1)
@@ -822,8 +835,9 @@ class TestXplaneGaps:
         def entry(field, key, msg):
             return ld(field, num(1, key) + ld(2, msg))
 
-        def event(mid, offset_ps, dur_ps):
-            return ld(4, num(1, mid) + num(2, offset_ps) + num(3, dur_ps))
+        def event(mid, offset_ps, dur_ps, stats=b""):
+            return ld(4, num(1, mid) + num(2, offset_ps) + num(3, dur_ps)
+                      + stats)
 
         path = "jit(decode_block)/while/body/attn.qkv/dot_general:"
         device = (
@@ -843,11 +857,19 @@ class TestXplaneGaps:
             + entry(4, 1, ld(2, "engine.decode.wait"))
             + entry(4, 2, ld(2, "engine.decode.stage"))
             + entry(4, 3, ld(2, "PjitFunction(decode_block)"))
+            + entry(4, 4, ld(2, "engine.prefill.enqueue"))
+            + entry(5, 1, num(1, 1) + ld(2, "request_id"))
+            + entry(5, 2, num(1, 2) + ld(2, "bucket"))
+            + entry(5, 3, num(1, 3) + ld(2, "name"))
             + ld(3, ld(2, "other-thread") + num(3, 1000)
                  + event(1, 0, 1_000_000))
             + ld(3, ld(2, "engine-thread") + num(3, 1000)
                  + event(1, 0, 4_000_000) + event(2, 4_000_000, 6_000_000)
-                 + event(3, 5_000_000, 1_000_000)))
+                 + event(3, 5_000_000, 1_000_000,
+                         ld(4, num(1, 3) + ld(5, "jit_decode_block")))
+                 + event(4, 6_000_000, 500_000,
+                         ld(4, num(1, 1) + ld(5, "ab12"))
+                         + ld(4, num(1, 2) + num(3, 128)))))
         f = tmp_path / "t.xplane.pb"
         f.write_bytes(ld(1, device) + ld(1, host))
         t = profile_report.read_xplane(str(f))
@@ -857,10 +879,19 @@ class TestXplaneGaps:
         assert t["ops"] == [(1000.0, 4000.0), (10000.0, 1000.0)]
         assert t["op_events"] == [("fusion.7", 4000.0, [path]),
                                   ("copy.3", 1000.0, [""])]
-        assert t["annotations"] == [("engine.decode.wait", 1000.0, 4000.0),
-                                    ("engine.decode.stage", 5000.0, 6000.0)]
-        table = profile_report.gaps_by_phase(t["ops"], t["annotations"])
-        assert table["total_ms"] == pytest.approx({"decode.stage": 5e-3})
+        assert t["annotations"] == [
+            ("engine.decode.wait", 1000.0, 4000.0),
+            ("engine.decode.stage", 5000.0, 6000.0),
+            ("engine.prefill.enqueue", 7000.0, 500.0)]
+        # an annotation's own metadata, not another host event's
+        assert t["notes"] == [(7000.0, {"request_id": "ab12", "bucket": 128})]
+        table = profile_report.gaps_by_phase(t["ops"], t["annotations"],
+                                             notes=t["notes"])
+        assert table["total_ms"] == pytest.approx(
+            {"decode.stage": 4.5e-3, "prefill.enqueue": 0.5e-3})
+        assert table["longest"][0]["notes"] == [
+            {"request_id": "ab12", "bucket": 128}]
+        assert "request_id=ab12 bucket=128" in profile_report.render_xplane(t)
         assert profile_report.ops_by_scope(t["op_events"])["ops"][0][
             "scope"] == "attn.qkv"
         with pytest.raises(ValueError):

@@ -19,9 +19,12 @@ With ``--xplane FILE.xplane.pb`` (a ``jax.profiler`` trace of a live
 replica) it reads the trace instead: the holes in the device's timeline (the
 union of the TPU plane's "XLA Ops") against the ``engine.<phase>``
 annotations the engine thread wrote into the same trace — for the ten
-longest holes and for all of them, which phase the thread was in — and the
-device time of the largest operations with the ``jax.named_scope`` each
-belongs to.
+longest holes and for all of them, which phase the thread was in, and for
+a hole that holds an admission the request's id, prompt length and bucket
+(the ``engine.prefill.enqueue`` annotation's metadata: the ``request_id``
+is the ``cmpl-`` id's tail, ``prompt_tokens`` and ``bucket`` are on the
+``engine.prefill`` span of ``/debug/traces``) — and the device time of
+the largest operations with the ``jax.named_scope`` each belongs to.
 
 This is the evidence layer for the ROADMAP item-2 decode levers: every
 "amortize the step loop" change must move the host-sync share DOWN on
@@ -41,6 +44,7 @@ import argparse
 import bisect
 import json
 import os
+import struct
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -241,14 +245,18 @@ def self_time_segments(events: list) -> list:
     return sorted(out)
 
 
-def gaps_by_phase(ops: list, annotations: list, top: int = 10) -> dict:
+def gaps_by_phase(ops: list, annotations: list, top: int = 10,
+                  notes: list = ()) -> dict:
     """Which phase the engine thread was in while the device sat idle.
 
     ``ops`` are the device's operations as ``(start_ns, duration_ns)``,
     ``annotations`` the engine thread's ``engine.*`` spans as ``(name,
     start_ns, duration_ns)`` on the same clock.  A gap is a hole in the
     union of ``ops``; each instant of it goes to the innermost annotation
-    open then (``other`` where none is).  Pure: lists in, a dict out."""
+    open then (``other`` where none is).  ``notes`` are ``(start_ns,
+    {key: value})`` of annotations with metadata (a prefill's enqueue names
+    its request): a longest gap lists those that began inside it.  Pure:
+    lists in, a dict out."""
     busy = union([(s, s + d) for s, d in ops if d > 0])
     if not busy:
         return {"error": "no device operation in the trace"}
@@ -291,7 +299,8 @@ def gaps_by_phase(ops: list, annotations: list, top: int = 10) -> dict:
         "longest": [
             {"at_s": (g0 - busy[0][0]) / 1e9, "gap_ms": g / 1e6,
              "phases_ms": {n: v / 1e6 for n, v in sorted(
-                 split(g0, g1).items(), key=lambda kv: -kv[1])}}
+                 split(g0, g1).items(), key=lambda kv: -kv[1])},
+             "notes": [meta for at, meta in notes if g0 <= at < g1]}
             for g, g0, g1 in longest],
     }
 
@@ -398,10 +407,23 @@ def _map_entry(view) -> tuple[int, memoryview]:
     return key, value
 
 
+def _stat_value(stat: dict, stat_names: dict):
+    """One XStat's value: a string (own or by reference) or a number."""
+    if 5 in stat:
+        return _text(stat[5])
+    if 7 in stat:
+        return stat_names.get(stat[7], "")
+    if 2 in stat:  # a double, read as fixed64
+        return struct.unpack("<d", stat[2].to_bytes(8, "little"))[0]
+    return stat.get(3, stat.get(4))
+
+
 def _plane(view) -> dict:
     """One XPlane: its name, its lines as ``(name, [(metadata id, start_ns,
-    duration_ns)])`` and, per event-metadata id, the operation's name and
-    its "tf_op" (source name) stat."""
+    duration_ns)])``, per event-metadata id the operation's name and its
+    "tf_op" (source name) stat, and for the host plane ``notes``: per line
+    ``(start_ns, name, {stat: value})`` of the events that carry stats of
+    their own (a ``TraceAnnotation``'s keyword arguments)."""
     name, lines, ev_meta, stat_names = "", [], {}, {}
     for field, val in _fields(view):
         if field == 2:
@@ -430,6 +452,7 @@ def _plane(view) -> dict:
                               else stat_names.get(ref, ""))
         meta[key] = (op_name, source)
     out_lines = []
+    notes: dict[str, list] = {}
     for ln in lines:
         lname, t0_ns, events = "", 0, []
         for field, val in _fields(ln):
@@ -439,11 +462,17 @@ def _plane(view) -> dict:
                 t0_ns = val
             elif field == 4:
                 ev = dict(_fields(val))
-                events.append((ev.get(1, 0),
-                               t0_ns + ev.get(2, 0) / 1e3,
-                               ev.get(3, 0) / 1e3))
+                start = t0_ns + ev.get(2, 0) / 1e3
+                events.append((ev.get(1, 0), start, ev.get(3, 0) / 1e3))
+                if 4 in ev and name == "/host:CPU":
+                    stats = [dict(_fields(v)) for f, v in _fields(val)
+                             if f == 4]
+                    notes.setdefault(lname, []).append((
+                        start, meta.get(ev.get(1, 0), ("", ""))[0], {
+                            stat_names.get(st.get(1), ""):
+                                _stat_value(st, stat_names) for st in stats}))
         out_lines.append((lname, events))
-    return {"name": name, "lines": out_lines, "meta": meta}
+    return {"name": name, "lines": out_lines, "meta": meta, "notes": notes}
 
 
 def read_xplane(path: str) -> dict:
@@ -472,6 +501,7 @@ def read_xplane(path: str) -> dict:
                 modules = sorted({meta.get(mid, ("", ""))[0]
                                   for mid, _, _ in events})
     annotations: list = []
+    notes: list = []
     thread = ""
     for p in planes:
         if p["name"] != "/host:CPU":
@@ -482,13 +512,17 @@ def read_xplane(path: str) -> dict:
             evs = [e for e in evs if e[0].startswith(ANNOTATION_PREFIX)]
             if len(evs) > len(annotations):
                 annotations, thread = evs, lname
+                notes = [(at, stats) for at, n, stats
+                         in p["notes"].get(lname, [])
+                         if n.startswith(ANNOTATION_PREFIX)]
     return {"plane": device[0]["name"] if device else "", "ops": ops,
             "op_events": op_events, "modules": modules,
-            "annotations": annotations, "thread": thread}
+            "annotations": annotations, "thread": thread, "notes": notes}
 
 
 def render_xplane(trace: dict, top: int = 10) -> str:
-    table = gaps_by_phase(trace["ops"], trace["annotations"], top)
+    table = gaps_by_phase(trace["ops"], trace["annotations"], top,
+                          trace.get("notes", ()))
     if "error" in table:
         return "error: " + table["error"]
     counts: dict[str, int] = {}
@@ -510,8 +544,12 @@ def render_xplane(trace: dict, top: int = 10) -> str:
         f"The {len(table['longest'])} longest gaps:",
         _table([{"at_s": round(g["at_s"], 4), "gap_ms": round(g["gap_ms"], 3),
                  "phases": ", ".join(f"{n} {v:.2f}"
-                                     for n, v in g["phases_ms"].items())}
-                for g in table["longest"]], ("at_s", "gap_ms", "phases")),
+                                     for n, v in g["phases_ms"].items()),
+                 "admits": "; ".join(
+                     " ".join(f"{k}={v}" for k, v in meta.items())
+                     for meta in g["notes"]) or "-"}
+                for g in table["longest"]],
+               ("at_s", "gap_ms", "phases", "admits")),
         "",
         "Annotations on the engine thread: " + ", ".join(
             f"{n} x{c}" for n, c in sorted(counts.items())),

@@ -7,8 +7,24 @@ Reads either shape and prints a per-phase p50/p95/p99 table:
   — each span's duration is one sample of its phase;
 - a loadgen ``--trace-out`` file: ``{"phases": {name: [seconds, ...]}}``.
 
+Over ``/debug/traces`` documents it then prints **first-token time by
+part**, in the order a streamed request lives them (p50 and p90 over the
+requests that have the part): the gateway before it posts (``pre_s`` of
+``gateway.stream``), the server's ``server.accept`` -> ``engine.queue_wait``
+-> ``engine.prefill`` (and of it what the engine thread's phase stack
+charged: ``stage_s`` / ``wait_s`` / ``emit_s``) -> ``server.first_write``,
+the residual between the gateway's ``first_chunk_s`` and those four
+(network and HTTP; where both processes share a clock, split into the way in
+and the way out), the gateway's way out, and their sum
+``ttft_s``; then the per-token lag (``relay_mean_s``, ``write_lag_max_s``).
+The server's spans of a streamed request live on the replica: give both
+``/debug/traces`` (``--url`` twice, or ``--replicas``) to see the whole
+table.
+
 Usage:
   python tools/trace_report.py http://localhost:8081/debug/traces
+  python tools/trace_report.py --url http://localhost:8081/debug/traces \
+      --url http://localhost:8000/debug/traces
   python tools/trace_report.py traces.json --json
   python -m llm_instance_gateway_tpu.gateway.loadgen --requests 2000 \
       --trace-out /tmp/phases.json && python tools/trace_report.py /tmp/phases.json
@@ -81,6 +97,101 @@ def phase_table(samples: dict[str, list[float]]) -> list[dict]:
     return rows
 
 
+def _span_index(trace: dict) -> dict[str, dict]:
+    """Span name -> the trace's first span of that name."""
+    out: dict[str, dict] = {}
+    for span in trace.get("spans", []):
+        out.setdefault(str(span.get("name")), span)
+    return out
+
+
+def _length(span: dict | None) -> float | None:
+    return None if span is None else max(
+        0.0, float(span["end"]) - float(span["start"]))
+
+
+SERVER_SPANS = ("server.accept", "engine.queue_wait", "engine.prefill",
+                "server.first_write")
+# The rows of the first-token table, in the order a streamed request lives
+# them; an indented row is a share of the one above it.
+PARTS = (
+    "gateway: entry -> POST (pre_s)",
+    "server.accept",
+    "engine.queue_wait",
+    "engine.prefill",
+    "  prefill.stage (stage_s)",
+    "  prefill.wait (wait_s)",
+    "  prefill.emit (emit_s)",
+    "server.first_write",
+    "network and HTTP (first_chunk_s - the four)",
+    "  way in: POST -> handler entry",
+    "  way out: first write -> gateway has it",
+    "POST -> first chunk (first_chunk_s)",
+    "gateway: first chunk -> client (rest of ttft_s)",
+    "= ttft_s of gateway.stream",
+    "per token: gateway received -> written (relay_mean_s)",
+    "per token: engine emit -> written, largest (write_lag_max_s)",
+)
+
+
+def first_token_parts(trace: dict) -> dict[str, float]:
+    """One request's first-token time by part (seconds, keys of ``PARTS``),
+    as far as the trace holds the spans and attributes."""
+    spans = _span_index(trace)
+    stream = (spans.get("gateway.stream") or {}).get("attrs") or {}
+    prefill = (spans.get("engine.prefill") or {}).get("attrs") or {}
+    decode = (spans.get("engine.decode") or {}).get("attrs") or {}
+    server = {name: _length(spans.get(name)) for name in SERVER_SPANS}
+    parts: dict[str, float | None] = {
+        "gateway: entry -> POST (pre_s)": stream.get("pre_s"),
+        **server,
+        "  prefill.stage (stage_s)": prefill.get("stage_s"),
+        "  prefill.wait (wait_s)": prefill.get("wait_s"),
+        "  prefill.emit (emit_s)": prefill.get("emit_s"),
+        "per token: gateway received -> written (relay_mean_s)":
+            stream.get("relay_mean_s"),
+        "per token: engine emit -> written, largest (write_lag_max_s)":
+            decode.get("write_lag_max_s"),
+    }
+    if "ttft_s" in stream:
+        first_chunk = stream["first_chunk_s"]
+        if None in server.values():  # the replica's spans are not here
+            parts["POST -> first chunk (first_chunk_s)"] = first_chunk
+        else:
+            parts["network and HTTP (first_chunk_s - the four)"] = (
+                first_chunk - sum(server.values()))
+            if not trace.get("skew"):
+                # Absolute stamps of two processes: only where they share
+                # a clock (one host; the stitcher found no skew to shift).
+                posted = float(spans["gateway.stream"]["start"])
+                parts["  way in: POST -> handler entry"] = (
+                    float(spans["server.accept"]["start"]) - posted)
+                parts["  way out: first write -> gateway has it"] = (
+                    posted + first_chunk
+                    - float(spans["server.first_write"]["end"]))
+        parts["gateway: first chunk -> client (rest of ttft_s)"] = (
+            stream["ttft_s"] - stream["pre_s"] - first_chunk)
+        parts["= ttft_s of gateway.stream"] = stream["ttft_s"]
+    return {k: float(v) for k, v in parts.items() if v is not None}
+
+
+def first_token_table(traces: list[dict]) -> list[dict]:
+    """One row per part in the order of ``PARTS``: n, p50 and p90 in
+    milliseconds over the traces that have it."""
+    samples: dict[str, list[float]] = {}
+    for trace in traces:
+        for name, v in first_token_parts(trace).items():
+            samples.setdefault(name, []).append(v)
+    rows = []
+    for name in PARTS:
+        xs = sorted(samples.get(name, ()))
+        if xs:
+            rows.append({"part": name, "n": len(xs),
+                         "p50_ms": round(percentile(xs, 0.50) * 1e3, 3),
+                         "p90_ms": round(percentile(xs, 0.90) * 1e3, 3)})
+    return rows
+
+
 def format_table(rows: list[dict], headers: tuple | None = None) -> str:
     if not rows:
         return "(no phase samples)"
@@ -94,16 +205,15 @@ def format_table(rows: list[dict], headers: tuple | None = None) -> str:
     return "\n".join(lines)
 
 
-def multi_replica_samples(sources: list[tuple[str, dict]]) -> dict:
-    """Phase samples over SEVERAL replicas' /debug/traces payloads,
-    merged through the fleet stitcher (gateway/fleetobs.py) — duplicate
-    spans (the gateway's ``x-lig-spans`` copy of a server span) fold and
-    per-source clock skew normalizes, so the table is the fleet truth
-    instead of one replica's view reported as the whole story."""
+def multi_replica_traces(sources: list[tuple[str, dict]]) -> dict:
+    """SEVERAL replicas' /debug/traces payloads as one document, merged
+    through the fleet stitcher (gateway/fleetobs.py) — duplicate spans (the
+    gateway's ``x-lig-spans`` copy of a server span) fold and per-source
+    clock skew normalizes, so the tables are the fleet truth instead of
+    one replica's view reported as the whole story."""
     from llm_instance_gateway_tpu.gateway import fleetobs
 
-    return phase_samples(
-        {"traces": fleetobs.stitch_traces(sources, limit=1024)})
+    return {"traces": fleetobs.stitch_traces(sources, limit=1024)}
 
 
 def main(argv=None) -> int:
@@ -132,16 +242,20 @@ def main(argv=None) -> int:
         sources = [(u, load(u)) for u in urls]
         if args.source:
             sources.append((args.source, load(args.source)))
-        samples = multi_replica_samples(sources)
+        doc = multi_replica_traces(sources)
     elif args.source:
-        samples = phase_samples(load(args.source))
+        doc = load(args.source)
     else:
         parser.error("need a source, --url, or --replicas")
-    rows = phase_table(samples)
+    rows = phase_table(phase_samples(doc))
     if args.json:
         print(json.dumps(rows))
     else:
         print(format_table(rows))
+        parts = first_token_table(doc.get("traces", []))
+        if parts:
+            print("\nfirst-token time by part, and the per-token lag:")
+            print(format_table(parts))
     return 0 if rows else 1
 
 
