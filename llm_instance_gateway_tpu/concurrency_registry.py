@@ -485,6 +485,11 @@ CLASSES = (
                         note="adapter rows of the decode steps: the engine "
                              "thread adds at each dispatch, the scrape "
                              "reads under the lock"),
+            SharedField("blocks_overlapped", LOCK_GUARDED,
+                        writers=("note_overlapped_block",),
+                        note="decode blocks dispatched over an unread one: "
+                             "the engine thread adds at each such dispatch, "
+                             "the scrape reads under the lock"),
             SharedField("latent_positions", LOCK_GUARDED,
                         writers=("note_latent_positions",),
                         note="latent cache rows the decode steps read: the "
@@ -571,8 +576,8 @@ CLASSES = (
                         writers=("_sweep_decode_wait",)),
             SharedField("_parked_kv_tokens", LOCK_GUARDED,
                         writers=("_do_attach", "_drain_decode_wait",
-                                 "_park_waiting", "_sweep_decode_wait",
-                                 "stop")),
+                                 "_park_waiting", "_read_first_tokens",
+                                 "_sweep_decode_wait", "stop")),
             SharedField("cache", SWAP_PUBLISHED,
                         writers=("_insert_prompt_kv", "_sync_tables"),
                         note="KV pytree swapped whole by the engine "
@@ -604,16 +609,14 @@ CLASSES = (
                                  "_dispatch_block", "_dispatch_spec_block",
                                  "_loop_pipelined")),
             SharedField("_dev_positions", OWNER_PRIVATE,
-                        writers=("_activate_slot_pipelined",
-                                 "_dispatch_block", "_dispatch_spec_block",
+                        writers=("_dispatch_block", "_dispatch_spec_block",
                                  "_loop_pipelined")),
             SharedField("_dev_remaining", OWNER_PRIVATE,
-                        writers=("_activate_slot_pipelined",
-                                 "_dispatch_block", "_dispatch_spec_block",
+                        writers=("_dispatch_block", "_dispatch_spec_block",
                                  "_loop_pipelined")),
             SharedField("_dev_stop_hist", OWNER_PRIVATE,
-                        writers=("_activate_slot_pipelined",
-                                 "_dispatch_block", "_loop_pipelined"),
+                        writers=("_dispatch_block", "_dispatch_spec_block",
+                                 "_loop_pipelined"),
                         note="stop-automaton history carry (device-"
                              "resident twin of _slot_stop_hist)"),
             SharedField("_dev_has_extra", OWNER_PRIVATE,
@@ -626,9 +629,19 @@ CLASSES = (
             SharedField("_dev_extra_tok", OWNER_PRIVATE,
                         writers=("_dispatch_spec_block",
                                  "_loop_pipelined")),
-            SharedField("_pending_budget_zero", OWNER_PRIVATE,
+            SharedField("_first_unread", OWNER_PRIVATE,
                         writers=("_activate_slot_pipelined",
-                                 "_loop_pipelined")),
+                                 "_park_waiting", "_read_first_tokens"),
+                        note="first tokens still on the device, in the "
+                             "order their prefills were enqueued"),
+            SharedField("_inflight", OWNER_PRIVATE,
+                        writers=("_loop_pipelined",),
+                        note="the decode block dispatched and not read"),
+            SharedField("_last_done_pc", OWNER_PRIVATE,
+                        writers=("_process_block", "_read_first_tokens"),
+                        note="when the loop last saw the device complete "
+                             "a block or a prefill: the step clock's "
+                             "anchor"),
             SharedField("_prev_dispatch_steps", OWNER_PRIVATE,
                         writers=("_loop_pipelined",
                                  "_paged_ensure_decode")),

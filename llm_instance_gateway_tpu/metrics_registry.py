@@ -418,6 +418,13 @@ SERVER_FAMILIES = (
            "of a step that use what it reads of the adapters (a step reads "
            "every slot's matrices whether any row does or not).",
            SERVER_SURFACE),
+    Family("tpu:decode_blocks_overlapped_total", "counter", (),
+           "Decode blocks dispatched from the device carry while an earlier "
+           "block was still unread: over the decode dispatches "
+           "(tpu:dispatch_wall_seconds_count, phases decode and spec), the "
+           "share of blocks for which the device had its next step queued "
+           "before the host read the last. 0 under --no-pipeline-decode.",
+           SERVER_SURFACE),
     Family("tpu:prefill_seconds", "histogram", ("model", "role"),
            "Prefill compute latency.", SERVER_SURFACE),
     Family("tpu:handoff_seconds", "histogram", ("model", "role"),

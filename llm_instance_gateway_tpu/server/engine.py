@@ -84,6 +84,10 @@ _SLOT_I32 = (
     ("bias_ids", (MAX_LOGIT_BIAS,), -1),
     ("stop_ids", (STOP_SEQS, STOP_LEN), -1), ("stop_lens", (STOP_SEQS,), 0),
     ("stop_hist", (STOP_LEN,), -1),
+    # 1 from a row's activation to the next dispatch (the overlapped loop):
+    # that block takes the row's position, budget and stop history from
+    # this buffer and not from the device carry (``_stage_carry``).
+    ("fresh", (), 0),
 )
 _SLOT_F32 = (
     ("temp", (), 0.0), ("topp", (), 1.0), ("presence", (), 0.0),
@@ -104,6 +108,33 @@ def _slot_views(buf, fields, b: int) -> dict:
         out[name] = buf[at:at + n].reshape((b, *shape))
         at += n
     return out
+
+
+def _stage_carry(carry, i32: dict):
+    """The device carry of the overlapped loop as the next block takes it,
+    decided from what the step stages anyway (``i32``: ``_slot_views`` of
+    the uploaded int32 buffer), so that one fixed-shape program serves
+    whatever the host freed or admitted since the last block:
+
+    - a row the host does not hold (staged budget 0: never registered, or
+      cleared for a reason the device cannot see: a custom stop id, the
+      length cap, a cancellation, an exhausted pool) has its budget zeroed;
+    - a row activated since the last block (``fresh``) takes its position,
+      budget and stop history from the staged values; its first token is
+      the carry's (the prefill left it on the device), and where the host
+      record holds no emitted token yet it also seeds the history.
+    """
+    tokens, positions, remaining, stop_hist = carry
+    fresh = i32["fresh"] > 0
+    positions = jnp.where(fresh, i32["positions"], positions)
+    remaining = jnp.where(
+        fresh, i32["remaining"],
+        jnp.where(i32["remaining"] > 0, remaining, 0))
+    staged_hist = i32["stop_hist"]
+    seeded = staged_hist.at[:, -1].set(
+        jnp.where(staged_hist[:, -1] < 0, tokens, staged_hist[:, -1]))
+    stop_hist = jnp.where(fresh[:, None], seeded, stop_hist)
+    return tokens, positions, remaining, stop_hist
 
 
 def _slot_buffer(fields, b: int, dtype) -> tuple[np.ndarray, dict]:
@@ -220,11 +251,16 @@ class EngineConfig:
     # first.  Lanes beyond the first admit only with KV headroom left for
     # active decode growth (paged pools).
     stream_lanes: int = 1
-    # Pipelined decode: dispatch block N+1 from the device-resident token/
-    # position/budget carry BEFORE reading block N's tokens, overlapping the
-    # host readback with compute.  Slot FREEING still lags one block (the
-    # frozen row just decodes invalid steps until the host sees the stop).
-    pipeline_decode: bool = False
+    # The overlapped order, what an engine runs when nothing is said:
+    # block N+1 is dispatched from the device-resident token/position/
+    # budget carry BEFORE block N's tokens are read, so readback, emit,
+    # accounting, planning and staging of a step all happen while the
+    # device computes the next one.  A prefill's first token is read as
+    # soon as the prefill is done, behind at most the one block in flight.
+    # Slot FREEING lags one block (the frozen row just decodes invalid
+    # steps until the host sees the stop).  False = ``Engine._loop``: each
+    # block is read before the next is staged (``--no-pipeline-decode``).
+    pipeline_decode: bool = True
     # Prefill-ahead depth: prompts prefilled while all decode slots are busy
     # wait here (KV held off-cache) and insert the instant a slot frees —
     # the decode batch never idles a slot waiting for a prefill, and the
@@ -424,9 +460,6 @@ class _Slot:
     request: Request
     lora_slot: int
     position: int  # position of the NEXT token to generate
-    # Pipelined mode: device array holding the prefill's first sampled token,
-    # materialized when this slot's first decode block is processed.
-    pending_first: object = None
 
 
 class _HostBatch:
@@ -767,6 +800,7 @@ class Engine:
         self._slot_stop_ids = i32["stop_ids"]
         self._slot_stop_lens = i32["stop_lens"]
         self._slot_stop_hist = i32["stop_hist"]
+        self._slot_fresh = i32["fresh"]
         # Count of rows with programmed device stop lanes: gates the
         # per-dispatch history rebuild AND excludes speculative dispatch
         # (the spec block does not evaluate the automaton, so its history
@@ -841,6 +875,15 @@ class Engine:
         # Requests whose first token is out and whose admission's phase
         # parts are not booked yet (_settle_admissions).
         self._unsettled: list[Request] = []
+        # The overlapped loop: first tokens still on the device, in the
+        # order their prefills were enqueued, as (request, token,
+        # logprob triple) (_read_first_tokens); the block in flight, if
+        # any; and when the device was last seen to complete something
+        # (a block, a prefill), on the profiler's clock: the step clock's
+        # anchor (_process_block).
+        self._first_unread: list[tuple] = []
+        self._inflight: dict | None = None
+        self._last_done_pc = 0.0
         # KV economy ledger (server/kv_ledger.py): block lifecycle,
         # per-prefix reuse, fragmentation.  Own lock; charged at the
         # allocator/prefix/park sites, state-recounted on the KV sync,
@@ -956,6 +999,12 @@ class Engine:
             self._jit_draft_insert = jax.jit(
                 _named("draft_insert", transformer.insert_prefill),
                 donate_argnames=("cache",))
+            # The overlapped loop's speculative dispatch lays the staged
+            # rows over the carry ahead of the call (a plain block does it
+            # inside its program).
+            self._jit_stage_carry = jax.jit(_named(
+                "stage_carry", lambda carry, buf: _stage_carry(
+                    carry, _slot_views(buf, _SLOT_I32, b))))
             self._jit_spec_block = jax.jit(
                 _named("spec_block", self._spec_block_impl, model_cfg,
                        draft_cfg),
@@ -1036,8 +1085,9 @@ class Engine:
         slices.  ``carry`` is None when the host record leads (the sync
         loop: tokens, positions, budgets and stop history are the
         buffers'), or the previous block's ``(tokens, positions, remaining,
-        stop_hist)`` outputs (the pipelined loop, whose host record lags
-        the device).  ``key`` is the ENGINE's key: the program splits it as
+        stop_hist)`` outputs (the overlapped loop, whose host record lags
+        the device; ``_stage_carry`` lays what the host freed or admitted
+        since over them).  ``key`` is the ENGINE's key: the program splits it as
         ``Engine._next_key`` does and returns the engine's next one, so the
         decode step and the prefills still draw from one stream.
 
@@ -1084,6 +1134,8 @@ class Engine:
         if carry is None:
             carry = (i32["tokens"], i32["positions"], i32["remaining"],
                      i32["stop_hist"])
+        else:
+            carry = _stage_carry(carry, i32)
         tokens, positions, remaining, stop_hist = carry
         next_key, key = jax.random.split(key)
         cache = transformer.with_moe_tally(model_cfg, cache)
@@ -1240,7 +1292,10 @@ class Engine:
             req = s.request
             if req.streaming:
                 n = min(n, max(1, self.cfg.adaptive_stream_cap))
-            rem = req.max_new_tokens - len(req.output_tokens) - inflight
+            # A slotted row has its first token, read or not (the
+            # overlapped loop reads it after this dispatch is planned).
+            rem = (req.max_new_tokens - max(1, len(req.output_tokens))
+                   - inflight)
             n = min(n, max(1, rem))
         p = 1
         while p * 2 <= n:
@@ -1276,7 +1331,7 @@ class Engine:
 
         The buffers go as private host copies, never the mirrors
         themselves: on the CPU backend an upload can alias its numpy
-        source (``_sync_tables``), and the pipelined loop rewrites rows
+        source (``_sync_tables``), and the overlapped loop rewrites rows
         while the block is in flight.  ``counts`` is the real buffer only
         when some row carries a penalty (static flag -> two compiled
         variants), so penalty-free serving never allocates or streams
@@ -1289,17 +1344,24 @@ class Engine:
             n_steps * int(np.count_nonzero(self._slot_lora >= 0)))
         if self._latent:
             # Step j of the block reads position + 1 + j rows of a live
-            # row's lane.  From the host record, which the pipelined loop
-            # keeps a block behind the device; a row that stops mid-block
+            # row's lane.  With a block still unread (the overlapped loop)
+            # the host record is that block's steps behind the device, for
+            # every row but one activated since; a row that stops mid-block
             # counts on to the block's end.
-            at = [s.position for s in self.slots if s is not None]
+            lag = self._inflight["n_steps"] if self._inflight else 0
+            at = [s.position + (0 if self._slot_fresh[i] else lag)
+                  for i, s in enumerate(self.slots) if s is not None]
             self.profiler.note_latent_positions(
                 sum(at) * n_steps + len(at) * n_steps * (n_steps + 1) // 2)
+        i32, f32 = self._slots_i32.copy(), self._slots_f32.copy()
+        # The copy carries the activations since the last block; the block
+        # after this one takes those rows from the carry like any other.
+        self._slot_fresh[:] = 0
         with self._enqueue("engine.decode.enqueue"):
             (*outs, carry, self._rng, counts, self.cache, moe) = (
                 self._jit_decode(
                     self.params, self._lora_buffers(), self.cache,
-                    self._slots_i32.copy(), self._slots_f32.copy(), carry,
+                    i32, f32, carry,
                     self._rng, self._eos_for_device, counts,
                     n_steps=n_steps, penalized=penalized))
         if penalized:
@@ -1724,7 +1786,10 @@ class Engine:
             self._spec_ok[i] = False
             self._spec_has_extra[i] = False
         self._slot_lora[i] = -1
+        # A staged budget of 0 is also how the overlapped loop's next block
+        # learns that the host let the row go (_stage_carry).
         self._slot_remaining[i] = 0
+        self._slot_fresh[i] = 0
         if self._slot_stop_lens[i].any():
             self._slot_stop_ids[i] = -1
             self._slot_stop_lens[i] = 0
@@ -2262,11 +2327,10 @@ class Engine:
     def _do_prefill_ahead(self, req: Request, pipelined: bool) -> None:
         """Prefill with NO slot: prompt KV parks in decode_wait.
 
-        In sync mode the first token is emitted immediately — TTFT is
-        prefill-bound, not slot-bound, which is the point of the
-        disaggregated design.  Pipelined mode keeps the token on device
-        (async-copied) and stamps TTFT at materialization like its other
-        admissions.
+        TTFT is prefill-bound, not slot-bound, which is the point of the
+        disaggregated design: the sync loop emits the first token at once,
+        the overlapped loop keeps it on the device (async-copied) and reads
+        it once the prefill is done (``_read_first_tokens``).
         """
         try:
             self._stamp_prefill_start(req)
@@ -2379,39 +2443,34 @@ class Engine:
     @_in_phase("prefill.stage")
     def _activate_slot_pipelined(self, slot_idx: int, req: Request,
                                  lora_slot: int, n: int, first_token,
-                                 lp_info) -> None:
-        """Pipelined-mode slot activation: scatter the device-resident first
-        token/position/budget into the carry arrays and register the slot
-        with its pending first token (materialized at block processing).
-        Shared by decode_wait inserts and chunk-stream activation — the
-        device-state bookkeeping must stay identical."""
-        self._pending_budget_zero = [
-            i for i in self._pending_budget_zero if i != slot_idx
-        ]
+                                 lp_info, emitted: bool = False) -> None:
+        """Overlapped-loop slot activation, shared by direct prefills,
+        decode_wait inserts and chunk-stream activation.  The first token
+        stays on the device: one scatter puts it into the token carry, and
+        ``_read_first_tokens`` reads it as soon as the loop holds the
+        thread after the prefill (``emitted``: it already reached the
+        request).  Position, budget and stop history are STAGED, not
+        scattered: the next block takes them from the slot buffer under
+        the row's ``fresh`` mark (``_stage_carry``), so an admission costs
+        one fixed-shape helper program whatever else the block frees or
+        admits."""
         # Grouped-prefill rows carry their device slice so this scatter
         # never forces a host sync mid-pipeline.
         tok_dev = (first_token.dev if isinstance(first_token, _Row)
                    and first_token.dev is not None else first_token)
         self._dev_tokens = self._dev_tokens.at[slot_idx].set(tok_dev)
-        self._dev_positions = self._dev_positions.at[slot_idx].set(n)
-        self._dev_remaining = self._dev_remaining.at[slot_idx].set(
-            max(0, req.max_new_tokens - 1))
-        slot = _Slot(request=req, lora_slot=lora_slot, position=n)
-        slot.pending_first = (first_token, lp_info)
-        self._register_slot(slot_idx, slot)
-        if self._slot_stop_lens[slot_idx].any():
-            # Re-seed the row's device stop history: emitted tokens from
-            # the host record (attach / sync-parked admissions), else the
-            # device-resident first token — no host sync either way.
-            row = np.full((STOP_LEN,), -1, np.int32)
-            tail = req.output_tokens[-STOP_LEN:]
-            if tail:
-                row[STOP_LEN - len(tail):] = tail
-                self._dev_stop_hist = self._dev_stop_hist.at[slot_idx].set(
-                    jnp.asarray(row))
-            else:
-                self._dev_stop_hist = self._dev_stop_hist.at[slot_idx].set(
-                    jnp.asarray(row)).at[slot_idx, STOP_LEN - 1].set(tok_dev)
+        self._register_slot(slot_idx, _Slot(
+            request=req, lora_slot=lora_slot, position=n))
+        self._slot_positions[slot_idx] = n
+        self._slot_fresh[slot_idx] = 1
+        # The row's stop history: emitted tokens from the host record
+        # (attach / already-read admissions); with none yet, the program
+        # seeds it with the device-resident first token.
+        tail = req.output_tokens[-STOP_LEN:]
+        if tail:
+            self._slot_stop_hist[slot_idx, STOP_LEN - len(tail):] = tail
+        if not emitted:
+            self._first_unread.append((req, first_token, lp_info))
         self._count_first_token(slot_idx, tok_dev)
         if self._spec:
             # _register_slot set the row's sampling params _draft_admit
@@ -2441,14 +2500,14 @@ class Engine:
             self._prefix_register_row(slot_idx, req.prompt_tokens,
                                       req.adapter)
             if pipelined:
+                # ``emitted``: the first token reached the request at
+                # attach admission, or was queued for reading when it
+                # parked (_park_waiting); queuing it again would emit it
+                # twice.  The carry scatter still uses it (decode
+                # continues from it).
                 self._activate_slot_pipelined(
-                    slot_idx, req, w.lora_slot, w.n, w.first_token, w.lp_info)
-                if w.first_emitted:
-                    # Attach path: the first token already reached the
-                    # request at admission — materializing pending_first
-                    # would emit it twice.  The device carry scatter above
-                    # still used it (correct: decode continues from it).
-                    self.slots[slot_idx].pending_first = None
+                    slot_idx, req, w.lora_slot, w.n, w.first_token,
+                    w.lp_info, emitted=True)
             else:
                 self._register_slot(slot_idx, _Slot(
                     request=req, lora_slot=w.lora_slot, position=w.n))
@@ -3050,8 +3109,9 @@ class Engine:
     def _park_waiting(self, req, first_token, lp_info, k, v, n: int,
                       lora_slot: int, pipelined: bool) -> None:
         """Park one prefilled row in decode_wait (the prefill-ahead
-        contract: sync mode emits the first token NOW — TTFT is
-        prefill-bound, not slot-bound; pipelined keeps it device-side)."""
+        contract: TTFT is prefill-bound, not slot-bound.  The sync loop
+        emits the first token NOW; the overlapped loop reads it with the
+        others once the prefill is done, ``_read_first_tokens``)."""
         w = _WaitingPrefill(request=req, first_token=first_token,
                             lp_info=lp_info, k=k, v=v, n=n,
                             lora_slot=lora_slot)
@@ -3062,6 +3122,8 @@ class Engine:
             if self._emit_first_token(req, tok, w.lp_info):
                 w.lp_info = None
                 return  # done at prefill; never needed a slot
+        else:
+            self._first_unread.append((req, first_token, lp_info))
         self.decode_wait.append(w)
         # Parked prompt KV pins real HBM ([L, 1, bucket, Kh, hd] per entry)
         # outside the decode cache — count the padded rows so the routing
@@ -3578,6 +3640,10 @@ class Engine:
         admitted by one program share its parts; a prompt streamed in
         chunks carries its last chunk's, the earlier ones having run
         between decode blocks."""
+        if self._first_unread:
+            # The overlapped loop between an admission's staging and the
+            # reading of its first token: its parts are not whole yet.
+            return
         parts = self.profiler.take_prefill_split()
         for req in self._unsettled:
             req.prefill_attrs.update(parts)
@@ -3686,8 +3752,6 @@ class Engine:
                 req.error = str(e)
                 self._finish(req, "error")
                 self._clear_slot(i)
-                if pipelined:
-                    self._pending_budget_zero.append(i)
         self._sync_tables()
 
     @_in_phase("decode.plan", hand_over=True)
@@ -3765,23 +3829,33 @@ class Engine:
     # ------------------------------------------------------------------
 
     def _loop_pipelined(self) -> None:
-        """Two-deep pipeline: dispatch block N+1 from the device-resident
-        token/position/budget carry BEFORE materializing block N's tokens, so
-        the (expensive, relay-bound) device->host readback overlaps compute.
+        """The overlapped order (the default): block N+1 is dispatched from
+        the device-resident token/position/budget carry BEFORE block N's
+        tokens are read, so the device never waits for the host between
+        two decode steps: readback, emit, accounting, admission, planning
+        and staging of a step all run while it computes the next one.
+
+        One turn of the loop, with block N in flight: admit (a prefill is
+        enqueued behind N), dispatch N+1 (behind the prefill), read N
+        (wait, readback, emit, account), read the admitted requests' first
+        tokens (the prefill is done about when N's walk is; N+1 is queued
+        behind it, so the device does not idle while the host reads).
 
         Consequences handled here:
         - stop detection is device-side (budget/EOS freeze rows and emit
-          invalid steps), so a finishing slot wastes no trimmed tokens; rows
-          freed for host-only reasons (custom stop ids) get their device
-          budget zeroed before the next dispatch;
-        - prefill first-tokens stay on device (async-copied) and materialize
-          when their slot's first block is processed.
+          invalid steps), so a finishing slot wastes no trimmed tokens; a
+          row freed for a reason only the host sees (custom stop ids, the
+          length cap, a cancellation) is staged with budget 0 and the next
+          block zeroes it in the carry (``_stage_carry``);
+        - a prefill's first token stays on the device until the prefill is
+          done, and leaves then, not with its slot's first block
+          (``_read_first_tokens``).
         """
         b = self.cfg.decode_slots
         self._dev_tokens = jnp.zeros((b,), jnp.int32)
         self._dev_positions = jnp.zeros((b,), jnp.int32)
         self._dev_remaining = jnp.zeros((b,), jnp.int32)
-        # Stop-automaton history rides the device carry (the pipelined
+        # Stop-automaton history rides the device carry (the overlapped
         # loop's no-host-round-trip contract); rows re-seed at activation.
         self._dev_stop_hist = jnp.full((b, STOP_LEN), -1, jnp.int32)
         if self._spec:
@@ -3790,10 +3864,8 @@ class Engine:
             self._dev_extra_tok = jnp.zeros((b,), jnp.int32)
             self._dev_extra_pos = jnp.zeros((b,), jnp.int32)
             self._dev_has_extra = jnp.zeros((b,), bool)
-        self._pending_budget_zero: list[int] = []
         # Write span of the dispatch currently in flight (paged reservation).
         self._prev_dispatch_steps = 0
-        inflight: dict | None = None
         while self._running:
             did_work = self._admit_and_insert(pipelined=True)
             if self._streams:
@@ -3807,9 +3879,9 @@ class Engine:
                     logger.exception("pipelined decode dispatch failed")
                     self._fail_all_slots(e)
                 did_work = True
-            if inflight is not None:
+            if self._inflight is not None:
                 try:
-                    self._process_block(inflight, current=block)
+                    self._process_block(self._inflight, current=block)
                 except Exception as e:
                     # Async JAX errors surface at materialization, not at
                     # dispatch — the sync loop's "engine must survive; fail
@@ -3818,15 +3890,67 @@ class Engine:
                     self._fail_all_slots(e)
                     block = None
                 did_work = True
-            inflight = block
+            if self._first_unread:
+                self._read_first_tokens(current=block)
+                did_work = True
+            self._inflight = block
+            if block is None:
+                self._prev_dispatch_steps = 0
             if not did_work:
                 self._wait_for_work()
-        if inflight is not None:
+        if self._inflight is not None:
             try:
-                self._process_block(inflight, current=None)
+                self._process_block(self._inflight, current=None)
             except Exception as e:
                 logger.exception("final block materialization failed")
                 self._fail_all_slots(e)
+            self._inflight = None
+
+    def _read_first_tokens(self, current: dict | None) -> None:
+        """Read the first tokens still on the device, in the order their
+        prefills were enqueued, and hand each to its request: the point at
+        which a streamed request's first chunk leaves in the overlapped
+        loop.  The thread waits here (``prefill.wait``) for a prefill that
+        is not done; block ``current`` is queued behind it meanwhile.  A
+        request that finishes with this token (``max_tokens`` 1, a stop)
+        gives its slot, or its place in decode_wait, back at once; its
+        lane in ``current`` is garbage."""
+        unread, self._first_unread = self._first_unread, []
+        for req, first_token, lp_info in unread:
+            if req.done.is_set():
+                continue  # cancelled or failed since
+            try:
+                with self._phase("prefill.wait"):
+                    tok = int(np.asarray(first_token))
+                    if lp_info is not None:
+                        lp_info = tuple(np.asarray(a) for a in lp_info)
+                self._last_done_pc = time.perf_counter()
+                finished = self._emit_first_token(req, tok, lp_info)
+            except Exception as e:  # a prefill's async error surfaces here
+                logger.exception("first token of %s failed", req.request_id)
+                req.error = str(e)
+                self._finish(req, "error")
+                finished = True
+            waiting = next(
+                (w for w in self.decode_wait if w.request is req), None)
+            if waiting is not None:
+                waiting.first_emitted = True
+                waiting.lp_info = None
+                if finished:  # done at its first token: it needs no slot
+                    self.decode_wait.remove(waiting)
+                    self._parked_kv_tokens -= waiting.k.shape[2]
+                    if self.kv_ledger is not None:
+                        self.kv_ledger.note_unpark(int(waiting.k.shape[2]))
+                    self._usage_sync_kv()
+                continue
+            if not finished:
+                continue
+            for i, slot in enumerate(self.slots):
+                if slot is not None and slot.request is req:
+                    self._clear_slot(i)
+                    if current is not None and current["rows"][i] is slot:
+                        current["rows"][i] = None
+        self._settle_admissions()
 
     def _fail_all_slots(self, e: Exception) -> None:
         for i, slot in enumerate(self.slots):
@@ -3837,7 +3961,8 @@ class Engine:
 
     def _do_prefill_pipelined(self, req: Request) -> None:
         """Prefill + insert with NO synchronous readback: the first token is
-        scattered into the device carry and async-copied for later use."""
+        scattered into the device carry and async-copied; the loop reads it
+        once the prefill is done (``_read_first_tokens``)."""
         if req.cancelled.is_set():  # died while queued: skip the prefill
             self._finish(req, "cancelled")
             return
@@ -3851,9 +3976,8 @@ class Engine:
             except AttributeError:
                 pass
             # t_first_token is stamped when the token MATERIALIZES in
-            # _process_block — stamping here would understate TTFT by a block.
-            # (_activate_slot_pipelined also drops any queued budget-zero
-            # belonging to this lane's PREVIOUS occupant.)
+            # _read_first_tokens: stamping here would understate TTFT by
+            # the prefill program and the block it is queued behind.
             self._activate_slot_pipelined(
                 slot_idx, req, lora_slot, n, first_token, lp_info)
             registered = True
@@ -3867,6 +3991,12 @@ class Engine:
 
     @_in_phase("decode.plan", hand_over=True)
     def _dispatch_block(self, ph) -> dict:
+        """Stage and enqueue the next block from the device carry, whether
+        or not the block before it has been read (``_inflight``: the
+        mechanism of the overlapped loop, counted in
+        ``tpu:decode_blocks_overlapped_total``)."""
+        if self._inflight is not None:
+            self.profiler.note_overlapped_block()
         # _stops_active: same speculative exclusion as the sync loop —
         # only plain blocks evaluate the stop automata.
         if self._spec and not self._stops_active and any(
@@ -3877,11 +4007,7 @@ class Engine:
         n_steps = self._plan_steps()
         self._paged_ensure_decode(n_steps, pipelined=True)
         ph.to("decode.stage")
-        if self._pending_budget_zero:
-            idxs = jnp.asarray(self._pending_budget_zero, jnp.int32)
-            self._dev_remaining = self._dev_remaining.at[idxs].set(0)
-            self._pending_budget_zero.clear()
-            self.profiler.note_stage_ops(2)  # the indices up, the scatter
+        t0 = time.perf_counter()
         (toks, valid, lps, top_v, top_i, paths), carry, moe = (
             self._enqueue_decode(
                 n_steps, (self._dev_tokens, self._dev_positions,
@@ -3905,7 +4031,7 @@ class Engine:
             "top_i": top_i,
             "rows": list(self.slots),  # request refs valid at dispatch time
             "n_steps": n_steps,
-            "t0": time.perf_counter(),
+            "t0": t0,
         }
 
     def _dispatch_spec_block(self, ph) -> dict:
@@ -3922,10 +4048,14 @@ class Engine:
             n_cycles * (k + 1), pipelined=True,
             per_row_steps=self._spec_row_steps(n_cycles, k))
         ph.to("decode.stage")
-        if self._pending_budget_zero:
-            idxs = jnp.asarray(self._pending_budget_zero, jnp.int32)
-            self._dev_remaining = self._dev_remaining.at[idxs].set(0)
-            self._pending_budget_zero.clear()
+        t0 = time.perf_counter()
+        # What the plain block does inside its program: freed rows' budgets
+        # zeroed, activated rows' positions and budgets taken as staged.
+        (self._dev_tokens, self._dev_positions, self._dev_remaining,
+         self._dev_stop_hist) = self._jit_stage_carry(
+            (self._dev_tokens, self._dev_positions, self._dev_remaining,
+             self._dev_stop_hist), self._slots_i32.copy())
+        self._slot_fresh[:] = 0
         args = (
             self.params, self.draft_params, self._lora_buffers(),
             self.cache, self.draft_cache,
@@ -3962,17 +4092,31 @@ class Engine:
             "top_i": top_i,
             "rows": list(self.slots),
             "n_steps": n_cycles * (k + 1),
-            "t0": time.perf_counter(),
+            "t0": t0,
             "spec": True,
         }
 
     @_in_phase("decode.wait", hand_over=True)
     def _process_block(self, blk: dict, current: dict | None, ph) -> None:
         """Materialise and walk block ``blk`` while ``current`` computes:
-        wait (for the block in flight), readback, emit, account."""
+        wait (the time the thread really blocks on the device), readback,
+        emit, account.
+
+        The step the block books (``tpu:decode_step_seconds``, the
+        profiler's wall) is the interval between two completions on the
+        device's queue: from the later of this block's staging and the
+        last thing the loop saw complete (the block before, or a prefill
+        read since) to this block's completion.  With blocks overlapped
+        that is the cadence at which a row's tokens become available, one
+        device step; for a block staged on an idle device it is stage +
+        wait, the sync loop's step."""
         outs = jax.block_until_ready(
             (blk["toks"], blk["valid"], blk["lps"], blk["top_v"],
              blk["top_i"], *blk.get("tail", ())))
+        done = time.perf_counter()
+        t0 = max(blk["t0"], self._last_done_pc)
+        step_s = done - t0
+        self._last_done_pc = done
         ph.to("decode.readback")
         # One device_get for the lot, as in ``_do_decode_step``.  A plain
         # block's tail is its sampler paths, then routing counts; a
@@ -3984,10 +4128,8 @@ class Engine:
             self._moe_account(tail[1:])
         ph.to("decode.emit")
         n_tokens = 0
-        n_pending = 0  # prefill first-tokens materialized in this block
         # Attribution owners = every row resident at DISPATCH time (they
-        # all shared this block's wall); tokens counted per owner below
-        # (pending-first tokens are prefill products, excluded).
+        # all shared this block's wall); tokens counted per owner below.
         owners = [s.request.adapter for s in blk["rows"] if s is not None]
         tok_by_owner: dict[str, int] = {}
         for i, slot in enumerate(blk["rows"]):
@@ -4000,73 +4142,47 @@ class Engine:
                 self._finish(req, "cancelled")
                 if self.slots[i] is slot:
                     self._clear_slot(i)
-                    self._pending_budget_zero.append(i)
                 if current is not None and current["rows"][i] is slot:
                     current["rows"][i] = None
                 continue
             finished = False
-            pending = getattr(slot, "pending_first", None)
-            if pending is not None:
-                pending_tok, pending_lp = pending
-                with self._phase("prefill.wait"):
-                    tok0 = int(np.asarray(pending_tok))
-                slot.pending_first = None
-                with self._phase("prefill.emit"):
-                    req.t_first_token = time.time()
-                    req.output_tokens.append(tok0)
-                    if pending_lp is not None:
-                        lp0, tv0, ti0 = pending_lp
-                        self._store_logprobs(
-                            req, np.asarray(lp0), np.asarray(tv0),
-                            np.asarray(ti0))
-                    self._record_ttft(req)
+            row_tokens = 0
+            for k in range(blk["n_steps"]):
+                if not valid_np[k, i]:
+                    continue  # device froze this row (budget/EOS)
+                tok = int(toks_np[k, i])
+                req.output_tokens.append(tok)
+                self._store_logprobs(req, lps_np[k, i], top_v_np[k, i],
+                                     top_i_np[k, i])
+                _publish(req)  # per-step emission (see decode walk)
                 n_tokens += 1
-                n_pending += 1
-                if self._is_finished(req, tok0):
+                row_tokens += 1
+                slot.position += 1
+                if (
+                    self._is_finished(req, tok)
+                    or slot.position >= self.cfg.max_seq_len - 1
+                ):
                     finished = True
-            if not finished:
-                row_tokens = 0
-                for k in range(blk["n_steps"]):
-                    if not valid_np[k, i]:
-                        continue  # device froze this row (budget/EOS)
-                    tok = int(toks_np[k, i])
-                    req.output_tokens.append(tok)
-                    self._store_logprobs(req, lps_np[k, i], top_v_np[k, i],
-                                         top_i_np[k, i])
-                    _publish(req)  # per-step emission (see decode walk)
-                    n_tokens += 1
-                    row_tokens += 1
-                    slot.position += 1
-                    if (
-                        self._is_finished(req, tok)
-                        or slot.position >= self.cfg.max_seq_len - 1
-                    ):
-                        finished = True
-                        break
-                if row_tokens:
-                    key = owner_key(req.adapter)
-                    tok_by_owner[key] = tok_by_owner.get(key, 0) + row_tokens
+                    break
+            if row_tokens:
+                key = owner_key(req.adapter)
+                tok_by_owner[key] = tok_by_owner.get(key, 0) + row_tokens
             _publish(req)
             if finished:
                 self._finish(req, "stop" if self._is_stop(req, req.output_tokens[-1])
                              else "length")
                 if self.slots[i] is slot:
+                    # Host-only stop reasons (custom ids, length cap) leave
+                    # a positive device budget: the cleared row's staged
+                    # budget of 0 zeroes it in the next block.
                     self._clear_slot(i)
-                    # Host-only stop reasons (custom ids, length cap) leave a
-                    # positive device budget — zero it before the next dispatch.
-                    self._pending_budget_zero.append(i)
                 if current is not None and current["rows"][i] is slot:
                     current["rows"][i] = None  # its lane in-flight is garbage
         ph.to("decode.account")
-        step_s = time.perf_counter() - blk["t0"]
         if blk.get("spec"):
-            # First tokens come from prefill, not speculation.
-            self.spec_emitted += n_tokens - n_pending
-        # Pipelined blocks overlap: block N+1's dispatch stamp predates
-        # block N's process end, so the profiler's gap clamps to ~0, and
-        # step_s is the block's WALL (dispatch-to-process).
+            self.spec_emitted += n_tokens
         self._account_dispatch("spec" if blk.get("spec") else "decode",
-                               blk["t0"], step_s, owners, tok_by_owner,
+                               t0, step_s, owners, tok_by_owner,
                                n_tokens, blk["n_steps"])
 
     def _is_stop(self, req: Request, tok: int) -> bool:

@@ -167,6 +167,11 @@ class StepProfiler:
         # rows' cache lengths, summed over the steps of the plain decode
         # dispatches.  0 for a model with per-head K/V lanes.
         self.latent_positions = 0
+        # Decode blocks dispatched while an earlier block was still unread:
+        # the device then had its next step queued before the host read
+        # the last.  Over the decode and spec dispatches: the share of
+        # blocks the overlapped loop kept the device ahead for.
+        self.blocks_overlapped = 0
         # End of the previous dispatch on the engine-thread clock; None
         # until the first dispatch (no gap to attribute yet).
         self._last_end: float | None = None
@@ -416,6 +421,12 @@ class StepProfiler:
         with self._lock:
             self.latent_positions += n
 
+    def note_overlapped_block(self) -> None:
+        """Count one decode block dispatched while an earlier block was
+        still unread."""
+        with self._lock:
+            self.blocks_overlapped += 1
+
     def hist_state(self) -> dict:
         """The small copy-out ``Engine.metrics_snapshot()`` embeds — the
         ``tpu:dispatch_wall_seconds`` / ``tpu:dispatch_gap_seconds``
@@ -430,6 +441,7 @@ class StepProfiler:
                 "stage_ops": self.stage_ops,
                 "lora_rows": self.lora_rows,
                 "latent_positions": self.latent_positions,
+                "blocks_overlapped": self.blocks_overlapped,
             }
         out["phases"] = self.phase_seconds()
         out["moe"] = self.moe_state()
@@ -509,4 +521,8 @@ def render_profile(hist: dict) -> list[str]:
         lines += ["# TYPE tpu:latent_kv_positions_total counter",
                   "tpu:latent_kv_positions_total "
                   f"{hist['latent_positions']}"]
+    if "blocks_overlapped" in hist:
+        lines += ["# TYPE tpu:decode_blocks_overlapped_total counter",
+                  "tpu:decode_blocks_overlapped_total "
+                  f"{hist['blocks_overlapped']}"]
     return lines

@@ -27,13 +27,17 @@ CFG = TINY_TEST
 EOS = 255  # byte tokenizer range; arbitrary for random weights
 
 
-@pytest.fixture(scope="module")
-def engine_env():
+@pytest.fixture(scope="module", params=[False, True],
+                ids=["sync", "overlapped"])
+def engine_env(request):
+    """One engine a loop: ``_loop`` by name (it was the default before
+    PR 40) and the overlapped order, which is what nothing said now runs."""
     params = transformer.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
     lora = LoRAManager(CFG, dtype=jnp.float32)
     engine = Engine(
         CFG, params,
-        EngineConfig(decode_slots=4, max_seq_len=64, prefill_buckets=(8, 16, 32)),
+        EngineConfig(decode_slots=4, max_seq_len=64, prefill_buckets=(8, 16, 32),
+                     pipeline_decode=request.param),
         lora_manager=lora, eos_id=None, dtype=jnp.float32,
     )
     engine.start()

@@ -152,6 +152,7 @@ def server_snapshot() -> dict:
     with prof.phase("decode.stage") as ph:
         ph.to("decode.wait")
     prof.note_lora_rows(3)  # tpu:lora_rows_total
+    prof.note_overlapped_block()  # tpu:decode_blocks_overlapped_total
     prof.note_latent_positions(41)  # tpu:latent_kv_positions_total
     return {
         "profile": prof.hist_state(),
@@ -281,6 +282,7 @@ def test_server_render_contract():
     assert phase_on == set(ENGINE_PHASES)
     # Adapter rows of the decode steps, beside the staging counter.
     assert families["tpu:lora_rows_total"][0].value == 3
+    assert families["tpu:decode_blocks_overlapped_total"][0].value == 1
     assert families["tpu:latent_kv_positions_total"][0].value == 41
     assert families["tpu:decode_stage_ops_total"][0].value == 0
     # Decode fast-path families (adaptive dispatch + stream lanes).

@@ -229,7 +229,7 @@ class TestTwoEngineParity:
             coll.stop(), pre.stop(), dec.stop()
 
     def test_pipelined_decode_engine_parity(self):
-        coll = make_engine()
+        coll = make_engine(pipeline_decode=False)
         pre = make_engine(role="prefill")
         dec = make_engine(role="decode", pipeline_decode=True,
                           decode_steps_per_sync=4)

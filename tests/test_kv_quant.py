@@ -113,7 +113,8 @@ class TestQuantizedEngine:
     def test_pipelined_multistep_matches_sync(self, params):
         rng = np.random.RandomState(32)
         prompts = [list(rng.randint(1, 250, size=n)) for n in (6, 11)]
-        want = gen_all(make_engine(params, quant=True), prompts)
+        want = gen_all(make_engine(params, quant=True,
+                                   pipeline_decode=False), prompts)
         got = gen_all(make_engine(params, quant=True, pipeline_decode=True,
                                   decode_steps_per_sync=4), prompts)
         assert got == want
@@ -123,7 +124,9 @@ class TestQuantizedEngine:
         quantized lane; pipelined and sync agree exactly."""
         rng = np.random.RandomState(31)
         prompts = [list(rng.randint(1, 250, size=40))]
-        want = gen_all(make_engine(params, quant=True), prompts, max_new=6)
+        want = gen_all(make_engine(params, quant=True,
+                                   pipeline_decode=False),
+                       prompts, max_new=6)
         got = gen_all(make_engine(params, quant=True, pipeline_decode=True),
                       prompts, max_new=6)
         assert got == want
@@ -229,7 +232,8 @@ class TestQuantizedEngine:
         branch) alongside bucketed ones."""
         rng = np.random.RandomState(36)
         prompts = [list(rng.randint(1, 250, size=n)) for n in (6, 40, 9)]
-        want = gen_all(make_engine(params, quant=True, paged_kv_block=8),
+        want = gen_all(make_engine(params, quant=True, paged_kv_block=8,
+                                   pipeline_decode=False),
                        prompts, max_new=6)
         got = gen_all(
             make_engine(params, quant=True, paged_kv_block=8,

@@ -90,7 +90,7 @@ class TestPenalties:
         assert a == b
 
     def test_pipelined_matches_sync(self, params):
-        sync = _engine(params)
+        sync = _engine(params, pipeline_decode=False)
         pipe = _engine(params, pipeline_decode=True, decode_steps_per_sync=4)
         sync.start(), pipe.start()
         try:

@@ -326,8 +326,11 @@ def profiled_engine():
                                      dtype=jnp.float32)
     engine = Engine(
         TINY_TEST, params,
+        # ``_loop`` by name: a record whose stage + wait + readback IS its
+        # wall is that loop's; the overlapped loop's clock has its own
+        # tests (tests/test_overlapped_loop.py).
         EngineConfig(decode_slots=2, max_seq_len=64,
-                     prefill_buckets=(8, 16, 32)),
+                     prefill_buckets=(8, 16, 32), pipeline_decode=False),
         eos_id=None, dtype=jnp.float32)
     engine.start()
     yield engine, params
@@ -524,7 +527,8 @@ def spec_engine():
 
 class TestSameNamesOnEveryLoop:
     @pytest.mark.parametrize("extra", [
-        {}, {"pipeline_decode": True}, {"speculative_k": 2},
+        {"pipeline_decode": False}, {"pipeline_decode": True},
+        {"pipeline_decode": False, "speculative_k": 2},
         {"pipeline_decode": True, "speculative_k": 2}],
         ids=["sync", "pipelined", "spec", "pipelined-spec"])
     def test_loop_charges_the_decode_and_prefill_phases(self, spec_engine,
@@ -676,6 +680,42 @@ class TestPhaseReport:
         del old["hist"]["stage_ops"]
         assert profile_report.stage_ops_row(old) == {}
         assert "Decode staging" not in profile_report.render_report(old)
+
+    def test_report_prints_the_decode_overlap(self):
+        """``tpu:decode_blocks_overlapped_total``
+        (``note_overlapped_block``): in ``/metrics``, in
+        ``/debug/profile``'s ``hist``, and as a share of the decode blocks
+        (plain and speculative) in the report; none for a payload from
+        before the counter, 0 for a loop that overlaps nothing."""
+        clock = FakeClock()
+        p = StepProfiler(capacity=8, clock=clock)
+        for kind, overlapped in (("decode", False), ("decode", True),
+                                 ("spec", True), ("decode", True)):
+            if overlapped:
+                p.note_overlapped_block()
+            p.note_dispatch(kind, clock.now, 0.01, active=3, total_slots=4)
+            clock.tick(0.02)
+        assert p.snapshot()["hist"]["blocks_overlapped"] == 3
+        row = profile_report.overlap_row(p.snapshot())
+        assert row == {"blocks_overlapped": 3, "decode_blocks": 4,
+                       "overlapped_pct": 75.0}
+        out = profile_report.render_report(p.snapshot())
+        assert "Decode overlap" in out and "75.0" in out
+        lines = render_profile(p.hist_state())
+        assert "# TYPE tpu:decode_blocks_overlapped_total counter" in lines
+        assert "tpu:decode_blocks_overlapped_total 3" in lines
+        old = p.snapshot()
+        del old["hist"]["blocks_overlapped"]
+        assert profile_report.overlap_row(old) == {}
+        assert "Decode overlap" not in profile_report.render_report(old)
+        assert not any("blocks_overlapped" in ln
+                       for ln in render_profile(old["hist"]))
+        sync = StepProfiler(capacity=8, clock=clock)
+        sync.note_dispatch("decode", clock.now, 0.01, active=1,
+                           total_slots=4)
+        assert profile_report.overlap_row(sync.snapshot()) == {
+            "blocks_overlapped": 0, "decode_blocks": 1,
+            "overlapped_pct": 0.0}
 
     def test_report_prints_adapter_rows_per_dispatch(self):
         """``tpu:lora_rows_total`` (``note_lora_rows``): in ``/metrics``,

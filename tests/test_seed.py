@@ -119,7 +119,7 @@ class TestEngineLevel:
             e2.stop()
 
     def test_reproducible_on_pipelined_multistep(self, params):
-        sync = _engine(params)
+        sync = _engine(params, pipeline_decode=False)
         pipe = _engine(params, pipeline_decode=True, decode_steps_per_sync=4)
         sync.start(), pipe.start()
         try:

@@ -222,7 +222,8 @@ class TestSpeculativeLoopComposition:
     def test_greedy_parity_multistep_sync(self):
         rng = np.random.RandomState(11)
         prompts = [list(rng.randint(1, 250, size=n)) for n in (6, 8, 12)]
-        plain, spec = make_engines(spec_k=2, decode_steps_per_sync=8)
+        plain, spec = make_engines(spec_k=2, decode_steps_per_sync=8,
+                                   pipeline_decode=False)
         want = [r.output_tokens for r in run_reqs(plain, prompts)]
         got = [r.output_tokens for r in run_reqs(spec, prompts)]
         assert got == want

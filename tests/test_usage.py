@@ -211,7 +211,8 @@ class TestEngineConservation:
         assert fams["tpu:decode_batch_occupancy_count"][0].value > 0
 
     @pytest.mark.parametrize("extra", [
-        {}, {"pipeline_decode": True}, {"speculative_k": 2},
+        {"pipeline_decode": False}, {"pipeline_decode": True},
+        {"pipeline_decode": False, "speculative_k": 2},
         {"pipeline_decode": True, "speculative_k": 2}],
         ids=["sync", "pipelined", "spec", "pipelined-spec"])
     def test_dispatch_accounting_agrees_across_its_sinks(self, extra):

@@ -195,6 +195,23 @@ def latent_positions_row(profile: dict) -> dict:
                                 "positions_per_dispatch")
 
 
+def overlap_row(profile: dict) -> dict:
+    """Decode blocks dispatched while an earlier block was still unread
+    (``tpu:decode_blocks_overlapped_total``) and their share of the decode
+    blocks (plain and speculative): ~100% in a loaded window of the
+    overlapped loop, 0 under ``--no-pipeline-decode``; empty for a payload
+    from before the counter."""
+    hist = profile.get("hist") or {}
+    if "blocks_overlapped" not in hist:
+        return {}
+    wall = hist.get("wall") or {}
+    n = sum(int((wall.get(kind) or {}).get("count", 0))
+            for kind in ("decode", "spec"))
+    over = int(hist["blocks_overlapped"])
+    return {"blocks_overlapped": over, "decode_blocks": n,
+            "overlapped_pct": round(100.0 * over / n, 2) if n else 0.0}
+
+
 # -- a device trace against the engine thread's annotations -----------------
 
 ANNOTATION_PREFIX = "engine."
@@ -632,6 +649,11 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
         out += ["", "Decode staging:",
                 _table([staged], ("stage_ops", "decode_dispatches",
                                   "ops_per_dispatch"))]
+    overlap = overlap_row(profile)
+    if overlap:
+        out += ["", "Decode overlap (blocks dispatched over an unread one):",
+                _table([overlap], ("blocks_overlapped", "decode_blocks",
+                                   "overlapped_pct"))]
     adapter_rows = lora_rows_row(profile)
     if adapter_rows:
         out += ["", "Adapter rows in the decode steps:",
