@@ -1,6 +1,6 @@
 """Published per-chip peaks, keyed by JAX's ``device_kind``.  A device that is
-not here is an error, not a default.  (A copy of ``bench.py`` ``DEVICE_PEAKS``:
-the yardstick does not move when the program's file does.)"""
+not here is an error, not a default.  The benchmark's own table: the yardstick
+is under ``paths`` and moves with no file of the program."""
 
 DEVICE_PEAKS = {
     # Google Cloud documentation, "TPU v5e": 819 GB/s of HBM bandwidth,

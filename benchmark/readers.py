@@ -2,6 +2,10 @@
 collected.  A metric is a data file ``metrics/<name>.json`` naming one reader
 and its arguments, so most new metrics are data.
 
+The set grows only by a ``benchmark`` PR.  What a program's PR can add
+without one is a metric file and, for a kernel's roofline share, a file of
+shapes beside it: ``kernel_roofline`` finds its bytes function by name.
+
 A reader gets its ``args`` and the run's ``ctx`` (see ``run.collect``) and
 returns a number, or None where it finds nothing to read — the harness then
 leaves that metric out of the line.
@@ -11,6 +15,7 @@ Stdlib only.
 
 from __future__ import annotations
 
+import importlib
 import re
 import statistics
 
@@ -212,7 +217,12 @@ def roofline(args: dict, ctx: dict):
     """Share of the HBM roofline reached by the decode program: the bytes
     one step must read (``shapes.decode_step_bytes`` at the window's mean
     live rows and mean context) over the chip's published bandwidth, over
-    the program's median device time in the trace."""
+    the program's median device time in the trace.  A sparse model's expert
+    bytes are those of the experts uniform routing of the configuration's
+    own ``n_experts_per_token`` would touch, or, under ``"experts":
+    "counted"``, of the experts the window's counters say a layer-step read
+    (``tpu:moe_experts_touched_total`` over ``tpu:moe_layer_steps_total``,
+    which the prefill and chunk programs' layer-steps ride too)."""
     tr = ctx.get("trace")
     if not tr or not tr.get("modules"):
         return None
@@ -227,11 +237,60 @@ def roofline(args: dict, ctx: dict):
     context = sum(r.tokens * (r.prompt_tokens + r.tokens / 2.0)
                   for r in done) / weight
     model = ctx["config"]["model"]
+    experts_read = None
+    if model.get("n_experts") and args.get("experts") == "counted":
+        experts_read = prom_delta(
+            {"num": {"family": "tpu:moe_experts_touched_total"},
+             "den": {"family": "tpu:moe_layer_steps_total"}}, ctx)
+        if experts_read is None:
+            return None
     nbytes = shapes.decode_step_bytes(
         model, rows, context, weights=args.get("weights", "int8"),
-        kv=args.get("kv", "bfloat16"))
+        kv=args.get("kv", "bfloat16"),
+        experts_per_token=model.get("n_experts_per_token", 2),
+        experts_read=experts_read)
     bw = peaks.device_peaks(ctx["device_kind"])["hbm_bytes_per_s"]
     return 100.0 * nbytes / bw / mod["median_s"]
+
+
+def kernel_roofline(args: dict, ctx: dict):
+    """Share of the HBM roofline reached by the operations whose name
+    matches ``regex``: the bytes they must move over the chip's published
+    bandwidth, over their device time.  ``bytes_fn`` names a function under
+    ``benchmark/`` as ``"<module>:<function>"``; it gets the configuration's
+    ``model`` group and ``inputs``, each the window's growth of a counter
+    (``{"family": ...}``) or the mean of a dispatch-record field
+    (``{"profile_field": ..., "phase": ...}``), and returns the bytes of the
+    whole window.  The trace holds the window's last seconds only, so the
+    kernels' traced time is taken up to the window by WORK and not by the
+    clock: by the decode programs the window dispatched
+    (``tpu:dispatch_steps_count``, all replicas) over those the trace holds
+    (replica 0's) — an open loop's traced seconds are not as busy as its
+    window.  Totals against totals, never a mean times a count of steps."""
+    traced_s = trace_op_time({"regex": args["regex"]}, ctx)
+    if not traced_s:
+        return None
+    inputs = {}
+    for key, spec in args["inputs"].items():
+        if "family" in spec:
+            value = _delta(ctx, spec)
+        else:
+            value = profile_field({"field": spec["profile_field"],
+                                   "phase": spec.get("phase", "decode")}, ctx)
+        if not value:
+            return None
+        inputs[key] = value
+    mod = trace_reduce.decode_module(ctx["trace"],
+                                     args.get("min_module_s", 0.001))
+    dispatched = _delta(ctx, {"family": "tpu:dispatch_steps_count"})
+    if mod is None or not dispatched:
+        return None
+    module, _, function = args["bytes_fn"].partition(":")
+    fn = getattr(importlib.import_module("benchmark." + module), function)
+    nbytes = fn(ctx["config"]["model"], inputs)
+    kernel_s = traced_s * dispatched / mod["count"]
+    bw = peaks.device_peaks(ctx["device_kind"])["hbm_bytes_per_s"]
+    return 100.0 * nbytes / bw / kernel_s
 
 
 def client_quantile(args: dict, ctx: dict):
@@ -283,4 +342,5 @@ def client_imbalance(args: dict, ctx: dict):
 READERS = {f.__name__: f for f in (
     prom_delta, prom_hist_quantile, span_quantile, profile_field, poll_peak,
     phase, device_field, trace_idle, trace_op_time, roofline,
-    client_quantile, client_tokens_per_s, client_slo_good, client_imbalance)}
+    client_quantile, client_tokens_per_s, client_slo_good, client_imbalance,
+    kernel_roofline)}

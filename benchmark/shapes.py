@@ -70,12 +70,16 @@ def kv_bytes_per_token(model: dict, kv: str = "bfloat16") -> int:
 
 def decode_step_bytes(model: dict, rows: float, context: float,
                       weights: str = "int8", kv: str = "bfloat16",
-                      experts_per_token: int = 2) -> float:
+                      experts_per_token: int = 2,
+                      experts_read: float | None = None) -> float:
     """Bytes one decode step over ``rows`` live rows of mean length
     ``context`` must read: the weights as stored (for sparse layers, the
-    experts the batch touches) and the cached keys and values in use."""
-    experts_read = None
-    if model.get("n_experts"):
+    experts the batch touches) and the cached keys and values in use.
+    ``experts_read``: how many experts a sparse layer-step read, where a
+    counter says so; otherwise what uniform routing would touch."""
+    if not model.get("n_experts"):
+        experts_read = None
+    elif experts_read is None:
         experts_read = experts_touched(model["n_experts"], experts_per_token,
                                        max(rows, 1.0))
     return (weight_bytes(model, weights, experts_read)

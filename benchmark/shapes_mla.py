@@ -37,6 +37,18 @@ def layer_step_flops(model: dict, positions: float) -> float:
     return 2.0 * positions * model["n_heads"] * (width + model["kv_lora_rank"])
 
 
+def window_bytes(model: dict, inputs: dict) -> float:
+    """Bytes the kernel must move over a whole window, for
+    ``kernel_roofline``: ``inputs`` holds the window's growth of
+    ``positions`` (``tpu:latent_kv_positions_total``: per decode step the
+    live rows' cache lengths, summed) and of ``steps``
+    (``tpu:dispatch_steps_sum``), and ``rows_mean``, the mean live rows of a
+    decode step; every layer of the stack runs the kernel once a step, and
+    ``layer_step_bytes`` is linear in positions and rows."""
+    return model["n_layers"] * layer_step_bytes(
+        model, inputs["positions"], inputs["rows_mean"] * inputs["steps"])
+
+
 def roofline_share(model: dict, positions: float, rows: float,
                    device_s: float, peak: dict) -> dict:
     """Share of the roofline the kernel reached in ``device_s`` seconds of

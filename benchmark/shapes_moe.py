@@ -42,6 +42,19 @@ def layer_step_flops(d_model: int, d_ff: int, rows: float) -> float:
     return 2.0 * rows * 3 * d_model * d_ff
 
 
+def window_bytes(model: dict, inputs: dict, weights: str = "int8") -> float:
+    """Bytes the expert matmuls of a whole window must move, for
+    ``kernel_roofline``: ``inputs`` holds the window's growth of
+    ``touched`` (``tpu:moe_experts_touched_total``) and ``assignments``
+    (``tpu:moe_assignments_total``), each summed over the decode, prefill
+    and chunk programs' layer-steps; ``layer_step_bytes`` is linear in both,
+    so the window's totals give the window's bytes.  An expert's width is
+    ``moe_d_ff`` where the model has one beside its dense ``d_ff``."""
+    d_ff = model.get("moe_d_ff") or model["d_ff"]
+    return layer_step_bytes(model["d_model"], d_ff, inputs["touched"],
+                            inputs["assignments"], weights)
+
+
 def roofline_share(model: dict, experts_touched: float, rows: float,
                    device_s: float, peak: dict,
                    weights: str = "int8") -> dict:

@@ -80,22 +80,31 @@ def test_cell_reports_setup_another_metric_and_a_layer(cell):
 def test_config_file_states_what_is_run(config):
     assert set(config) == {"name", "source", "file", "reduced", "why"}
     cfg = manifest.load_config(config["name"])
-    # Only depth may be cut: no width is ever in ``reduced``.
-    assert set(cfg["reduced"]) <= {"n_layers"}
+    # Counts may be cut (the depth, the experts held here, the vocabulary's
+    # slice), never a width; a share states the published count, keeps the
+    # floors and says how many chips share a layer (manifest.config_problems).
+    assert set(cfg["reduced"]) <= set(manifest.REDUCIBLE)
+    assert manifest.config_problems(cfg) == []
     assert sorted(cfg["reduced"]) == sorted(config["reduced"])
     pub, model = cfg["published"], cfg["model"]
     assert model["d_model"] == pub["hidden_size"]
     assert model["d_ff"] == pub["intermediate_size"]
     assert model["n_heads"] == pub["num_attention_heads"]
     assert model["n_kv_heads"] == pub["num_key_value_heads"]
-    assert model["vocab_size"] == pub["vocab_size"]
     assert model["head_dim"] * model["n_heads"] in (
         pub["hidden_size"], model["head_dim"] * pub["num_attention_heads"])
-    if "n_layers" not in cfg["reduced"]:
-        assert model["n_layers"] == pub["num_hidden_layers"]
-    else:
-        assert model["n_layers"] == cfg["reduced"]["n_layers"]
-    assert model.get("n_experts", 0) == pub.get("num_local_experts", 0)
+    for key in ("n_layers", "vocab_size"):
+        source_key = {"n_layers": "num_hidden_layers"}.get(key, key)
+        if key in cfg["reduced"]:
+            assert model[key] == cfg["reduced"][key] <= pub[source_key]
+        else:
+            assert model[key] == pub[source_key]
+    held = next((model[k] for k in manifest.EXPERTS_HELD if k in model), 0)
+    if not set(manifest.EXPERTS_HELD) & set(cfg["reduced"]):
+        # the source's own key: num_local_experts, num_experts or
+        # n_routed_experts, whichever it has
+        assert held == manifest.published_experts(pub)
+        assert len([k for k in manifest.PUBLISHED_EXPERTS if k in pub]) <= 1
 
 
 def test_at_most_a_quarter_of_the_cells_take_four_chips():

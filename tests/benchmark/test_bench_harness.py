@@ -52,7 +52,10 @@ def test_traced_rehearsal_holds_every_check_and_prints_no_result():
     for name in ("gateway.pick_p50_us", "server.queue_wait_p50_ms",
                  "engine.host_gap_pct", "engine.batch_rows_mean",
                  "model.decode_step_ms", "client.late_p90_ms",
-                 "device.idle_pct", "setup.load_s"):
+                 "device.idle_pct", "setup.load_s",
+                 # appended by PR 41: the request path and the overlap
+                 "gateway.ttft_p50_ms", "server.first_write_p50_ms",
+                 "server.write_lag_ms", "engine.decode_overlap_pct"):
         assert name in read, tail
     assert "phases replica-0" in r.stdout
     assert "new traced programs in the window [0]" in r.stdout

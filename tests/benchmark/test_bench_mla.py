@@ -1,7 +1,9 @@
 """What PR 35 added to the benchmark: the configuration ``glm-4.7-flash-d13``,
-the traffic mix ``agents``, the cell ``glm47flash_d13_agents``, five per-layer
-metrics, the benchmark's own copy of the plain reference, ``shapes_mla`` and
-the check script ``reference_check_glm.py``."""
+the traffic mix ``agents``, the cell ``glm47flash_d13_agents``, its per-layer
+metrics (PR 41 added the latent kernel's roofline share and took the three
+``moe.*.glm`` copies away: the cell reads the ``.batch`` twins), the
+benchmark's own copy of the plain reference, ``shapes_mla`` and the check
+script ``reference_check_glm.py``."""
 
 import json
 import os
@@ -18,9 +20,9 @@ MAN = manifest.load_manifest()
 E2E = {m["name"]: m for m in MAN["end_to_end"]}
 PER_LAYER = {m["name"]: m for m in MAN["per_layer"]}
 CELL = "glm47flash_d13_agents"
+# the cell's alone: they read what only a latent cache has
 NEW = ["mla.decode_attn_ops_pct.batch", "mla.ctx_positions_mean.batch",
-       "moe.experts_touched_mean.glm", "moe.rows_per_expert_mean.glm",
-       "moe.experts_ops_pct.glm"]
+       "mla.decode_attn_hbm_roofline.batch"]
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 
 
@@ -35,15 +37,20 @@ def test_the_cell_and_its_lists():
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "glm-4.7-flash-d13", "agents", 1)
     assert CELL in E2E["output_tok_s"]["workloads"]
-    # every .batch metric of the engine and the device reads here too ...
+    # every metric of the other closed-loop cell reads here too, but for
+    # what this cell gives nothing to read: a trace regex that matches none
+    # of its kernels, and ``roofline``, whose shapes.py counts per-head lanes
+    model = manifest.load_config(cell["config"])["model"]
     listed = [m["name"] for m in MAN["per_layer"]
               if "mixtral_d6_batch" in m.get("workloads", ())]
-    off = {"model.decode_step_hbm_roofline.batch",  # shapes.py counts heads
-           "moe.experts_touched_mean.batch", "moe.rows_per_expert_mean.batch",
-           "moe.experts_ops_pct.batch"}  # pinned by test_bench_moe.py
+    assert listed
     for name in listed:
-        assert (CELL in PER_LAYER[name]["workloads"]) == (name not in off), name
-    assert len(listed) == 20
+        here = manifest.can_report(manifest.load_metric(name), model)
+        assert (CELL in PER_LAYER[name]["workloads"]) == here, name
+    off = {n for n in listed if CELL not in PER_LAYER[n]["workloads"]}
+    assert {"model.decode_step_hbm_roofline.batch",
+            "attn.decode_ops_pct.batch"} <= off
+    assert not any(n.startswith(("engine.", "device.", "moe.")) for n in off)
     # ... and nothing of an open-loop cell does
     for m in MAN["per_layer"]:
         if m["moves"] != "output_tok_s" and "workloads" in m:
@@ -51,23 +58,23 @@ def test_the_cell_and_its_lists():
 
 
 @pytest.mark.parametrize("name", NEW)
-def test_each_new_metric_is_the_cells_alone_and_moves_what_it_reports(name):
+def test_each_latent_metric_lists_latent_cells_and_moves_what_they_report(name):
     entry = PER_LAYER[name]
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"]
     assert entry["moves"] == "output_tok_s"
+    assert set(entry["workloads"]) <= set(E2E[entry["moves"]]["workloads"])
     assert set(entry) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
     assert entry["layer"] in {m["layer"] for m in MAN["per_layer"][:40]}
-    assert manifest.load_metric(name)["reader"] in readers.READERS
-    assert MAN["per_layer"].index(entry) >= len(MAN["per_layer"]) - len(NEW)
-
-
-@pytest.mark.parametrize("name", ["moe.experts_touched_mean",
-                                  "moe.rows_per_expert_mean",
-                                  "moe.experts_ops_pct"])
-def test_glm_twins_read_what_the_batch_twins_read(name):
-    mine, twin = (manifest.load_metric(name + s) for s in (".glm", ".batch"))
-    assert (mine["reader"], mine["args"]) == (twin["reader"], twin["args"])
+    spec = manifest.load_metric(name)
+    assert spec["reader"] in readers.READERS
+    # a cell can read it exactly where its model keeps a latent cache
+    for w in MAN["workloads"]:
+        model = manifest.load_config(w["config"])["model"]
+        assert manifest.can_report(spec, model) == bool(
+            model.get("kv_lora_rank")), w["name"]
+        if w["name"] in entry["workloads"]:
+            assert model.get("kv_lora_rank")
 
 
 def test_counter_metric_reads_a_canned_metrics_text():
@@ -89,7 +96,7 @@ def test_kernel_share_reads_a_canned_trace_summary():
         ["moe_gmm_int8.3", 1.0], ["decode_attention.13", 0.2]]}
     assert read("mla.decode_attn_ops_pct.batch", {"trace": trace}) == (
         pytest.approx(10.0))
-    assert read("moe.experts_ops_pct.glm", {"trace": trace}) == (
+    assert read("moe.experts_ops_pct.batch", {"trace": trace}) == (
         pytest.approx(25.0))
     parent = {"trace": {"window_s": 4.0, "op_totals": [["while.15", 2.0]]}}
     assert read("mla.decode_attn_ops_pct.batch", parent) is None
@@ -114,9 +121,9 @@ def test_configuration_file_holds_the_catalogs_numbers():
     assert model["first_k_dense"] == cfg["first_k_dense_replace"] == 1
     assert model["routed_scaling_factor"] == cfg["routed_scaling_factor"]
     assert cfg["num_hidden_layers"] == 47 and model["n_layers"] == 13
-    for key in ("multi_token_prediction", "rope_pairing", "adapters",
-                "published_num_local_experts"):
+    for key in ("multi_token_prediction", "rope_pairing", "adapters"):
         assert key in cfg["assumed"]
+    assert "num_local_experts" not in cfg["published"]  # the source's own key
     assert "13 of 47 layers" in cfg["deployment"]
     if not os.path.exists(CATALOG):
         pytest.skip("no model catalog here")
@@ -172,6 +179,52 @@ def test_shapes_mla_counts_a_layer_step():
     got = shapes_mla.roofline_share(model, 40000, 32, 2 * at_roofline, peak)
     assert got["bound"] == "hbm"
     assert got["share_pct"] == pytest.approx(50.0)
+
+
+def test_kernel_roofline_sets_the_windows_bytes_against_the_kernels_time():
+    """4,000 decode programs in the window, 500 of them in the trace: the
+    counters' growth over the window stands against eight times the traced
+    kernel time, whatever the clock says of the traced stretch."""
+    cfg = manifest.load_config("glm-4.7-flash-d13")
+    model = cfg["model"]
+    steps, rows, positions = 4000, 32.0, 40000 * 4000
+    nbytes = 13 * shapes_mla.layer_step_bytes(model, positions, rows * steps)
+    assert shapes_mla.window_bytes(
+        model, {"positions": positions, "steps": steps,
+                "rows_mean": rows}) == nbytes
+    at_roofline_s = nbytes / 819e9
+    before = ("tpu:latent_kv_positions_total 7\ntpu:dispatch_steps_sum 1\n"
+              "tpu:dispatch_steps_count 1\n")
+    after = (f"tpu:latent_kv_positions_total {positions + 7}\n"
+             f"tpu:dispatch_steps_sum {steps + 1}\n"
+             f"tpu:dispatch_steps_count {steps + 1}\n")
+    trace = {"window_s": 4.0, "op_totals": [
+        ["mla_decode_attention.16", 0.1875 * at_roofline_s],
+        ["mla_decode_attention.17", 0.0625 * at_roofline_s],
+        ["moe_gmm_int8.3", 1.0]],
+        "modules": {"jit_decode_block": {"count": 500, "total_s": 3.9,
+                                         "median_s": 0.0088},
+                    "jit_prefill": {"count": 9, "total_s": 0.3,
+                                    "median_s": 0.039},
+                    "jit_next_key": {"count": 900, "total_s": 0.004,
+                                     "median_s": 4e-6}}}
+    ctx = {"window_s": 40.0, "config": cfg, "device_kind": "TPU v5 lite",
+           "prom_before": [before], "prom_after": [after], "trace": trace,
+           "profile_records": [[{"phase": "decode", "active": 32},
+                                {"phase": "prefill", "active": 1}]]}
+    name = "mla.decode_attn_hbm_roofline.batch"
+    assert read(name, ctx) == pytest.approx(50.0)
+    assert read(name, dict(ctx, trace=dict(trace, window_s=9.0))) == (
+        pytest.approx(50.0))  # by work, not by the clock
+    # nothing to read: no trace, no kernel in it, a counter that stood still
+    assert read(name, dict(ctx, trace=None)) is None
+    assert read(name, dict(ctx, trace=dict(trace, op_totals=[
+        ["decode_attention.13", 0.2]]))) is None
+    assert read(name, dict(ctx, trace=dict(trace, modules={}))) is None
+    assert read(name, dict(ctx, prom_after=[before])) is None
+    assert read(name, dict(ctx, profile_records=[[]])) is None
+    with pytest.raises(KeyError):  # a device with no published peak
+        read(name, dict(ctx, device_kind="TPU v9"))
 
 
 def test_benchmarks_reference_equals_the_programs_on_glm_tiny():

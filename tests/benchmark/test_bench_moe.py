@@ -1,6 +1,8 @@
 """What PR 27 added to the benchmark: the configuration ``olmoe-1b-7b``, the
-cell ``olmoe_chat``, six per-layer metrics of the sparse layer, the
-benchmark's own copy of the plain reference, and ``shapes_moe``."""
+cell ``olmoe_chat``, six per-layer metrics of the sparse layer (PR 41: and the
+expert matmuls' roofline share), the benchmark's own copy of the plain
+reference, and ``shapes_moe``.  The ``moe.*`` metrics are held to a rule, not
+to lists: any sparse cell may join them."""
 
 import json
 import os
@@ -13,13 +15,15 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
-from benchmark import manifest, readers, shapes_moe  # noqa: E402
+from benchmark import manifest, readers, shapes, shapes_moe  # noqa: E402
 
 MAN = manifest.load_manifest()
 E2E = {m["name"]: m for m in MAN["end_to_end"]}
 PER_LAYER = {m["name"]: m for m in MAN["per_layer"]}
 NEW = ["moe.experts_touched_mean", "moe.rows_per_expert_mean",
        "moe.experts_ops_pct"]
+MOE = [m["name"] for m in MAN["per_layer"] if m["name"].startswith("moe.")]
+QWEN_BYTES, MIXTRAL_BYTES = 7216468992, 8059207362.0
 
 # /metrics of a replica before and after a window: 100 layer-steps of 64
 # experts, 4,000 assignments, 4,500 experts touched.
@@ -49,19 +53,26 @@ def test_manifest_has_no_problem_with_the_new_entries():
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "olmoe-1b-7b", "chat_olmoe", 1)
     assert "olmoe_chat" in E2E["tpot_p50_ms"]["workloads"]
-    assert "olmoe_chat" not in PER_LAYER[
+    # the whole step's share counts the experts the counters say were read
+    assert "olmoe_chat" in PER_LAYER[
         "model.decode_step_hbm_roofline"]["workloads"]
+    assert manifest.load_metric("model.decode_step_hbm_roofline")[
+        "args"]["experts"] == "counted"
+    assert {n + s for n in NEW for s in ("", ".batch")} <= set(MOE)
 
 
-@pytest.mark.parametrize("name", [n + s for n in NEW for s in ("", ".batch")])
-def test_each_new_metric_names_a_cell_that_reports_what_it_moves(name):
+@pytest.mark.parametrize("name", MOE)
+def test_each_moe_metric_lists_sparse_cells_that_report_what_it_moves(name):
     entry = PER_LAYER[name]
-    batch = name.endswith(".batch")
-    assert entry["workloads"] == (
-        ["mixtral_d6_batch"] if batch else ["olmoe_chat"])
-    assert entry["moves"] == ("output_tok_s" if batch else "tpot_p50_ms")
+    assert entry["workloads"], "a moe.* metric names its cells"
+    for cell in entry["workloads"]:
+        config = manifest.cell(MAN, cell)["config"]
+        assert manifest.load_config(config)["model"].get("n_experts", 0) > 0
     assert set(entry["workloads"]) <= set(E2E[entry["moves"]]["workloads"])
-    assert manifest.load_metric(name)["reader"] in readers.READERS
+    spec = manifest.load_metric(name)
+    assert spec["reader"] in readers.READERS
+    dense = manifest.load_config("qwen2.5-7b")["model"]
+    assert not manifest.can_report(spec, dense)
 
 
 @pytest.mark.parametrize("suffix", ["", ".batch"])
@@ -85,6 +96,110 @@ def test_ops_share_reads_a_canned_trace_summary(suffix):
     parent = {"trace": {"window_s": 4.0, "op_totals": [["while.15", 2.0]]}}
     assert read("moe.experts_ops_pct" + suffix, parent) is None
     assert read("moe.experts_ops_pct" + suffix, {}) is None
+
+
+def test_experts_roofline_reads_the_windows_totals_against_the_traced_time():
+    """1,000 decode programs in the window, 100 of them in the trace: the
+    touched experts' bytes of the window stand against ten times the traced
+    kernel time."""
+    cfg = manifest.load_config("olmoe-1b-7b")
+    nbytes = shapes_moe.layer_step_bytes(2048, 1024, 4500, 4000)
+    assert shapes_moe.window_bytes(
+        cfg["model"], {"touched": 4500, "assignments": 4000}) == nbytes
+    at_roofline_s = nbytes / 819e9
+    trace = {"window_s": 4.0, "op_totals": [
+        ["moe_gmm_int8.3", 0.1 * at_roofline_s],
+        ["moe_gmm_int8.4", 0.1 * at_roofline_s], ["while.15", 2.0]],
+        "modules": {"jit_decode_block": {"count": 100, "total_s": 0.44,
+                                         "median_s": 0.0044},
+                    "jit_prefill": {"count": 3, "total_s": 0.03,
+                                    "median_s": 0.0106}}}
+    ctx = {"prom_before": [BEFORE + "tpu:dispatch_steps_count 50\n"],
+           "prom_after": [AFTER + "tpu:dispatch_steps_count 1050\n"],
+           "window_s": 40.0, "config": cfg, "device_kind": "TPU v5 lite",
+           "trace": trace}
+    name = "moe.experts_hbm_roofline.batch"
+    assert read(name, ctx) == pytest.approx(50.0)
+    assert read(name, dict(ctx, trace=None)) is None
+    assert read(name, dict(ctx, trace=dict(trace, op_totals=[
+        ["while.15", 2.0]]))) is None
+    assert read(name, dict(ctx, prom_after=ctx["prom_before"])) is None
+    # four replicas' counters against replica 0's trace
+    assert read(name, dict(ctx, prom_before=ctx["prom_before"] * 4,
+                           prom_after=ctx["prom_after"] * 4)) == (
+        pytest.approx(50.0))
+    # closed loops only: an open loop's traced seconds hold other rows than
+    # its window's mean (PERF.md section 6, PR 41), so olmoe_chat has none
+    assert "moe.experts_hbm_roofline" not in PER_LAYER
+    closed = {w["name"] for w in MAN["workloads"]
+              if manifest.load_traffic(w["traffic"])["loop"] == "closed"}
+    assert set(PER_LAYER[name]["workloads"]) <= closed
+    # an expert's width is moe_d_ff where the model has one beside d_ff
+    glm = manifest.load_config("glm-4.7-flash-d13")["model"]
+    assert shapes_moe.window_bytes(glm, {"touched": 10, "assignments": 0}) == (
+        10 * shapes_moe.expert_bytes(2048, 1536))
+
+
+STEP_S = 0.0044
+
+
+class Row:  # what ``roofline`` reads of a client's result
+    in_window, tokens, prompt_tokens = True, 64, 96
+
+
+def roofline_ctx(config, experts_touched=None, step_s=STEP_S):
+    prom = ["", ""]
+    if experts_touched is not None:
+        prom = ["tpu:moe_experts_touched_total 0\n"
+                "tpu:moe_layer_steps_total 0\n",
+                f"tpu:moe_experts_touched_total {experts_touched * 100}\n"
+                "tpu:moe_layer_steps_total 100\n"]
+    return {"config": manifest.load_config(config), "window_s": 40.0,
+            "device_kind": "TPU v5 lite", "results": [Row()],
+            "prom_before": [prom[0]], "prom_after": [prom[1]],
+            "profile_records": [[{"phase": "decode", "active": 4}]],
+            # olmoe's traced 4 s since PR 40: a 4.4 ms decode program, the
+            # prefills longer and rarer, the helpers far under a millisecond
+            "trace": {"modules": {
+                "jit_decode_block": {"count": 787, "total_s": 3.5,
+                                     "median_s": step_s},
+                "jit_prefill": {"count": 10, "total_s": 0.1,
+                                "median_s": 0.0106},
+                "jit_convert_element_type": {"count": 900, "total_s": 0.001,
+                                             "median_s": 1e-6}}}}
+
+
+def test_the_whole_steps_roofline_counts_the_experts_the_counters_say():
+    name = "model.decode_step_hbm_roofline"
+    olmoe = manifest.load_config("olmoe-1b-7b")["model"]
+    got = read(name, roofline_ctx("olmoe-1b-7b", experts_touched=20))
+    want = shapes.decode_step_bytes(olmoe, 4, 96 + 32, experts_read=20)
+    assert got == pytest.approx(100 * want / 819e9 / STEP_S)
+    more = read(name, roofline_ctx("olmoe-1b-7b", experts_touched=40))
+    assert more > got
+    # no counter (a parent before PR 27): nothing, not a guess
+    assert read(name, roofline_ctx("olmoe-1b-7b")) is None
+    # a dense model has no experts: it reads with or without the counters
+    dense = read(name, roofline_ctx("qwen2.5-7b"))
+    assert dense == read(name, roofline_ctx("qwen2.5-7b", experts_touched=3))
+    qwen = manifest.load_config("qwen2.5-7b")["model"]
+    assert dense == pytest.approx(
+        100 * shapes.decode_step_bytes(qwen, 4, 128) / 819e9 / STEP_S)
+    # the batch twin keeps uniform routing at the configuration's own top-k:
+    # Mixtral's file states none, and 2 is the default it always had
+    batch = read(name + ".batch", roofline_ctx("mixtral-8x7b-d6", 7, 0.0143))
+    mixtral = manifest.load_config("mixtral-8x7b-d6")["model"]
+    assert batch == pytest.approx(
+        100 * shapes.decode_step_bytes(mixtral, 4, 128) / 819e9 / 0.0143)
+
+
+def test_decode_step_bytes_are_what_they_were_before_pr_41():
+    """Byte for byte the parent's numbers (computed on 62dcf1d) at 8 live
+    rows of 300 positions: the new arguments move nothing that was there."""
+    qwen = manifest.load_config("qwen2.5-7b")["model"]
+    mixtral = manifest.load_config("mixtral-8x7b-d6")["model"]
+    assert shapes.decode_step_bytes(qwen, 8, 300) == QWEN_BYTES
+    assert shapes.decode_step_bytes(mixtral, 8, 300) == MIXTRAL_BYTES
 
 
 def test_the_traffic_file_is_the_chat_mix_at_its_own_rate():

@@ -5,14 +5,10 @@ outside the engine loop, all data files over two readers that were there
 parent gives (no counter, no attribute, no span); three read what the parent
 records already and report there too.
 
-Their ``per_layer`` entries are not in ``BENCHMARK.json`` yet: a program's PR
-may only append to that list, and ``test_bench_mla.py`` holds PR 35's five to
-its last five places (PERF.md section 7 row 24).  They wait in
-``benchmark/pending/request_path.json``, and are held here to the manifest's
-rules as they will stand at its end."""
+Their ``per_layer`` entries waited in ``benchmark/pending/`` until PR 41
+appended them to ``BENCHMARK.json``, where this file reads them; the six
+``.batch`` twins list both closed-loop cells."""
 
-import copy
-import json
 import os
 import sys
 
@@ -24,13 +20,12 @@ sys.path.insert(0, REPO)
 from benchmark import manifest, readers  # noqa: E402
 
 MAN = manifest.load_manifest()
-with open(os.path.join(REPO, "benchmark", "pending", "request_path.json")) as f:
-    PENDING = json.load(f)["per_layer"]
-PER_LAYER = {m["name"]: m for m in PENDING}
+PER_LAYER = {m["name"]: m for m in MAN["per_layer"]}
+E2E = {m["name"]: m for m in MAN["end_to_end"]}
 OPEN3 = ["qwen7b_chat", "qwen7b_doc", "olmoe_chat"]
 OPEN4 = ["qwen7b_chat", "qwen7b_doc", "qwen7b_pool4_chat", "olmoe_chat"]
 POOL = ["qwen7b_pool4_chat"]
-GLM = ["glm47flash_d13_agents"]
+CLOSED = ["mixtral_d6_batch", "glm47flash_d13_agents"]
 
 # name -> (layer, its value over the canned change)
 FIRST_TOKEN = {  # twin .pool
@@ -60,7 +55,7 @@ PARENT_HAS = {"server.prefill_span_p50_ms", "engine.prefill_stage_ms",
 CASES = ([(n, "", "tpot_p50_ms", OPEN3) for n in FIRST_TOKEN]
          + [(n, ".pool", "ttft_p50_ms", POOL) for n in FIRST_TOKEN]
          + [(n, "", "tpot_p50_ms", OPEN4) for n in PER_TOKEN]
-         + [(n, ".batch", "output_tok_s", GLM) for n in PER_TOKEN]
+         + [(n, ".batch", "output_tok_s", CLOSED) for n in PER_TOKEN]
          + [(n, "", "tpot_p50_ms", OPEN4) for n in OPEN_ONLY])
 EXPECT = {**FIRST_TOKEN, **PER_TOKEN, **OPEN_ONLY}
 
@@ -133,19 +128,19 @@ def ctx_of(new: bool) -> dict:
             "server_traces": [[server_trace(i, new) for i in range(3)]]}
 
 
-def test_manifest_is_untouched_and_takes_the_pending_entries_at_its_end():
+def test_the_manifest_holds_the_entries_after_those_that_were_there():
     assert manifest.problems(MAN) == []
     names = [m["name"] for m in MAN["per_layer"]]
-    new = {n + s for n, s, _, _ in CASES}
-    assert len(new) == len(CASES) == len(PENDING) == 24
-    assert {m["name"] for m in PENDING} == new
-    assert not new & set(names) and len(names) == 64
-    grown = copy.deepcopy(MAN)
-    grown["per_layer"] += PENDING
-    assert manifest.problems(grown) == []
-    # tests/benchmark/test_bench_mla.py counts the metrics of
-    # mixtral_d6_batch: none of these lists it (PERF.md section 7 row 24)
-    assert not any("mixtral_d6_batch" in m["workloads"] for m in PENDING)
+    new = [n + s for n, s, _, _ in CASES]
+    assert len(set(new)) == len(CASES) == 24
+    assert set(new) <= set(names)
+    # appended, in one block and in the order PR 37 gave them: a program's
+    # PR appends and never inserts
+    first = names.index(new[0])
+    assert first > names.index("mla.ctx_positions_mean.batch")
+    block = names[first:first + len(new)]
+    assert set(block) == set(new)
+    assert not os.path.exists(os.path.join(REPO, "benchmark", "pending"))
 
 
 @pytest.mark.parametrize("base,suffix,moves,cells", CASES,
@@ -156,8 +151,12 @@ def test_entry_and_its_reading(base, suffix, moves, cells):
     layer, value = EXPECT[base]
     assert set(entry) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    assert (entry["layer"], entry["moves"], entry["workloads"],
-            entry["better"]) == (layer, moves, cells, "lower")
+    assert (entry["layer"], entry["moves"], entry["better"]) == (
+        layer, moves, "lower")
+    # the cells it came with, and whichever joined since: each reports
+    # what the metric moves
+    assert set(cells) <= set(entry["workloads"]) <= set(
+        E2E[moves]["workloads"])
     spec = manifest.load_metric(name)
     assert spec["reader"] in ("span_quantile", "prom_delta")
     assert entry["source"] == ("program_span"
