@@ -495,6 +495,11 @@ CLASSES = (
                         note="latent cache rows the decode steps read: the "
                              "engine thread adds at each dispatch, the "
                              "scrape reads under the lock"),
+            SharedField("ssm_rows", LOCK_GUARDED,
+                        writers=("note_ssm_rows",),
+                        note="recurrent states the decode steps rewrote: "
+                             "the engine thread adds at each dispatch, the "
+                             "scrape reads under the lock"),
             SharedField("_last_end", OWNER_PRIVATE,
                         writers=("note_dispatch",)),
             SharedField("_idle_pending", OWNER_PRIVATE,

@@ -119,8 +119,12 @@ RELAY_TAIL_BYTES = 16384
 # how many concurrent connections one pod may hold.  Reuse is the point —
 # a fresh TCP handshake per request is pure data-plane tax.
 UPSTREAM_KEEPALIVE_S = float(os.environ.get("LIG_UPSTREAM_KEEPALIVE_S", "30"))
+# A replica streams one connection a request it decodes: the cap has to lie
+# above the largest ``--decode-slots`` a pool serves, or the gateway fills
+# half a replica and times the rest out waiting for a connection (a 64-slot
+# replica behind the old 32: chip run, PR 43).
 UPSTREAM_CONNS_PER_POD = int(os.environ.get("LIG_UPSTREAM_CONNS_PER_POD",
-                                            "32"))
+                                            "256"))
 
 
 def final_data_line(tail: bytes) -> bytes:

@@ -412,6 +412,13 @@ SERVER_FAMILIES = (
            "attention kernel reads per layer. 0 for a model with per-head "
            "K/V lanes.",
            SERVER_SURFACE),
+    Family("tpu:ssm_state_rows_total", "counter", (),
+           "Rows whose recurrent (state-space) state a decode step rewrote, "
+           "summed over the steps of the plain decode dispatches: over "
+           "tpu:dispatch_steps_sum, the states one decode step's update "
+           "kernel reads and writes per layer. 0 for a model without a "
+           "mixer.",
+           SERVER_SURFACE),
     Family("tpu:lora_rows_total", "counter", (),
            "Live rows whose LoRA slot is >= 0, summed over the steps of the "
            "plain decode dispatches: over tpu:dispatch_steps_sum, the rows "

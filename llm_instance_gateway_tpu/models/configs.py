@@ -5,7 +5,10 @@ The reference's PoC pools serve Llama-2-7b + LoRA on vLLM
 BASELINE.json milestone configs call for Gemma-2B, Llama-3-8B and a
 Mixtral-8x7B + Gemma-7B mixed pool.  These dataclasses cover all of them with
 one decoder family (RoPE + GQA + RMSNorm + gated MLP, optionally MoE;
-Qwen2's QKV bias and OLMoE's QK-norm and gate rule are flags).
+Qwen2's QKV bias and OLMoE's QK-norm and gate rule are flags; GLM's latent
+attention, ``models/mla.py``; Falcon-H1's parallel block, a state-space
+mixer beside the attention of every layer, ``models/ssm.py``, with the
+family's fixed muP multipliers).
 
 All dims are chosen/padded TPU-first: head_dim and d_model multiples of 128
 (MXU lane width), d_ff multiples of 128, vocab padded to 128 so the final
@@ -84,6 +87,37 @@ class ModelConfig:
     first_k_dense: int = 0
     router_sigmoid: bool = False
     routed_scaling_factor: float = 1.0
+    # A state-space mixer beside attention in every layer (Falcon-H1,
+    # ``falcon_h1``; the Mamba-2 form, ``models/ssm.py`` holds the
+    # equations): ``ssm_d_inner`` > 0 makes the block parallel,
+    # ``x + attn(x_n) + ssm(x_n)`` and then the MLP, and the decode cache
+    # hold a recurrent state ``ssm`` [L, B, heads, d_state, head_dim]
+    # (float32) and the conv's last ``ssm_d_conv - 1`` inputs ``conv``
+    # [L, d_conv - 1, B, conv_dim] beside ``k`` and ``v``.  ``ssm_d_inner`` = ``ssm_n_heads`` x
+    # ``ssm_head_dim``; B and C are shared by the heads of a group (head h
+    # reads group ``h // (ssm_n_heads // ssm_n_groups)``); a prompt's
+    # recurrence is computed in chunks of ``ssm_chunk`` positions.
+    ssm_d_inner: int = 0
+    ssm_n_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_d_state: int = 0
+    ssm_n_groups: int = 1
+    ssm_d_conv: int = 4
+    ssm_chunk: int = 128
+    # That family's fixed scalar multipliers (muP), each 1 for every other
+    # model, which then traces no multiply: on the embedding, on the input
+    # of the attention and of the mixer, on the keys, on both branches'
+    # outputs, on the mixer's projected z | x | B | C | dt, on the MLP's
+    # gate and output, on the logits.
+    embedding_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: tuple[float, float] = (1.0, 1.0)
+    lm_head_multiplier: float = 1.0
     # LoRA serving slots (compile-time constants: resizing reshapes buffers
     # and recompiles, so they mirror vLLM's --max-loras / max rank flags).
     max_lora_slots: int = 4
@@ -121,6 +155,16 @@ class ModelConfig:
     def latent_lanes(self) -> int:
         """``latent_width`` padded to whole 128-lane vregs: the cache row."""
         return pad_to(self.latent_width, 128)
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the mixer's causal conv runs over: x | B | C."""
+        return self.ssm_d_inner + 2 * self.ssm_n_groups * self.ssm_d_state
+
+    @property
+    def ssm_in_dim(self) -> int:
+        """Columns of the mixer's input projection: z | x | B | C | dt."""
+        return self.ssm_d_inner + self.ssm_conv_dim + self.ssm_n_heads
 
     @property
     def q_per_kv(self) -> int:
@@ -308,6 +352,52 @@ TINY_GLM_TEST = replace(
     GLM_4_7_FLASH.tiny(), name="glm-tiny", n_layers=3, n_kv_heads=4,
     head_dim=32, q_lora_rank=48, kv_lora_rank=40, qk_nope_head_dim=24,
     qk_rope_head_dim=8, v_head_dim=32, moe_d_ff=96)
+
+# tiiuae/Falcon-H1-34B-Instruct (``falcon_h1``): every layer runs attention
+# (20 query / 4 kv heads of 128) and a Mamba-2 mixer (32 heads of 128, state
+# 256, 2 groups, conv 4) side by side on one normed input, then a dense MLP;
+# the muP multipliers are the published config's.
+FALCON_H1_34B = ModelConfig(
+    name="falcon-h1-34b",
+    vocab_size=261_120,
+    d_model=5120,
+    n_layers=72,
+    n_heads=20,
+    n_kv_heads=4,
+    d_ff=21_504,
+    head_dim=128,
+    rope_theta=1e11,
+    norm_eps=1e-5,
+    ssm_d_inner=4096,
+    ssm_n_heads=32,
+    ssm_head_dim=128,
+    ssm_d_state=256,
+    ssm_n_groups=2,
+    ssm_d_conv=4,
+    ssm_chunk=128,
+    embedding_multiplier=5.656854249492381,
+    attention_in_multiplier=1.0,
+    attention_out_multiplier=0.0375,
+    key_multiplier=0.011048543456039804,
+    ssm_in_multiplier=0.25,
+    ssm_out_multiplier=0.08838834764831845,
+    ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                     0.3535533905932738),
+    mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+    lm_head_multiplier=0.0078125,
+    max_seq_len=262_144,
+    max_lora_slots=0,  # adapters are not served beside the multipliers
+)
+
+# The CPU's Falcon-H1: the ratios kept (5 queries a kv head, 2 groups of
+# mixer heads, conv 4, a prompt of several scan chunks), every multiplier
+# away from 1 and distinct.
+TINY_FALCON_H1_TEST = replace(
+    FALCON_H1_34B, name="falcon-h1-tiny", vocab_size=320, d_model=256,
+    n_layers=3, n_heads=5, n_kv_heads=1, head_dim=32, d_ff=512,
+    ssm_d_inner=128, ssm_n_heads=4, ssm_head_dim=32, ssm_d_state=16,
+    ssm_chunk=8, attention_in_multiplier=0.8, max_seq_len=512,
+    max_lora_rank=4)
 
 TINY_TEST = LLAMA3_8B.tiny()
 TINY_MOE_TEST = MIXTRAL_8X7B.tiny()

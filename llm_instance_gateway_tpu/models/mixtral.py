@@ -2,7 +2,10 @@
 gates) and OLMoE-1B-7B (64 experts, top-8, gates as the full softmax gives
 them, QK-norm) and GLM-4.7-Flash (latent attention, a leading dense layer,
 64 experts top-4 behind a sigmoid router with a selection bias, a shared
-expert).
+expert).  Falcon-H1-34B (dense; a state-space mixer beside attention in
+every layer, ``models/ssm.py``) is entered here too: the benchmark's server
+wrapper looks a preset up in this dict, ``llama``'s, ``gemma``'s and
+``qwen``'s and nowhere else.
 
 BASELINE.json's criticality-tiered mixed pool pairs Mixtral-8x7B with
 Gemma-7B on v5e-32.  The MoE MLP lives in ``transformer._moe_mlp``; expert
@@ -14,9 +17,11 @@ from __future__ import annotations
 
 from llm_instance_gateway_tpu.models import transformer
 from llm_instance_gateway_tpu.models.configs import (
+    FALCON_H1_34B,
     GLM_4_7_FLASH,
     MIXTRAL_8X7B,
     OLMOE_1B_7B,
+    TINY_FALCON_H1_TEST,
     TINY_GLM_TEST,
     TINY_MOE_TEST,
     TINY_OLMOE_TEST,
@@ -24,7 +29,9 @@ from llm_instance_gateway_tpu.models.configs import (
 
 CONFIGS = {"mixtral-8x7b": MIXTRAL_8X7B, "mixtral-tiny": TINY_MOE_TEST,
            "olmoe-1b-7b": OLMOE_1B_7B, "olmoe-tiny": TINY_OLMOE_TEST,
-           "glm-4.7-flash": GLM_4_7_FLASH, "glm-tiny": TINY_GLM_TEST}
+           "glm-4.7-flash": GLM_4_7_FLASH, "glm-tiny": TINY_GLM_TEST,
+           "falcon-h1-34b": FALCON_H1_34B,
+           "falcon-h1-tiny": TINY_FALCON_H1_TEST}
 
 init_params = transformer.init_params
 init_decode_cache = transformer.init_decode_cache

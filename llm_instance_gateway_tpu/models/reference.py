@@ -43,6 +43,30 @@ and its sparse layers (the first ``first_k_dense`` layers keep the dense MLP):
     chosen s + 1e-20): b picks and never weighs;
     x = x + sum_i g_i E_i(h_n) + S(h_n),  S the shared expert
 
+Falcon-H1 (``falcon_h1``, ``ssm_d_inner`` > 0): a PARALLEL block, attention
+and a state-space mixer (Mamba-2 form) both on x_n, their outputs added to
+the residual together, and fixed scalar multipliers (1 for the models above):
+
+    x0 = E[token] * embedding_multiplier
+    q, k, v = (x_n * attention_in_multiplier) Wq, Wk, Wv;  k = k * key_multiplier
+    a = attention as above;  a = (a Wo) * attention_out_multiplier
+    [z | xBC | dt] = ((x_n * ssm_in_multiplier) W_in) * m
+        m = ssm_multipliers over the parts z [d_inner], x [d_inner],
+        B [groups * d_state], C [groups * d_state], dt [heads], in this order
+    xBC_t = silu(sum_j w_conv[j] * xBC_{t-(K-1)+j} + b_conv)   causal,
+        depthwise, K = ssm_d_conv taps, zeros before position 0
+    x | B | C = xBC:  x [heads, head_dim];  B, C [groups, d_state];
+        head h reads group h // (heads / groups)
+    dt_t = softplus(dt_t + dt_bias) (no clamp);  A = -exp(A_log)
+    H_t = exp(dt_t A) * H_{t-1} + dt_t * x_t (outer) B_t,   H_{-1} = 0,
+        one position after another
+    y_t = H_t C_t + D * x_t
+    y = RMSNorm_w(y * silu(z)) with the mean of squares over each group's
+        d_inner / groups columns (the gate first: norm_before_gate false)
+    s = (y W_out) * ssm_out_multiplier;   x = x + a + s
+    x = x + ((silu((h_n Wg) * mlp_multipliers[0]) * (h_n Wu)) Wd) * mlp_multipliers[1]
+    logits = (RMSNorm(x) W_head) * lm_head_multiplier
+
 A LoRA adapter adds ``scale * (z A) B`` to a projection of ``z``.
 
 Departures from the published descriptions, each on purpose:
@@ -101,6 +125,7 @@ def _lora(z, lora, layer, target):
 
 def _attention(cfg, lp, layer, x_n, lora):
     s = x_n.shape[0]
+    x_n = x_n * cfg.attention_in_multiplier
     hd = cfg.head_dim or cfg.d_model // cfg.n_heads
     proj = {}
     for t in ("q", "k", "v"):
@@ -109,7 +134,7 @@ def _attention(cfg, lp, layer, x_n, lora):
             z = z + lp[f"w{t}_b"][layer].astype(F32)
         if cfg.qk_norm and t != "v":
             z = _rms_norm(z, lp[f"{t}_norm"][layer].astype(F32), cfg.norm_eps)
-        proj[t] = z
+        proj[t] = z * cfg.key_multiplier if t == "k" else z
     q = _rope(proj["q"].reshape(s, cfg.n_heads, hd), cfg.rope_theta)
     k = _rope(proj["k"].reshape(s, cfg.n_kv_heads, hd), cfg.rope_theta)
     v = proj["v"].reshape(s, cfg.n_kv_heads, hd)
@@ -119,7 +144,47 @@ def _attention(cfg, lp, layer, x_n, lora):
     causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
     probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
     a = jnp.einsum("hij,jhd->ihd", probs, v).reshape(s, -1)
-    return a @ _weight(lp["wo"], layer) + _lora(a, lora, layer, "o")
+    return ((a @ _weight(lp["wo"], layer) + _lora(a, lora, layer, "o"))
+            * cfg.attention_out_multiplier)
+
+
+def _mixer(cfg, lp, layer, x_n, states=None):
+    """The state-space branch of a Falcon-H1 block, position by position.
+    ``states``, a list, gets the layer's last ``H`` [heads, head_dim,
+    d_state] appended."""
+    s = x_n.shape[0]
+    heads, hd, n = cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_d_state
+    groups, taps, di = cfg.ssm_n_groups, cfg.ssm_d_conv, cfg.ssm_d_inner
+    gn = groups * n
+    m = jnp.concatenate([jnp.full((w,), mult, F32) for w, mult in zip(
+        (di, di, gn, gn, heads), cfg.ssm_multipliers)])
+    proj = ((x_n * cfg.ssm_in_multiplier) @ _weight(lp["ssm_in"], layer)) * m
+    z, xbc, dt = proj[:, :di], proj[:, di:di + di + 2 * gn], proj[:, -heads:]
+    w = lp["ssm_conv_w"][layer].astype(F32)  # [taps, channels]
+    padded = jnp.concatenate([jnp.zeros((taps - 1, xbc.shape[1]), F32), xbc])
+    xbc = jax.nn.silu(sum(w[j] * padded[j:j + s] for j in range(taps))
+                      + lp["ssm_conv_b"][layer].astype(F32))
+    x = xbc[:, :di].reshape(s, heads, hd)
+    b = jnp.repeat(xbc[:, di:di + gn].reshape(s, groups, n),
+                   heads // groups, axis=1)  # [S, heads, d_state]
+    c = jnp.repeat(xbc[:, di + gn:].reshape(s, groups, n),
+                   heads // groups, axis=1)
+    dt = jax.nn.softplus(dt + lp["ssm_dt_bias"][layer].astype(F32))
+    a = -jnp.exp(lp["ssm_a_log"][layer].astype(F32))
+    state = jnp.zeros((heads, hd, n), F32)
+    ys = []
+    for t in range(s):
+        state = (jnp.exp(dt[t] * a)[:, None, None] * state
+                 + dt[t][:, None, None] * x[t][:, :, None] * b[t][:, None, :])
+        ys.append(jnp.sum(state * c[t][:, None, :], axis=-1))
+    if states is not None:
+        states.append(state)
+    y = jnp.stack(ys) + lp["ssm_d"][layer].astype(F32)[:, None] * x
+    y = (y.reshape(s, di) * jax.nn.silu(z)).reshape(s, groups, di // groups)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+    y = y.reshape(s, di) * lp["ssm_norm"][layer].astype(F32)
+    return (y @ _weight(lp["ssm_out"], layer)) * cfg.ssm_out_multiplier
 
 
 def _latent_attention(cfg, lp, layer, x_n):
@@ -152,8 +217,9 @@ def _mlp(cfg, lp, layer, h_n, lora):
     if "router" not in lp:  # a dense model, or a leading dense layer
         gate = h_n @ _weight(lp["w_gate"], layer) + _lora(h_n, lora, layer, "gate")
         up = h_n @ _weight(lp["w_up"], layer) + _lora(h_n, lora, layer, "up")
-        act = jax.nn.silu(gate) * up
-        return act @ _weight(lp["w_down"], layer) + _lora(act, lora, layer, "down")
+        act = jax.nn.silu(gate * cfg.mlp_multipliers[0]) * up
+        return ((act @ _weight(lp["w_down"], layer)
+                 + _lora(act, lora, layer, "down")) * cfg.mlp_multipliers[1])
     router = h_n @ lp["router"][layer].astype(F32)  # [S, E]
     if cfg.router_sigmoid:
         p = jax.nn.sigmoid(router)
@@ -178,19 +244,23 @@ def _mlp(cfg, lp, layer, h_n, lora):
     return y
 
 
-def forward(cfg, params, tokens, lora=None):
+def forward(cfg, params, tokens, lora=None, states=None):
     """Logits [S, V] (float32) of one sequence ``tokens`` [S] at positions
     0..S-1.  ``params``: the program's tree (``transformer.init_params``
     layout; int8 leaves allowed).  ``lora``: None, or ``(buffers, slot)``,
     the serving LoRA buffers and the slot whose adapter this sequence uses.
+    ``states``: None, or a list that gets each layer's recurrent state after
+    the last position (a model with a mixer), to hold a cache's against.
     """
     if (cfg.tie_embeddings or cfg.embedding_scale or cfg.norm_plus_one
             or cfg.gelu_mlp or cfg.rope_scaling_factor):
         raise NotImplementedError(
-            "the reference covers the Llama/Qwen2/Mixtral/OLMoE/GLM block; the "
+            "the reference covers the Llama/Qwen2/Mixtral/OLMoE/GLM/Falcon-H1 "
+            "block; the "
             f"Gemma conventions and rope scaling of {cfg.name} are not in it")
-    if cfg.kv_lora_rank and lora is not None:
-        raise NotImplementedError("no adapter over latent projections")
+    if (cfg.kv_lora_rank or cfg.ssm_d_inner) and lora is not None:
+        raise NotImplementedError(
+            "no adapter over latent projections or beside a mixer")
     # (stack, index in it) of every layer: leading dense layers, if the
     # model has them, lie in a stack of their own.
     n_dense = (params["dense_layers"]["attn_norm"].shape[0]
@@ -199,13 +269,16 @@ def forward(cfg, params, tokens, lora=None):
              + [(params["layers"], i)
                 for i in range(cfg.n_layers - n_dense)])
     with jax.default_matmul_precision("highest"):
-        x = params["embed"][tokens].astype(F32)
+        x = params["embed"][tokens].astype(F32) * cfg.embedding_multiplier
         for lp, layer in stack:
             x_n = _rms_norm(x, lp["attn_norm"][layer].astype(F32), cfg.norm_eps)
-            x = x + (_latent_attention(cfg, lp, layer, x_n)
-                     if cfg.kv_lora_rank
-                     else _attention(cfg, lp, layer, x_n, lora))
+            branches = (_latent_attention(cfg, lp, layer, x_n)
+                        if cfg.kv_lora_rank
+                        else _attention(cfg, lp, layer, x_n, lora))
+            if cfg.ssm_d_inner:
+                branches = branches + _mixer(cfg, lp, layer, x_n, states)
+            x = x + branches
             h_n = _rms_norm(x, lp["mlp_norm"][layer].astype(F32), cfg.norm_eps)
             x = x + _mlp(cfg, lp, layer, h_n, lora)
         x = _rms_norm(x, params["final_norm"].astype(F32), cfg.norm_eps)
-        return x @ _weight(params["lm_head"])
+        return (x @ _weight(params["lm_head"])) * cfg.lm_head_multiplier

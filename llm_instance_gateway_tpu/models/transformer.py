@@ -1,11 +1,14 @@
-"""Core decoder-only transformer: one implementation, six families.
+"""Core decoder-only transformer: one implementation, seven families.
 
 Covers Llama-3 (RoPE+GQA+SwiGLU), Gemma (tied embeddings, sqrt(d) embedding
 scale, GeLU gate, (1+w) RMSNorm, shared KV head), Qwen2 (QKV bias), Mixtral
 (top-k MoE MLP), OLMoE (QK-norm, 64 experts top-8, gates not renormalised)
 and GLM-4.7-Flash (latent attention and a latent cache, ``models/mla.py``;
 a leading dense layer before the sparse ones; a sigmoid router with a
-selection bias and a shared expert) via ``ModelConfig`` flags.
+selection bias and a shared expert) and Falcon-H1 (a parallel block: a
+state-space mixer beside the attention of every layer, ``models/ssm.py``,
+whose recurrent state rides the cache and the layer loop's carry next to
+the K/V lanes; fixed muP multipliers) via ``ModelConfig`` flags.
 
 TPU-first structure:
 - Parameters are stacked over layers (``[n_layers, ...]`` leaves) and the
@@ -22,7 +25,8 @@ TPU-first structure:
 - Every block sits in a ``jax.named_scope`` (embed, attn.qkv, attn.rope,
   attn.kv_update, attn.core, attn.out, mlp, moe.route / .dispatch /
   .experts / .shared, lora, lm_head, kv.insert; a latent model's attn.q_latent,
-  attn.kv_latent, attn.absorb, attn.expand): the scope is in each
+  attn.kv_latent, attn.absorb, attn.expand; a mixer's ssm.in_proj, ssm.conv,
+  ssm.scan, ssm.update, ssm.gate_norm, ssm.out_proj): the scope is in each
   compiled operation's name, so a device trace says which line of this file
   an operation belongs to.
 """
@@ -37,6 +41,7 @@ import jax.numpy as jnp
 
 from llm_instance_gateway_tpu.models import lora as lora_lib
 from llm_instance_gateway_tpu.models import mla
+from llm_instance_gateway_tpu.models import ssm
 from llm_instance_gateway_tpu.models.configs import ModelConfig
 from llm_instance_gateway_tpu.ops.attention import (
     decode_attention,
@@ -44,7 +49,12 @@ from llm_instance_gateway_tpu.ops.attention import (
     prefill_attention,
     xla_chunk_attention,
 )
-from llm_instance_gateway_tpu.ops.layers import apply_rope, rms_norm, swiglu
+from llm_instance_gateway_tpu.ops.layers import (
+    apply_rope,
+    rms_norm,
+    scaled,
+    swiglu,
+)
 from llm_instance_gateway_tpu.ops import pallas_moe
 from llm_instance_gateway_tpu.ops.quant import (
     QUANT_TARGETS,
@@ -102,7 +112,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     """
     hd = cfg.resolved_head_dim
     d, v = cfg.d_model, cfg.padded_vocab
-    keys = iter(jax.random.split(key, 32 if cfg.latent_width else 16))
+    keys = iter(jax.random.split(
+        key, 32 if cfg.latent_width or cfg.ssm_d_inner else 16))
     dtype = jnp.dtype(dtype)
 
     def rand(tree_sh, name, shape, fan_in):
@@ -122,11 +133,15 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
             "attn_norm": const(layer_sh, "attn_norm", 1, (n_l, d)),
             "mlp_norm": const(layer_sh, "mlp_norm", 1, (n_l, d)),
         }
+        def drawn(shapes) -> Params:
+            """A module's leaves: name -> (shape, fan_in; 0: ones)."""
+            return {name: (rand(layer_sh, name, (n_l, *shape), fan_in)
+                           if fan_in
+                           else const(layer_sh, name, 1, (n_l, *shape)))
+                    for name, (shape, fan_in) in shapes.items()}
+
         if cfg.latent_width:
-            for name, (shape, fan_in) in mla.leaf_shapes(cfg).items():
-                layers[name] = (
-                    rand(layer_sh, name, (n_l, *shape), fan_in) if fan_in
-                    else const(layer_sh, name, 1, (n_l, *shape)))
+            layers.update(drawn(mla.leaf_shapes(cfg)))
         else:
             layers["wq"] = rand(layer_sh, "wq", (n_l, d, cfg.n_heads * hd), d)
             layers["wk"] = rand(layer_sh, "wk",
@@ -135,6 +150,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
                                 (n_l, d, cfg.n_kv_heads * hd), d)
             layers["wo"] = rand(layer_sh, "wo", (n_l, cfg.n_heads * hd, d),
                                 cfg.n_heads * hd)
+        if cfg.ssm_d_inner:
+            layers.update(drawn(ssm.leaf_shapes(cfg)))
+            layers.update(ssm.init_vectors(cfg, next(keys), n_l))
         if cfg.attention_bias:
             # Qwen2-family Q/K/V biases (zero init; checkpoints overwrite).
             layers["wq_b"] = const(layer_sh, "wq_b", 0,
@@ -203,7 +221,10 @@ def init_decode_cache(
     JetStream serving trade); the dequantize multiply fuses into the
     attention reads, so HBM sees int8 while the MXU computes in ``dtype``.
     Scale overhead is 1/(2*head_dim) of the bf16 cache.  A latent model's
-    cache is ``mla.init_cache``: one row a position under ``k``, no ``v``."""
+    cache is ``mla.init_cache``: one row a position under ``k``, no ``v``.
+    A model with a state-space mixer also gets ``ssm`` and ``conv``
+    (``ssm.init_state``: the recurrent state of every slot, float32, and the
+    conv's history); every other model's cache has no such key."""
     if cfg.latent_width:
         if quantized:
             raise ValueError("a latent (MLA) cache has no int8 form")
@@ -216,8 +237,13 @@ def init_decode_cache(
         "length": jnp.zeros((batch,), jnp.int32),
     }
     if quantized:
+        if cfg.ssm_d_inner:
+            raise ValueError("an int8 KV cache beside a recurrent state is "
+                             "not served (untested)")
         cache["k_scale"] = jnp.zeros(shape[:-1], jnp.float32)
         cache["v_scale"] = jnp.zeros(shape[:-1], jnp.float32)
+    if cfg.ssm_d_inner:
+        cache.update(ssm.init_state(cfg, batch, dtype))
     return cache
 
 
@@ -268,7 +294,25 @@ def _attn_proj(cfg: ModelConfig, lp, target, x, layer_lora, slot_ids):
     norm = lp.get(f"{target}_norm")
     if norm is not None:
         out = rms_norm(out, norm, cfg.norm_eps)
-    return out
+    return scaled(out, cfg.key_multiplier) if target == "k" else out
+
+
+def _attn_in(cfg: ModelConfig, hn):
+    """The normed input as the attention branch takes it."""
+    return scaled(hn, cfg.attention_in_multiplier)
+
+
+def _branches(cfg: ModelConfig, attn_out, ssm_out=None):
+    """What a block adds to the residual before its MLP: the attention
+    branch (times its multiplier) and, of a parallel block, the mixer's."""
+    attn_out = scaled(attn_out, cfg.attention_out_multiplier)
+    return attn_out if ssm_out is None else attn_out + ssm_out
+
+
+def _split_carry(kv: tuple, n_rec: int) -> tuple[tuple, tuple]:
+    """The layer loop's carry as (the attention's arrays, a mixer's
+    (ssm, conv)): ``n_rec`` is 2 where the cache holds them, else 0."""
+    return kv[:len(kv) - n_rec], kv[len(kv) - n_rec:]
 
 
 @jax.named_scope("attn.out")
@@ -283,14 +327,15 @@ def _embed(cfg: ModelConfig, params: Params, tokens):
     h = params["embed"][tokens]
     if cfg.embedding_scale:
         h = h * jnp.sqrt(cfg.d_model).astype(h.dtype)
-    return h
+    return scaled(h, cfg.embedding_multiplier)
 
 
 @jax.named_scope("lm_head")
 def _lm_head(cfg: ModelConfig, params: Params, h):
     """Output head matmul (tied or separate) to f32 logits."""
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return q_matmul(h, head).astype(jnp.float32)
+    return scaled(q_matmul(h, head).astype(jnp.float32),
+                  cfg.lm_head_multiplier)
 
 
 @jax.named_scope("attn.core")
@@ -325,8 +370,10 @@ def _mlp(cfg: ModelConfig, lp: Params, x, layer_lora, slot_ids, live=None):
     with jax.named_scope("mlp"):
         gate = _project(x, lp["w_gate"], layer_lora, "gate", slot_ids)
         up = _project(x, lp["w_up"], layer_lora, "up", slot_ids)
-        return _project(swiglu(gate, up, cfg.gelu_mlp), lp["w_down"],
-                        layer_lora, "down", slot_ids), None
+        m_gate, m_down = cfg.mlp_multipliers
+        y = _project(swiglu(scaled(gate, m_gate), up, cfg.gelu_mlp),
+                     lp["w_down"], layer_lora, "down", slot_ids)
+        return scaled(y, m_down), None
 
 
 # The sparse layer's routing counts, one int32 vector a layer-step:
@@ -554,6 +601,10 @@ def prefill_layer(
 ):
     """One decoder block over a full sequence.  Returns (h, (k, v, tally)),
     ``tally`` the sparse layer's routing counts (None for a dense model).
+    A parallel block (``cfg.ssm_d_inner``) hands back, in ``v``'s place,
+    ``{"v", "ssm", "conv"}``: the values and what the mixer's recurrence
+    leaves after each row's last true position (``live``; all of them
+    without it), which ``insert_prefill`` installs together.
 
     The single source of truth for the prefill block: ``prefill`` scans it
     over the stacked layer params, and ``parallel.pipeline`` scans each
@@ -569,11 +620,15 @@ def prefill_layer(
         return _finish_block(cfg, lp, h, attn, layer_lora, slot_ids, live,
                              (k, k[..., :0]))
     hd = cfg.resolved_head_dim
-    q = _attn_proj(cfg, lp, "q", hn, layer_lora, slot_ids).reshape(b, s, cfg.n_heads, hd)
-    k = _attn_proj(cfg, lp, "k", hn, layer_lora, slot_ids).reshape(b, s, cfg.n_kv_heads, hd)
-    v = _attn_proj(cfg, lp, "v", hn, layer_lora, slot_ids).reshape(b, s, cfg.n_kv_heads, hd)
+    ha = _attn_in(cfg, hn)
+    q = _attn_proj(cfg, lp, "q", ha, layer_lora, slot_ids).reshape(b, s, cfg.n_heads, hd)
+    k = _attn_proj(cfg, lp, "k", ha, layer_lora, slot_ids).reshape(b, s, cfg.n_kv_heads, hd)
+    v = _attn_proj(cfg, lp, "v", ha, layer_lora, slot_ids).reshape(b, s, cfg.n_kv_heads, hd)
     q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
     k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+    mixed = None
+    if cfg.ssm_d_inner:
+        mixed, state, tail = ssm.prompt_mix(cfg, lp, hn, live)
     with jax.named_scope("attn.core"):
         if attention_fn is not None:
             attn = attention_fn(q, k, v, positions)
@@ -587,9 +642,13 @@ def prefill_layer(
             attn = flash_attention(q, k, v)
         else:
             attn = prefill_attention(q, k, v, positions)
-    h = h + _attn_out(lp, attn.reshape(b, s, -1), layer_lora, slot_ids)
+    h = h + _branches(
+        cfg, _attn_out(lp, attn.reshape(b, s, -1), layer_lora, slot_ids),
+        mixed)
     hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live)
+    if cfg.ssm_d_inner:
+        v = {"v": v, "ssm": state, "conv": tail}
     return h + y, (k, v, tally)
 
 
@@ -607,8 +666,12 @@ def prefill(
     """Full-prompt forward.  Returns (logits [B,S,V] f32, k [L,B,S,K,hd], v),
     and with ``moe_tally`` a sparse model's routing counts (``MOE_TALLY``,
     summed over layers) as a fourth.  A latent model's ``k`` is its latent
-    rows [L,B,S,lanes] and its ``v`` empty [L,B,S,0] (``mla``).  With ``lengths`` the padding past a
-    prompt's end routes to no expert (its outputs are garbage either way).
+    rows [L,B,S,lanes] and its ``v`` empty [L,B,S,0] (``mla``); a model
+    with a mixer returns ``{"v", "ssm" [L,B,H,N,P], "conv" [L,B,K-1,C]}``
+    (the prompt's rows; a cache lays ``conv`` [L,K-1,B,C])
+    in ``v``'s place (``prefill_layer``).  With ``lengths`` the padding past
+    a prompt's end routes to no expert (its outputs are garbage either way)
+    and leaves a mixer's state alone.
 
     ``attention_fn`` swaps the attention implementation — used by
     ``parallel.long_context`` to run ring attention over a sequence-sharded
@@ -653,22 +716,26 @@ def prefill(
 # tests/test_models.py::test_layer_scan_carries_the_cache).
 
 
+def _carry_names(cache: Params) -> tuple[str, ...]:
+    """The cache's stacked arrays that ride the layer loop, in the carry's
+    order: (k, v) [L, B, S, K, hd], then (k_scale, v_scale) [L, B, S, K]
+    of an int8 cache, or a mixer's recurrent (ssm, conv); a latent cache's
+    rows alone (they are keys and values)."""
+    if "v" not in cache:
+        return ("k",)
+    if "ssm" in cache:
+        return ("k", "v", "ssm", "conv")
+    return ("k", "v") + (("k_scale", "v_scale") if "k_scale" in cache else ())
+
+
 def _kv_carry(cache: Params) -> tuple:
-    """The stacked cache arrays that ride the layer loop: (k, v)
-    [L, B, S, K, hd], plus (k_scale, v_scale) [L, B, S, K] of an int8
-    cache."""
-    if "v" not in cache:  # a latent cache: its rows are keys and values
-        return (cache["k"],)
-    kv = (cache["k"], cache["v"])
-    if "k_scale" in cache:
-        kv += (cache["k_scale"], cache["v_scale"])
-    return kv
+    return tuple(cache[name] for name in _carry_names(cache))
 
 
 def _cache_of(cache: Params, kv: tuple, length: jax.Array, tally) -> Params:
     """The cached programs' new cache: the carry's arrays, the new lengths,
     and the routing counts if the old cache asked for them."""
-    new = dict(zip(("k", "v", "k_scale", "v_scale"), kv), length=length)
+    new = dict(zip(_carry_names(cache), kv), length=length)
     return _tallied(cache, new, tally)
 
 
@@ -789,6 +856,7 @@ def decode_step(
                     else jnp.where(active, lengths, 0))
     batch_idx = jnp.arange(b)
     s_max = cache["k"].shape[2]
+    n_rec = 2 if "ssm" in cache else 0  # a mixer's (ssm, conv) in the carry
     # Scatter address only — rope/masks keep the true positions.  s_max is
     # out of bounds, so inactive rows' updates are dropped whole.
     write_pos = (positions if active is None
@@ -804,18 +872,25 @@ def decode_step(
         return h, kv, tally
 
     def layer_fn(h, kv, layer, lp, layer_lora):
+        kv, rec = _split_carry(kv, n_rec)
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        q = _attn_proj(cfg, lp, "q", hn, layer_lora, slot_ids).reshape(b, cfg.n_heads, hd)
-        k = _attn_proj(cfg, lp, "k", hn, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
-        v = _attn_proj(cfg, lp, "v", hn, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
+        ha = _attn_in(cfg, hn)
+        q = _attn_proj(cfg, lp, "q", ha, layer_lora, slot_ids).reshape(b, cfg.n_heads, hd)
+        k = _attn_proj(cfg, lp, "k", ha, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
+        v = _attn_proj(cfg, lp, "v", ha, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
         q = apply_rope(q[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
         k = apply_rope(k[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
         kv = _write_kv(kv, (layer, batch_idx, write_pos), k, v)
         attn = _decode_attend(cfg, attention_fn, q, kv, layer, read_lengths)
-        h = h + _attn_out(lp, attn.reshape(b, -1), layer_lora, slot_ids)
+        mixed = None
+        if rec:
+            mixed, rec = ssm.decode_mix(cfg, lp, hn, rec, layer, active)
+        h = h + _branches(
+            cfg, _attn_out(lp, attn.reshape(b, -1), layer_lora, slot_ids),
+            mixed)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=active)
-        return h + y, kv, tally
+        return h + y, kv + rec, tally
 
     h, kv, tally = _scan_cached_layers(
         params, cache, lora_bufs, h,
@@ -851,6 +926,10 @@ def extend_step(
     if cfg.latent_width:
         raise NotImplementedError(
             "extend_step (speculative verify) has no latent (MLA) form")
+    if cfg.ssm_d_inner:
+        raise NotImplementedError(
+            "extend_step (speculative verify) is not served over a "
+            "recurrent state: a rejected draft would need it rolled back")
     b, c = tokens.shape
     hd = cfg.resolved_head_dim
     s_max = cache["k"].shape[2]
@@ -918,6 +997,9 @@ def prefill_with_cache(
     chunk's queries attend to EVERYTHING cached so far (previous chunks) plus
     causally within the chunk — so N chunks reproduce a monolithic prefill
     exactly (parity-tested) while compiling only one chunk-sized program.
+    A mixer's recurrent state and conv history ride the slot's lane from
+    chunk to chunk the same way (``ssm.chunk_mix``; a chunk at position 0
+    starts from zeros, whatever the lane held).
 
     A padded final chunk passes pad positions CONTINUING past the prompt
     (start+i): pads scatter into unused cells beyond ``lane_end`` (masked by
@@ -943,11 +1025,15 @@ def prefill_with_cache(
                                        slot_ids, live, (kv,))
         return h, kv, tally
 
+    n_rec = 2 if "ssm" in cache else 0  # a mixer's (ssm, conv) in the carry
+
     def layer_fn(h, kv, layer, lp, layer_lora):
+        kv, rec = _split_carry(kv, n_rec)
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        q = _attn_proj(cfg, lp, "q", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_heads, hd)
-        k = _attn_proj(cfg, lp, "k", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
-        v = _attn_proj(cfg, lp, "v", hn, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
+        ha = _attn_in(cfg, hn)
+        q = _attn_proj(cfg, lp, "q", ha, layer_lora, slot_ids).reshape(1, c, cfg.n_heads, hd)
+        k = _attn_proj(cfg, lp, "k", ha, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
+        v = _attn_proj(cfg, lp, "v", ha, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
         q = apply_rope(q, pos2d, cfg.rope_theta, cfg.rope_scaling)
         k = apply_rope(k, pos2d, cfg.rope_theta, cfg.rope_scaling)
         # Scatter the chunk's K/V into the slot's lane at absolute positions.
@@ -961,10 +1047,16 @@ def prefill_with_cache(
         # K blocks past the chunk's reach elide their DMAs — bandwidth
         # tracks the prompt's progress, not S_max (_chunk_attend).
         attn = _chunk_attend(cfg, quant, q, lane_k, lane_v, positions[0])
-        h = h + _attn_out(lp, attn, layer_lora, slot_ids)
+        mixed = None
+        if rec:
+            # The slot's lane holds what the chunks before this one left.
+            mixed, rec = ssm.chunk_mix(cfg, lp, hn, rec, layer, slot,
+                                       positions[0] == 0, live)
+        h = h + _branches(cfg, _attn_out(lp, attn, layer_lora, slot_ids),
+                          mixed)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live)
-        return h + y, kv, tally
+        return h + y, kv + rec, tally
 
     h, kv, tally = _scan_cached_layers(
         params, cache, lora_bufs, h,
@@ -994,6 +1086,21 @@ def insert_prefill(
             k, k_prompt.astype(k.dtype), (0, slot, 0, 0))
         return {"k": k, "length": cache["length"].at[slot].set(length)}
     v = cache["v"]
+    if "ssm" in cache:  # v_prompt: prefill's {"v", "ssm", "conv"}
+        # One insert installs lanes, state, conv history and length
+        # together, so a freed slot needs no clearing.
+        rec = {
+            "ssm": jax.lax.dynamic_update_slice(
+                cache["ssm"], v_prompt["ssm"].astype(cache["ssm"].dtype),
+                (0, slot, 0, 0, 0)),
+            # the prompt's [L, 1, K - 1, C] into the cache's [L, K - 1, B, C]
+            "conv": jax.lax.dynamic_update_slice(
+                cache["conv"], jnp.swapaxes(v_prompt["conv"], 1, 2).astype(
+                    cache["conv"].dtype), (0, 0, slot, 0)),
+        }
+        lanes = insert_prefill({"k": k, "v": v, "length": cache["length"]},
+                               k_prompt, v_prompt["v"], slot, length)
+        return {**lanes, **rec}
     if "k_scale" in cache:
         kq, ks = _kv_quantize(k_prompt)  # [L,1,S,K,hd] -> scales [L,1,S,K]
         vq, vs = _kv_quantize(v_prompt)

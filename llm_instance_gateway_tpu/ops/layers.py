@@ -68,6 +68,14 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
     return out.astype(x.dtype)
 
 
+def scaled(x: jax.Array, multiplier: float) -> jax.Array:
+    """``x`` times a config's fixed scalar, in ``x``'s dtype; at 1 (every
+    model but the family that publishes multipliers) nothing is traced."""
+    if multiplier == 1.0:
+        return x
+    return x * jnp.asarray(multiplier, x.dtype)
+
+
 def swiglu(gate: jax.Array, up: jax.Array, gelu: bool = False) -> jax.Array:
     """Gated MLP activation: SiLU (Llama/Mixtral) or tanh-GeLU (Gemma)."""
     act = jax.nn.gelu(gate, approximate=True) if gelu else jax.nn.silu(gate)

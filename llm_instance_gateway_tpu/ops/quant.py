@@ -29,7 +29,9 @@ import jax.numpy as jnp
 QUANT_TARGETS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                  # a latent (MLA) layer's projections, a shared expert's
                  "wq_down", "wq_up", "wkv_down", "wkv_up",
-                 "ws_gate", "ws_up", "ws_down")
+                 "ws_gate", "ws_up", "ws_down",
+                 # a state-space mixer's two projections (models/ssm.py)
+                 "ssm_in", "ssm_out")
 
 
 def quantize_weight(w: jax.Array) -> dict[str, jax.Array]:

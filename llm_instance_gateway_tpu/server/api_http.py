@@ -1539,7 +1539,9 @@ class ModelServer:
                 "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                 "qk_rope_head_dim", "v_head_dim", "moe_d_ff",
                 "n_shared_experts", "first_k_dense", "router_sigmoid",
-                "routed_scaling_factor")
+                "routed_scaling_factor", "ssm_d_inner", "ssm_n_heads",
+                "ssm_head_dim", "ssm_d_state", "ssm_n_groups", "ssm_d_conv",
+                "ssm_chunk")
         return web.json_response({
             "model": self.model_name,
             "platform": devices[0].platform,
@@ -1751,6 +1753,11 @@ def main(argv=None) -> None:
             f"{args.model} keeps a latent (MLA) KV cache: adapters are not "
             "served over latent projections (models/lora.py sizes its "
             "targets from per-head q, k, v); start it with --max-loras 0")
+    if cfg.ssm_d_inner and (args.max_loras > 0 or args.mesh):
+        raise SystemExit(
+            f"{args.model} keeps a recurrent (state-space) state beside its "
+            "KV lanes: it is served on one device, base model only; start "
+            "it with --max-loras 0 and without --mesh")
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
 
     tokenizer = load_tokenizer(args.tokenizer)

@@ -554,11 +554,26 @@ class _ChunkStream:
     last_logits: object = None
 
 
-def _refuse_for_latent(model_cfg, cfg: "EngineConfig", lora_manager,
-                       mesh) -> None:
-    """A latent (MLA) cache is served from contiguous lanes on one device,
-    base model only.  Every other way to hold or move KV assumes per-head
-    K and V arrays; each is refused here by name rather than run wrong."""
+def _refuse_what_lanes_alone_serve(model_cfg, cfg: "EngineConfig",
+                                   lora_manager, mesh) -> None:
+    """A latent (MLA) cache, and a recurrent state beside the K/V lanes (a
+    state-space mixer), are served from contiguous lanes on one device,
+    base model only.  Every other way to hold or move a row's state assumes
+    that it is per-head K and V by position and nothing else: what a
+    rebuilt, shared, shipped or rolled-back row would need of a state that
+    is no function of positions is not there.  Each is refused here by name
+    rather than run wrong."""
+    if model_cfg.latent_width:
+        kind = "keeps a latent (MLA) KV cache"
+        loras = ("--max-loras above 0 (models/lora.py sizes its targets "
+                 "from per-head q, k, v)")
+        extra = {}
+    else:
+        kind = "keeps a recurrent (state-space) state beside its KV lanes"
+        loras = ("--max-loras above 0 (an adapter's delta beside the "
+                 "model's fixed multipliers is undefined here)")
+        extra = {"--prefill-batch above 1 (a grouped prefill's rows are "
+                 "cut out of K and V alone)": cfg.prefill_batch > 1}
     asked = {
         "--paged-kv-block (models/paged.py; the prefix cache needs it)":
             cfg.paged_kv_block is not None or cfg.prefix_cache,
@@ -567,14 +582,13 @@ def _refuse_for_latent(model_cfg, cfg: "EngineConfig", lora_manager,
         "--role prefill/decode (server/kv_transfer.py)":
             cfg.role != "collocated",
         "--mesh": mesh is not None and mesh.size > 1,
-        "--max-loras above 0 (models/lora.py sizes its targets from "
-        "per-head q, k, v)": lora_manager is not None,
+        loras: lora_manager is not None,
+        **extra,
     }
     for what, on in asked.items():
         if on:
             raise ValueError(
-                f"{model_cfg.name} keeps a latent (MLA) KV cache, which "
-                f"{what} does not serve yet")
+                f"{model_cfg.name} {kind}, which {what} does not serve yet")
 
 
 class Engine:
@@ -614,8 +628,10 @@ class Engine:
         self.paged = self.cfg.paged_kv_block is not None
         self._kv_quant = self.cfg.kv_cache_quant is not None
         self._latent = bool(model_cfg.latent_width)
-        if self._latent:
-            _refuse_for_latent(model_cfg, self.cfg, lora_manager, mesh)
+        self._recurrent = bool(model_cfg.ssm_d_inner)
+        if self._latent or self._recurrent:
+            _refuse_what_lanes_alone_serve(
+                model_cfg, self.cfg, lora_manager, mesh)
         if self.cfg.kv_cache_quant not in (None, "int8"):
             raise ValueError(
                 f"kv_cache_quant={self.cfg.kv_cache_quant!r}: only 'int8' "
@@ -1342,6 +1358,11 @@ class Engine:
         self.profiler.note_stage_ops(STAGE_UPLOADS)
         self.profiler.note_lora_rows(
             n_steps * int(np.count_nonzero(self._slot_lora >= 0)))
+        if self._recurrent:
+            # Every step of the block rewrites the state of every row the
+            # host holds (a row that stops mid-block counts on to its end).
+            self.profiler.note_ssm_rows(
+                n_steps * sum(s is not None for s in self.slots))
         if self._latent:
             # Step j of the block reads position + 1 + j rows of a live
             # row's lane.  With a block still unread (the overlapped loop)
@@ -1529,6 +1550,7 @@ class Engine:
         write into THIS engine's cache, which is exactly what a handoff
         exists to avoid.
         """
+        self._refuse_handoff()
         n = len(request.prompt_tokens)
         if self._max_bucket() <= 0 or n > self._max_bucket():
             raise ValueError(
@@ -1546,6 +1568,15 @@ class Engine:
             raise RuntimeError(request.error)
         return request.handoff
 
+    def _refuse_handoff(self) -> None:
+        """Every engine keeps the handoff API in every role; one whose rows
+        hold more than per-head K and V by position cannot ship them."""
+        if self._latent or self._recurrent:
+            raise ValueError(
+                f"{self.model_cfg.name}: the handoff wire "
+                "(server/kv_transfer.py) ships per-head K and V only, not a "
+                "latent cache or a recurrent state")
+
     def attach_prefilled(self, handoff) -> Request:
         """Admit a ``PrefillHandoff`` straight into decode (hop 2): the KV
         imports into this engine's cache and the request decodes from its
@@ -1555,6 +1586,7 @@ class Engine:
         """
         from llm_instance_gateway_tpu.server import kv_transfer
 
+        self._refuse_handoff()
         request = kv_transfer.make_request(handoff)
         if self._draining:
             raise EngineDraining("engine is draining (graceful termination)")

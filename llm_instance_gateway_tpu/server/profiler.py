@@ -167,6 +167,10 @@ class StepProfiler:
         # rows' cache lengths, summed over the steps of the plain decode
         # dispatches.  0 for a model with per-head K/V lanes.
         self.latent_positions = 0
+        # Rows whose recurrent (state-space) state a decode step rewrote,
+        # summed over the steps of the plain decode dispatches.  0 for a
+        # model without a mixer.
+        self.ssm_rows = 0
         # Decode blocks dispatched while an earlier block was still unread:
         # the device then had its next step queued before the host read
         # the last.  Over the decode and spec dispatches: the share of
@@ -421,6 +425,12 @@ class StepProfiler:
         with self._lock:
             self.latent_positions += n
 
+    def note_ssm_rows(self, n: int) -> None:
+        """Count ``n`` rows whose recurrent state the steps of one plain
+        decode dispatch rewrote (live rows x the block's steps)."""
+        with self._lock:
+            self.ssm_rows += n
+
     def note_overlapped_block(self) -> None:
         """Count one decode block dispatched while an earlier block was
         still unread."""
@@ -441,6 +451,7 @@ class StepProfiler:
                 "stage_ops": self.stage_ops,
                 "lora_rows": self.lora_rows,
                 "latent_positions": self.latent_positions,
+                "ssm_rows": self.ssm_rows,
                 "blocks_overlapped": self.blocks_overlapped,
             }
         out["phases"] = self.phase_seconds()
@@ -521,6 +532,9 @@ def render_profile(hist: dict) -> list[str]:
         lines += ["# TYPE tpu:latent_kv_positions_total counter",
                   "tpu:latent_kv_positions_total "
                   f"{hist['latent_positions']}"]
+    if "ssm_rows" in hist:
+        lines += ["# TYPE tpu:ssm_state_rows_total counter",
+                  f"tpu:ssm_state_rows_total {hist['ssm_rows']}"]
     if "blocks_overlapped" in hist:
         lines += ["# TYPE tpu:decode_blocks_overlapped_total counter",
                   "tpu:decode_blocks_overlapped_total "

@@ -534,3 +534,38 @@ def test_kernel_compiles_for_the_v5e_at_published_widths(one_chip):
         compilation_cache.reset_cache()
     text = compiled.as_text()
     assert "mla_decode_attention" in text and "tpu_custom_call" in text
+
+
+def test_ssm_update_kernel_compiles_for_the_v5e_at_published_widths(one_chip):
+    """Falcon-H1-34B's state, 64 slots x 8 layers of 32 x 256 x 128 float32
+    (2 GiB), through ``ssm_decode_update`` as the cell runs it (here, beside
+    the other compile for the chip, because one process may describe the
+    chip and this file is the one that does): the chip's compiler takes the
+    kernel, and the state goes in and comes out as ONE buffer."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from llm_instance_gateway_tpu.ops import pallas_ssm
+
+    sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    fn = jax.jit(
+        lambda state, x, dt, a, bm, cm, d, live, layer:
+        pallas_ssm.ssm_decode_update_pallas(state, x, dt, a, bm, cm, d, live,
+                                            layer),
+        donate_argnums=(0,))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = fn.lower(
+            sd((8, 64, 32, 256, 128)), sd((64, 32, 128)), sd((64, 32)),
+            sd((32,)), sd((64, 2, 256)), sd((64, 2, 256)), sd((32,)),
+            sd((64,), jnp.bool_), sd((), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "ssm_decode_update" in text and "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
+    state_bytes = 8 * 64 * 32 * 256 * 128 * 4
+    assert mem.alias_size_in_bytes >= state_bytes  # aliased, not copied
+    assert mem.temp_size_in_bytes < state_bytes // 100

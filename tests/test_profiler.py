@@ -944,6 +944,7 @@ class TestXplaneGaps:
         used = set()
         for rel in ("llm_instance_gateway_tpu/models/transformer.py",
                     "llm_instance_gateway_tpu/models/mla.py",
+                    "llm_instance_gateway_tpu/models/ssm.py",
                     "llm_instance_gateway_tpu/models/paged.py",
                     "llm_instance_gateway_tpu/models/lora.py",
                     "llm_instance_gateway_tpu/ops/layers.py",

@@ -12,7 +12,10 @@ missed parity.  Needs a TPU (fails on any other backend).  Timing is not
 this tool's job, speed comes from the benchmark's device trace, with one
 exception: the ``live-rows`` cases also print what the lane decode kernel
 costs a layer at 32 slots when 5 or all 32 of them decode (host clock
-round one program of 400 calls, as a model's layer loop makes them).
+round one program of 400 calls, as a model's layer loop makes them), and
+the ``ssm-update`` cases what the state-space decode update costs a layer at
+64 slots of Falcon-H1-34B's state (32 x 256 x 128 float32) with 64, 8 and 1
+of them live, beside the time its bytes would take at the chip's bandwidth.
 
     python tools/onchip_pallas_check.py            # on the chip
 """
@@ -40,6 +43,7 @@ from llm_instance_gateway_tpu.ops import attention as xla_att
 from llm_instance_gateway_tpu.ops import pallas_attention as flash
 from llm_instance_gateway_tpu.ops import pallas_decode_attention as pdec
 from llm_instance_gateway_tpu.ops import pallas_moe as pmoe
+from llm_instance_gateway_tpu.ops import pallas_ssm as pssm
 
 # Parity bound, per element: |kernel - reference| <= TOL * max(1, |reference|).
 # Both sides accumulate in f32 and round the output once to bf16 (half an
@@ -256,8 +260,77 @@ def case_moe(e, k, n, m, quant):
     return out * live, ref * live, TOL_BF16
 
 
+def case_ssm_update(n_live, b=64, h=32, g=2, n=256, p=128, n_layers=8,
+                    calls=160):
+    """``ssm_decode_update`` as a decode step of ``b`` slots runs it at the
+    published shape: ``n_live`` rows spread over the slots, the others
+    sitting out.  Parity with the ``jax.numpy`` update in float32 on the
+    live rows' y and on the whole state array (rows that sit out and the
+    other layers unchanged bit for bit), and the time a layer of one program
+    that walks the stacked, donated state ``calls`` times."""
+    ks = _keys(7, 7)
+    f32 = jnp.float32
+    state = jax.random.normal(ks[0], (n_layers, b, h, n, p), f32)
+    x = jax.random.normal(ks[1], (b, h, p), f32)
+    dt = jnp.exp(jax.random.uniform(ks[2], (b, h), f32, -7.0, -2.0))
+    a = -jax.random.uniform(ks[3], (h,), f32, 1.0, 16.0)
+    bm = jax.random.normal(ks[4], (b, g, n), f32)
+    cm = jax.random.normal(ks[5], (b, g, n), f32)
+    d = jax.random.normal(ks[6], (h,), f32)
+    mask = np.zeros(b, bool)
+    mask[np.linspace(0, b - 1, n_live).astype(int)] = True
+    live = jnp.asarray(mask)
+
+    want_y, want = jax.jit(pssm.ssm_update_xla)(state[1], x, dt, a, bm, cm,
+                                                d, live)
+    y, new = jax.jit(lambda s: pssm.ssm_decode_update_pallas(
+        s, x, dt, a, bm, cm, d, live, jnp.int32(1)))(state)
+    untouched = bool(jnp.all(new[0] == state[0])) and bool(jnp.all(
+        jnp.where(live[:, None, None, None], True, new[1] == state[1])))
+    state_err = _scaled_err(new[1], want)
+    del new, want
+
+    def loop(state, x):
+        def body(carry, layer):
+            state, acc = carry
+            y, state = pssm.ssm_decode_update_pallas(
+                state, x + acc, dt, a, bm, cm, d, live, layer)
+            return (state, y * 1e-6), None
+        layers = jnp.arange(calls, dtype=jnp.int32) % n_layers
+        (state, acc), _ = jax.lax.scan(body, (state, jnp.zeros_like(x)),
+                                       layers)
+        return state, acc
+
+    loop = jax.jit(loop, donate_argnums=(0,))
+    state, acc = loop(state, x)
+    acc.block_until_ready()
+    times = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        state, acc = loop(state, x)
+        acc.block_until_ready()
+        times.append((time.perf_counter() - t0) / calls * 1e6)
+    at_bandwidth = n_live * 2 * h * n * p * 4 / 819e9 * 1e6
+    print(f"TIME   ssm-update {n_live}/{b} live rows h={h} n={n} p={p}: "
+          f"{min(times):.1f} us a layer (median {sorted(times)[2]:.1f}, "
+          f"{calls} calls a program; the live states both ways at 819 GB/s: "
+          f"{at_bandwidth:.1f} us); other rows and layers untouched: "
+          f"{untouched}", flush=True)
+    if not untouched:
+        raise AssertionError("a row that sits out, or another layer, moved")
+    if state_err > 1e-5:
+        raise AssertionError(f"new state off by {state_err}")
+    # float32 both sides; the kernel sums a head's 256 products in another
+    # order than the reference
+    return y, want_y, 1e-4
+
+
 def cases():
     """(name, gate reasons, thunk) for every kernel x layout x shape."""
+    for n_live in (64, 8, 1):
+        yield (f"ssm-update {n_live}/64 live [falcon-h1-34b 32x256x128]",
+               pssm.shape_reasons(32, 2, 256, 128),
+               lambda n_live=n_live: case_ssm_update(n_live))
     for label, e, k, n, m in MOE_SHAPES:
         for quant in (False, True):
             yield (f"moe-gmm-{'int8' if quant else 'bf16'} [{label}]",

@@ -195,6 +195,14 @@ def latent_positions_row(profile: dict) -> dict:
                                 "positions_per_dispatch")
 
 
+def ssm_rows_row(profile: dict) -> dict:
+    """Rows whose recurrent (state-space) state the decode steps rewrote
+    (``tpu:ssm_state_rows_total``); empty for a model without a mixer."""
+    if not (profile.get("hist") or {}).get("ssm_rows"):
+        return {}
+    return _per_decode_dispatch(profile, "ssm_rows", "rows_per_dispatch")
+
+
 def overlap_row(profile: dict) -> dict:
     """Decode blocks dispatched while an earlier block was still unread
     (``tpu:decode_blocks_overlapped_total``) and their share of the decode
@@ -217,13 +225,15 @@ def overlap_row(profile: dict) -> dict:
 ANNOTATION_PREFIX = "engine."
 NO_ANNOTATION = "other"  # the bottom of the phase stack is not annotated
 # The jax.named_scope names the model code uses (models/transformer.py,
-# models/mla.py, models/paged.py, models/lora.py, server/sampling.py, server/engine.py).
+# models/mla.py, models/ssm.py, models/paged.py, models/lora.py,
+# server/sampling.py, server/engine.py).
 SCOPES = frozenset((
     "embed", "attn.qkv", "attn.rope", "attn.kv_update", "attn.core",
     "attn.out", "attn.q_latent", "attn.kv_latent", "attn.absorb",
-    "attn.expand", "mlp", "moe.route", "moe.dispatch", "moe.experts",
-    "moe.shared", "lora", "lm_head", "sample", "sample.topk_sort",
-    "logprobs", "stops", "kv.insert"))
+    "attn.expand", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.update",
+    "ssm.gate_norm", "ssm.out_proj", "mlp", "moe.route", "moe.dispatch",
+    "moe.experts", "moe.shared", "lora", "lm_head", "sample",
+    "sample.topk_sort", "logprobs", "stops", "kv.insert"))
 
 
 def union(intervals: list) -> list:
@@ -664,6 +674,11 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
         out += ["", "Latent cache rows read by the decode steps:",
                 _table([latent], ("latent_positions", "decode_dispatches",
                                   "positions_per_dispatch"))]
+    recurrent = ssm_rows_row(profile)
+    if recurrent:
+        out += ["", "Recurrent states rewritten by the decode steps:",
+                _table([recurrent], ("ssm_rows", "decode_dispatches",
+                                     "rows_per_dispatch"))]
     delta = host_sync_delta(profile, previous)
     if delta:
         out += ["", "Host-sync share vs previous baseline: "
