@@ -393,6 +393,11 @@ SERVER_FAMILIES = (
            "Experts with at least one live assignment, summed over "
            "layer-steps: over tpu:moe_layer_steps_total, the experts a "
            "layer reads.", SERVER_SURFACE),
+    Family("tpu:moe_tiles_used_total", "counter", (),
+           "Row tiles of the expert dispatch that hold a group, summed over "
+           "layer-steps: over tpu:moe_experts_touched_total, the tiles a "
+           "touched expert's group takes (1.0: every group in one tile).",
+           SERVER_SURFACE),
     Family("tpu:sample_steps_total", "counter", ("path",),
            "Decode steps by the sampler's path, as the device took it: "
            "argmax (no live row samples: no sort, filter or draw) | draw "

@@ -377,9 +377,10 @@ def _mlp(cfg: ModelConfig, lp: Params, x, layer_lora, slot_ids, live=None):
 
 
 # The sparse layer's routing counts, one int32 vector a layer-step:
-# (layer-steps, live assignments, experts with at least one).  They sum over
-# layers and steps; the engine reads them back with the step.
-MOE_TALLY = ("layer_steps", "assignments", "experts_touched")
+# (layer-steps, live assignments, experts with at least one, row tiles that
+# hold a group: over the experts touched, the tiles a group takes).  They sum
+# over layers and steps; the engine reads them back with the step.
+MOE_TALLY = ("layer_steps", "assignments", "experts_touched", "tiles_used")
 _EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
 
@@ -462,7 +463,7 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x, live=None):
         x_e = jnp.zeros((n_rows, d), xf.dtype).at[row].set(
             jnp.repeat(xf, k, axis=0), mode="drop")
         tally = jnp.stack([jnp.ones((), jnp.int32), jnp.sum(sizes),
-                           jnp.sum(sizes > 0)])
+                           jnp.sum(sizes > 0), n_used])
 
     with jax.named_scope("moe.experts"):
         gmm = functools.partial(

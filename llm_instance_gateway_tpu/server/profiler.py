@@ -69,7 +69,7 @@ DISPATCH_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3,
                     5e-3, 1e-2, 2.5e-2, 5e-2, 0.1, 0.25, 1.0)
 
 # transformer.MOE_TALLY, restated so that this module imports no JAX.
-MOE_COUNTERS = ("layer_steps", "assignments", "experts_touched")
+MOE_COUNTERS = ("layer_steps", "assignments", "experts_touched", "tiles_used")
 GAP_HOST = "host"
 GAP_IDLE = "idle"
 
@@ -514,7 +514,8 @@ def render_profile(hist: dict) -> list[str]:
         for family, name in (
                 ("tpu:moe_layer_steps_total", "layer_steps"),
                 ("tpu:moe_assignments_total", "assignments"),
-                ("tpu:moe_experts_touched_total", "experts_touched")):
+                ("tpu:moe_experts_touched_total", "experts_touched"),
+                ("tpu:moe_tiles_used_total", "tiles_used")):
             lines += [f"# TYPE {family} counter", f"{family} {moe[name]}"]
     sample_steps = hist.get("sample_steps")
     if sample_steps:

@@ -96,6 +96,9 @@ def test_routing_counters_come_back_with_the_readback(name, pipelined):
     assert moe["assignments"] <= (23 + steps) * k * layers
     assert k * moe["layer_steps"] <= moe["experts_touched"] <= min(
         cfg.n_experts * moe["layer_steps"], moe["assignments"])
+    # every touched expert's group holds a tile or more, an assignment at most
+    # one of its own
+    assert moe["experts_touched"] <= moe["tiles_used"] <= moe["assignments"]
 
 
 def test_gemma_with_lora_multiplexing():

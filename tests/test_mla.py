@@ -569,3 +569,45 @@ def test_ssm_update_kernel_compiles_for_the_v5e_at_published_widths(one_chip):
     state_bytes = 8 * 64 * 32 * 256 * 128 * 4
     assert mem.alias_size_in_bytes >= state_bytes  # aliased, not copied
     assert mem.temp_size_in_bytes < state_bytes // 100
+
+
+@pytest.mark.parametrize("k,n,assignments,by_group", [
+    (4096, 14336, 64, True),     # gate/up of a decode step: 32 rows x top-2
+    (14336, 4096, 64, True),     # down of the same
+    (4096, 14336, 1024, True),   # gate/up of a 512-token prompt: the most
+                                 # rows the group order holds in VMEM
+    (14336, 4096, 2048, False),  # down of a 1,024-token prompt: by tile
+])
+def test_expert_matmul_compiles_for_the_v5e_at_mixtrals_widths(
+        one_chip, k, n, assignments, by_group):
+    """``moe_gmm_int8`` over a stack of 6 layers of Mixtral-8x7B's 8 experts
+    (here for the reason above): the chip's compiler takes the kernel in both
+    of its orders, with all rows' x, output block and accumulators in VMEM
+    beside the double-buffered weight block."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from llm_instance_gateway_tpu.ops import pallas_moe
+
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    tm = pallas_moe.tile_rows(assignments, 8)
+    tiles = pallas_moe.n_tiles(assignments, 8, tm)
+    tk, tn = pallas_moe._blocks(k, n, 1)
+    assert pallas_moe._by_group(tiles * tm, k, n, tn, 1) == by_group
+    fn = jax.jit(lambda x, w, te, used, layer: pallas_moe.grouped_matmul_pallas(
+        x, w, te, used, layer, tm=tm))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = fn.lower(
+            sd((tiles * tm, k), jnp.bfloat16),
+            {"q": sd((6, 8, k, n), jnp.int8), "s": sd((6, 8, n), jnp.float32)},
+            sd((tiles,), jnp.int32), sd((), jnp.int32),
+            sd((), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "moe_gmm_int8" in text and "tpu_custom_call" in text
+    # the stack is read where it lies: no layer's experts are copied out
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

@@ -8,8 +8,10 @@ Contracts:
   empty slots, and a single token;
 - both gate rules (Mixtral renormalises over the chosen, OLMoE does not);
 - int8 stacks; per-layer leaves and the stacked leaves + layer index;
-- the Pallas grouped matmul (interpret mode) equals the XLA tiles;
-- the routing tally counts what was routed;
+- the Pallas grouped matmul (interpret mode) equals the XLA tiles, by group
+  and by tile, with a column in one K block and in several;
+- by group a touched expert's weight block is fetched once a call;
+- the routing tally counts what was routed, and the tiles it took;
 - compiled FLOPs track the assignments made, not experts x tokens.
 """
 
@@ -97,7 +99,7 @@ class TestDroplessMatchesReference:
         none, tally0 = transformer._moe_mlp(
             cfg, layer0(params), x, jnp.zeros((8,), bool))
         assert float(jnp.max(jnp.abs(none))) == 0.0
-        assert [int(v) for v in tally0] == [1, 0, 0]
+        assert [int(v) for v in tally0] == [1, 0, 0, 0]
 
     def test_stacked_leaves_and_layer_index(self, model):
         cfg, params = model
@@ -140,28 +142,174 @@ def test_tally_counts_assignments_and_touched_experts():
     _, tally = transformer._moe_mlp(cfg, lp, x)
     _, topi = jax.lax.top_k(x @ lp["router"], cfg.n_experts_per_token)
     counts = np.bincount(np.asarray(topi).ravel(), minlength=cfg.n_experts)
-    assert [int(v) for v in tally] == [1, 20 * 8, int((counts > 0).sum())]
+    tm = pallas_moe.tile_rows(20 * 8, cfg.n_experts)
+    assert [int(v) for v in tally] == [
+        1, 20 * 8, int((counts > 0).sum()), int((-(-counts // tm)).sum())]
 
 
-@pytest.mark.parametrize("quant", [False, True])
-@pytest.mark.parametrize("k,n", [(128, 256), (256, 128)])
-def test_pallas_grouped_matmul_matches_xla_tiles(k, n, quant):
-    """The kernel in interpret mode against the XLA form of the same tiles,
-    over a stack of two layers, with tiles past the last group."""
-    e, tm, n_tiles, n_used = 5, 16, 9, 6
-    key = jax.random.PRNGKey(0)
-    w = jax.random.normal(key, (2, e, k, n), jnp.float32) / np.sqrt(k)
+def test_tally_counts_the_tiles_of_a_group_that_outgrows_one():
+    """Every token's first choice forced onto expert 0: its group of 48
+    rows takes more tiles than one, and the fourth count says how many."""
+    cfg = TINY_OLMOE_TEST
+    params = transformer.init_params(cfg, jax.random.PRNGKey(3),
+                                     dtype=jnp.float32)
+    x = jnp.abs(rows(cfg, 48)) + 0.1
+    lp = layer0(params)
+    lp["router"] = lp["router"].at[:, 0].set(5.0)
+    _, tally = transformer._moe_mlp(cfg, lp, x)
+    _, topi = jax.lax.top_k(x @ lp["router"], cfg.n_experts_per_token)
+    counts = np.bincount(np.asarray(topi).ravel(), minlength=cfg.n_experts)
+    tm = pallas_moe.tile_rows(48 * 8, cfg.n_experts)
+    assert counts[0] == 48 > tm
+    touched, tiles = int(tally[2]), int(tally[3])
+    assert tiles == int((-(-counts // tm)).sum()) > touched
+
+
+# Groups of 33, 0, 1, 17 and 5 rows in tiles of 16: three consecutive tiles
+# of expert 0, an expert nobody chose, a group of one row, two tiles of
+# expert 3, the expert changing between tiles, and two tiles past the last
+# group.
+SKEWED_SIZES, SKEWED_TM, SKEWED_TILES = (33, 0, 1, 17, 5), 16, 9
+
+
+def skewed_plan(k, seed=1):
+    """(x laid out by group with zeroed padding rows, tile_expert, n_used)."""
+    sizes = jnp.asarray(SKEWED_SIZES, jnp.int32)
+    first_row, te, n_used = pallas_moe.tile_plan(sizes, SKEWED_TM,
+                                                 SKEWED_TILES)
+    assert [int(v) for v in te] == [0, 0, 0, 2, 3, 3, 4, 4, 4]
+    assert int(n_used) == 7
+    row = np.arange(SKEWED_TILES * SKEWED_TM)
+    held = np.zeros(row.shape, bool)
+    for start, size in zip(np.asarray(first_row), SKEWED_SIZES):
+        held |= (row >= start) & (row < start + size)
+    x = jax.random.normal(jax.random.PRNGKey(seed), (row.size, k))
+    return x * held[:, None], te, n_used
+
+
+def cut_k_in(monkeypatch, k, n, itemsize, nk, by_group):
+    """A block budget under which a column of K rows is cut into ``nk``
+    blocks, and the order the kernel then walks the rows in."""
+    tn = next(c for c in (1024, 512, 256, 128) if n % c == 0)
+    monkeypatch.setattr(pallas_moe, "_W_BLOCK_BYTES", k // nk * tn * itemsize)
+    assert pallas_moe._blocks(k, n, itemsize) == (k // nk, tn)
+    monkeypatch.setattr(pallas_moe, "_by_group", lambda *shape: by_group)
+
+
+def stack_of_two(k, n, quant, e=len(SKEWED_SIZES)):
+    w = jax.random.normal(jax.random.PRNGKey(0), (2, e, k, n),
+                          jnp.float32) / np.sqrt(k)
     if quant:
         from llm_instance_gateway_tpu.ops.quant import quantize_weight
         w = quantize_weight(w)
-    x = jax.random.normal(jax.random.PRNGKey(1), (n_tiles * tm, k))
-    te = jnp.array([0, 0, 1, 3, 4, 4, 4, 4, 4], jnp.int32)
-    got = pallas_moe.grouped_matmul_pallas(x, w, te, n_used, 1, tm=tm,
-                                           interpret=True)
-    want = pallas_moe.grouped_matmul_xla(x, w, te, 1, tm=tm)
-    np.testing.assert_allclose(got[: n_used * tm], want[: n_used * tm],
-                               rtol=1e-5, atol=1e-5)
-    assert float(jnp.max(jnp.abs(got[n_used * tm:]))) == 0.0
+    return w
+
+
+@pytest.mark.parametrize("quant", [False, True])
+@pytest.mark.parametrize("k,n,nk,by_group", [
+    (128, 256, 1, True), (256, 128, 1, True), (512, 256, 1, False),
+    (512, 256, 2, True), (512, 256, 4, True),
+    (512, 256, 2, False), (512, 256, 4, False)])
+def test_pallas_grouped_matmul_matches_xla_tiles(k, n, nk, by_group, quant,
+                                                 monkeypatch):
+    """The kernel in interpret mode against the XLA form of the same tiles,
+    over layer 1 of a stack of two, a column in one K block and cut in two
+    and in four, by group and by tile, with the skewed plan above: tiles
+    past the last group are exact zeros."""
+    cut_k_in(monkeypatch, k, n, 1 if quant else 4, nk, by_group)
+    w = stack_of_two(k, n, quant)
+    x, te, n_used = skewed_plan(k)
+    got = pallas_moe.grouped_matmul_pallas(x, w, te, n_used, 1,
+                                           tm=SKEWED_TM, interpret=True)
+    want = pallas_moe.grouped_matmul_xla(x, w, te, 1, tm=SKEWED_TM)
+    used = int(n_used) * SKEWED_TM
+    np.testing.assert_allclose(got[:used], want[:used], rtol=1e-5, atol=1e-5)
+    assert float(jnp.max(jnp.abs(got[used:]))) == 0.0
+    other = pallas_moe.grouped_matmul_pallas(x, w, te, n_used, 0,
+                                             tm=SKEWED_TM, interpret=True)
+    assert float(jnp.max(jnp.abs(other[:used] - got[:used]))) > 0.1
+
+
+@pytest.mark.parametrize("quant", [False, True])
+@pytest.mark.parametrize("nk", [1, 2, 4])
+def test_both_orders_add_over_k_alike(nk, quant, monkeypatch):
+    """By group and by tile accumulate the same K blocks in the same order
+    into f32: the same bits, whatever the plan; and nothing used is nothing
+    written."""
+    k, n = 512, 256
+    w = stack_of_two(k, n, quant)
+    x, te, n_used = skewed_plan(k, seed=2)
+    outs = []
+    for by_group in (True, False):
+        with monkeypatch.context() as m:
+            cut_k_in(m, k, n, 1 if quant else 4, nk, by_group)
+            outs.append([pallas_moe.grouped_matmul_pallas(
+                x, w, te, used, 1, tm=SKEWED_TM, interpret=True)
+                for used in (n_used, 0)])
+    assert bool(jnp.array_equal(outs[0][0], outs[1][0]))
+    assert float(jnp.max(jnp.abs(outs[0][0]))) > 0.1
+    for out in (outs[0][1], outs[1][1]):
+        assert float(jnp.max(jnp.abs(out))) == 0.0
+
+
+def weight_fetches(k, n, itemsize, te, n_used, n_experts, tm):
+    """Walk the kernel's grid in its order through the weight operand's
+    index map and count the steps whose block index differs from the step
+    before's: each is one DMA of a [tk, tn] block."""
+    tk, tn = pallas_moe._blocks(k, n, itemsize)
+    by_group = pallas_moe._by_group(te.shape[0] * tm, k, n, tn, itemsize)
+    steps = pallas_moe._steps(te, n_used, n_experts, by_group)
+    w_index = pallas_moe._index_maps(k // tk, by_group)["w"]
+    grid = pallas_moe._grid(n, tn, k // tk, te.shape[0], n_experts, by_group)
+    layer = jnp.ones((1,), jnp.int32)
+    fetches, before = 0, None
+    for j, a, b in np.ndindex(*grid):
+        block = tuple(int(v) for v in w_index(j, a, b, *steps, layer))
+        fetches += block != before
+        before = block
+    return fetches
+
+
+@pytest.mark.parametrize("nk,by_group", [(1, True), (2, True), (4, True),
+                                         (1, False), (2, False), (4, False)])
+def test_a_touched_experts_block_is_fetched_once_a_call(nk, by_group,
+                                                        monkeypatch):
+    """The invariant of PR 44, without a chip: by group the weight
+    operand's block index changes touched experts x N/tn x nk times a call
+    whatever the tiles a group takes; by tile, once K is cut, used tiles x
+    N/tn x nk times: every tile of a group fetches the column again."""
+    k, n = 512, 768  # three column blocks of 256
+    cut_k_in(monkeypatch, k, n, 1, nk, by_group)
+    _, te, n_used = skewed_plan(k)
+    touched = sum(size > 0 for size in SKEWED_SIZES)
+    assert (touched, int(n_used)) == (4, 7)
+    got = weight_fetches(k, n, 1, te, n_used, len(SKEWED_SIZES), SKEWED_TM)
+    tiles_fetch = int(n_used) if nk > 1 and not by_group else touched
+    assert got == tiles_fetch * 3 * nk
+
+
+@pytest.mark.parametrize("e,k,n,assignments,by_group", [
+    (8, 4096, 14336, 64, True),      # Mixtral gate/up, a decode step
+    (8, 14336, 4096, 64, True),      # ... down
+    (8, 4096, 14336, 1024, True),    # ... gate/up, a 512-token prompt
+    (8, 14336, 4096, 512, False),    # ... down, 256 tokens: x is 40 MB
+    (8, 4096, 14336, 2048, False),   # ... 1,024 tokens: 2,944 rows
+    (64, 2048, 1024, 256, False),    # OLMoE gate/up, a decode step
+    (64, 1024, 2048, 256, False),    # ... down
+    (64, 2048, 1536, 128, False),    # GLM-4.7-Flash gate/up
+    (64, 1536, 2048, 128, False),    # ... down
+])
+def test_the_grid_walks_groups_where_experts_are_wide(e, k, n, assignments,
+                                                      by_group):
+    """The order comes from the shapes: by group where x whole is at most
+    half of one expert's int8 matrix and all rows fit VMEM; the many narrow
+    experts of OLMoE and GLM keep the order by tile, block sizes and all."""
+    tm = pallas_moe.tile_rows(assignments, e)
+    rows = pallas_moe.n_tiles(assignments, e, tm) * tm
+    tk, tn = pallas_moe._blocks(k, n, 1)
+    assert pallas_moe._by_group(rows, k, n, tn, 1) == by_group
+    if e == 64:  # a whole column a block, as before the budget grew
+        assert tk == k and tk * tn <= 2 << 20
 
 
 def test_tile_plan_adapts_to_experts_and_rows():

@@ -1,0 +1,101 @@
+"""``tpu:moe_tiles_used_total`` (PR 44), from the program to the benchmark's
+line: the family is registered and rendered beside the three it joins, the
+metric ``moe.tiles_per_expert_mean.batch`` reads it over the touched experts
+in the two sparse closed-loop cells, a program without the counter (the
+parent) gives the reader nothing to read and no error, and the on-chip
+tool's two layouts of the same rows take the tiles it says they take."""
+
+import os
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.join(REPO, "tools"))
+
+from benchmark import manifest, readers  # noqa: E402
+from llm_instance_gateway_tpu import metrics_registry  # noqa: E402
+from llm_instance_gateway_tpu.ops import pallas_moe  # noqa: E402
+from llm_instance_gateway_tpu.server import profiler  # noqa: E402
+
+NAME = "moe.tiles_per_expert_mean.batch"
+BEFORE = """tpu:moe_layer_steps_total 12
+tpu:moe_experts_touched_total 80
+tpu:moe_tiles_used_total 100
+"""
+AFTER = """tpu:moe_layer_steps_total 1212
+tpu:moe_experts_touched_total 8480
+tpu:moe_tiles_used_total 11020
+"""
+
+
+def read(ctx):
+    spec = manifest.load_metric(NAME)
+    return readers.READERS[spec["reader"]](spec.get("args", {}), ctx)
+
+
+def test_the_family_is_registered_and_rendered_with_the_tally():
+    families = {f.name for f in metrics_registry.SERVER_FAMILIES}
+    assert "tpu:moe_tiles_used_total" in families
+    assert profiler.MOE_COUNTERS[-1] == "tiles_used"
+    hist = {"moe": dict(zip(profiler.MOE_COUNTERS, (6, 384, 42, 51)))}
+    text = "\n".join(profiler.render_profile(hist))
+    assert "tpu:moe_tiles_used_total 51" in text
+    assert "tpu:moe_experts_touched_total 42" in text
+
+
+def test_the_metric_reads_tiles_over_touched_experts():
+    ctx = {"prom_before": [BEFORE], "prom_after": [AFTER], "window_s": 40.0}
+    assert read(ctx) == pytest.approx((11020 - 100) / (8480 - 80))
+    # two replicas: sums over sums
+    assert read({**ctx, "prom_before": [BEFORE] * 2,
+                 "prom_after": [AFTER] * 2}) == pytest.approx(1.3)
+
+
+def test_a_program_without_the_counter_reads_nothing():
+    strip = lambda text: "\n".join(  # noqa: E731
+        line for line in text.splitlines() if "tiles_used" not in line) + "\n"
+    parent = {"prom_before": [strip(BEFORE)], "prom_after": [strip(AFTER)],
+              "window_s": 40.0}
+    assert read(parent) is None
+    dense = {"prom_before": ["tpu:x 1\n"], "prom_after": ["tpu:x 2\n"],
+             "window_s": 40.0}
+    assert read(dense) is None
+
+
+def test_the_entry_is_the_last_and_lists_the_sparse_closed_loops():
+    man = manifest.load_manifest()
+    assert manifest.problems(man) == []
+    entry = man["per_layer"][-1]
+    assert entry == {
+        "name": NAME, "unit": "tiles", "better": "lower",
+        "source": "program_counter", "layer": "model step",
+        "moves": "output_tok_s",
+        "workloads": ["mixtral_d6_batch", "glm47flash_d13_agents"]}
+    for cell in entry["workloads"]:
+        model = manifest.load_config(manifest.cell(man, cell)["config"])[
+            "model"]
+        assert manifest.can_report(manifest.load_metric(NAME), model)
+
+
+@pytest.fixture(scope="module")
+def tool():
+    import onchip_pallas_check
+    return onchip_pallas_check
+
+
+@pytest.mark.parametrize("shape", range(4))
+def test_the_tools_two_layouts_hold_the_same_rows_in_more_tiles(tool, shape):
+    """``moe-reuse``: every touched group in one tile, and the same rows
+    with two groups in three and two tiles."""
+    _, e, k, n, m, touched = tool.MOE_REUSE_SHAPES[shape]
+    assert not pallas_moe.shape_reasons(k, n)
+    tm = pallas_moe.tile_rows(m, e)
+    tiles = pallas_moe.n_tiles(m, e, tm)
+    used = []
+    for skewed in (False, True):
+        sizes = tool._group_sizes(m, e, touched, tm, skewed)
+        assert int(sizes.sum()) == m and int((sizes > 0).sum()) == touched
+        used.append(int(pallas_moe.tile_plan(sizes, tm, tiles)[2]))
+    assert used == [touched, touched + 3] and used[1] <= tiles

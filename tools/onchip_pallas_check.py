@@ -15,7 +15,10 @@ costs a layer at 32 slots when 5 or all 32 of them decode (host clock
 round one program of 400 calls, as a model's layer loop makes them), and
 the ``ssm-update`` cases what the state-space decode update costs a layer at
 64 slots of Falcon-H1-34B's state (32 x 256 x 128 float32) with 64, 8 and 1
-of them live, beside the time its bytes would take at the chip's bandwidth.
+of them live, beside the time its bytes would take at the chip's bandwidth;
+and the ``moe-reuse`` cases what the int8 expert matmul costs a call at
+Mixtral's, OLMoE's and GLM's decode shapes with every touched group in one
+row tile and with two groups in two and three.
 
     python tools/onchip_pallas_check.py            # on the chip
 """
@@ -83,6 +86,17 @@ MOE_SHAPES = (
     ("mixtral down prefill 1024x2", 8, 14336, 4096, 2048),
 )
 
+# What a second and third row tile of one expert cost the grouped matmul:
+# (label, E, K, N, assignments, experts touched), decode-sized, at the shapes
+# whose column is cut into several K blocks (Mixtral: nk 2 and 8) and at two
+# whose column is one block (OLMoE, GLM-4.7-Flash).
+MOE_REUSE_SHAPES = (
+    ("mixtral gate/up decode 32x2", 8, 4096, 14336, 64, 7),
+    ("mixtral down decode 32x2", 8, 14336, 4096, 64, 7),
+    ("olmoe gate/up decode 32x8", 64, 2048, 1024, 256, 36),
+    ("glm-4.7-flash gate/up decode 32x4", 64, 2048, 1536, 128, 27),
+)
+
 DTYPE = jnp.bfloat16
 
 
@@ -103,6 +117,18 @@ def _scaled_err(out, ref):
     if not np.all(np.isfinite(out)):
         return float("inf")
     return float(np.max(np.abs(out - ref) / np.maximum(1.0, np.abs(ref))))
+
+
+def _us_a_call(run, calls):
+    """(least, median) of five timings of ``run()``, which blocks until its
+    program of ``calls`` calls is done, after one run to compile it."""
+    run()
+    times = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        run()
+        times.append((time.perf_counter() - t0) / calls * 1e6)
+    return min(times), sorted(times)[2]
 
 
 def case_flash(h, n_kv, hd, s, b=1):
@@ -176,15 +202,11 @@ def case_live_rows(h, n_kv, hd, s_max, n_live, b=32, held=150, n_layers=8,
         layers = jnp.arange(calls, dtype=jnp.int32) % n_layers
         return jax.lax.scan(body, jnp.zeros(q.shape, jnp.float32), layers)[0]
 
-    loop(q, kc, vc, lengths).block_until_ready()
-    times = []
-    for _ in range(5):
-        t0 = time.perf_counter()
-        loop(q, kc, vc, lengths).block_until_ready()
-        times.append((time.perf_counter() - t0) / calls * 1e6)
+    least, median = _us_a_call(
+        lambda: loop(q, kc, vc, lengths).block_until_ready(), calls)
     print(f"TIME   lane-decode {n_live}/{b} live rows x{held} h={h} "
-          f"kv={n_kv} s_max={s_max}: {min(times):.1f} us a layer "
-          f"(median {sorted(times)[2]:.1f}, {calls} calls a program)",
+          f"kv={n_kv} s_max={s_max}: {least:.1f} us a layer "
+          f"(median {median:.1f}, {calls} calls a program)",
           flush=True)
     out = jax.jit(lambda q, kc, vc: pdec.decode_attention_pallas(
         q, kc, vc, lengths, layer=jnp.int32(1), interpret=False))(q, kc, vc)
@@ -252,6 +274,71 @@ def case_moe(e, k, n, m, quant):
     sizes = jnp.zeros((e,), jnp.int32).at[0].add(m // 4).at[rest].add(1)
     _, te, n_used = pmoe.tile_plan(sizes, tm, n_tiles)
     x = jax.random.normal(kx, (n_tiles * tm, k), DTYPE)
+    out = jax.jit(lambda x, w, te, nu: pmoe.grouped_matmul_pallas(
+        x, w, te, nu, 1, tm=tm))(x, w, te, n_used)
+    ref = jax.jit(lambda x, w, te: pmoe.grouped_matmul_xla(
+        x, w, te, 1, tm=tm))(x, w, te)
+    live = (jnp.arange(n_tiles * tm) < n_used * tm)[:, None]
+    return out * live, ref * live, TOL_BF16
+
+
+def _group_sizes(m, e, touched, tm, skewed):
+    """``m`` rows over ``touched`` of ``e`` experts, spread over the experts'
+    range as a router spreads them (the untouched lie between the touched):
+    evenly, every group inside one tile; or the same rows skewed so that
+    the first group takes three tiles and the second two, the others still
+    one each."""
+    heads = [2 * tm + 1, tm + 1] if skewed else []
+    rest, left = touched - len(heads), m - sum(heads)
+    sizes = heads + [left // rest + (i < left % rest) for i in range(rest)]
+    assert 1 <= min(sizes) and max(sizes[len(heads):]) <= tm, sizes
+    where = np.linspace(0, e - 1, touched).astype(int)
+    return jnp.zeros((e,), jnp.int32).at[where].set(jnp.asarray(sizes))
+
+
+def case_moe_reuse(e, k, n, m, touched, n_layers=4, calls=100):
+    """The int8 grouped matmul as a decode step's layer loop calls it: ``m``
+    assignment rows over ``touched`` experts, once with every group in one
+    tile and once skewed (``_group_sizes``), through a stack of ``n_layers``
+    layers, ``calls`` calls a program.  Prints us a call (host clock, least of
+    five) and the bytes/s that each TOUCHED expert's [K, N] int8 matrix read
+    ONCE a call implies: what a tile more of the same expert costs is the
+    difference of the two lines.  Parity of the skewed plan against the XLA
+    tiles on the same weights."""
+    kw, ks, kx = _keys(8, 3)
+    # one [K, N] matrix a draw: a draw of the whole stack would take four
+    # times its bytes in 32-bit words
+    draw = jax.jit(lambda key: jax.lax.bitcast_convert_type(
+        jax.random.bits(key, (k, n), jnp.uint8), jnp.int8))
+    q = jnp.stack([jnp.stack([draw(key) for key in jax.random.split(kl, e)])
+                   for kl in jax.random.split(kw, n_layers)])
+    scale = (1 + 0.1 * jax.random.uniform(ks, (n_layers, e, n), jnp.float32)
+             ) / (74.0 * np.sqrt(k))
+    w = {"q": q, "s": scale}
+    tm = pmoe.tile_rows(m, e)
+    n_tiles = pmoe.n_tiles(m, e, tm)
+    x = jax.random.normal(kx, (n_tiles * tm, k), DTYPE)
+
+    @jax.jit
+    def loop(x, w, te, n_used):
+        def body(nu, layer):
+            out = pmoe.grouped_matmul_pallas(x, w, te, nu, layer, tm=tm)
+            # never true; ties each call to the one before
+            return nu + (out[0, 0] > 1e30).astype(jnp.int32), None
+        layers = jnp.arange(calls, dtype=jnp.int32) % n_layers
+        return jax.lax.scan(body, n_used, layers)[0]
+
+    for skewed in (False, True):
+        _, te, n_used = pmoe.tile_plan(
+            _group_sizes(m, e, touched, tm, skewed), tm, n_tiles)
+        least, median = _us_a_call(
+            lambda: loop(x, w, te, n_used).block_until_ready(), calls)
+        print(f"TIME   moe-reuse K={k} N={n} blocks={pmoe._blocks(k, n, 1)} "
+              f"{m} rows, {touched} experts in {int(n_used)} tiles of {tm}: "
+              f"{least:.1f} us a call (median {median:.1f}, "
+              f"{calls} calls a program): "
+              f"{touched * k * n / least / 1e3:.0f} GB/s of the touched "
+              f"experts' bytes read once", flush=True)
     out = jax.jit(lambda x, w, te, nu: pmoe.grouped_matmul_pallas(
         x, w, te, nu, 1, tm=tm))(x, w, te, n_used)
     ref = jax.jit(lambda x, w, te: pmoe.grouped_matmul_xla(
@@ -337,6 +424,10 @@ def cases():
                    pmoe.shape_reasons(k, n),
                    lambda e=e, k=k, n=n, m=m, quant=quant: case_moe(
                        e, k, n, m, quant))
+    for label, e, k, n, m, touched in MOE_REUSE_SHAPES:
+        yield (f"moe-reuse [{label}]", pmoe.shape_reasons(k, n),
+               lambda e=e, k=k, n=n, m=m, touched=touched: case_moe_reuse(
+                   e, k, n, m, touched))
     # Speed 2 of ROADMAP.md: what the rows that do not decode cost the lane
     # kernel, at the two benchmark models' layouts and lane lengths.
     for label, h, n_kv, hd, s_max in (("qwen2.5-7b g=7", 28, 4, 128, 2048),
