@@ -500,6 +500,11 @@ CLASSES = (
                         note="recurrent states the decode steps rewrote: "
                              "the engine thread adds at each dispatch, the "
                              "scrape reads under the lock"),
+            SharedField("kv_positions", LOCK_GUARDED,
+                        writers=("note_kv_positions",),
+                        note="cache positions the decode steps read by kind "
+                             "of lane: the engine thread adds at each "
+                             "dispatch, the scrape reads under the lock"),
             SharedField("_last_end", OWNER_PRIVATE,
                         writers=("note_dispatch",)),
             SharedField("_idle_pending", OWNER_PRIVATE,

@@ -56,6 +56,9 @@ ENGINE_PHASES = (
 # device from the live rows' parameters.  ``sample_routed`` returns an index
 # into this tuple.
 SAMPLE_PATHS = ("argmax", "draw", "filtered")
+# The ``lanes`` label set of ``tpu:kv_positions_read_total``: the two kinds
+# of cache lane a model with window layers keeps (models/transformer.py).
+KV_LANES = ("full", "window")
 
 GATEWAY_FAMILIES = (
     Family("gateway_requests_total", "counter", ("model",),
@@ -423,6 +426,15 @@ SERVER_FAMILIES = (
            "tpu:dispatch_steps_sum, the states one decode step's update "
            "kernel reads and writes per layer. 0 for a model without a "
            "mixer.",
+           SERVER_SURFACE),
+    Family("tpu:kv_positions_read_total", "counter", ("lanes",),
+           "Cache positions the attention of the plain decode dispatches' "
+           "steps read of the live rows' lanes, a layer of the kind: "
+           "lanes=full the rows' whole lengths (a full-attention layer), "
+           "lanes=window at most the window of each (a sliding-window "
+           "layer's ring); over tpu:dispatch_steps_sum, the positions one "
+           "decode step's kernel reads per layer of the kind. 0 for a model "
+           "without a window; metrics_registry.KV_LANES.",
            SERVER_SURFACE),
     Family("tpu:lora_rows_total", "counter", (),
            "Live rows whose LoRA slot is >= 0, summed over the steps of the "

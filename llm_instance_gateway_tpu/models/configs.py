@@ -8,7 +8,11 @@ one decoder family (RoPE + GQA + RMSNorm + gated MLP, optionally MoE;
 Qwen2's QKV bias and OLMoE's QK-norm and gate rule are flags; GLM's latent
 attention, ``models/mla.py``; Falcon-H1's parallel block, a state-space
 mixer beside the attention of every layer, ``models/ssm.py``, with the
-family's fixed muP multipliers).
+family's fixed muP multipliers; SmallThinker's PERIOD of layer kinds,
+``layer_pattern``: full attention without a position encoding and RoPE
+layers with a sliding window in one stack, which the layer loop scans a
+period a step and whose decode cache is of two kinds, full lanes of
+``max_seq_len`` positions and ring lanes of ``sliding_window``).
 
 All dims are chosen/padded TPU-first: head_dim and d_model multiples of 128
 (MXU lane width), d_ff multiples of 128, vocab padded to 128 so the final
@@ -18,10 +22,22 @@ projection tiles cleanly onto the systolic array.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
+from typing import NamedTuple
 
 
 def pad_to(x: int, multiple: int) -> int:
     return ((x + multiple - 1) // multiple) * multiple
+
+
+class LayerKind(NamedTuple):
+    """What the attention of one layer of a stack is: ``window`` > 0 lets
+    position i attend j iff 0 <= i - j < window (0: every j <= i), ``rope``
+    says whether q and k get the rotary position encoding at all."""
+    window: int = 0
+    rope: bool = True
+
+
+MLP_ACTIVATIONS = ("silu", "gelu", "relu")
 
 
 @dataclass(frozen=True)
@@ -46,7 +62,9 @@ class ModelConfig:
     tie_embeddings: bool = False
     embedding_scale: bool = False  # Gemma multiplies embeddings by sqrt(d_model)
     norm_plus_one: bool = False  # Gemma RMSNorm uses (1 + w) weighting
-    gelu_mlp: bool = False  # Gemma uses GeLU gating; Llama uses SiLU
+    # The gated MLP's activation, dense and expert alike: ``act(gate) * up``
+    # with SiLU (Llama), tanh-GeLU (Gemma) or ReLU (SmallThinker's ReGLU).
+    mlp_activation: str = "silu"
     # Qwen2-family difference: learned biases on the Q/K/V projections
     # (attention only — o and the MLP stay bias-free).
     attention_bias: bool = False
@@ -87,6 +105,22 @@ class ModelConfig:
     first_k_dense: int = 0
     router_sigmoid: bool = False
     routed_scaling_factor: float = 1.0
+    # A stack of more than one kind of attention layer (SmallThinker,
+    # ``smallthinker``): ``layer_pattern`` is the PERIOD of kinds the stack
+    # repeats, each "full" (causal, RoPE), "nope" (causal, no position
+    # encoding at all) or "window" (RoPE; position i attends j iff
+    # 0 <= i - j < ``sliding_window``).  Empty: every layer "full".  The
+    # layer loop scans one period a step (``transformer._scan_groups``), and
+    # a model with a window keeps TWO decode caches: ``k``/``v`` of
+    # ``max_seq_len`` positions for its full layers and ``k_win``/``v_win``
+    # rings of ``sliding_window`` positions (position p at p mod ring) for
+    # its window layers.  ``router_pre_attention``: the router's logits come
+    # from the block's INPUT (the residual stream before the attention
+    # norm), not from the normed input of the MLP; the experts still read
+    # the latter.
+    layer_pattern: tuple[str, ...] = ()
+    sliding_window: int = 0
+    router_pre_attention: bool = False
     # A state-space mixer beside attention in every layer (Falcon-H1,
     # ``falcon_h1``; the Mamba-2 form, ``models/ssm.py`` holds the
     # equations): ``ssm_d_inner`` > 0 makes the block parallel,
@@ -136,6 +170,45 @@ class ModelConfig:
     # and int8, lane and paged (same run); DMA-clamping skips cache blocks
     # past each row's length.  Speed against XLA: not measured.
     use_pallas_decode: bool = True
+
+    def __post_init__(self):
+        # (a config restored from JSON brings the period as a list)
+        object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+        if self.mlp_activation not in MLP_ACTIVATIONS:
+            raise ValueError(f"mlp_activation {self.mlp_activation!r}: one "
+                             f"of {MLP_ACTIVATIONS}")
+        unknown = set(self.layer_pattern) - {"full", "nope", "window"}
+        if unknown:
+            raise ValueError(f"layer_pattern names {sorted(unknown)}")
+        if ("window" in self.layer_pattern) != bool(self.sliding_window):
+            raise ValueError("a window layer needs sliding_window > 0, and "
+                             "sliding_window a window layer")
+        if self.n_layers % len(self.layer_kinds):
+            raise ValueError(
+                f"{self.n_layers} layers are not whole periods of "
+                f"{len(self.layer_kinds)}")
+        if len(self.layer_kinds) > 1 and (
+                self.first_k_dense or self.latent_width or self.ssm_d_inner):
+            raise NotImplementedError(
+                "a period of layer kinds beside leading dense layers, a "
+                "latent cache or a mixer")
+
+    @property
+    def gelu_mlp(self) -> bool:
+        return self.mlp_activation == "gelu"
+
+    @property
+    def layer_kinds(self) -> tuple[LayerKind, ...]:
+        """The period of the stack, one ``LayerKind`` a layer of it."""
+        return tuple(LayerKind(self.sliding_window if k == "window" else 0,
+                               k != "nope")
+                     for k in self.layer_pattern or ("full",))
+
+    @property
+    def n_window_layers(self) -> int:
+        kinds = self.layer_kinds
+        return (self.n_layers // len(kinds)) * sum(
+            1 for k in kinds if k.window)
 
     @property
     def resolved_head_dim(self) -> int:
@@ -244,7 +317,7 @@ GEMMA_2B = ModelConfig(
     tie_embeddings=True,
     embedding_scale=True,
     norm_plus_one=True,
-    gelu_mlp=True,
+    mlp_activation="gelu",
     max_seq_len=8192,
 )
 
@@ -261,7 +334,7 @@ GEMMA_7B = ModelConfig(
     tie_embeddings=True,
     embedding_scale=True,
     norm_plus_one=True,
-    gelu_mlp=True,
+    mlp_activation="gelu",
     max_seq_len=8192,
 )
 
@@ -397,6 +470,42 @@ TINY_FALCON_H1_TEST = replace(
     n_layers=3, n_heads=5, n_kv_heads=1, head_dim=32, d_ff=512,
     ssm_d_inner=128, ssm_n_heads=4, ssm_head_dim=32, ssm_d_state=16,
     ssm_chunk=8, attention_in_multiplier=0.8, max_seq_len=512,
+    max_lora_rank=4)
+
+# PowerInfer/SmallThinker-21BA3B-Instruct (``smallthinker``): layer l with
+# l % 4 == 0 is full attention with NO position encoding, the other three of
+# each period are RoPE layers with a 4,096-position window; 64 experts of
+# width 768, top-6 by the softmax over the chosen, no shared expert and no
+# dense MLP, ReLU gating; the router reads the block's input.
+SMALLTHINKER_21B_A3B = ModelConfig(
+    name="smallthinker-21b-a3b",
+    vocab_size=151_936,
+    d_model=2560,
+    n_layers=52,
+    n_heads=28,
+    n_kv_heads=4,
+    d_ff=768,
+    head_dim=128,
+    rope_theta=1_500_000.0,
+    norm_eps=1e-6,
+    mlp_activation="relu",
+    n_experts=64,
+    n_experts_per_token=6,
+    norm_topk_prob=True,
+    layer_pattern=("nope", "window", "window", "window"),
+    sliding_window=4096,
+    router_pre_attention=True,
+    max_seq_len=16_384,
+    max_lora_slots=0,  # adapters are not served over a period-scanned stack
+)
+
+# The CPU's SmallThinker: two periods, a window of 16 (a 16-token chunk
+# stream and a few decode steps cross it and wrap the ring), 7 queries over
+# 1 kv head kept as a ratio (here 4 over 2), 8 experts top-3.
+TINY_SMALLTHINKER_TEST = replace(
+    SMALLTHINKER_21B_A3B, name="smallthinker-tiny", vocab_size=320,
+    d_model=64, n_layers=8, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=32,
+    n_experts=8, n_experts_per_token=3, sliding_window=16, max_seq_len=128,
     max_lora_rank=4)
 
 TINY_TEST = LLAMA3_8B.tiny()

@@ -22,7 +22,11 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 
-from llm_instance_gateway_tpu.models.configs import LLAMA3_8B, ModelConfig
+from llm_instance_gateway_tpu.models.configs import (
+    LLAMA3_8B,
+    SMALLTHINKER_21B_A3B,
+    ModelConfig,
+)
 
 
 def config_from_hf(hf_config) -> ModelConfig:
@@ -33,10 +37,15 @@ def config_from_hf(hf_config) -> ModelConfig:
     (1+w) norm, tanh-GeLU), Mixtral (expert stacks + router), OLMoE (the
     same stacks under its own names, QK-norm, ``norm_topk_prob``).  Loud
     rejections instead of silent wrong math for everything else: unknown
-    model types, non-llama3 rope_scaling types, and sliding-window attention
-    (our decoder attends the full causal context).
+    model types, non-llama3 rope_scaling types, and a sliding window on a
+    family whose preset has none (its layers attend the full causal
+    context).  SmallThinker (``smallthinker``), whose preset has a window,
+    gets its per-layer layouts converted into the period of layer kinds the
+    layer loop scans (``_smallthinker_config``).
     """
     model_type = getattr(hf_config, "model_type", "llama")
+    if model_type == "smallthinker":
+        return _smallthinker_config(hf_config)
     if model_type not in ("llama", "gemma", "mixtral", "qwen2", "olmoe"):
         raise NotImplementedError(
             f"HF model_type {model_type!r} not supported by the converter "
@@ -102,7 +111,7 @@ def config_from_hf(hf_config) -> ModelConfig:
         # sqrt(d_model) embedding normalizer, (1+w) RMSNorm, tanh-GeLU gate.
         embedding_scale=gemma,
         norm_plus_one=gemma,
-        gelu_mlp=gemma,
+        mlp_activation="gelu" if gemma else "silu",
         # Mixtral MoE (parity-tested against MixtralForCausalLM; top-k
         # routing normalizations are algebraically identical).
         n_experts={"mixtral": getattr(hf_config, "num_local_experts", 0),
@@ -122,13 +131,63 @@ def config_from_hf(hf_config) -> ModelConfig:
     )
 
 
+def _smallthinker_config(hf_config) -> ModelConfig:
+    """The preset with a window, from the source's own keys: the two
+    per-layer layouts become ONE period of kinds (``sliding_window_layout``
+    1 with ``rope_layout`` 1: "window"; both 0: "nope"; window 0 with rope
+    1: "full"), the shortest that the stack repeats; a window without a
+    position encoding, or layouts with no period, are refused."""
+    n = hf_config.num_hidden_layers
+    windows = list(getattr(hf_config, "sliding_window_layout", None)
+                   or [0] * n)
+    ropes = list(getattr(hf_config, "rope_layout", None) or [1] * n)
+    if len(windows) != n or len(ropes) != n:
+        raise NotImplementedError(
+            "sliding_window_layout / rope_layout do not name every layer")
+    kinds = []
+    for w, r in zip(windows, ropes):
+        if w and not r:
+            raise NotImplementedError(
+                "a sliding-window layer without a position encoding")
+        kinds.append("window" if w else "full" if r else "nope")
+    period = next((p for p in range(1, n + 1)
+                   if n % p == 0 and kinds == kinds[:p] * (n // p)), n)
+    window = int(getattr(hf_config, "sliding_window_size", 0) or 0)
+    if "window" in kinds and not window:
+        raise NotImplementedError("window layers but no sliding_window_size")
+    if getattr(hf_config, "rope_scaling", None):
+        raise NotImplementedError("rope_scaling on a smallthinker model")
+    if not getattr(hf_config, "moe_primary_router_apply_softmax", True):
+        raise NotImplementedError(
+            "moe_primary_router_apply_softmax false (a sigmoid router)")
+    return dataclasses.replace(
+        SMALLTHINKER_21B_A3B,
+        name=getattr(hf_config, "name_or_path", "") or "hf-smallthinker",
+        vocab_size=hf_config.vocab_size,
+        d_model=hf_config.hidden_size,
+        n_layers=n,
+        n_heads=hf_config.num_attention_heads,
+        n_kv_heads=hf_config.num_key_value_heads,
+        head_dim=hf_config.head_dim,
+        d_ff=hf_config.moe_ffn_hidden_size,
+        n_experts=hf_config.moe_num_primary_experts,
+        n_experts_per_token=hf_config.moe_num_active_primary_experts,
+        norm_topk_prob=bool(getattr(hf_config, "norm_topk_prob", True)),
+        rope_theta=float(hf_config.rope_theta),
+        norm_eps=hf_config.rms_norm_eps,
+        max_seq_len=hf_config.max_position_embeddings,
+        tie_embeddings=bool(getattr(hf_config, "tie_word_embeddings", False)),
+        layer_pattern=tuple(kinds[:period]),
+        sliding_window=window if "window" in kinds else 0,
+    )
+
+
 def params_from_hf_state_dict(cfg: ModelConfig, state_dict, dtype=jnp.bfloat16):
     """Map an HF Llama state dict onto our stacked-layer pytree.
 
     HF Linear weights are [out, in]; ours are [in, out] — transposed here.
     The embedding row space is padded to ``cfg.padded_vocab``.
     """
-
     # Per-tensor dtype cast at stack time: staging whole stacked layers in
     # f32 would triple peak host memory on an 8B conversion.
     def t(name):  # tensor -> [in, out] in the target dtype
@@ -165,10 +224,17 @@ def params_from_hf_state_dict(cfg: ModelConfig, state_dict, dtype=jnp.bfloat16):
         layers["k_norm"] = stack_raw("model.layers.{}.self_attn.k_norm.weight")
     if cfg.n_experts:
         # Mixtral: block_sparse_moe.experts.N.{w1=gate, w3=up, w2=down};
-        # OLMoE: mlp.experts.N.{gate,up,down}_proj (each [f, d] or [d, f]
-        # in HF's [out, in]); stacked here as [L, E, in, out].
+        # OLMoE: mlp.experts.N.{gate,up,down}_proj; SmallThinker:
+        # block_sparse_moe.experts.N.{gate,up,down} under a router named
+        # primary_router (each [f, d] or [d, f] in HF's [out, in]); stacked
+        # here as [L, E, in, out].
+        router = "gate"
         if "model.layers.0.mlp.gate.weight" in state_dict:
             moe, names = "mlp", ("gate_proj", "up_proj", "down_proj")
+        elif ("model.layers.0.block_sparse_moe.primary_router.weight"
+              in state_dict):
+            moe, names = "block_sparse_moe", ("gate", "up", "down")
+            router = "primary_router"
         else:
             moe, names = "block_sparse_moe", ("w1", "w3", "w2")
 
@@ -181,7 +247,8 @@ def params_from_hf_state_dict(cfg: ModelConfig, state_dict, dtype=jnp.bfloat16):
                 for i in range(cfg.n_layers)
             ])
 
-        layers["router"] = stack("model.layers.{}." + moe + ".gate.weight")
+        layers["router"] = stack(
+            "model.layers.{}." + moe + "." + router + ".weight")
         layers["w_gate"], layers["w_up"], layers["w_down"] = (
             stack_experts(n) for n in names)
     else:

@@ -5,7 +5,9 @@ them, QK-norm) and GLM-4.7-Flash (latent attention, a leading dense layer,
 expert).  Falcon-H1-34B (dense; a state-space mixer beside attention in
 every layer, ``models/ssm.py``) is entered here too: the benchmark's server
 wrapper looks a preset up in this dict, ``llama``'s, ``gemma``'s and
-``qwen``'s and nowhere else.
+``qwen``'s and nowhere else.  SmallThinker-21B-A3B (64 experts, top-6, ReLU
+gating, a router on the block's input; a period of one full layer without a
+position encoding and three window layers) is the stack of two kinds.
 
 BASELINE.json's criticality-tiered mixed pool pairs Mixtral-8x7B with
 Gemma-7B on v5e-32.  The MoE MLP lives in ``transformer._moe_mlp``; expert
@@ -21,17 +23,21 @@ from llm_instance_gateway_tpu.models.configs import (
     GLM_4_7_FLASH,
     MIXTRAL_8X7B,
     OLMOE_1B_7B,
+    SMALLTHINKER_21B_A3B,
     TINY_FALCON_H1_TEST,
     TINY_GLM_TEST,
     TINY_MOE_TEST,
     TINY_OLMOE_TEST,
+    TINY_SMALLTHINKER_TEST,
 )
 
 CONFIGS = {"mixtral-8x7b": MIXTRAL_8X7B, "mixtral-tiny": TINY_MOE_TEST,
            "olmoe-1b-7b": OLMOE_1B_7B, "olmoe-tiny": TINY_OLMOE_TEST,
            "glm-4.7-flash": GLM_4_7_FLASH, "glm-tiny": TINY_GLM_TEST,
            "falcon-h1-34b": FALCON_H1_34B,
-           "falcon-h1-tiny": TINY_FALCON_H1_TEST}
+           "falcon-h1-tiny": TINY_FALCON_H1_TEST,
+           "smallthinker-21b-a3b": SMALLTHINKER_21B_A3B,
+           "smallthinker-tiny": TINY_SMALLTHINKER_TEST}
 
 init_params = transformer.init_params
 init_decode_cache = transformer.init_decode_cache

@@ -67,6 +67,17 @@ the residual together, and fixed scalar multipliers (1 for the models above):
     x = x + ((silu((h_n Wg) * mlp_multipliers[0]) * (h_n Wu)) Wd) * mlp_multipliers[1]
     logits = (RMSNorm(x) W_head) * lm_head_multiplier
 
+SmallThinker (``smallthinker``, ``layer_pattern``): a stack that repeats a
+period of layer kinds, a router that reads the block's INPUT, ReLU gating:
+
+    r = x Wr                        router logits, from x BEFORE the norm
+    layer kind "window":  q, k = RoPE(q), RoPE(k);  i attends j iff
+                          0 <= i - j < sliding_window
+    layer kind "nope":    no position encoding at all; i attends j iff j <= i
+    p = softmax(r) over all E; the k largest are the experts, w_i = p_i /
+        sum of the chosen (norm_topk_prob)
+    x = x + sum_i w_i * (relu(h_n Wg_i) * (h_n Wu_i)) Wd_i
+
 A LoRA adapter adds ``scale * (z A) B`` to a projection of ``z``.
 
 Departures from the published descriptions, each on purpose:
@@ -123,8 +134,16 @@ def _lora(z, lora, layer, target):
     return bufs["scale"][slot].astype(F32) * ((z @ a) @ b)
 
 
+def _kind(cfg, layer):
+    """(window, rope) of layer ``layer``: 0 = every earlier position."""
+    pattern = cfg.layer_pattern or ("full",)
+    name = pattern[layer % len(pattern)]
+    return (cfg.sliding_window if name == "window" else 0), name != "nope"
+
+
 def _attention(cfg, lp, layer, x_n, lora):
     s = x_n.shape[0]
+    window, rope = _kind(cfg, layer)
     x_n = x_n * cfg.attention_in_multiplier
     hd = cfg.head_dim or cfg.d_model // cfg.n_heads
     proj = {}
@@ -135,14 +154,17 @@ def _attention(cfg, lp, layer, x_n, lora):
         if cfg.qk_norm and t != "v":
             z = _rms_norm(z, lp[f"{t}_norm"][layer].astype(F32), cfg.norm_eps)
         proj[t] = z * cfg.key_multiplier if t == "k" else z
-    q = _rope(proj["q"].reshape(s, cfg.n_heads, hd), cfg.rope_theta)
-    k = _rope(proj["k"].reshape(s, cfg.n_kv_heads, hd), cfg.rope_theta)
+    q = proj["q"].reshape(s, cfg.n_heads, hd)
+    k = proj["k"].reshape(s, cfg.n_kv_heads, hd)
+    if rope:
+        q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
     v = proj["v"].reshape(s, cfg.n_kv_heads, hd)
     group = cfg.n_heads // cfg.n_kv_heads
     k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     scores = jnp.einsum("ihd,jhd->hij", q, k) / jnp.sqrt(F32(hd))
-    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
-    probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
+    behind = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+    seen = (behind >= 0) & (behind < window) if window else behind >= 0
+    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
     a = jnp.einsum("hij,jhd->ihd", probs, v).reshape(s, -1)
     return ((a @ _weight(lp["wo"], layer) + _lora(a, lora, layer, "o"))
             * cfg.attention_out_multiplier)
@@ -209,18 +231,24 @@ def _latent_attention(cfg, lp, layer, x_n):
     return a @ _weight(lp["wo"], layer)
 
 
-def _gated(z, wg, wu, wd):
-    return (jax.nn.silu(z @ wg) * (z @ wu)) @ wd
+_ACT = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
-def _mlp(cfg, lp, layer, h_n, lora):
+def _gated(z, wg, wu, wd, act="silu"):
+    return (_ACT[act](z @ wg) * (z @ wu)) @ wd
+
+
+def _mlp(cfg, lp, layer, h_n, lora, x=None):
+    """``x``: the block's input, which the router of a
+    ``router_pre_attention`` model reads in ``h_n``'s place."""
     if "router" not in lp:  # a dense model, or a leading dense layer
         gate = h_n @ _weight(lp["w_gate"], layer) + _lora(h_n, lora, layer, "gate")
         up = h_n @ _weight(lp["w_up"], layer) + _lora(h_n, lora, layer, "up")
-        act = jax.nn.silu(gate * cfg.mlp_multipliers[0]) * up
+        act = _ACT[cfg.mlp_activation](gate * cfg.mlp_multipliers[0]) * up
         return ((act @ _weight(lp["w_down"], layer)
                  + _lora(act, lora, layer, "down")) * cfg.mlp_multipliers[1])
-    router = h_n @ lp["router"][layer].astype(F32)  # [S, E]
+    router = ((x if cfg.router_pre_attention else h_n)
+              @ lp["router"][layer].astype(F32))  # [S, E]
     if cfg.router_sigmoid:
         p = jax.nn.sigmoid(router)
         pick = p + lp["router_bias"][layer].astype(F32)
@@ -237,10 +265,12 @@ def _mlp(cfg, lp, layer, h_n, lora):
     wg, wu, wd = (_weight(lp[n], layer) for n in ("w_gate", "w_up", "w_down"))
     y = jnp.zeros_like(h_n)
     for e in range(cfg.n_experts):
-        y = y + w[:, e: e + 1] * _gated(h_n, wg[e], wu[e], wd[e])
+        y = y + w[:, e: e + 1] * _gated(h_n, wg[e], wu[e], wd[e],
+                                        cfg.mlp_activation)
     if cfg.n_shared_experts:
         y = y + _gated(h_n, *(_weight(lp[n], layer)
-                              for n in ("ws_gate", "ws_up", "ws_down")))
+                              for n in ("ws_gate", "ws_up", "ws_down")),
+                       cfg.mlp_activation)
     return y
 
 
@@ -255,8 +285,8 @@ def forward(cfg, params, tokens, lora=None, states=None):
     if (cfg.tie_embeddings or cfg.embedding_scale or cfg.norm_plus_one
             or cfg.gelu_mlp or cfg.rope_scaling_factor):
         raise NotImplementedError(
-            "the reference covers the Llama/Qwen2/Mixtral/OLMoE/GLM/Falcon-H1 "
-            "block; the "
+            "the reference covers the Llama/Qwen2/Mixtral/OLMoE/GLM/Falcon-H1/"
+            "SmallThinker block; the "
             f"Gemma conventions and rope scaling of {cfg.name} are not in it")
     if (cfg.kv_lora_rank or cfg.ssm_d_inner) and lora is not None:
         raise NotImplementedError(
@@ -277,8 +307,8 @@ def forward(cfg, params, tokens, lora=None, states=None):
                         else _attention(cfg, lp, layer, x_n, lora))
             if cfg.ssm_d_inner:
                 branches = branches + _mixer(cfg, lp, layer, x_n, states)
-            x = x + branches
+            block_in, x = x, x + branches
             h_n = _rms_norm(x, lp["mlp_norm"][layer].astype(F32), cfg.norm_eps)
-            x = x + _mlp(cfg, lp, layer, h_n, lora)
+            x = x + _mlp(cfg, lp, layer, h_n, lora, block_in)
         x = _rms_norm(x, params["final_norm"].astype(F32), cfg.norm_eps)
         return (x @ _weight(params["lm_head"])) * cfg.lm_head_multiplier

@@ -1,4 +1,4 @@
-"""Core decoder-only transformer: one implementation, seven families.
+"""Core decoder-only transformer: one implementation, eight families.
 
 Covers Llama-3 (RoPE+GQA+SwiGLU), Gemma (tied embeddings, sqrt(d) embedding
 scale, GeLU gate, (1+w) RMSNorm, shared KV head), Qwen2 (QKV bias), Mixtral
@@ -8,7 +8,11 @@ a leading dense layer before the sparse ones; a sigmoid router with a
 selection bias and a shared expert) and Falcon-H1 (a parallel block: a
 state-space mixer beside the attention of every layer, ``models/ssm.py``,
 whose recurrent state rides the cache and the layer loop's carry next to
-the K/V lanes; fixed muP multipliers) via ``ModelConfig`` flags.
+the K/V lanes; fixed muP multipliers) and SmallThinker (a PERIOD of layer
+kinds, full attention without a position encoding and RoPE layers with a
+sliding window, scanned a period a step over a cache of two kinds: full
+lanes and ring lanes; a router that reads the block's input; ReLU gating)
+via ``ModelConfig`` flags.
 
 TPU-first structure:
 - Parameters are stacked over layers (``[n_layers, ...]`` leaves) and the
@@ -23,7 +27,8 @@ TPU-first structure:
   slot over the whole batch, kept per row by its slot id, so one decode batch
   multiplexes adapters + base model.
 - Every block sits in a ``jax.named_scope`` (embed, attn.qkv, attn.rope,
-  attn.kv_update, attn.core, attn.out, mlp, moe.route / .dispatch /
+  attn.kv_update, attn.core (attn.core.window over a window layer's ring
+  lanes), attn.out, mlp, moe.route / .dispatch /
   .experts / .shared, lora, lm_head, kv.insert; a latent model's attn.q_latent,
   attn.kv_latent, attn.absorb, attn.expand; a mixer's ssm.in_proj, ssm.conv,
   ssm.scan, ssm.update, ssm.gate_norm, ssm.out_proj): the scope is in each
@@ -42,7 +47,7 @@ import jax.numpy as jnp
 from llm_instance_gateway_tpu.models import lora as lora_lib
 from llm_instance_gateway_tpu.models import mla
 from llm_instance_gateway_tpu.models import ssm
-from llm_instance_gateway_tpu.models.configs import ModelConfig
+from llm_instance_gateway_tpu.models.configs import LayerKind, ModelConfig
 from llm_instance_gateway_tpu.ops.attention import (
     decode_attention,
     log_choice,
@@ -51,9 +56,9 @@ from llm_instance_gateway_tpu.ops.attention import (
 )
 from llm_instance_gateway_tpu.ops.layers import (
     apply_rope,
+    gated,
     rms_norm,
     scaled,
-    swiglu,
 )
 from llm_instance_gateway_tpu.ops import pallas_moe
 from llm_instance_gateway_tpu.ops.quant import (
@@ -224,13 +229,21 @@ def init_decode_cache(
     cache is ``mla.init_cache``: one row a position under ``k``, no ``v``.
     A model with a state-space mixer also gets ``ssm`` and ``conv``
     (``ssm.init_state``: the recurrent state of every slot, float32, and the
-    conv's history); every other model's cache has no such key."""
+    conv's history); every other model's cache has no such key.  A model
+    with window layers keeps them apart: ``k``/``v`` hold its FULL layers
+    alone and ``k_win``/``v_win`` [n_window_layers, B, ring, K, hd] its
+    window layers' rings, ``ring`` = min(``sliding_window``, ``max_len``)
+    positions of which position p lies at p mod ring, so that a row's ring
+    holds exactly its last ``ring`` positions."""
     if cfg.latent_width:
         if quantized:
             raise ValueError("a latent (MLA) cache has no int8 form")
         return mla.init_cache(cfg, batch, max_len, dtype)
     hd = cfg.resolved_head_dim
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, hd)
+    n_win = cfg.n_window_layers
+    if n_win and quantized:
+        raise ValueError("ring lanes have no int8 form")
+    shape = (cfg.n_layers - n_win, batch, max_len, cfg.n_kv_heads, hd)
     cache = {
         "k": jnp.zeros(shape, jnp.int8 if quantized else dtype),
         "v": jnp.zeros(shape, jnp.int8 if quantized else dtype),
@@ -244,6 +257,10 @@ def init_decode_cache(
         cache["v_scale"] = jnp.zeros(shape[:-1], jnp.float32)
     if cfg.ssm_d_inner:
         cache.update(ssm.init_state(cfg, batch, dtype))
+    if n_win:
+        ring = (n_win, batch, min(cfg.sliding_window, max_len),
+                cfg.n_kv_heads, hd)
+        cache.update(k_win=jnp.zeros(ring, dtype), v_win=jnp.zeros(ring, dtype))
     return cache
 
 
@@ -338,42 +355,68 @@ def _lm_head(cfg: ModelConfig, params: Params, h):
                   cfg.lm_head_multiplier)
 
 
-@jax.named_scope("attn.core")
-def _chunk_attend(cfg: ModelConfig, quant: bool, q, lane_k, lane_v, start):
+def _core_scope(kind: LayerKind):
+    """The scope of a layer's attention proper: a window layer's apart, so
+    that a device trace tells the two kinds of lane from each other."""
+    if kind.window:
+        return jax.named_scope("attn.core.window")
+    return jax.named_scope("attn.core")
+
+
+def _chunk_attend(cfg: ModelConfig, quant: bool, q, lane_k, lane_v, start,
+                  kind: LayerKind = LayerKind()):
     """Chunk-vs-lane attention dispatch, shared by the lane and paged
     chunk-stream paths.  Flash-style kernel (XLA off-TPU/odd shapes, logged
     by the dispatcher) unless the lane was dequantized from an int8 cache —
     an opaque kernel can't fuse the dequant into its reads and would
     materialize a bf16 copy, so quantized lanes keep the fused XLA path
-    (same reasoning as the decode-path quant gate).  Returns [1, C, H*hd]."""
+    (same reasoning as the decode-path quant gate).  A window layer's
+    queries see the last ``kind.window`` positions only.  Returns
+    [1, C, H*hd]."""
     from llm_instance_gateway_tpu.ops import pallas_attention
 
     c = q.shape[1]
-    if cfg.use_flash_attention and not quant:
-        return pallas_attention.chunk_attention(
-            q, lane_k[None], lane_v[None], start).reshape(1, c, -1)
-    log_choice(
-        "chunk_attend", f"q{tuple(q.shape)} lane{tuple(lane_k.shape)}",
-        "int8 lane: the dequant fuses into the XLA reads" if quant
-        else "use_flash_attention=False")
-    return xla_chunk_attention(q, lane_k[None], lane_v[None],
-                               start).reshape(1, c, -1)
+    with _core_scope(kind):
+        if cfg.use_flash_attention and not quant:
+            return pallas_attention.chunk_attention(
+                q, lane_k[None], lane_v[None], start,
+                window=kind.window).reshape(1, c, -1)
+        log_choice(
+            "chunk_attend", f"q{tuple(q.shape)} lane{tuple(lane_k.shape)}",
+            "int8 lane: the dequant fuses into the XLA reads" if quant
+            else "use_flash_attention=False")
+        return xla_chunk_attention(q, lane_k[None], lane_v[None], start,
+                                   kind.window).reshape(1, c, -1)
 
 
-def _mlp(cfg: ModelConfig, lp: Params, x, layer_lora, slot_ids, live=None):
+def _mlp(cfg: ModelConfig, lp: Params, x, layer_lora, slot_ids, live=None,
+         plan=None):
     """The block's MLP.  Returns ``(y, tally)``: ``tally`` is None for a
     dense model and the sparse layer's routing counts (``_moe_mlp``) for a
     sparse one.  ``live`` (bool, ``x``'s leading dims) marks the rows that
-    hold a request; a dense MLP has no use for it."""
+    hold a request; a dense MLP has no use for it.  ``plan``: the routing
+    a block made before its attention (``_route_early``); without one the
+    sparse layer routes on ``x``."""
     if cfg.n_experts and "router" in lp:  # not a leading dense layer
-        return _moe_mlp(cfg, lp, x, live)
+        return _moe_experts(cfg, lp, x,
+                            plan or _moe_route(cfg, lp, x, live))
     with jax.named_scope("mlp"):
         gate = _project(x, lp["w_gate"], layer_lora, "gate", slot_ids)
         up = _project(x, lp["w_up"], layer_lora, "up", slot_ids)
         m_gate, m_down = cfg.mlp_multipliers
-        y = _project(swiglu(scaled(gate, m_gate), up, cfg.gelu_mlp),
+        y = _project(gated(scaled(gate, m_gate), up, cfg.mlp_activation),
                      lp["w_down"], layer_lora, "down", slot_ids)
         return scaled(y, m_down), None
+
+
+def _route_early(cfg: ModelConfig, lp: Params, h, live):
+    """The routing plan of a block whose router reads the block's INPUT
+    (``router_pre_attention``): logits, the chosen experts, their gates and
+    the tile plan need nothing of the attention's output, so they are made
+    before it.  None for every other model, whose ``_mlp`` routes."""
+    if cfg.router_pre_attention and "router" in lp:
+        return _moe_route(cfg, lp, h, live)
+    return None
 
 
 # The sparse layer's routing counts, one int32 vector a layer-step:
@@ -385,6 +428,12 @@ _EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
 
 def _moe_mlp(cfg: ModelConfig, lp: Params, x, live=None):
+    """The sparse layer routed on its own input: ``_moe_route`` then
+    ``_moe_experts``.  Returns ``(y, tally)``."""
+    return _moe_experts(cfg, lp, x, _moe_route(cfg, lp, x, live))
+
+
+def _moe_route(cfg: ModelConfig, lp: Params, x, live=None) -> dict:
     """Top-k mixture-of-experts MLP by ONE dropless dispatch, the same code
     at every E, k and token count (Mixtral 8 top-2, OLMoE 64 top-8; decode
     batches and 1024-token prefills).
@@ -409,22 +458,20 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x, live=None):
 
     LoRA is not applied to expert weights (matching vLLM, which targets
     attention + dense MLP only).  A model with shared experts adds their
-    gated MLP of every token to the mix, once.  Returns ``(y, tally)``,
-    ``MOE_TALLY``.
+    gated MLP of every token to the mix, once.
+
+    This half is the route and the plan of the dispatch, which read ``x``
+    (the MLP's normed input, or the block's input under
+    ``router_pre_attention``) through the router alone: the gates [T, k],
+    each assignment's ``row`` in the grouped layout, the tiles' experts and
+    the ``tally`` (``MOE_TALLY``).  ``_moe_experts`` does the rest.
     """
-    orig_shape = x.shape
-    d = orig_shape[-1]
-    xf = x.reshape(-1, d)
+    xf = x.reshape(-1, x.shape[-1])
     t = xf.shape[0]
     e, k = cfg.n_experts, cfg.n_experts_per_token
     tm = pallas_moe.tile_rows(t * k, e)
     n_tiles = pallas_moe.n_tiles(t * k, e, tm)
     n_rows = n_tiles * tm
-
-    stacks = {name: lp[name] for name in _EXPERT_STACKS}
-    layer = lp.get("layer")
-    if layer is None:  # one layer's leaves: a stack of one
-        stacks, layer = jax.tree.map(lambda a: a[None], stacks), 0
 
     with jax.named_scope("moe.route"):
         router_logits = jnp.dot(xf, lp["router"],
@@ -460,17 +507,38 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x, live=None):
         # its gather reads zeros.
         row = jnp.where(expert < e,
                         first_row[jnp.minimum(expert, e - 1)] + rank, n_rows)
-        x_e = jnp.zeros((n_rows, d), xf.dtype).at[row].set(
-            jnp.repeat(xf, k, axis=0), mode="drop")
         tally = jnp.stack([jnp.ones((), jnp.int32), jnp.sum(sizes),
                            jnp.sum(sizes > 0), n_used])
+    return {"gates": gates, "row": row, "tile_expert": tile_expert,
+            "n_used": n_used, "tally": tally, "tm": tm, "n_rows": n_rows}
+
+
+def _moe_experts(cfg: ModelConfig, lp: Params, x, plan: dict):
+    """The sparse layer from its plan on (``_moe_route``): the rows of
+    ``x`` laid out by expert, the three grouped matmuls, the way back and
+    the gates' mix, the shared experts.  Returns ``(y, tally)``."""
+    orig_shape = x.shape
+    d = orig_shape[-1]
+    xf = x.reshape(-1, d)
+    t, k = xf.shape[0], cfg.n_experts_per_token
+    gates, row = plan["gates"], plan["row"]
+
+    stacks = {name: lp[name] for name in _EXPERT_STACKS}
+    layer = lp.get("layer")
+    if layer is None:  # one layer's leaves: a stack of one
+        stacks, layer = jax.tree.map(lambda a: a[None], stacks), 0
+
+    with jax.named_scope("moe.dispatch"):
+        x_e = jnp.zeros((plan["n_rows"], d), xf.dtype).at[row].set(
+            jnp.repeat(xf, k, axis=0), mode="drop")
 
     with jax.named_scope("moe.experts"):
         gmm = functools.partial(
-            pallas_moe.grouped_matmul, tile_expert=tile_expert, n_used=n_used,
-            layer=layer, tm=tm, use_kernel=cfg.use_pallas_decode)
-        act = swiglu(gmm(x_e, stacks["w_gate"]), gmm(x_e, stacks["w_up"]),
-                     cfg.gelu_mlp)
+            pallas_moe.grouped_matmul, tile_expert=plan["tile_expert"],
+            n_used=plan["n_used"], layer=layer, tm=plan["tm"],
+            use_kernel=cfg.use_pallas_decode)
+        act = gated(gmm(x_e, stacks["w_gate"]), gmm(x_e, stacks["w_up"]),
+                    cfg.mlp_activation)
         out_e = gmm(act, stacks["w_down"])  # [n_rows, D]
 
     with jax.named_scope("moe.dispatch"):  # the way back: gather + mix
@@ -480,9 +548,9 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x, live=None):
     if "ws_gate" in lp:
         with jax.named_scope("moe.shared"):
             y = y + q_matmul(
-                swiglu(q_matmul(xf, lp["ws_gate"]), q_matmul(xf, lp["ws_up"]),
-                       cfg.gelu_mlp), lp["ws_down"])
-    return y.reshape(orig_shape), tally
+                gated(q_matmul(xf, lp["ws_gate"]), q_matmul(xf, lp["ws_up"]),
+                      cfg.mlp_activation), lp["ws_down"])
+    return y.reshape(orig_shape), plan["tally"]
 
 
 def _layer_xs(layers: Params) -> tuple[Params, Params | None]:
@@ -514,12 +582,20 @@ def _layer_groups(params: Params) -> list[Params]:
     return [params["layers"]]
 
 
-def _scan_groups(params: Params, lora_bufs: Params | None, carry, body):
+def _scan_groups(cfg: ModelConfig, params: Params, lora_bufs: Params | None,
+                 carry, body):
     """``lax.scan`` over each group of layers in turn, one carry through
-    all.  ``body(carry, layer, lp, layer_lora) -> (carry, ys)``: ``layer``
-    counts through the whole model, ``lp`` is the layer's params with a
-    sparse group's expert stacks beside their index (``_layer_lp``).
-    Returns (carry, [each group's stacked ys])."""
+    all.  ``body(carry, layer, lp, layer_lora, kind, lane) -> (carry, ys)``:
+    ``layer`` counts through the whole model, ``lp`` is the layer's params
+    with a sparse group's expert stacks beside their index (``_layer_lp``),
+    ``kind`` its ``LayerKind`` and ``lane`` its index among the layers that
+    share its kind of cache (``layer`` itself for a model of one kind).
+    Returns (carry, [each group's stacked ys]).
+
+    A model whose stack repeats a PERIOD of kinds (``cfg.layer_kinds``)
+    scans periods: the scanned leaves are viewed [periods, period, ...],
+    one step runs the period's layers one after another, each traced as its
+    own kind, and the ys come back one a layer as from a scan of layers."""
     per_layer_lora = None
     if lora_bufs is not None:
         per_layer_lora, _ = lora_lib.stack_for_scan(lora_bufs)
@@ -527,20 +603,53 @@ def _scan_groups(params: Params, lora_bufs: Params | None, carry, body):
     if len(groups) > 1 and lora_bufs is not None:
         raise NotImplementedError(
             "LoRA buffers are one stack: not over two kinds of layers")
+    kinds = cfg.layer_kinds
+    period = len(kinds)
+    # A layer's place among the layers that share its kind of cache (ring
+    # lanes or full lanes): how many of them a period holds, and how many
+    # come before it there.
+    ringed = [bool(k.window) for k in kinds]
+    per_period = [ringed.count(r) for r in ringed]
+    before = [ringed[:j].count(r) for j, r in enumerate(ringed)]
+    if period > 1 and lora_bufs is not None:
+        raise NotImplementedError(
+            "LoRA buffers are scanned a layer a step: not over a period of "
+            "layer kinds")
     first, ys = 0, []
     for layers in groups:
         scanned, stacks = _layer_xs(layers)
+        n = layers["attn_norm"].shape[0]
 
         def step(carry, xs, stacks=stacks, first=first):
             lp, ll, i = xs
             layer_lora = (None if ll is None
                           else {**ll, "scale": lora_bufs["scale"]})
-            return body(carry, first + i if first else i,
-                        _layer_lp(lp, stacks, i), layer_lora)
+            layer = first + i if first else i
+            return body(carry, layer, _layer_lp(lp, stacks, i), layer_lora,
+                        kinds[0], layer)
 
-        n = layers["attn_norm"].shape[0]
-        carry, y = jax.lax.scan(
-            step, carry, (scanned, per_layer_lora, jnp.arange(n)))
+        def period_step(carry, xs, stacks=stacks):
+            lps, _, i = xs
+            ys = []
+            for j, kind in enumerate(kinds):
+                layer = i * period + j
+                lane = i * per_period[j] + before[j]
+                lp = jax.tree.map(lambda a, j=j: a[j], lps)
+                carry, y = body(carry, layer, _layer_lp(lp, stacks, layer),
+                                None, kind, lane)
+                ys.append(y)
+            return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+
+        if period > 1:
+            scanned = jax.tree.map(
+                lambda a: a.reshape(n // period, period, *a.shape[1:]),
+                scanned)
+            carry, y = jax.lax.scan(
+                period_step, carry, (scanned, None, jnp.arange(n // period)))
+            y = jax.tree.map(lambda a: a.reshape(n, *a.shape[2:]), y)
+        else:
+            carry, y = jax.lax.scan(
+                step, carry, (scanned, per_layer_lora, jnp.arange(n)))
         ys.append(y)
         first += n
     return carry, ys
@@ -599,13 +708,18 @@ def prefill_layer(
     slot_ids: jax.Array | None = None,  # [B] int32, -1 = base model
     attention_fn=None,
     live: jax.Array | None = None,  # [B, S] bool — positions of the prompt
+    kind: LayerKind = LayerKind(),
 ):
     """One decoder block over a full sequence.  Returns (h, (k, v, tally)),
     ``tally`` the sparse layer's routing counts (None for a dense model).
     A parallel block (``cfg.ssm_d_inner``) hands back, in ``v``'s place,
     ``{"v", "ssm", "conv"}``: the values and what the mixer's recurrence
     leaves after each row's last true position (``live``; all of them
-    without it), which ``insert_prefill`` installs together.
+    without it), which ``insert_prefill`` installs together.  ``kind`` is
+    the layer's place in a period of kinds: a "nope" layer rotates nothing,
+    a window layer of a prompt longer than its window masks by it (the XLA
+    form: the flash kernel is causal only, and the serving buckets are
+    shorter than a served window).
 
     The single source of truth for the prefill block: ``prefill`` scans it
     over the stacked layer params, and ``parallel.pipeline`` scans each
@@ -614,6 +728,7 @@ def prefill_layer(
     b, s, _ = h.shape
     if slot_ids is None:
         slot_ids = jnp.full((b,), -1, jnp.int32)
+    plan = _route_early(cfg, lp, h, live)
     hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     if cfg.latent_width:
         # "k" is the layer's latent rows, keys and values both; "v" is empty.
@@ -625,14 +740,23 @@ def prefill_layer(
     q = _attn_proj(cfg, lp, "q", ha, layer_lora, slot_ids).reshape(b, s, cfg.n_heads, hd)
     k = _attn_proj(cfg, lp, "k", ha, layer_lora, slot_ids).reshape(b, s, cfg.n_kv_heads, hd)
     v = _attn_proj(cfg, lp, "v", ha, layer_lora, slot_ids).reshape(b, s, cfg.n_kv_heads, hd)
-    q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-    k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+    if kind.rope:
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     mixed = None
     if cfg.ssm_d_inner:
         mixed, state, tail = ssm.prompt_mix(cfg, lp, hn, live)
-    with jax.named_scope("attn.core"):
+    windowed = 0 < kind.window < s
+    if windowed and attention_fn is not None:
+        raise NotImplementedError(
+            "an attention override (--mesh) knows no sliding window")
+    with _core_scope(kind):
         if attention_fn is not None:
             attn = attention_fn(q, k, v, positions)
+        elif windowed:
+            log_choice("flash_prefill", f"q{tuple(q.shape)}",
+                       f"window {kind.window} < s: the kernel is causal only")
+            attn = prefill_attention(q, k, v, positions, kind.window)
         elif cfg.use_flash_attention:
             # Right-padded batches: causal tiling alone keeps real positions
             # exact (pallas_attention.flash_attention docstring).
@@ -647,7 +771,7 @@ def prefill_layer(
         cfg, _attn_out(lp, attn.reshape(b, s, -1), layer_lora, slot_ids),
         mixed)
     hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-    y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live)
+    y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live, plan=plan)
     if cfg.ssm_d_inner:
         v = {"v": v, "ssm": state, "conv": tail}
     return h + y, (k, v, tally)
@@ -686,14 +810,14 @@ def prefill(
     live = (None if lengths is None
             else jnp.arange(s)[None] < jnp.reshape(lengths, (-1, 1)))
 
-    def layer_fn(h, layer, lp, layer_lora):
+    def layer_fn(h, layer, lp, layer_lora, kind, lane):
         return prefill_layer(
             cfg, lp, h, positions,
             layer_lora=layer_lora, slot_ids=slot_ids,
-            attention_fn=attention_fn, live=live,
+            attention_fn=attention_fn, live=live, kind=kind,
         )
 
-    h, ys = _scan_groups(params, lora_bufs, h, layer_fn)
+    h, ys = _scan_groups(cfg, params, lora_bufs, h, layer_fn)
     k_all, v_all, tally = (_stacked(list(part)) for part in zip(*ys))
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     logits = _lm_head(cfg, params, h)
@@ -720,12 +844,16 @@ def prefill(
 def _carry_names(cache: Params) -> tuple[str, ...]:
     """The cache's stacked arrays that ride the layer loop, in the carry's
     order: (k, v) [L, B, S, K, hd], then (k_scale, v_scale) [L, B, S, K]
-    of an int8 cache, or a mixer's recurrent (ssm, conv); a latent cache's
-    rows alone (they are keys and values)."""
+    of an int8 cache, or a mixer's recurrent (ssm, conv), or a window
+    model's ring lanes (k_win, v_win) [L_window, B, ring, K, hd], k and v
+    then being its full layers' alone; a latent cache's rows alone (they
+    are keys and values)."""
     if "v" not in cache:
         return ("k",)
     if "ssm" in cache:
         return ("k", "v", "ssm", "conv")
+    if "k_win" in cache:
+        return ("k", "v", "k_win", "v_win")
     return ("k", "v") + (("k_scale", "v_scale") if "k_scale" in cache else ())
 
 
@@ -770,26 +898,47 @@ def _layer_view(kv: tuple, layer, dtype) -> tuple[jax.Array, jax.Array]:
     return k, v
 
 
-def _scan_cached_layers(params: Params, cache: Params,
+def _scan_cached_layers(cfg: ModelConfig, params: Params, cache: Params,
                         lora_bufs: Params | None, h: jax.Array, layer_fn):
     """The cached programs' layer loop.  xs: the stacked layer params, the
     LoRA stack and the layer index; carry: the activations and the stacked
-    cache.  ``layer_fn(h, kv, layer, lp, layer_lora) -> (h, kv, tally)``;
-    returns (h, kv, the layers' tallies stacked or None)."""
-    def body(carry, layer, lp, layer_lora):
-        h, kv, tally = layer_fn(*carry, layer, lp, layer_lora)
+    cache.  ``layer_fn(h, kv, layer, lp, layer_lora, kind, lane) -> (h, kv,
+    tally)`` (``_scan_groups``); returns (h, kv, the layers' tallies stacked
+    or None)."""
+    def body(carry, *layer_args):
+        h, kv, tally = layer_fn(*carry, *layer_args)
         return (h, kv), tally
 
     (h, kv), tallies = _scan_groups(
-        params, lora_bufs, (h, _kv_carry(cache)), body)
+        cfg, params, lora_bufs, (h, _kv_carry(cache)), body)
     return h, kv, _stacked(tallies)
 
 
-@jax.named_scope("attn.core")
-def _decode_attend(cfg: ModelConfig, attention_fn, q, kv, layer, lengths):
+def _own_lanes(cfg: ModelConfig, kv: tuple, kind: LayerKind):
+    """The carry's attention arrays as (this layer's own stacks, a function
+    that lays the written stacks back into the carry's order).  A model of
+    one kind owns them all; a window model's carry is (k, v, k_win, v_win),
+    of which a window layer owns the rings and a full layer the rest."""
+    if not cfg.sliding_window:
+        return kv, lambda own: own
+    if kind.window:
+        return kv[2:], lambda own: kv[:2] + own
+    return kv[:2], lambda own: own + kv[2:]
+
+
+def _decode_attend(cfg: ModelConfig, attention_fn, q, kv, layer, lengths,
+                   kind: LayerKind = LayerKind()):
     """One layer's cached attention over the lanes just written: which
     implementation reads them.  The kernel reads ``layer`` of the stacked
-    carry in place; everything else gets that layer's view."""
+    carry in place; everything else gets that layer's view.  Over a window
+    layer's ring lanes ``lengths`` are the positions the ring holds, all of
+    them inside the window."""
+    with _core_scope(kind):
+        return _attend_cached(cfg, attention_fn, q, kv, layer, lengths,
+                              bool(kind.window))
+
+
+def _attend_cached(cfg, attention_fn, q, kv, layer, lengths, ring: bool):
     quant = len(kv) == 4
     if attention_fn is None and cfg.use_pallas_decode:
         from llm_instance_gateway_tpu.ops import pallas_decode_attention as pda
@@ -797,8 +946,9 @@ def _decode_attend(cfg: ModelConfig, attention_fn, q, kv, layer, lengths):
         # The int8-aware kernel dequantizes in VMEM at the MXU feed, so HBM
         # streams half the bytes of the bf16 kernel.  (Both fall back to
         # XLA by themselves off-TPU and on unsupported shapes.)
-        attend = pda.decode_attention_quant if quant else pda.decode_attention
-        return attend(q, *kv, lengths, layer=layer)
+        if quant:
+            return pda.decode_attention_quant(q, *kv, lengths, layer=layer)
+        return pda.decode_attention(q, *kv, lengths, layer=layer, ring=ring)
     if quant and getattr(attention_fn, "quant_aware", False):
         # Quant-aware override (sharded_attention.make_cached_decode_quant):
         # raw int8 + scales go in; each shard's kernel dequantizes in VMEM,
@@ -862,8 +1012,16 @@ def decode_step(
     # out of bounds, so inactive rows' updates are dropped whole.
     write_pos = (positions if active is None
                  else jnp.where(active, positions, s_max))
+    if "k_win" in cache:
+        # A window layer's ring: position p lies at p mod ring, so after
+        # this step's write a row's ring holds its last min(p + 1, ring)
+        # positions, each inside the window, and the attention reads that
+        # many of them in the order they lie.
+        ring = cache["k_win"].shape[2]
+        ring_pos = jnp.where(write_pos < s_max, positions % ring, ring)
+        ring_lengths = jnp.minimum(read_lengths, ring)
 
-    def latent_layer_fn(h, kv, layer, lp, layer_lora):
+    def latent_layer_fn(h, kv, layer, lp, layer_lora, kind, lane):
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         attn, kv = mla.decode_attend(
             cfg, lp, hn, positions, kv, (layer, batch_idx, write_pos),
@@ -872,17 +1030,23 @@ def decode_step(
                                        slot_ids, active, (kv,))
         return h, kv, tally
 
-    def layer_fn(h, kv, layer, lp, layer_lora):
+    def layer_fn(h, kv, layer, lp, layer_lora, kind, lane):
         kv, rec = _split_carry(kv, n_rec)
+        own, put_back = _own_lanes(cfg, kv, kind)
+        plan = _route_early(cfg, lp, h, active)
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         ha = _attn_in(cfg, hn)
         q = _attn_proj(cfg, lp, "q", ha, layer_lora, slot_ids).reshape(b, cfg.n_heads, hd)
         k = _attn_proj(cfg, lp, "k", ha, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
         v = _attn_proj(cfg, lp, "v", ha, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
-        q = apply_rope(q[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
-        k = apply_rope(k[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
-        kv = _write_kv(kv, (layer, batch_idx, write_pos), k, v)
-        attn = _decode_attend(cfg, attention_fn, q, kv, layer, read_lengths)
+        if kind.rope:
+            q = apply_rope(q[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
+            k = apply_rope(k[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
+        at, held = ((ring_pos, ring_lengths) if kind.window
+                    else (write_pos, read_lengths))
+        own = _write_kv(own, (lane, batch_idx, at), k, v)
+        attn = _decode_attend(cfg, attention_fn, q, own, lane, held, kind)
+        kv = put_back(own)
         mixed = None
         if rec:
             mixed, rec = ssm.decode_mix(cfg, lp, hn, rec, layer, active)
@@ -890,11 +1054,12 @@ def decode_step(
             cfg, _attn_out(lp, attn.reshape(b, -1), layer_lora, slot_ids),
             mixed)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=active)
+        y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=active,
+                        plan=plan)
         return h + y, kv + rec, tally
 
     h, kv, tally = _scan_cached_layers(
-        params, cache, lora_bufs, h,
+        cfg, params, cache, lora_bufs, h,
         latent_layer_fn if cfg.latent_width else layer_fn)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     logits = _lm_head(cfg, params, h)
@@ -931,6 +1096,11 @@ def extend_step(
         raise NotImplementedError(
             "extend_step (speculative verify) is not served over a "
             "recurrent state: a rejected draft would need it rolled back")
+    if cfg.sliding_window:
+        raise NotImplementedError(
+            "extend_step (speculative verify) is not served over ring "
+            "lanes: a rejected draft has overwritten positions the window "
+            "still needs")
     b, c = tokens.shape
     hd = cfg.resolved_head_dim
     s_max = cache["k"].shape[2]
@@ -943,7 +1113,8 @@ def extend_step(
                  else jnp.where(active[:, None], positions, s_max))
     live = None if active is None else jnp.broadcast_to(active[:, None], (b, c))
 
-    def layer_fn(h, kv, layer, lp, layer_lora):
+    def layer_fn(h, kv, layer, lp, layer_lora, kind, lane):
+        plan = _route_early(cfg, lp, h, live)
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         q = _attn_proj(cfg, lp, "q", hn, layer_lora, slot_ids).reshape(
             b, c, cfg.n_heads, hd)
@@ -951,8 +1122,9 @@ def extend_step(
             b, c, cfg.n_kv_heads, hd)
         v = _attn_proj(cfg, lp, "v", hn, layer_lora, slot_ids).reshape(
             b, c, cfg.n_kv_heads, hd)
-        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+        if kind.rope:
+            q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+            k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
         kv = _write_kv(kv, (layer, batch_idx, write_pos), k, v)
         with jax.named_scope("attn.core"):
             k_read, v_read = _layer_view(kv, layer, h.dtype)
@@ -969,13 +1141,54 @@ def extend_step(
                 "bkgij,bjkh->bikgh", probs, v_read).reshape(b, c, -1)
         h = h + _attn_out(lp, attn, layer_lora, slot_ids)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live)
+        y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live,
+                        plan=plan)
         return h + y, kv, tally
 
-    h, kv, tally = _scan_cached_layers(params, cache, lora_bufs, h, layer_fn)
+    h, kv, tally = _scan_cached_layers(cfg, params, cache, lora_bufs, h,
+                                       layer_fn)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     logits = _lm_head(cfg, params, h)
     return logits, _cache_of(cache, kv, positions[:, -1] + 1, tally)
+
+
+def _ring_chunk(cfg: ModelConfig, q, k, v, own: tuple, lane, slot,
+                positions, live, kind: LayerKind):
+    """One chunk of a streamed prompt through a WINDOW layer: the chunk's
+    queries ``q`` [1, C, H, hd] against the slot's ring as it stood and the
+    chunk's own keys ``k``/``v`` [C, K, hd], which go into the ring only
+    afterwards.  Returns (attention output [1, C, H*hd], the written rings).
+
+    The ring (position p at p mod ring) is laid out in position order in
+    front of the chunk's keys: before it has wrapped, slot s holds position
+    s and the chunk goes in at index ``start``, over cells this prompt has
+    not reached; once it has, the roll brings the oldest held position
+    (``start`` - ring, at ``start`` mod ring) to index 0 and the chunk
+    follows the ring's end.  What of the ring lies behind a query's window
+    the mask drops, and a slot's last request is never read: every index
+    before the chunk's is a position this prompt wrote.  The padding of a
+    final chunk (``live`` false) is written nowhere: in a ring it would land
+    on positions the window still holds."""
+    ring = own[0].shape[2]
+    c = k.shape[0]
+    start = positions[0]
+    held = jnp.minimum(start, ring)  # the chunk's index in position order
+    shift = jnp.where(start > ring, start % ring, 0)
+
+    def in_order(x, new):
+        lane_x = jax.lax.dynamic_index_in_dim(
+            jax.lax.dynamic_index_in_dim(x, lane, 0, keepdims=False),
+            slot, 0, keepdims=False)  # [ring, K, hd]
+        buf = jnp.concatenate(
+            [jnp.roll(lane_x, -shift, axis=0),
+             jnp.zeros((c, *lane_x.shape[1:]), lane_x.dtype)])
+        return jax.lax.dynamic_update_slice(
+            buf, new.astype(buf.dtype), (held, 0, 0))
+
+    attn = _chunk_attend(cfg, False, q, in_order(own[0], k),
+                         in_order(own[1], v), held, kind)
+    at = jnp.where(live, positions % ring, ring)  # out of bounds: dropped
+    return attn, _write_kv(own, (lane, slot, at), k, v)
 
 
 def prefill_with_cache(
@@ -1000,7 +1213,10 @@ def prefill_with_cache(
     exactly (parity-tested) while compiling only one chunk-sized program.
     A mixer's recurrent state and conv history ride the slot's lane from
     chunk to chunk the same way (``ssm.chunk_mix``; a chunk at position 0
-    starts from zeros, whatever the lane held).
+    starts from zeros, whatever the lane held).  A window layer's chunk
+    attends over its ring AS IT STOOD plus its own keys and is written
+    afterwards (``_ring_chunk``): written first, a chunk would overwrite
+    positions its own first queries still need.
 
     A padded final chunk passes pad positions CONTINUING past the prompt
     (start+i): pads scatter into unused cells beyond ``lane_end`` (masked by
@@ -1017,7 +1233,7 @@ def prefill_with_cache(
     quant = "k_scale" in cache
     live = (jnp.arange(c) <= last_index)[None]  # the final chunk's padding
 
-    def latent_layer_fn(h, kv, layer, lp, layer_lora):
+    def latent_layer_fn(h, kv, layer, lp, layer_lora, kind, lane):
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         attn, kv = mla.chunk_attend(
             cfg, lp, hn, positions, kv, layer, slot,
@@ -1028,26 +1244,38 @@ def prefill_with_cache(
 
     n_rec = 2 if "ssm" in cache else 0  # a mixer's (ssm, conv) in the carry
 
-    def layer_fn(h, kv, layer, lp, layer_lora):
+    def layer_fn(h, kv, layer, lp, layer_lora, kind, lane):
         kv, rec = _split_carry(kv, n_rec)
+        own, put_back = _own_lanes(cfg, kv, kind)
+        plan = _route_early(cfg, lp, h, live)
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
         ha = _attn_in(cfg, hn)
         q = _attn_proj(cfg, lp, "q", ha, layer_lora, slot_ids).reshape(1, c, cfg.n_heads, hd)
         k = _attn_proj(cfg, lp, "k", ha, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
         v = _attn_proj(cfg, lp, "v", ha, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
-        q = apply_rope(q, pos2d, cfg.rope_theta, cfg.rope_scaling)
-        k = apply_rope(k, pos2d, cfg.rope_theta, cfg.rope_scaling)
-        # Scatter the chunk's K/V into the slot's lane at absolute positions.
-        kv = _write_kv(kv, (layer, slot, positions), k[0], v[0])
-        # Chunk queries vs the whole lane, masked to index <= q position:
-        # the one lane [S, K, hd] of this layer is sliced out of the carry.
-        lane_k, lane_v = _layer_view(
-            tuple(jax.lax.dynamic_index_in_dim(x, slot, 1, keepdims=False)
-                  for x in kv), layer, h.dtype)
-        # Flash-style chunk attend: no [C, S_max] logits materialize, and
-        # K blocks past the chunk's reach elide their DMAs — bandwidth
-        # tracks the prompt's progress, not S_max (_chunk_attend).
-        attn = _chunk_attend(cfg, quant, q, lane_k, lane_v, positions[0])
+        if kind.rope:
+            q = apply_rope(q, pos2d, cfg.rope_theta, cfg.rope_scaling)
+            k = apply_rope(k, pos2d, cfg.rope_theta, cfg.rope_scaling)
+        if kind.window:
+            attn, own = _ring_chunk(cfg, q, k[0], v[0], own, lane, slot,
+                                    positions, live[0], kind)
+        else:
+            # Scatter the chunk's K/V into the slot's lane at absolute
+            # positions.
+            own = _write_kv(own, (lane, slot, positions), k[0], v[0])
+            # Chunk queries vs the whole lane, masked to index <= q
+            # position: the one lane [S, K, hd] of this layer is sliced out
+            # of the carry.
+            lane_k, lane_v = _layer_view(
+                tuple(jax.lax.dynamic_index_in_dim(x, slot, 1, keepdims=False)
+                      for x in own), lane, h.dtype)
+            # Flash-style chunk attend: no [C, S_max] logits materialize,
+            # and K blocks past the chunk's reach elide their DMAs —
+            # bandwidth tracks the prompt's progress, not S_max
+            # (_chunk_attend).
+            attn = _chunk_attend(cfg, quant, q, lane_k, lane_v, positions[0],
+                                 kind)
+        kv = put_back(own)
         mixed = None
         if rec:
             # The slot's lane holds what the chunks before this one left.
@@ -1056,11 +1284,12 @@ def prefill_with_cache(
         h = h + _branches(cfg, _attn_out(lp, attn, layer_lora, slot_ids),
                           mixed)
         hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
-        y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live)
+        y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live,
+                        plan=plan)
         return h + y, kv + rec, tally
 
     h, kv, tally = _scan_cached_layers(
-        params, cache, lora_bufs, h,
+        cfg, params, cache, lora_bufs, h,
         latent_layer_fn if cfg.latent_width else layer_fn)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     last_h = jax.lax.dynamic_index_in_dim(h[0], last_index, 0, keepdims=False)
@@ -1076,11 +1305,35 @@ def insert_prefill(
     v_prompt: jax.Array,
     slot: jax.Array | int,
     length: jax.Array | int,
+    cfg: ModelConfig | None = None,
 ) -> Params:
     """Insert a prefilled sequence's KV into a decode slot (JetStream-style
     prefill->insert->generate).  ``length`` is the true prompt length; the
     padded tail beyond it is garbage but masked by ``cache['length']``.
+    ``cfg`` is needed by a window model alone, whose prompt's layers part
+    by kind: the full layers' into ``k``/``v`` as ever, the window layers'
+    into the rings, ring slot s taking the newest position p < ``length``
+    with p mod ring = s (for a prompt shorter than the ring, position s).
     """
+    if "k_win" in cache:
+        kinds = cfg.layer_kinds
+        windowed = [bool(kinds[l % len(kinds)].window)
+                    for l in range(k_prompt.shape[0])]
+        full = jnp.asarray([l for l, w in enumerate(windowed) if not w])
+        win = jnp.asarray([l for l, w in enumerate(windowed) if w])
+        lanes = insert_prefill(
+            {"k": cache["k"], "v": cache["v"], "length": cache["length"]},
+            k_prompt[full], v_prompt[full], slot, length)
+        ring = cache["k_win"].shape[2]
+        cell = jnp.arange(ring)
+        newest = length - 1 - (length - 1 - cell) % ring
+        newest = jnp.clip(newest, 0, k_prompt.shape[2] - 1)
+        rings = {
+            name: jax.lax.dynamic_update_slice(
+                cache[name], prompt[win][:, :, newest].astype(
+                    cache[name].dtype), (0, slot, 0, 0, 0))
+            for name, prompt in (("k_win", k_prompt), ("v_win", v_prompt))}
+        return {**lanes, **rings}
     k = cache["k"]
     if "v" not in cache:  # a latent cache: k_prompt [L, 1, S, lanes]
         k = jax.lax.dynamic_update_slice(

@@ -55,11 +55,13 @@ def xla_chunk_attention(
     k_cache: jax.Array,  # [B, S_max, n_kv, hd] — incl. the chunk's own KV
     v_cache: jax.Array,
     start,               # scalar int32: global position of chunk token 0
+    window: int = 0,     # > 0: and only positions > start+i - window
 ) -> jax.Array:
     """Chunk-vs-cache attention reference: chunk token i (global position
-    start+i) attends cache positions <= start+i.  The chunk-stream prefill
-    hot op; ``pallas_attention.chunk_attention`` auto-dispatches between
-    this and the flash-style kernel.  Returns [B, C, n_heads, hd]."""
+    start+i) attends cache positions <= start+i, with ``window`` the last
+    ``window`` of them, its own included.  The chunk-stream prefill hot op;
+    ``pallas_attention.chunk_attention`` auto-dispatches between this and
+    the flash-style kernel.  Returns [B, C, n_heads, hd]."""
     b, c, n_heads, hd = q.shape
     s_max, n_kv = k_cache.shape[1], k_cache.shape[2]
     g = n_heads // n_kv
@@ -68,7 +70,10 @@ def xla_chunk_attention(
     logits = jnp.einsum("bikgh,bjkh->bkgij", qg, k_cache,
                         preferred_element_type=jnp.float32) * scale
     q_pos = jnp.asarray(start, jnp.int32) + jnp.arange(c)
-    mask = jnp.arange(s_max)[None, :] <= q_pos[:, None]  # [C, S]
+    behind = q_pos[:, None] - jnp.arange(s_max)[None, :]  # [C, S]
+    mask = behind >= 0
+    if window:
+        mask &= behind < window
     logits = jnp.where(mask[None, None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgij,bjkh->bikgh", probs, v_cache)
@@ -96,12 +101,14 @@ def prefill_attention(
     k: jax.Array,  # [B, S, n_kv, hd]
     v: jax.Array,  # [B, S, n_kv, hd]
     positions: jax.Array | None = None,  # [B, S] for packed/padded masking
+    window: int = 0,
 ) -> jax.Array:
     """Causal self-attention over a full prompt.  Returns [B, S, n_heads, hd].
 
     With ``positions`` given, token i attends to j iff positions[j] <=
     positions[i] AND j <= i — correct for right-padded and left-packed
-    batches alike.
+    batches alike.  ``window`` > 0 (a sliding-window layer): and only iff
+    i - j < window, over the indices of a prompt that starts at position 0.
     """
     b, s, n_heads, hd = q.shape
     n_kv = k.shape[2]
@@ -111,6 +118,8 @@ def prefill_attention(
     logits = jnp.einsum("bikgh,bjkh->bkgij", qg, k, preferred_element_type=jnp.float32)
     logits *= scale
     causal = jnp.tril(jnp.ones((s, s), dtype=bool))
+    if window:
+        causal &= ~jnp.tril(jnp.ones((s, s), dtype=bool), -window)
     mask = causal[None, None, None]
     if positions is not None:
         valid = positions[:, None, :] <= positions[:, :, None]  # [B,S_i,S_j]

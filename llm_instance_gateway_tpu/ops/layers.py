@@ -76,7 +76,15 @@ def scaled(x: jax.Array, multiplier: float) -> jax.Array:
     return x * jnp.asarray(multiplier, x.dtype)
 
 
-def swiglu(gate: jax.Array, up: jax.Array, gelu: bool = False) -> jax.Array:
-    """Gated MLP activation: SiLU (Llama/Mixtral) or tanh-GeLU (Gemma)."""
-    act = jax.nn.gelu(gate, approximate=True) if gelu else jax.nn.silu(gate)
+def gated(gate: jax.Array, up: jax.Array,
+          activation: str = "silu") -> jax.Array:
+    """Gated MLP activation ``act(gate) * up``: SiLU (Llama/Mixtral),
+    tanh-GeLU (Gemma) or ReLU (SmallThinker's ReGLU), by
+    ``ModelConfig.mlp_activation``."""
+    if activation == "gelu":
+        act = jax.nn.gelu(gate, approximate=True)
+    elif activation == "relu":
+        act = jax.nn.relu(gate)
+    else:
+        act = jax.nn.silu(gate)
     return act * up

@@ -43,29 +43,46 @@ NEG_INF = -1e30
 
 BLOCK_Q = 128
 BLOCK_K = 128
+# The chunk attend's tiles where the shapes allow them: a [256, 512] tile of
+# scores is 16 of the [128, 128] ones, and a grid step costs ~0.35 us whether
+# it computes or is skipped; at [128, 128] a 1,024-token chunk against a
+# 16,384-position lane is 28,672 steps a layer (device trace, PR 45: 5.7 ms
+# a full layer, 3.7 a window layer, half of a 91 ms chunk program).
+CHUNK_BLOCK_Q = 256
+CHUNK_BLOCK_K = 512
 
 
 def _softmax_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
-                   q_start, k_start, masked: bool, scale: float):
+                   q_start, k_start, masked: bool, scale: float,
+                   window: int = 0):
     """One K/V tile of the online-softmax recurrence — the numerically
     sensitive core shared by the self-attention flash kernel (static
-    q_start) and the chunk-attend kernel (dynamic, offset q_start)."""
+    q_start) and the chunk-attend kernel (dynamic, offset q_start).  A
+    ``masked`` tile keeps the keys at or before each query and, with
+    ``window``, fewer than ``window`` positions behind it."""
     def go():
         bq = q_ref.shape[2]
         block_k = k_ref.shape[2]
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
+        # The tiles feed the MXU in their storage dtype with float32
+        # accumulation, as the decode kernel's do: a product of two bf16
+        # numbers is exact in float32, so the scores are those of float32
+        # operands, at the MXU's bf16 rate (float32 operands took 3.7 ms a
+        # window layer a 1,024-token chunk at SmallThinker's layout: device
+        # trace, PR 45).  Float32 tiles (the CPU's tests) stay float32.
+        q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [BQ, BK]
+        ) * scale  # [BQ, BK]
         if masked:
             q_pos = q_start + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, block_k), 0)
             k_pos = k_start + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            keep = q_pos >= k_pos
+            if window:
+                keep &= q_pos - k_pos < window
+            s = jnp.where(keep, s, NEG_INF)
         m_prev = m_scr[:, :1]
         l_prev = l_scr[:, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -73,8 +90,9 @@ def _softmax_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
         corr = jnp.exp(m_prev - m_new)
         l_scr[...] = jnp.broadcast_to(
             l_prev * corr + p.sum(axis=-1, keepdims=True), l_scr.shape)
+        # The weights go to the values' dtype, as the XLA forms' do.
         acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
@@ -206,13 +224,16 @@ def supports(s: int, hd: int, block_q: int = BLOCK_Q, block_k: int = BLOCK_K) ->
 
 
 def _chunk_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                  *, scale: float):
+                  *, scale: float, window: int = 0):
     # Same online-softmax core as _flash_kernel (shared _softmax_block)
     # with ONE difference: query positions are offset by the chunk's
     # dynamic start (off_ref, SMEM) — chunk token i sits at global
     # position off + q_start + i and attends cache positions <= it.
     # K blocks wholly above the chunk's last position skip compute
-    # (their DMA is elided by the index-map clamp).
+    # (their DMA is elided by the index-map clamp).  With ``window`` a query
+    # attends only the last ``window`` positions, its own included: K blocks
+    # wholly behind every query's window skip compute and DMA the same way,
+    # and a block the window's far edge cuts through takes the mask.
     qi = pl.program_id(2)
     kb = pl.program_id(3)
     n_kblocks = pl.num_programs(3)
@@ -229,12 +250,22 @@ def _chunk_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
     def _compute(masked: bool):
         return _softmax_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
-                              q_start, k_start, masked, scale)
+                              q_start, k_start, masked, scale, window)
 
-    # Dynamic diagonal (off is a runtime value): exactly one branch fires.
+    # Dynamic diagonal (off is a runtime value): at most one branch fires.
     on_diagonal = (k_start + block_k > q_start) & (k_start < q_start + bq)
-    pl.when(on_diagonal)(_compute(masked=True))
-    pl.when(k_start + block_k <= q_start)(_compute(masked=False))
+    below = k_start + block_k <= q_start
+    if window:
+        # The block's newest key lies ``window`` or more behind the tile's
+        # oldest query: nothing of it is attended.
+        dead = k_start + block_k - 1 + window <= q_start
+        # Its oldest key lies that far behind the tile's newest query.
+        edge = q_start + bq - 1 - k_start >= window
+        pl.when(on_diagonal | (below & edge & ~dead))(_compute(masked=True))
+        pl.when(below & ~edge)(_compute(masked=False))
+    else:
+        pl.when(on_diagonal)(_compute(masked=True))
+        pl.when(below)(_compute(masked=False))
 
     @pl.when(kb == n_kblocks - 1)
     def _finalize():
@@ -251,9 +282,11 @@ def chunk_attention_pallas(
     block_q: int = BLOCK_Q,
     block_k: int = BLOCK_K,
     interpret: bool = False,
+    window: int = 0,
 ) -> jax.Array:
     """Flash-style chunk attend: chunk token i (global position start+i)
-    attends cache positions <= start+i.  Replaces the XLA einsum's [C, S]
+    attends cache positions <= start+i (with ``window``: the last
+    ``window`` of them, its own included).  Replaces the XLA einsum's [C, S]
     logits materialization on the chunk-stream path — the long-context
     TTFT hot loop — with O(block) VMEM tiles; K blocks past each query
     tile's reach are clamped to the last contributing tile so their HBM
@@ -279,11 +312,16 @@ def chunk_attention_pallas(
         return (bi, 0, qi, hi)
 
     def kv_index(bi, hi, qi, kb, off, g=g):
-        last = (off[0] + qi * block_q + block_q - 1) // block_k
-        return (bi, 0, jnp.minimum(kb, last), hi // g)
+        q_first = off[0] + qi * block_q
+        last = (q_first + block_q - 1) // block_k
+        tile = jnp.minimum(kb, last)
+        if window:  # blocks behind the window hold the first one inside it
+            tile = jnp.maximum(
+                tile, jnp.maximum(q_first - window + 1, 0) // block_k)
+        return (bi, 0, tile, hi // g)
 
     out = pl.pallas_call(
-        functools.partial(_chunk_kernel, scale=scale),
+        functools.partial(_chunk_kernel, scale=scale, window=window),
         out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,  # chunk start: masking + DMA clamping
@@ -327,7 +365,7 @@ def supports_chunk(c: int, s_max: int, hd: int) -> bool:
 
 def chunk_attention(
     q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, start,
-    interpret: bool = False,
+    interpret: bool = False, window: int = 0,
 ) -> jax.Array:
     """Dispatch for the chunk attend; XLA reference otherwise."""
     from llm_instance_gateway_tpu.ops.attention import xla_chunk_attention
@@ -339,9 +377,13 @@ def chunk_attention(
         "chunk_attend", f"q{tuple(q.shape)} cache{tuple(k_cache.shape)}",
         reason, interpret)
     if reason is not None:
-        return xla_chunk_attention(q, k_cache, v_cache, start)
-    return chunk_attention_pallas(q, k_cache, v_cache, start,
-                                  interpret=interpret)
+        return xla_chunk_attention(q, k_cache, v_cache, start, window)
+    s_max = k_cache.shape[1]
+    return chunk_attention_pallas(
+        q, k_cache, v_cache, start,
+        block_q=BLOCK_Q if c % CHUNK_BLOCK_Q else CHUNK_BLOCK_Q,
+        block_k=BLOCK_K if s_max % CHUNK_BLOCK_K else CHUNK_BLOCK_K,
+        interpret=interpret, window=window)
 
 
 def flash_attention(

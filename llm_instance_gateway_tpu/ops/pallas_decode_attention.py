@@ -224,7 +224,8 @@ def _pick_block(s_max: int, row_bytes: int = 0) -> int:
 
 
 def _pallas_decode_call(q, k_all, v_all, scales, lengths, layer,
-                        block_s: int | None, interpret: bool) -> jax.Array:
+                        block_s: int | None, interpret: bool,
+                        name: str = "decode_attention") -> jax.Array:
     """Shared pallas_call builder for the bf16 and int8 variants, over the
     STACKED cache [L, B, S, K, hd] and a layer index: the index rides the
     scalar prefetch into the tiles' index map, so the kernel reads the
@@ -232,7 +233,9 @@ def _pallas_decode_call(q, k_all, v_all, scales, lengths, layer,
     ``layer`` None the arrays are one layer's, a stack of one (a leading 1
     is free).  ``scales`` is None (bf16) or (k_scale, v_scale) f32, stacked
     like the cache: only that layer's reach the kernel, as lane vectors — a
-    relayout kept to one layer of an array 1/hd the size of the cache."""
+    relayout kept to one layer of an array 1/hd the size of the cache.
+    ``name`` is the call's name in a device trace: the same body over a
+    window layer's ring lanes runs as ``decode_attention_window``."""
     if scales is not None:
         scales = [_layer_view(s, layer) for s in scales]
     if layer is None:
@@ -285,7 +288,7 @@ def _pallas_decode_call(q, k_all, v_all, scales, lengths, layer,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="decode_attention_int8" if quant else "decode_attention",
+        name="decode_attention_int8" if quant else name,
     )(*operands)
 
 
@@ -297,9 +300,10 @@ def decode_attention_pallas(
     layer=None,          # scalar int32: which layer of a stacked cache
     block_s: int | None = None,
     interpret: bool = False,
+    name: str = "decode_attention",
 ) -> jax.Array:
     return _pallas_decode_call(q, k_cache, v_cache, None, lengths, layer,
-                               block_s, interpret)
+                               block_s, interpret, name)
 
 
 def decode_attention_quant_pallas(
@@ -606,21 +610,27 @@ def mla_decode_attention(
 
 def decode_attention(
     q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, lengths: jax.Array,
-    layer=None, interpret: bool = False,
+    layer=None, interpret: bool = False, ring: bool = False,
 ) -> jax.Array:
     """Dispatch: Pallas kernel when shapes allow, XLA reference otherwise.
     With ``layer`` the caches are the stacked [L, B, S, K, hd] arrays and
-    the kernel reads that layer in place."""
+    the kernel reads that layer in place.  ``ring``: the lanes are a window
+    layer's rings, of which ``lengths`` says how many positions are held
+    (the order of a softmax's keys does not matter, and each key's rotary
+    encoding went on when it was written); the same kernel, named
+    ``decode_attention_window`` so that a trace tells the two apart."""
     s_max, hd = k_cache.shape[-3], k_cache.shape[-1]
     reason = kernel_reason(
         shape_reasons(s_max, hd, _row_bytes(k_cache)), interpret)
-    log_choice("decode", f"q{tuple(q.shape)} cache{tuple(k_cache.shape)}",
+    log_choice("decode_window" if ring else "decode",
+               f"q{tuple(q.shape)} cache{tuple(k_cache.shape)}",
                reason, interpret)
     if reason is not None:
         return xla_decode(q, _layer_view(k_cache, layer),
                           _layer_view(v_cache, layer), lengths)
-    return decode_attention_pallas(q, k_cache, v_cache, lengths, layer,
-                                   interpret=interpret)
+    return decode_attention_pallas(
+        q, k_cache, v_cache, lengths, layer, interpret=interpret,
+        name="decode_attention_window" if ring else "decode_attention")
 
 
 def decode_attention_quant(

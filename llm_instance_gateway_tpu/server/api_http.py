@@ -1541,7 +1541,8 @@ class ModelServer:
                 "n_shared_experts", "first_k_dense", "router_sigmoid",
                 "routed_scaling_factor", "ssm_d_inner", "ssm_n_heads",
                 "ssm_head_dim", "ssm_d_state", "ssm_n_groups", "ssm_d_conv",
-                "ssm_chunk")
+                "ssm_chunk", "layer_pattern", "sliding_window",
+                "router_pre_attention", "mlp_activation")
         return web.json_response({
             "model": self.model_name,
             "platform": devices[0].platform,
@@ -1758,6 +1759,11 @@ def main(argv=None) -> None:
             f"{args.model} keeps a recurrent (state-space) state beside its "
             "KV lanes: it is served on one device, base model only; start "
             "it with --max-loras 0 and without --mesh")
+    if cfg.layer_pattern and (args.max_loras > 0 or args.mesh):
+        raise SystemExit(
+            f"{args.model} scans a period of layer kinds over ring lanes "
+            "beside its full lanes: it is served on one device, base model "
+            "only; start it with --max-loras 0 and without --mesh")
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
 
     tokenizer = load_tokenizer(args.tokenizer)

@@ -556,14 +556,23 @@ class _ChunkStream:
 
 def _refuse_what_lanes_alone_serve(model_cfg, cfg: "EngineConfig",
                                    lora_manager, mesh) -> None:
-    """A latent (MLA) cache, and a recurrent state beside the K/V lanes (a
-    state-space mixer), are served from contiguous lanes on one device,
+    """A latent (MLA) cache, a recurrent state beside the K/V lanes (a
+    state-space mixer), and a stack of two kinds of layer with ring lanes
+    for its window layers are served from contiguous lanes on one device,
     base model only.  Every other way to hold or move a row's state assumes
-    that it is per-head K and V by position and nothing else: what a
-    rebuilt, shared, shipped or rolled-back row would need of a state that
-    is no function of positions is not there.  Each is refused here by name
+    that it is per-head K and V by position and nothing else, in one stack
+    a layer: what a rebuilt, shared, shipped or rolled-back row would need
+    of a state that is no function of positions, or of a ring that has
+    already overwritten them, is not there.  Each is refused here by name
     rather than run wrong."""
-    if model_cfg.latent_width:
+    if model_cfg.layer_pattern:
+        kind = ("scans a period of layer kinds over ring lanes beside its "
+                "full lanes")
+        loras = ("--max-loras above 0 (models/lora.py's buffers are scanned "
+                 "a layer a step, not a period)")
+        extra = {"--prefill-batch above 1 (a grouped prefill's rows are "
+                 "cut out of one stack of K and V)": cfg.prefill_batch > 1}
+    elif model_cfg.latent_width:
         kind = "keeps a latent (MLA) KV cache"
         loras = ("--max-loras above 0 (models/lora.py sizes its targets "
                  "from per-head q, k, v)")
@@ -629,7 +638,12 @@ class Engine:
         self._kv_quant = self.cfg.kv_cache_quant is not None
         self._latent = bool(model_cfg.latent_width)
         self._recurrent = bool(model_cfg.ssm_d_inner)
-        if self._latent or self._recurrent:
+        # A stack of two kinds of layer: full lanes and, for its window
+        # layers, ring lanes (``transformer.init_decode_cache``).
+        self._ringed = bool(model_cfg.layer_pattern)
+        # Positions a window layer's ring holds of a row (0: no window).
+        self._window = min(model_cfg.sliding_window, self.cfg.max_seq_len)
+        if self._latent or self._recurrent or self._ringed:
             _refuse_what_lanes_alone_serve(
                 model_cfg, self.cfg, lora_manager, mesh)
         if self.cfg.kv_cache_quant not in (None, "int8"):
@@ -937,6 +951,9 @@ class Engine:
         self._jit_insert = jax.jit(
             _named("insert_prefill",
                    paged_lib.insert_prefill_paged if self.paged
+                   # a window model's prompt parts by kind of layer
+                   else functools.partial(transformer.insert_prefill,
+                                          cfg=model_cfg) if self._ringed
                    else transformer.insert_prefill),
             donate_argnames=("cache",),
         )
@@ -1363,7 +1380,7 @@ class Engine:
             # host holds (a row that stops mid-block counts on to its end).
             self.profiler.note_ssm_rows(
                 n_steps * sum(s is not None for s in self.slots))
-        if self._latent:
+        if self._latent or self._window:
             # Step j of the block reads position + 1 + j rows of a live
             # row's lane.  With a block still unread (the overlapped loop)
             # the host record is that block's steps behind the device, for
@@ -1372,8 +1389,14 @@ class Engine:
             lag = self._inflight["n_steps"] if self._inflight else 0
             at = [s.position + (0 if self._slot_fresh[i] else lag)
                   for i, s in enumerate(self.slots) if s is not None]
-            self.profiler.note_latent_positions(
-                sum(at) * n_steps + len(at) * n_steps * (n_steps + 1) // 2)
+            held = sum(at) * n_steps + len(at) * n_steps * (n_steps + 1) // 2
+            if self._latent:
+                self.profiler.note_latent_positions(held)
+            else:
+                # ... and a window layer's ring at most the window of them.
+                self.profiler.note_kv_positions(held, sum(
+                    min(p + j, self._window)
+                    for p in at for j in range(1, n_steps + 1)))
         i32, f32 = self._slots_i32.copy(), self._slots_f32.copy()
         # The copy carries the activations since the last block; the block
         # after this one takes those rows from the carry like any other.
@@ -1571,11 +1594,12 @@ class Engine:
     def _refuse_handoff(self) -> None:
         """Every engine keeps the handoff API in every role; one whose rows
         hold more than per-head K and V by position cannot ship them."""
-        if self._latent or self._recurrent:
+        if self._latent or self._recurrent or self._ringed:
             raise ValueError(
                 f"{self.model_cfg.name}: the handoff wire "
-                "(server/kv_transfer.py) ships per-head K and V only, not a "
-                "latent cache or a recurrent state")
+                "(server/kv_transfer.py) ships one stack of per-head K and "
+                "V only, not a latent cache, a recurrent state or ring "
+                "lanes")
 
     def attach_prefilled(self, handoff) -> Request:
         """Admit a ``PrefillHandoff`` straight into decode (hop 2): the KV
@@ -1689,8 +1713,27 @@ class Engine:
             waiting.add(pending.adapter)
         return sorted(running), sorted(waiting)
 
+    def _kv_usage(self, held, parked: int, used_tokens: int,
+                  capacity: int) -> float:
+        """``tpu:kv_cache_usage_perc``, the number the gateway's threshold
+        routes on: held bytes over allocated bytes.  For a cache of one
+        kind that is tokens over token capacity (``held`` is not looked
+        at).  A window model's row of ``p`` positions holds ``p`` in each
+        full layer and min(p, ring) in each window layer, of the
+        ``max_seq_len`` and ``ring`` allocated: position-layers over
+        position-layers, every one the same bytes."""
+        if not self._window:
+            return used_tokens / capacity if capacity else 0.0
+        n_win = self.model_cfg.n_window_layers
+        n_full = self.model_cfg.n_layers - n_win
+        used = sum(n_full * p + n_win * min(p, self._window) for p in held)
+        used += (n_full + n_win) * parked
+        return used / (self.cfg.decode_slots * (
+            n_full * self.cfg.max_seq_len + n_win * self._window))
+
     def metrics_snapshot(self) -> dict:
         active = sum(1 for s in self.slots if s is not None)
+        held = ()  # the lanes' rows by their lengths; a paged pool has none
         if self.paged:
             # vLLM gpu_cache_usage_perc semantics: allocated / total blocks.
             # Zero-ref cached prefix blocks are reclaimable on demand, so
@@ -1699,9 +1742,9 @@ class Engine:
             used_tokens = (self._n_blocks - len(self._free_blocks)
                            - len(self._evictable)) * self._block
         else:
-            used_tokens = sum(
-                (s.position if s is not None else 0) for s in self.slots
-            ) + sum(st.next_start for st in list(self._streams))
+            held = [s.position for s in self.slots if s is not None] + [
+                st.next_start for st in list(self._streams)]
+            used_tokens = sum(held)
             capacity = self.cfg.decode_slots * self.cfg.max_seq_len
         # decode_wait KV is real allocated HBM held OUTSIDE the cache/pool;
         # vLLM's counter (the semantics the 0.8 threshold was tuned against,
@@ -1730,7 +1773,8 @@ class Engine:
             "decode_queue_size": decode_depth,  # prefilled, awaiting a slot
             "num_requests_running": active,
             "num_requests_waiting": prefill_depth + decode_depth,
-            "kv_cache_usage_perc": used_tokens / capacity if capacity else 0.0,
+            "kv_cache_usage_perc": self._kv_usage(
+                held, parked, used_tokens, capacity),
             "kv_tokens_capacity": capacity,
             "kv_tokens_free": max(0, capacity - used_tokens),
             "kv_parked_tokens": parked,

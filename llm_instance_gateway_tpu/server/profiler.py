@@ -58,6 +58,7 @@ import time
 from llm_instance_gateway_tpu.lockwitness import witness_lock
 from llm_instance_gateway_tpu.metrics_registry import (
     ENGINE_PHASES,
+    KV_LANES,
     SAMPLE_PATHS,
 )
 from llm_instance_gateway_tpu.tracing import Histogram
@@ -171,6 +172,12 @@ class StepProfiler:
         # summed over the steps of the plain decode dispatches.  0 for a
         # model without a mixer.
         self.ssm_rows = 0
+        # Cache positions the decode steps' attention read of the live
+        # rows' lanes, a layer of the kind, by the kind of lane
+        # (metrics_registry.KV_LANES): the full lanes' grow with a row, a
+        # window layer's ring stops at the window.  0 for a model without
+        # a window.
+        self.kv_positions = [0] * len(KV_LANES)
         # Decode blocks dispatched while an earlier block was still unread:
         # the device then had its next step queued before the host read
         # the last.  Over the decode and spec dispatches: the share of
@@ -431,6 +438,14 @@ class StepProfiler:
         with self._lock:
             self.ssm_rows += n
 
+    def note_kv_positions(self, full: int, window: int) -> None:
+        """Count the positions the steps of one plain decode dispatch read
+        of the live rows' full lanes and of their rings, a layer of each
+        kind."""
+        with self._lock:
+            self.kv_positions[0] += full
+            self.kv_positions[1] += window
+
     def note_overlapped_block(self) -> None:
         """Count one decode block dispatched while an earlier block was
         still unread."""
@@ -452,6 +467,7 @@ class StepProfiler:
                 "lora_rows": self.lora_rows,
                 "latent_positions": self.latent_positions,
                 "ssm_rows": self.ssm_rows,
+                "kv_positions": dict(zip(KV_LANES, self.kv_positions)),
                 "blocks_overlapped": self.blocks_overlapped,
             }
         out["phases"] = self.phase_seconds()
@@ -536,6 +552,12 @@ def render_profile(hist: dict) -> list[str]:
     if "ssm_rows" in hist:
         lines += ["# TYPE tpu:ssm_state_rows_total counter",
                   f"tpu:ssm_state_rows_total {hist['ssm_rows']}"]
+    kv_positions = hist.get("kv_positions")
+    if kv_positions:
+        lines.append("# TYPE tpu:kv_positions_read_total counter")
+        lines += [
+            f'tpu:kv_positions_read_total{{lanes="{escape_label(lanes)}"}} '
+            f'{n}' for lanes, n in kv_positions.items()]
     if "blocks_overlapped" in hist:
         lines += ["# TYPE tpu:decode_blocks_overlapped_total counter",
                   "tpu:decode_blocks_overlapped_total "

@@ -64,15 +64,23 @@ def test_a_program_without_the_counter_reads_nothing():
     assert read(dense) is None
 
 
-def test_the_entry_is_the_last_and_lists_the_sparse_closed_loops():
+def test_the_entry_lists_the_sparse_closed_loops():
     man = manifest.load_manifest()
     assert manifest.problems(man) == []
-    entry = man["per_layer"][-1]
-    assert entry == {
+    entry = next(m for m in man["per_layer"] if m["name"] == NAME)
+    # A rule, as PR 41 made of the pins of ``tests/benchmark/``: the entry as
+    # it came (PR 44), wherever later entries put it, and its list every
+    # sparse closed loop, the two it came with first.
+    assert dict(entry, workloads=entry["workloads"][:2]) == {
         "name": NAME, "unit": "tiles", "better": "lower",
         "source": "program_counter", "layer": "model step",
         "moves": "output_tok_s",
         "workloads": ["mixtral_d6_batch", "glm47flash_d13_agents"]}
+    assert entry["workloads"] == [
+        c["name"] for c in man["workloads"]
+        if c["name"] in next(m for m in man["end_to_end"]
+                             if m["name"] == "output_tok_s")["workloads"]
+        and manifest.load_config(c["config"])["model"].get("n_experts")]
     for cell in entry["workloads"]:
         model = manifest.load_config(manifest.cell(man, cell)["config"])[
             "model"]

@@ -203,6 +203,21 @@ def ssm_rows_row(profile: dict) -> dict:
     return _per_decode_dispatch(profile, "ssm_rows", "rows_per_dispatch")
 
 
+def kv_positions_rows(profile: dict) -> list[dict]:
+    """Cache positions the decode steps read of the live rows' lanes, by
+    the kind of lane (``tpu:kv_positions_read_total``); empty for a model
+    without a window."""
+    hist = profile.get("hist") or {}
+    read = hist.get("kv_positions") or {}
+    if not any(read.values()):
+        return []
+    n = int(((hist.get("wall") or {}).get("decode") or {}).get("count", 0))
+    return [{"lanes": lanes, "positions": int(total),
+             "decode_dispatches": n,
+             "positions_per_dispatch": round(total / n, 3) if n else 0.0}
+            for lanes, total in read.items()]
+
+
 def overlap_row(profile: dict) -> dict:
     """Decode blocks dispatched while an earlier block was still unread
     (``tpu:decode_blocks_overlapped_total``) and their share of the decode
@@ -229,7 +244,7 @@ NO_ANNOTATION = "other"  # the bottom of the phase stack is not annotated
 # server/sampling.py, server/engine.py).
 SCOPES = frozenset((
     "embed", "attn.qkv", "attn.rope", "attn.kv_update", "attn.core",
-    "attn.out", "attn.q_latent", "attn.kv_latent", "attn.absorb",
+    "attn.core.window", "attn.out", "attn.q_latent", "attn.kv_latent", "attn.absorb",
     "attn.expand", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.update",
     "ssm.gate_norm", "ssm.out_proj", "mlp", "moe.route", "moe.dispatch",
     "moe.experts", "moe.shared", "lora", "lm_head", "sample",
@@ -679,6 +694,12 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
         out += ["", "Recurrent states rewritten by the decode steps:",
                 _table([recurrent], ("ssm_rows", "decode_dispatches",
                                      "rows_per_dispatch"))]
+    lanes = kv_positions_rows(profile)
+    if lanes:
+        out += ["", "Cache positions read by the decode steps, a layer of "
+                "the kind:",
+                _table(lanes, ("lanes", "positions", "decode_dispatches",
+                               "positions_per_dispatch"))]
     delta = host_sync_delta(profile, previous)
     if delta:
         out += ["", "Host-sync share vs previous baseline: "
