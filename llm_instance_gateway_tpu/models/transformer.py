@@ -446,11 +446,16 @@ def _moe_route(cfg: ModelConfig, lp: Params, x, live=None) -> dict:
     (``norm_topk_prob``) and scaled by ``routed_scaling_factor``.  dispatch: each of the ``T*k`` assignments
     gets a row in a layout grouped by expert, every group padded to whole
     tiles of ``tm`` rows (``pallas_moe.tile_rows``: from the mean group
-    size, so static), by a one-hot cumsum: no sort, no capacity, nothing
-    dropped, nothing recomputed.  experts: three grouped matmuls, each tile
-    against its own expert's weights (``ops.pallas_moe``); an expert no row
-    chose has no tile and is not read.  ``live`` rows only: a row that
-    holds no request routes nowhere and gets zeros.
+    size, so static); its ``row`` there comes from a one-hot cumsum: no
+    capacity, nothing dropped, nothing recomputed.  The token rows get
+    there by one of two forms chosen from the static ``T*k``
+    (``_gathers_in``): a decode batch's few by a row scatter into zeros, a
+    prompt's many by a row GATHER in layout order, whose source map ``src``
+    is one sort of the layout's keys (``_layout_source``).  experts: three
+    grouped matmuls, each tile against its own expert's weights
+    (``ops.pallas_moe``); an expert no row chose has no tile and is not
+    read.  ``live`` rows only: a row that holds no request routes nowhere
+    and gets zeros.
 
     ``lp`` carries either this layer's expert leaves ``[E, in, out]`` or,
     from the layer loops below, the STACKED leaves ``[L, E, in, out]`` and
@@ -463,8 +468,10 @@ def _moe_route(cfg: ModelConfig, lp: Params, x, live=None) -> dict:
     This half is the route and the plan of the dispatch, which read ``x``
     (the MLP's normed input, or the block's input under
     ``router_pre_attention``) through the router alone: the gates [T, k],
-    each assignment's ``row`` in the grouped layout, the tiles' experts and
-    the ``tally`` (``MOE_TALLY``).  ``_moe_experts`` does the rest.
+    each assignment's ``row`` in the grouped layout, each layout row's
+    source token ``src`` (None where the scatter lays the rows out), the
+    tiles' experts and the ``tally`` (``MOE_TALLY``).  ``_moe_experts`` does
+    the rest.
     """
     xf = x.reshape(-1, x.shape[-1])
     t = xf.shape[0]
@@ -503,20 +510,82 @@ def _moe_route(cfg: ModelConfig, lp: Params, x, live=None) -> dict:
         rank = jnp.sum((jnp.cumsum(chose, axis=0) - 1) * chose, axis=-1)
         first_row, tile_expert, n_used = pallas_moe.tile_plan(
             sizes, tm, n_tiles)
-        # A dead row's address is out of bounds: its scatter is dropped and
-        # its gather reads zeros.
+        # A dead row's address is out of bounds: no layout row is its own
+        # (its scatter is dropped, the sorted layout keeps it past the last
+        # group) and the way back's gather reads zeros for it.
         row = jnp.where(expert < e,
                         first_row[jnp.minimum(expert, e - 1)] + rank, n_rows)
+        src = (_layout_source(expert, sizes, k, tm, n_rows)
+               if _gathers_in(t * k, e) else None)
         tally = jnp.stack([jnp.ones((), jnp.int32), jnp.sum(sizes),
                            jnp.sum(sizes > 0), n_used])
-    return {"gates": gates, "row": row, "tile_expert": tile_expert,
-            "n_used": n_used, "tally": tally, "tm": tm, "n_rows": n_rows}
+    return {"gates": gates, "row": row, "src": src,
+            "tile_expert": tile_expert, "n_used": n_used, "tally": tally,
+            "tm": tm, "n_rows": n_rows}
+
+
+# The most assignments (``T*k``, static) whose rows the SCATTER lays out.
+# The way in alone on the v5e, us a call, scatter | gather
+# (``tools/onchip_pallas_check.py "moe-dispatch"``; my chip runs, PR 46):
+# decode, 32 rows: mixtral 64 -> [176, 4096] 13 | 17, glm 128 -> [1088, 2048]
+# 14 | 31, smallthinker 192 -> [1152, 2560] 29 | 35, olmoe 256 ->
+# [1216, 2048] 15 | 31; prompts: 256 -> [704, 4096] 48 | 35, 384 ->
+# [1344, 2560] 120 | 39, 512 -> [1472, 2048] 53 | 35, 2,048 -> [2944, 4096]
+# 272 | 59, 4,096 -> [12160, 2048] 321 | 182, 6,144 -> [14208, 2560]
+# 1,452 | 251.  The gather's sort costs ~20 us whatever it sorts, more than a
+# decode batch's whole scatter; from 384 assignments on the scatter loses.
+_SCATTER_MAX_ASSIGN = 256
+
+
+def _gathers_in(n_assign: int, n_experts: int) -> bool:
+    """Whether ``n_assign`` assignments' rows reach the layout by gather
+    (``_layout_source``) and not by scatter: above the crossing the chip
+    shows, and where a layout key fits an int32."""
+    return (n_assign > _SCATTER_MAX_ASSIGN
+            and (n_experts + 1) << n_assign.bit_length() <= 1 << 31)
+
+
+def _layout_source(expert, sizes, k: int, tm: int, n_rows: int):
+    """The token whose row each of the layout's ``n_rows`` rows holds, T for
+    a row that holds none, from the assignments' experts [T*k] (E: dead)
+    and the groups' sizes [E], by ONE sort and no scatter.  The layout IS
+    the sorted order of its rows' keys (group, place): an assignment's
+    place is its index (a group keeps token order), a padding row's is past
+    every index; the ``n_rows - T*k`` padding rows are dealt to the groups
+    by what each lacks to whole tiles, the rest and the dead past the last
+    group."""
+    n, e = expert.shape[0], sizes.shape[0]
+    bits = n.bit_length()
+    low = (1 << bits) - 1  # over every assignment's index
+    pad_end = jnp.cumsum(-(-sizes // tm) * tm - sizes)
+    pad_group = jnp.sum(
+        jnp.arange(n_rows - n, dtype=jnp.int32)[:, None] >= pad_end,
+        axis=1, dtype=jnp.int32)
+    keys = jax.lax.sort(jnp.concatenate([
+        (expert << bits) | jnp.arange(n, dtype=jnp.int32),
+        (pad_group << bits) | low]))
+    index = keys & low
+    return jnp.where((index < n) & ((keys >> bits) < e), index // k, n // k)
+
+
+def _lay_out(xf, plan: dict, k: int):
+    """The token rows ``xf`` [T, D] in the expert-grouped layout
+    [n_rows, D], zeros where a row holds no assignment: gathered in layout
+    order where the plan has the source map, else scattered."""
+    if plan["src"] is None:
+        return jnp.zeros((plan["n_rows"], xf.shape[-1]), xf.dtype).at[
+            plan["row"]].set(jnp.repeat(xf, k, axis=0), mode="drop")
+    # a zero row at index T: a gather that promises its indices in bounds
+    # is one pass, ``mode="fill"`` a second one over the whole layout
+    with_zero = jnp.concatenate([xf, jnp.zeros_like(xf[:1])])
+    return with_zero.at[plan["src"]].get(mode="promise_in_bounds")
 
 
 def _moe_experts(cfg: ModelConfig, lp: Params, x, plan: dict):
     """The sparse layer from its plan on (``_moe_route``): the rows of
-    ``x`` laid out by expert, the three grouped matmuls, the way back and
-    the gates' mix, the shared experts.  Returns ``(y, tally)``."""
+    ``x`` laid out by expert (``_lay_out``), the three grouped matmuls, the
+    way back (a gather by ``row``) and the gates' mix, the shared experts.
+    Returns ``(y, tally)``."""
     orig_shape = x.shape
     d = orig_shape[-1]
     xf = x.reshape(-1, d)
@@ -529,8 +598,7 @@ def _moe_experts(cfg: ModelConfig, lp: Params, x, plan: dict):
         stacks, layer = jax.tree.map(lambda a: a[None], stacks), 0
 
     with jax.named_scope("moe.dispatch"):
-        x_e = jnp.zeros((plan["n_rows"], d), xf.dtype).at[row].set(
-            jnp.repeat(xf, k, axis=0), mode="drop")
+        x_e = _lay_out(xf, plan, k)
 
     with jax.named_scope("moe.experts"):
         gmm = functools.partial(

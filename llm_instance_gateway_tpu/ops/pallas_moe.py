@@ -5,7 +5,12 @@ weights of each tile's own expert.
 each expert's group padded to whole tiles of ``tm`` rows, so a tile belongs
 to ONE expert and the matmul over ragged groups becomes: for each row tile,
 ``x[tile] @ w[expert_of(tile)]``.  No capacity, nothing dropped; an expert
-with no assignment has no tile and its weights are never read.
+with no assignment has no tile and its weights are never read.  The rows
+get there by a scatter into zeros from a decode batch (up to 256
+assignments) and by a gather in layout order from a prompt
+(``transformer._lay_out``: the layout is one sort of its rows' keys); a row
+that holds no assignment is zeros either way, and what the kernel writes
+there is never gathered back.
 
 The kernel takes the STACKED leaf ``[L, E, K, N]`` with the layer index as
 a scalar-prefetch operand (as the decode-attention kernel takes the stacked

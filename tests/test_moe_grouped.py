@@ -12,6 +12,9 @@ Contracts:
   and by tile, with a column in one K block and in several;
 - by group a touched expert's weight block is fetched once a call;
 - the routing tally counts what was routed, and the tiles it took;
+- the two ways into the layout (a decode batch's row scatter, a prompt's
+  row gather from the sorted layout) give the same layout, ``y`` and tally
+  bit for bit, and a prompt-sized program holds no scatter of token rows;
 - compiled FLOPs track the assignments made, not experts x tokens.
 """
 
@@ -338,3 +341,134 @@ def test_flops_track_assignments_not_experts():
     sparse = flops(lambda v: transformer._moe_mlp(cfg, lp, v)[0])
     dense = flops(lambda v: reference._mlp(cfg, params["layers"], 0, v, None))
     assert sparse < dense / 2
+
+
+# The two ways into the layout (``transformer._lay_out``), each forced at
+# every size by moving the crossing: the widths of the three sparse models
+# the benchmark serves, as routing arithmetic over tiny rows.
+DISPATCH_CFGS = {
+    "mixtral-8-top2": TINY_MOE_TEST,
+    "olmoe-64-top8": TINY_OLMOE_TEST,
+    "smallthinker-64-top6": dataclasses.replace(
+        TINY_OLMOE_TEST, n_experts_per_token=6, norm_topk_prob=True),
+}
+ROUTINGS = ("drawn", "dead-rows", "one-expert", "expert-unchosen")
+
+
+def routed(cfg, t, routing):
+    """(lp, x, live) of one sparse layer: as drawn; with about a fifth of
+    the rows dead; with every token's first choice forced onto expert 0 (a
+    group of ``t`` rows: many tiles); with expert 3 chosen by no row."""
+    params = transformer.init_params(cfg, jax.random.PRNGKey(3),
+                                     dtype=jnp.float32)
+    lp, x, live = layer0(params), jnp.abs(rows(cfg, t)) + 0.1, None
+    if routing == "dead-rows":
+        live = jax.random.uniform(jax.random.PRNGKey(7), (t,)) >= 0.2
+        live = live.at[0].set(t == 1)  # a dead and a live row at least
+    elif routing == "one-expert":
+        lp["router"] = lp["router"].at[:, 0].set(5.0)
+    elif routing == "expert-unchosen":
+        lp["router"] = lp["router"].at[:, 3].set(-5.0)
+    return lp, x, live
+
+
+def in_both_forms(monkeypatch, cfg, lp, x, live):
+    """{form: (plan, layout, y, tally)} with the crossing moved so that the
+    scatter, then the gather, lays these rows out."""
+    out = {}
+    for form, most in (("scatter", 1 << 30), ("gather", 0)):
+        monkeypatch.setattr(transformer, "_SCATTER_MAX_ASSIGN", most)
+        plan = transformer._moe_route(cfg, lp, x, live)
+        assert (plan["src"] is None) == (form == "scatter")
+        out[form] = (plan, transformer._lay_out(
+            x, plan, cfg.n_experts_per_token),
+            *transformer._moe_experts(cfg, lp, x, plan))
+    return out
+
+
+ROUTED_SIZES = {"drawn": (1, 7, 32, 200, 1024),
+                "dead-rows": (1, 7, 32, 200, 1024),
+                "one-expert": (32, 200), "expert-unchosen": (7, 200)}
+
+
+@pytest.mark.parametrize("routing,t", [
+    (routing, t) for routing in ROUTINGS for t in ROUTED_SIZES[routing]])
+@pytest.mark.parametrize("name", sorted(DISPATCH_CFGS))
+def test_gathered_layout_is_the_scattered_one(name, routing, t, monkeypatch):
+    cfg = DISPATCH_CFGS[name]
+    e, k = cfg.n_experts, cfg.n_experts_per_token
+    lp, x, live = routed(cfg, t, routing)
+    forms = in_both_forms(monkeypatch, cfg, lp, x, live)
+    plan, want_x_e, want_y, want_tally = forms["scatter"]
+    new_plan, x_e, y, tally = forms["gather"]
+    row = np.asarray(plan["row"])
+    np.testing.assert_array_equal(new_plan["row"], row)
+    held = row[row < plan["n_rows"]]
+    # the premise: rows are assigned (as many as are live), the case is the
+    # one its name says, and the layout holds each assignment's token row
+    n_live = t if live is None else int(live.sum())
+    assert held.size == n_live * k == int(want_tally[1])
+    assert len(set(held.tolist())) == held.size
+    sizes = np.bincount(np.asarray(plan["tile_expert"])[held // plan["tm"]],
+                        minlength=e)
+    if routing == "dead-rows":
+        assert 0 < n_live < t or t == 1
+    elif routing == "one-expert":
+        assert sizes[0] == t > plan["tm"]
+    elif routing == "expert-unchosen":
+        assert sizes[3] == 0 < sizes.sum()
+    np.testing.assert_array_equal(
+        np.asarray(want_x_e)[row[row < plan["n_rows"]]],
+        np.repeat(np.asarray(x), k, axis=0)[row < plan["n_rows"]])
+    # the assigned rows bit for bit, and zeros wherever no row is assigned
+    np.testing.assert_array_equal(x_e, want_x_e)
+    np.testing.assert_array_equal(y, want_y)
+    np.testing.assert_array_equal(tally, want_tally)
+    if live is not None:
+        assert not np.asarray(y)[~np.asarray(live)].any()
+
+
+def test_the_form_is_chosen_from_the_static_assignment_count():
+    """Decode batches of the benchmark's cells (64-256 assignments) scatter,
+    every prefill bucket and chunk gathers; a key that would not fit an
+    int32 is never built."""
+    assert not any(transformer._gathers_in(n, e) for n, e in (
+        (1, 8), (32 * 2, 8), (32 * 4, 64), (32 * 6, 64), (32 * 8, 64)))
+    assert all(transformer._gathers_in(n, e) for n, e in (
+        (257, 64), (64 * 6, 64), (64 * 8, 64), (1024 * 2, 8), (1024 * 8, 64),
+        (8192 * 8, 256)))
+    assert not transformer._gathers_in(1 << 24, 64)
+    cfg, x = TINY_OLMOE_TEST, rows(TINY_OLMOE_TEST, 33)
+    lp = routed(cfg, 33, "drawn")[0]
+    assert transformer._moe_route(cfg, lp, x[:32])["src"] is None
+    assert transformer._moe_route(cfg, lp, x)["src"].shape == (
+        transformer._moe_route(cfg, lp, x)["n_rows"],)
+
+
+def _row_scatters(jaxpr, d):
+    """Every scatter equation of a jaxpr, sub-jaxprs included, that writes
+    ``[*, d]`` rows."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("scatter") and any(
+                v.aval.ndim == 2 and v.aval.shape[-1] == d
+                for v in eqn.outvars):
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _row_scatters(sub, d)
+
+
+@pytest.mark.parametrize("name", sorted(DISPATCH_CFGS))
+def test_a_prompt_sized_program_scatters_no_rows(name):
+    """Structure, not timing: the sparse layer over a prompt's tokens holds
+    no scatter of ``[*, d_model]`` rows (a decode batch's does: the test
+    can see one)."""
+    cfg = DISPATCH_CFGS[name]
+    lp = routed(cfg, 1, "drawn")[0]
+
+    def scatters(t):
+        jaxpr = jax.make_jaxpr(
+            lambda x: transformer._moe_mlp(cfg, lp, x))(rows(cfg, t))
+        return list(_row_scatters(jaxpr.jaxpr, cfg.d_model))
+
+    assert len(scatters(32)) == 1
+    assert scatters(200) == [] and scatters(1024) == []

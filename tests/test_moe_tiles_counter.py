@@ -107,3 +107,46 @@ def test_the_tools_two_layouts_hold_the_same_rows_in_more_tiles(tool, shape):
         assert int(sizes.sum()) == m and int((sizes > 0).sum()) == touched
         used.append(int(pallas_moe.tile_plan(sizes, tm, tiles)[2]))
     assert used == [touched, touched + 3] and used[1] <= tiles
+
+
+# ``moe-dispatch``: (assignments, layout rows, d_model) of each shape the
+# tool times, as the cells' programs make them (ISSUE 46), and whether the
+# model gathers there.
+DISPATCH_LAYOUTS = {
+    "smallthinker chunk 1024x6": (6144, 14208, 2560, True),
+    "glm-4.7-flash chunk 1024x4": (4096, 12160, 2048, True),
+    "mixtral prompt 1024x2": (2048, 2944, 4096, True),
+    "olmoe prompt 64x8": (512, 1472, 2048, True),
+    "smallthinker prompt 64x6": (384, 1344, 2560, True),
+    "mixtral prompt 128x2": (256, 704, 4096, False),
+    "olmoe decode 32x8": (256, 1216, 2048, False),
+    "mixtral decode 32x2": (64, 176, 4096, False),
+    "glm-4.7-flash decode 32x4": (128, 1088, 2048, False),
+    "smallthinker decode 32x6": (192, 1152, 2560, False),
+}
+
+
+@pytest.mark.parametrize("shape", range(len(DISPATCH_LAYOUTS)))
+def test_the_tools_dispatch_shapes_are_the_cells_layouts(tool, shape):
+    from llm_instance_gateway_tpu.models import transformer
+
+    label, t, k, e, d = tool.MOE_DISPATCH_SHAPES[shape]
+    tm = pallas_moe.tile_rows(t * k, e)
+    rows = pallas_moe.n_tiles(t * k, e, tm) * tm
+    assert (t * k, rows, d, transformer._gathers_in(t * k, e)) == (
+        DISPATCH_LAYOUTS[label])
+
+
+@pytest.mark.parametrize("t", [8, 40])
+def test_the_tools_dispatch_case_compares_the_two_layouts(tool, t, capsys):
+    """The case's program runs here at a small width, a decode batch with
+    dead rows and a prompt: it returns both layouts, equal and not empty,
+    and its line names the form the model takes."""
+    import numpy as np
+
+    got, want, tol = tool.case_moe_dispatch(t, 8, 64, 128, calls=2, runs=1)
+    assert tol == 0.0 and tool._scaled_err(got, want) == 0.0
+    assert np.asarray(want, np.float32).any()
+    line = capsys.readouterr().out
+    assert f"moe-dispatch {t * 8} assignments" in line
+    assert ("takes the gather" if t == 40 else "takes the scatter") in line

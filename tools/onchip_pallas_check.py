@@ -18,7 +18,11 @@ the ``ssm-update`` cases what the state-space decode update costs a layer at
 of them live, beside the time its bytes would take at the chip's bandwidth;
 and the ``moe-reuse`` cases what the int8 expert matmul costs a call at
 Mixtral's, OLMoE's and GLM's decode shapes with every touched group in one
-row tile and with two groups in two and three.
+row tile and with two groups in two and three; and the ``moe-dispatch``
+cases what the expert dispatch's way in costs alone (the token rows into the
+expert-grouped layout) by the row scatter and by the gather from the sorted
+layout, at the cells' chunk, prompt and decode shapes: where
+``transformer._SCATTER_MAX_ASSIGN`` comes from.
 
     python tools/onchip_pallas_check.py            # on the chip
 """
@@ -39,8 +43,11 @@ import numpy as np
 
 from llm_instance_gateway_tpu import runtime
 from llm_instance_gateway_tpu.models.transformer import (
+    _gathers_in,
     _kv_dequantize,
     _kv_quantize,
+    _lay_out,
+    _layout_source,
 )
 from llm_instance_gateway_tpu.ops import attention as xla_att
 from llm_instance_gateway_tpu.ops import pallas_attention as flash
@@ -95,6 +102,22 @@ MOE_REUSE_SHAPES = (
     ("mixtral down decode 32x2", 8, 14336, 4096, 64, 7),
     ("olmoe gate/up decode 32x8", 64, 2048, 1024, 256, 36),
     ("glm-4.7-flash gate/up decode 32x4", 64, 2048, 1536, 128, 27),
+)
+
+# The expert dispatch's way in (``transformer._lay_out``): (label, tokens,
+# k, E, d_model) of the sparse cells' chunk and prompt programs, of the
+# shortest prompts that gather, and of their decode steps at 32 slots.
+MOE_DISPATCH_SHAPES = (
+    ("smallthinker chunk 1024x6", 1024, 6, 64, 2560),
+    ("glm-4.7-flash chunk 1024x4", 1024, 4, 64, 2048),
+    ("mixtral prompt 1024x2", 1024, 2, 8, 4096),
+    ("olmoe prompt 64x8", 64, 8, 64, 2048),
+    ("smallthinker prompt 64x6", 64, 6, 64, 2560),
+    ("mixtral prompt 128x2", 128, 2, 8, 4096),
+    ("olmoe decode 32x8", 32, 8, 64, 2048),
+    ("mixtral decode 32x2", 32, 2, 8, 4096),
+    ("glm-4.7-flash decode 32x4", 32, 4, 64, 2048),
+    ("smallthinker decode 32x6", 32, 6, 64, 2560),
 )
 
 DTYPE = jnp.bfloat16
@@ -347,6 +370,66 @@ def case_moe_reuse(e, k, n, m, touched, n_layers=4, calls=100):
     return out * live, ref * live, TOL_BF16
 
 
+def case_moe_dispatch(t, k, e, d, calls=100, runs=100):
+    """The way in alone: ``t`` token rows of ``d`` into the layout of their
+    ``t * k`` assignments over ``e`` experts (a skewed draw; a fifth of a
+    decode batch's rows dead), by the scatter and by the gather with its
+    source map, each a program of ``calls`` calls whose inputs hang on the
+    call before (nothing is hoisted, the layout is written whole).  Prints us
+    a call (host clock, median of ``runs`` programs) beside the time the
+    layout's bytes take at 819 GB/s; parity: the two layouts are equal."""
+    kx, kl, kb, kd = _keys(11, 4)
+    n = t * k
+    tm = pmoe.tile_rows(n, e)
+    n_rows = pmoe.n_tiles(n, e, tm) * tm
+    xf = jax.random.normal(kx, (t, d), DTYPE)
+    _, topi = jax.lax.top_k(jax.random.normal(kl, (t, e))
+                            + 0.7 * jax.random.normal(kb, (e,)), k)
+    dead = jax.random.uniform(kd, (t,)) < (0.2 if t <= 32 else 0.0)
+    expert = jnp.where(jnp.repeat(dead, k), e, topi.reshape(-1)).astype(
+        jnp.int32)
+    chose = (expert[:, None] == jnp.arange(e)).astype(jnp.int32)
+    sizes = jnp.sum(chose, axis=0)
+    first_row, _, _ = pmoe.tile_plan(sizes, tm, n_rows // tm)
+    rank = jnp.sum((jnp.cumsum(chose, axis=0) - 1) * chose, axis=-1)
+    row = jnp.where(expert < e, first_row[jnp.minimum(expert, e - 1)] + rank,
+                    n_rows)
+    args = (xf, expert, sizes, row)
+
+    def scatter(xf, expert, sizes, row):
+        return _lay_out(xf, {"src": None, "row": row, "n_rows": n_rows}, k)
+
+    def gather(xf, expert, sizes, row):
+        return _lay_out(
+            xf, {"src": _layout_source(expert, sizes, k, tm, n_rows)}, k)
+
+    def us_a_call(form):
+        @jax.jit
+        def loop(*args):
+            def body(c, _):  # c stays 0, which the compiler cannot know
+                out = jax.lax.optimization_barrier(form(
+                    *(a + c.astype(a.dtype) for a in args)))
+                return c + (out[0, 0] > 3e4).astype(jnp.int32), None
+            return jax.lax.scan(body, jnp.int32(0), None, length=calls)[0]
+
+        assert int(loop(*args)) == 0
+        times = []
+        for _ in range(runs):
+            t0 = time.perf_counter()
+            loop(*args).block_until_ready()
+            times.append((time.perf_counter() - t0) / calls * 1e6)
+        return sorted(times)[runs // 2]
+
+    moved = (n_rows + n) * d * 2  # the layout written, the rows read
+    print(f"TIME   moe-dispatch {n} assignments -> [{n_rows}, {d}] "
+          f"(tiles of {tm}): scatter {us_a_call(scatter):.1f} us, gather "
+          f"{us_a_call(gather):.1f} us a call (median of {runs} programs of "
+          f"{calls}); the bytes at 819 GB/s {moved / 819e3:.1f} us; the "
+          f"model takes the {'gather' if _gathers_in(n, e) else 'scatter'}",
+          flush=True)
+    return jax.jit(gather)(*args), jax.jit(scatter)(*args), 0.0
+
+
 def case_ssm_update(n_live, b=64, h=32, g=2, n=256, p=128, n_layers=8,
                     calls=160):
     """``ssm_decode_update`` as a decode step of ``b`` slots runs it at the
@@ -424,6 +507,9 @@ def cases():
                    pmoe.shape_reasons(k, n),
                    lambda e=e, k=k, n=n, m=m, quant=quant: case_moe(
                        e, k, n, m, quant))
+    for label, t, k, e, d in MOE_DISPATCH_SHAPES:
+        yield (f"moe-dispatch [{label}]", [],
+               lambda t=t, k=k, e=e, d=d: case_moe_dispatch(t, k, e, d))
     for label, e, k, n, m, touched in MOE_REUSE_SHAPES:
         yield (f"moe-reuse [{label}]", pmoe.shape_reasons(k, n),
                lambda e=e, k=k, n=n, m=m, touched=touched: case_moe_reuse(
