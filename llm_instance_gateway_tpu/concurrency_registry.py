@@ -505,6 +505,11 @@ CLASSES = (
                         note="cache positions the decode steps read by kind "
                              "of lane: the engine thread adds at each "
                              "dispatch, the scrape reads under the lock"),
+            SharedField("attn_grid_steps", LOCK_GUARDED,
+                        writers=("note_attn_grid_steps",),
+                        note="grid steps the decode kernel's schedule held: "
+                             "the engine thread adds at each dispatch, the "
+                             "scrape reads under the lock"),
             SharedField("_last_end", OWNER_PRIVATE,
                         writers=("note_dispatch",)),
             SharedField("_idle_pending", OWNER_PRIVATE,

@@ -436,6 +436,16 @@ SERVER_FAMILIES = (
            "decode step's kernel reads per layer of the kind. 0 for a model "
            "without a window; metrics_registry.KV_LANES.",
            SERVER_SURFACE),
+    Family("tpu:decode_attn_grid_steps_total", "counter", (),
+           "Grid steps the decode-attention kernel walks a layer's call, "
+           "summed over the steps of the plain decode dispatches: the live "
+           "rows' tiles (ceil(length / tile) each; a row that does not "
+           "decode has none), reckoned on the host from the staged lengths "
+           "by the schedule's own rule; of a stack with two kinds of lane, "
+           "the full lanes'. Over tpu:dispatch_steps_sum, the steps one "
+           "decode step's kernel call walks, where slots x tiles was walked "
+           "before. 0 where no kernel takes the cache's shape.",
+           SERVER_SURFACE),
     Family("tpu:lora_rows_total", "counter", (),
            "Live rows whose LoRA slot is >= 0, summed over the steps of the "
            "plain decode dispatches: over tpu:dispatch_steps_sum, the rows "

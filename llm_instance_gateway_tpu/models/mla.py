@@ -174,11 +174,14 @@ def absorb_output(cfg: ModelConfig, lp, o_lat):
     return o.reshape(o.shape[0], -1)
 
 
-def decode_attend(cfg: ModelConfig, lp, hn, positions, kv, at, lengths,
+def decode_attend(cfg: ModelConfig, lp, hn, positions, kv, at, held,
                   layer):
     """One decode step's attention, absorbed form.  ``hn`` [B, D]; ``kv``
     the carry ``(rows [L, B, S, lanes],)``; ``at`` the scatter address of
-    the new rows.  Returns (attention output [B, H * vd], the carry)."""
+    the new rows; ``held`` (the rows each lane holds, the kernel's schedule
+    over them or None: ``transformer._held``).  Returns (attention output
+    [B, H * vd], the carry)."""
+    lengths, schedule = held
     from llm_instance_gateway_tpu.ops import pallas_decode_attention as pda
 
     q_nope, q_rope, latent = project(cfg, lp, hn[:, None],
@@ -189,7 +192,7 @@ def decode_attend(cfg: ModelConfig, lp, hn, positions, kv, at, lengths,
     with jax.named_scope("attn.core"):
         o_lat = pda.mla_decode_attention(
             q_lat, rows, lengths, cfg.kv_lora_rank, _scale(cfg), layer=layer,
-            use_kernel=cfg.use_pallas_decode)
+            use_kernel=cfg.use_pallas_decode, schedule=schedule)
     return absorb_output(cfg, lp, o_lat), (rows,)
 
 

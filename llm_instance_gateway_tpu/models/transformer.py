@@ -994,19 +994,39 @@ def _own_lanes(cfg: ModelConfig, kv: tuple, kind: LayerKind):
     return kv[:2], lambda own: own + kv[2:]
 
 
-def _decode_attend(cfg: ModelConfig, attention_fn, q, kv, layer, lengths,
+def _decode_attend(cfg: ModelConfig, attention_fn, q, kv, layer, held,
                    kind: LayerKind = LayerKind()):
     """One layer's cached attention over the lanes just written: which
     implementation reads them.  The kernel reads ``layer`` of the stacked
-    carry in place; everything else gets that layer's view.  Over a window
-    layer's ring lanes ``lengths`` are the positions the ring holds, all of
-    them inside the window."""
+    carry in place; everything else gets that layer's view.  ``held``:
+    (the positions each row's lane holds, the kernel's schedule over them:
+    ``_held``); over a window layer's ring lanes all of them lie inside the
+    window."""
     with _core_scope(kind):
-        return _attend_cached(cfg, attention_fn, q, kv, layer, lengths,
+        return _attend_cached(cfg, attention_fn, q, kv, layer, *held,
                               bool(kind.window))
 
 
-def _attend_cached(cfg, attention_fn, q, kv, layer, lengths, ring: bool):
+def _held(cfg: ModelConfig, attention_fn, lengths, stack):
+    """(``lengths``, the decode kernel's schedule of live steps over them)
+    for the lanes ``stack``.  The schedule hangs on the lengths alone, and
+    XLA does not lift it out of a layer loop (compiled for the v5e, PR 47:
+    its cumulative sum and compares stayed in the loop's body), so
+    ``decode_step`` builds it here, once a step, before the loop; None
+    where no kernel takes it, which then builds its own or is XLA's."""
+    if attention_fn is not None or not cfg.use_pallas_decode:
+        return lengths, None
+    from llm_instance_gateway_tpu.ops import pallas_decode_attention as pda
+
+    block_s, n_tiles = (pda.mla_tiles if cfg.latent_width
+                        else pda.lane_tiles)(stack)
+    if not n_tiles:
+        return lengths, None
+    return lengths, pda.decode_schedule(lengths, block_s, n_tiles)
+
+
+def _attend_cached(cfg, attention_fn, q, kv, layer, lengths, schedule,
+                   ring: bool):
     quant = len(kv) == 4
     if attention_fn is None and cfg.use_pallas_decode:
         from llm_instance_gateway_tpu.ops import pallas_decode_attention as pda
@@ -1015,8 +1035,10 @@ def _attend_cached(cfg, attention_fn, q, kv, layer, lengths, ring: bool):
         # streams half the bytes of the bf16 kernel.  (Both fall back to
         # XLA by themselves off-TPU and on unsupported shapes.)
         if quant:
-            return pda.decode_attention_quant(q, *kv, lengths, layer=layer)
-        return pda.decode_attention(q, *kv, lengths, layer=layer, ring=ring)
+            return pda.decode_attention_quant(q, *kv, lengths, layer=layer,
+                                              schedule=schedule)
+        return pda.decode_attention(q, *kv, lengths, layer=layer, ring=ring,
+                                    schedule=schedule)
     if quant and getattr(attention_fn, "quant_aware", False):
         # Quant-aware override (sharded_attention.make_cached_decode_quant):
         # raw int8 + scales go in; each shard's kernel dequantizes in VMEM,
@@ -1049,8 +1071,9 @@ def decode_step(
     their scatter index is pushed out of bounds, where XLA drops the
     update, and the attention takes them at length 0 (a free slot keeps its
     last request's position, so ``positions + 1`` is never 0 by itself),
-    for which the decode kernels copy no tile and compute no matmul
-    (``ops.pallas_decode_attention.held_tile``).  Without the mask a frozen
+    which the decode kernels' schedule leaves out: no tile copied, no
+    matmul, no grid step (``ops.pallas_decode_attention.decode_schedule``,
+    built here once for the layer loop).  Without the mask a frozen
     or empty row keeps stomping its lane at a stale position, which is
     fatal once a lane can be mid-chunk-stream for a DIFFERENT request
     while decode dispatches run (the concurrent-lane engine); lockstep
@@ -1073,6 +1096,7 @@ def decode_step(
     # What the attention reads of each lane: none of a row that sits out.
     read_lengths = (lengths if active is None
                     else jnp.where(active, lengths, 0))
+    held_full = _held(cfg, attention_fn, read_lengths, cache["k"])
     batch_idx = jnp.arange(b)
     s_max = cache["k"].shape[2]
     n_rec = 2 if "ssm" in cache else 0  # a mixer's (ssm, conv) in the carry
@@ -1087,13 +1111,14 @@ def decode_step(
         # many of them in the order they lie.
         ring = cache["k_win"].shape[2]
         ring_pos = jnp.where(write_pos < s_max, positions % ring, ring)
-        ring_lengths = jnp.minimum(read_lengths, ring)
+        held_ring = _held(cfg, attention_fn,
+                          jnp.minimum(read_lengths, ring), cache["k_win"])
 
     def latent_layer_fn(h, kv, layer, lp, layer_lora, kind, lane):
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         attn, kv = mla.decode_attend(
             cfg, lp, hn, positions, kv, (layer, batch_idx, write_pos),
-            read_lengths, layer)
+            held_full, layer)
         h, (kv, tally) = _finish_block(cfg, lp, h, attn, layer_lora,
                                        slot_ids, active, (kv,))
         return h, kv, tally
@@ -1110,8 +1135,8 @@ def decode_step(
         if kind.rope:
             q = apply_rope(q[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
             k = apply_rope(k[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
-        at, held = ((ring_pos, ring_lengths) if kind.window
-                    else (write_pos, read_lengths))
+        at, held = ((ring_pos, held_ring) if kind.window
+                    else (write_pos, held_full))
         own = _write_kv(own, (lane, batch_idx, at), k, v)
         attn = _decode_attend(cfg, attention_fn, q, own, lane, held, kind)
         kv = put_back(own)

@@ -11,12 +11,16 @@ index rides the scalar prefetch, and the tiles are taken from the cache's
 rows view (``_rows``), which costs no copy.
 
 Length masking is exact (positions >= length contribute nothing), matching
-the engine's garbage-tail cache contract.  A row of length 0 (a slot that
-does not decode in this step: ``transformer.decode_step`` zeroes the length
-of every row whose ``active`` bit is off) costs no tile and no matmul: its
-grid steps point at the tile the step before them holds (``held_tile``), so
-nothing is copied, nothing is computed, and the row emits zeros.  What it
-still costs is its grid steps themselves.  ``decode_attention`` is the
+the engine's garbage-tail cache contract.  The grid is ONE dimension over a
+schedule of the live steps (``decode_schedule``): for every row that reads
+something, its tiles up to its length, and nothing else.  A row of length 0
+(a slot that does not decode in this step: ``transformer.decode_step``
+zeroes the length of every row whose ``active`` bit is off) and the tiles
+past a short row's end are not in it, so they cost no copy, no matmul and
+no grid step; such a row emits zeros.  On the chip the grid's bound is the
+schedule's length, a dynamic bound; the interpreter takes none, so there
+the same kernel walks the same schedule under the static bound slots x
+tiles and the steps past its end do nothing.  ``decode_attention`` is the
 dispatching entry: the kernel on a TPU backend for every shape ``supports``
 accepts, the XLA reference otherwise — and it SAYS which, with the reason,
 each time a program is traced (``ops.attention.log_choice``).
@@ -42,41 +46,93 @@ from llm_instance_gateway_tpu.ops.attention import (
 NEG_INF = -1e30
 
 
-def live_source(lengths: jax.Array) -> jax.Array:
-    """[B] int32 for ``held_tile``: each row's nearest live row (length > 0)
-    at or before it; for the dead rows that lead, the first live row; 0
-    with no live row.  A live row is its own source.  Built from the
-    lengths alone, so in a layer loop it is loop-invariant."""
-    idx = jnp.arange(lengths.shape[0], dtype=jnp.int32)
-    live = lengths > 0
-    # A running maximum over the live rows' indices; row 0, when dead,
-    # stands in with the first live row's, which then leads the maximum up
-    # to that row.  (The cumulative maximum comes last so that XLA lifts
-    # all of this out of a layer loop: compiled for the v5e, a trailing
-    # select stayed inside it.)
-    first = jnp.argmax(live).astype(jnp.int32)
-    return jax.lax.cummax(
-        jnp.where(live, idx, jnp.where(idx == 0, first, -1)))
+def decode_schedule(lengths: jax.Array, block_s: int, n_tiles: int):
+    """The steps every decode kernel here walks, from the lengths alone (so
+    in a layer loop it is loop-invariant): for every row of length > 0 its
+    tiles 0 .. ceil(length / block_s) - 1, rows in slot order, tiles
+    ascending.  Returns (row [B * n_tiles], tile [B * n_tiles], n_steps
+    [1]), all int32: step ``i`` < ``n_steps`` works on tile ``tile[i]`` of
+    row ``row[i]``; the entries past ``n_steps`` repeat the last live
+    step's (of row 0's first tile when nothing is live), so that a walk
+    under a static bound copies nothing for them."""
+    b = lengths.shape[0]
+    tiles_of = jnp.minimum((lengths + block_s - 1) // block_s,
+                           n_tiles).astype(jnp.int32)
+    ends = jnp.cumsum(tiles_of)
+    n_steps = ends[-1:]
+    i = jnp.minimum(jnp.arange(b * n_tiles, dtype=jnp.int32),
+                    jnp.maximum(n_steps - 1, 0))
+    # the rows whose steps all lie before step i: their count is i's row
+    # (the dead rows on the way count themselves in), their tiles its start
+    done = ends[None, :] <= i[:, None]
+    row = jnp.minimum(jnp.sum(done, axis=1, dtype=jnp.int32), b - 1)
+    tile = i - jnp.sum(jnp.where(done, tiles_of, 0), axis=1)
+    return row, tile, n_steps
 
 
-def held_tile(bi, sb, lens, src, block_s: int):
-    """The index rule of every decode kernel here: (row, S-tile) of the
-    cache that grid step (row ``bi``, S-block ``sb``) holds.  A live row
-    sweeps its own tiles and clamps the blocks past its length to its last
-    live tile; a dead row (length 0) holds its source's last live tile, or,
-    leading, the first live row's first tile.  Either way a step that has
-    nothing to read names the tile of the step before it, and Pallas
-    copies a block only when its index changes: short rows cost bandwidth
-    by their length, not by S_max, and dead rows none."""
-    row = src[bi]
-    last = jnp.maximum(lens[row] - 1, 0) // block_s
-    tile = jnp.where(row == bi, jnp.minimum(sb, last),
-                     jnp.where(row < bi, last, 0))
-    return row, tile
+def _walk(lengths: jax.Array, schedule, block_s: int, n_tiles: int,
+          interpret: bool):
+    """(the scalar-prefetch operands of a walk over ``schedule``, its grid).
+    ``schedule`` None: built here from the lengths (a caller with a layer
+    loop builds it once before the loop, over ``lane_tiles`` or
+    ``mla_tiles``).  The bound is
+    the schedule's length where the backend lowers a dynamic one (Mosaic
+    does, the interpreter does not), else its capacity."""
+    capacity = lengths.shape[0] * n_tiles
+    if schedule is None:
+        schedule = decode_schedule(lengths, block_s, n_tiles)
+    row, tile, n_steps = schedule
+    if row.shape != (capacity,) or tile.shape != (capacity,):
+        raise ValueError(f"a schedule of {row.shape[0]} steps for a walk "
+                         f"of {capacity}: built for another tile")
+    grid = (capacity,) if interpret else (n_steps[0],)
+    return (lengths, row, tile, n_steps), grid
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *refs,
-                   block_s: int, n_kv: int, scale: float, quant: bool):
+def schedule_steps(lengths, block_s: int, n_tiles: int) -> int:
+    """``decode_schedule``'s ``n_steps`` by the same rule on the host, from
+    lengths the host holds (``tpu:decode_attn_grid_steps_total``)."""
+    return sum(min(-(-int(n) // block_s), n_tiles) for n in lengths)
+
+
+def lane_tiles(k_cache) -> tuple[int, int]:
+    """(positions a tile, tiles a lane) of the lane kernel's walk over
+    ``k_cache`` ([.., B, S, K, hd], bf16 or int8); (0, 0) where the kernel
+    takes no such cache (``shape_reasons``)."""
+    s_max = k_cache.shape[-3]
+    block_s = _pick_block(s_max, _row_bytes(k_cache))
+    return block_s, block_s and s_max // block_s
+
+
+def mla_tiles(rows) -> tuple[int, int]:
+    """``lane_tiles`` of the latent kernel over ``rows`` ([.., B, S, lanes])."""
+    s_max = rows.shape[-2]
+    block_s = _mla_block(s_max)
+    return block_s, block_s and s_max // block_s
+
+
+def _step(len_ref, row_ref, tile_ref, n_ref, block_s: int, s_max: int):
+    """Where this grid step stands: (it is one of the schedule's, its tile's
+    first position, its row's length).  It is its row's first step when the
+    tile starts at 0 and its last when the tile reaches the length."""
+    i = pl.program_id(0)
+    length = jnp.minimum(len_ref[row_ref[i]], s_max)
+    return i < n_ref[0], tile_ref[i] * block_s, length
+
+
+def _emit(out: jax.Array, lengths: jax.Array) -> jax.Array:
+    """A row the schedule never visits has no output block written: it
+    emits zeros, not an unwritten buffer (the engine masks such rows either
+    way)."""
+    return jnp.where((lengths > 0)[:, None, None], out, 0)
+
+
+_WALK = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+
+
+def _decode_kernel(len_ref, row_ref, tile_ref, n_ref, index_ref, q_ref,
+                   k_ref, v_ref, *refs, block_s: int, s_max: int, n_kv: int,
+                   scale: float, quant: bool):
     # q_ref: [1, H, hd]; k_ref/v_ref: [1, block_s*K, hd] — one S-tile of the
     # cache in its ROWS view: row s*K + kh is position s of kv head kh, which
     # is how the [.., S, K, hd] cache lies in HBM (``_rows``), so the tile
@@ -84,14 +140,18 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *refs,
     # through the MXU together (what a per-head grid would cost on the
     # chip: not measured): the [H, block_s*K] logits hold every query head
     # against every row, and the mask keeps the columns of its own kv head.
-    # len_ref: [B] (SMEM, scalar-prefetched).  The S-block axis is the
-    # innermost grid dim with "arbitrary" semantics: online-softmax state
-    # rides f32 VMEM scratch across the sweep, like the prefill flash kernel.
+    # len_ref [B], row_ref / tile_ref / n_ref (``decode_schedule``): SMEM,
+    # scalar-prefetched; index_ref (the lane kernel's layer index, the paged
+    # kernel's block table) is consumed by the index maps, not the body:
+    # only the DMA source moves.  The grid is sequential ("arbitrary"): a
+    # row's tiles follow one another, and the online-softmax state rides
+    # f32 VMEM scratch across them, like the prefill flash kernel.
     # ``quant``: K/V tiles arrive int8 with per-row f32 scales as lane
     # vectors (ks_ref/vs_ref: [1, 1, block_s*K]); int8 is exact in the
     # compute dtype, so the tiles feed the MXU as they are and the scales
     # multiply the logits' and the probabilities' columns — HBM streams
     # half the bytes of the bf16 variant, decode's actual bound.
+    del index_ref
     if quant:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -99,22 +159,18 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *refs,
     n_heads = q_ref.shape[1]
     g = n_heads // n_kv
     rows = block_s * n_kv
-    bi = pl.program_id(0)
-    sb = pl.program_id(1)
-    n_sb = pl.num_programs(1)
-    length = len_ref[bi]
-    start = sb * block_s
+    live, start, length = _step(len_ref, row_ref, tile_ref, n_ref, block_s,
+                                s_max)
 
-    @pl.when(sb == 0)
+    @pl.when(live & (start == 0))
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Blocks entirely past `length`, and every block of a row of length 0,
-    # do nothing (their DMA is elided too: ``held_tile`` names the tile the
-    # step before already holds); the straddling block masks.
-    @pl.when(start < length)
+    # Every step of the schedule holds a tile that starts inside its row's
+    # length; the tile that straddles the length masks.
+    @pl.when(live)
     def _compute():
         q = q_ref[0]  # [H, hd]
         k = k_ref[0]  # [rows, hd]
@@ -152,22 +208,11 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *refs,
         )
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
 
-    @pl.when(sb == n_sb - 1)
+    @pl.when(live & (start + block_s >= length))
     def _finalize():
-        # Rows with length == 0 (slots that do not decode in this step) never
-        # accumulate (l stays 0) and emit zeros, not an unwritten buffer;
-        # the engine masks such rows either way.
         o_ref[0] = (
             acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
         ).astype(o_ref.dtype)
-
-
-def _indexed_kernel(len_ref, src_ref, index_ref, *rest, **kw):
-    # The second and third scalar-prefetch operands (``live_source``; the
-    # lane kernel's layer index, the paged kernel's block table) are
-    # consumed by the index maps, not the body: only the DMA source moves.
-    del src_ref, index_ref
-    _decode_kernel(len_ref, *rest, **kw)
 
 
 def _layer_view(x: jax.Array, layer) -> jax.Array:
@@ -225,7 +270,8 @@ def _pick_block(s_max: int, row_bytes: int = 0) -> int:
 
 def _pallas_decode_call(q, k_all, v_all, scales, lengths, layer,
                         block_s: int | None, interpret: bool,
-                        name: str = "decode_attention") -> jax.Array:
+                        name: str = "decode_attention",
+                        schedule=None) -> jax.Array:
     """Shared pallas_call builder for the bf16 and int8 variants, over the
     STACKED cache [L, B, S, K, hd] and a layer index: the index rides the
     scalar prefetch into the tiles' index map, so the kernel reads the
@@ -243,21 +289,18 @@ def _pallas_decode_call(q, k_all, v_all, scales, lengths, layer,
     b, n_heads, hd = q.shape
     s_max, n_kv = k_all.shape[2], k_all.shape[3]
     if block_s is None:
-        block_s = _pick_block(s_max,
-                              n_kv * hd * jnp.dtype(k_all.dtype).itemsize)
+        block_s = lane_tiles(k_all)[0]
     rows = block_s * n_kv
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def q_index(bi, sb, lens, src, lay):
-        return (bi, 0, 0)
+    def q_index(i, lens, row, tile, n, lay):
+        return (row[i], 0, 0)
 
-    def kv_index(bi, sb, lens, src, lay):
-        row, tile = held_tile(bi, sb, lens, src, block_s)
-        return (lay[0], row, tile, 0)
+    def kv_index(i, lens, row, tile, n, lay):
+        return (lay[0], row[i], tile[i], 0)
 
-    def scale_index(bi, sb, lens, src, lay):
-        _, row, tile, _ = kv_index(bi, sb, lens, src, lay)
-        return (row, 0, tile)
+    def scale_index(i, lens, row, tile, n, lay):
+        return (row[i], 0, tile[i])
 
     quant = scales is not None
     in_specs = [
@@ -265,31 +308,32 @@ def _pallas_decode_call(q, k_all, v_all, scales, lengths, layer,
         pl.BlockSpec((None, 1, rows, hd), kv_index),
         pl.BlockSpec((None, 1, rows, hd), kv_index),
     ]
-    operands = [lengths, live_source(lengths), layer, q,
-                _rows(k_all), _rows(v_all)]
+    walk, grid = _walk(lengths, schedule, block_s, s_max // block_s,
+                       interpret)
+    operands = [*walk, layer, q, _rows(k_all), _rows(v_all)]
     if quant:
         in_specs += [pl.BlockSpec((1, 1, rows), scale_index)] * 2
         operands += [_scale_rows(s) for s in scales]
-    kernel = functools.partial(_indexed_kernel, block_s=block_s, n_kv=n_kv,
-                               scale=float(1.0 / (hd ** 0.5)), quant=quant)
-    return pl.pallas_call(
+    kernel = functools.partial(_decode_kernel, block_s=block_s, s_max=s_max,
+                               n_kv=n_kv, scale=float(1.0 / (hd ** 0.5)),
+                               quant=quant)
+    out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, n_heads, hd), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            # lengths: masking + DMA clamping; their live_source: what a
-            # dead row's steps hold; layer: which of the stack
-            num_scalar_prefetch=3,
-            grid=(b, s_max // block_s),
+            # lengths and their schedule: masking + which tile a step
+            # holds; layer: which of the stack
+            num_scalar_prefetch=5,
+            grid=grid,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, n_heads, hd), q_index),
             scratch_shapes=_scratch(n_heads, hd),
         ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
+        compiler_params=_WALK,
         interpret=interpret,
         name="decode_attention_int8" if quant else name,
     )(*operands)
+    return _emit(out, lengths)
 
 
 def decode_attention_pallas(
@@ -301,9 +345,10 @@ def decode_attention_pallas(
     block_s: int | None = None,
     interpret: bool = False,
     name: str = "decode_attention",
+    schedule=None,       # ``decode_schedule`` over ``lane_tiles``, built ahead
 ) -> jax.Array:
     return _pallas_decode_call(q, k_cache, v_cache, None, lengths, layer,
-                               block_s, interpret, name)
+                               block_s, interpret, name, schedule)
 
 
 def decode_attention_quant_pallas(
@@ -316,9 +361,11 @@ def decode_attention_quant_pallas(
     layer=None,
     block_s: int | None = None,
     interpret: bool = False,
+    schedule=None,
 ) -> jax.Array:
     return _pallas_decode_call(q, k_cache, v_cache, (k_scale, v_scale),
-                               lengths, layer, block_s, interpret)
+                               lengths, layer, block_s, interpret,
+                               schedule=schedule)
 
 
 def shape_reasons(s_max: int, hd: int, row_bytes: int = 0) -> list[str]:
@@ -385,12 +432,11 @@ def paged_decode_attention_pallas(
     the bytes decode is bound by).  Here the BLOCK TABLE rides the scalar
     prefetch (vLLM-PagedAttention's indirection, Pallas-style): the index
     map of each (row, logical-block) grid cell looks up the physical block
-    and the DMA streams it straight from the pool, once.  Dead blocks
-    (start >= length) clamp to the row's last live LOGICAL block, and a
-    row of length 0 holds its source row's (``held_tile``) — whose
-    physical index the revisited map returns again, so Mosaic elides their
-    copies exactly like the lane kernel.  Composes with int8 pools: scale
-    columns ride the same indirection.
+    and the DMA streams it straight from the pool, once.  The grid walks
+    the lane kernel's schedule (``decode_schedule``, a tile being one
+    logical block): the blocks past a row's length and the rows of length 0
+    are no step of it.  Composes with int8 pools: scale columns ride the
+    same indirection.
     """
     b, n_heads, hd = q.shape
     n_kv = k_pool.shape[2]
@@ -398,11 +444,11 @@ def paged_decode_attention_pallas(
     m = tables.shape[1]
     rows = block * n_kv
 
-    def q_index(bi, sb, lens, src, tabs):
-        return (bi, 0, 0)
+    def q_index(i, lens, row, tile, n, tabs):
+        return (row[i], 0, 0)
 
-    def kv_index(bi, sb, lens, src, tabs):
-        return (tabs[held_tile(bi, sb, lens, src, block)], 0, 0)
+    def kv_index(i, lens, row, tile, n, tabs):
+        return (tabs[row[i], tile[i]], 0, 0)
 
     quant = k_scale is not None
     in_specs = [
@@ -410,33 +456,34 @@ def paged_decode_attention_pallas(
         pl.BlockSpec((1, rows, hd), kv_index),
         pl.BlockSpec((1, rows, hd), kv_index),
     ]
-    operands = [lengths, live_source(lengths), tables, q,
-                _rows(k_pool), _rows(v_pool)]
+    walk, grid = _walk(lengths, None, block, m, interpret)
+    operands = [*walk, tables, q, _rows(k_pool), _rows(v_pool)]
     if quant:
         in_specs += [pl.BlockSpec((1, 1, rows), kv_index)] * 2
         operands += [_scale_rows(k_scale), _scale_rows(v_scale)]
-    # Same body as the lane kernel — the logical S-block index (grid dim 1)
-    # drives masking exactly as there; the table routes the DMA.
-    kernel = functools.partial(_indexed_kernel, block_s=block, n_kv=n_kv,
-                               scale=float(1.0 / (hd ** 0.5)), quant=quant)
-    return pl.pallas_call(
+    # Same body as the lane kernel — the step's logical block drives the
+    # masking exactly as there; the table routes the DMA.
+    kernel = functools.partial(_decode_kernel, block_s=block, s_max=block * m,
+                               n_kv=n_kv, scale=float(1.0 / (hd ** 0.5)),
+                               quant=quant)
+    out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, n_heads, hd), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            # lengths (masking), live_source + tables (DMA routing)
-            num_scalar_prefetch=3,
-            grid=(b, m),
+            # lengths and their schedule (masking, which block a step
+            # holds), tables (DMA routing)
+            num_scalar_prefetch=5,
+            grid=grid,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, n_heads, hd), q_index),
             scratch_shapes=_scratch(n_heads, hd),
         ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
+        compiler_params=_WALK,
         interpret=interpret,
         name=("paged_decode_attention_int8" if quant
               else "paged_decode_attention"),
     )(*operands)
+    return _emit(out, lengths)
 
 
 def paged_decode_attention(
@@ -476,27 +523,26 @@ def paged_decode_attention(
 # ---------------------------------------------------------------------------
 
 
-def _mla_kernel(len_ref, src_ref, layer_ref, q_ref, c_ref, o_ref, m_scr,
-                l_scr, acc_scr, *, block_s: int, n_values: int, scale: float):
+def _mla_kernel(len_ref, row_ref, tile_ref, n_ref, layer_ref, q_ref, c_ref,
+                o_ref, m_scr, l_scr, acc_scr, *, block_s: int, s_max: int,
+                n_values: int, scale: float):
     # q_ref: [1, H, lanes], the absorbed queries; c_ref: [1, block_s, lanes],
     # one S-tile of the layer's latent rows [c | k_rope | 0].  The tile is
     # read from HBM once and used twice: all its columns are the keys of
     # EVERY head (one [H, lanes] x [lanes, block_s] matmul, no head of it
     # masked away), its first ``n_values`` columns the values.  The same
-    # online-softmax sweep as ``_decode_kernel``.
-    del src_ref, layer_ref  # consumed by the index maps
-    bi = pl.program_id(0)
-    sb = pl.program_id(1)
-    length = len_ref[bi]
-    start = sb * block_s
+    # walk of the schedule and online-softmax sweep as ``_decode_kernel``.
+    del layer_ref  # consumed by the index maps
+    live, start, length = _step(len_ref, row_ref, tile_ref, n_ref, block_s,
+                                s_max)
 
-    @pl.when(sb == 0)
+    @pl.when(live & (start == 0))
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(start < length)
+    @pl.when(live)
     def _compute():
         q = q_ref[0]
         s = jax.lax.dot_general(
@@ -515,7 +561,7 @@ def _mla_kernel(len_ref, src_ref, layer_ref, q_ref, c_ref, o_ref, m_scr,
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
 
-    @pl.when(sb == pl.num_programs(1) - 1)
+    @pl.when(live & (start + block_s >= length))
     def _finalize():
         o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
                     ).astype(o_ref.dtype)
@@ -551,6 +597,7 @@ def mla_decode_attention_pallas(
     layer=None,
     block_s: int | None = None,
     interpret: bool = False,
+    schedule=None,      # ``decode_schedule`` over ``mla_tiles``, built ahead
 ) -> jax.Array:
     if layer is None:
         rows, layer = rows[None], 0
@@ -559,42 +606,43 @@ def mla_decode_attention_pallas(
     block_s = block_s or _mla_block(s_max)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def q_index(bi, sb, lens, src, lay):
-        return (bi, 0, 0)
+    def q_index(i, lens, row, tile, n, lay):
+        return (row[i], 0, 0)
 
-    def row_index(bi, sb, lens, src, lay):
-        # Dead S-blocks and dead rows revisit a held tile: no DMA.
-        row, tile = held_tile(bi, sb, lens, src, block_s)
-        return (lay[0], row, tile, 0)
+    def row_index(i, lens, row, tile, n, lay):
+        return (lay[0], row[i], tile[i], 0)
 
-    kernel = functools.partial(_mla_kernel, block_s=block_s,
+    walk, grid = _walk(lengths, schedule, block_s, s_max // block_s,
+                       interpret)
+    kernel = functools.partial(_mla_kernel, block_s=block_s, s_max=s_max,
                                n_values=n_values, scale=float(scale))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, n_heads, n_values), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,  # lengths, their live_source, layer
-            grid=(b, s_max // block_s),
+            num_scalar_prefetch=5,  # lengths, their schedule, layer
+            grid=grid,
             in_specs=[pl.BlockSpec((1, n_heads, lanes), q_index),
                       pl.BlockSpec((None, 1, block_s, lanes), row_index)],
             out_specs=pl.BlockSpec((1, n_heads, n_values), q_index),
             scratch_shapes=_scratch(n_heads, n_values),
         ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
+        compiler_params=_WALK,
         interpret=interpret,
         name="mla_decode_attention",
-    )(lengths, live_source(lengths), layer, q, rows)
+    )(*walk, layer, q, rows)
+    return _emit(out, lengths)
 
 
 def mla_decode_attention(
     q: jax.Array, rows: jax.Array, lengths: jax.Array, n_values: int,
     scale: float, layer=None, use_kernel: bool = True,
-    interpret: bool = False,
+    interpret: bool = False, schedule=None,
 ) -> jax.Array:
     """Dispatch for the latent cache: the kernel over the stacked rows and
-    a layer index, the XLA form (``latent_decode_attention``) otherwise."""
+    a layer index, the XLA form (``latent_decode_attention``) otherwise.
+    ``schedule``: the kernel's (``decode_schedule`` over ``mla_tiles``),
+    where the caller built it ahead of its layer loop."""
     reason = ("pallas kernels off in the config" if not use_kernel
               else kernel_reason(
                   mla_shape_reasons(rows.shape[-2], rows.shape[-1], n_values),
@@ -605,12 +653,13 @@ def mla_decode_attention(
         return latent_decode_attention(q, _layer_view(rows, layer), lengths,
                                        n_values, scale)
     return mla_decode_attention_pallas(q, rows, lengths, n_values, scale,
-                                       layer, interpret=interpret)
+                                       layer, interpret=interpret,
+                                       schedule=schedule)
 
 
 def decode_attention(
     q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, lengths: jax.Array,
-    layer=None, interpret: bool = False, ring: bool = False,
+    layer=None, interpret: bool = False, ring: bool = False, schedule=None,
 ) -> jax.Array:
     """Dispatch: Pallas kernel when shapes allow, XLA reference otherwise.
     With ``layer`` the caches are the stacked [L, B, S, K, hd] arrays and
@@ -618,7 +667,10 @@ def decode_attention(
     layer's rings, of which ``lengths`` says how many positions are held
     (the order of a softmax's keys does not matter, and each key's rotary
     encoding went on when it was written); the same kernel, named
-    ``decode_attention_window`` so that a trace tells the two apart."""
+    ``decode_attention_window`` so that a trace tells the two apart.
+    ``schedule``: the kernel's (``decode_schedule`` over ``lane_tiles``),
+    where the caller built it ahead of its layer loop; built here
+    otherwise."""
     s_max, hd = k_cache.shape[-3], k_cache.shape[-1]
     reason = kernel_reason(
         shape_reasons(s_max, hd, _row_bytes(k_cache)), interpret)
@@ -630,13 +682,14 @@ def decode_attention(
                           _layer_view(v_cache, layer), lengths)
     return decode_attention_pallas(
         q, k_cache, v_cache, lengths, layer, interpret=interpret,
-        name="decode_attention_window" if ring else "decode_attention")
+        name="decode_attention_window" if ring else "decode_attention",
+        schedule=schedule)
 
 
 def decode_attention_quant(
     q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     k_scale: jax.Array, v_scale: jax.Array, lengths: jax.Array,
-    layer=None, interpret: bool = False,
+    layer=None, interpret: bool = False, schedule=None,
 ) -> jax.Array:
     """int8-KV dispatch: the quantized kernel streams half the HBM
     bytes AND skips the logits materialization; unsupported shapes / CPU
@@ -655,4 +708,4 @@ def decode_attention_quant(
         return xla_decode(q, deq, dev, lengths)
     return decode_attention_quant_pallas(
         q, k_cache, v_cache, k_scale, v_scale, lengths, layer,
-        interpret=interpret)
+        interpret=interpret, schedule=schedule)

@@ -44,6 +44,7 @@ from llm_instance_gateway_tpu.lockwitness import witness_lock
 from llm_instance_gateway_tpu.models import paged as paged_lib
 from llm_instance_gateway_tpu.models import transformer
 from llm_instance_gateway_tpu.models.configs import ModelConfig
+from llm_instance_gateway_tpu.ops import pallas_decode_attention as pda
 from llm_instance_gateway_tpu.server.sampling import (
     STOP_LEN,
     STOP_SEQS,
@@ -685,6 +686,14 @@ class Engine:
                 model_cfg, b, self.cfg.max_seq_len, dtype=dtype,
                 quantized=self._kv_quant,
             )
+        # (positions a tile, tiles a lane) of the decode-attention kernel's
+        # walk over this cache, for tpu:decode_attn_grid_steps_total: a
+        # page of the pool, else the kernel's own tile; (0, 0) where no
+        # kernel takes the cache's shape.
+        self._attn_tiles = (
+            (self._block, self._max_blocks_per_seq) if self.paged
+            else pda.mla_tiles(self.cache["k"]) if self._latent
+            else pda.lane_tiles(self.cache["k"]))
         # Sharded serving (SURVEY §2.5/§7 ICI domain): pin params and the
         # decode cache to the mesh via GSPMD specs; every jitted step then
         # partitions from its committed inputs — XLA inserts the ICI
@@ -1380,15 +1389,20 @@ class Engine:
             # host holds (a row that stops mid-block counts on to its end).
             self.profiler.note_ssm_rows(
                 n_steps * sum(s is not None for s in self.slots))
+        # Step j of the block reads position + 1 + j rows of a live row's
+        # lane.  With a block still unread (the overlapped loop) the host
+        # record is that block's steps behind the device, for every row but
+        # one activated since; a row that stops mid-block counts on to the
+        # block's end.
+        lag = self._inflight["n_steps"] if self._inflight else 0
+        at = [s.position + (0 if self._slot_fresh[i] else lag)
+              for i, s in enumerate(self.slots) if s is not None]
+        if self._attn_tiles[1]:
+            # ... in the tiles of the kernel's schedule, a layer's call.
+            self.profiler.note_attn_grid_steps(sum(
+                pda.schedule_steps([p + j for p in at], *self._attn_tiles)
+                for j in range(1, n_steps + 1)))
         if self._latent or self._window:
-            # Step j of the block reads position + 1 + j rows of a live
-            # row's lane.  With a block still unread (the overlapped loop)
-            # the host record is that block's steps behind the device, for
-            # every row but one activated since; a row that stops mid-block
-            # counts on to the block's end.
-            lag = self._inflight["n_steps"] if self._inflight else 0
-            at = [s.position + (0 if self._slot_fresh[i] else lag)
-                  for i, s in enumerate(self.slots) if s is not None]
             held = sum(at) * n_steps + len(at) * n_steps * (n_steps + 1) // 2
             if self._latent:
                 self.profiler.note_latent_positions(held)

@@ -178,6 +178,12 @@ class StepProfiler:
         # window layer's ring stops at the window.  0 for a model without
         # a window.
         self.kv_positions = [0] * len(KV_LANES)
+        # Grid steps the decode-attention kernel walks a layer's call (the
+        # live rows' tiles: ``pallas_decode_attention.decode_schedule``),
+        # summed over the steps of the plain decode dispatches; of a stack
+        # with two kinds of lane, the full lanes'.  0 where no kernel takes
+        # the cache's shape.
+        self.attn_grid_steps = 0
         # Decode blocks dispatched while an earlier block was still unread:
         # the device then had its next step queued before the host read
         # the last.  Over the decode and spec dispatches: the share of
@@ -446,6 +452,12 @@ class StepProfiler:
             self.kv_positions[0] += full
             self.kv_positions[1] += window
 
+    def note_attn_grid_steps(self, n: int) -> None:
+        """Count ``n`` grid steps the decode-attention kernel's schedule
+        held a layer's call over the steps of one plain decode dispatch."""
+        with self._lock:
+            self.attn_grid_steps += n
+
     def note_overlapped_block(self) -> None:
         """Count one decode block dispatched while an earlier block was
         still unread."""
@@ -468,6 +480,7 @@ class StepProfiler:
                 "latent_positions": self.latent_positions,
                 "ssm_rows": self.ssm_rows,
                 "kv_positions": dict(zip(KV_LANES, self.kv_positions)),
+                "attn_grid_steps": self.attn_grid_steps,
                 "blocks_overlapped": self.blocks_overlapped,
             }
         out["phases"] = self.phase_seconds()
@@ -558,6 +571,10 @@ def render_profile(hist: dict) -> list[str]:
         lines += [
             f'tpu:kv_positions_read_total{{lanes="{escape_label(lanes)}"}} '
             f'{n}' for lanes, n in kv_positions.items()]
+    if "attn_grid_steps" in hist:
+        lines += ["# TYPE tpu:decode_attn_grid_steps_total counter",
+                  "tpu:decode_attn_grid_steps_total "
+                  f"{hist['attn_grid_steps']}"]
     if "blocks_overlapped" in hist:
         lines += ["# TYPE tpu:decode_blocks_overlapped_total counter",
                   "tpu:decode_blocks_overlapped_total "

@@ -466,6 +466,43 @@ def test_base_rows_book_no_adapter_row():
         engine.metrics_snapshot()) + "\n"
 
 
+@pytest.mark.parametrize("max_seq_len,n_prompt,tiles", [
+    (384, 126, (128, 3)), (256, 3, (256, 1)), (64, 3, (0, 0))],
+    ids=["crosses-a-tile", "one-tile", "no-kernel-for-the-shape"])
+def test_grid_steps_are_booked_by_the_schedules_rule(max_seq_len, n_prompt,
+                                                     tiles):
+    """``tpu:decode_attn_grid_steps_total``: a decode step books the tiles
+    the kernel's schedule holds of its live rows' lanes (the first new
+    token comes from the prefill, so the steps read n_prompt + 1, + 2, ...
+    positions), by ``decode_schedule``'s rule on the host; nothing where no
+    kernel takes the cache's shape."""
+    from llm_instance_gateway_tpu.ops import pallas_decode_attention as pda
+
+    cfg = MODELS["dense"]
+    engine = Engine(
+        cfg, transformer.init_params(cfg, jax.random.PRNGKey(0),
+                                     dtype=jnp.float32),
+        EngineConfig(decode_slots=2, max_seq_len=max_seq_len,
+                     prefill_buckets=(8, 128), pipeline_decode=False),
+        eos_id=None, dtype=jnp.float32)
+    assert engine._attn_tiles == pda.lane_tiles(engine.cache["k"]) == tiles
+    engine.start()
+    try:
+        req = engine.generate(
+            Request([3 + i % 50 for i in range(n_prompt)], 6), timeout_s=180)
+        assert req.error is None, req.error
+    finally:
+        engine.stop()
+    steps = engine.profiler.dispatches["decode"]
+    want = sum(-(-(n_prompt + 1 + j) // tiles[0]) for j in range(steps)
+               ) if tiles[1] else 0
+    assert steps >= 5 and (want > steps or tiles[1] < 2)
+    hist = engine.profiler.hist_state()
+    assert hist["attn_grid_steps"] == want
+    assert f"tpu:decode_attn_grid_steps_total {want}\n" in metrics.render(
+        engine.metrics_snapshot()) + "\n"
+
+
 @pytest.fixture(scope="module")
 def idle_engine():
     return build(Engine, "dense", False)

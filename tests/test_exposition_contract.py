@@ -154,6 +154,7 @@ def server_snapshot() -> dict:
     prof.note_lora_rows(3)  # tpu:lora_rows_total
     prof.note_overlapped_block()  # tpu:decode_blocks_overlapped_total
     prof.note_latent_positions(41)  # tpu:latent_kv_positions_total
+    prof.note_attn_grid_steps(17)  # tpu:decode_attn_grid_steps_total
     return {
         "profile": prof.hist_state(),
         "model_name": HOSTILE,
@@ -284,6 +285,7 @@ def test_server_render_contract():
     assert families["tpu:lora_rows_total"][0].value == 3
     assert families["tpu:decode_blocks_overlapped_total"][0].value == 1
     assert families["tpu:latent_kv_positions_total"][0].value == 41
+    assert families["tpu:decode_attn_grid_steps_total"][0].value == 17
     assert families["tpu:decode_stage_ops_total"][0].value == 0
     # Decode fast-path families (adaptive dispatch + stream lanes).
     assert families["tpu:stream_lanes"][0].value == 2

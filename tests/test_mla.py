@@ -536,6 +536,31 @@ def test_kernel_compiles_for_the_v5e_at_published_widths(one_chip):
     assert "mla_decode_attention" in text and "tpu_custom_call" in text
 
 
+def test_lane_kernel_compiles_for_the_v5e_with_its_dynamic_grid(one_chip):
+    """Qwen2.5-7B's lanes, 32 slots x 2,048 positions over 28 layers (here,
+    beside the other compiles for the chip: one process may describe it):
+    the chip's compiler takes the lane kernel with its grid's bound a
+    runtime value, the schedule's length, which the interpreter the other
+    tests run under cannot take."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    fn = jax.jit(lambda q, k, v, lens, layer: pda.decode_attention_pallas(
+        q, k, v, lens, layer=layer))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        lanes = sd((28, 32, 2048, 4, 128), jnp.bfloat16)
+        compiled = fn.lower(sd((32, 28, 128), jnp.bfloat16), lanes, lanes,
+                            sd((32,), jnp.int32), sd((), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "decode_attention" in text and "tpu_custom_call" in text
+
+
 def test_ssm_update_kernel_compiles_for_the_v5e_at_published_widths(one_chip):
     """Falcon-H1-34B's state, 64 slots x 8 layers of 32 x 256 x 128 float32
     (2 GiB), through ``ssm_decode_update`` as the cell runs it (here, beside

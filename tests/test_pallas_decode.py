@@ -80,8 +80,7 @@ class TestDecodeKernel:
     @pytest.mark.parametrize("h,kv", [(28, 4), (32, 8)])
     def test_short_rows_in_a_long_cache(self, h, kv):
         """Rows of 1..40 tokens in a 1024-position cache, four S-blocks of
-        256: the dead blocks (clamped to the last live tile, never
-        computed) hold poison."""
+        256: the dead blocks (no step of the schedule) hold poison."""
         q, k, v, _ = make_inputs(b=3, h=h, kv=kv, s=1024, seed=9)
         lengths = jnp.asarray([1, 17, 40], jnp.int32)
         ref = xla_decode(q, k, v, lengths)
@@ -101,16 +100,16 @@ class TestDecodeKernel:
                                    rtol=2e-5, atol=2e-5)
 
     def test_multi_block_recurrence(self):
-        # Force n_sb > 1 so the cross-block online-softmax carry (scratch
-        # m/l/acc, corr rescaling) and the dead-block DMA clamp actually run;
-        # the default _pick_block(256) would cover s=256 in a single step.
+        # Force several tiles a row so the cross-block online-softmax carry
+        # (scratch m/l/acc, corr rescaling) actually runs; the default
+        # _pick_block(256) would cover s=256 in a single step.
         q, k, v, lengths = make_inputs(s=256, seed=7)
         ref = xla_decode(q, k, v, lengths)
         got = pda.decode_attention_pallas(q, k, v, lengths, block_s=64,
                                           interpret=True)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
                                    rtol=2e-5, atol=2e-5)
-        # Short rows exercise the clamp-to-last-live-tile index map.
+        # Short rows end their sweep before the lane does.
         short = jnp.minimum(lengths, 70)
         ref_s = xla_decode(q, k, v, short)
         got_s = pda.decode_attention_pallas(q, k, v, short, block_s=64,
@@ -212,45 +211,8 @@ class TestRowsThatDoNotDecode:
         assert np.all(np.isfinite(np.asarray(xla_decode(q, k, v, lengths))))
 
     @pytest.mark.parametrize("pattern", LIVE_PATTERNS)
-    def test_walking_the_grid_a_dead_step_holds_the_tile_before_it(
-            self, pattern):
-        """``held_tile`` over the grid in the order Pallas walks it: a live
-        row's indices are the clamp's of always, every other step names
-        the tile of the step before it, so the cache is copied once a live
-        tile and never for a dead row."""
-        block_s, n_sb = 64, 4
-        live, lengths = masked_lengths(pattern)
-        bi = jnp.repeat(jnp.arange(6), n_sb)
-        sb = jnp.tile(jnp.arange(n_sb), 6)
-        row, tile = pda.held_tile(bi, sb, lengths, pda.live_source(lengths),
-                                  block_s)
-        steps = list(zip(np.asarray(row).tolist(), np.asarray(tile).tolist()))
-        lens = np.asarray(lengths)
-        for i, (b, s) in enumerate(zip(bi.tolist(), sb.tolist())):
-            if live[b]:
-                assert steps[i] == (b, min(s, (lens[b] - 1) // block_s))
-            elif i:
-                assert steps[i] == steps[i - 1]
-        if live.any():  # the dead rows that lead hold what the first live row starts on
-            first = int(np.argmax(live))
-            assert steps[0] == (first, 0)
-        else:
-            assert set(steps) == {(0, 0)}
-        copies = 1 + sum(a != b for a, b in zip(steps, steps[1:]))
-        assert copies == max(1, int(sum(-(-lens[live] // block_s))))
-
-    def test_a_caller_that_passes_no_zero_gets_todays_index_map(self):
-        lengths = jnp.asarray(STALE_LENGTHS, jnp.int32)
-        src = pda.live_source(lengths)
-        assert np.asarray(src).tolist() == list(range(6))
-        for b in range(6):
-            for s in range(4):
-                assert tuple(map(int, pda.held_tile(b, s, lengths, src, 64))
-                             ) == (b, min(s, (STALE_LENGTHS[b] - 1) // 64))
-
-    @pytest.mark.parametrize("pattern", LIVE_PATTERNS)
     def test_paged_and_latent_kernels_share_the_rule(self, pattern):
-        """The paged kernel (the table routes ``held_tile``'s row and tile)
+        """The paged kernel (the table routes the schedule's row and tile)
         and the latent kernel: live rows as with every slot live, dead rows
         zeros."""
         from llm_instance_gateway_tpu.ops.attention import (
@@ -280,6 +242,186 @@ class TestRowsThatDoNotDecode:
         np.testing.assert_array_equal(got[~live], 0.0)
         ref = np.asarray(latent_decode_attention(ql, rows, stale, 128, 0.1))
         np.testing.assert_allclose(ref[live], got[live], rtol=2e-5, atol=2e-5)
+
+
+# (tile length, tiles a lane): the lane kernel's at Qwen's lanes and at
+# SmallThinker's full lanes, a page of a paged pool, the latent kernel's.
+SCHEDULE_SHAPES = {"lane-512x4": (512, 4), "lane-512x32": (512, 32),
+                   "paged-64x4": (64, 4), "paged-16x128": (16, 128),
+                   "latent-1024x4": (1024, 4)}
+# Six slots' lengths as shares of a lane: mixed with dead rows first, between
+# and last; rows that end on a tile's edge; one live row; none; all full.
+SCHEDULE_ROWS = {
+    "mixed": [0, 0.3, 0, 0.01, 1.0, 0],
+    "edges": [0.25, 0.5, 0, 0.75, 1.0, 0.25],
+    "one": [0, 0, 0, 0.6, 0, 0],
+    "none": [0] * 6,
+    "full": [1.0] * 6,
+}
+
+
+class TestDecodeSchedule:
+    """``decode_schedule`` as a pure function of the lengths."""
+
+    @pytest.mark.parametrize("rows", SCHEDULE_ROWS)
+    @pytest.mark.parametrize("shape", SCHEDULE_SHAPES)
+    def test_every_live_tile_once_in_slot_then_tile_order(self, shape, rows):
+        block_s, n_tiles = SCHEDULE_SHAPES[shape]
+        s_max = block_s * n_tiles
+        lens = [int(np.ceil(share * s_max)) for share in SCHEDULE_ROWS[rows]]
+        row, tile, n_steps = pda.decode_schedule(
+            jnp.asarray(lens, jnp.int32), block_s, n_tiles)
+        assert row.shape == tile.shape == (6 * n_tiles,)
+        assert row.dtype == tile.dtype == n_steps.dtype == jnp.int32
+        n = int(n_steps[0])
+        want = [(b, t) for b, held in enumerate(lens)
+                for t in range(-(-held // block_s))]
+        assert n == len(want) == sum(-(-held // block_s) for held in lens)
+        assert n == pda.schedule_steps(lens, block_s, n_tiles)
+        steps = list(zip(np.asarray(row).tolist(), np.asarray(tile).tolist()))
+        assert steps[:n] == want  # slot order, tiles ascending, no dead row
+        # past its end the schedule repeats its last step: a walk under a
+        # static bound copies nothing more
+        assert set(steps[n:]) <= {want[-1] if want else (5, 0)}
+        if rows == "full":
+            assert n == 6 * n_tiles  # the rectangle
+        if rows == "none":
+            assert n == 0
+
+    def test_a_length_past_the_lane_is_held_to_the_lane(self):
+        row, tile, n_steps = pda.decode_schedule(
+            jnp.asarray([300, 0, 1000], jnp.int32), 64, 4)
+        assert int(n_steps[0]) == 8 == pda.schedule_steps([300, 0, 1000], 64, 4)
+        assert int(tile.max()) == 3
+
+    def test_a_schedule_built_for_another_tile_is_refused(self):
+        q, k, v, lengths = make_inputs(s=256)
+        with pytest.raises(ValueError, match="another tile"):
+            pda.decode_attention_pallas(
+                q, k, v, lengths, block_s=64, interpret=True,
+                schedule=pda.decode_schedule(lengths, 128, 2))
+
+    @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+    def test_the_kernels_tiles_are_the_schedules(self, quant):
+        """``lane_tiles`` / ``mla_tiles`` name the tile the kernels pick by
+        themselves (a schedule built over them is the kernel's own), and
+        ``decode_step`` builds one where there is a tile and none where
+        there is not."""
+        from llm_instance_gateway_tpu.models import transformer
+        from llm_instance_gateway_tpu.models.configs import TINY_TEST
+
+        lens = jnp.asarray([5, 0, 700], jnp.int32)
+        k = jnp.zeros((2, 3, 2048, 4, 128), jnp.int8 if quant else jnp.bfloat16)
+        assert pda.lane_tiles(k) == (512, 4)
+        held = transformer._held(TINY_TEST, None, lens, k)
+        assert held[0] is lens
+        for got, want in zip(held[1], pda.decode_schedule(lens, 512, 4)):
+            np.testing.assert_array_equal(got, want)
+        wide = jnp.zeros((3, 2048, 32, 128), jnp.bfloat16)  # MHA: 256 a tile
+        assert pda.lane_tiles(wide) == (256, 8)
+        assert pda.mla_tiles(jnp.zeros((2, 3, 4096, 640), jnp.bfloat16)) == (
+            1024, 4)
+        odd = jnp.zeros((3, 200, 4, 128), jnp.bfloat16)
+        assert pda.lane_tiles(odd) == pda.mla_tiles(odd[..., 0, :]) == (0, 0)
+        assert transformer._held(TINY_TEST, None, lens, odd) == (lens, None)
+        # an attention override (--mesh) builds its own, shard by shard
+        assert transformer._held(TINY_TEST, lambda *a: None, lens, k) == (
+            lens, None)
+
+
+# Six slots of a 256-position lane in tiles of 64: dead rows first, dead rows
+# between live ones, a row that ends on a tile's edge, one live row, none.
+MIXED_BATCHES = {
+    "leading-dead": [0, 0, 130, 5, 256, 33],
+    "dead-between": [70, 0, 0, 200, 0, 1],
+    "tile-edges": [64, 128, 0, 192, 256, 0],
+    "one-live": [0, 0, 0, 0, 97, 0],
+    "none": [0] * 6,
+}
+
+
+class TestWalkingTheSchedule:
+    """The three kernels under the interpreter (the schedule under its
+    static bound) against the XLA references on mixed batches: live rows
+    right, dead rows exact zeros."""
+
+    @staticmethod
+    def check(got, ref, lengths):
+        live = np.asarray(lengths) > 0
+        got = np.asarray(got)
+        np.testing.assert_allclose(np.asarray(ref)[live], got[live],
+                                   rtol=2e-5, atol=2e-5)
+        np.testing.assert_array_equal(got[~live], 0.0)
+
+    @pytest.mark.parametrize("batch", MIXED_BATCHES)
+    @pytest.mark.parametrize("variant", ["bf16", "int8", "ring", "prebuilt"])
+    def test_lane_kernel(self, variant, batch):
+        from llm_instance_gateway_tpu.models.transformer import (
+            _kv_dequantize, _kv_quantize)
+
+        q, k, v, _ = make_inputs(b=6, h=28, kv=4, s=256, seed=31)
+        lengths = jnp.asarray(MIXED_BATCHES[batch], jnp.int32)
+        if variant == "int8":
+            (k8, ks), (v8, vs) = _kv_quantize(k), _kv_quantize(v)
+            got = pda.decode_attention_quant_pallas(
+                q, stack_at(k8, 1), stack_at(v8, 1), stack_at(ks, 1),
+                stack_at(vs, 1), lengths, layer=jnp.int32(1), block_s=64,
+                interpret=True)
+            k, v = (_kv_dequantize(k8, ks, jnp.float32),
+                    _kv_dequantize(v8, vs, jnp.float32))
+        elif variant == "ring":
+            # the dispatcher's own tile (one of 256) and the ring's name
+            got = jax.jit(lambda q, k, v, lay: pda.decode_attention(
+                q, k, v, lengths, layer=lay, interpret=True, ring=True))(
+                    q, stack_at(k, 1), stack_at(v, 1), jnp.int32(1))
+        else:
+            schedule = (pda.decode_schedule(lengths, 64, 4)
+                        if variant == "prebuilt" else None)
+            got = pda.decode_attention_pallas(
+                q, stack_at(k, 1), stack_at(v, 1), lengths,
+                layer=jnp.int32(1), block_s=64, interpret=True,
+                schedule=schedule)
+        self.check(got, xla_decode(q, k, v, lengths), lengths)
+
+    @pytest.mark.parametrize("batch", MIXED_BATCHES)
+    @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+    def test_paged_kernel(self, quant, batch):
+        from llm_instance_gateway_tpu.models.transformer import (
+            _kv_dequantize, _kv_quantize)
+        from llm_instance_gateway_tpu.ops.attention import gather_pool_rows
+
+        q, k_pool, v_pool, tables, _ = TestPagedDecodeKernel().make_paged(
+            b=6, h=28, kv=4, seed=13)
+        lengths = jnp.asarray(MIXED_BATCHES[batch], jnp.int32)
+        scales = ()
+        if quant:
+            (k_pool, ks), (v_pool, vs) = _kv_quantize(k_pool), _kv_quantize(v_pool)
+            scales = (ks, vs)
+        got = pda.paged_decode_attention_pallas(
+            q, k_pool, v_pool, tables, lengths, *scales, interpret=True)
+        if quant:
+            k_pool = _kv_dequantize(k_pool, ks, jnp.float32)
+            v_pool = _kv_dequantize(v_pool, vs, jnp.float32)
+        self.check(got, xla_decode(q, gather_pool_rows(k_pool, tables),
+                                   gather_pool_rows(v_pool, tables), lengths),
+                   lengths)
+
+    @pytest.mark.parametrize("batch", MIXED_BATCHES)
+    @pytest.mark.parametrize("prebuilt", [False, True], ids=["own", "prebuilt"])
+    def test_latent_kernel(self, prebuilt, batch):
+        from llm_instance_gateway_tpu.ops.attention import (
+            latent_decode_attention)
+
+        kq, kr = jax.random.split(jax.random.PRNGKey(17))
+        q = jax.random.normal(kq, (6, 20, 256), jnp.float32)
+        rows = jax.random.normal(kr, (6, 256, 256), jnp.float32)
+        lengths = jnp.asarray(MIXED_BATCHES[batch], jnp.int32)
+        schedule = pda.decode_schedule(lengths, 64, 4) if prebuilt else None
+        got = pda.mla_decode_attention_pallas(
+            q, stack_at(rows, 1), lengths, 128, 0.1, layer=jnp.int32(1),
+            block_s=64, interpret=True, schedule=schedule)
+        self.check(got, latent_decode_attention(q, rows, lengths, 128, 0.1),
+                   lengths)
 
 
 class TestPagedDecodeKernel:

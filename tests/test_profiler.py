@@ -773,6 +773,40 @@ class TestPhaseReport:
             lanes.hist_state())
 
 
+    def test_report_prints_grid_steps_per_dispatch(self):
+        """``tpu:decode_attn_grid_steps_total`` (``note_attn_grid_steps``):
+        in ``/metrics`` always, in the report where a kernel's schedule
+        counted any."""
+        clock = FakeClock()
+        p = StepProfiler(capacity=8, clock=clock)
+        for steps in (3, 4, 4, 5):
+            p.note_attn_grid_steps(steps)
+            p.note_dispatch("decode", clock.now, 0.01, active=3,
+                            total_slots=4)
+            clock.tick(0.02)
+        assert p.snapshot()["hist"]["attn_grid_steps"] == 16
+        assert profile_report.attn_grid_steps_row(p.snapshot()) == {
+            "attn_grid_steps": 16, "decode_dispatches": 4,
+            "steps_per_dispatch": 4.0}
+        out = profile_report.render_report(p.snapshot())
+        assert "Grid steps of the decode-attention kernel" in out
+        assert "tpu:decode_attn_grid_steps_total 16" in render_profile(
+            p.hist_state())
+        none = StepProfiler(capacity=8, clock=clock)
+        none.note_dispatch("decode", clock.now, 0.01, active=3,
+                           total_slots=4)
+        assert profile_report.attn_grid_steps_row(none.snapshot()) == {}
+        assert "Grid steps" not in profile_report.render_report(
+            none.snapshot())
+        assert "tpu:decode_attn_grid_steps_total 0" in render_profile(
+            none.hist_state())
+        old = p.snapshot()
+        del old["hist"]["attn_grid_steps"]  # a payload from before PR 47
+        assert profile_report.attn_grid_steps_row(old) == {}
+        assert not any("grid_steps" in ln
+                       for ln in render_profile(old["hist"]))
+
+
 class TestXplaneGaps:
     """``--xplane``'s reduction, on a hand-made event list: device busy
     0-10, 14-20 and 30-40; the engine thread in decode.wait 0-9, then

@@ -10,9 +10,14 @@ as GATED with the gate's reason and not run.
 Every case runs; the exit code is non-zero if any failed to lower or
 missed parity.  Needs a TPU (fails on any other backend).  Timing is not
 this tool's job, speed comes from the benchmark's device trace, with one
-exception: the ``live-rows`` cases also print what the lane decode kernel
-costs a layer at 32 slots when 5 or all 32 of them decode (host clock
-round one program of 400 calls, as a model's layer loop makes them), and
+exception: the ``live-rows`` cases also print what a decode kernel costs a
+layer, beside the grid steps it walks (the schedule's: the live rows'
+tiles): the lane kernel at 32 slots when 5 or all 32 of them decode at 150
+positions, at Qwen's and OLMoE's layouts, and at SmallThinker's full lanes
+(rows of 4-10k in lanes of 16,384) and Falcon-H1's (64 slots, rows of
+~450), and the latent kernel at GLM's rows (~1,400 of 4,096); host clock
+round one program of calls, as a model's layer loop makes them, and a
+digest of the live rows' output, which two trees' kernels must share; and
 the ``ssm-update`` cases what the state-space decode update costs a layer at
 64 slots of Falcon-H1-34B's state (32 x 256 x 128 float32) with 64, 8 and 1
 of them live, beside the time its bytes would take at the chip's bandwidth;
@@ -200,41 +205,75 @@ def case_decode(h, n_kv, hd, s_max, quant, b=16):
     return out, ref, TOL_BF16
 
 
-def case_live_rows(h, n_kv, hd, s_max, n_live, b=32, held=150, n_layers=8,
-                   calls=400):
-    """The lane kernel as a decode step of ``b`` slots runs it: ``n_live``
-    rows of ``held`` positions spread over the slots, the others at length
-    0 (``transformer.decode_step``: ``active`` off).  Parity on every row
-    (a dead row's is zeros on both sides), and the time a layer of one
-    program that walks a stacked cache ``calls`` times."""
-    kq, kk, kv = _keys(6, 3)
-    q = jax.random.normal(kq, (b, h, hd), DTYPE)
-    kc = jax.random.normal(kk, (n_layers, b, s_max, n_kv, hd), DTYPE)
-    vc = jax.random.normal(kv, (n_layers, b, s_max, n_kv, hd), DTYPE)
+def _spread(b, n_live, held):
+    """``n_live`` rows of ``held`` positions spread over ``b`` slots, the
+    others at length 0 (``transformer.decode_step``: ``active`` off)."""
     live = np.zeros(b, bool)
     live[np.linspace(0, b - 1, n_live).astype(int)] = True
-    lengths = jnp.asarray(np.where(live, held, 0), jnp.int32)
+    return np.where(live, held, 0)
+
+
+def _ragged(b, low, high, seed):
+    """``b`` live rows of ``low`` .. ``high`` positions, as a closed loop's
+    slots hold them."""
+    return np.random.RandomState(seed).randint(low, high + 1, b)
+
+
+def case_live_rows(h, n_kv, hd, s_max, lengths, n_layers=8, calls=400,
+                   latent=0):
+    """A decode kernel as a decode step runs it over rows of ``lengths``
+    positions (0: a slot that does not decode): the lane kernel over
+    [L, B, S, K, hd] lanes, or with ``latent`` (a row's value columns) the
+    latent kernel over [L, B, S, hd] rows.  Parity on every row (a dead
+    row's is zeros on both sides), the grid steps the schedule holds of the
+    slots x tiles rectangle, the time a layer of one program that walks the
+    stacked cache ``calls`` times, and a digest of the live rows' output."""
+    import hashlib
+
+    b = len(lengths)
+    lens = jnp.asarray(lengths, jnp.int32)
+    live = np.asarray(lengths) > 0
+    kq, kk, kv = _keys(6, 3)
+    q = jax.random.normal(kq, (b, h, hd), DTYPE)
+    if latent:
+        block = pdec._mla_block(s_max)
+        cache = (jax.random.normal(kk, (n_layers, b, s_max, hd), DTYPE),)
+        kernel = lambda q, layer, rows: pdec.mla_decode_attention_pallas(
+            q, rows, lens, latent, 1 / 16, layer=layer, interpret=False)
+        ref = lambda q, rows: xla_att.latent_decode_attention(
+            q, rows[1], lens, latent, 1 / 16)
+    else:
+        block = pdec._pick_block(s_max, n_kv * hd * 2)
+        cache = tuple(jax.random.normal(key, (n_layers, b, s_max, n_kv, hd),
+                                        DTYPE) for key in (kk, kv))
+        kernel = lambda q, layer, kc, vc: pdec.decode_attention_pallas(
+            q, kc, vc, lens, layer=layer, interpret=False)
+        ref = lambda q, kc, vc: xla_att.decode_attention(
+            q, kc[1], vc[1], lens)
+    steps = int(np.sum(-(-np.asarray(lengths) // block)))
 
     @jax.jit
-    def loop(q, kc, vc, lengths):
+    def loop(q, *cache):
         def body(acc, layer):
-            out = pdec.decode_attention_pallas(
-                q + acc.astype(q.dtype), kc, vc, lengths, layer=layer,
-                interpret=False)
-            return out.astype(jnp.float32) * 1e-6, None
+            out = kernel(q + acc.astype(q.dtype), layer, *cache)
+            return out[..., :1].astype(jnp.float32) * 1e-6, None
         layers = jnp.arange(calls, dtype=jnp.int32) % n_layers
-        return jax.lax.scan(body, jnp.zeros(q.shape, jnp.float32), layers)[0]
+        return jax.lax.scan(body, jnp.zeros((b, h, 1), jnp.float32),
+                            layers)[0]
 
     least, median = _us_a_call(
-        lambda: loop(q, kc, vc, lengths).block_until_ready(), calls)
-    print(f"TIME   lane-decode {n_live}/{b} live rows x{held} h={h} "
-          f"kv={n_kv} s_max={s_max}: {least:.1f} us a layer "
-          f"(median {median:.1f}, {calls} calls a program)",
-          flush=True)
-    out = jax.jit(lambda q, kc, vc: pdec.decode_attention_pallas(
-        q, kc, vc, lengths, layer=jnp.int32(1), interpret=False))(q, kc, vc)
-    ref = jax.jit(xla_att.decode_attention)(q, kc[1], vc[1], lengths)
-    return out, ref * live[:, None, None], TOL_BF16
+        lambda: loop(q, *cache).block_until_ready(), calls)
+    out = jax.jit(lambda q, *cache: kernel(q, jnp.int32(1), *cache))(
+        q, *cache)
+    digest = hashlib.sha256(
+        np.asarray(out.astype(jnp.float32))[live].tobytes()).hexdigest()[:12]
+    print(f"TIME   {'latent' if latent else 'lane'}-decode "
+          f"{int(live.sum())}/{b} live rows x{int(np.sum(lengths)) // max(1, live.sum())} "
+          f"h={h} kv={n_kv} s_max={s_max}: {least:.1f} us a layer (median "
+          f"{median:.1f}, {calls} calls a program); {steps} live tiles of "
+          f"the {b * (s_max // block)} of slots x tiles; live rows' output "
+          f"sha256 {digest}", flush=True)
+    return out, jax.jit(ref)(q, *cache) * live[:, None, None], TOL_BF16
 
 
 def case_paged(h, n_kv, hd, block, quant, b=16, s_max=2048):
@@ -514,16 +553,30 @@ def cases():
         yield (f"moe-reuse [{label}]", pmoe.shape_reasons(k, n),
                lambda e=e, k=k, n=n, m=m, touched=touched: case_moe_reuse(
                    e, k, n, m, touched))
-    # Speed 2 of ROADMAP.md: what the rows that do not decode cost the lane
-    # kernel, at the two benchmark models' layouts and lane lengths.
+    # Speed 2 of ROADMAP.md: what the steps of the decode kernels' grid
+    # cost, at the open-loop cells' layouts with few rows live and with all,
+    # and at the closed-loop cells' ragged rows.
     for label, h, n_kv, hd, s_max in (("qwen2.5-7b g=7", 28, 4, 128, 2048),
                                       ("olmoe-1b-7b g=1 kv=16", 16, 16, 128,
                                        1024)):
-        for n_live in (5, 32):
+        for n_live in (0, 5, 32):
             yield (f"live-rows {n_live}/32 x150 [{label}]",
                    pdec.shape_reasons(s_max, hd, n_kv * hd * 2),
                    lambda h=h, n_kv=n_kv, hd=hd, s_max=s_max, n_live=n_live:
-                   case_live_rows(h, n_kv, hd, s_max, n_live))
+                   case_live_rows(h, n_kv, hd, s_max,
+                                  _spread(32, n_live, 150)))
+    yield ("live-rows 32/32 x4-10k [smallthinker-21b-a3b full lanes]",
+           pdec.shape_reasons(16384, 128, 4 * 128 * 2),
+           lambda: case_live_rows(28, 4, 128, 16384,
+                                  _ragged(32, 4096, 10240, 1), n_layers=3,
+                                  calls=120))
+    yield ("live-rows 64/64 x~450 [falcon-h1-34b]",
+           pdec.shape_reasons(2048, 128, 4 * 128 * 2),
+           lambda: case_live_rows(20, 4, 128, 2048, _ragged(64, 200, 700, 2)))
+    yield ("live-rows 32/32 x~1400 [glm-4.7-flash latent rows]",
+           pdec.mla_shape_reasons(4096, 640, 512),
+           lambda: case_live_rows(20, 1, 640, 4096,
+                                  _ragged(32, 300, 2500, 3), latent=512))
     for label, h, n_kv, hd in LAYOUTS:
         for s in (128, 1024):
             yield (f"flash s={s} [{label}]", flash.shape_reasons(s, hd),

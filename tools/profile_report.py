@@ -218,6 +218,16 @@ def kv_positions_rows(profile: dict) -> list[dict]:
             for lanes, total in read.items()]
 
 
+def attn_grid_steps_row(profile: dict) -> dict:
+    """Grid steps the decode-attention kernel's schedule held a layer's call,
+    summed over the decode steps (``tpu:decode_attn_grid_steps_total``);
+    empty where no kernel takes the cache's shape."""
+    if not (profile.get("hist") or {}).get("attn_grid_steps"):
+        return {}
+    return _per_decode_dispatch(profile, "attn_grid_steps",
+                                "steps_per_dispatch")
+
+
 def overlap_row(profile: dict) -> dict:
     """Decode blocks dispatched while an earlier block was still unread
     (``tpu:decode_blocks_overlapped_total``) and their share of the decode
@@ -700,6 +710,12 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
                 "the kind:",
                 _table(lanes, ("lanes", "positions", "decode_dispatches",
                                "positions_per_dispatch"))]
+    grid = attn_grid_steps_row(profile)
+    if grid:
+        out += ["", "Grid steps of the decode-attention kernel, a layer's "
+                "call:",
+                _table([grid], ("attn_grid_steps", "decode_dispatches",
+                                "steps_per_dispatch"))]
     delta = host_sync_delta(profile, previous)
     if delta:
         out += ["", "Host-sync share vs previous baseline: "
