@@ -586,9 +586,8 @@ def server_argv(model: str, port: int, rehearsal: bool,
     else:
         # Steady state at these settings: int8 weights 7.1 GB + bf16
         # embedding 1.1 GB + bf16 KV 16 slots x 2048 x 57,344 B = 1.9 GB.
-        # Step knobs are the server's defaults (--adaptive-steps 8 and the
-        # overlapped loop, --pipeline-decode, on since PR 40): recorded,
-        # not tuned, here.
+        # Step knobs are the server's defaults (--adaptive-steps 8):
+        # recorded, not tuned, here.
         argv += ["--quantize", "int8", "--decode-slots", "16",
                  "--max-seq-len", "2048"]
     if mesh:

@@ -585,8 +585,7 @@ CLASSES = (
             SharedField("_stops_active", OWNER_PRIVATE,
                         writers=("_clear_slot", "_program_stop_lanes"),
                         note="rows with programmed device stop lanes; "
-                             "gates the history rebuild and excludes "
-                             "speculative dispatch"),
+                             "excludes speculative dispatch"),
             SharedField("decode_wait", LOCK_GUARDED,
                         writers=("_sweep_decode_wait",)),
             SharedField("_parked_kv_tokens", LOCK_GUARDED,
@@ -620,37 +619,30 @@ CLASSES = (
                              "donated to each decode block and handed "
                              "back by it"),
             SharedField("_dev_tokens", OWNER_PRIVATE,
-                        writers=("_activate_slot_pipelined",
-                                 "_dispatch_block", "_dispatch_spec_block",
-                                 "_loop_pipelined")),
+                        writers=("_activate_slot",
+                                 "_dispatch_block", "_dispatch_spec_block")),
             SharedField("_dev_positions", OWNER_PRIVATE,
-                        writers=("_dispatch_block", "_dispatch_spec_block",
-                                 "_loop_pipelined")),
+                        writers=("_dispatch_block", "_dispatch_spec_block")),
             SharedField("_dev_remaining", OWNER_PRIVATE,
-                        writers=("_dispatch_block", "_dispatch_spec_block",
-                                 "_loop_pipelined")),
+                        writers=("_dispatch_block", "_dispatch_spec_block")),
             SharedField("_dev_stop_hist", OWNER_PRIVATE,
-                        writers=("_dispatch_block", "_dispatch_spec_block",
-                                 "_loop_pipelined"),
+                        writers=("_dispatch_block", "_dispatch_spec_block"),
                         note="stop-automaton history carry (device-"
                              "resident twin of _slot_stop_hist)"),
             SharedField("_dev_has_extra", OWNER_PRIVATE,
-                        writers=("_activate_slot_pipelined",
-                                 "_dispatch_spec_block", "_draft_admit",
-                                 "_loop_pipelined")),
+                        writers=("_activate_slot",
+                                 "_dispatch_spec_block")),
             SharedField("_dev_extra_pos", OWNER_PRIVATE,
-                        writers=("_dispatch_spec_block",
-                                 "_loop_pipelined")),
+                        writers=("_dispatch_spec_block",)),
             SharedField("_dev_extra_tok", OWNER_PRIVATE,
-                        writers=("_dispatch_spec_block",
-                                 "_loop_pipelined")),
+                        writers=("_dispatch_spec_block",)),
             SharedField("_first_unread", OWNER_PRIVATE,
-                        writers=("_activate_slot_pipelined",
-                                 "_park_waiting", "_read_first_tokens"),
+                        writers=("_queue_first_token",
+                                 "_read_first_tokens"),
                         note="first tokens still on the device, in the "
                              "order their prefills were enqueued"),
             SharedField("_inflight", OWNER_PRIVATE,
-                        writers=("_loop_pipelined",),
+                        writers=("_loop",),
                         note="the decode block dispatched and not read"),
             SharedField("_last_done_pc", OWNER_PRIVATE,
                         writers=("_process_block", "_read_first_tokens"),
@@ -658,8 +650,7 @@ CLASSES = (
                              "a block or a prefill: the step clock's "
                              "anchor"),
             SharedField("_prev_dispatch_steps", OWNER_PRIVATE,
-                        writers=("_loop_pipelined",
-                                 "_paged_ensure_decode")),
+                        writers=("_loop", "_paged_ensure_decode")),
             SharedField("decode_tps_ema", SWAP_PUBLISHED,
                         writers=("_account_dispatch",),
                         note="float rebind; the scrape thread reads it "
@@ -668,9 +659,9 @@ CLASSES = (
                         writers=("_prefix_bucket_prefill",
                                  "_prefix_match_and_map")),
             SharedField("spec_cycles", MONOTONIC,
-                        writers=("_dispatch_spec_block", "_do_spec_step")),
+                        writers=("_dispatch_spec_block",)),
             SharedField("spec_emitted", MONOTONIC,
-                        writers=("_do_spec_step", "_process_block")),
+                        writers=("_process_block",)),
             SharedField("total_generated", MONOTONIC,
                         writers=("_account_dispatch",
                                  "_emit_first_token")),

@@ -457,7 +457,8 @@ SERVER_FAMILIES = (
            "block was still unread: over the decode dispatches "
            "(tpu:dispatch_wall_seconds_count, phases decode and spec), the "
            "share of blocks for which the device had its next step queued "
-           "before the host read the last. 0 under --no-pipeline-decode.",
+           "before the host read the last. 0: every block was staged with "
+           "none in flight (the device had run dry).",
            SERVER_SURFACE),
     Family("tpu:prefill_seconds", "histogram", ("model", "role"),
            "Prefill compute latency.", SERVER_SURFACE),

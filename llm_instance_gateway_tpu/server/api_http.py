@@ -1640,14 +1640,6 @@ def main(argv=None) -> None:
     parser.add_argument("--prefill-batch", type=int, default=1,
                         help="group up to P same-bucket queued prompts into "
                              "one prefill program (contiguous-lane cache)")
-    parser.add_argument("--pipeline-decode",
-                        action=argparse.BooleanOptionalAction, default=True,
-                        help="dispatch decode block N+1 from the device "
-                             "carry before block N is read, so the device "
-                             "never waits for the host between two steps "
-                             "(the default; slot freeing lags one block). "
-                             "--no-pipeline-decode reads each block before "
-                             "the next is staged")
     parser.add_argument("--quantize", choices=["none", "int8"], default="none",
                         help="weight-only quantization of the big projections")
     parser.add_argument("--tokenizer", default=None, help="local HF tokenizer dir")
@@ -1864,7 +1856,6 @@ def main(argv=None) -> None:
             decode_steps_per_sync=args.decode_steps,
             adaptive_steps=args.adaptive_steps,
             stream_lanes=args.stream_lanes,
-            pipeline_decode=args.pipeline_decode,
             prefill_batch=args.prefill_batch,
             paged_kv_block=args.paged_kv_block,
             paged_kv_blocks=args.paged_kv_blocks,

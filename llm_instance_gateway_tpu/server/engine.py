@@ -80,14 +80,14 @@ TPS_EMA_ALPHA = 0.2
 # row need no dirty mark, and a dispatch uploads two buffers, not fifteen
 # arrays.  ``_slot_views`` is the layout on both sides of the transfer.
 _SLOT_I32 = (
-    ("tokens", (), 0), ("positions", (), 0), ("lora", (), -1),
+    ("positions", (), 0), ("lora", (), -1),
     ("topk", (), 0), ("remaining", (), 0), ("seed", (), -1),
     ("bias_ids", (MAX_LOGIT_BIAS,), -1),
     ("stop_ids", (STOP_SEQS, STOP_LEN), -1), ("stop_lens", (STOP_SEQS,), 0),
     ("stop_hist", (STOP_LEN,), -1),
-    # 1 from a row's activation to the next dispatch (the overlapped loop):
-    # that block takes the row's position, budget and stop history from
-    # this buffer and not from the device carry (``_stage_carry``).
+    # 1 from a row's activation to the next dispatch: that block takes the
+    # row's position, budget and stop history from this buffer and not from
+    # the device carry (``_stage_carry``).
     ("fresh", (), 0),
 )
 _SLOT_F32 = (
@@ -112,10 +112,10 @@ def _slot_views(buf, fields, b: int) -> dict:
 
 
 def _stage_carry(carry, i32: dict):
-    """The device carry of the overlapped loop as the next block takes it,
-    decided from what the step stages anyway (``i32``: ``_slot_views`` of
-    the uploaded int32 buffer), so that one fixed-shape program serves
-    whatever the host freed or admitted since the last block:
+    """The device carry as the next block takes it, decided from what the
+    step stages anyway (``i32``: ``_slot_views`` of the uploaded int32
+    buffer), so that one fixed-shape program serves whatever the host freed
+    or admitted since the last block:
 
     - a row the host does not hold (staged budget 0: never registered, or
       cleared for a reason the device cannot see: a custom stop id, the
@@ -252,16 +252,6 @@ class EngineConfig:
     # first.  Lanes beyond the first admit only with KV headroom left for
     # active decode growth (paged pools).
     stream_lanes: int = 1
-    # The overlapped order, what an engine runs when nothing is said:
-    # block N+1 is dispatched from the device-resident token/position/
-    # budget carry BEFORE block N's tokens are read, so readback, emit,
-    # accounting, planning and staging of a step all happen while the
-    # device computes the next one.  A prefill's first token is read as
-    # soon as the prefill is done, behind at most the one block in flight.
-    # Slot FREEING lags one block (the frozen row just decodes invalid
-    # steps until the host sees the stop).  False = ``Engine._loop``: each
-    # block is read before the next is staged (``--no-pipeline-decode``).
-    pipeline_decode: bool = True
     # Prefill-ahead depth: prompts prefilled while all decode slots are busy
     # wait here (KV held off-cache) and insert the instant a slot frees —
     # the decode batch never idles a slot waiting for a prefill, and the
@@ -303,9 +293,8 @@ class EngineConfig:
     # prefix — EXACT greedy parity with non-speculative decoding; sampled
     # rows fall back to one verified token per cycle.  Requires
     # ``draft_params``/``draft_cfg`` at Engine construction.  Composes with
-    # BOTH engine loops, ``decode_steps_per_sync`` (cycles are fused into
-    # one device-side scan of ceil(steps/(K+1)) cycles per dispatch, in
-    # the pipelined loop too), the paged cache
+    # ``decode_steps_per_sync`` (cycles are fused into one device-side
+    # scan of ceil(steps/(K+1)) cycles per dispatch), the paged cache
     # (extend_step_paged verify), and GSPMD serve meshes (draft replicated)
     # — including all three together on a tensor/expert mesh
     # (parity-tested).  paged + a data mesh is excluded by the engine's
@@ -466,12 +455,11 @@ class _Slot:
 class _HostBatch:
     """One device->host transfer for a grouped-prefill output set.
 
-    The pipelined grouped path previously async-copied P separate 0-d
-    device scalars (and later sync-transferred each at materialization),
-    re-paying per-row dispatch round-trips the batched prefill was meant to
-    amortize.  This starts ONE async copy per array and materializes all
-    rows with one ``np.asarray`` per array on first access — mirroring the
-    sync path's single bulk transfer.
+    P separate 0-d device scalars, each async-copied and later transferred
+    alone, would re-pay the per-row round-trips the batched prefill is
+    meant to amortize.  This starts ONE async copy per array and
+    materializes all rows with one ``np.asarray`` per array on first
+    access.
     """
 
     __slots__ = ("arrays", "_host")
@@ -495,7 +483,7 @@ class _Row:
     """Row ``i`` of array ``a`` in a ``_HostBatch``: numpy-protocol view
     whose first host access materializes the whole batch.  ``dev`` carries
     the device slice for carry scatters that must stay device-resident
-    (the pipelined loop's no-host-round-trip contract)."""
+    (the loop's no-host-round-trip contract)."""
 
     __slots__ = ("_batch", "_a", "_i", "dev")
 
@@ -520,17 +508,16 @@ class _WaitingPrefill:
     disaggregation inside one engine)."""
 
     request: Request
-    first_token: object  # device scalar (sync mode materializes eagerly)
+    first_token: object  # device scalar
     k: object            # [L, 1, bucket, Kh, hd]
     v: object
     n: int
     lora_slot: int
-    first_token_host: int | None = None  # sync mode: already-emitted token
     # First-token (lp, top_v, top_i) device tuple; None once recorded.
     lp_info: object = None
     # Cross-engine attach: the first token was already emitted (on THIS
-    # engine, at attach admission) — the pipelined insert must not schedule
-    # a second pending-first materialization.
+    # engine, at attach admission) — the insert must not schedule a second
+    # pending-first materialization.
     first_emitted: bool = False
     # Imported via attach_prefilled: the insert may map already-cached
     # prefix blocks instead of re-writing identical content.
@@ -809,7 +796,6 @@ class Engine:
         # they always were and uploaded whole by ``_enqueue_decode``.
         self._slots_i32, i32 = _slot_buffer(_SLOT_I32, b, np.int32)
         self._slots_f32, f32 = _slot_buffer(_SLOT_F32, b, np.float32)
-        self._slot_tokens = i32["tokens"]
         self._slot_positions = i32["positions"]
         self._slot_lora = i32["lora"]
         self._slot_temp = f32["temp"]
@@ -820,8 +806,8 @@ class Engine:
         self._slot_frequency = f32["frequency"]
         self._slot_bias_ids = i32["bias_ids"]
         self._slot_bias_vals = f32["bias_vals"]
-        # Generated-token occurrence counts, device-resident (transferring
-        # [B, V] per dispatch would swamp the sync loop): rows zero at
+        # Generated-token occurrence counts, device-resident (a [B, V]
+        # transfer per dispatch would swamp the step): rows zero at
         # registration, the decode scan updates them in its carry.
         self._dev_counts = None  # lazy: [B, V_padded] int32 on first use
         # What a penalty-free dispatch passes instead (``penalized`` is the
@@ -833,17 +819,16 @@ class Engine:
         self._eos_for_device = jnp.int32(-1 if eos_id is None else eos_id)
         # Device stop-string automata (server/sampling.py): per-row stop
         # suffix lanes (right-aligned, -1 padded) programmed at slot
-        # registration, plus the host history scratch the sync loop
-        # rebuilds per dispatch (the pipelined loop keeps its history
-        # device-resident in the dispatch carry instead).
+        # registration, plus the history a fresh row is staged with (the
+        # tokens the host has emitted for it; from then on the history
+        # rides the device carry, ``_stage_carry``).
         self._slot_stop_ids = i32["stop_ids"]
         self._slot_stop_lens = i32["stop_lens"]
         self._slot_stop_hist = i32["stop_hist"]
         self._slot_fresh = i32["fresh"]
-        # Count of rows with programmed device stop lanes: gates the
-        # per-dispatch history rebuild AND excludes speculative dispatch
-        # (the spec block does not evaluate the automaton, so its history
-        # carry would go stale mid-generation).
+        # Count of rows with programmed device stop lanes: excludes
+        # speculative dispatch (the spec block does not evaluate the
+        # automaton, so its history carry would go stale mid-generation).
         self._stops_active = 0
 
         self.prefill_queue: queue_mod.Queue[Request] = queue_mod.Queue(
@@ -914,15 +899,24 @@ class Engine:
         # Requests whose first token is out and whose admission's phase
         # parts are not booked yet (_settle_admissions).
         self._unsettled: list[Request] = []
-        # The overlapped loop: first tokens still on the device, in the
+        # The loop's state.  First tokens still on the device, in the
         # order their prefills were enqueued, as (request, token,
         # logprob triple) (_read_first_tokens); the block in flight, if
-        # any; and when the device was last seen to complete something
+        # any, and its write span (the paged reservation, the planner's
+        # lag); and when the device was last seen to complete something
         # (a block, a prefill), on the profiler's clock: the step clock's
         # anchor (_process_block).
         self._first_unread: list[tuple] = []
         self._inflight: dict | None = None
+        self._prev_dispatch_steps = 0
         self._last_done_pc = 0.0
+        # The device carry from one block to the next: each row's last
+        # token, position, budget and stop-automaton history never make a
+        # host round-trip; rows re-seed at activation (_stage_carry).
+        self._dev_tokens = jnp.zeros((b,), jnp.int32)
+        self._dev_positions = jnp.zeros((b,), jnp.int32)
+        self._dev_remaining = jnp.zeros((b,), jnp.int32)
+        self._dev_stop_hist = jnp.full((b, STOP_LEN), -1, jnp.int32)
         # KV economy ledger (server/kv_ledger.py): block lifecycle,
         # per-prefix reuse, fragmentation.  Own lock; charged at the
         # allocator/prefix/park sites, state-recounted on the KV sync,
@@ -1023,12 +1017,11 @@ class Engine:
                 self.draft_cache = jax.device_put(self.draft_cache, rep)
             self._spec_ok = np.zeros((b,), bool)
             # The (token, position) the draft hasn't ingested yet — only set
-            # after a FULLY-accepted cycle (d_K's kv is missing then).  Host
-            # mirrors for the sync loop; the pipelined loop keeps the same
-            # triple device-resident in its dispatch carry.
-            self._spec_extra_tok = np.zeros((b,), np.int32)
-            self._spec_extra_pos = np.zeros((b,), np.int32)
-            self._spec_has_extra = np.zeros((b,), bool)
+            # after a FULLY-accepted cycle (d_K's kv is missing then): spec
+            # blocks update the triple in their carry, no host round-trip.
+            self._dev_extra_tok = jnp.zeros((b,), jnp.int32)
+            self._dev_extra_pos = jnp.zeros((b,), jnp.int32)
+            self._dev_has_extra = jnp.zeros((b,), bool)
             self.spec_cycles = 0
             self.spec_emitted = 0
 
@@ -1041,9 +1034,8 @@ class Engine:
             self._jit_draft_insert = jax.jit(
                 _named("draft_insert", transformer.insert_prefill),
                 donate_argnames=("cache",))
-            # The overlapped loop's speculative dispatch lays the staged
-            # rows over the carry ahead of the call (a plain block does it
-            # inside its program).
+            # The speculative dispatch lays the staged rows over the carry
+            # ahead of the call (a plain block does it inside its program).
             self._jit_stage_carry = jax.jit(_named(
                 "stage_carry", lambda carry, buf: _stage_carry(
                     carry, _slot_views(buf, _SLOT_I32, b))))
@@ -1124,12 +1116,10 @@ class Engine:
 
         The per-slot inputs arrive as the engine's two flat buffers
         (``_SLOT_I32`` / ``_SLOT_F32``) and are taken apart here by static
-        slices.  ``carry`` is None when the host record leads (the sync
-        loop: tokens, positions, budgets and stop history are the
-        buffers'), or the previous block's ``(tokens, positions, remaining,
-        stop_hist)`` outputs (the overlapped loop, whose host record lags
-        the device; ``_stage_carry`` lays what the host freed or admitted
-        since over them).  ``key`` is the ENGINE's key: the program splits it as
+        slices.  ``carry`` is the previous block's ``(tokens, positions,
+        remaining, stop_hist)`` outputs (the host record lags the device;
+        ``_stage_carry`` lays what the host freed or admitted since over
+        them).  ``key`` is the ENGINE's key: the program splits it as
         ``Engine._next_key`` does and returns the engine's next one, so the
         decode step and the prefills still draw from one stream.
 
@@ -1173,12 +1163,7 @@ class Engine:
         stop_lens = i32["stop_lens"]
         temp, topp, bias_vals = f32["temp"], f32["topp"], f32["bias_vals"]
         presence, frequency = f32["presence"], f32["frequency"]
-        if carry is None:
-            carry = (i32["tokens"], i32["positions"], i32["remaining"],
-                     i32["stop_hist"])
-        else:
-            carry = _stage_carry(carry, i32)
-        tokens, positions, remaining, stop_hist = carry
+        tokens, positions, remaining, stop_hist = _stage_carry(carry, i32)
         next_key, key = jax.random.split(key)
         cache = transformer.with_moe_tally(model_cfg, cache)
 
@@ -1237,7 +1222,7 @@ class Engine:
         )
         cache, *carry, counts = carry
         # The token/position/budget/history carries live on device for
-        # pipelined dispatch of the following block (no host round-trip).
+        # the dispatch of the following block (no host round-trip).
         moe = cache.pop("moe", None)
         return (toks, valid, lps, top_v, top_i, paths, tuple(carry),
                 next_key, counts, cache, moe)
@@ -1248,8 +1233,7 @@ class Engine:
 
     def start(self) -> None:
         self._running = True
-        target = self._loop_pipelined if self.cfg.pipeline_decode else self._loop
-        self._thread = threading.Thread(target=target, daemon=True)
+        self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
 
     def stop(self) -> None:
@@ -1304,8 +1288,8 @@ class Engine:
           drop to 1 step;
         - **remaining budget** — never fuse past the minimum remaining
           token budget across active rows (the block would spend its tail
-          decoding frozen rows); pipelined mode subtracts the in-flight
-          block's steps since the host record lags it;
+          decoding frozen rows), less the in-flight block's steps, since
+          the host record lags it;
         - **SSE cadence** — any active streaming consumer caps fusion at
           ``adaptive_stream_cap`` so perceived TPOT cannot regress.
 
@@ -1326,55 +1310,35 @@ class Engine:
                     and self._free_slot_index() is not None)):
             return 1
         n = max(1, ceiling)
-        inflight = (self._prev_dispatch_steps
-                    if self.cfg.pipeline_decode else 0)
         for s in self.slots:
             if s is None:
                 continue
             req = s.request
             if req.streaming:
                 n = min(n, max(1, self.cfg.adaptive_stream_cap))
-            # A slotted row has its first token, read or not (the
-            # overlapped loop reads it after this dispatch is planned).
+            # A slotted row has its first token, read or not (the loop
+            # reads it after this dispatch is planned).
             rem = (req.max_new_tokens - max(1, len(req.output_tokens))
-                   - inflight)
+                   - self._prev_dispatch_steps)
             n = min(n, max(1, rem))
         p = 1
         while p * 2 <= n:
             p *= 2
         return p
 
-    def _sync_stop_hist(self) -> None:
-        """The sync loop's per-dispatch stop-history input, written into
-        its mirror: each stop-lane row's last STOP_LEN emitted tokens
-        (right-aligned, -1 padded), rebuilt from the request's own output
-        record — the host record IS the history, so a fused block never
-        sees a stale ring.  Stop-free batches skip the rebuild and pass the
-        all--1 scratch."""
-        hist = self._slot_stop_hist
-        if not self._stops_active:
-            return  # all -1 by construction: nothing can match
-        hist[:] = -1
-        for i, s in enumerate(self.slots):
-            if s is None or not self._slot_stop_lens[i].any():
-                continue
-            tail = s.request.output_tokens[-STOP_LEN:]
-            if tail:
-                hist[i, STOP_LEN - len(tail):] = tail
-
-    def _enqueue_decode(self, n_steps: int, carry=None):
-        """Stage and enqueue one plain decode block, for both loops: the
-        slot mirrors go up as their two buffers and nothing else is made
-        for the call — the engine's key, the penalty-free counts dummy and
-        the cache are each the previous block's output.  ``carry`` as in
+    def _enqueue_decode(self, n_steps: int, carry):
+        """Stage and enqueue one plain decode block: the slot mirrors go up
+        as their two buffers and nothing else is made for the call — the
+        engine's key, the penalty-free counts dummy and the cache are each
+        the previous block's output.  ``carry`` as in
         ``_decode_impl``.  Returns the block's (toks, valid, lps, top_v,
         top_i, paths), its next carry, and its routing counts with those
         the prefills left (``_moe_drain``).
 
         The buffers go as private host copies, never the mirrors
         themselves: on the CPU backend an upload can alias its numpy
-        source (``_sync_tables``), and the overlapped loop rewrites rows
-        while the block is in flight.  ``counts`` is the real buffer only
+        source (``_sync_tables``), and the loop rewrites rows while the
+        block is in flight.  ``counts`` is the real buffer only
         when some row carries a penalty (static flag -> two compiled
         variants), so penalty-free serving never allocates or streams
         [B, V] counts."""
@@ -1390,8 +1354,8 @@ class Engine:
             self.profiler.note_ssm_rows(
                 n_steps * sum(s is not None for s in self.slots))
         # Step j of the block reads position + 1 + j rows of a live row's
-        # lane.  With a block still unread (the overlapped loop) the host
-        # record is that block's steps behind the device, for every row but
+        # lane.  With a block still unread the host record is that block's
+        # steps behind the device, for every row but
         # one activated since; a row that stops mid-block counts on to the
         # block's end.
         lag = self._inflight["n_steps"] if self._inflight else 0
@@ -1874,10 +1838,9 @@ class Engine:
         self.slots[i] = None
         if self._spec:
             self._spec_ok[i] = False
-            self._spec_has_extra[i] = False
         self._slot_lora[i] = -1
-        # A staged budget of 0 is also how the overlapped loop's next block
-        # learns that the host let the row go (_stage_carry).
+        # A staged budget of 0 is also how the next block learns that the
+        # host let the row go (_stage_carry).
         self._slot_remaining[i] = 0
         self._slot_fresh[i] = 0
         if self._slot_stop_lens[i].any():
@@ -2169,45 +2132,6 @@ class Engine:
             prompt_tokens=attrs.get("prompt_tokens", 0),
             bucket=attrs.get("bucket", 0))
 
-    def _loop(self) -> None:
-        while self._running:
-            # 1) Drain admissions: fill EVERY free slot before decoding (a
-            # slot left empty idles for a whole K-step block), then prefill
-            # AHEAD into decode_wait while slots are busy.
-            did_work = self._admit_and_insert(pipelined=False)
-            # 1b) One chunk of ONE in-flight long-prompt stream (fair
-            # round-robin across lanes): decode blocks run between chunks,
-            # so streaming a 32k prompt no longer freezes every active
-            # slot's TPOT — and N lanes advance interleaved instead of a
-            # second long prompt head-of-line blocking behind the first.
-            if self._streams:
-                self._stream_step(pipelined=False)
-                did_work = True
-            # 2) One fused decode block for all active slots.
-            if any(s is not None for s in self.slots):
-                try:
-                    # Stop-automaton rows exclude speculative dispatch:
-                    # the spec block does not evaluate the suffix automata,
-                    # so its history carry would go stale — plain fused
-                    # blocks serve the batch until those rows finish.
-                    if self._spec and not self._stops_active and any(
-                        s is not None and self._spec_ok[i]
-                        and self._slot_temp[i] <= 0.0
-                        for i, s in enumerate(self.slots)
-                    ):
-                        self._do_spec_step()
-                    else:
-                        # No row can accept proposals (all sampled or
-                        # stream-admitted): speculation would only add the
-                        # draft+verify overhead per token.
-                        self._do_decode_step()
-                except Exception as e:  # engine must survive; fail the batch
-                    logger.exception("decode step failed")
-                    self._fail_all_slots(e)
-                did_work = True
-            if not did_work:
-                self._wait_for_work()
-
     def _wait_for_work(self) -> None:
         self._settle_admissions()
         self.profiler.note_idle()
@@ -2215,8 +2139,9 @@ class Engine:
             self._work.wait(timeout=0.05)
 
     @_in_phase("admit")
-    def _admit_and_insert(self, pipelined: bool) -> bool:
-        """Admission for both loops: drain decode_wait into freed slots,
+    def _admit_and_insert(self) -> bool:
+        """Admission: drain decode_wait into freed slots (fill EVERY free
+        slot before decoding: one left empty idles for a whole block),
         direct-prefill into free slots, prefill AHEAD when slots are full.
 
         FIFO holds: decode_wait drains before the raw queue, and a direct
@@ -2226,7 +2151,7 @@ class Engine:
         stream straight into a cache lane, so with no lane free they
         head-of-line block as ``_pending``.
         """
-        did = self._drain_decode_wait(pipelined)
+        did = self._drain_decode_wait()
         cap = (self.cfg.decode_wait_cap if self.cfg.decode_wait_cap is not None
                else self.cfg.decode_slots)
         while True:
@@ -2263,10 +2188,10 @@ class Engine:
                 self._pending = None
                 self._admitting += 1
                 try:
-                    self._do_attach(req, pipelined)
+                    self._do_attach(req)
                 finally:
                     self._admitting -= 1
-                self._drain_decode_wait(pipelined)
+                self._drain_decode_wait()
                 did = True
                 continue
             if self._free_slot_index() is not None:
@@ -2313,9 +2238,7 @@ class Engine:
                         # program computes full-prompt KV, so a cached-prefix
                         # row would pay the compute reuse exists to skip.
                         self._do_prefill_group(
-                            self._collect_prefill_group(req), pipelined)
-                    elif pipelined:
-                        self._do_prefill_pipelined(req)
+                            self._collect_prefill_group(req))
                     else:
                         self._do_prefill(req)
                 finally:
@@ -2332,9 +2255,9 @@ class Engine:
                         # so no pool blocks are touched until the drain,
                         # which gates per row on _paged_can_admit.
                         self._do_prefill_ahead_group(
-                            self._collect_ahead_group(req, cap), pipelined)
+                            self._collect_ahead_group(req, cap))
                     else:
-                        self._do_prefill_ahead(req, pipelined)
+                        self._do_prefill_ahead(req)
                 finally:
                     self._admitting -= 1
                 did = True
@@ -2379,7 +2302,7 @@ class Engine:
             self._usage_sync_kv()  # parked holdings changed
         return swept
 
-    def _drain_decode_wait(self, pipelined: bool) -> bool:
+    def _drain_decode_wait(self) -> bool:
         did = self._sweep_decode_wait()
         while self.decode_wait:
             w = self.decode_wait[0]
@@ -2408,19 +2331,19 @@ class Engine:
             # request still in flight.
             self._admitting += 1
             try:
-                self._insert_waiting(slot_idx, w, pipelined)
+                self._insert_waiting(slot_idx, w)
             finally:
                 self._admitting -= 1
             did = True
         return did
 
-    def _do_prefill_ahead(self, req: Request, pipelined: bool) -> None:
+    def _do_prefill_ahead(self, req: Request) -> None:
         """Prefill with NO slot: prompt KV parks in decode_wait.
 
         TTFT is prefill-bound, not slot-bound, which is the point of the
-        disaggregated design: the sync loop emits the first token at once,
-        the overlapped loop keeps it on the device (async-copied) and reads
-        it once the prefill is done (``_read_first_tokens``).
+        disaggregated design: the first token stays on the device
+        (async-copied) and is read once the prefill is done
+        (``_read_first_tokens``).
         """
         try:
             self._stamp_prefill_start(req)
@@ -2429,13 +2352,7 @@ class Engine:
                          if self.lora is not None else -1)
             first_token, k, v, lp_info = self._bucket_prefill(
                 req, n, lora_slot)
-            if pipelined:
-                try:
-                    first_token.copy_to_host_async()
-                except AttributeError:
-                    pass
-            self._park_waiting(req, first_token, lp_info, k, v, n, lora_slot,
-                               pipelined)
+            self._park_waiting(req, first_token, lp_info, k, v, n, lora_slot)
         except Exception as e:  # engine must survive a poison request
             logger.exception("prefill-ahead failed for %s", req.request_id)
             req.error = str(e)
@@ -2495,13 +2412,14 @@ class Engine:
         vp[:, 0, :n] = v_np
         return jnp.asarray(kp), jnp.asarray(vp)
 
-    def _do_attach(self, req: Request, pipelined: bool) -> None:
+    def _do_attach(self, req: Request) -> None:
         """Import a handoff's KV and park it in ``decode_wait`` — from
         there the normal drain inserts it into a freed slot (allocating
         pool blocks, registering the prefix-cache chain) and decode starts
-        at the carried position.  The first token is emitted HERE, like a
-        sync prefill-ahead park: TTFT on this engine is attach latency,
-        and a one-token request finishes without ever taking a slot."""
+        at the carried position.  The first token is emitted HERE (it
+        came over the wire, on the host): TTFT on this engine is attach
+        latency, and a one-token request finishes without ever taking a
+        slot."""
         handoff = req._attach_handoff
         if req.cancelled.is_set():
             self._finish(req, "cancelled")
@@ -2517,7 +2435,6 @@ class Engine:
                 request=req,
                 first_token=jnp.asarray(handoff.first_token, jnp.int32),
                 k=k, v=v, n=handoff.n, lora_slot=lora_slot,
-                first_token_host=handoff.first_token,
                 lp_info=None, first_emitted=True, from_handoff=True,
                 t_parked=time.time())
             self.decode_wait.append(w)
@@ -2531,11 +2448,11 @@ class Engine:
             self._finish(req, "error")
 
     @_in_phase("prefill.stage")
-    def _activate_slot_pipelined(self, slot_idx: int, req: Request,
-                                 lora_slot: int, n: int, first_token,
-                                 lp_info, emitted: bool = False) -> None:
-        """Overlapped-loop slot activation, shared by direct prefills,
-        decode_wait inserts and chunk-stream activation.  The first token
+    def _activate_slot(self, slot_idx: int, req: Request, lora_slot: int,
+                       n: int, first_token, lp_info,
+                       emitted: bool = False) -> None:
+        """Slot activation, shared by direct prefills, decode_wait inserts
+        and chunk-stream activation.  The first token
         stays on the device: one scatter puts it into the token carry, and
         ``_read_first_tokens`` reads it as soon as the loop holds the
         thread after the prefill (``emitted``: it already reached the
@@ -2545,7 +2462,7 @@ class Engine:
         one fixed-shape helper program whatever else the block frees or
         admits."""
         # Grouped-prefill rows carry their device slice so this scatter
-        # never forces a host sync mid-pipeline.
+        # never forces a host sync.
         tok_dev = (first_token.dev if isinstance(first_token, _Row)
                    and first_token.dev is not None else first_token)
         self._dev_tokens = self._dev_tokens.at[slot_idx].set(tok_dev)
@@ -2560,7 +2477,7 @@ class Engine:
         if tail:
             self._slot_stop_hist[slot_idx, STOP_LEN - len(tail):] = tail
         if not emitted:
-            self._first_unread.append((req, first_token, lp_info))
+            self._queue_first_token(req, first_token, lp_info)
         self._count_first_token(slot_idx, tok_dev)
         if self._spec:
             # _register_slot set the row's sampling params _draft_admit
@@ -2568,8 +2485,17 @@ class Engine:
             self._dev_has_extra = self._dev_has_extra.at[slot_idx].set(False)
             self._draft_admit(slot_idx, req.prompt_tokens)
 
-    def _insert_waiting(self, slot_idx: int, w: _WaitingPrefill,
-                        pipelined: bool) -> None:
+    def _queue_first_token(self, req: Request, first_token, lp_info) -> None:
+        """Start the first token's copy to the host (a grouped row's left
+        with its batch, ``_HostBatch``) and queue it for
+        ``_read_first_tokens``."""
+        try:
+            first_token.copy_to_host_async()
+        except AttributeError:
+            pass
+        self._first_unread.append((req, first_token, lp_info))
+
+    def _insert_waiting(self, slot_idx: int, w: _WaitingPrefill) -> None:
         """Insert a parked prefill's KV into a freed cache lane."""
         req = w.request
         try:
@@ -2589,22 +2515,13 @@ class Engine:
                                    skip_leading_blocks=skip_blocks)
             self._prefix_register_row(slot_idx, req.prompt_tokens,
                                       req.adapter)
-            if pipelined:
-                # ``emitted``: the first token reached the request at
-                # attach admission, or was queued for reading when it
-                # parked (_park_waiting); queuing it again would emit it
-                # twice.  The carry scatter still uses it (decode
-                # continues from it).
-                self._activate_slot_pipelined(
-                    slot_idx, req, w.lora_slot, w.n, w.first_token,
-                    w.lp_info, emitted=True)
-            else:
-                self._register_slot(slot_idx, _Slot(
-                    request=req, lora_slot=w.lora_slot, position=w.n))
-                self._slot_tokens[slot_idx] = w.first_token_host
-                self._slot_positions[slot_idx] = w.n
-                self._count_first_token(slot_idx, w.first_token_host)
-                self._draft_admit(slot_idx, req.prompt_tokens)
+            # ``emitted``: the first token reached the request at attach
+            # admission, or was queued for reading when it parked
+            # (_park_waiting); queuing it again would emit it twice.  The
+            # carry scatter still uses it (decode continues from it).
+            self._activate_slot(
+                slot_idx, req, w.lora_slot, w.n, w.first_token,
+                w.lp_info, emitted=True)
         except Exception as e:
             logger.exception("decode-wait insert failed for %s", req.request_id)
             req.error = str(e)
@@ -2637,7 +2554,7 @@ class Engine:
         budget, and EOS truncation are all mask arithmetic, so the whole
         block is one jitted program: the same dispatch/readback shape as
         ``_decode_impl``, which is what lets speculation compose with the
-        pipelined loop and ``decode_steps_per_sync > 1``.
+        overlapped order and ``decode_steps_per_sync > 1``.
 
         Stale-KV safety: cycle writes at positions p..p+K may leave garbage
         beyond the accepted prefix, but the NEXT cycle's K+1 writes start at
@@ -2804,10 +2721,6 @@ class Engine:
             self.draft_cache = self._jit_draft_insert(
                 self.draft_cache, k, v, jnp.int32(slot_idx), jnp.int32(n))
             self._spec_ok[slot_idx] = True
-            self._spec_has_extra[slot_idx] = False
-            if self.cfg.pipeline_decode and hasattr(self, "_dev_has_extra"):
-                self._dev_has_extra = self._dev_has_extra.at[slot_idx].set(
-                    False)
         except Exception:
             logger.exception("draft admit failed; slot %d decodes "
                              "non-speculatively", slot_idx)
@@ -2847,102 +2760,6 @@ class Engine:
             else n_cycles + k + 1
             for i in range(self.cfg.decode_slots)
         ]
-
-    @_in_phase("decode.plan", hand_over=True)
-    def _do_spec_step(self, ph) -> None:
-        """Sync-loop speculative dispatch: one fused block of cycles, in
-        the phases of ``_do_decode_step``."""
-        k = self.cfg.speculative_k
-        n_cycles = self._spec_cycles_per_sync()
-        # Paged: every position a cycle can write (accepted or rejected)
-        # must have a real block before dispatch.
-        self._paged_ensure_decode(
-            n_cycles * (k + 1), pipelined=False,
-            per_row_steps=self._spec_row_steps(n_cycles, k))
-        ph.to("decode.stage")
-        t0 = time.perf_counter()
-        args = (
-            self.params, self.draft_params, self._lora_buffers(),
-            self.cache, self.draft_cache,
-            jnp.asarray(self._slot_tokens),
-            jnp.asarray(self._slot_positions),
-            jnp.asarray(self._slot_remaining),
-            jnp.asarray(self._spec_extra_tok),
-            jnp.asarray(self._spec_extra_pos),
-            jnp.asarray(self._spec_has_extra),
-            jnp.asarray(self._spec_ok),
-            jnp.asarray(self._slot_temp), jnp.asarray(self._slot_topk),
-            jnp.asarray(self._slot_topp), self._next_key(),
-            jnp.asarray(self._slot_lora), self._eos_for_device,
-            jnp.asarray(self._slot_seed),
-        )
-        with self._enqueue("engine.decode.enqueue"):
-            (toks, valid, lps, top_v, top_i, _next_tok, _next_pos,
-             _next_rem, next_etok, next_epos, next_has, self.cache,
-             self.draft_cache) = self._jit_spec_block(
-                *args, n_cycles=n_cycles, k_steps=k)
-        ph.to("decode.wait")
-        outs = jax.block_until_ready(
-            (toks, valid, lps, top_v, top_i, next_etok, next_epos, next_has))
-        ph.to("decode.readback")
-        # [T, B] each, then the draft's catch-up triple [B]
-        (toks_np, valid_np, lps_np, top_v_np, top_i_np,
-         etok_np, epos_np, ehas_np) = map(np.asarray, outs)
-        step_s = time.perf_counter() - t0
-        ph.to("decode.emit")
-        n_tokens = 0
-        self.spec_cycles += n_cycles
-        t_steps = toks_np.shape[0]
-        owners = [s.request.adapter for s in self.slots if s is not None]
-        tok_by_owner: dict[str, int] = {}
-        for i, slot in enumerate(self.slots):
-            if slot is None:
-                continue
-            req = slot.request
-            if req.cancelled.is_set():
-                self._finish(req, "cancelled")
-                self._clear_slot(i)
-                continue
-            finished = False
-            row_start = n_tokens
-            for j in range(t_steps):
-                if not valid_np[j, i]:
-                    continue  # rejected / frozen / past-EOS entry
-                tok = int(toks_np[j, i])
-                req.output_tokens.append(tok)
-                self._store_logprobs(req, lps_np[j, i], top_v_np[j, i],
-                                     top_i_np[j, i])
-                _publish(req)  # per-step emission (see decode walk)
-                n_tokens += 1
-                slot.position += 1
-                self._slot_tokens[i] = tok
-                self._slot_remaining[i] = max(0, self._slot_remaining[i] - 1)
-                if (self._is_finished(req, tok)
-                        or slot.position >= self.cfg.max_seq_len - 1):
-                    self._finish(req, "stop" if self._is_stop(req, tok)
-                                 else "length")
-                    self._clear_slot(i)
-                    finished = True
-                    break
-            if n_tokens > row_start:
-                key = owner_key(req.adapter)
-                tok_by_owner[key] = (tok_by_owner.get(key, 0)
-                                     + n_tokens - row_start)
-            _publish(req)
-            if finished:
-                continue
-            self._slot_positions[i] = slot.position
-            # Draft catch-up state from the device carry.  A host-only stop
-            # (custom ids) above cleared the slot instead; its lane resets
-            # on reuse via _draft_admit.
-            self._spec_extra_tok[i] = etok_np[i]
-            self._spec_extra_pos[i] = epos_np[i]
-            self._spec_has_extra[i] = bool(ehas_np[i])
-        ph.to("decode.account")
-        self.spec_emitted += n_tokens
-        # Per-cycle cadence (each verify cycle emits >= 1 token/row).
-        self._account_dispatch("spec", t0, step_s, owners, tok_by_owner,
-                               n_tokens, t_steps, cadence_steps=n_cycles)
 
     def _prefill_common(self, req: Request):
         """Shared admission path: bucketed (or ring sequence-parallel)
@@ -3197,23 +3014,15 @@ class Engine:
             self.cfg.prefill_batch, max(1, cap - len(self.decode_wait))))
 
     def _park_waiting(self, req, first_token, lp_info, k, v, n: int,
-                      lora_slot: int, pipelined: bool) -> None:
+                      lora_slot: int) -> None:
         """Park one prefilled row in decode_wait (the prefill-ahead
-        contract: TTFT is prefill-bound, not slot-bound.  The sync loop
-        emits the first token NOW; the overlapped loop reads it with the
-        others once the prefill is done, ``_read_first_tokens``)."""
+        contract: TTFT is prefill-bound, not slot-bound.  The first token
+        is read with the others once the prefill is done,
+        ``_read_first_tokens``)."""
         w = _WaitingPrefill(request=req, first_token=first_token,
                             lp_info=lp_info, k=k, v=v, n=n,
                             lora_slot=lora_slot)
-        if not pipelined:
-            with self._phase("prefill.wait"):
-                tok = int(first_token)
-            w.first_token_host = tok
-            if self._emit_first_token(req, tok, w.lp_info):
-                w.lp_info = None
-                return  # done at prefill; never needed a slot
-        else:
-            self._first_unread.append((req, first_token, lp_info))
+        self._queue_first_token(req, first_token, lp_info)
         self.decode_wait.append(w)
         # Parked prompt KV pins real HBM ([L, 1, bucket, Kh, hd] per entry)
         # outside the decode cache — count the padded rows so the routing
@@ -3223,12 +3032,10 @@ class Engine:
             self.kv_ledger.note_park(int(w.k.shape[2]), "prefill_ahead")
         self._usage_sync_kv()
 
-    def _do_prefill_ahead_group(self, reqs, pipelined: bool) -> None:
+    def _do_prefill_ahead_group(self, reqs) -> None:
         """Batched prefill-ahead: one program, every row parks in
         decode_wait (mirrors ``_do_prefill_ahead`` per row)."""
-        batch = self._grouped_batch(
-            reqs, pipelined,
-            lambda req: self._do_prefill_ahead(req, pipelined))
+        batch = self._grouped_batch(reqs, self._do_prefill_ahead)
         if batch is None:
             return
         live, ns, lora_slots, k, v, tok_rows, lp_rows = batch
@@ -3236,15 +3043,14 @@ class Engine:
             try:
                 self._park_waiting(
                     req, tok_rows[i], lp_rows[i],
-                    k[:, i:i + 1], v[:, i:i + 1], ns[i], lora_slots[i],
-                    pipelined)
+                    k[:, i:i + 1], v[:, i:i + 1], ns[i], lora_slots[i])
             except Exception as e:
                 logger.exception("grouped parking failed for %s",
                                  req.request_id)
                 req.error = str(e)
                 self._finish(req, "error")
 
-    def _grouped_batch(self, reqs, pipelined: bool, single_fn):
+    def _grouped_batch(self, reqs, single_fn):
         """Shared grouped-prefill preamble: filter cancelled/bad-adapter
         rows (each fails alone), fall back to ``single_fn`` for a group of
         one, run ONE batched prefill (a failure there fails the whole
@@ -3252,11 +3058,8 @@ class Engine:
 
         Returns None when the caller has nothing left to do, else
         ``(live, ns, lora_slots, k, v, tok_rows, lp_rows)`` where the
-        per-row token/logprob views are already in the right place for the
-        mode: sync mode fetched them host-side in ONE transfer each
-        (P scalar syncs would re-pay the round-trips batching removed);
-        pipelined mode holds device scalars with the async copy issued on
-        the exact slices later materialized.
+        per-row token/logprob views hold device slices, with one async
+        copy an array issued for the lot (``_HostBatch``).
         """
         live, ns, lora_slots = [], [], []
         for req in reqs:
@@ -3282,24 +3085,14 @@ class Engine:
         try:
             first_tokens, k, v, (lps, top_vs, top_is) = (
                 self._bucket_prefill_many(live, ns, lora_slots))
-            if pipelined:
-                # One async DMA per ARRAY (not per row); rows keep their
-                # device slice for the carry scatter and materialize
-                # host-side from the shared bulk transfer.
-                hb = _HostBatch(first_tokens, lps, top_vs, top_is)
-                tok_rows = [_Row(hb, 0, i, dev=first_tokens[i])
-                            for i in range(len(live))]
-                lp_rows = [(_Row(hb, 1, i), _Row(hb, 2, i), _Row(hb, 3, i))
-                           for i in range(len(live))]
-            else:
-                with self._phase("prefill.wait"):
-                    toks = np.asarray(first_tokens)
-                    lps_h, top_vs_h, top_is_h = (
-                        np.asarray(lps), np.asarray(top_vs),
-                        np.asarray(top_is))
-                tok_rows = [int(t) for t in toks]
-                lp_rows = [(lps_h[i], top_vs_h[i], top_is_h[i])
-                           for i in range(len(live))]
+            # One async DMA per ARRAY (not per row); rows keep their
+            # device slice for the carry scatter and materialize
+            # host-side from the shared bulk transfer.
+            hb = _HostBatch(first_tokens, lps, top_vs, top_is)
+            tok_rows = [_Row(hb, 0, i, dev=first_tokens[i])
+                        for i in range(len(live))]
+            lp_rows = [(_Row(hb, 1, i), _Row(hb, 2, i), _Row(hb, 3, i))
+                       for i in range(len(live))]
         except Exception as e:
             logger.exception("grouped prefill failed (%d reqs)", len(live))
             for req in live:
@@ -3308,14 +3101,11 @@ class Engine:
             return None
         return live, ns, lora_slots, k, v, tok_rows, lp_rows
 
-    def _do_prefill_group(self, reqs, pipelined: bool) -> None:
+    def _do_prefill_group(self, reqs) -> None:
         """Batched admission: one prefill program fills len(reqs) slots.
-        Per-row post-processing mirrors ``_do_prefill`` /
-        ``_do_prefill_pipelined``; a row that fails after the batched call
-        fails alone."""
-        batch = self._grouped_batch(
-            reqs, pipelined,
-            self._do_prefill_pipelined if pipelined else self._do_prefill)
+        Per-row post-processing mirrors ``_do_prefill``; a row that fails
+        after the batched call fails alone."""
+        batch = self._grouped_batch(reqs, self._do_prefill)
         if batch is None:
             return
         live, ns, lora_slots, k, v, tok_rows, lp_rows = batch
@@ -3332,8 +3122,7 @@ class Engine:
                     # dropped.  Park it exactly like a prefill-ahead.
                     self._park_waiting(
                         req, tok_rows[i], lp_rows[i],
-                        k[:, i:i + 1], v[:, i:i + 1], ns[i], lora_slots[i],
-                        pipelined)
+                        k[:, i:i + 1], v[:, i:i + 1], ns[i], lora_slots[i])
                     continue
                 try:
                     self._insert_prompt_kv(
@@ -3347,24 +3136,11 @@ class Engine:
                     pool_starved = True
                     self._park_waiting(
                         req, tok_rows[i], lp_rows[i],
-                        k[:, i:i + 1], v[:, i:i + 1], ns[i], lora_slots[i],
-                        pipelined)
+                        k[:, i:i + 1], v[:, i:i + 1], ns[i], lora_slots[i])
                     continue
-                if pipelined:
-                    self._activate_slot_pipelined(
-                        slot_idx, req, lora_slots[i], ns[i],
-                        tok_rows[i], lp_rows[i])
-                else:
-                    if self._emit_first_token(req, tok_rows[i], lp_rows[i]):
-                        continue  # finished at prefill
-                    self._register_slot(slot_idx, _Slot(
-                        request=req, lora_slot=lora_slots[i],
-                        position=ns[i]))
-                    self._slot_tokens[slot_idx] = int(req.output_tokens[-1])
-                    self._slot_positions[slot_idx] = ns[i]
-                    self._count_first_token(
-                        slot_idx, int(req.output_tokens[-1]))
-                    self._draft_admit(slot_idx, req.prompt_tokens)
+                self._activate_slot(
+                    slot_idx, req, lora_slots[i], ns[i],
+                    tok_rows[i], lp_rows[i])
             except Exception as e:
                 logger.exception("grouped admission failed for %s",
                                  req.request_id)
@@ -3388,24 +3164,29 @@ class Engine:
             return
         try:
             self._paged_ensure(slot_idx, n)
-        except PagedPoolExhausted:
+            bucket = k.shape[2]
+            nb_bucket = -(-bucket // self._block)
+            row_bl = self._row_blocks[slot_idx]
+            # Wholly-padding bucket blocks scatter into the trash block.
+            phys = row_bl + [paged_lib.TRASH_BLOCK] * (
+                nb_bucket - len(row_bl))
+            if skip_leading_blocks:
+                phys = ([paged_lib.TRASH_BLOCK] * skip_leading_blocks
+                        + phys[skip_leading_blocks:])
+            self._sync_tables()
+            self.cache = self._jit_insert(
+                self.cache, k, v, jnp.int32(slot_idx),
+                jnp.asarray(phys, jnp.int32),
+                jnp.asarray(self._tables_host[slot_idx]),
+                jnp.int32(n),
+            )
+        except Exception:
+            # No slot is registered yet, so no _clear_slot will ever free
+            # the row: an exhausted pool (the caller parks the row) or a
+            # failed scatter (the caller fails the request) would strand
+            # its blocks.
             self._paged_free_row(slot_idx)
             raise
-        bucket = k.shape[2]
-        nb_bucket = -(-bucket // self._block)
-        row_bl = self._row_blocks[slot_idx]
-        # Wholly-padding bucket blocks scatter into the trash block.
-        phys = row_bl + [paged_lib.TRASH_BLOCK] * (nb_bucket - len(row_bl))
-        if skip_leading_blocks:
-            phys = ([paged_lib.TRASH_BLOCK] * skip_leading_blocks
-                    + phys[skip_leading_blocks:])
-        self._sync_tables()
-        self.cache = self._jit_insert(
-            self.cache, k, v, jnp.int32(slot_idx),
-            jnp.asarray(phys, jnp.int32),
-            jnp.asarray(self._tables_host[slot_idx]),
-            jnp.int32(n),
-        )
 
     # ------------------------------------------------------------------
     # interleaved long-prompt streaming (one chunk per engine cycle)
@@ -3485,11 +3266,13 @@ class Engine:
         self._finish(st.request, reason)
 
     @_in_phase("admit")
-    def _stream_step(self, pipelined: bool) -> None:
+    def _stream_step(self) -> None:
         """Dispatch ONE chunk of ONE in-flight stream — the round-robin
         cursor rotates across lanes, so N concurrent long prompts advance
         fairly interleaved (one chunk per engine cycle total keeps the
-        decode cadence unchanged versus a single lane).  On a stream's
+        decode cadence unchanged versus a single lane), and decode blocks
+        run between chunks, so streaming a 32k prompt does not freeze
+        every active slot's TPOT.  On a stream's
         final chunk, sample the first token and activate its lane as a
         live decode slot."""
         if not self._streams:
@@ -3531,26 +3314,8 @@ class Engine:
         slot_idx = st.slot_idx
         try:
             first_token, lp_info = self._sample_first(req, st.last_logits, n)
-            if pipelined:
-                try:
-                    first_token.copy_to_host_async()
-                except AttributeError:
-                    pass
-                self._activate_slot_pipelined(
-                    slot_idx, req, st.lora_slot, n, first_token, lp_info)
-                return
-            with self._phase("prefill.wait"):
-                tok = int(first_token)
-            if self._emit_first_token(req, tok, lp_info):
-                if self.paged:  # finished at prefill; free the lane's blocks
-                    self._paged_free_row(slot_idx)
-                return
-            self._register_slot(
-                slot_idx, _Slot(request=req, lora_slot=st.lora_slot,
-                                position=n))
-            self._slot_tokens[slot_idx] = int(req.output_tokens[-1])
-            self._slot_positions[slot_idx] = n
-            self._count_first_token(slot_idx, int(req.output_tokens[-1]))
+            self._activate_slot(
+                slot_idx, req, st.lora_slot, n, first_token, lp_info)
         except Exception as e:
             logger.exception("stream activation failed for %s", req.request_id)
             req.error = str(e)
@@ -3675,13 +3440,11 @@ class Engine:
 
     def _account_dispatch(self, kind: str, t0: float, step_s: float,
                           owners: list, tok_by_owner: dict[str, int],
-                          n_tokens: int, n_steps: int,
-                          cadence_steps: int | None = None) -> None:
-        """End-of-dispatch bookkeeping of every decode dispatch (sync,
-        pipelined, speculative), under the caller's ``decode.account``
-        phase.  ``tpu:decode_step_seconds`` observes ``step_s /
-        cadence_steps`` (``n_steps`` unless given: a sync speculative
-        block passes its verify cycles).  ``tpu:dispatch_steps`` records
+                          n_tokens: int, n_steps: int) -> None:
+        """End-of-dispatch bookkeeping of every decode dispatch (plain or
+        speculative), under the caller's ``decode.account`` phase.
+        ``tpu:decode_step_seconds`` observes ``step_s / n_steps``.
+        ``tpu:dispatch_steps`` records
         the PLANNER's power-of-two choices, so only ``kind == "decode"``
         observes it: a speculative block's token-row count is not one."""
         self.usage.charge_decode(step_s, owners, tok_by_owner)
@@ -3696,7 +3459,7 @@ class Engine:
             self.decode_tps_ema = ((1 - TPS_EMA_ALPHA) * self.decode_tps_ema
                                    + TPS_EMA_ALPHA * inst)
             self.phase_hist["decode_step"].observe(
-                step_s / max(1, cadence_steps or n_steps))
+                step_s / max(1, n_steps))
             if kind == "decode":
                 self.dispatch_steps_hist.observe(n_steps)
 
@@ -3731,8 +3494,8 @@ class Engine:
         chunks carries its last chunk's, the earlier ones having run
         between decode blocks."""
         if self._first_unread:
-            # The overlapped loop between an admission's staging and the
-            # reading of its first token: its parts are not whole yet.
+            # Between an admission's staging and the reading of its first
+            # token: its parts are not whole yet.
             return
         parts = self.profiler.take_prefill_split()
         for req in self._unsettled:
@@ -3769,44 +3532,12 @@ class Engine:
             return True
         return False
 
-    def _do_prefill(self, req: Request) -> None:
-        if req.cancelled.is_set():  # died while queued: skip the prefill
-            self._finish(req, "cancelled")
-            return
-        slot_idx = None
-        registered = False
-        try:
-            slot_idx, first_token, n, lora_slot, lp_info = (
-                self._prefill_common(req))
-            with self._phase("prefill.wait"):
-                tok = int(first_token)
-            if self._emit_first_token(req, tok, lp_info):
-                return  # finished at prefill; the finally frees its blocks
-            self._register_slot(
-                slot_idx, _Slot(request=req, lora_slot=lora_slot, position=n)
-            )
-            registered = True
-            self._slot_tokens[slot_idx] = int(req.output_tokens[-1])
-            self._slot_positions[slot_idx] = n
-            self._count_first_token(slot_idx, int(req.output_tokens[-1]))
-            self._draft_admit(slot_idx, req.prompt_tokens)
-        except Exception as e:  # engine must survive a poison request
-            logger.exception("prefill failed for %s", req.request_id)
-            req.error = str(e)
-            self._finish(req, "error")
-        finally:
-            if self.paged and slot_idx is not None and not registered:
-                # Early finish or failure after blocks were allocated: a
-                # slot-less row would strand them forever (no _clear_slot
-                # will ever run for it).
-                self._paged_free_row(slot_idx)
-
-    def _paged_ensure_decode(self, n_steps: int, pipelined: bool,
+    def _paged_ensure_decode(self, n_steps: int,
                              per_row_steps: list[int] | None = None) -> None:
         """Pre-dispatch block growth for every active row.
 
-        Pipelined mode's host position lags the device by the IN-FLIGHT
-        dispatch, so the reservation is previous-dispatch-steps + this
+        The host position lags the device by the IN-FLIGHT dispatch, so
+        the reservation is previous-dispatch-steps + this
         dispatch's steps — dispatch sizes vary when speculative blocks
         (cycles x (K+1) writes, including rejected tails) interleave with
         plain blocks, so a flat 2*n_steps would under-reserve after a
@@ -3819,12 +3550,10 @@ class Engine:
         exhausted pool cannot grow fails with "kv pool exhausted" (the
         documented oversubscription tradeoff) without touching the batch.
         """
-        prev = self._prev_dispatch_steps if pipelined else 0
-        if pipelined:
-            # Recorded for BOTH cache layouts: the paged reservation below
-            # needs it, and _plan_steps subtracts it from the host-lagged
-            # remaining budgets on non-paged pipelined engines too.
-            self._prev_dispatch_steps = n_steps
+        # Recorded for BOTH cache layouts: the paged reservation below
+        # needs it, and _plan_steps subtracts it from the host-lagged
+        # remaining budgets on lanes too.
+        prev, self._prev_dispatch_steps = self._prev_dispatch_steps, n_steps
         if not self.paged:
             return
         for i, slot in enumerate(self.slots):
@@ -3844,83 +3573,13 @@ class Engine:
                 self._clear_slot(i)
         self._sync_tables()
 
-    @_in_phase("decode.plan", hand_over=True)
-    def _do_decode_step(self, ph) -> None:
-        """One sync-loop decode dispatch; ``ph`` is the open phase, renamed
-        as the step goes: plan, stage, wait, readback, emit, account."""
-        n_steps = self._plan_steps()
-        self._paged_ensure_decode(n_steps, pipelined=False)
-        ph.to("decode.stage")
-        t0 = time.perf_counter()
-        self._sync_stop_hist()
-        outs, _, moe = self._enqueue_decode(n_steps)
-        ph.to("decode.wait")
-        outs = jax.block_until_ready((*outs, *moe))
-        ph.to("decode.readback")
-        # [n_steps, B] each.  One device_get for the lot: the copies start
-        # together and the thread waits once, where one np.asarray per array
-        # waited in turn (2.3-2.4 ms a step for five on the v5e host; ledger,
-        # PR 25).
-        toks_np, valid_np, lps_np, top_v_np, top_i_np, paths_np, *moe = (
-            jax.device_get(outs))
-        self.profiler.note_sample_paths(paths_np)
-        self._moe_account(moe)
-        step_s = time.perf_counter() - t0
-        ph.to("decode.emit")
-        n_tokens = 0
-        # Attribution: owners captured BEFORE the loop clears finished
-        # slots (they were all resident for this dispatch's wall).
-        owners = [s.request.adapter for s in self.slots if s is not None]
-        tok_by_owner: dict[str, int] = {}
-        for i, slot in enumerate(self.slots):
-            if slot is None:
-                continue
-            req = slot.request
-            if req.cancelled.is_set():
-                self._finish(req, "cancelled")
-                self._clear_slot(i)
-                continue
-            finished = False
-            slot_tokens = 0
-            for k in range(n_steps):
-                if not valid_np[k, i]:
-                    continue  # device froze this row (budget/EOS)
-                tok = int(toks_np[k, i])
-                req.output_tokens.append(tok)
-                self._store_logprobs(req, lps_np[k, i], top_v_np[k, i],
-                                     top_i_np[k, i])
-                # Per-step emission: each token of the fused block is
-                # published to the stream consumer as it lands in the
-                # trim walk, not once per dispatch — an SSE reader wakes
-                # per token instead of per burst.
-                _publish(req)
-                n_tokens += 1
-                slot_tokens += 1
-                slot.position += 1
-                self._slot_tokens[i] = tok
-                self._slot_remaining[i] = max(0, self._slot_remaining[i] - 1)
-                if self._is_finished(req, tok) or slot.position >= self.cfg.max_seq_len - 1:
-                    self._finish(req, "stop" if self._is_stop(req, tok) else "length")
-                    self._clear_slot(i)
-                    finished = True
-                    break  # tokens past the stop condition are trimmed
-            if slot_tokens:
-                key = owner_key(req.adapter)
-                tok_by_owner[key] = tok_by_owner.get(key, 0) + slot_tokens
-            _publish(req)
-            if not finished:
-                self._slot_positions[i] = slot.position
-        ph.to("decode.account")
-        self._account_dispatch("decode", t0, step_s, owners, tok_by_owner,
-                               n_tokens, n_steps)
-
     # ------------------------------------------------------------------
-    # pipelined decode: overlap host readback with the next device block
+    # the loop: host readback overlaps the next device block
     # ------------------------------------------------------------------
 
-    def _loop_pipelined(self) -> None:
-        """The overlapped order (the default): block N+1 is dispatched from
-        the device-resident token/position/budget carry BEFORE block N's
+    def _loop(self) -> None:
+        """The overlapped order: block N+1 is dispatched from the
+        device-resident token/position/budget carry BEFORE block N's
         tokens are read, so the device never waits for the host between
         two decode steps: readback, emit, accounting, admission, planning
         and staging of a step all run while it computes the next one.
@@ -3937,36 +3596,23 @@ class Engine:
           row freed for a reason only the host sees (custom stop ids, the
           length cap, a cancellation) is staged with budget 0 and the next
           block zeroes it in the carry (``_stage_carry``);
+        - slot FREEING lags one block (the frozen row just decodes invalid
+          steps until the host sees the stop);
         - a prefill's first token stays on the device until the prefill is
           done, and leaves then, not with its slot's first block
           (``_read_first_tokens``).
         """
-        b = self.cfg.decode_slots
-        self._dev_tokens = jnp.zeros((b,), jnp.int32)
-        self._dev_positions = jnp.zeros((b,), jnp.int32)
-        self._dev_remaining = jnp.zeros((b,), jnp.int32)
-        # Stop-automaton history rides the device carry (the overlapped
-        # loop's no-host-round-trip contract); rows re-seed at activation.
-        self._dev_stop_hist = jnp.full((b, STOP_LEN), -1, jnp.int32)
-        if self._spec:
-            # Draft catch-up triple lives on device: spec blocks update it
-            # in their carry, no host round-trip.
-            self._dev_extra_tok = jnp.zeros((b,), jnp.int32)
-            self._dev_extra_pos = jnp.zeros((b,), jnp.int32)
-            self._dev_has_extra = jnp.zeros((b,), bool)
-        # Write span of the dispatch currently in flight (paged reservation).
-        self._prev_dispatch_steps = 0
         while self._running:
-            did_work = self._admit_and_insert(pipelined=True)
+            did_work = self._admit_and_insert()
             if self._streams:
-                self._stream_step(pipelined=True)
+                self._stream_step()
                 did_work = True
             block = None
             if any(s is not None for s in self.slots):
                 try:
                     block = self._dispatch_block()
                 except Exception as e:
-                    logger.exception("pipelined decode dispatch failed")
+                    logger.exception("decode dispatch failed")
                     self._fail_all_slots(e)
                 did_work = True
             if self._inflight is not None:
@@ -3974,9 +3620,8 @@ class Engine:
                     self._process_block(self._inflight, current=block)
                 except Exception as e:
                     # Async JAX errors surface at materialization, not at
-                    # dispatch — the sync loop's "engine must survive; fail
-                    # the batch" invariant applies here too.
-                    logger.exception("pipelined block materialization failed")
+                    # dispatch: the engine must survive; fail the batch.
+                    logger.exception("block materialization failed")
                     self._fail_all_slots(e)
                     block = None
                 did_work = True
@@ -3999,12 +3644,12 @@ class Engine:
     def _read_first_tokens(self, current: dict | None) -> None:
         """Read the first tokens still on the device, in the order their
         prefills were enqueued, and hand each to its request: the point at
-        which a streamed request's first chunk leaves in the overlapped
-        loop.  The thread waits here (``prefill.wait``) for a prefill that
-        is not done; block ``current`` is queued behind it meanwhile.  A
-        request that finishes with this token (``max_tokens`` 1, a stop)
-        gives its slot, or its place in decode_wait, back at once; its
-        lane in ``current`` is garbage."""
+        which a streamed request's first chunk leaves.  The thread waits
+        here (``prefill.wait``) for a prefill that is not done; block
+        ``current`` is queued behind it meanwhile.  A request that finishes
+        with this token (``max_tokens`` 1, a stop) gives its slot, or its
+        place in decode_wait, back at once; its lane in ``current`` is
+        garbage."""
         unread, self._first_unread = self._first_unread, []
         for req, first_token, lp_info in unread:
             if req.done.is_set():
@@ -4049,7 +3694,7 @@ class Engine:
                 self._finish(slot.request, "error")
                 self._clear_slot(i)
 
-    def _do_prefill_pipelined(self, req: Request) -> None:
+    def _do_prefill(self, req: Request) -> None:
         """Prefill + insert with NO synchronous readback: the first token is
         scattered into the device carry and async-copied; the loop reads it
         once the prefill is done (``_read_first_tokens``)."""
@@ -4061,18 +3706,14 @@ class Engine:
         try:
             slot_idx, first_token, n, lora_slot, lp_info = (
                 self._prefill_common(req))
-            try:
-                first_token.copy_to_host_async()
-            except AttributeError:
-                pass
             # t_first_token is stamped when the token MATERIALIZES in
             # _read_first_tokens: stamping here would understate TTFT by
             # the prefill program and the block it is queued behind.
-            self._activate_slot_pipelined(
+            self._activate_slot(
                 slot_idx, req, lora_slot, n, first_token, lp_info)
             registered = True
-        except Exception as e:
-            logger.exception("pipelined prefill failed for %s", req.request_id)
+        except Exception as e:  # engine must survive a poison request
+            logger.exception("prefill failed for %s", req.request_id)
             req.error = str(e)
             self._finish(req, "error")
         finally:
@@ -4083,19 +3724,22 @@ class Engine:
     def _dispatch_block(self, ph) -> dict:
         """Stage and enqueue the next block from the device carry, whether
         or not the block before it has been read (``_inflight``: the
-        mechanism of the overlapped loop, counted in
+        mechanism of the overlapped order, counted in
         ``tpu:decode_blocks_overlapped_total``)."""
         if self._inflight is not None:
             self.profiler.note_overlapped_block()
-        # _stops_active: same speculative exclusion as the sync loop —
-        # only plain blocks evaluate the stop automata.
+        # Stop-automaton rows exclude speculative dispatch: the spec
+        # block does not evaluate the suffix automata, so its history
+        # carry would go stale; plain fused blocks serve the batch until
+        # those rows finish.  Nor is it worth its draft and verify where
+        # no row can accept proposals (all sampled or stream-admitted).
         if self._spec and not self._stops_active and any(
             s is not None and self._spec_ok[i] and self._slot_temp[i] <= 0.0
             for i, s in enumerate(self.slots)
         ):
             return self._dispatch_spec_block(ph)
         n_steps = self._plan_steps()
-        self._paged_ensure_decode(n_steps, pipelined=True)
+        self._paged_ensure_decode(n_steps)
         ph.to("decode.stage")
         t0 = time.perf_counter()
         (toks, valid, lps, top_v, top_i, paths), carry, moe = (
@@ -4125,7 +3769,7 @@ class Engine:
         }
 
     def _dispatch_spec_block(self, ph) -> dict:
-        """Pipelined speculative dispatch: same block contract as the plain
+        """Speculative dispatch: same block contract as the plain
         path — flattened [T, B] outputs plus device carries — so
         ``_process_block`` consumes it unchanged.  The draft-extra triple
         rides the device carry; between spec and plain blocks (e.g. the
@@ -4134,8 +3778,10 @@ class Engine:
         verify is exact regardless of what the draft proposes."""
         k = self.cfg.speculative_k
         n_cycles = self._spec_cycles_per_sync()
+        # Paged: every position a cycle can write (accepted or rejected)
+        # must have a real block before dispatch.
         self._paged_ensure_decode(
-            n_cycles * (k + 1), pipelined=True,
+            n_cycles * (k + 1),
             per_row_steps=self._spec_row_steps(n_cycles, k))
         ph.to("decode.stage")
         t0 = time.perf_counter()
@@ -4199,7 +3845,7 @@ class Engine:
         read since) to this block's completion.  With blocks overlapped
         that is the cadence at which a row's tokens become available, one
         device step; for a block staged on an idle device it is stage +
-        wait, the sync loop's step."""
+        wait."""
         outs = jax.block_until_ready(
             (blk["toks"], blk["valid"], blk["lps"], blk["top_v"],
              blk["top_i"], *blk.get("tail", ())))
@@ -4208,9 +3854,11 @@ class Engine:
         step_s = done - t0
         self._last_done_pc = done
         ph.to("decode.readback")
-        # One device_get for the lot, as in ``_do_decode_step``.  A plain
-        # block's tail is its sampler paths, then routing counts; a
-        # speculative block has none.
+        # [n_steps, B] each.  One device_get for the lot: the copies start
+        # together and the thread waits once, where one np.asarray per
+        # array waited in turn (2.3-2.4 ms a step for five on the v5e
+        # host; ledger, PR 25).  A plain block's tail is its sampler
+        # paths, then routing counts; a speculative block has none.
         toks_np, valid_np, lps_np, top_v_np, top_i_np, *tail = (
             jax.device_get(outs))
         if tail:
@@ -4244,7 +3892,11 @@ class Engine:
                 req.output_tokens.append(tok)
                 self._store_logprobs(req, lps_np[k, i], top_v_np[k, i],
                                      top_i_np[k, i])
-                _publish(req)  # per-step emission (see decode walk)
+                # Per-step emission: each token of the fused block is
+                # published to the stream consumer as it lands in the
+                # trim walk, not once per dispatch — an SSE reader wakes
+                # per token instead of per burst.
+                _publish(req)
                 n_tokens += 1
                 row_tokens += 1
                 slot.position += 1

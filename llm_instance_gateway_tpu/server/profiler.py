@@ -12,7 +12,7 @@ recorder charged at the same call sites as the usage tracker
 disjoint buckets:
 
 - **dispatch**: the jitted program call plus its host sync (the
-  ``step_s`` every decode/spec/pipelined block already measures, and the
+  ``step_s`` every decode/spec block already measures, and the
   prefill compute wall) — ``tpu:dispatch_wall_seconds{phase}``;
 - **host-sync**: the gap between one dispatch's end and the next
   dispatch's start while the engine had work — the Python step-loop tax
@@ -315,14 +315,14 @@ class StepProfiler:
         dispatch is its ``t0`` less the end of the one before (a prefill's
         wall is never host-sync, and the host's time between a prefill and
         the next decode block is).  A dispatch that began before the last
-        one ended (a pipelined block, a prompt streamed in chunks between
+        one ended (an overlapped block, a prompt streamed in chunks between
         decode blocks) has no gap.
 
         A decode or spec record also carries what the phase stack charged
         to ``decode.stage`` / ``.wait`` / ``.readback`` / ``.emit`` since
-        the last such record: in the sync loop exactly this dispatch's
-        (stage + wait + readback is its wall); in the pipelined loop the
-        stage is the next block's, staged before this one was awaited.
+        the last such record: the wait, the readback and the emit are
+        this block's, the stage is the next block's, staged before this
+        one was awaited.
         """
         if wall_s < 0.0:
             wall_s = 0.0

@@ -53,8 +53,7 @@ def test_prefill_with_cache_matches_monolithic(chunk):
     assert float(jnp.abs(cache["k"][:, 0]).sum()) == 0.0
 
 
-@pytest.mark.parametrize("pipeline", [False, True], ids=["sync", "pipelined"])
-def test_engine_long_prompt_matches_bucketed(pipeline):
+def test_engine_long_prompt_matches_bucketed():
     """A prompt beyond the largest bucket (chunked path) must produce the
     same greedy continuation as an engine whose bucket covers it whole."""
     params = transformer.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
@@ -75,7 +74,7 @@ def test_engine_long_prompt_matches_bucketed(pipeline):
     chunked = Engine(
         CFG, params,
         EngineConfig(decode_slots=2, max_seq_len=96, prefill_buckets=(16,),
-                     decode_steps_per_sync=2, pipeline_decode=pipeline),
+                     decode_steps_per_sync=2),
         eos_id=None, dtype=jnp.float32,
     )
     chunked.start()
@@ -118,8 +117,7 @@ def test_cancel_during_chunked_prefill_stops_chunks():
         engine.stop()
 
 
-@pytest.mark.parametrize("pipeline", [False, True])
-def test_stream_interleaves_with_decode(pipeline):
+def test_stream_interleaves_with_decode():
     """While a long prompt streams in chunk-by-chunk, an already-active
     request must keep producing tokens (round 1 ran the whole chunked
     prefill inside one admission, stalling every active slot)."""
@@ -127,7 +125,7 @@ def test_stream_interleaves_with_decode(pipeline):
     engine = Engine(
         CFG, params,
         EngineConfig(decode_slots=2, max_seq_len=256, prefill_buckets=(8,),
-                     decode_steps_per_sync=1, pipeline_decode=pipeline),
+                     decode_steps_per_sync=1),
         eos_id=None, dtype=jnp.float32,
     )
     engine.start()

@@ -40,9 +40,9 @@ def params():
                                    dtype=jnp.float32)
 
 
-def make_engine(params, *, adaptive=0, device_stops=True, pipeline=False,
-                steps=1, lanes=1, slots=2, paged=False, blocks=None,
-                max_seq=64, buckets=(8, 16)):
+def make_engine(params, *, adaptive=0, device_stops=True, steps=1, lanes=1,
+                slots=2, paged=False, blocks=None, max_seq=64,
+                buckets=(8, 16)):
     return Engine(
         CFG, params,
         EngineConfig(
@@ -50,7 +50,6 @@ def make_engine(params, *, adaptive=0, device_stops=True, pipeline=False,
             prefill_buckets=buckets,
             decode_steps_per_sync=steps, adaptive_steps=adaptive,
             device_stops=device_stops, stream_lanes=lanes,
-            pipeline_decode=pipeline,
             paged_kv_block=8 if paged else None, paged_kv_blocks=blocks,
         ),
         lora_manager=None, eos_id=None, dtype=jnp.float32,
@@ -105,11 +104,9 @@ class TestStopAutomatonUnits:
 
 class TestDeviceStopParity:
     """Fused device-side stop strings == steps=1 host oracle, byte for
-    byte, on both loops (the PR's pinned acceptance bar)."""
+    byte (the PR's pinned acceptance bar)."""
 
-    @pytest.mark.parametrize("pipeline", [False, True],
-                             ids=["sync", "pipelined"])
-    def test_multi_token_stop_parity(self, params, pipeline):
+    def test_multi_token_stop_parity(self, params):
         oracle = make_engine(params, steps=1, device_stops=False)
         oracle.start()
         try:
@@ -138,8 +135,7 @@ class TestDeviceStopParity:
             ]
         finally:
             oracle.stop()
-        fused = make_engine(params, adaptive=8, device_stops=True,
-                            pipeline=pipeline)
+        fused = make_engine(params, adaptive=8, device_stops=True)
         fused.start()
         try:
             for (ss, ids), want in zip(cases, wants):
@@ -150,10 +146,7 @@ class TestDeviceStopParity:
         finally:
             fused.stop()
 
-    @pytest.mark.parametrize("pipeline", [False, True],
-                             ids=["sync", "pipelined"])
-    def test_stop_spanning_dispatch_boundary_static_steps(self, params,
-                                                          pipeline):
+    def test_stop_spanning_dispatch_boundary_static_steps(self, params):
         """History must carry ACROSS dispatches: with static 4-step fusion
         a stop whose tokens straddle the block edge still matches."""
         oracle = make_engine(params, steps=1, device_stops=False)
@@ -164,8 +157,7 @@ class TestDeviceStopParity:
             want = gen(oracle, (9, 9), max_new=12, stop_sequences=[stop])
         finally:
             oracle.stop()
-        fused = make_engine(params, steps=4, device_stops=True,
-                            pipeline=pipeline)
+        fused = make_engine(params, steps=4, device_stops=True)
         fused.start()
         try:
             got = gen(fused, (9, 9), max_new=12, stop_sequences=[stop])
@@ -230,10 +222,7 @@ class TestDeviceStopParity:
 
 
 class TestAdaptivePlanner:
-    @pytest.mark.parametrize("pipeline", [False, True],
-                             ids=["sync", "pipelined"])
-    def test_same_seed_parity_across_loops_and_fusion(self, params,
-                                                      pipeline):
+    def test_same_seed_parity_across_fusion(self, params):
         """Seeded sampling depends only on (seed, position): adaptive
         fused dispatch must reproduce the steps=1 oracle token-for-token
         even at temperature > 0."""
@@ -244,7 +233,7 @@ class TestAdaptivePlanner:
                        seed=42).output_tokens
         finally:
             oracle.stop()
-        fused = make_engine(params, adaptive=8, pipeline=pipeline)
+        fused = make_engine(params, adaptive=8)
         fused.start()
         try:
             got = gen(fused, (3, 1, 4), max_new=12, temp=0.9,
@@ -264,8 +253,10 @@ class TestAdaptivePlanner:
         # Some dispatch fused more than one step...
         assert st["sum"] > st["count"]
         # ...and the planner clamped to the remaining budget instead of
-        # overshooting: 16 decode tokens exactly (1 came from prefill).
-        assert st["sum"] == 16
+        # overshooting: 16 decode steps (1 token came from prefill), and
+        # the one block that is dispatched before the host has read the
+        # row's last token is a single step, never a fused one.
+        assert 16 <= st["sum"] <= 17
 
     def test_streaming_rows_cap_fusion(self, params):
         """The SSE-cadence planner input: a streaming consumer pins every

@@ -8,7 +8,7 @@ numpy source, which would hide a stale device value), splits the key on the
 host and makes a fresh counts dummy, and runs the parent's decode program.
 A schedule in which consecutive occupants of a slot differ in everything a
 slot carries has to give the same tokens and logprobs through the engine as
-through it, in both loops, dense and sparse.
+through it, dense and sparse.
 """
 
 import time
@@ -45,9 +45,10 @@ from llm_instance_gateway_tpu.server.sampling import (
 
 SLOTS = 2
 MODELS = {"dense": TINY_TEST, "moe": TINY_MOE_TEST}
-# The parent's fifteen mirrors: name -> (dtype, shape of a row, empty row).
+# The parent's mirrors, but for ``tokens`` (PR 48: a row's last token never
+# leaves the device carry): name -> (dtype, shape of a row, empty row).
 PARENT_MIRRORS = {
-    "tokens": (np.int32, (), 0), "positions": (np.int32, (), 0),
+    "positions": (np.int32, (), 0),
     "lora": (np.int32, (), -1), "temp": (np.float32, (), 0.0),
     "topk": (np.int32, (), 0), "topp": (np.float32, (), 1.0),
     "seed": (np.int32, (), -1), "presence": (np.float32, (), 0.0),
@@ -143,7 +144,7 @@ def _parent_decode_impl(
 
 
 class RestagingEngine(Engine):
-    """The parent's staging: fifteen uploads, an eager key split (and its
+    """The parent's staging: an upload a field, an eager key split (and its
     unpacking) on the host and a fresh dummy before every block of the
     parent's program; the same eager split before every prefill."""
 
@@ -159,14 +160,11 @@ class RestagingEngine(Engine):
         self._rng, sub = jax.random.split(self._rng)
         return sub
 
-    def _enqueue_decode(self, n_steps, carry=None):
+    def _enqueue_decode(self, n_steps, carry):
         def up(name):
             return jnp.array(getattr(self, "_slot_" + name), copy=True)
 
-        if carry is not None:
-            carry = self._scatter_into(carry)
-        tokens, positions, remaining, hist = carry or (
-            up("tokens"), up("positions"), up("remaining"), up("stop_hist"))
+        tokens, positions, remaining, hist = self._scatter_into(carry)
         penalized = bool(self._slot_presence.any()
                          or self._slot_frequency.any())
         counts = (self._counts() if penalized
@@ -186,7 +184,7 @@ class RestagingEngine(Engine):
 
 
 def _scatter_into(self, carry):
-    """The parent's way with the overlapped loop's carry, eager and row by
+    """The parent's way with the device carry, eager and row by
     row: an activated row's position, budget and stop history scattered
     in, a freed row's budget zeroed (``_stage_carry`` does both inside the
     program, from the staged buffer)."""
@@ -207,7 +205,7 @@ def _scatter_into(self, carry):
 RestagingEngine._scatter_into = _scatter_into
 
 
-def build(engine_cls, model: str, pipelined: bool, slots: int = SLOTS):
+def build(engine_cls, model: str, slots: int = SLOTS):
     """An engine of ``engine_cls`` over seeded tiny weights and two
     adapters; same arguments, same weights."""
     cfg = MODELS[model]
@@ -224,7 +222,7 @@ def build(engine_cls, model: str, pipelined: bool, slots: int = SLOTS):
     return engine_cls(
         cfg, params,
         EngineConfig(decode_slots=slots, max_seq_len=256,
-                     prefill_buckets=(8,), pipeline_decode=pipelined),
+                     prefill_buckets=(8,)),
         lora_manager=lora, eos_id=None, dtype=jnp.float32)
 
 
@@ -308,15 +306,12 @@ def run_schedule(engine: Engine) -> list[dict]:
     return [record(r) for r in done]
 
 
-@pytest.fixture(scope="module", params=[
-    (model, pipelined) for model in MODELS for pipelined in (False, True)],
-    ids=lambda p: f"{p[0]}-{'pipelined' if p[1] else 'sync'}")
+@pytest.fixture(scope="module", params=list(MODELS))
 def schedules(request):
     """(engine's records, reference's records, the staged buffers the
-    engine's dispatches passed) of one model in one loop."""
-    model, pipelined = request.param
-    want = run_schedule(build(RestagingEngine, model, pipelined))
-    engine = build(Engine, model, pipelined)
+    engine's dispatches passed) of one model."""
+    want = run_schedule(build(RestagingEngine, request.param))
+    engine = build(Engine, request.param)
     program, aliased = engine._jit_decode, []
 
     def spy(*args, **kwargs):
@@ -374,15 +369,11 @@ class TestParity:
         _, _, _, engine = schedules
         ops = engine.profiler.hist_state()["stage_ops"]
         n = engine.profiler.dispatches["decode"]
-        # In both loops: the overlapped loop's budget-zero scatters went
-        # into the program (PR 40).
+        # The budget-zero scatters went into the program (PR 40).
         assert ops == STAGE_UPLOADS * n
 
 
-@pytest.mark.parametrize("pipelined", [False, True],
-                         ids=["sync", "pipelined"])
-def test_steady_dispatch_books_two_ops_and_draws_the_parents_stream(
-        pipelined):
+def test_steady_dispatch_books_two_ops_and_draws_the_parents_stream():
     """One request at a time, sampled WITHOUT a seed: its draws come from
     the engine's key, which the decode program now splits on the device
     and prefill's ``_next_key`` on the host.  The answers equal the
@@ -403,9 +394,9 @@ def test_steady_dispatch_books_two_ops_and_draws_the_parents_stream(
             engine.stop()
         return out
 
-    engine = build(Engine, "dense", pipelined)
+    engine = build(Engine, "dense")
     got = answers(engine)
-    assert got == answers(build(RestagingEngine, "dense", pipelined))
+    assert got == answers(build(RestagingEngine, "dense"))
     assert len({tuple(t) for t, _ in got}) == 4
 
     ops = engine.profiler.hist_state()["stage_ops"]
@@ -417,13 +408,11 @@ def test_steady_dispatch_books_two_ops_and_draws_the_parents_stream(
     assert engine.profiler.snapshot()["hist"]["stage_ops"] == ops
 
 
-@pytest.mark.parametrize("pipelined", [False, True],
-                         ids=["sync", "pipelined"])
-def test_adapter_rows_are_booked_from_the_staged_buffer(pipelined):
+def test_adapter_rows_are_booked_from_the_staged_buffer():
     """``tpu:lora_rows_total``: two adapter rows and one base row live
     book 2 a step, read off the int32 buffer that goes up anyway, so a
     dispatch still stages with its two uploads and nothing else."""
-    engine = build(Engine, "dense", pipelined, slots=3)
+    engine = build(Engine, "dense", slots=3)
     booked = []
     note = engine.profiler.note_lora_rows
 
@@ -453,7 +442,7 @@ def test_adapter_rows_are_booked_from_the_staged_buffer(pipelined):
 
 
 def test_base_rows_book_no_adapter_row():
-    engine = build(Engine, "dense", False)
+    engine = build(Engine, "dense")
     engine.start()
     try:
         req = engine.generate(Request([3, 5, 7], 8), timeout_s=180)
@@ -483,7 +472,7 @@ def test_grid_steps_are_booked_by_the_schedules_rule(max_seq_len, n_prompt,
         cfg, transformer.init_params(cfg, jax.random.PRNGKey(0),
                                      dtype=jnp.float32),
         EngineConfig(decode_slots=2, max_seq_len=max_seq_len,
-                     prefill_buckets=(8, 128), pipeline_decode=False),
+                     prefill_buckets=(8, 128)),
         eos_id=None, dtype=jnp.float32)
     assert engine._attn_tiles == pda.lane_tiles(engine.cache["k"]) == tiles
     engine.start()
@@ -505,7 +494,7 @@ def test_grid_steps_are_booked_by_the_schedules_rule(max_seq_len, n_prompt,
 
 @pytest.fixture(scope="module")
 def idle_engine():
-    return build(Engine, "dense", False)
+    return build(Engine, "dense")
 
 
 @pytest.mark.parametrize("name", sorted(PARENT_MIRRORS))
@@ -526,9 +515,9 @@ def test_mirror_is_a_view_of_its_buffer(idle_engine, name):
     assert (buf == before).all()
 
 
-def test_buffers_hold_the_fifteen_fields_and_nothing_else(idle_engine):
-    # ... but the overlapped loop's mark of a row activated since the last
-    # block (PR 40), which no mirror of the parent's stood for.
+def test_buffers_hold_the_mirrors_fields_and_nothing_else(idle_engine):
+    # ... but the mark of a row activated since the last block (PR 40),
+    # which no mirror of the parent's stood for.
     assert sorted(n for n, _, _ in _SLOT_I32 + _SLOT_F32) == sorted(
         [*PARENT_MIRRORS, "fresh"])
     for fields, buf in ((_SLOT_I32, idle_engine._slots_i32),

@@ -22,22 +22,24 @@ from llm_instance_gateway_tpu.server.engine import (
     SamplingParams,
 )
 from llm_instance_gateway_tpu.server.lora_manager import LoRAManager
+from tests._reference import reference_tokens
 
 CFG = TINY_TEST
 EOS = 255  # byte tokenizer range; arbitrary for random weights
 
 
-@pytest.fixture(scope="module", params=[False, True],
-                ids=["sync", "overlapped"])
+@pytest.fixture(scope="module", params=[None, 8], ids=["lanes", "paged"])
 def engine_env(request):
-    """One engine a loop: ``_loop`` by name (it was the default before
-    PR 40) and the overlapped order, which is what nothing said now runs."""
+    """One engine a cache layout: contiguous lanes and the paged pool.  The
+    contracts held here (generation, multiplexing, decode-wait, the
+    snapshot) are the engine's, not a layout's.  A sharded paged engine
+    is left out: ``TestShardedEngine`` builds its own, on lanes."""
     params = transformer.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
     lora = LoRAManager(CFG, dtype=jnp.float32)
     engine = Engine(
         CFG, params,
         EngineConfig(decode_slots=4, max_seq_len=64, prefill_buckets=(8, 16, 32),
-                     pipeline_decode=request.param),
+                     paged_kv_block=request.param),
         lora_manager=lora, eos_id=None, dtype=jnp.float32,
     )
     engine.start()
@@ -70,26 +72,11 @@ class TestGeneration:
         assert a.output_tokens == b.output_tokens
 
     def test_matches_reference_decode(self, engine_env):
-        """Engine greedy output == hand-rolled prefill+decode greedy chain."""
+        """Engine greedy output == the plain prefill+decode greedy chain."""
         engine, _, params = engine_env
         prompt = [3, 1, 4, 1, 5]
         got = engine.generate(make_req(prompt, max_new=6), timeout_s=60).output_tokens
-
-        tokens = jnp.asarray([prompt], jnp.int32)
-        positions = jnp.arange(len(prompt))[None]
-        logits, k, v = transformer.prefill(CFG, params, tokens, positions)
-        # argmax over the TRUE vocab: the engine masks MXU vocab padding.
-        want = [int(jnp.argmax(logits[0, len(prompt) - 1, :CFG.vocab_size]))]
-        cache = transformer.init_decode_cache(CFG, 1, 64, dtype=jnp.float32)
-        cache = transformer.insert_prefill(cache, k, v, 0, len(prompt))
-        pos = len(prompt)
-        for _ in range(5):
-            lg, cache = transformer.decode_step(
-                CFG, params, cache,
-                jnp.asarray([want[-1]], jnp.int32), jnp.asarray([pos], jnp.int32),
-            )
-            want.append(int(jnp.argmax(lg[0, :CFG.vocab_size])))
-            pos += 1
+        want = reference_tokens(CFG, params, prompt, 6)
         assert got == want
 
     def test_concurrent_requests_batch_consistency(self, engine_env):
@@ -113,16 +100,14 @@ class TestGeneration:
         with pytest.raises(ValueError, match="exceeds"):
             engine.submit(make_req(tuple(range(100))))
 
-    @pytest.mark.parametrize("pipeline", [False, True], ids=["sync", "pipelined"])
-    def test_multistep_decode_matches_single_step(self, engine_env, pipeline):
-        """decode_steps_per_sync / pipelining must not change outputs (greedy)."""
+    def test_multistep_decode_matches_single_step(self, engine_env):
+        """decode_steps_per_sync must not change outputs (greedy)."""
         engine, _, params = engine_env
         want = engine.generate(make_req((7, 8, 9), max_new=7), timeout_s=60).output_tokens
         multi = Engine(
             CFG, params,
             EngineConfig(decode_slots=4, max_seq_len=64,
-                         prefill_buckets=(8, 16, 32), decode_steps_per_sync=4,
-                         pipeline_decode=pipeline),
+                         prefill_buckets=(8, 16, 32), decode_steps_per_sync=4),
             lora_manager=None, eos_id=None, dtype=jnp.float32,
         )
         multi.start()
@@ -132,8 +117,7 @@ class TestGeneration:
             multi.stop()
         assert got == want
 
-    @pytest.mark.parametrize("pipeline", [False, True], ids=["sync", "pipelined"])
-    def test_device_side_eos_stops_mid_block(self, engine_env, pipeline):
+    def test_device_side_eos_stops_mid_block(self, engine_env):
         """With eos set and K > max_new, the device freezes the row at EOS:
         output ends exactly at the stop token, no trailing garbage."""
         engine, _, params = engine_env
@@ -143,7 +127,7 @@ class TestGeneration:
         eng = Engine(
             CFG, params,
             EngineConfig(decode_slots=2, max_seq_len=64, prefill_buckets=(8, 16),
-                         decode_steps_per_sync=6, pipeline_decode=pipeline),
+                         decode_steps_per_sync=6),
             lora_manager=None, eos_id=eos, dtype=jnp.float32,
         )
         eng.start()
@@ -155,8 +139,8 @@ class TestGeneration:
         assert req.output_tokens[-1] == eos
         assert req.output_tokens == probe.output_tokens[:2]
 
-    def test_pipelined_concurrent_consistency(self, engine_env):
-        """Pipelined engine under churn (slot reuse, mixed lengths) must match
+    def test_concurrent_consistency_under_churn(self, engine_env):
+        """Fused blocks under churn (slot reuse, mixed lengths) must match
         the sequential reference outputs exactly."""
         engine, _, params = engine_env
         prompts = [(5, 6, 7), (9, 9), (1, 2, 3, 4, 5, 6), (200, 100), (42,), (3, 3, 3)]
@@ -167,8 +151,7 @@ class TestGeneration:
         piped = Engine(
             CFG, params,
             EngineConfig(decode_slots=2, max_seq_len=64,
-                         prefill_buckets=(8, 16, 32), decode_steps_per_sync=3,
-                         pipeline_decode=True),
+                         prefill_buckets=(8, 16, 32), decode_steps_per_sync=3),
             lora_manager=None, eos_id=None, dtype=jnp.float32,
         )
         piped.start()
@@ -546,11 +529,10 @@ class TestGracefulDrain:
         finally:
             engine.stop()
 
-    def test_drain_on_paged_pipelined_engine(self):
-        """Drain under the production shape (paged + pipelined + grouped):
+    def test_drain_on_paged_engine(self):
+        """Drain under the production shape (paged + grouped):
         everything in flight — including decode_wait parkers — finishes."""
-        engine = self._engine(paged_kv_block=8, pipeline_decode=True,
-                              decode_steps_per_sync=4, prefill_batch=2,
+        engine = self._engine(paged_kv_block=8, decode_steps_per_sync=4, prefill_batch=2,
                               decode_wait_cap=2)
         engine.start()
         try:

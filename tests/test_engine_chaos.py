@@ -1,7 +1,7 @@
 """Engine chaos test: a randomized storm must terminate cleanly.
 
 Mixed prompt lengths (bucketed + chunked), adapters + base, random
-cancellations mid-flight, pipelined mode — every request must reach a
+cancellations mid-flight — every request must reach a
 terminal state (done set, a finish_reason, no engine-thread death), bounded
 outputs, and the engine must still serve a clean request afterwards.
 """
@@ -29,19 +29,18 @@ from llm_instance_gateway_tpu.server.lora_manager import LoRAManager
 CFG = TINY_TEST
 
 
-@pytest.mark.parametrize("pipeline,prefill_batch,spec_k,paged,quant,prefix", [
-    (False, 1, 0, False, False, False), (True, 1, 0, False, False, False),
-    (False, 3, 0, False, False, False), (True, 3, 0, False, False, False),
-    (False, 1, 2, False, False, False), (True, 1, 2, False, False, False),
-    (True, 3, 0, True, False, False),
-    # Round-5 production shape: paged + int8 KV + prefix cache + pipelined
+@pytest.mark.parametrize("prefill_batch,spec_k,paged,quant,prefix", [
+    (1, 0, False, False, False), (3, 0, False, False, False),
+    (1, 2, False, False, False), (3, 0, True, False, False),
+    # Round-5 production shape: paged + int8 KV + prefix cache
     # (grouped stays off with prefix, per the engine's own reuse gate).
-    (True, 1, 0, True, True, True),
-], ids=["sync", "pipelined", "sync-grouped", "pipelined-grouped",
-        "sync-spec", "pipelined-spec", "pipelined-grouped-paged",
-        "pipelined-paged-int8-prefix"])
-def test_request_storm_terminates(pipeline, prefill_batch, spec_k, paged,
-                                  quant, prefix):
+    (1, 0, True, True, True),
+    (1, 0, True, False, False), (1, 2, True, False, False),
+    (1, 0, False, True, False),
+], ids=["plain", "grouped", "spec", "grouped-paged", "paged-int8-prefix",
+        "paged", "spec-paged", "int8"])
+def test_request_storm_terminates(prefill_batch, spec_k, paged, quant,
+                                  prefix):
     import dataclasses
 
     rng = random.Random(0)
@@ -67,7 +66,7 @@ def test_request_storm_terminates(pipeline, prefill_batch, spec_k, paged,
     engine = Engine(
         CFG, params,
         EngineConfig(decode_slots=3, max_seq_len=96, prefill_buckets=(8, 16),
-                     decode_steps_per_sync=3, pipeline_decode=pipeline,
+                     decode_steps_per_sync=3,
                      prefill_batch=prefill_batch, speculative_k=spec_k,
                      paged_kv_block=8 if paged else None,
                      # Undersized pool: the storm must survive grouped

@@ -52,9 +52,8 @@ def test_family_serves_end_to_end(name):
     assert req.finish_reason == "length"
 
 
-@pytest.mark.parametrize("pipelined", [False, True], ids=["sync", "pipelined"])
 @pytest.mark.parametrize("name", ["olmoe-tiny", "mixtral-tiny", "qwen-tiny"])
-def test_routing_counters_come_back_with_the_readback(name, pipelined):
+def test_routing_counters_come_back_with_the_readback(name):
     """A sparse model's layer-steps are counted in its decode, bucket
     prefill and chunk programs and booked at the decode readback; a dense
     model's programs count nothing and its counters read 0."""
@@ -65,8 +64,7 @@ def test_routing_counters_come_back_with_the_readback(name, pipelined):
     params = transformer.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
     engine = Engine(
         cfg, params,
-        EngineConfig(decode_slots=2, max_seq_len=64, prefill_buckets=(8,),
-                     pipeline_decode=pipelined),
+        EngineConfig(decode_slots=2, max_seq_len=64, prefill_buckets=(8,)),
         eos_id=None, dtype=jnp.float32,
     )
     engine.start()

@@ -228,11 +228,12 @@ class TestTwoEngineParity:
         finally:
             coll.stop(), pre.stop(), dec.stop()
 
-    def test_pipelined_decode_engine_parity(self):
-        coll = make_engine(pipeline_decode=False)
+    def test_fused_decode_engine_parity(self):
+        """The decode hop fuses four steps a dispatch from the attached
+        first token; the collocated engine steps one at a time."""
+        coll = make_engine()
         pre = make_engine(role="prefill")
-        dec = make_engine(role="decode", pipeline_decode=True,
-                          decode_steps_per_sync=4)
+        dec = make_engine(role="decode", decode_steps_per_sync=4)
         try:
             want = coll.generate(make_req(), timeout_s=180).output_tokens
             h = pre.prefill_only(make_req(), timeout_s=180)

@@ -28,6 +28,7 @@ from llm_instance_gateway_tpu.ops import pallas_decode_attention as pda
 from llm_instance_gateway_tpu.ops.attention import latent_decode_attention
 from llm_instance_gateway_tpu.server import metrics
 from llm_instance_gateway_tpu.server.engine import Engine, EngineConfig, Request
+from tests._reference import reference_tokens
 
 CFG = TINY_GLM_TEST
 TOL = 1e-4
@@ -356,31 +357,13 @@ def test_planted_faults_miss_the_reference(params):
 
 # -- the engine ---------------------------------------------------------------
 
-_PADDED_REFERENCE = jax.jit(lambda p, t: reference.forward(CFG, p, t))
-
-
-def reference_tokens(params, prompt, n):
-    """The reference's greedy continuation.  One compiled shape: the
-    sequence is padded to 32, and causal attention keeps what follows a
-    position out of its logits."""
-    seq = list(prompt)
-    for _ in range(n):
-        padded = np.zeros((32,), np.int32)
-        padded[:len(seq)] = seq
-        logits = _PADDED_REFERENCE(params, jnp.asarray(padded))
-        seq.append(int(jnp.argmax(logits[len(seq) - 1, :CFG.vocab_size])))
-    return seq[len(prompt):]
-
-
-@pytest.mark.parametrize("pipelined", [False, True], ids=["sync", "pipelined"])
-def test_engine_gives_the_references_tokens_with_slot_reuse(params, pipelined):
+def test_engine_gives_the_references_tokens_with_slot_reuse(params):
     """Five requests over two slots, bucketed and chunk-streamed prompts
     mixed, no adapter buffers at all (``lora_manager`` None, as
     ``--max-loras 0`` serves): greedy tokens equal the reference's."""
     engine = Engine(
         CFG, params,
-        EngineConfig(decode_slots=2, max_seq_len=64, prefill_buckets=(8, 16),
-                     pipeline_decode=pipelined),
+        EngineConfig(decode_slots=2, max_seq_len=64, prefill_buckets=(8, 16)),
         eos_id=None, dtype=jnp.float32)
     prompts = [[3, 5, 7], list(range(3, 28)), [9, 8, 7, 6, 5, 4, 3, 2, 1, 11],
                list(range(40, 60)), [100, 200]]
@@ -393,7 +376,7 @@ def test_engine_gives_the_references_tokens_with_slot_reuse(params, pipelined):
     finally:
         engine.stop()
     for prompt, req in zip(prompts, reqs):
-        assert req.output_tokens == reference_tokens(params, prompt, 5)
+        assert req.output_tokens == reference_tokens(CFG, params, prompt, 5)
     hist = engine.profiler.hist_state()
     # every decode step read its live rows' whole lanes
     assert hist["latent_positions"] > sum(map(len, prompts))
@@ -404,9 +387,9 @@ def test_engine_gives_the_references_tokens_with_slot_reuse(params, pipelined):
 
 
 def test_counter_sums_the_live_rows_cache_lengths(params):
-    """One request of 3 prompt tokens and 6 new ones on the sync loop: the
-    decode steps read 4, 5, ... positions (the first new token comes from
-    the prefill)."""
+    """One request of 3 prompt tokens and 6 new ones: the decode steps
+    read 4, 5, ... positions (the first new token comes from the
+    prefill)."""
     engine = Engine(CFG, params,
                     EngineConfig(decode_slots=2, max_seq_len=64,
                                  prefill_buckets=(8,)),

@@ -1,7 +1,7 @@
-"""The overlapped decode loop (PR 40), which an engine runs when nothing is
-said: block N+1 is dispatched from the device carry before block N is read.
+"""The engine's one loop, the overlapped order of PR 40: block N+1 is
+dispatched from the device carry before block N is read.
 
-Four things are held here, each of which the tree's earlier pipelined loop
+Five things are held here; the first four the tree's earlier pipelined loop
 got wrong or left unsaid:
 
 (a) nothing is traced after the first block of each shape, however many
@@ -10,12 +10,16 @@ got wrong or left unsaid:
     freed);
 (b) a first token leaves when its prefill is done, not with its slot's first
     decode block;
-(c) the two orders give the same tokens and logprobs on one seeded mix, on
-    lanes, on a latent cache and on the paged pool;
+(c) one seeded mix gives every request the tokens and logprobs it gets
+    alone, on every kind of cache the engine serves;
 (d) the step a block books is one step, not two, and the twelve phases tile
-    the engine thread's wall.
+    the engine thread's wall;
+(e) the loop survives what a device call raises: it fails the rows that
+    were hit and goes on serving.
 """
 
+import dataclasses
+import inspect
 import threading
 import time
 
@@ -26,11 +30,17 @@ import pytest
 from jax._src import monitoring
 
 from llm_instance_gateway_tpu.models import transformer
-from llm_instance_gateway_tpu.models.configs import TINY_TEST
+from llm_instance_gateway_tpu.models.configs import (
+    TINY_FALCON_H1_TEST,
+    TINY_MOE_TEST,
+    TINY_SMALLTHINKER_TEST,
+    TINY_TEST,
+)
 from llm_instance_gateway_tpu.models.lora import target_dims
 from llm_instance_gateway_tpu.models.mixtral import CONFIGS
 from llm_instance_gateway_tpu.server import metrics
 from llm_instance_gateway_tpu.server.engine import (
+    _SLOT_I32,
     Engine,
     EngineConfig,
     Request,
@@ -66,20 +76,54 @@ def greedy(prompt, n, **kw) -> Request:
                    sampling=SamplingParams(temperature=0.0), **kw)
 
 
-def test_the_overlapped_order_is_what_an_engine_runs_when_nothing_is_said():
-    assert EngineConfig().pipeline_decode is True
+def test_no_field_of_the_configuration_selects_an_order():
+    assert "pipeline_decode" not in {
+        f.name for f in dataclasses.fields(EngineConfig)}
+    with pytest.raises(TypeError):
+        EngineConfig(pipeline_decode=True)
+
+
+def test_the_servers_parser_refuses_the_flag_that_selected_one(capsys):
+    """A deployment that still passes it gets argparse's error, not a
+    server that ignores what it was told."""
+    from llm_instance_gateway_tpu.server import api_http
+
+    for flag in ("--no-pipeline-decode", "--pipeline-decode"):
+        with pytest.raises(SystemExit) as exit_:
+            api_http.main(["--model", "llama3-tiny", "--platform", "cpu",
+                           flag])
+        assert exit_.value.code == 2
+        assert f"unrecognized arguments: {flag}" in capsys.readouterr().err
+
+
+def test_an_engine_has_one_loop_and_one_way_to_dispatch():
+    for gone in ("_loop_pipelined", "_do_decode_step", "_do_spec_step",
+                 "_sync_stop_hist", "_activate_slot_pipelined",
+                 "_do_prefill_pipelined"):
+        assert not hasattr(Engine, gone), gone
     engine = tiny_engine()
     engine.start()
     try:
-        assert engine._thread._target == engine._loop_pipelined
+        assert engine._thread._target == engine._loop
     finally:
         engine.stop()
-    sync = tiny_engine(pipeline_decode=False)
-    sync.start()
-    try:
-        assert sync._thread._target == sync._loop
-    finally:
-        sync.stop()
+    for method in ("_admit_and_insert", "_drain_decode_wait", "_do_attach",
+                   "_do_prefill_ahead", "_insert_waiting", "_park_waiting",
+                   "_do_prefill_ahead_group", "_grouped_batch",
+                   "_do_prefill_group", "_stream_step",
+                   "_paged_ensure_decode"):
+        assert "pipelined" not in inspect.signature(
+            getattr(Engine, method)).parameters, method
+
+
+def test_a_rows_last_token_is_the_device_carrys_alone():
+    """No host mirror of it is staged: the decode program takes the carry
+    it is given, and the int32 buffer is a value a slot shorter."""
+    assert "tokens" not in {name for name, _, _ in _SLOT_I32}
+    engine = tiny_engine()
+    assert not hasattr(engine, "_slot_tokens")
+    assert inspect.signature(Engine._enqueue_decode).parameters[
+        "carry"].default is inspect.Parameter.empty
 
 
 # -- (a) nothing traced after the first block of each shape -----------------
@@ -95,8 +139,8 @@ class TestNothingIsTracedAfterTheFirstBlockOfEachShape:
         hold = threading.Event()
         admit = engine._admit_and_insert
 
-        def gated(pipelined):
-            return False if hold.is_set() else admit(pipelined=pipelined)
+        def gated():
+            return False if hold.is_set() else admit()
 
         engine._admit_and_insert = gated
         freed_in_a_block: list[int] = []
@@ -208,7 +252,7 @@ def test_first_token_is_out_before_the_slots_first_block_is_processed():
     assert span["stage_s"] > 0 and span["emit_s"] > 0
 
 
-# -- (c) the two orders give the same answers --------------------------------
+# -- (c) a mix gives each request the answer it gets alone ---------------------
 
 GLM = CONFIGS["glm-tiny"]
 
@@ -226,10 +270,15 @@ def _adapters(cfg) -> LoRAManager:
 
 
 KINDS = {
-    # name -> (model config, adapters served, extra EngineConfig fields)
+    # name -> (model config, adapters served, extra EngineConfig fields);
+    # a kind that refuses adapters (``_refuse_what_lanes_alone_serve``)
+    # runs the mix on the base model.
     "lanes": (TINY_TEST, True, {}),
     "latent": (GLM, False, {}),
     "paged": (TINY_TEST, True, {"paged_kv_block": 8}),
+    "recurrent": (TINY_FALCON_H1_TEST, False, {}),
+    "window": (TINY_SMALLTHINKER_TEST, False, {}),
+    "sparse": (TINY_MOE_TEST, True, {}),
 }
 
 
@@ -258,12 +307,14 @@ def _mix(adapters: bool, probe: list[int]) -> list[dict]:
     ]
 
 
-def _run_mix(kind: str, pipelined: bool) -> list[dict]:
+def _run_mix(kind: str, together: bool) -> list[dict]:
+    """The mix through one engine: ``together``, every request submitted
+    as the script goes; else each request alone, the next one submitted
+    when the one before it is done.  Same programs, same batch width."""
     cfg, adapters, extra = KINDS[kind]
     params = tiny_params(cfg)
     engine = tiny_engine(params, cfg, lora=_adapters(cfg) if adapters
-                         else None, decode_slots=3,
-                         pipeline_decode=pipelined, **extra)
+                         else None, decode_slots=3, **extra)
     engine.start()
     try:
         probe = engine.generate(greedy([5, 6, 7], 12), timeout_s=180)
@@ -286,12 +337,13 @@ def _run_mix(kind: str, pipelined: bool) -> list[dict]:
                          >= s["cancel_after"] or r.done.is_set(),
                          "tokens before the cancellation")
                 req.cancelled.set()
+            if not together:
+                assert req.done.wait(300), "request never finished"
         for req, _ in reqs:
             assert req.done.wait(300), "request never finished"
     finally:
         engine.stop()
-    overlapped = engine.profiler.hist_state()["blocks_overlapped"]
-    assert (overlapped > 0) == pipelined
+    assert engine.profiler.hist_state()["blocks_overlapped"] > 0
     return [{"tokens": list(r.output_tokens),
              "logprobs": list(r.output_logprobs),
              "top": list(r.output_top_logprobs),
@@ -300,26 +352,29 @@ def _run_mix(kind: str, pipelined: bool) -> list[dict]:
 
 
 @pytest.fixture(scope="module", params=sorted(KINDS))
-def both_orders(request):
-    return (request.param, _run_mix(request.param, pipelined=False),
-            _run_mix(request.param, pipelined=True))
+def alone_and_together(request):
+    return (request.param, _run_mix(request.param, together=False),
+            _run_mix(request.param, together=True))
 
 
-class TestTheTwoOrdersGiveTheSameAnswers:
-    def test_the_mix_ran_what_it_scripts(self, both_orders):
-        _, sync, _ = both_orders
-        assert all(r["error"] is None for r in sync)
-        finishes = [r["finish"] for r in sync]
+class TestAMixGivesEachRequestTheAnswerItGetsAlone:
+    def test_the_mix_ran_what_it_scripts(self, alone_and_together):
+        _, alone, _ = alone_and_together
+        assert all(r["error"] is None for r in alone)
+        finishes = [r["finish"] for r in alone]
         assert finishes.count("cancelled") == 1
         assert finishes.count("stop") == 2
-        assert len(sync[2]["tokens"]) == 1 and len(sync[3]["tokens"]) == 2
-        assert len(sync[4]["tokens"]) == 5   # the custom stop id
-        assert len(sync[5]["tokens"]) == 7   # the stop sequence
-        assert len(sync[6]["tokens"]) == 8   # the chunk-streamed prompt
+        assert len(alone[2]["tokens"]) == 1 and len(alone[3]["tokens"]) == 2
+        # the custom stop id and the stop sequence, the fifth token and the
+        # sixth and seventh of the greedy answer (sooner where a kind's
+        # answer repeats itself)
+        assert alone[4]["finish"] == alone[5]["finish"] == "stop"
+        assert len(alone[4]["tokens"]) <= 5 and len(alone[5]["tokens"]) <= 7
+        assert len(alone[6]["tokens"]) == 8   # the chunk-streamed prompt
 
-    def test_token_for_token(self, both_orders):
-        kind, sync, over = both_orders
-        for i, (s, o) in enumerate(zip(sync, over, strict=True)):
+    def test_token_for_token(self, alone_and_together):
+        kind, alone, over = alone_and_together
+        for i, (s, o) in enumerate(zip(alone, over, strict=True)):
             assert o["error"] is None, (kind, i, o["error"])
             assert o["finish"] == s["finish"], (kind, i)
             if s["finish"] == "cancelled":
@@ -329,9 +384,9 @@ class TestTheTwoOrdersGiveTheSameAnswers:
                 assert o["tokens"] == s["tokens"], (kind, i)
             assert o["t_first"] > 0
 
-    def test_logprob_for_logprob(self, both_orders):
-        kind, sync, over = both_orders
-        for i, (s, o) in enumerate(zip(sync, over, strict=True)):
+    def test_logprob_for_logprob(self, alone_and_together):
+        kind, alone, over = alone_and_together
+        for i, (s, o) in enumerate(zip(alone, over, strict=True)):
             n = min(len(s["logprobs"]), len(o["logprobs"]))
             assert n == len(o["tokens"]) or o["finish"] == "cancelled"
             assert o["logprobs"][:n] == s["logprobs"][:n], (kind, i)
@@ -442,36 +497,113 @@ class TestTheStepClock:
         assert "Decode overlap" in profile_report.render_report(snap)
 
 
-def test_the_sync_loop_overlaps_nothing():
-    engine = tiny_engine(pipeline_decode=False)
-    engine.start()
-    try:
-        req = engine.generate(greedy([5, 6, 7], 10), timeout_s=120)
-        assert req.error is None
-    finally:
-        engine.stop()
-    assert engine.profiler.hist_state()["blocks_overlapped"] == 0
-    assert "tpu:decode_blocks_overlapped_total 0\n" in metrics.render(
-        engine.metrics_snapshot()) + "\n"
-
-
 def test_the_planner_counts_the_unread_first_token():
     """A two-token answer that does not stream, under the adaptive planner:
-    the one token left after the prefill's is one step, in both loops.  The
-    overlapped loop plans that block before it has read the first token;
-    taking the host record for the row's progress it fused two steps, and a
-    benchmark run's probes compiled a decode variant of their own inside
-    `setup_s` (my chip runs, PR 40)."""
-    for pipelined in (False, True):
-        engine = tiny_engine(adaptive_steps=8, pipeline_decode=pipelined)
+    the one token left after the prefill's is one step.  The loop plans
+    that block before it has read the first token; taking the host record
+    for the row's progress it fused two steps, and a benchmark run's probes
+    compiled a decode variant of their own inside `setup_s` (my chip runs,
+    PR 40)."""
+    engine = tiny_engine(adaptive_steps=8)
+    engine.start()
+    try:
+        for _ in range(3):
+            req = engine.generate(greedy([5, 6, 7], 2), timeout_s=120)
+            assert req.error is None and len(req.output_tokens) == 2
+    finally:
+        engine.stop()
+    steps = engine.dispatch_steps_hist.state()
+    assert steps["count"] >= 3 and steps["sum"] == steps["count"], steps
+    assert engine._jit_decode._cache_size() == 1
+
+
+# -- (e) the loop survives ----------------------------------------------------
+
+class Injected(RuntimeError):
+    pass
+
+
+def _raise_once(armed: threading.Event, fn):
+    """``fn``, but for the one call after ``armed`` is set."""
+    def stub(*args, **kwargs):
+        if armed.is_set():
+            armed.clear()
+            raise Injected("injected fault")
+        return fn(*args, **kwargs)
+    return stub
+
+
+# what raises -> (the engine's attribute that is stubbed, whom it hits:
+# "all" the rows in flight, "one" the request being admitted)
+FAULTS = {
+    "decode-dispatch": ("_jit_decode", "all"),
+    "block-materialisation": (None, "all"),  # jax.block_until_ready
+    "first-token-read": ("_emit_first_token", "one"),
+    "direct-prefill": ("_jit_insert", "one"),
+    "stream-chunk": ("_jit_chunk", "one"),
+}
+
+
+class TestTheLoopSurvives:
+    """"The engine must survive; fail the batch": whatever a device call
+    raises, the rows it hit finish with ``error`` set and ``finish_reason``
+    "error", the rows it did not hit get their tokens, a request submitted
+    afterwards gets the tokens an untouched engine gives, and the paged
+    pool has all its blocks back."""
+
+    @pytest.mark.parametrize("layout", [{}, {"paged_kv_block": 8}],
+                             ids=["lanes", "paged"])
+    @pytest.mark.parametrize("fault", sorted(FAULTS))
+    def test_it_fails_the_rows_that_were_hit_and_goes_on(
+            self, fault, layout, monkeypatch):
+        attr, hits = FAULTS[fault]
+        engine = tiny_engine(decode_slots=3, **layout)
+        armed = threading.Event()
+        if attr is None:
+            real = jax.block_until_ready
+            faulty = _raise_once(armed, real)
+            monkeypatch.setattr(
+                jax, "block_until_ready", lambda tree: (
+                    faulty if threading.current_thread() is engine._thread
+                    else real)(tree))
+        else:
+            setattr(engine, attr, _raise_once(armed, getattr(engine, attr)))
+        # over the largest bucket (16) where the fault is a chunk's
+        victim_prompt = (list(range(3, 43)) if fault == "stream-chunk"
+                         else [9, 8, 7, 6])
         engine.start()
         try:
-            for _ in range(3):
-                req = engine.generate(greedy([5, 6, 7], 2), timeout_s=120)
-                assert req.error is None and len(req.output_tokens) == 2
+            free = len(engine._free_blocks) if engine.paged else None
+            want = engine.generate(greedy([5, 6, 7], 8), timeout_s=120)
+            bystander_alone = engine.generate(greedy([2, 4, 6], 80),
+                                              timeout_s=120)
+            assert want.error is None and bystander_alone.error is None
+            bystander = engine.submit(greedy([2, 4, 6], 80))
+            wait_for(lambda: len(bystander.output_tokens) >= 3,
+                     "a block in flight")
+            if hits == "one":  # the next admission is the victim's
+                armed.set()
+            victim = engine.submit(greedy(victim_prompt, 40))
+            if hits == "all":  # both rows are in the next block
+                wait_for(lambda: len(victim.output_tokens) >= 2,
+                         "the victim's row in flight")
+                armed.set()
+            assert victim.done.wait(120) and bystander.done.wait(120)
+            assert not armed.is_set(), "the fault was never reached"
+            assert victim.finish_reason == "error"
+            assert "injected fault" in victim.error
+            if hits == "all":
+                assert bystander.finish_reason == "error"
+                assert "injected fault" in bystander.error
+            else:
+                assert bystander.error is None
+                assert (bystander.output_tokens
+                        == bystander_alone.output_tokens)
+            after = engine.generate(greedy([5, 6, 7], 8), timeout_s=120)
+            assert after.error is None
+            assert after.output_tokens == want.output_tokens
+            if engine.paged:  # done is set before the slot is cleared
+                wait_for(lambda: len(engine._free_blocks) == free,
+                         "blocks never came back", timeout_s=10)
         finally:
             engine.stop()
-        steps = engine.dispatch_steps_hist.state()
-        assert steps["count"] >= 3 and steps["sum"] == steps["count"], (
-            pipelined, steps)
-        assert engine._jit_decode._cache_size() == 1

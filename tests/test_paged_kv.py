@@ -2,7 +2,7 @@
 
 The contract: paged mode produces EXACTLY the tokens the contiguous-lane
 cache produces (greedy), under plain decode, chunked prefill, decode_wait
-pressure, and the pipelined loop — while reporting vLLM-semantics block
+pressure, and fused blocks — while reporting vLLM-semantics block
 usage and applying backpressure (not corruption) when an oversubscribed
 pool runs dry.
 """
@@ -31,14 +31,13 @@ def params():
     return transformer.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
 
 
-def make_engine(params, paged: bool, pipeline: bool = False,
+def make_engine(params, paged: bool, steps: int = 1,
                 n_blocks: int | None = None, slots: int = 4):
     return Engine(
         CFG, params,
         EngineConfig(
             decode_slots=slots, max_seq_len=64, prefill_buckets=(8, 16),
-            pipeline_decode=pipeline,
-            decode_steps_per_sync=4 if pipeline else 1,
+            decode_steps_per_sync=steps,
             paged_kv_block=8 if paged else None,
             paged_kv_blocks=n_blocks,
         ),
@@ -77,15 +76,18 @@ class TestPagedParity:
         finally:
             lanes.stop(); paged.stop()
 
-    def test_paged_pipelined_matches_sync(self, params):
-        sync = make_engine(params, paged=True)
-        pipe = make_engine(params, paged=True, pipeline=True)
-        sync.start(); pipe.start()
+    def test_paged_fused_blocks_match_single_steps(self, params):
+        """Four steps a dispatch reserve their blocks ahead of the block in
+        flight (``_paged_ensure_decode``) and give one step's tokens."""
+        single = make_engine(params, paged=True)
+        fused = make_engine(params, paged=True, steps=4)
+        single.start(); fused.start()
         try:
             prompt = (9, 2, 4)
-            assert gen(pipe, prompt, max_new=10) == gen(sync, prompt, max_new=10)
+            assert gen(fused, prompt, max_new=10) == gen(single, prompt,
+                                                         max_new=10)
         finally:
-            sync.stop(); pipe.stop()
+            single.stop(); fused.stop()
 
     def test_paged_concurrent_batch_consistency(self, params):
         engine = make_engine(params, paged=True)

@@ -89,15 +89,17 @@ class TestPenalties:
             e.stop()
         assert a == b
 
-    def test_pipelined_matches_sync(self, params):
-        sync = _engine(params, pipeline_decode=False)
-        pipe = _engine(params, pipeline_decode=True, decode_steps_per_sync=4)
-        sync.start(), pipe.start()
+    def test_fused_blocks_match_single_steps(self, params):
+        """The counts ride the fused block's carry: four steps a dispatch
+        penalise what one step a dispatch does."""
+        single = _engine(params)
+        fused = _engine(params, decode_steps_per_sync=4)
+        single.start(), fused.start()
         try:
-            assert (_gen(pipe, presence=1.2, frequency=0.6) ==
-                    _gen(sync, presence=1.2, frequency=0.6))
+            assert (_gen(fused, presence=1.2, frequency=0.6) ==
+                    _gen(single, presence=1.2, frequency=0.6))
         finally:
-            sync.stop(), pipe.stop()
+            single.stop(), fused.stop()
 
     def test_counts_reset_on_slot_reuse(self, params):
         """A later request must not inherit the previous occupant's

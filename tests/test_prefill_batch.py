@@ -32,13 +32,13 @@ PROMPTS = [
 ]
 
 
-def _serve(prefill_batch: int, pipeline: bool, lora=None,
+def _serve(prefill_batch: int, lora=None,
            adapters=(None,) * len(PROMPTS)):
     engine = Engine(
         CFG, PARAMS,
         EngineConfig(decode_slots=8, max_seq_len=128,
                      prefill_buckets=(16, 32, 64),
-                     decode_steps_per_sync=4, pipeline_decode=pipeline,
+                     decode_steps_per_sync=4,
                      prefill_batch=prefill_batch),
         lora_manager=lora, eos_id=None, dtype=jnp.float32,
     )
@@ -59,10 +59,9 @@ def _serve(prefill_batch: int, pipeline: bool, lora=None,
         engine.stop()
 
 
-@pytest.mark.parametrize("pipeline", [False, True], ids=["sync", "pipelined"])
-def test_grouped_outputs_match_single(pipeline):
-    single = _serve(1, pipeline)
-    grouped = _serve(4, pipeline)
+def test_grouped_outputs_match_single():
+    single = _serve(1)
+    grouped = _serve(4)
     assert grouped == single
 
 
@@ -79,11 +78,11 @@ def test_grouped_with_adapters_matches_single():
         return lora
 
     adapters = ("ad-x", None, "ad-x", None, "ad-x", None)
-    single = _serve(1, False, lora=make_lora(), adapters=adapters)
-    grouped = _serve(4, False, lora=make_lora(), adapters=adapters)
+    single = _serve(1, lora=make_lora(), adapters=adapters)
+    grouped = _serve(4, lora=make_lora(), adapters=adapters)
     assert grouped == single
     # The adapter genuinely changes output (the parity isn't vacuous).
-    base = _serve(4, False, lora=make_lora(), adapters=(None,) * 6)
+    base = _serve(4, lora=make_lora(), adapters=(None,) * 6)
     assert base != grouped
 
 
@@ -120,8 +119,7 @@ def test_unknown_adapter_rejected_at_submit_not_in_group():
         engine.stop()
 
 
-@pytest.mark.parametrize("pipeline", [False, True], ids=["sync", "pipelined"])
-def test_saturated_slots_group_through_decode_wait(pipeline):
+def test_saturated_slots_group_through_decode_wait():
     """More requests than slots: the overflow admits through GROUPED
     prefill-ahead and still matches the one-at-a-time engine exactly."""
     def run(prefill_batch):
@@ -129,7 +127,7 @@ def test_saturated_slots_group_through_decode_wait(pipeline):
             CFG, PARAMS,
             EngineConfig(decode_slots=2, max_seq_len=128,
                          prefill_buckets=(16, 32),
-                         decode_steps_per_sync=4, pipeline_decode=pipeline,
+                         decode_steps_per_sync=4,
                          prefill_batch=prefill_batch, decode_wait_cap=8),
             eos_id=None, dtype=jnp.float32,
         )
@@ -207,13 +205,13 @@ class TestPagedGroupedAdmission:
     bursts prefill as one program, rows allocate their blocks at insert,
     and pool exhaustion parks rows (FIFO) instead of erroring them."""
 
-    def _serve_paged(self, prefill_batch, pipeline, n_blocks=None, slots=8,
+    def _serve_paged(self, prefill_batch, n_blocks=None, slots=8,
                      max_new=6):
         engine = Engine(
             CFG, PARAMS,
             EngineConfig(decode_slots=slots, max_seq_len=128,
                          prefill_buckets=(16, 32, 64),
-                         decode_steps_per_sync=4, pipeline_decode=pipeline,
+                         decode_steps_per_sync=4,
                          prefill_batch=prefill_batch,
                          paged_kv_block=16, paged_kv_blocks=n_blocks),
             lora_manager=None, eos_id=None, dtype=jnp.float32,
@@ -234,19 +232,15 @@ class TestPagedGroupedAdmission:
         finally:
             engine.stop()
 
-    @pytest.mark.parametrize("pipeline", [False, True],
-                             ids=["sync", "pipelined"])
-    def test_paged_grouped_matches_single(self, pipeline):
-        want = self._serve_paged(1, pipeline)
-        got = self._serve_paged(4, pipeline)
+    def test_paged_grouped_matches_single(self):
+        want = self._serve_paged(1)
+        got = self._serve_paged(4)
         assert got == want
 
-    @pytest.mark.parametrize("pipeline", [False, True],
-                             ids=["sync", "pipelined"])
-    def test_tight_pool_parks_not_errors(self, pipeline):
+    def test_tight_pool_parks_not_errors(self):
         """A pool too small for the whole burst at once: grouped admission
         must backpressure rows through decode_wait and still produce the
         unconstrained outputs."""
-        want = self._serve_paged(1, pipeline)
-        got = self._serve_paged(4, pipeline, n_blocks=10, slots=4)
+        want = self._serve_paged(1)
+        got = self._serve_paged(4, n_blocks=10, slots=4)
         assert got == want

@@ -326,11 +326,8 @@ def profiled_engine():
                                      dtype=jnp.float32)
     engine = Engine(
         TINY_TEST, params,
-        # ``_loop`` by name: a record whose stage + wait + readback IS its
-        # wall is that loop's; the overlapped loop's clock has its own
-        # tests (tests/test_overlapped_loop.py).
         EngineConfig(decode_slots=2, max_seq_len=64,
-                     prefill_buckets=(8, 16, 32), pipeline_decode=False),
+                     prefill_buckets=(8, 16, 32)),
         eos_id=None, dtype=jnp.float32)
     engine.start()
     yield engine, params
@@ -364,7 +361,9 @@ class TestEngineIntegration:
             1.0, abs=1e-6)
         assert snap["records"], "per-dispatch records recorded"
         occ = [r for r in snap["records"] if r["phase"] == "decode"]
-        assert all(0 < r["active"] <= r["slots"] for r in occ)
+        # (a block whose rows all finished before it was read has no row)
+        assert all(0 <= r["active"] <= r["slots"] for r in occ)
+        assert sum(r["active"] > 0 for r in occ) >= 3 * 5
 
     def test_metrics_snapshot_and_exposition(self, profiled_engine):
         engine, _ = profiled_engine
@@ -386,8 +385,9 @@ class TestEngineIntegration:
 
     def test_phases_of_a_run_and_the_dispatch_split(self, profiled_engine):
         """A tiny run leaves the waits and the staging non-zero, and a
-        decode record's stage + wait + readback is its wall (two clock
-        reads apart at each end)."""
+        decode record's wait lies inside its wall: the step a block books
+        runs from the completion before it (or its own staging, on an idle
+        device) to its own, and the thread waits for it after both."""
         engine, _ = profiled_engine
         run_requests(engine)
         snap = engine.profiler.snapshot()
@@ -401,9 +401,8 @@ class TestEngineIntegration:
         decode = [r for r in snap["records"] if r["phase"] == "decode"]
         assert decode
         for r in decode:
-            parts = r["stage_s"] + r["wait_s"] + r["readback_s"]
-            assert parts == pytest.approx(r["wall_s"], abs=2e-4), r
-            assert r["emit_s"] > 0
+            assert 0 < r["wait_s"] <= r["wall_s"] + 2e-4, r
+            assert r["readback_s"] > 0 and r["emit_s"] > 0
 
     def test_phases_tile_the_engine_threads_wall(self, profiled_engine):
         """Between two scrapes the phase counters grow by the wall time
@@ -525,15 +524,14 @@ def spec_engine():
     return build
 
 
-class TestSameNamesOnEveryLoop:
-    @pytest.mark.parametrize("extra", [
-        {"pipeline_decode": False}, {"pipeline_decode": True},
-        {"pipeline_decode": False, "speculative_k": 2},
-        {"pipeline_decode": True, "speculative_k": 2}],
-        ids=["sync", "pipelined", "spec", "pipelined-spec"])
+class TestSameNamesOnEveryDispatch:
+    @pytest.mark.parametrize("layout", [{}, {"paged_kv_block": 8}],
+                             ids=["lanes", "paged"])
+    @pytest.mark.parametrize("extra", [{}, {"speculative_k": 2}],
+                             ids=["plain", "spec"])
     def test_loop_charges_the_decode_and_prefill_phases(self, spec_engine,
-                                                        extra):
-        engine = spec_engine(**extra)
+                                                        extra, layout):
+        engine = spec_engine(**extra, **layout)
         engine.start()
         try:
             run_requests(engine, n=2, max_new=8)
@@ -1014,7 +1012,8 @@ class TestReportOfALiveProfile:
     def test_host_sync_delta_against_previous_shares(self, profiled_engine):
         profile = self.profile(profiled_engine)
         cur = profile["attribution"]["shares"]["host_sync"]
-        assert 0.0 < cur < 1.0
+        # (0 where every block of the run was staged behind another)
+        assert 0.0 <= cur < 1.0
         previous = {"shares": {"host_sync": cur + (1.0 - cur) / 2}}
         delta = profile_report.host_sync_delta(profile, previous)
         assert delta is not None and delta["improved"], delta

@@ -195,8 +195,7 @@ def test_a_rows_token_does_not_depend_on_its_batchs_path(seeded):
 # The engine books the path the DEVICE took
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("pipelined", [False, True], ids=["sync", "pipelined"])
-def test_engine_books_the_devices_path_step_by_step(pipelined):
+def test_engine_books_the_devices_path_step_by_step():
     """Greedy traffic books ``argmax`` alone; a temperature request books
     ``draw`` while it lives, and the count goes back to ``argmax`` on the
     step after it finishes although its freed slot still holds 0.8 in the
@@ -216,8 +215,7 @@ def test_engine_books_the_devices_path_step_by_step(pipelined):
                                      dtype=jnp.float32)
     engine = Engine(
         cfg, params,
-        EngineConfig(decode_slots=2, max_seq_len=512, prefill_buckets=(8,),
-                     pipeline_decode=pipelined),
+        EngineConfig(decode_slots=2, max_seq_len=512, prefill_buckets=(8,)),
         eos_id=None, dtype=jnp.float32,
     )
     state = engine.profiler.sample_state
@@ -247,8 +245,8 @@ def test_engine_books_the_devices_path_step_by_step(pipelined):
             sampling=SamplingParams(temperature=0.8)), timeout_s=120)
         after_draw = state()
         assert warm_t.error is None and not long.done.is_set()
-        # 3 decode steps follow the prefill's first token (a pipelined
-        # block in flight may add the one after the row froze).
+        # 3 decode steps follow the prefill's first token (the block in
+        # flight may add the one after the row froze).
         assert 1 <= after_draw["draw"] <= 4
         assert after_draw["filtered"] == 0
         # The freed slot keeps its last request's temperature...

@@ -45,14 +45,13 @@ class TestPoolSchedulerConfig:
 
 
 class TestCancellation:
-    @pytest.mark.parametrize("pipeline", [False, True], ids=["sync", "pipelined"])
-    def test_cancel_frees_slot(self, pipeline):
+    def test_cancel_frees_slot(self):
         cfg = TINY_TEST
         params = transformer.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
         engine = Engine(
             cfg, params,
             EngineConfig(decode_slots=1, max_seq_len=1024, prefill_buckets=(8,),
-                         decode_steps_per_sync=2, pipeline_decode=pipeline),
+                         decode_steps_per_sync=2),
             eos_id=None, dtype=jnp.float32,
         )
         engine.start()

@@ -19,6 +19,7 @@ from llm_instance_gateway_tpu.server.engine import (
     SamplingParams,
 )
 from llm_instance_gateway_tpu.server.sampling import sample
+from tests._reference import reference_tokens
 
 CFG = TINY_TEST
 
@@ -118,14 +119,19 @@ class TestEngineLevel:
         finally:
             e2.stop()
 
-    def test_reproducible_on_pipelined_multistep(self, params):
-        sync = _engine(params, pipeline_decode=False)
-        pipe = _engine(params, pipeline_decode=True, decode_steps_per_sync=4)
-        sync.start(), pipe.start()
+    def test_fused_blocks_draw_what_the_plain_reference_draws(self, params):
+        """Four steps a dispatch against no engine at all: ``prefill`` and
+        ``decode_step`` on one row, each token drawn by ``sample`` with the
+        request's seed at its position."""
+        fused = _engine(params, decode_steps_per_sync=4)
+        fused.start()
         try:
-            assert _gen(pipe, seed=11) == _gen(sync, seed=11)
+            got = _gen(fused, seed=11)
         finally:
-            sync.stop(), pipe.stop()
+            fused.stop()
+        assert got == reference_tokens(
+            CFG, params, [5, 6, 7], 12,
+            sampling=SamplingParams(temperature=0.9, seed=11))
 
 
 class TestSeedFanout:

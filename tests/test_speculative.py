@@ -62,8 +62,9 @@ def _tiny_draft():
 def make_engines(spec_k, draft_like_target=False, slots=3, eos_id=None,
                  **extra):
     """Build a (plain, speculative) engine pair over SHARED target params.
-    ``extra`` EngineConfig fields apply to BOTH, so loop-composition tests
-    (pipelined, multi-step sync) compare like against like."""
+    ``extra`` EngineConfig fields apply to BOTH, so composition tests
+    (fused steps, the adaptive planner, grouped prefill) compare like
+    against like."""
     from llm_instance_gateway_tpu.server.engine import Engine, EngineConfig
 
     params = transformer.init_params(CFG, jax.random.PRNGKey(0),
@@ -207,23 +208,24 @@ class TestSpeculativeMesh:
 
 class TestSpeculativeLoopComposition:
     """Speculation under the production loop shapes (VERDICT r2 #5): the
-    pipelined loop and multi-step sync dispatch must keep exact greedy parity with their non-speculative twins."""
+    adaptive planner, fused steps and grouped prefill must keep exact
+    greedy parity with their non-speculative twins."""
 
-    def test_greedy_parity_pipelined(self):
+    def test_greedy_parity_under_the_adaptive_planner(self):
+        """``_spec_cycles_per_sync`` takes its budget from the planner."""
         rng = np.random.RandomState(10)
         prompts = [list(rng.randint(1, 250, size=n)) for n in (5, 9, 14)]
-        plain, spec = make_engines(spec_k=3, pipeline_decode=True)
+        plain, spec = make_engines(spec_k=3, adaptive_steps=8)
         want = [r.output_tokens for r in run_reqs(plain, prompts)]
         got = [r.output_tokens for r in run_reqs(spec, prompts)]
         assert got == want
         assert spec.spec_cycles > 0
         assert spec.spec_emitted > 0
 
-    def test_greedy_parity_multistep_sync(self):
+    def test_greedy_parity_multistep(self):
         rng = np.random.RandomState(11)
         prompts = [list(rng.randint(1, 250, size=n)) for n in (6, 8, 12)]
-        plain, spec = make_engines(spec_k=2, decode_steps_per_sync=8,
-                                   pipeline_decode=False)
+        plain, spec = make_engines(spec_k=2, decode_steps_per_sync=8)
         want = [r.output_tokens for r in run_reqs(plain, prompts)]
         got = [r.output_tokens for r in run_reqs(spec, prompts)]
         assert got == want
@@ -231,60 +233,41 @@ class TestSpeculativeLoopComposition:
         assert spec.spec_cycles >= 3
 
     def test_greedy_parity_all_three_levers(self):
-        """pipeline_decode + decode_steps_per_sync>1 + grouped prefill
-        together."""
+        """decode_steps_per_sync>1 + grouped prefill together."""
         rng = np.random.RandomState(12)
         prompts = [list(rng.randint(1, 250, size=n)) for n in (5, 7, 9, 11)]
         plain, spec = make_engines(
-            spec_k=3, slots=4, pipeline_decode=True,
-            decode_steps_per_sync=8, prefill_batch=2)
+            spec_k=3, slots=4, decode_steps_per_sync=8, prefill_batch=2)
         want = [r.output_tokens for r in run_reqs(plain, prompts)]
         got = [r.output_tokens for r in run_reqs(spec, prompts)]
         assert got == want
         assert spec.spec_emitted > 0
 
-    def test_perfect_draft_pipelined_token_multiplier(self):
-        """Draft == target under the pipelined loop: every cycle emits the
-        full K+1 block, so cycles ~= tokens/(K+1)."""
-        rng = np.random.RandomState(13)
-        prompts = [list(rng.randint(1, 250, size=6))]
-        plain, spec = make_engines(spec_k=3, draft_like_target=True, slots=1,
-                                   pipeline_decode=True)
-        want = [r.output_tokens for r in run_reqs(plain, prompts, max_new=16)]
-        got = [r.output_tokens for r in run_reqs(spec, prompts, max_new=16)]
-        assert got == want
-        assert spec.spec_emitted == 15
-        # 15 post-prefill tokens / 4-token cycles = 4 productive cycles;
-        # pipelined dispatch may add idle blocks after rows freeze.
-        assert spec.spec_cycles >= 4
-
     def test_eos_stops_inside_block(self):
         """Device-side EOS truncation: tokens proposed past an accepted EOS
-        are discarded and the row freezes, in both loops."""
+        are discarded and the row freezes."""
         rng = np.random.RandomState(14)
         prompt = list(rng.randint(1, 250, size=6))
-        for pipelined in (False, True):
-            plain, spec = make_engines(
-                spec_k=3, draft_like_target=True, slots=1,
-                pipeline_decode=pipelined)
-            # Discover the greedy continuation, then rerun with eos set to
-            # a mid-sequence token so the stop lands inside a cycle.
-            ref = run_reqs(plain, [prompt], max_new=16)[0].output_tokens
-            eos = ref[6]
-            plain2, spec2 = make_engines(
-                spec_k=3, draft_like_target=True, slots=1, eos_id=eos,
-                pipeline_decode=pipelined)
-            want = run_reqs(plain2, [prompt], max_new=16)[0]
-            got = run_reqs(spec2, [prompt], max_new=16)[0]
-            assert got.output_tokens == want.output_tokens
-            assert got.finish_reason == want.finish_reason == "stop"
+        plain, spec = make_engines(
+            spec_k=3, draft_like_target=True, slots=1)
+        # Discover the greedy continuation, then rerun with eos set to
+        # a mid-sequence token so the stop lands inside a cycle.
+        ref = run_reqs(plain, [prompt], max_new=16)[0].output_tokens
+        eos = ref[6]
+        plain2, spec2 = make_engines(
+            spec_k=3, draft_like_target=True, slots=1, eos_id=eos)
+        want = run_reqs(plain2, [prompt], max_new=16)[0]
+        got = run_reqs(spec2, [prompt], max_new=16)[0]
+        assert got.output_tokens == want.output_tokens
+        assert got.finish_reason == want.finish_reason == "stop"
 
 
 class TestSpeculativePaged:
     """Speculation over the paged KV cache (extend_step_paged): exact
-    greedy parity with the non-speculative paged engine, in both loops."""
+    greedy parity with the non-speculative paged engine, a step and four
+    fused steps a dispatch."""
 
-    def _engines(self, spec_k, pipelined, slots=3):
+    def _engines(self, spec_k, steps, slots=3):
         from llm_instance_gateway_tpu.server.engine import Engine, EngineConfig
 
         params = transformer.init_params(CFG, jax.random.PRNGKey(0),
@@ -293,8 +276,7 @@ class TestSpeculativePaged:
         dparams = transformer.init_params(dcfg, jax.random.PRNGKey(7),
                                           dtype=jnp.float32)
         ecfg = dict(decode_slots=slots, max_seq_len=96, prefill_buckets=(8, 16),
-                    paged_kv_block=8, pipeline_decode=pipelined,
-                    decode_steps_per_sync=4 if pipelined else 1)
+                    paged_kv_block=8, decode_steps_per_sync=steps)
         plain = Engine(CFG, params, EngineConfig(**ecfg), eos_id=None,
                        dtype=jnp.float32)
         spec = Engine(CFG, params, EngineConfig(**ecfg, speculative_k=spec_k),
@@ -302,12 +284,11 @@ class TestSpeculativePaged:
                       draft_params=dparams, draft_cfg=dcfg)
         return plain, spec
 
-    @pytest.mark.parametrize("pipelined", [False, True],
-                             ids=["sync", "pipelined"])
-    def test_greedy_parity_paged(self, pipelined):
+    @pytest.mark.parametrize("steps", [1, 4], ids=["one-step", "fused"])
+    def test_greedy_parity_paged(self, steps):
         rng = np.random.RandomState(20)
         prompts = [list(rng.randint(1, 250, size=n)) for n in (5, 9, 14)]
-        plain, spec = self._engines(spec_k=3, pipelined=pipelined)
+        plain, spec = self._engines(spec_k=3, steps=steps)
         want = [r.output_tokens for r in run_reqs(plain, prompts)]
         got = [r.output_tokens for r in run_reqs(spec, prompts)]
         assert got == want
@@ -351,7 +332,7 @@ class TestSpeculativePaged:
                                    rtol=2e-4, atol=2e-4)
 
     def test_mixed_batch_schedule_shrink_keeps_parity(self):
-        """Regression: pipelined+paged with VARIABLE dispatch sizes — a
+        """Regression: paged with VARIABLE dispatch sizes — a
         mixed batch (sampled row present) dispatches steps*(K+1) writes,
         then the sampled row finishes and the schedule shrinks.  The paged
         reservation must cover the in-flight larger dispatch or accepted
@@ -361,7 +342,7 @@ class TestSpeculativePaged:
 
         rng = np.random.RandomState(21)
         prompts = [list(rng.randint(1, 250, size=n)) for n in (6, 9)]
-        plain, spec = self._engines(spec_k=3, pipelined=True, slots=3)
+        plain, spec = self._engines(spec_k=3, steps=4, slots=3)
 
         def run(engine, with_sampled):
             reqs = [Request(prompt_tokens=list(p), max_new_tokens=40,

@@ -349,6 +349,7 @@ def _decode_block_text(jax, jnp, Engine) -> str:
         _SLOT_I32,
         _named,
     )
+    from llm_instance_gateway_tpu.server.sampling import STOP_LEN
 
     cfg, b = TINY_TEST, 2
     params = jax.eval_shape(
@@ -365,9 +366,11 @@ def _decode_block_text(jax, jnp, Engine) -> str:
         return jax.ShapeDtypeStruct(
             (b * sum(math.prod(shape) for _, shape, _ in fields),), dtype)
 
+    row = jax.ShapeDtypeStruct((b,), jnp.int32)
+    carry = (row, row, row, jax.ShapeDtypeStruct((b, STOP_LEN), jnp.int32))
     return fn.lower(
         params, None, cache, flat(_SLOT_I32, jnp.int32),
-        flat(_SLOT_F32, jnp.float32), None, jax.random.PRNGKey(0),
+        flat(_SLOT_F32, jnp.float32), carry, jax.random.PRNGKey(0),
         jnp.int32(-1), jax.ShapeDtypeStruct((b, 1), jnp.int32),
         n_steps=1, penalized=False,
     ).as_text(debug_info=True)

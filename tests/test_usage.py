@@ -210,13 +210,12 @@ class TestEngineConservation:
         assert fams["tpu:prefill_padding_tokens_total"][0].value > 0
         assert fams["tpu:decode_batch_occupancy_count"][0].value > 0
 
-    @pytest.mark.parametrize("extra", [
-        {"pipeline_decode": False}, {"pipeline_decode": True},
-        {"pipeline_decode": False, "speculative_k": 2},
-        {"pipeline_decode": True, "speculative_k": 2}],
-        ids=["sync", "pipelined", "spec", "pipelined-spec"])
-    def test_dispatch_accounting_agrees_across_its_sinks(self, extra):
-        """Every decode dispatch of every loop ends in the one
+    @pytest.mark.parametrize("layout", [{}, {"paged_kv_block": 8}],
+                             ids=["lanes", "paged"])
+    @pytest.mark.parametrize("extra", [{}, {"speculative_k": 2}],
+                             ids=["plain", "spec"])
+    def test_dispatch_accounting_agrees_across_its_sinks(self, extra, layout):
+        """Every decode dispatch, plain or speculative, ends in the one
         ``Engine._account_dispatch``; its four sinks (usage tracker,
         profiler, generated total + throughput EMA, step histograms) must
         tell one story of the same requests."""
@@ -240,7 +239,8 @@ class TestEngineConservation:
                 dcfg, jax.random.PRNGKey(7), dtype=jnp.float32)}
         engine = Engine(TINY_TEST, params,
                         EngineConfig(decode_slots=2, max_seq_len=64,
-                                     prefill_buckets=(8, 16), **extra),
+                                     prefill_buckets=(8, 16), **extra,
+                                     **layout),
                         eos_id=None, dtype=jnp.float32, **draft)
         engine.start()
         try:
@@ -270,7 +270,7 @@ class TestEngineConservation:
         assert set(decode_kinds) <= {"decode", "spec"}
         if extra.get("speculative_k"):
             assert decode_kinds.get("spec", 0) > 0
-        # (a pipelined block whose rows all finished meanwhile has no
+        # (a block whose rows all finished before it was read has no
         # owner: the tracker books its occupancy and charges it to nobody)
         owned = [r for r in prof.snapshot()["records"]
                  if r["phase"] != "prefill" and r["active"]]

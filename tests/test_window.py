@@ -41,6 +41,7 @@ from llm_instance_gateway_tpu.ops import pallas_decode_attention as pda
 from llm_instance_gateway_tpu.ops.layers import gated
 from llm_instance_gateway_tpu.server import metrics
 from llm_instance_gateway_tpu.server.engine import Engine, EngineConfig, Request
+from tests._reference import reference_tokens
 
 CFG = TINY_SMALLTHINKER_TEST
 W = CFG.sliding_window
@@ -494,22 +495,6 @@ def test_the_decode_kernel_over_ring_lanes_has_its_own_name():
 
 # -- the engine ---------------------------------------------------------------
 
-_PADDED_REFERENCE = jax.jit(lambda p, t: reference.forward(CFG, p, t))
-
-
-def reference_tokens(params, prompt, n):
-    """The reference's greedy continuation, one compiled shape: the
-    sequence is padded to 64, and a causal model keeps what follows a
-    position out of its logits."""
-    seq = list(prompt)
-    for _ in range(n):
-        padded = np.zeros((64,), np.int32)
-        padded[:len(seq)] = seq
-        logits = _PADDED_REFERENCE(params, jnp.asarray(padded))
-        seq.append(int(jnp.argmax(logits[len(seq) - 1, :CFG.vocab_size])))
-    return seq[len(prompt):]
-
-
 def make_engine(params, cfg=CFG, **kw):
     kw = {"decode_slots": 2, "max_seq_len": 64, "prefill_buckets": (8, 16),
           **kw}
@@ -517,13 +502,12 @@ def make_engine(params, cfg=CFG, **kw):
                   dtype=jnp.float32)
 
 
-@pytest.mark.parametrize("pipelined", [False, True], ids=["sync", "pipelined"])
-def test_engine_gives_the_references_tokens_with_slot_reuse(params, pipelined):
+def test_engine_gives_the_references_tokens_with_slot_reuse(params):
     """Five requests over two slots, bucketed and chunk-streamed prompts
     mixed, prompts under and over the window, answers that wrap the ring:
     greedy tokens equal the reference's, so no slot reads its last
     request's ring and no step writes a row it should not."""
-    engine = make_engine(params, pipeline_decode=pipelined)
+    engine = make_engine(params)
     prompts = [[3, 5, 7], list(range(3, 40)), [9, 8, 7, 6, 5, 4, 3, 2, 1, 11],
                list(range(40, 75)), [100, 200]]
     engine.start()
@@ -535,7 +519,7 @@ def test_engine_gives_the_references_tokens_with_slot_reuse(params, pipelined):
     finally:
         engine.stop()
     for prompt, req in zip(prompts, reqs):
-        assert req.output_tokens == reference_tokens(params, prompt, 14)
+        assert req.output_tokens == reference_tokens(CFG, params, prompt, 14)
     read = engine.profiler.hist_state()["kv_positions"]
     assert read["full"] > read["window"] > 0
     text = metrics.render(engine.metrics_snapshot()) + "\n"
@@ -546,10 +530,11 @@ def test_engine_gives_the_references_tokens_with_slot_reuse(params, pipelined):
 
 
 def test_counter_is_positions_by_kind_of_lane(params):
-    """One request of 12 prompt tokens and 9 new ones on the sync loop:
-    eight decode steps (the first new token comes from the prefill), step j
-    reading 12 + j positions of a full lane and at most 16 of a ring."""
-    engine = make_engine(params, pipeline_decode=False)
+    """One request of 12 prompt tokens and 9 new ones: eight decode steps
+    (the first new token comes from the prefill) and the one dispatched
+    before the eighth was read, step j reading 12 + j positions of a full
+    lane and at most 16 of a ring."""
+    engine = make_engine(params)
     engine.start()
     try:
         req = engine.generate(Request(prompt_tokens=list(range(3, 15)),
@@ -558,8 +543,10 @@ def test_counter_is_positions_by_kind_of_lane(params):
     finally:
         engine.stop()
     read = engine.profiler.hist_state()["kv_positions"]
-    assert read["full"] == sum(12 + j for j in range(1, 9))
-    assert read["window"] == sum(min(12 + j, W) for j in range(1, 9))
+    steps = engine.profiler.dispatches["decode"]
+    assert 8 <= steps <= 9
+    assert read["full"] == sum(12 + j for j in range(1, steps + 1))
+    assert read["window"] == sum(min(12 + j, W) for j in range(1, steps + 1))
 
 
 def test_a_model_without_a_window_counts_no_position():

@@ -231,9 +231,9 @@ def attn_grid_steps_row(profile: dict) -> dict:
 def overlap_row(profile: dict) -> dict:
     """Decode blocks dispatched while an earlier block was still unread
     (``tpu:decode_blocks_overlapped_total``) and their share of the decode
-    blocks (plain and speculative): ~100% in a loaded window of the
-    overlapped loop, 0 under ``--no-pipeline-decode``; empty for a payload
-    from before the counter."""
+    blocks (plain and speculative): ~100% in a loaded window, 0 where
+    every block was staged with none in flight (the device had run dry);
+    empty for a payload from before the counter."""
     hist = profile.get("hist") or {}
     if "blocks_overlapped" not in hist:
         return {}
