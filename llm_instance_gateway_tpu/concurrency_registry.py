@@ -485,6 +485,11 @@ CLASSES = (
                         note="adapter rows of the decode steps: the engine "
                              "thread adds at each dispatch, the scrape "
                              "reads under the lock"),
+            SharedField("lora_free_steps", LOCK_GUARDED,
+                        writers=("note_lora_free_steps",),
+                        note="decode steps run without the adapter delta: "
+                             "the engine thread adds at each such dispatch, "
+                             "the scrape reads under the lock"),
             SharedField("blocks_overlapped", LOCK_GUARDED,
                         writers=("note_overlapped_block",),
                         note="decode blocks dispatched over an unread one: "

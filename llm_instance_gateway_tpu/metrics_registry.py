@@ -449,8 +449,16 @@ SERVER_FAMILIES = (
     Family("tpu:lora_rows_total", "counter", (),
            "Live rows whose LoRA slot is >= 0, summed over the steps of the "
            "plain decode dispatches: over tpu:dispatch_steps_sum, the rows "
-           "of a step that use what it reads of the adapters (a step reads "
-           "every slot's matrices whether any row does or not).",
+           "of a step that use what it reads of the adapters (a step that "
+           "is handed the adapter buffers reads every slot's matrices, "
+           "whichever rows use them).",
+           SERVER_SURFACE),
+    Family("tpu:lora_free_steps_total", "counter", (),
+           "Steps of the plain decode dispatches that ran the decode program "
+           "without the LoRA delta, because no row of the block named an "
+           "adapter: over tpu:dispatch_steps_sum, the share of decode steps "
+           "that read no adapter matrix. 0 on a server without adapter "
+           "buffers (--max-loras 0), whose one program never has the delta.",
            SERVER_SURFACE),
     Family("tpu:decode_blocks_overlapped_total", "counter", (),
            "Decode blocks dispatched from the device carry while an earlier "

@@ -16,9 +16,14 @@ multiplexing the gateway's LoRA-affinity routing assumes.
 
 The delta is computed BY SLOT (``lora_delta``): two matmuls a target over
 all slots at once, each row keeping its own slot's rank block in between.
-A step so reads every slot's matrices once for the batch, whatever its rows
-ask for — including a batch of base rows, which pays the read for nothing
-(``tpu:lora_rows_total`` counts the rows that use it).
+A program that is handed the buffers so reads every slot's matrices once for
+the batch, whatever its rows ask for.  A prompt program always is, so a base
+prompt pays the read for nothing; a decode block is only when one of its
+rows names an adapter (``Engine._block_lora_buffers`` decides from the slot
+ids it stages): a block of base rows runs the decode program traced with
+``lora_bufs=None``, which holds no delta at all.  ``tpu:lora_rows_total``
+counts the rows that use the read, ``tpu:lora_free_steps_total`` the decode
+steps that do not make it.
 
 Targets: the attention projections q/k/v/o and the MLP gate/up/down, matching
 what vLLM serves for Llama-family adapters.

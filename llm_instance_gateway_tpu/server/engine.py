@@ -99,16 +99,23 @@ _SLOT_F32 = (
 STAGE_UPLOADS = 2
 
 
+def _slot_spans(fields, b: int) -> dict:
+    """``{name: (slice of the flat buffer, (b, *shape))}`` for ``b`` rows
+    laid out as ``fields``."""
+    out, at = {}, 0
+    for name, shape, _ in fields:
+        n = b * math.prod(shape)
+        out[name] = slice(at, at + n), (b, *shape)
+        at += n
+    return out
+
+
 def _slot_views(buf, fields, b: int) -> dict:
     """``{name: buf's [b, *shape] part}`` for a flat buffer laid out as
     ``fields``: views of a numpy buffer on the host, static slices of the
     uploaded copy inside the decode program."""
-    out, at = {}, 0
-    for name, shape, _ in fields:
-        n = b * math.prod(shape)
-        out[name] = buf[at:at + n].reshape((b, *shape))
-        at += n
-    return out
+    return {name: buf[span].reshape(shape)
+            for name, (span, shape) in _slot_spans(fields, b).items()}
 
 
 def _stage_carry(carry, i32: dict):
@@ -798,6 +805,9 @@ class Engine:
         self._slots_f32, f32 = _slot_buffer(_SLOT_F32, b, np.float32)
         self._slot_positions = i32["positions"]
         self._slot_lora = i32["lora"]
+        # Where the rows' adapter slots lie in a staged copy of the buffer
+        # (_block_lora_buffers).
+        self._lora_span = _slot_spans(_SLOT_I32, b)["lora"][0]
         self._slot_temp = f32["temp"]
         self._slot_topk = i32["topk"]
         self._slot_topp = f32["topp"]
@@ -908,6 +918,9 @@ class Engine:
         # anchor (_process_block).
         self._first_unread: list[tuple] = []
         self._inflight: dict | None = None
+        # The decode programs the loop has met or had prepared, as
+        # (n_steps, penalized, with the adapter delta): _prepare_other_trace.
+        self._decode_traces: set[tuple] = set()
         self._prev_dispatch_steps = 0
         self._last_done_pc = 0.0
         # The device carry from one block to the next: each row's last
@@ -1295,7 +1308,8 @@ class Engine:
 
         The choice quantizes DOWN to a power of two: ``n_steps`` is a
         static jit argument, so this bounds the compiled-variant set to
-        log2(ceiling) programs per (penalized,) combination.
+        log2(ceiling) programs per (penalized, adapter buffers or none)
+        combination.
         """
         ceiling = self.cfg.adaptive_steps
         if ceiling <= 0:
@@ -1341,13 +1355,15 @@ class Engine:
         block is in flight.  ``counts`` is the real buffer only
         when some row carries a penalty (static flag -> two compiled
         variants), so penalty-free serving never allocates or streams
-        [B, V] counts."""
+        [B, V] counts.  The adapter buffers likewise go only when some row
+        of the staged copy names an adapter (``_block_lora_buffers``:
+        ``None`` is another pytree -> a second trace of each variant, the
+        program of a server without adapters), so a block of base rows
+        reads no adapter matrix."""
         penalized = bool(self._slot_presence.any()
                          or self._slot_frequency.any())
         counts = self._counts() if penalized else self._counts_dummy
         self.profiler.note_stage_ops(STAGE_UPLOADS)
-        self.profiler.note_lora_rows(
-            n_steps * int(np.count_nonzero(self._slot_lora >= 0)))
         if self._recurrent:
             # Every step of the block rewrites the state of every row the
             # host holds (a row that stops mid-block counts on to its end).
@@ -1379,10 +1395,14 @@ class Engine:
         # The copy carries the activations since the last block; the block
         # after this one takes those rows from the carry like any other.
         self._slot_fresh[:] = 0
+        lora_bufs, adapter_rows = self._block_lora_buffers(i32)
+        self.profiler.note_lora_rows(n_steps * adapter_rows)
+        if lora_bufs is None and self.lora is not None:
+            self.profiler.note_lora_free_steps(n_steps)
         with self._enqueue("engine.decode.enqueue"):
             (*outs, carry, self._rng, counts, self.cache, moe) = (
                 self._jit_decode(
-                    self.params, self._lora_buffers(), self.cache,
+                    self.params, lora_bufs, self.cache,
                     i32, f32, carry,
                     self._rng, self._eos_for_device, counts,
                     n_steps=n_steps, penalized=penalized))
@@ -1390,7 +1410,56 @@ class Engine:
             self._dev_counts = counts
         else:
             self._counts_dummy = counts
+        if self.lora is not None:
+            # The outputs have the inputs' shapes: they stand for them.
+            self._prepare_other_trace(
+                lora_bufs is not None,
+                (self.cache, i32, f32, carry, self._rng,
+                 self._eos_for_device, counts), n_steps, penalized)
         return outs, carry, self._moe_drain(moe)
+
+    def _prepare_other_trace(self, with_delta: bool, rest: tuple,
+                             n_steps: int, penalized: bool) -> None:
+        """An engine that holds adapter buffers runs each decode variant on
+        two traces (``_block_lora_buffers``).  When the loop has met one of
+        them for the first time, the other is lowered and compiled here on
+        a helper thread from the same call's shapes (``rest``: the call's
+        arguments after the buffers), so that the block that first needs it
+        (an adapter row joining base rows, or the last one leaving) does
+        not hold every row up for a compile: ``lower().compile()`` fills
+        the caches the jitted call then finds.  The trace with the delta is
+        prepared only while an adapter is resident.  Which program a block
+        runs never depends on any of this."""
+        met, other = self._decode_traces, not with_delta
+        if (n_steps, penalized, with_delta) in met:
+            return
+        met.add((n_steps, penalized, with_delta))
+        if (n_steps, penalized, other) in met or (
+                other and not self.lora.running_adapters()):
+            return
+        met.add((n_steps, penalized, other))
+
+        def abstract(x):
+            committed = isinstance(x, jax.Array) and x.committed
+            return jax.ShapeDtypeStruct(
+                np.shape(x), x.dtype, sharding=x.sharding if committed
+                else None)
+
+        args = jax.tree_util.tree_map(
+            abstract, (self.params, self.lora.buffers if other else None,
+                       *rest))
+
+        def prepare():
+            try:
+                self._jit_decode.lower(
+                    *args, n_steps=n_steps, penalized=penalized).compile()
+            except Exception:  # the call itself compiles when it comes
+                logger.exception("preparing the decode program %s the "
+                                 "adapter delta failed",
+                                 "with" if other else "without")
+
+        threading.Thread(target=prepare, daemon=True,
+                         name="decode-trace-prepare").start()
 
     def _count_first_token(self, slot_idx: int, tok) -> None:
         """Penalty rows count their prefill-sampled first token too (vLLM
@@ -2112,6 +2181,21 @@ class Engine:
 
     def _lora_buffers(self):
         return self.lora.buffers if self.lora is not None else None
+
+    def _block_lora_buffers(self, staged_i32: np.ndarray):
+        """What a decode block reads of the adapters, decided from
+        ``staged_i32``, the private copy of the int32 slot buffer that goes
+        up with it (a freed row holds -1): ``(buffers, rows naming an
+        adapter)``, the buffers ``None`` when no row names one.  A slot -1
+        row's delta is an exact 0 (``models/lora.py``), so a block of base
+        rows runs the program traced without the delta and says what the
+        other would; one adapter row among base rows keeps the buffers for
+        the block.  The rule of every decode dispatch, plain and
+        speculative; the prompt programs always take the buffers."""
+        if self.lora is None:
+            return None, 0
+        rows = int(np.count_nonzero(staged_i32[self._lora_span] >= 0))
+        return (self.lora.buffers if rows else None), rows
 
     def _phase(self, name: str):
         """The engine thread's phase from here to the end of the ``with``
@@ -3787,20 +3871,24 @@ class Engine:
         t0 = time.perf_counter()
         # What the plain block does inside its program: freed rows' budgets
         # zeroed, activated rows' positions and budgets taken as staged.
+        staged = self._slots_i32.copy()
         (self._dev_tokens, self._dev_positions, self._dev_remaining,
          self._dev_stop_hist) = self._jit_stage_carry(
             (self._dev_tokens, self._dev_positions, self._dev_remaining,
-             self._dev_stop_hist), self._slots_i32.copy())
+             self._dev_stop_hist), staged)
         self._slot_fresh[:] = 0
+        # The verify reads the adapters by the plain block's rule, from
+        # the slots that go up with it.
+        lora_bufs, _ = self._block_lora_buffers(staged)
         args = (
-            self.params, self.draft_params, self._lora_buffers(),
+            self.params, self.draft_params, lora_bufs,
             self.cache, self.draft_cache,
             self._dev_tokens, self._dev_positions, self._dev_remaining,
             self._dev_extra_tok, self._dev_extra_pos, self._dev_has_extra,
             jnp.asarray(self._spec_ok),
             jnp.asarray(self._slot_temp), jnp.asarray(self._slot_topk),
             jnp.asarray(self._slot_topp), self._next_key(),
-            jnp.asarray(self._slot_lora), self._eos_for_device,
+            jnp.asarray(staged[self._lora_span]), self._eos_for_device,
             jnp.asarray(self._slot_seed),
         )
         with self._enqueue("engine.decode.enqueue"):

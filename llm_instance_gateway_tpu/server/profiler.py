@@ -164,6 +164,10 @@ class StepProfiler:
         # Live rows whose LoRA slot is >= 0, summed over the steps of the
         # plain decode dispatches: the rows a step's adapter reads serve.
         self.lora_rows = 0
+        # Steps of the plain decode dispatches that ran the program without
+        # the LoRA delta: no row of the block named an adapter on an engine
+        # that holds adapter buffers (0 on one that holds none).
+        self.lora_free_steps = 0
         # Cache rows the latent (MLA) decode kernel had to read: the live
         # rows' cache lengths, summed over the steps of the plain decode
         # dispatches.  0 for a model with per-head K/V lanes.
@@ -432,6 +436,13 @@ class StepProfiler:
         with self._lock:
             self.lora_rows += n
 
+    def note_lora_free_steps(self, n: int) -> None:
+        """Count the ``n`` steps of one plain decode dispatch that was
+        handed no adapter buffers because none of its rows named an
+        adapter."""
+        with self._lock:
+            self.lora_free_steps += n
+
     def note_latent_positions(self, n: int) -> None:
         """Count ``n`` cache positions a latent model's live rows held over
         the steps of one plain decode dispatch."""
@@ -477,6 +488,7 @@ class StepProfiler:
                         for k, h in sorted(self.gap_hist.items())},
                 "stage_ops": self.stage_ops,
                 "lora_rows": self.lora_rows,
+                "lora_free_steps": self.lora_free_steps,
                 "latent_positions": self.latent_positions,
                 "ssm_rows": self.ssm_rows,
                 "kv_positions": dict(zip(KV_LANES, self.kv_positions)),
@@ -558,6 +570,9 @@ def render_profile(hist: dict) -> list[str]:
     if "lora_rows" in hist:
         lines += ["# TYPE tpu:lora_rows_total counter",
                   f"tpu:lora_rows_total {hist['lora_rows']}"]
+    if "lora_free_steps" in hist:
+        lines += ["# TYPE tpu:lora_free_steps_total counter",
+                  f"tpu:lora_free_steps_total {hist['lora_free_steps']}"]
     if "latent_positions" in hist:
         lines += ["# TYPE tpu:latent_kv_positions_total counter",
                   "tpu:latent_kv_positions_total "

@@ -33,14 +33,24 @@ def _programs(cfg):
 S_MAX = 64  # the row's length; prompt and answer fit in every caller
 
 
-def reference_tokens(cfg, params, prompt, n, *, sampling=None) -> list[int]:
+def reference_tokens(cfg, params, prompt, n, *, sampling=None,
+                     logprobs: list | None = None) -> list[int]:
     """The ``n`` tokens that follow ``prompt``, on float32 weights.
     ``sampling``: a ``SamplingParams`` with a seed, or None for greedy.
+    ``logprobs``: a list that takes each token's log-probability under the
+    model (before temperature, over the true vocabulary).
     One compiled shape a configuration: the prompt is padded to
     ``S_MAX``."""
     run, p_len = _programs(cfg), len(prompt)
 
     def pick(logits, position):
+        tok = draw(logits, position)
+        if logprobs is not None:
+            logprobs.append(float(
+                jax.nn.log_softmax(logits[:cfg.vocab_size])[tok]))
+        return tok
+
+    def draw(logits, position):
         if sampling is None or sampling.temperature <= 0.0:
             return int(jnp.argmax(logits[:cfg.vocab_size]))
         one = lambda x, t: jnp.full((1,), x, t)

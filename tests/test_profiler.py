@@ -716,26 +716,41 @@ class TestPhaseReport:
             "overlapped_pct": 0.0}
 
     def test_report_prints_adapter_rows_per_dispatch(self):
-        """``tpu:lora_rows_total`` (``note_lora_rows``): in ``/metrics``,
-        in ``/debug/profile``'s ``hist``, and over the decode dispatches
-        in the report; none for a payload from before the counter."""
+        """``tpu:lora_rows_total`` (``note_lora_rows``) and
+        ``tpu:lora_free_steps_total`` (``note_lora_free_steps``): in
+        ``/metrics``, in ``/debug/profile``'s ``hist``, and over the decode
+        dispatches in the report; the second left out, and then both, for
+        payloads from before each counter."""
         clock = FakeClock()
         p = StepProfiler(capacity=8, clock=clock)
         for rows in (2, 0, 3, 1):
             p.note_lora_rows(rows)
+            if not rows:
+                p.note_lora_free_steps(1)
             p.note_dispatch("decode", clock.now, 0.01, active=3,
                             total_slots=4)
             clock.tick(0.02)
         assert p.snapshot()["hist"]["lora_rows"] == 6
+        assert p.snapshot()["hist"]["lora_free_steps"] == 1
         row = profile_report.lora_rows_row(p.snapshot())
         assert row == {"lora_rows": 6, "decode_dispatches": 4,
-                       "rows_per_dispatch": 1.5}
+                       "rows_per_dispatch": 1.5, "lora_free_steps": 1,
+                       "free_steps_per_dispatch": 0.25}
         out = profile_report.render_report(p.snapshot())
-        assert "Adapter rows in the decode steps:" in out and "1.5" in out
+        assert "Adapter rows in the decode steps" in out and "1.5" in out
+        assert "free_steps_per_dispatch" in out and "0.25" in out
         lines = render_profile(p.hist_state())
         assert "# TYPE tpu:lora_rows_total counter" in lines
         assert "tpu:lora_rows_total 6" in lines
+        assert "# TYPE tpu:lora_free_steps_total counter" in lines
+        assert "tpu:lora_free_steps_total 1" in lines
         old = p.snapshot()
+        del old["hist"]["lora_free_steps"]
+        assert profile_report.lora_rows_row(old) == {
+            "lora_rows": 6, "decode_dispatches": 4, "rows_per_dispatch": 1.5}
+        assert "free_steps" not in profile_report.render_report(old)
+        assert not any("lora_free_steps" in ln
+                       for ln in render_profile(old["hist"]))
         del old["hist"]["lora_rows"]
         assert profile_report.lora_rows_row(old) == {}
         assert "Adapter rows" not in profile_report.render_report(old)

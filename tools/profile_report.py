@@ -180,9 +180,15 @@ def stage_ops_row(profile: dict) -> dict:
 
 def lora_rows_row(profile: dict) -> dict:
     """Live rows with a LoRA slot, summed over the decode steps
-    (``tpu:lora_rows_total``); per dispatch is per step where dispatches
-    are one step long."""
-    return _per_decode_dispatch(profile, "lora_rows", "rows_per_dispatch")
+    (``tpu:lora_rows_total``), and the decode steps that ran without the
+    adapter delta (``tpu:lora_free_steps_total``; left out for a payload
+    from before that counter); per dispatch is per step, and the second a
+    share of the steps, where dispatches are one step long."""
+    row = _per_decode_dispatch(profile, "lora_rows", "rows_per_dispatch")
+    if row:
+        row.update(_per_decode_dispatch(profile, "lora_free_steps",
+                                        "free_steps_per_dispatch"))
+    return row
 
 
 def latent_positions_row(profile: dict) -> dict:
@@ -691,9 +697,13 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
                                    "overlapped_pct"))]
     adapter_rows = lora_rows_row(profile)
     if adapter_rows:
-        out += ["", "Adapter rows in the decode steps:",
-                _table([adapter_rows], ("lora_rows", "decode_dispatches",
-                                        "rows_per_dispatch"))]
+        out += ["", "Adapter rows in the decode steps, and the steps run "
+                "without the adapter delta:",
+                _table([adapter_rows], tuple(
+                    k for k in ("lora_rows", "decode_dispatches",
+                                "rows_per_dispatch", "lora_free_steps",
+                                "free_steps_per_dispatch")
+                    if k in adapter_rows))]
     latent = latent_positions_row(profile)
     if latent:
         out += ["", "Latent cache rows read by the decode steps:",
