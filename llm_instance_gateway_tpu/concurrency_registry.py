@@ -490,6 +490,12 @@ CLASSES = (
                         note="decode steps run without the adapter delta: "
                              "the engine thread adds at each such dispatch, "
                              "the scrape reads under the lock"),
+            SharedField("lora_target_reads", LOCK_GUARDED,
+                        writers=("note_lora_target_reads",),
+                        note="adapter targets handed to the decode steps "
+                             "run with the delta: the engine thread adds at "
+                             "each such dispatch, the scrape reads under "
+                             "the lock"),
             SharedField("blocks_overlapped", LOCK_GUARDED,
                         writers=("note_overlapped_block",),
                         note="decode blocks dispatched over an unread one: "
@@ -564,10 +570,28 @@ CLASSES = (
                         writers=("load", "demote", "unload"),
                         note="device buffer pytree swapped whole per "
                              "residency verb"),
+            SharedField("_targets", LOCK_GUARDED, writers=("_retarget",),
+                        note="union of the slot tier's LoRA targets (and "
+                             "a load's under way): a frozenset swapped "
+                             "whole under _lock by the residency verbs"),
+            SharedField("_targets_hook", SWAP_PUBLISHED,
+                        writers=("watch_targets",), domain=CONTROL,
+                        note="the engine's listener, set once at its "
+                             "construction; called under _mutate_lock, "
+                             "never under _lock"),
         )),
     SharedClass(
         f"{PKG}/server/engine.py", "Engine", ENGINE_STEP,
+        lock_attrs=("_lock", "_trace_lock"),
         fields=(
+            SharedField("_lora_targets", SWAP_PUBLISHED,
+                        writers=("_retarget",), domain=CONTROL,
+                        note="the LoRA targets a decode block with an "
+                             "adapter row is handed: a tuple swapped whole "
+                             "under _trace_lock by the thread of a load "
+                             "that widens it or a helper thread that "
+                             "narrows it; the engine thread reads it "
+                             "lock-free at each dispatch"),
             SharedField("_running", SWAP_PUBLISHED,
                         writers=("start", "stop"), domain=CONTROL),
             SharedField("_thread", SWAP_PUBLISHED, writers=("start",),

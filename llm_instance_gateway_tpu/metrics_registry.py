@@ -450,8 +450,8 @@ SERVER_FAMILIES = (
            "Live rows whose LoRA slot is >= 0, summed over the steps of the "
            "plain decode dispatches: over tpu:dispatch_steps_sum, the rows "
            "of a step that use what it reads of the adapters (a step that "
-           "is handed the adapter buffers reads every slot's matrices, "
-           "whichever rows use them).",
+           "is handed adapter buffers reads every slot's matrices of the "
+           "targets handed, whichever rows use them).",
            SERVER_SURFACE),
     Family("tpu:lora_free_steps_total", "counter", (),
            "Steps of the plain decode dispatches that ran the decode program "
@@ -459,6 +459,15 @@ SERVER_FAMILIES = (
            "adapter: over tpu:dispatch_steps_sum, the share of decode steps "
            "that read no adapter matrix. 0 on a server without adapter "
            "buffers (--max-loras 0), whose one program never has the delta.",
+           SERVER_SURFACE),
+    Family("tpu:lora_target_reads_total", "counter", (),
+           "LoRA targets (of q, k, v, o, gate, up, down) whose buffers the "
+           "plain decode dispatches that ran WITH the delta were handed, "
+           "summed over their steps: a block with an adapter row is handed "
+           "only the targets some resident adapter carries. Over "
+           "(tpu:dispatch_steps_sum - tpu:lora_free_steps_total), the "
+           "targets a delta step reads: 2 where every resident adapter is "
+           "on q and v, 7 where one carries them all.",
            SERVER_SURFACE),
     Family("tpu:decode_blocks_overlapped_total", "counter", (),
            "Decode blocks dispatched from the device carry while an earlier "

@@ -6,7 +6,7 @@ slot limits (``--max-loras 4``).  On TPU the equivalent must dodge XLA's
 recompile-on-shape-change (SURVEY.md §7 "hard parts"): adapters live in
 PRE-ALLOCATED buffers of compile-time shape ``[n_layers, n_slots, d,
 r_max]`` — loading an adapter is a pure device-buffer donation
-(``buffers.at[:, slot].set(...)``), never a new program.
+(``buffers.at[:, slot].set(...)``).
 
 Adapters with rank r < r_max are zero-padded: padded lanes contribute exactly
 0 to the delta, so correctness is rank-independent.  Per-slot ``scale`` holds
@@ -16,14 +16,34 @@ multiplexing the gateway's LoRA-affinity routing assumes.
 
 The delta is computed BY SLOT (``lora_delta``): two matmuls a target over
 all slots at once, each row keeping its own slot's rank block in between.
-A program that is handed the buffers so reads every slot's matrices once for
-the batch, whatever its rows ask for.  A prompt program always is, so a base
-prompt pays the read for nothing; a decode block is only when one of its
-rows names an adapter (``Engine._block_lora_buffers`` decides from the slot
-ids it stages): a block of base rows runs the decode program traced with
-``lora_bufs=None``, which holds no delta at all.  ``tpu:lora_rows_total``
-counts the rows that use the read, ``tpu:lora_free_steps_total`` the decode
-steps that do not make it.
+A program reads every slot's matrices of every target it is HANDED once for
+the batch, whatever its rows ask for, and holds no operation for a target
+whose ``{t}_a`` / ``{t}_b`` are not in the dict it was handed (``_project``,
+``layer_slice``, ``stack_for_scan`` go by the keys).  A prompt program is
+always handed all seven, so a base prompt pays the read for nothing.  A
+decode block is handed what ``Engine._block_lora_buffers`` decides from the
+slot ids it stages: nothing (``lora_bufs=None``, no delta at all) when none of
+its rows names an adapter, else the buffers of the targets that some RESIDENT
+adapter carries (``LoRAManager.resident_targets``; a target no resident
+adapter carries holds exact zeros in every slot).  Most published adapters
+carry q and v only, and then five of the seven reads never happen.
+
+What "never a new program" means, then.  Each set of targets is another trace
+of the decode program, so:
+
+- never for the engine loop: a block only ever runs a trace that was compiled
+  before a row could ask for it (``Engine._retarget``);
+- never for a load INSIDE the set (an adapter whose targets a resident one
+  already carries): the buffer write it always was;
+- one compile, off the loop, for a load that WIDENS the set: ``LoRAManager.
+  load`` has the engine compile the wider traces on its own (HTTP) thread
+  before it publishes the adapter, so such a load takes a compile longer;
+- an unload that narrows the set leaves the wider traces in use until a
+  helper thread has compiled the narrower ones.
+
+``tpu:lora_rows_total`` counts the rows that use the read,
+``tpu:lora_free_steps_total`` the decode steps that do not make it,
+``tpu:lora_target_reads_total`` the targets the other steps were handed.
 
 Targets: the attention projections q/k/v/o and the MLP gate/up/down, matching
 what vLLM serves for Llama-family adapters.
@@ -110,6 +130,17 @@ def load_adapter(
     return out
 
 
+def select_targets(bufs: dict[str, Any], targets) -> dict[str, Any]:
+    """``scale`` and the ``{t}_a`` / ``{t}_b`` of ``targets``: the same device
+    arrays under the same keys, nothing copied.  A program handed this dict
+    computes those targets' deltas and no other (another pytree, so another
+    trace of it); all of ``TARGETS`` gives the whole dict back."""
+    out = {"scale": bufs["scale"]}
+    for t in targets:
+        out[f"{t}_a"], out[f"{t}_b"] = bufs[f"{t}_a"], bufs[f"{t}_b"]
+    return out
+
+
 def unload_adapter(bufs: dict[str, Any], cfg, slot: int) -> dict[str, Any]:
     """Zero a slot (slot becomes base-model passthrough)."""
     out = dict(bufs)
@@ -159,15 +190,12 @@ def lora_delta(
 
 
 def layer_slice(bufs: dict[str, Any], layer: jax.Array | int) -> dict[str, Any]:
-    """Per-layer view for use inside lax.scan over layers."""
-    out = {"scale": bufs["scale"]}
-    for t in TARGETS:
-        out[f"{t}_a"] = jax.lax.dynamic_index_in_dim(
-            bufs[f"{t}_a"], layer, axis=0, keepdims=False
-        )
-        out[f"{t}_b"] = jax.lax.dynamic_index_in_dim(
-            bufs[f"{t}_b"], layer, axis=0, keepdims=False
-        )
+    """Per-layer view of the buffers handed, for use inside lax.scan over
+    layers."""
+    per_layer, out = stack_for_scan(bufs)
+    for k, v in per_layer.items():
+        out[k] = jax.lax.dynamic_index_in_dim(v, layer, axis=0,
+                                              keepdims=False)
     return out
 
 

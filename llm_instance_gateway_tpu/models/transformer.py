@@ -23,9 +23,10 @@ TPU-first structure:
   the entire serving lifetime (XLA recompile storms are the TPU-serving
   failure mode the design avoids, SURVEY.md §7).
 - bfloat16 params/activations, f32 softmax/norms, f32 logits.
-- Multi-LoRA deltas (``models.lora``) apply to every projection: computed by
-  slot over the whole batch, kept per row by its slot id, so one decode batch
-  multiplexes adapters + base model.
+- Multi-LoRA deltas (``models.lora``) apply to every projection whose
+  target's buffers the program was handed: computed by slot over the whole
+  batch, kept per row by its slot id, so one decode batch multiplexes
+  adapters + base model.
 - Every block sits in a ``jax.named_scope`` (embed, attn.qkv, attn.rope,
   attn.kv_update, attn.core (attn.core.window over a window layer's ring
   lanes), attn.out, mlp, moe.route / .dispatch /
@@ -283,9 +284,10 @@ def _kv_dequantize(q: jax.Array, s: jax.Array, dtype) -> jax.Array:
 
 
 def _project(x, w, layer_lora, target, slot_ids):
-    """x @ w plus each row's LoRA delta for ``target`` (w may be int8)."""
+    """x @ w plus each row's LoRA delta for ``target`` (w may be int8), where
+    the layer was handed that target's buffers."""
     out = q_matmul(x, w)
-    if layer_lora is not None:
+    if layer_lora is not None and f"{target}_a" in layer_lora:
         out = out + lora_lib.lora_delta(
             x,
             layer_lora[f"{target}_a"],

@@ -34,6 +34,7 @@ import queue as queue_mod
 import threading
 import time
 import uuid
+from collections.abc import Callable
 from dataclasses import dataclass, field
 
 import jax
@@ -41,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from llm_instance_gateway_tpu.lockwitness import witness_lock
+from llm_instance_gateway_tpu.models import lora as lora_lib
 from llm_instance_gateway_tpu.models import paged as paged_lib
 from llm_instance_gateway_tpu.models import transformer
 from llm_instance_gateway_tpu.models.configs import ModelConfig
@@ -172,6 +174,19 @@ def _named(name: str, fn, *bound):
     part = functools.partial(fn, *bound)
     part.__name__ = part.__qualname__ = name
     return part
+
+
+def _abstract(x):
+    """Shape, dtype and, of a committed array, sharding: what a jitted
+    program's trace is keyed by."""
+    committed = isinstance(x, jax.Array) and x.committed
+    return jax.ShapeDtypeStruct(
+        np.shape(x), x.dtype, sharding=x.sharding if committed else None)
+
+
+def _in_order(targets) -> tuple[str, ...]:
+    """A set of LoRA targets in ``models.lora.TARGETS``' order."""
+    return tuple(t for t in lora_lib.TARGETS if t in targets)
 
 
 def _in_phase(name: str, hand_over: bool = False):
@@ -918,9 +933,23 @@ class Engine:
         # anchor (_process_block).
         self._first_unread: list[tuple] = []
         self._inflight: dict | None = None
-        # The decode programs the loop has met or had prepared, as
-        # (n_steps, penalized, with the adapter delta): _prepare_other_trace.
-        self._decode_traces: set[tuple] = set()
+        # The traces of the decode programs, where the engine holds
+        # adapter buffers (_traced, _retarget).  _lora_targets: the LoRA
+        # targets whose buffers a block with an adapter row is handed, in
+        # TARGETS' order: the manager's resident targets, or an older,
+        # wider set while the narrower one's traces are compiled.
+        # _decode_variants: each decode program the loop has met under each
+        # of its static arguments -> how to lower it for another set of
+        # targets.  _decode_traces: (variant, targets or None) -> set once
+        # that trace is compiled.  Written under _trace_lock, which is never
+        # held over a compile; the loop reads all three without it.
+        self._trace_lock = witness_lock("Engine._trace_lock")
+        self._lora_targets: tuple[str, ...] = ()
+        self._decode_variants: dict[tuple, Callable] = {}
+        self._decode_traces: dict[tuple, threading.Event] = {}
+        if lora_manager is not None:
+            lora_manager.watch_targets(self._on_resident_targets)
+            self._lora_targets = _in_order(lora_manager.resident_targets())
         self._prev_dispatch_steps = 0
         self._last_done_pc = 0.0
         # The device carry from one block to the next: each row's last
@@ -1356,10 +1385,12 @@ class Engine:
         when some row carries a penalty (static flag -> two compiled
         variants), so penalty-free serving never allocates or streams
         [B, V] counts.  The adapter buffers likewise go only when some row
-        of the staged copy names an adapter (``_block_lora_buffers``:
-        ``None`` is another pytree -> a second trace of each variant, the
-        program of a server without adapters), so a block of base rows
-        reads no adapter matrix."""
+        of the staged copy names an adapter, and then only those of the
+        targets a resident adapter carries (``_block_lora_buffers``:
+        ``None`` or a dict of fewer targets is another pytree -> another
+        trace of each variant, ``None`` the program of a server without
+        adapters), so a block of base rows reads no adapter matrix and a
+        block with an adapter row none that holds only zeros."""
         penalized = bool(self._slot_presence.any()
                          or self._slot_frequency.any())
         counts = self._counts() if penalized else self._counts_dummy
@@ -1395,13 +1426,16 @@ class Engine:
         # The copy carries the activations since the last block; the block
         # after this one takes those rows from the carry like any other.
         self._slot_fresh[:] = 0
-        lora_bufs, adapter_rows = self._block_lora_buffers(i32)
+        lora_bufs, targets, adapter_rows = self._block_lora_buffers(i32)
         self.profiler.note_lora_rows(n_steps * adapter_rows)
-        if lora_bufs is None and self.lora is not None:
+        if targets is not None:
+            self.profiler.note_lora_target_reads(n_steps * len(targets))
+        elif self.lora is not None:
             self.profiler.note_lora_free_steps(n_steps)
         with self._enqueue("engine.decode.enqueue"):
             (*outs, carry, self._rng, counts, self.cache, moe) = (
-                self._jit_decode(
+                self._traced(
+                    self._jit_decode, 1, targets,
                     self.params, lora_bufs, self.cache,
                     i32, f32, carry,
                     self._rng, self._eos_for_device, counts,
@@ -1410,56 +1444,118 @@ class Engine:
             self._dev_counts = counts
         else:
             self._counts_dummy = counts
-        if self.lora is not None:
-            # The outputs have the inputs' shapes: they stand for them.
-            self._prepare_other_trace(
-                lora_bufs is not None,
-                (self.cache, i32, f32, carry, self._rng,
-                 self._eos_for_device, counts), n_steps, penalized)
         return outs, carry, self._moe_drain(moe)
 
-    def _prepare_other_trace(self, with_delta: bool, rest: tuple,
-                             n_steps: int, penalized: bool) -> None:
-        """An engine that holds adapter buffers runs each decode variant on
-        two traces (``_block_lora_buffers``).  When the loop has met one of
-        them for the first time, the other is lowered and compiled here on
-        a helper thread from the same call's shapes (``rest``: the call's
-        arguments after the buffers), so that the block that first needs it
-        (an adapter row joining base rows, or the last one leaving) does
-        not hold every row up for a compile: ``lower().compile()`` fills
-        the caches the jitted call then finds.  The trace with the delta is
-        prepared only while an adapter is resident.  Which program a block
-        runs never depends on any of this."""
-        met, other = self._decode_traces, not with_delta
-        if (n_steps, penalized, with_delta) in met:
+    def _traced(self, fn, at: int, targets, *args, **statics):
+        """``fn(*args, **statics)``, a jitted decode program handed what
+        ``_block_lora_buffers`` decided: ``targets``, and as ``args[at]``
+        the buffers themselves.  An engine that holds adapter buffers runs
+        each variant of such a program (its static arguments) on several
+        traces: without the delta, and with the delta of each set of
+        targets it has handed.  The loop itself compiles only the first
+        trace it meets of a variant, as an engine without adapters does;
+        every other one is lowered and compiled off the loop from that
+        first call's shapes (``lower().compile()`` fills the caches the
+        jitted call then finds), before a block can need it:
+
+        - the variant's other trace (without the delta, or with the
+          targets now handed) by a helper thread as soon as the first is
+          met, so that an adapter row joining base rows, or the last one
+          leaving, does not hold every row up for a compile;
+        - a wider set's by the thread of the load that widens it, before
+          a request can name the adapter; a narrower set's by a helper
+          thread after an unload, the wider set in use meanwhile
+          (``_on_resident_targets``).
+
+        Which trace a block runs never depends on any of this."""
+        if self.lora is None:
+            return fn(*args, **statics)
+        variant = tuple(sorted(statics.items()))
+        met = self._decode_traces.get((variant, targets))
+        if met is not None:
+            # Compiled ahead; or being compiled: that is waited for, not
+            # made a second time beside it.
+            met.wait()
+            return fn(*args, **statics)
+        lower = None
+        if variant not in self._decode_variants:
+            # Now, while the donated arguments still are: shapes and
+            # shardings, with the buffers' place left open.
+            shapes = jax.tree_util.tree_map(_abstract, args)
+
+            def lower(bufs):
+                fn.lower(*shapes[:at], bufs, *shapes[at + 1:],
+                         **statics).compile()
+        out = fn(*args, **statics)
+        with self._trace_lock:
+            if lower is not None:
+                self._decode_variants[variant] = lower
+            self._decode_traces.setdefault(
+                (variant, targets), threading.Event()).set()
+        other = self._lora_targets if targets is None else None
+        if other != ():
+            threading.Thread(target=self._retarget, args=(other,),
+                             daemon=True, name="decode-trace-prepare").start()
+        return out
+
+    def _retarget(self, targets: tuple[str, ...] | None,
+                  adopt: bool = False) -> None:
+        """Compile, on the calling thread (never the loop's), the trace
+        with ``targets`` (``None``: without the delta) of every decode
+        variant the loop has met, and wait for those another thread is
+        compiling; with ``adopt``, then make ``targets`` what a block with
+        an adapter row is handed, if they still are the manager's resident
+        targets (a later change of those has made its own call)."""
+        bufs = None if targets is None else jax.tree_util.tree_map(
+            _abstract, lora_lib.select_targets(self.lora.buffers, targets))
+        while True:
+            mine, theirs = [], []
+            with self._trace_lock:
+                # A program handed no target's buffers is none to compile.
+                for variant, lower in (self._decode_variants.items()
+                                       if targets != () else ()):
+                    done = self._decode_traces.get((variant, targets))
+                    if done is None:
+                        done = threading.Event()
+                        self._decode_traces[(variant, targets)] = done
+                        mine.append((lower, done))
+                    elif not done.is_set():
+                        theirs.append(done)
+                if not mine and not theirs:
+                    if adopt and (self.lora.resident_targets()
+                                  == frozenset(targets)):
+                        self._lora_targets = targets
+                    return
+            for lower, done in mine:
+                try:
+                    lower(bufs)
+                except Exception:  # the call itself compiles when it comes
+                    logger.exception(
+                        "preparing a decode program with the adapter "
+                        "targets %s failed", targets)
+                finally:
+                    done.set()
+            for done in theirs:
+                done.wait()
+
+    def _on_resident_targets(self, resident: frozenset[str]) -> None:
+        """``LoRAManager``'s hook (``watch_targets``): the targets the
+        resident adapters carry are now ``resident``.  What a block with an
+        adapter row is handed always holds them all.  A load that brings a
+        target not handed yet waits here, on its own thread and before any
+        request can name its adapter, for the wider set's traces; an unload
+        that takes one away returns at once and a helper thread adopts the
+        narrower set when its traces are compiled."""
+        targets = _in_order(resident)
+        with self._trace_lock:  # not between a helper's check and its word
+            handed = self._lora_targets
+        if resident <= set(handed):
+            if targets != handed:
+                threading.Thread(
+                    target=self._retarget, args=(targets, True), daemon=True,
+                    name="decode-trace-prepare").start()
             return
-        met.add((n_steps, penalized, with_delta))
-        if (n_steps, penalized, other) in met or (
-                other and not self.lora.running_adapters()):
-            return
-        met.add((n_steps, penalized, other))
-
-        def abstract(x):
-            committed = isinstance(x, jax.Array) and x.committed
-            return jax.ShapeDtypeStruct(
-                np.shape(x), x.dtype, sharding=x.sharding if committed
-                else None)
-
-        args = jax.tree_util.tree_map(
-            abstract, (self.params, self.lora.buffers if other else None,
-                       *rest))
-
-        def prepare():
-            try:
-                self._jit_decode.lower(
-                    *args, n_steps=n_steps, penalized=penalized).compile()
-            except Exception:  # the call itself compiles when it comes
-                logger.exception("preparing the decode program %s the "
-                                 "adapter delta failed",
-                                 "with" if other else "without")
-
-        threading.Thread(target=prepare, daemon=True,
-                         name="decode-trace-prepare").start()
+        self._retarget(targets, adopt=True)
 
     def _count_first_token(self, slot_idx: int, tok) -> None:
         """Penalty rows count their prefill-sampled first token too (vLLM
@@ -2185,17 +2281,30 @@ class Engine:
     def _block_lora_buffers(self, staged_i32: np.ndarray):
         """What a decode block reads of the adapters, decided from
         ``staged_i32``, the private copy of the int32 slot buffer that goes
-        up with it (a freed row holds -1): ``(buffers, rows naming an
-        adapter)``, the buffers ``None`` when no row names one.  A slot -1
-        row's delta is an exact 0 (``models/lora.py``), so a block of base
-        rows runs the program traced without the delta and says what the
-        other would; one adapter row among base rows keeps the buffers for
-        the block.  The rule of every decode dispatch, plain and
-        speculative; the prompt programs always take the buffers."""
+        up with it (a freed row holds -1): ``(buffers, their targets, rows
+        naming an adapter)``, buffers and targets ``None`` when no row
+        names one.  A slot -1 row's delta is an exact 0 (``models/lora.py``),
+        so a block of base rows runs the program traced without the delta
+        and says what the other would.  One adapter row among base rows
+        brings the block ``scale`` and the ``{t}_a`` / ``{t}_b`` of
+        ``_lora_targets``: the manager's own arrays, nothing copied, and
+        only of the targets a resident adapter carries, since every other
+        target's hold exact zeros in every slot.  The rule of every decode
+        dispatch, plain and speculative; the prompt programs always take
+        all the buffers."""
         if self.lora is None:
-            return None, 0
+            return None, None, 0
         rows = int(np.count_nonzero(staged_i32[self._lora_span] >= 0))
-        return (self.lora.buffers if rows else None), rows
+        if not rows:
+            return None, None, 0
+        targets = self._lora_targets
+        assert self.lora.targets_of(
+            s.request.adapter for s in self.slots
+            if s is not None and s.lora_slot >= 0) <= set(targets), (
+                "a row names an adapter with a target the block is not "
+                f"handed: {targets}")
+        return (lora_lib.select_targets(self.lora.buffers, targets),
+                targets, rows)
 
     def _phase(self, name: str):
         """The engine thread's phase from here to the end of the ``with``
@@ -3879,7 +3988,7 @@ class Engine:
         self._slot_fresh[:] = 0
         # The verify reads the adapters by the plain block's rule, from
         # the slots that go up with it.
-        lora_bufs, _ = self._block_lora_buffers(staged)
+        lora_bufs, targets, _ = self._block_lora_buffers(staged)
         args = (
             self.params, self.draft_params, lora_bufs,
             self.cache, self.draft_cache,
@@ -3894,8 +4003,9 @@ class Engine:
         with self._enqueue("engine.decode.enqueue"):
             (toks, valid, lps, top_v, top_i, next_tokens, next_positions,
              next_remaining, next_etok, next_epos, next_has,
-             self.cache, self.draft_cache) = self._jit_spec_block(
-                *args, n_cycles=n_cycles, k_steps=k)
+             self.cache, self.draft_cache) = self._traced(
+                self._jit_spec_block, 2, targets, *args,
+                n_cycles=n_cycles, k_steps=k)
         self._dev_tokens = next_tokens
         self._dev_positions = next_positions
         self._dev_remaining = next_remaining

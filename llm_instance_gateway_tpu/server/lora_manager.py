@@ -18,6 +18,7 @@ from __future__ import annotations
 import logging
 import time
 from collections import OrderedDict
+from collections.abc import Callable, Iterable
 from dataclasses import dataclass
 
 import jax.numpy as jnp
@@ -69,6 +70,14 @@ class AdapterInfo:
     # and host->slot one device put.  Excluded from repr (huge).
     weights: dict | None = None
 
+    @property
+    def targets(self) -> frozenset[str]:
+        """The LoRA targets this adapter carries: every other target's slot
+        holds exact zeros (``models.lora.load_adapter``)."""
+        if self.weights is None:  # legacy load path: nothing is known
+            return frozenset(lora_lib.TARGETS)
+        return frozenset(lora_lib.TARGETS).intersection(self.weights)
+
     def __repr__(self):  # keep logs/debug payloads weight-free
         return (f"AdapterInfo(name={self.name!r}, slot={self.slot}, "
                 f"rank={self.rank}, alpha={self.alpha}, "
@@ -117,6 +126,12 @@ class LoRAManager:
         self._adapters: dict[str, AdapterInfo] = {}
         self._active: dict[str, int] = {}  # name -> in-flight request count
         self._free_slots = list(range(cfg.max_lora_slots))
+        # The union of the targets the slot-tier adapters carry, and of a
+        # load under way from the moment it holds its slot: what a decode
+        # block with an adapter row has to read (resident_targets).  The
+        # hook hears of every change of it (watch_targets).
+        self._targets: frozenset[str] = frozenset()
+        self._targets_hook: Callable[[frozenset[str]], None] | None = None
         # Host-RAM tier: name -> (weights numpy pytree, alpha, rank,
         # source), LRU-bounded.  Promotion (host -> slot) skips the Orbax
         # restore entirely — one device put; demotion (slot -> host) copies
@@ -191,6 +206,46 @@ class LoRAManager:
         with self._lock:
             return (dict(self.tier_transitions),
                     {t: list(sc) for t, sc in self.load_seconds.items()})
+
+    def resident_targets(self) -> frozenset[str]:
+        """The LoRA targets some slot-tier adapter carries (the keys of
+        ``AdapterInfo.weights``), and those of a load that has its slot and
+        is not published yet.  Every other target's buffers hold exact zeros
+        in every slot, so a program that serves adapter rows need not be
+        handed them (``Engine._block_lora_buffers``)."""
+        with self._lock:
+            return self._targets
+
+    def targets_of(self, names: Iterable[str]) -> frozenset[str]:
+        """The targets the slot-tier adapters among ``names`` carry."""
+        with self._lock:
+            return frozenset().union(*(
+                self._adapters[n].targets for n in names
+                if n in self._adapters))
+
+    def watch_targets(
+            self, hook: Callable[[frozenset[str]], None] | None) -> None:
+        """``hook(resident_targets)`` is called, on the thread of the
+        residency verb and outside ``_lock``, whenever that set changes:
+        by ``load`` BEFORE the adapter is published (no request can name it
+        until the hook returns, so a hook may take its time to get ready
+        for a wider set), by ``unload`` and ``demote`` after the slot is
+        zeroed."""
+        self._targets_hook = hook
+
+    def _retarget(self, loading: frozenset[str] = frozenset()) -> bool:
+        """Recount ``_targets`` from the slot tier and a load under way;
+        True if it changed.  Caller holds self._lock."""
+        before = self._targets
+        self._targets = loading.union(*(
+            info.targets for info in self._adapters.values()))
+        return self._targets != before
+
+    def _targets_changed(self) -> None:
+        """Tell the hook.  Caller holds ``_mutate_lock`` (the set cannot
+        move under the hook) and not ``_lock``."""
+        if self._targets_hook is not None:
+            self._targets_hook(self.resident_targets())
 
     def _note_transition(self, frm: str, to: str) -> None:
         """Caller holds self._lock."""
@@ -312,18 +367,27 @@ class LoRAManager:
                 self.buffers = self._pin(lora_lib.load_adapter(
                     self.buffers, self.cfg, slot, weights, alpha, rank
                 ))
+                info = AdapterInfo(
+                    name=name, slot=slot, rank=rank, alpha=alpha,
+                    source=source, weights=weights,
+                )
+                # A load that widens the resident targets waits here, on
+                # its own thread, for whoever serves them to be ready
+                # (the engine compiles the wider decode programs), and
+                # only then becomes visible to acquire() and the gateway.
+                with self._lock:
+                    widened = self._retarget(loading=info.targets)
+                if widened:
+                    self._targets_changed()
             except Exception:
                 with self._lock:
                     self._free_slots.insert(0, slot)
                     if cached is not None:  # promotion failed: keep the copy
                         self._host[name] = cached
+                    self._retarget()
                 raise
             if timed_tier is not None:
                 self._note_load(timed_tier, self._clock() - t0)
-            info = AdapterInfo(
-                name=name, slot=slot, rank=rank, alpha=alpha,
-                source=source, weights=weights,
-            )
             with self._lock:
                 self._adapters[name] = info
                 self._note_transition(from_tier, TIER_SLOT)
@@ -356,6 +420,9 @@ class LoRAManager:
             with self._lock:
                 self._free_slots.append(info.slot)
                 self._note_transition(TIER_SLOT, TIER_DISK)
+                narrowed = self._retarget()
+            if narrowed:
+                self._targets_changed()
         logger.info("unloaded adapter %s from slot %d", name, info.slot)
         return True
 
@@ -399,6 +466,9 @@ class LoRAManager:
                 self._host_put(name, info.weights, info.alpha, info.rank,
                                info.source)
                 self._note_transition(TIER_SLOT, TIER_HOST)
+                narrowed = self._retarget()
+            if narrowed:
+                self._targets_changed()
         logger.info("demoted adapter %s: slot %d -> host RAM", name,
                     info.slot)
         return True

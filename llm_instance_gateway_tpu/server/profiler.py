@@ -168,6 +168,11 @@ class StepProfiler:
         # the LoRA delta: no row of the block named an adapter on an engine
         # that holds adapter buffers (0 on one that holds none).
         self.lora_free_steps = 0
+        # LoRA targets whose buffers the plain decode dispatches with the
+        # delta were handed, summed over their steps: over those steps, the
+        # targets a delta step reads (all seven where a resident adapter
+        # carries them all).
+        self.lora_target_reads = 0
         # Cache rows the latent (MLA) decode kernel had to read: the live
         # rows' cache lengths, summed over the steps of the plain decode
         # dispatches.  0 for a model with per-head K/V lanes.
@@ -443,6 +448,12 @@ class StepProfiler:
         with self._lock:
             self.lora_free_steps += n
 
+    def note_lora_target_reads(self, n: int) -> None:
+        """Count ``n`` LoRA targets handed over the steps of one plain
+        decode dispatch that ran with the delta (targets x steps)."""
+        with self._lock:
+            self.lora_target_reads += n
+
     def note_latent_positions(self, n: int) -> None:
         """Count ``n`` cache positions a latent model's live rows held over
         the steps of one plain decode dispatch."""
@@ -489,6 +500,7 @@ class StepProfiler:
                 "stage_ops": self.stage_ops,
                 "lora_rows": self.lora_rows,
                 "lora_free_steps": self.lora_free_steps,
+                "lora_target_reads": self.lora_target_reads,
                 "latent_positions": self.latent_positions,
                 "ssm_rows": self.ssm_rows,
                 "kv_positions": dict(zip(KV_LANES, self.kv_positions)),
@@ -573,6 +585,10 @@ def render_profile(hist: dict) -> list[str]:
     if "lora_free_steps" in hist:
         lines += ["# TYPE tpu:lora_free_steps_total counter",
                   f"tpu:lora_free_steps_total {hist['lora_free_steps']}"]
+    if "lora_target_reads" in hist:
+        lines += ["# TYPE tpu:lora_target_reads_total counter",
+                  "tpu:lora_target_reads_total "
+                  f"{hist['lora_target_reads']}"]
     if "latent_positions" in hist:
         lines += ["# TYPE tpu:latent_kv_positions_total counter",
                   "tpu:latent_kv_positions_total "

@@ -87,10 +87,12 @@ def make_engine(model, adapters: bool = True, **extra) -> Engine:
 class Dispatches:
     """Every decode dispatch of ``engine`` as ``(kind, handed the adapter
     buffers, rows of the staged slots naming an adapter, steps, a block was
-    in flight)``, recorded at the jitted call itself."""
+    in flight)``, recorded at the jitted call itself; beside each, in
+    ``bufs``, the adapter buffers it was handed (the dict itself)."""
 
     def __init__(self, engine: Engine):
         self.seen: list[tuple] = []
+        self.bufs: list[dict | None] = []
         self.plain = engine._jit_decode
 
         def decode(params, lora_bufs, cache, i32, *rest, n_steps, **kw):
@@ -98,10 +100,11 @@ class Dispatches:
             self.seen.append(("decode", lora_bufs is not None,
                               int((slots >= 0).sum()), n_steps,
                               engine._inflight is not None))
+            self.bufs.append(lora_bufs)
             return self.plain(params, lora_bufs, cache, i32, *rest,
                               n_steps=n_steps, **kw)
 
-        decode.lower = self.plain.lower  # _prepare_other_trace's way in
+        decode.lower = self.plain.lower  # Engine._traced prepares by it
         engine._jit_decode = decode
         if engine._spec:
             spec = engine._jit_spec_block
@@ -112,8 +115,10 @@ class Dispatches:
                                   int((slots >= 0).sum()),
                                   kw["n_cycles"] * (kw["k_steps"] + 1),
                                   engine._inflight is not None))
+                self.bufs.append(args[2])
                 return spec(*args, **kw)
 
+            spec_block.lower = spec.lower
             engine._jit_spec_block = spec_block
 
 

@@ -716,35 +716,51 @@ class TestPhaseReport:
             "overlapped_pct": 0.0}
 
     def test_report_prints_adapter_rows_per_dispatch(self):
-        """``tpu:lora_rows_total`` (``note_lora_rows``) and
-        ``tpu:lora_free_steps_total`` (``note_lora_free_steps``): in
+        """``tpu:lora_rows_total`` (``note_lora_rows``),
+        ``tpu:lora_free_steps_total`` (``note_lora_free_steps``) and
+        ``tpu:lora_target_reads_total`` (``note_lora_target_reads``): in
         ``/metrics``, in ``/debug/profile``'s ``hist``, and over the decode
-        dispatches in the report; the second left out, and then both, for
-        payloads from before each counter."""
+        dispatches in the report; the third left out, then the second, then
+        all, for payloads from before each counter."""
         clock = FakeClock()
         p = StepProfiler(capacity=8, clock=clock)
         for rows in (2, 0, 3, 1):
             p.note_lora_rows(rows)
             if not rows:
                 p.note_lora_free_steps(1)
+            else:
+                p.note_lora_target_reads(2)
             p.note_dispatch("decode", clock.now, 0.01, active=3,
                             total_slots=4)
             clock.tick(0.02)
         assert p.snapshot()["hist"]["lora_rows"] == 6
         assert p.snapshot()["hist"]["lora_free_steps"] == 1
+        assert p.snapshot()["hist"]["lora_target_reads"] == 6
         row = profile_report.lora_rows_row(p.snapshot())
         assert row == {"lora_rows": 6, "decode_dispatches": 4,
                        "rows_per_dispatch": 1.5, "lora_free_steps": 1,
-                       "free_steps_per_dispatch": 0.25}
+                       "free_steps_per_dispatch": 0.25,
+                       "lora_target_reads": 6,
+                       "target_reads_per_dispatch": 1.5}
         out = profile_report.render_report(p.snapshot())
         assert "Adapter rows in the decode steps" in out and "1.5" in out
         assert "free_steps_per_dispatch" in out and "0.25" in out
+        assert "target_reads_per_dispatch" in out
         lines = render_profile(p.hist_state())
+        assert "# TYPE tpu:lora_target_reads_total counter" in lines
+        assert "tpu:lora_target_reads_total 6" in lines
+        old = p.snapshot()
+        del old["hist"]["lora_target_reads"]
+        assert profile_report.lora_rows_row(old) == {
+            "lora_rows": 6, "decode_dispatches": 4, "rows_per_dispatch": 1.5,
+            "lora_free_steps": 1, "free_steps_per_dispatch": 0.25}
+        assert "target_reads" not in profile_report.render_report(old)
+        assert not any("lora_target_reads" in ln
+                       for ln in render_profile(old["hist"]))
         assert "# TYPE tpu:lora_rows_total counter" in lines
         assert "tpu:lora_rows_total 6" in lines
         assert "# TYPE tpu:lora_free_steps_total counter" in lines
         assert "tpu:lora_free_steps_total 1" in lines
-        old = p.snapshot()
         del old["hist"]["lora_free_steps"]
         assert profile_report.lora_rows_row(old) == {
             "lora_rows": 6, "decode_dispatches": 4, "rows_per_dispatch": 1.5}

@@ -180,14 +180,18 @@ def stage_ops_row(profile: dict) -> dict:
 
 def lora_rows_row(profile: dict) -> dict:
     """Live rows with a LoRA slot, summed over the decode steps
-    (``tpu:lora_rows_total``), and the decode steps that ran without the
-    adapter delta (``tpu:lora_free_steps_total``; left out for a payload
-    from before that counter); per dispatch is per step, and the second a
-    share of the steps, where dispatches are one step long."""
+    (``tpu:lora_rows_total``), the decode steps that ran without the
+    adapter delta (``tpu:lora_free_steps_total``) and the adapter targets
+    the other steps were handed (``tpu:lora_target_reads_total``; each left
+    out for a payload from before that counter); per dispatch is per step,
+    and the second a share of the steps, where dispatches are one step
+    long."""
     row = _per_decode_dispatch(profile, "lora_rows", "rows_per_dispatch")
     if row:
         row.update(_per_decode_dispatch(profile, "lora_free_steps",
                                         "free_steps_per_dispatch"))
+        row.update(_per_decode_dispatch(profile, "lora_target_reads",
+                                        "target_reads_per_dispatch"))
     return row
 
 
@@ -697,12 +701,15 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
                                    "overlapped_pct"))]
     adapter_rows = lora_rows_row(profile)
     if adapter_rows:
-        out += ["", "Adapter rows in the decode steps, and the steps run "
-                "without the adapter delta:",
+        out += ["", "Adapter rows in the decode steps, the steps run "
+                "without the adapter delta, and the targets the others "
+                "were handed:",
                 _table([adapter_rows], tuple(
                     k for k in ("lora_rows", "decode_dispatches",
                                 "rows_per_dispatch", "lora_free_steps",
-                                "free_steps_per_dispatch")
+                                "free_steps_per_dispatch",
+                                "lora_target_reads",
+                                "target_reads_per_dispatch")
                     if k in adapter_rows))]
     latent = latent_positions_row(profile)
     if latent:
