@@ -511,6 +511,11 @@ CLASSES = (
                         note="recurrent states the decode steps rewrote: "
                              "the engine thread adds at each dispatch, the "
                              "scrape reads under the lock"),
+            SharedField("conv_rows", LOCK_GUARDED,
+                        writers=("note_conv_rows",),
+                        note="conv states the decode steps rewrote: the "
+                             "engine thread adds at each dispatch, the "
+                             "scrape reads under the lock"),
             SharedField("kv_positions", LOCK_GUARDED,
                         writers=("note_kv_positions",),
                         note="cache positions the decode steps read by kind "

@@ -427,6 +427,13 @@ SERVER_FAMILIES = (
            "kernel reads and writes per layer. 0 for a model without a "
            "mixer.",
            SERVER_SURFACE),
+    Family("tpu:conv_state_rows_total", "counter", (),
+           "Rows whose conv state (the last inputs of a gated short "
+           "convolution, models/shortconv.py) a decode step shifted and "
+           "rewrote, summed over the steps of the plain decode dispatches: "
+           "over tpu:dispatch_steps_sum, the rows one decode step's conv "
+           "layers update, a layer. 0 for a model without conv layers.",
+           SERVER_SURFACE),
     Family("tpu:kv_positions_read_total", "counter", ("lanes",),
            "Cache positions the attention of the plain decode dispatches' "
            "steps read of the live rows' lanes, a layer of the kind: "
@@ -434,7 +441,10 @@ SERVER_FAMILIES = (
            "lanes=window at most the window of each (a sliding-window "
            "layer's ring); over tpu:dispatch_steps_sum, the positions one "
            "decode step's kernel reads per layer of the kind. 0 for a model "
-           "without a window; metrics_registry.KV_LANES.",
+           "all of whose layers hold full lanes (it counts for a stack with "
+           "a window, lanes=full and lanes=window, and for one with conv "
+           "layers, lanes=full of its attention layers); "
+           "metrics_registry.KV_LANES.",
            SERVER_SURFACE),
     Family("tpu:decode_attn_grid_steps_total", "counter", (),
            "Grid steps the decode-attention kernel walks a layer's call, "

@@ -21,6 +21,7 @@ projection tiles cleanly onto the systolic array.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
 from typing import NamedTuple
 
@@ -30,11 +31,19 @@ def pad_to(x: int, multiple: int) -> int:
 
 
 class LayerKind(NamedTuple):
-    """What the attention of one layer of a stack is: ``window`` > 0 lets
-    position i attend j iff 0 <= i - j < window (0: every j <= i), ``rope``
-    says whether q and k get the rotary position encoding at all."""
+    """What the operator of one layer of a stack is.  Attention: ``window``
+    > 0 lets position i attend j iff 0 <= i - j < window (0: every j <= i),
+    ``rope`` says whether q and k get the rotary position encoding at all.
+    ``conv``: no attention at all but a gated short convolution
+    (``models/shortconv.py``), whose layer holds no K and V."""
     window: int = 0
     rope: bool = True
+    conv: bool = False
+
+    @property
+    def cache(self) -> str:
+        """Which of a decode cache's stacks the layer's rows live in."""
+        return "conv" if self.conv else "ring" if self.window else "full"
 
 
 MLP_ACTIVATIONS = ("silu", "gelu", "relu")
@@ -79,6 +88,9 @@ class ModelConfig:
     # OLMoE: RMSNorm on the whole projected q and k vectors (all heads
     # together), after the projection and before the split into heads.
     qk_norm: bool = False
+    # LFM2: RMSNorm over the ``head_dim`` numbers of EACH head of q and k,
+    # one weight vector of ``head_dim`` for all heads, before RoPE.
+    qk_norm_head: bool = False
     # Latent attention (MLA; GLM-4.7-Flash, ``glm4_moe_lite``):
     # ``kv_lora_rank`` > 0 makes every layer's keys and values come out of
     # ONE normed latent of that width plus ONE rope key of
@@ -105,6 +117,8 @@ class ModelConfig:
     first_k_dense: int = 0
     router_sigmoid: bool = False
     routed_scaling_factor: float = 1.0
+    # What a sigmoid router adds to the chosen scores' sum before it divides.
+    router_gate_eps: float = 1e-20
     # A stack of more than one kind of attention layer (SmallThinker,
     # ``smallthinker``): ``layer_pattern`` is the PERIOD of kinds the stack
     # repeats, each "full" (causal, RoPE), "nope" (causal, no position
@@ -121,6 +135,22 @@ class ModelConfig:
     layer_pattern: tuple[str, ...] = ()
     sliding_window: int = 0
     router_pre_attention: bool = False
+    # Layers WITHOUT attention (LFM2, ``lfm2_moe``): a "conv" layer of the
+    # pattern runs a gated short convolution in attention's place
+    # (``models/shortconv.py``: in_proj to B | C | u, z = B * u, a causal
+    # depthwise conv of ``conv_kernel`` taps over z, C * that, out_proj).
+    # It holds no K and V: ``k``/``v`` are the ATTENTION layers' alone and
+    # the cache keeps the conv's last ``conv_kernel - 1`` inputs a slot in
+    # ``conv`` [L_conv, conv_kernel - 1, B, d_model].  The pattern is counted
+    # from layer 0 of the MODEL: a group of layers that starts at layer f
+    # (the sparse layers after ``first_k_dense`` dense ones) runs the
+    # pattern rotated by f, and a depth that ends part of a period in (the
+    # published 40 = 2 + 9 x 4 + 2) runs the layers left over as a span of
+    # their own (``group_spans``).  The parameters of a group are one stack
+    # a kind: the attention leaves stacked over the attention layers, the
+    # conv leaves over the conv layers, the rest over all.  Its heads may be
+    # narrower than a vreg's 128 lanes (``kv_pack``).
+    conv_kernel: int = 0
     # A state-space mixer beside attention in every layer (Falcon-H1,
     # ``falcon_h1``; the Mamba-2 form, ``models/ssm.py`` holds the
     # equations): ``ssm_d_inner`` > 0 makes the block parallel,
@@ -177,21 +207,28 @@ class ModelConfig:
         if self.mlp_activation not in MLP_ACTIVATIONS:
             raise ValueError(f"mlp_activation {self.mlp_activation!r}: one "
                              f"of {MLP_ACTIVATIONS}")
-        unknown = set(self.layer_pattern) - {"full", "nope", "window"}
+        unknown = set(self.layer_pattern) - {"full", "nope", "window", "conv"}
         if unknown:
             raise ValueError(f"layer_pattern names {sorted(unknown)}")
         if ("window" in self.layer_pattern) != bool(self.sliding_window):
             raise ValueError("a window layer needs sliding_window > 0, and "
                              "sliding_window a window layer")
-        if self.n_layers % len(self.layer_kinds):
+        if ("conv" in self.layer_pattern) != bool(self.conv_kernel):
+            raise ValueError("a conv layer needs conv_kernel > 0, and "
+                             "conv_kernel a conv layer")
+        period = len(self.layer_kinds)
+        # (A stack with conv layers runs what a depth leaves over of a
+        # period as a span of its own, ``group_spans``: LFM2's published
+        # depth ends half a period in.  Ring lanes are counted in whole
+        # periods.)
+        if self.n_layers % period and not self.conv_kernel:
             raise ValueError(
-                f"{self.n_layers} layers are not whole periods of "
-                f"{len(self.layer_kinds)}")
-        if len(self.layer_kinds) > 1 and (
-                self.first_k_dense or self.latent_width or self.ssm_d_inner):
+                f"{self.n_layers} layers are not whole periods of {period}")
+        if period > 1 and (self.latent_width or self.ssm_d_inner or (
+                self.first_k_dense and not self.conv_kernel)):
             raise NotImplementedError(
-                "a period of layer kinds beside leading dense layers, a "
-                "latent cache or a mixer")
+                "a period of layer kinds beside a latent cache or a mixer, "
+                "or of attention kinds beside leading dense layers")
 
     @property
     def gelu_mlp(self) -> bool:
@@ -199,16 +236,66 @@ class ModelConfig:
 
     @property
     def layer_kinds(self) -> tuple[LayerKind, ...]:
-        """The period of the stack, one ``LayerKind`` a layer of it."""
+        """The period of the stack, one ``LayerKind`` a layer of it, counted
+        from layer 0 of the model."""
         return tuple(LayerKind(self.sliding_window if k == "window" else 0,
-                               k != "nope")
+                               k != "nope", k == "conv")
                      for k in self.layer_pattern or ("full",))
 
     @property
-    def n_window_layers(self) -> int:
+    def n_dense_layers(self) -> int:
+        """Leading layers that keep a dense MLP in a sparse model."""
+        return self.first_k_dense if self.n_experts else 0
+
+    def group_spans(self, first: int, n: int
+                    ) -> list[tuple[int, int, tuple[LayerKind, ...]]]:
+        """The ``n`` layers from layer ``first`` on as spans (first layer,
+        layers, the period of kinds the span repeats): whole periods of the
+        model's pattern rotated by ``first``, or ONE kind where all ``n``
+        are of it (LFM2's two leading dense layers, conv and conv); and,
+        where the depth ends part of a period in, the layers left over as
+        a last span, one run of what they are."""
+        kinds = tuple(self.kind_of(first + j) for j in range(n))
+        if len(set(kinds)) == 1:
+            return [(first, n, kinds[:1])]
+        period = len(self.layer_kinds)
+        whole = n - n % period
+        spans = [(first, whole, kinds[:period])] if whole else []
+        if whole < n:
+            rest = kinds[whole:]
+            spans.append((first + whole, n - whole,
+                          rest[:1] if len(set(rest)) == 1 else rest))
+        return spans
+
+    def kind_of(self, layer: int) -> LayerKind:
         kinds = self.layer_kinds
-        return (self.n_layers // len(kinds)) * sum(
-            1 for k in kinds if k.window)
+        return kinds[layer % len(kinds)]
+
+    def n_layers_of(self, cache: str) -> int:
+        """How many of the model's layers keep their rows in the decode
+        cache's ``cache`` stack ("full", "ring" or "conv")."""
+        return sum(self.kind_of(l).cache == cache
+                   for l in range(self.n_layers))
+
+    @property
+    def n_window_layers(self) -> int:
+        return self.n_layers_of("ring")
+
+    @property
+    def kv_pack(self) -> int:
+        """How many kv heads lie side by side in one cache row [..,
+        n_kv_heads / kv_pack, kv_pack * head_dim], which is how the cache is
+        allocated, written and read (``ops.attention.pack_heads``): heads
+        narrower than a vreg's 128 lanes as many as fill them (LFM2's 64:
+        two), or as the kv heads allow; 1: a row a head.  Only a stack with
+        conv layers packs: the engine serves it from contiguous bf16 lanes
+        on one device and nothing else, and every other holder of K and V
+        (the paged pool, int8 lanes, ``extend_step``, the handoff wire, a
+        mesh) indexes a row by its head."""
+        hd = self.resolved_head_dim
+        if not self.conv_kernel or hd >= 128 or 128 % hd:
+            return 1
+        return math.gcd(128 // hd, self.n_kv_heads)
 
     @property
     def resolved_head_dim(self) -> int:
@@ -507,6 +594,45 @@ TINY_SMALLTHINKER_TEST = replace(
     d_model=64, n_layers=8, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=32,
     n_experts=8, n_experts_per_token=3, sliding_window=16, max_seq_len=128,
     max_lora_rank=4)
+
+# LiquidAI/LFM2-24B-A2B (``lfm2_moe``): layer l is attention iff l % 4 == 2
+# (32 query / 8 kv heads of 64, a per-head QK-norm, RoPE), else a gated short
+# convolution of 3 taps; layers 0-1 a dense MLP of 11,776, the others 64
+# experts of 1,536, top-4 of sigmoid score + bias, renormalised; the head is
+# tied to the embedding.  Two 64-wide kv heads share a cache row.
+LFM2_24B_A2B = ModelConfig(
+    name="lfm2-24b-a2b",
+    vocab_size=65_536,
+    d_model=2048,
+    n_layers=40,
+    n_heads=32,
+    n_kv_heads=8,
+    d_ff=11_776,
+    head_dim=64,
+    rope_theta=1_000_000.0,
+    norm_eps=1e-5,
+    tie_embeddings=True,
+    n_experts=64,
+    n_experts_per_token=4,
+    norm_topk_prob=True,
+    qk_norm_head=True,
+    moe_d_ff=1536,
+    first_k_dense=2,
+    router_sigmoid=True,
+    routed_scaling_factor=1.0,
+    router_gate_eps=1e-6,
+    layer_pattern=("conv", "conv", "full", "conv"),
+    conv_kernel=3,
+    max_seq_len=128_000,
+    max_lora_slots=0,  # adapters are not served over a period-scanned stack
+)
+
+# The CPU's LFM2: 2 dense layers and 2 periods, 4 queries over 2 kv heads of
+# 16 (a packed row of 32), 8 experts top-2.
+TINY_LFM2_TEST = replace(
+    LFM2_24B_A2B, name="lfm2-tiny", vocab_size=320, d_model=64, n_layers=10,
+    n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128, moe_d_ff=32, n_experts=8,
+    n_experts_per_token=2, max_seq_len=256, max_lora_rank=4)
 
 TINY_TEST = LLAMA3_8B.tiny()
 TINY_MOE_TEST = MIXTRAL_8X7B.tiny()

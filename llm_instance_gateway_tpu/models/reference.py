@@ -78,6 +78,25 @@ period of layer kinds, a router that reads the block's INPUT, ReLU gating:
         sum of the chosen (norm_topk_prob)
     x = x + sum_i w_i * (relu(h_n Wg_i) * (h_n Wu_i)) Wd_i
 
+LFM2 (``lfm2_moe``, ``conv_kernel`` > 0): layer l of the MODEL is of the kind
+``layer_pattern[l % period]``; a "conv" layer has no attention but a gated
+short convolution; the attention layers' QK-norm is per head; the head is
+the embedding transposed:
+
+    kind "conv":  [B | C | u] = x_n W_in (split in this order);  z_t = B_t * u_t
+                  c_t = sum_j w[j] * z_{t-(K-1)+j}    K = conv_kernel taps,
+                      depthwise, causal, z = 0 before position 0, no bias, no
+                      activation: an explicit sum over K shifted copies
+                  x = x + (C_t * c_t) W_out
+    kind "full":  q_h = RMSNorm_g_q(q_h), k_h = RMSNorm_g_k(k_h) over the hd
+                  numbers of EACH head (one g of hd for all heads), before
+                  RoPE; the rest as above
+    sparse:       GLM's rule, g_i = scale * s_i / (sum of the chosen s +
+                  router_gate_eps) (1e-6), no shared expert
+    logits = RMSNorm(x) E^T
+    The leaves of the attention lie stacked over the attention layers of
+    their group alone, the conv operator's over its conv layers.
+
 A LoRA adapter adds ``scale * (z A) B`` to a projection of ``z``.
 
 Departures from the published descriptions, each on purpose:
@@ -134,16 +153,39 @@ def _lora(z, lora, layer, target):
     return bufs["scale"][slot].astype(F32) * ((z @ a) @ b)
 
 
+def _pattern(cfg, layer):
+    """The kind of layer ``layer`` of the MODEL, by name."""
+    pattern = cfg.layer_pattern or ("full",)
+    return pattern[layer % len(pattern)]
+
+
 def _kind(cfg, layer):
     """(window, rope) of layer ``layer``: 0 = every earlier position."""
-    pattern = cfg.layer_pattern or ("full",)
-    name = pattern[layer % len(pattern)]
+    name = _pattern(cfg, layer)
     return (cfg.sliding_window if name == "window" else 0), name != "nope"
 
 
-def _attention(cfg, lp, layer, x_n, lora):
+def _short_conv(cfg, lp, layer, x_n, states=None):
+    """LFM2's gated short convolution; ``layer`` counts the group's conv
+    layers.  ``states``, a list, gets the layer's z of the last K - 1
+    positions [K - 1, D] appended (zeros before position 0)."""
+    s, taps = x_n.shape[0], cfg.conv_kernel
+    b, c, u = jnp.split(x_n @ _weight(lp["conv_in"], layer), 3, axis=-1)
+    z = b * u
+    w = lp["conv_w"][layer].astype(F32)  # [taps, D]
+    padded = jnp.concatenate([jnp.zeros((taps - 1, z.shape[1]), F32), z])
+    conv = sum(w[j] * padded[j:j + s] for j in range(taps))
+    if states is not None:
+        states.append(padded[s:])
+    return (c * conv) @ _weight(lp["conv_out"], layer)
+
+
+def _attention(cfg, lp, layer, x_n, lora, model_layer=None):
+    """``layer`` counts the leaves' stack; ``model_layer`` (where they
+    differ: a model whose attention leaves skip its conv layers) names the
+    layer's kind."""
     s = x_n.shape[0]
-    window, rope = _kind(cfg, layer)
+    window, rope = _kind(cfg, layer if model_layer is None else model_layer)
     x_n = x_n * cfg.attention_in_multiplier
     hd = cfg.head_dim or cfg.d_model // cfg.n_heads
     proj = {}
@@ -156,6 +198,9 @@ def _attention(cfg, lp, layer, x_n, lora):
         proj[t] = z * cfg.key_multiplier if t == "k" else z
     q = proj["q"].reshape(s, cfg.n_heads, hd)
     k = proj["k"].reshape(s, cfg.n_kv_heads, hd)
+    if cfg.qk_norm_head:
+        q = _rms_norm(q, lp["q_norm"][layer].astype(F32), cfg.norm_eps)
+        k = _rms_norm(k, lp["k_norm"][layer].astype(F32), cfg.norm_eps)
     if rope:
         q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
     v = proj["v"].reshape(s, cfg.n_kv_heads, hd)
@@ -258,7 +303,8 @@ def _mlp(cfg, lp, layer, h_n, lora, x=None):
     w = jnp.where(pick >= kth, p, 0.0)
     if cfg.router_sigmoid:
         if cfg.norm_topk_prob:
-            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+            w = w / (jnp.sum(w, axis=-1, keepdims=True)
+                     + cfg.router_gate_eps)
         w = w * cfg.routed_scaling_factor
     elif cfg.norm_topk_prob:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
@@ -280,17 +326,20 @@ def forward(cfg, params, tokens, lora=None, states=None):
     layout; int8 leaves allowed).  ``lora``: None, or ``(buffers, slot)``,
     the serving LoRA buffers and the slot whose adapter this sequence uses.
     ``states``: None, or a list that gets each layer's recurrent state after
-    the last position (a model with a mixer), to hold a cache's against.
+    the last position (a model with a mixer) or each CONV layer's last
+    K - 1 inputs z (a model with conv layers), to hold a cache's against.
     """
-    if (cfg.tie_embeddings or cfg.embedding_scale or cfg.norm_plus_one
+    if (cfg.embedding_scale or cfg.norm_plus_one
             or cfg.gelu_mlp or cfg.rope_scaling_factor):
         raise NotImplementedError(
             "the reference covers the Llama/Qwen2/Mixtral/OLMoE/GLM/Falcon-H1/"
-            "SmallThinker block; the "
+            "SmallThinker/LFM2 block; the "
             f"Gemma conventions and rope scaling of {cfg.name} are not in it")
-    if (cfg.kv_lora_rank or cfg.ssm_d_inner) and lora is not None:
+    if (cfg.kv_lora_rank or cfg.ssm_d_inner or cfg.conv_kernel) and (
+            lora is not None):
         raise NotImplementedError(
-            "no adapter over latent projections or beside a mixer")
+            "no adapter over latent projections, beside a mixer or over "
+            "conv layers")
     # (stack, index in it) of every layer: leading dense layers, if the
     # model has them, lie in a stack of their own.
     n_dense = (params["dense_layers"]["attn_norm"].shape[0]
@@ -298,17 +347,31 @@ def forward(cfg, params, tokens, lora=None, states=None):
     stack = ([(params["dense_layers"], i) for i in range(n_dense)]
              + [(params["layers"], i)
                 for i in range(cfg.n_layers - n_dense)])
+    # A layer's place among the layers of ITS kind (conv or not) of its
+    # group: where a model with conv layers keeps that kind's leaves.
+    of_kind, seen = [], {}
+    for l, (lp, _) in enumerate(stack):
+        key = (id(lp), _pattern(cfg, l) == "conv")
+        of_kind.append(seen.get(key, 0))
+        seen[key] = of_kind[-1] + 1
     with jax.default_matmul_precision("highest"):
         x = params["embed"][tokens].astype(F32) * cfg.embedding_multiplier
-        for lp, layer in stack:
+        for l, (lp, layer) in enumerate(stack):
             x_n = _rms_norm(x, lp["attn_norm"][layer].astype(F32), cfg.norm_eps)
-            branches = (_latent_attention(cfg, lp, layer, x_n)
-                        if cfg.kv_lora_rank
-                        else _attention(cfg, lp, layer, x_n, lora))
+            if _pattern(cfg, l) == "conv":
+                branches = _short_conv(cfg, lp, of_kind[l], x_n, states)
+            elif cfg.conv_kernel:
+                branches = _attention(cfg, lp, of_kind[l], x_n, lora, l)
+            else:
+                branches = (_latent_attention(cfg, lp, layer, x_n)
+                            if cfg.kv_lora_rank
+                            else _attention(cfg, lp, layer, x_n, lora))
             if cfg.ssm_d_inner:
                 branches = branches + _mixer(cfg, lp, layer, x_n, states)
             block_in, x = x, x + branches
             h_n = _rms_norm(x, lp["mlp_norm"][layer].astype(F32), cfg.norm_eps)
             x = x + _mlp(cfg, lp, layer, h_n, lora, block_in)
         x = _rms_norm(x, params["final_norm"].astype(F32), cfg.norm_eps)
-        return (x @ _weight(params["lm_head"])) * cfg.lm_head_multiplier
+        head = (params["embed"].astype(F32).T if cfg.tie_embeddings
+                else _weight(params["lm_head"]))
+        return (x @ head) * cfg.lm_head_multiplier

@@ -136,12 +136,21 @@ def conv_window(cfg: ModelConfig, lp, padded):
     return jax.nn.silu(acc).astype(padded.dtype)
 
 
-def conv_tail(cfg: ModelConfig, padded, n_true):
-    """The conv's history after ``n_true`` [B] true positions of ``padded``
-    [B, S + K - 1, C]: the last K - 1 TRUE inputs (``padded``'s own history
-    where the row is shorter than that)."""
-    idx = n_true[:, None] + jnp.arange(cfg.ssm_d_conv - 1)[None]  # [B, K-1]
+def conv_tail(padded, n_true, taps: int):
+    """The history of a causal conv of ``taps`` taps after ``n_true`` [B]
+    true positions of ``padded`` [B, S + K - 1, C]: the last K - 1 TRUE
+    inputs (``padded``'s own history where the row is shorter than that).
+    The mixer's conv and LFM2's short convolution (``models/shortconv.py``)
+    both cut their tails here."""
+    idx = n_true[:, None] + jnp.arange(taps - 1)[None]  # [B, K-1]
     return jnp.take_along_axis(padded, idx[..., None], axis=1)
+
+
+def true_lengths(live, b: int, s: int):
+    """[B] true positions of each row of a (padded) prompt: ``live``
+    [B, S] bool marks them, and they lead (None: all ``s``)."""
+    return (jnp.full((b,), s, jnp.int32) if live is None
+            else jnp.sum(live, axis=1, dtype=jnp.int32))
 
 
 def split_xbc(cfg: ModelConfig, xbc):
@@ -264,9 +273,7 @@ def prompt_mix(cfg: ModelConfig, lp, hn, live=None, history=None, h0=None):
         history = jnp.zeros((b, cfg.ssm_d_conv - 1, xbc.shape[-1]),
                             xbc.dtype)
     padded = jnp.concatenate([history.astype(xbc.dtype), xbc], axis=1)
-    n_true = (jnp.full((b,), s, jnp.int32) if live is None
-              else jnp.sum(live, axis=1, dtype=jnp.int32))
-    tail = conv_tail(cfg, padded, n_true)
+    tail = conv_tail(padded, true_lengths(live, b, s), cfg.ssm_d_conv)
     x, bm, cm = split_xbc(cfg, conv_window(cfg, lp, padded))
     y, h = scan_chunked(cfg, x, step_size(lp, dt_raw, live), _a(lp), bm, cm,
                         lp["ssm_d"], h0)

@@ -1,4 +1,4 @@
-"""Core decoder-only transformer: one implementation, eight families.
+"""Core decoder-only transformer: one implementation, nine families.
 
 Covers Llama-3 (RoPE+GQA+SwiGLU), Gemma (tied embeddings, sqrt(d) embedding
 scale, GeLU gate, (1+w) RMSNorm, shared KV head), Qwen2 (QKV bias), Mixtral
@@ -12,6 +12,11 @@ the K/V lanes; fixed muP multipliers) and SmallThinker (a PERIOD of layer
 kinds, full attention without a position encoding and RoPE layers with a
 sliding window, scanned a period a step over a cache of two kinds: full
 lanes and ring lanes; a router that reads the block's input; ReLU gating)
+and LFM2 (layers WITHOUT attention: a gated short convolution in its place in
+three layers of four, ``models/shortconv.py``, whose per-slot state rides the
+cache and the carry beside the K/V lanes of the attention layers alone; the
+leaves of a period one stack a kind; 64-wide heads, two to a cache row, with
+a per-head QK-norm; leading dense layers before a rotated period)
 via ``ModelConfig`` flags.
 
 TPU-first structure:
@@ -32,7 +37,9 @@ TPU-first structure:
   lanes), attn.out, mlp, moe.route / .dispatch /
   .experts / .shared, lora, lm_head, kv.insert; a latent model's attn.q_latent,
   attn.kv_latent, attn.absorb, attn.expand; a mixer's ssm.in_proj, ssm.conv,
-  ssm.scan, ssm.update, ssm.gate_norm, ssm.out_proj): the scope is in each
+  ssm.scan, ssm.update, ssm.gate_norm, ssm.out_proj; a conv layer's
+  conv.in_proj, conv.mix, conv.out_proj; attn.qk_norm of a per-head
+  QK-norm): the scope is in each
   compiled operation's name, so a device trace says which line of this file
   an operation belongs to.
 """
@@ -47,12 +54,15 @@ import jax.numpy as jnp
 
 from llm_instance_gateway_tpu.models import lora as lora_lib
 from llm_instance_gateway_tpu.models import mla
+from llm_instance_gateway_tpu.models import shortconv
 from llm_instance_gateway_tpu.models import ssm
 from llm_instance_gateway_tpu.models.configs import LayerKind, ModelConfig
 from llm_instance_gateway_tpu.ops.attention import (
     decode_attention,
     log_choice,
+    pack_heads,
     prefill_attention,
+    unpack_heads,
     xla_chunk_attention,
 )
 from llm_instance_gateway_tpu.ops.layers import (
@@ -119,7 +129,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     hd = cfg.resolved_head_dim
     d, v = cfg.d_model, cfg.padded_vocab
     keys = iter(jax.random.split(
-        key, 32 if cfg.latent_width or cfg.ssm_d_inner else 16))
+        key, 32 if cfg.latent_width or cfg.ssm_d_inner or cfg.conv_kernel
+        else 16))
     dtype = jnp.dtype(dtype)
 
     def rand(tree_sh, name, shape, fan_in):
@@ -133,28 +144,36 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
         x = jnp.full(shape, fill, dtype)
         return x if tree_sh is None else jax.device_put(x, tree_sh[name])
 
-    def group(layer_sh, n_l: int, sparse: bool) -> Params:
-        """``n_l`` stacked layers of one kind."""
+    def group(layer_sh, n_l: int, sparse: bool, first: int = 0) -> Params:
+        """``n_l`` stacked layers with one kind of MLP, from layer ``first``
+        of the model on.  A model with conv layers stacks the attention's
+        leaves over the group's ``n_a`` attention layers alone and the conv
+        operator's over its conv layers (``shortconv.ATTN_LEAVES``,
+        ``CONV_LEAVES``); a group without one of the two has no such
+        leaf."""
+        n_a = n_l - sum(cfg.kind_of(first + j).conv for j in range(n_l))
         layers: Params = {
             "attn_norm": const(layer_sh, "attn_norm", 1, (n_l, d)),
             "mlp_norm": const(layer_sh, "mlp_norm", 1, (n_l, d)),
         }
-        def drawn(shapes) -> Params:
+        def drawn(shapes, n: int = n_l) -> Params:
             """A module's leaves: name -> (shape, fan_in; 0: ones)."""
-            return {name: (rand(layer_sh, name, (n_l, *shape), fan_in)
+            return {name: (rand(layer_sh, name, (n, *shape), fan_in)
                            if fan_in
-                           else const(layer_sh, name, 1, (n_l, *shape)))
+                           else const(layer_sh, name, 1, (n, *shape)))
                     for name, (shape, fan_in) in shapes.items()}
 
+        if n_a < n_l:
+            layers.update(drawn(shortconv.leaf_shapes(cfg), n_l - n_a))
         if cfg.latent_width:
             layers.update(drawn(mla.leaf_shapes(cfg)))
-        else:
-            layers["wq"] = rand(layer_sh, "wq", (n_l, d, cfg.n_heads * hd), d)
+        elif n_a:
+            layers["wq"] = rand(layer_sh, "wq", (n_a, d, cfg.n_heads * hd), d)
             layers["wk"] = rand(layer_sh, "wk",
-                                (n_l, d, cfg.n_kv_heads * hd), d)
+                                (n_a, d, cfg.n_kv_heads * hd), d)
             layers["wv"] = rand(layer_sh, "wv",
-                                (n_l, d, cfg.n_kv_heads * hd), d)
-            layers["wo"] = rand(layer_sh, "wo", (n_l, cfg.n_heads * hd, d),
+                                (n_a, d, cfg.n_kv_heads * hd), d)
+            layers["wo"] = rand(layer_sh, "wo", (n_a, cfg.n_heads * hd, d),
                                 cfg.n_heads * hd)
         if cfg.ssm_d_inner:
             layers.update(drawn(ssm.leaf_shapes(cfg)))
@@ -172,6 +191,17 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
                                      (n_l, cfg.n_heads * hd))
             layers["k_norm"] = const(layer_sh, "k_norm", 1,
                                      (n_l, cfg.n_kv_heads * hd))
+        if cfg.qk_norm_head and n_a:
+            # One vector of ``hd`` for all heads, drawn about 2 (std 0.1):
+            # normed q and k of weight 1 are what random projections give
+            # anyway (a logit's std ~1, attention near uniform: with the
+            # norm left out the logits moved by 0.03 of the largest at the
+            # published widths, inside bf16's rounding; chip run, PR 54).
+            # About 2 a logit's std is ~4, attention as peaked as a trained
+            # model's, and a norm left out or without its weight is another
+            # function by any limit.
+            for name in ("q_norm", "k_norm"):
+                layers[name] = 2 + rand(layer_sh, name, (n_a, hd), 100)
         if sparse:
             e, f = cfg.n_experts, cfg.expert_d_ff
             layers["router"] = rand(layer_sh, "router", (n_l, d, e), d)
@@ -199,11 +229,11 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     def group_sh(name):
         return None if shardings is None else shardings[name]
 
-    n_dense = cfg.first_k_dense if cfg.n_experts else 0
+    n_dense = cfg.n_dense_layers
     dense_layers = (group(group_sh("dense_layers"), n_dense, False)
                     if n_dense else None)
     layers = group(group_sh("layers"), cfg.n_layers - n_dense,
-                   bool(cfg.n_experts))
+                   bool(cfg.n_experts), n_dense)
 
     params: Params = {
         "embed": rand(shardings, "embed", (v, d), None),
@@ -235,7 +265,10 @@ def init_decode_cache(
     alone and ``k_win``/``v_win`` [n_window_layers, B, ring, K, hd] its
     window layers' rings, ``ring`` = min(``sliding_window``, ``max_len``)
     positions of which position p lies at p mod ring, so that a row's ring
-    holds exactly its last ``ring`` positions."""
+    holds exactly its last ``ring`` positions.  A model with conv layers
+    holds K and V for its ATTENTION layers alone and for each conv layer a
+    slot's last inputs, ``conv`` (``shortconv.init_state``).  ``kv_pack``
+    narrow kv heads share a row: [.., K / pack, pack * hd]."""
     if cfg.latent_width:
         if quantized:
             raise ValueError("a latent (MLA) cache has no int8 form")
@@ -244,7 +277,11 @@ def init_decode_cache(
     n_win = cfg.n_window_layers
     if n_win and quantized:
         raise ValueError("ring lanes have no int8 form")
-    shape = (cfg.n_layers - n_win, batch, max_len, cfg.n_kv_heads, hd)
+    if cfg.conv_kernel and quantized:
+        raise ValueError("an int8 KV cache beside a conv state is not "
+                         "served (untested)")
+    shape = (cfg.n_layers_of("full"), batch, max_len,
+             cfg.n_kv_heads // cfg.kv_pack, hd * cfg.kv_pack)
     cache = {
         "k": jnp.zeros(shape, jnp.int8 if quantized else dtype),
         "v": jnp.zeros(shape, jnp.int8 if quantized else dtype),
@@ -258,6 +295,8 @@ def init_decode_cache(
         cache["v_scale"] = jnp.zeros(shape[:-1], jnp.float32)
     if cfg.ssm_d_inner:
         cache.update(ssm.init_state(cfg, batch, dtype))
+    if cfg.conv_kernel:
+        cache.update(shortconv.init_state(cfg, batch, dtype))
     if n_win:
         ring = (n_win, batch, min(cfg.sliding_window, max_len),
                 cfg.n_kv_heads, hd)
@@ -311,9 +350,19 @@ def _attn_proj(cfg: ModelConfig, lp, target, x, layer_lora, slot_ids):
     if b is not None:
         out = out + b
     norm = lp.get(f"{target}_norm")
-    if norm is not None:
+    if norm is not None and not cfg.qk_norm_head:
         out = rms_norm(out, norm, cfg.norm_eps)
     return scaled(out, cfg.key_multiplier) if target == "k" else out
+
+
+@jax.named_scope("attn.qk_norm")
+def _head_norm(cfg: ModelConfig, lp, target, x):
+    """LFM2's QK-norm on ``x`` [.., heads, hd], before RoPE: RMSNorm over
+    the ``hd`` numbers of EACH head, one weight vector for all heads.  Every
+    other model's q and k pass as they are."""
+    if not cfg.qk_norm_head:
+        return x
+    return rms_norm(x, lp[f"{target}_norm"], cfg.norm_eps)
 
 
 def _attn_in(cfg: ModelConfig, hn):
@@ -329,9 +378,15 @@ def _branches(cfg: ModelConfig, attn_out, ssm_out=None):
 
 
 def _split_carry(kv: tuple, n_rec: int) -> tuple[tuple, tuple]:
-    """The layer loop's carry as (the attention's arrays, a mixer's
-    (ssm, conv)): ``n_rec`` is 2 where the cache holds them, else 0."""
+    """The layer loop's carry as (the attention's arrays, what is no K or V:
+    a mixer's (ssm, conv), the conv layers' (conv,)); ``_n_rec``."""
     return kv[:len(kv) - n_rec], kv[len(kv) - n_rec:]
+
+
+def _n_rec(cache: Params) -> int:
+    """How many of the carry's last arrays hold a state that is no K or V:
+    a mixer's (ssm, conv), the conv layers' (conv,), else none."""
+    return 2 if "ssm" in cache else 1 if "conv" in cache else 0
 
 
 @jax.named_scope("attn.out")
@@ -382,13 +437,15 @@ def _chunk_attend(cfg: ModelConfig, quant: bool, q, lane_k, lane_v, start,
         if cfg.use_flash_attention and not quant:
             return pallas_attention.chunk_attention(
                 q, lane_k[None], lane_v[None], start,
-                window=kind.window).reshape(1, c, -1)
+                window=kind.window, pack=cfg.kv_pack).reshape(1, c, -1)
         log_choice(
             "chunk_attend", f"q{tuple(q.shape)} lane{tuple(lane_k.shape)}",
             "int8 lane: the dequant fuses into the XLA reads" if quant
             else "use_flash_attention=False")
-        return xla_chunk_attention(q, lane_k[None], lane_v[None], start,
-                                   kind.window).reshape(1, c, -1)
+        return xla_chunk_attention(
+            q, unpack_heads(lane_k[None], cfg.kv_pack),
+            unpack_heads(lane_v[None], cfg.kv_pack), start,
+            kind.window).reshape(1, c, -1)
 
 
 def _mlp(cfg: ModelConfig, lp: Params, x, layer_lora, slot_ids, live=None,
@@ -492,7 +549,7 @@ def _moe_route(cfg: ModelConfig, lp: Params, x, live=None) -> dict:
             gates = jnp.take_along_axis(scores, topi, axis=-1)  # [T, k]
             if cfg.norm_topk_prob:
                 gates = gates / (jnp.sum(gates, axis=-1, keepdims=True)
-                                 + 1e-20)
+                                 + cfg.router_gate_eps)
             gates = gates * cfg.routed_scaling_factor
         else:
             topv, topi = jax.lax.top_k(router_logits, k)
@@ -652,6 +709,73 @@ def _layer_groups(params: Params) -> list[Params]:
     return [params["layers"]]
 
 
+def _period_layer(cfg: ModelConfig, lps: Params, kinds, j: int) -> Params:
+    """Layer ``j`` of a period out of the period's leaves ``lps`` (each
+    [layers of the period that have it, ...]).  Every leaf is every
+    layer's, but in a model with conv layers: there the attention's leaves
+    are stacked over the period's attention layers alone and the conv
+    operator's over its conv layers, and a layer gets its own kind's."""
+    if not cfg.conv_kernel:
+        return jax.tree.map(lambda a: a[j], lps)
+    conv = kinds[j].conv
+    mine, others = ((shortconv.CONV_LEAVES, shortconv.ATTN_LEAVES) if conv
+                    else (shortconv.ATTN_LEAVES, shortconv.CONV_LEAVES))
+    own = sum(k.conv == conv for k in kinds[:j])  # its place in its kind
+    return {name: jax.tree.map(
+                lambda a, i=own if name in mine else j: a[i], leaf)
+            for name, leaf in lps.items() if name not in others}
+
+
+def _stack_layers(ys: list):
+    """One ``y`` a layer of a period -> each leaf stacked over the layers
+    that HAVE it (a conv layer has no K and V, an attention layer no conv
+    tail: None there); a leaf no layer has stays None."""
+    first = next((y for y in ys if y is not None), None)
+    if isinstance(first, tuple):
+        return tuple(_stack_layers([y[i] for y in ys])
+                     for i in range(len(first)))
+    if isinstance(first, dict):
+        return {name: _stack_layers([y[name] for y in ys]) for name in first}
+    present = [y for y in ys if y is not None]
+    return jnp.stack(present) if present else None
+
+
+def _span_leaves(cfg: ModelConfig, scanned: Params, first: int, start: int,
+                 n: int) -> Params:
+    """The scanned leaves of the ``n`` layers from layer ``start`` on, out of
+    those of a group with conv layers that starts at layer ``first``: each
+    leaf's rows of those layers, counted among the group's layers that HAVE
+    the leaf (``_period_layer``); a leaf none of them has is left out, as
+    in a group of one kind."""
+    def rows(conv):  # None: a leaf every layer has
+        def upto(end):
+            return sum(conv is None or cfg.kind_of(l).conv == conv
+                       for l in range(first, end))
+        return slice(upto(start), upto(start + n))
+    own = {**dict.fromkeys(shortconv.CONV_LEAVES, rows(True)),
+           **dict.fromkeys(shortconv.ATTN_LEAVES, rows(False))}
+    spans = {name: own.get(name, rows(None)) for name in scanned}
+    return {name: jax.tree.map(lambda a, at=at: a[at], scanned[name])
+            for name, at in spans.items() if at.start < at.stop}
+
+
+def _layer_spans(cfg: ModelConfig, params: Params):
+    """The model's scans of layers in forward order (``cfg.group_spans`` of
+    each of ``_layer_groups``): (the leaves the scan scans, the group's
+    expert stacks, the span's first layer, its place in those stacks, its
+    layers, the period of kinds it repeats).  A group is one span, its
+    leaves as they are, but where a depth leaves part of a period over."""
+    first = 0
+    for layers in _layer_groups(params):
+        scanned, stacks = _layer_xs(layers)
+        n_group = layers["attn_norm"].shape[0]
+        for start, n, kinds in cfg.group_spans(first, n_group):
+            yield (scanned if n == n_group
+                   else _span_leaves(cfg, scanned, first, start, n),
+                   stacks, start, start - first, n, kinds)
+        first += n_group
+
+
 def _scan_groups(cfg: ModelConfig, params: Params, lora_bufs: Params | None,
                  carry, body):
     """``lax.scan`` over each group of layers in turn, one carry through
@@ -660,12 +784,17 @@ def _scan_groups(cfg: ModelConfig, params: Params, lora_bufs: Params | None,
     with a sparse group's expert stacks beside their index (``_layer_lp``),
     ``kind`` its ``LayerKind`` and ``lane`` its index among the layers that
     share its kind of cache (``layer`` itself for a model of one kind).
-    Returns (carry, [each group's stacked ys]).
+    Returns (carry, [each scan's stacked ys]).
 
     A model whose stack repeats a PERIOD of kinds (``cfg.layer_kinds``)
     scans periods: the scanned leaves are viewed [periods, period, ...],
     one step runs the period's layers one after another, each traced as its
-    own kind, and the ys come back one a layer as from a scan of layers."""
+    own kind, and the ys come back one a layer as from a scan of layers
+    (of a model with conv layers: each leaf one a layer that has it).  The
+    period is counted from layer 0 of the MODEL, so a group that starts at
+    layer f runs it rotated by f, a group all of one kind scans a layer a
+    step, and the layers a depth leaves over of a period are a scan of
+    their own (``ModelConfig.group_spans``, ``_layer_spans``)."""
     per_layer_lora = None
     if lora_bufs is not None:
         per_layer_lora, _ = lora_lib.stack_for_scan(lora_bufs)
@@ -673,62 +802,75 @@ def _scan_groups(cfg: ModelConfig, params: Params, lora_bufs: Params | None,
     if len(groups) > 1 and lora_bufs is not None:
         raise NotImplementedError(
             "LoRA buffers are one stack: not over two kinds of layers")
-    kinds = cfg.layer_kinds
-    period = len(kinds)
-    # A layer's place among the layers that share its kind of cache (ring
-    # lanes or full lanes): how many of them a period holds, and how many
-    # come before it there.
-    ringed = [bool(k.window) for k in kinds]
-    per_period = [ringed.count(r) for r in ringed]
-    before = [ringed[:j].count(r) for j, r in enumerate(ringed)]
-    if period > 1 and lora_bufs is not None:
+    if len(cfg.layer_kinds) > 1 and lora_bufs is not None:
         raise NotImplementedError(
             "LoRA buffers are scanned a layer a step: not over a period of "
             "layer kinds")
-    first, ys = 0, []
-    for layers in groups:
-        scanned, stacks = _layer_xs(layers)
-        n = layers["attn_norm"].shape[0]
+    ys = []
+    seen: dict[str, int] = {}  # layers of each kind of cache so far
+    # (``first``: the span's first layer; ``off``: its place in the stacks)
+    for scanned, stacks, first, off, n, kinds in _layer_spans(cfg, params):
+        period = len(kinds)
+        # A layer's place among the layers that share its kind of cache
+        # (full lanes, ring lanes, conv state): how many of them a period
+        # holds, how many come before it there, how many before the span.
+        caches = [k.cache for k in kinds]
+        per_period = [caches.count(c) for c in caches]
+        before = [caches[:j].count(c) + seen.get(c, 0)
+                  for j, c in enumerate(caches)]
 
-        def step(carry, xs, stacks=stacks, first=first):
+        def step(carry, xs, stacks=stacks, first=first, off=off, kinds=kinds,
+                 before=before):
             lp, ll, i = xs
             layer_lora = (None if ll is None
                           else {**ll, "scale": lora_bufs["scale"]})
             layer = first + i if first else i
-            return body(carry, layer, _layer_lp(lp, stacks, i), layer_lora,
-                        kinds[0], layer)
+            lane = layer if before[0] == first else before[0] + i
+            return body(carry, layer,
+                        _layer_lp(lp, stacks, off + i if off else i),
+                        layer_lora, kinds[0], lane)
 
-        def period_step(carry, xs, stacks=stacks):
+        def period_step(carry, xs, stacks=stacks, first=first, off=off,
+                        kinds=kinds, period=period, per_period=per_period,
+                        before=before):
             lps, _, i = xs
             ys = []
             for j, kind in enumerate(kinds):
-                layer = i * period + j
+                at = i * period + j  # the layer's place in its span
                 lane = i * per_period[j] + before[j]
-                lp = jax.tree.map(lambda a, j=j: a[j], lps)
-                carry, y = body(carry, layer, _layer_lp(lp, stacks, layer),
-                                None, kind, lane)
+                carry, y = body(
+                    carry, first + at if first else at,
+                    _layer_lp(_period_layer(cfg, lps, kinds, j), stacks,
+                              off + at if off else at),
+                    None, kind, lane)
                 ys.append(y)
-            return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+            return carry, _stack_layers(ys)
 
         if period > 1:
+            periods = n // period
             scanned = jax.tree.map(
-                lambda a: a.reshape(n // period, period, *a.shape[1:]),
-                scanned)
+                lambda a: a.reshape(periods, a.shape[0] // periods,
+                                    *a.shape[1:]), scanned)
             carry, y = jax.lax.scan(
-                period_step, carry, (scanned, None, jnp.arange(n // period)))
-            y = jax.tree.map(lambda a: a.reshape(n, *a.shape[2:]), y)
+                period_step, carry, (scanned, None, jnp.arange(periods)))
+            y = jax.tree.map(
+                lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), y)
         else:
             carry, y = jax.lax.scan(
                 step, carry, (scanned, per_layer_lora, jnp.arange(n)))
         ys.append(y)
-        first += n
+        for c in set(caches):
+            seen[c] = seen.get(c, 0) + n // period * caches.count(c)
     return carry, ys
 
 
 def _stacked(parts: list):
     """The groups' stacked outputs as one stack over the model's layers
-    (None where no group has any, as a dense model's tallies)."""
+    (None where no group has any, as a dense model's tallies; a dict, as a
+    prefill's ``v`` of a model with a state beside its lanes, key by key)."""
     parts = [p for p in parts if p is not None]
+    if parts and isinstance(parts[0], dict):
+        return {name: _stacked([p[name] for p in parts]) for name in parts[0]}
     if len(parts) <= 1:
         return parts[0] if parts else None
     return jnp.concatenate(parts, axis=0)
@@ -769,6 +911,21 @@ def _finish_block(cfg: ModelConfig, lp: Params, h, attn, layer_lora,
     return h + y, (*kv, tally)
 
 
+def _conv_block(cfg: ModelConfig, lp: Params, h, mix, layer_lora, slot_ids,
+                live):
+    """A conv layer (``LayerKind.conv``): the gated short convolution where
+    another layer has its attention, then the MLP.  ``mix(hn) -> (its
+    output, the operator's new state)`` is the form the program runs
+    (``shortconv.prompt_mix`` / ``decode_mix`` / ``chunk_mix``).  Returns
+    (h, the state, tally)."""
+    hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
+    mixed, state = mix(hn)
+    h = h + mixed
+    hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
+    y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live)
+    return h + y, state, tally
+
+
 def prefill_layer(
     cfg: ModelConfig,
     lp: Params,              # one layer's params (leaves without the L dim)
@@ -785,7 +942,10 @@ def prefill_layer(
     A parallel block (``cfg.ssm_d_inner``) hands back, in ``v``'s place,
     ``{"v", "ssm", "conv"}``: the values and what the mixer's recurrence
     leaves after each row's last true position (``live``; all of them
-    without it), which ``insert_prefill`` installs together.  ``kind`` is
+    without it), which ``insert_prefill`` installs together.  A model with
+    conv layers hands back ``{"v", "conv"}`` there: an attention layer its
+    values and no conv state (None), a conv layer no ``k``, no values and
+    the operator's state after each row's last true position.  ``kind`` is
     the layer's place in a period of kinds: a "nope" layer rotates nothing,
     a window layer of a prompt longer than its window masks by it (the XLA
     form: the flash kernel is causal only, and the serving buckets are
@@ -798,6 +958,11 @@ def prefill_layer(
     b, s, _ = h.shape
     if slot_ids is None:
         slot_ids = jnp.full((b,), -1, jnp.int32)
+    if kind.conv:
+        h, tail, tally = _conv_block(
+            cfg, lp, h, lambda hn: shortconv.prompt_mix(cfg, lp, hn, live),
+            layer_lora, slot_ids, live)
+        return h, (None, {"v": None, "conv": tail}, tally)
     plan = _route_early(cfg, lp, h, live)
     hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     if cfg.latent_width:
@@ -810,9 +975,11 @@ def prefill_layer(
     q = _attn_proj(cfg, lp, "q", ha, layer_lora, slot_ids).reshape(b, s, cfg.n_heads, hd)
     k = _attn_proj(cfg, lp, "k", ha, layer_lora, slot_ids).reshape(b, s, cfg.n_kv_heads, hd)
     v = _attn_proj(cfg, lp, "v", ha, layer_lora, slot_ids).reshape(b, s, cfg.n_kv_heads, hd)
+    q, k = _head_norm(cfg, lp, "q", q), _head_norm(cfg, lp, "k", k)
     if kind.rope:
         q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
         k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+    pack = cfg.kv_pack
     mixed = None
     if cfg.ssm_d_inner:
         mixed, state, tail = ssm.prompt_mix(cfg, lp, hn, live)
@@ -834,7 +1001,8 @@ def prefill_layer(
                 flash_attention,
             )
 
-            attn = flash_attention(q, k, v)
+            attn = flash_attention(q, pack_heads(k, pack),
+                                   pack_heads(v, pack), pack=pack)
         else:
             attn = prefill_attention(q, k, v, positions)
     h = h + _branches(
@@ -842,8 +1010,11 @@ def prefill_layer(
         mixed)
     hn2 = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     y, tally = _mlp(cfg, lp, hn2, layer_lora, slot_ids, live=live, plan=plan)
+    k, v = pack_heads(k, pack), pack_heads(v, pack)  # as a cache holds them
     if cfg.ssm_d_inner:
         v = {"v": v, "ssm": state, "conv": tail}
+    elif cfg.conv_kernel:
+        v = {"v": v, "conv": None}
     return h + y, (k, v, tally)
 
 
@@ -864,7 +1035,9 @@ def prefill(
     rows [L,B,S,lanes] and its ``v`` empty [L,B,S,0] (``mla``); a model
     with a mixer returns ``{"v", "ssm" [L,B,H,N,P], "conv" [L,B,K-1,C]}``
     (the prompt's rows; a cache lays ``conv`` [L,K-1,B,C])
-    in ``v``'s place (``prefill_layer``).  With ``lengths`` the padding past
+    in ``v``'s place (``prefill_layer``); a model with conv layers ``k``
+    and ``{"v"}`` of its ATTENTION layers alone and ``"conv"``
+    [L_conv,B,K-1,D] of its conv layers.  With ``lengths`` the padding past
     a prompt's end routes to no expert (its outputs are garbage either way)
     and leaves a mixer's state alone.
 
@@ -922,6 +1095,8 @@ def _carry_names(cache: Params) -> tuple[str, ...]:
         return ("k",)
     if "ssm" in cache:
         return ("k", "v", "ssm", "conv")
+    if "conv" in cache:  # the conv layers' state; k and v the others' alone
+        return ("k", "v", "conv")
     if "k_win" in cache:
         return ("k", "v", "k_win", "v_win")
     return ("k", "v") + (("k_scale", "v_scale") if "k_scale" in cache else ())
@@ -1040,7 +1215,7 @@ def _attend_cached(cfg, attention_fn, q, kv, layer, lengths, schedule,
             return pda.decode_attention_quant(q, *kv, lengths, layer=layer,
                                               schedule=schedule)
         return pda.decode_attention(q, *kv, lengths, layer=layer, ring=ring,
-                                    schedule=schedule)
+                                    schedule=schedule, pack=cfg.kv_pack)
     if quant and getattr(attention_fn, "quant_aware", False):
         # Quant-aware override (sharded_attention.make_cached_decode_quant):
         # raw int8 + scales go in; each shard's kernel dequantizes in VMEM,
@@ -1052,7 +1227,9 @@ def _attend_cached(cfg, attention_fn, q, kv, layer, lengths, schedule,
     # and materializes a full bf16 cache; the engine only installs
     # quant_aware wrappers on quantized lanes for exactly that reason.
     k_cache, v_cache = _layer_view(kv, layer, q.dtype)
-    return (attention_fn or decode_attention)(q, k_cache, v_cache, lengths)
+    return (attention_fn or decode_attention)(
+        q, unpack_heads(k_cache, cfg.kv_pack),
+        unpack_heads(v_cache, cfg.kv_pack), lengths)
 
 
 def decode_step(
@@ -1101,7 +1278,7 @@ def decode_step(
     held_full = _held(cfg, attention_fn, read_lengths, cache["k"])
     batch_idx = jnp.arange(b)
     s_max = cache["k"].shape[2]
-    n_rec = 2 if "ssm" in cache else 0  # a mixer's (ssm, conv) in the carry
+    n_rec = _n_rec(cache)  # a mixer's (ssm, conv), the conv layers' (conv,)
     # Scatter address only — rope/masks keep the true positions.  s_max is
     # out of bounds, so inactive rows' updates are dropped whole.
     write_pos = (positions if active is None
@@ -1127,6 +1304,12 @@ def decode_step(
 
     def layer_fn(h, kv, layer, lp, layer_lora, kind, lane):
         kv, rec = _split_carry(kv, n_rec)
+        if kind.conv:
+            h, conv, tally = _conv_block(
+                cfg, lp, h, lambda hn: shortconv.decode_mix(
+                    cfg, lp, hn, rec[0], lane, active),
+                layer_lora, slot_ids, active)
+            return h, kv + (conv,), tally
         own, put_back = _own_lanes(cfg, kv, kind)
         plan = _route_early(cfg, lp, h, active)
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
@@ -1134,16 +1317,18 @@ def decode_step(
         q = _attn_proj(cfg, lp, "q", ha, layer_lora, slot_ids).reshape(b, cfg.n_heads, hd)
         k = _attn_proj(cfg, lp, "k", ha, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
         v = _attn_proj(cfg, lp, "v", ha, layer_lora, slot_ids).reshape(b, cfg.n_kv_heads, hd)
+        q, k = _head_norm(cfg, lp, "q", q), _head_norm(cfg, lp, "k", k)
         if kind.rope:
             q = apply_rope(q[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
             k = apply_rope(k[:, None], positions[:, None], cfg.rope_theta, cfg.rope_scaling)[:, 0]
         at, held = ((ring_pos, held_ring) if kind.window
                     else (write_pos, held_full))
-        own = _write_kv(own, (lane, batch_idx, at), k, v)
+        own = _write_kv(own, (lane, batch_idx, at),
+                        pack_heads(k, cfg.kv_pack), pack_heads(v, cfg.kv_pack))
         attn = _decode_attend(cfg, attention_fn, q, own, lane, held, kind)
         kv = put_back(own)
         mixed = None
-        if rec:
+        if n_rec == 2:
             mixed, rec = ssm.decode_mix(cfg, lp, hn, rec, layer, active)
         h = h + _branches(
             cfg, _attn_out(lp, attn.reshape(b, -1), layer_lora, slot_ids),
@@ -1191,6 +1376,11 @@ def extend_step(
         raise NotImplementedError(
             "extend_step (speculative verify) is not served over a "
             "recurrent state: a rejected draft would need it rolled back")
+    if cfg.conv_kernel:
+        raise NotImplementedError(
+            "extend_step (speculative verify) is not served over a conv "
+            "state (a rejected draft would need it rolled back) or packed "
+            "heads")
     if cfg.sliding_window:
         raise NotImplementedError(
             "extend_step (speculative verify) is not served over ring "
@@ -1337,10 +1527,18 @@ def prefill_with_cache(
                                        slot_ids, live, (kv,))
         return h, kv, tally
 
-    n_rec = 2 if "ssm" in cache else 0  # a mixer's (ssm, conv) in the carry
+    n_rec = _n_rec(cache)  # a mixer's (ssm, conv), the conv layers' (conv,)
 
     def layer_fn(h, kv, layer, lp, layer_lora, kind, lane):
         kv, rec = _split_carry(kv, n_rec)
+        if kind.conv:
+            # The slot's lane holds what the chunks before this one left.
+            h, conv, tally = _conv_block(
+                cfg, lp, h, lambda hn: shortconv.chunk_mix(
+                    cfg, lp, hn, rec[0], lane, slot, positions[0] == 0,
+                    live),
+                layer_lora, slot_ids, live)
+            return h, kv + (conv,), tally
         own, put_back = _own_lanes(cfg, kv, kind)
         plan = _route_early(cfg, lp, h, live)
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
@@ -1348,9 +1546,11 @@ def prefill_with_cache(
         q = _attn_proj(cfg, lp, "q", ha, layer_lora, slot_ids).reshape(1, c, cfg.n_heads, hd)
         k = _attn_proj(cfg, lp, "k", ha, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
         v = _attn_proj(cfg, lp, "v", ha, layer_lora, slot_ids).reshape(1, c, cfg.n_kv_heads, hd)
+        q, k = _head_norm(cfg, lp, "q", q), _head_norm(cfg, lp, "k", k)
         if kind.rope:
             q = apply_rope(q, pos2d, cfg.rope_theta, cfg.rope_scaling)
             k = apply_rope(k, pos2d, cfg.rope_theta, cfg.rope_scaling)
+        k, v = pack_heads(k, cfg.kv_pack), pack_heads(v, cfg.kv_pack)
         if kind.window:
             attn, own = _ring_chunk(cfg, q, k[0], v[0], own, lane, slot,
                                     positions, live[0], kind)
@@ -1372,7 +1572,7 @@ def prefill_with_cache(
                                  kind)
         kv = put_back(own)
         mixed = None
-        if rec:
+        if n_rec == 2:
             # The slot's lane holds what the chunks before this one left.
             mixed, rec = ssm.chunk_mix(cfg, lp, hn, rec, layer, slot,
                                        positions[0] == 0, live)
@@ -1409,6 +1609,9 @@ def insert_prefill(
     by kind: the full layers' into ``k``/``v`` as ever, the window layers'
     into the rings, ring slot s taking the newest position p < ``length``
     with p mod ring = s (for a prompt shorter than the ring, position s).
+    A model with conv layers brings ``k_prompt`` and ``v_prompt["v"]`` of
+    its attention layers and ``v_prompt["conv"]`` [L_conv, 1, K - 1, D],
+    each conv layer's state at the TRUE length (``prefill``).
     """
     if "k_win" in cache:
         kinds = cfg.layer_kinds
@@ -1435,18 +1638,19 @@ def insert_prefill(
             k, k_prompt.astype(k.dtype), (0, slot, 0, 0))
         return {"k": k, "length": cache["length"].at[slot].set(length)}
     v = cache["v"]
-    if "ssm" in cache:  # v_prompt: prefill's {"v", "ssm", "conv"}
+    if "conv" in cache:  # v_prompt: prefill's {"v", "conv"}, a mixer's "ssm"
         # One insert installs lanes, state, conv history and length
         # together, so a freed slot needs no clearing.
         rec = {
-            "ssm": jax.lax.dynamic_update_slice(
-                cache["ssm"], v_prompt["ssm"].astype(cache["ssm"].dtype),
-                (0, slot, 0, 0, 0)),
             # the prompt's [L, 1, K - 1, C] into the cache's [L, K - 1, B, C]
             "conv": jax.lax.dynamic_update_slice(
                 cache["conv"], jnp.swapaxes(v_prompt["conv"], 1, 2).astype(
                     cache["conv"].dtype), (0, 0, slot, 0)),
         }
+        if "ssm" in cache:
+            rec["ssm"] = jax.lax.dynamic_update_slice(
+                cache["ssm"], v_prompt["ssm"].astype(cache["ssm"].dtype),
+                (0, slot, 0, 0, 0))
         lanes = insert_prefill({"k": k, "v": v, "length": cache["length"]},
                                k_prompt, v_prompt["v"], slot, length)
         return {**lanes, **rec}

@@ -80,6 +80,61 @@ def xla_chunk_attention(
     return out.reshape(b, c, n_heads, hd)
 
 
+# Heads narrower than a vreg's 128 lanes (LFM2: 64).  ``pack`` kv heads share
+# one cache row [.., K / pack, pack * hd]: the cache is allocated, written
+# and streamed in whole lanes (an [.., 8, 64] bf16 array is tiled with its
+# minor dim padded to 128 on the TPU: twice the bytes).  A kernel sees a model
+# of K / pack kv heads of pack * hd: query head h, whose kv head h // g lies
+# in columns ((h // g) % pack) * hd of its row, goes in with zeros in the
+# other heads' columns, so its product with the row is its own head's alone
+# and its output's own columns are its own head's values.  The softmax scale
+# stays 1 / sqrt(hd): the kernels take it.
+
+
+def pack_heads(x: jax.Array, pack: int) -> jax.Array:
+    """[.., K, hd] -> [.., K / pack, pack * hd] (``pack`` 1: as it is)."""
+    if pack == 1:
+        return x
+    *lead, k, hd = x.shape
+    return x.reshape(*lead, k // pack, pack * hd)
+
+
+def unpack_heads(x: jax.Array, pack: int) -> jax.Array:
+    """``pack_heads``'s inverse."""
+    if pack == 1:
+        return x
+    *lead, k, w = x.shape
+    return x.reshape(*lead, k * pack, w // pack)
+
+
+def _own_columns(n_heads: int, n_kv: int, pack: int, dtype) -> jax.Array:
+    """[H, pack] one-hot: which head of its packed row a query head reads."""
+    sub = (jnp.arange(n_heads) // (n_heads // n_kv)) % pack
+    return (sub[:, None] == jnp.arange(pack)[None]).astype(dtype)
+
+
+def pad_queries(q: jax.Array, n_kv: int, pack: int) -> jax.Array:
+    """[.., H, hd] -> [.., H, pack * hd]: each head in its kv head's
+    columns of the packed row, zeros elsewhere.  ``n_kv``: the unpacked
+    count."""
+    if pack == 1:
+        return q
+    *lead, h, hd = q.shape
+    own = _own_columns(h, n_kv, pack, q.dtype)
+    return (q[..., None, :] * own[:, :, None]).reshape(*lead, h, pack * hd)
+
+
+def own_values(out: jax.Array, n_kv: int, pack: int) -> jax.Array:
+    """[.., H, pack * hd] -> [.., H, hd]: a padded query's output, its own
+    kv head's columns."""
+    if pack == 1:
+        return out
+    *lead, h, w = out.shape
+    own = _own_columns(h, n_kv, pack, out.dtype)
+    return jnp.sum(out.reshape(*lead, h, pack, w // pack) * own[:, :, None],
+                   axis=-2)
+
+
 def gather_pool_rows(pool: jax.Array, tables: jax.Array) -> jax.Array:
     """Paged-pool gather: ``[n_blocks+1, P, ...] x [B, M] -> [B, M*P, ...]``
     — each table row's physical blocks concatenated into the contiguous

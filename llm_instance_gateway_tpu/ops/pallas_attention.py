@@ -36,7 +36,10 @@ from jax.experimental.pallas import tpu as pltpu
 from llm_instance_gateway_tpu.ops.attention import (
     kernel_reason,
     log_choice,
+    own_values,
+    pad_queries,
     prefill_attention,
+    unpack_heads,
 )
 
 NEG_INF = -1e30
@@ -148,11 +151,12 @@ def flash_attention_bhsd(
     block_q: int = BLOCK_Q,
     block_k: int = BLOCK_K,
     interpret: bool = False,
+    scale: float | None = None,  # the softmax's, if not 1 / sqrt(hd)
 ) -> jax.Array:
     b, h, s, hd = q.shape
     n_kv = k.shape[1]
     g = h // n_kv
-    scale = float(1.0 / (hd ** 0.5))
+    scale = float(scale or 1.0 / (hd ** 0.5))
     # K-block axis innermost and sequential: scratch carries the online
     # softmax state across it; the three outer axes parallelize freely.
     grid = (b, h, s // block_q, s // block_k)
@@ -283,6 +287,7 @@ def chunk_attention_pallas(
     block_k: int = BLOCK_K,
     interpret: bool = False,
     window: int = 0,
+    scale: float | None = None,  # the softmax's, if not 1 / sqrt(hd)
 ) -> jax.Array:
     """Flash-style chunk attend: chunk token i (global position start+i)
     attends cache positions <= start+i (with ``window``: the last
@@ -295,7 +300,7 @@ def chunk_attention_pallas(
     s_max = k_cache.shape[1]
     n_kv = k_cache.shape[2]
     g = h // n_kv
-    scale = float(1.0 / (hd ** 0.5))
+    scale = float(scale or 1.0 / (hd ** 0.5))
     # Heads as lane columns: in the flat [B, 1, S, K*hd] view a head is the
     # hd-wide column block its index map names, so neither the queries nor
     # the lane are transposed to a heads-major copy.  The lane is flattened
@@ -365,25 +370,32 @@ def supports_chunk(c: int, s_max: int, hd: int) -> bool:
 
 def chunk_attention(
     q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, start,
-    interpret: bool = False, window: int = 0,
+    interpret: bool = False, window: int = 0, pack: int = 1,
 ) -> jax.Array:
-    """Dispatch for the chunk attend; XLA reference otherwise."""
+    """Dispatch for the chunk attend; XLA reference otherwise.  ``pack`` >
+    1: the lane holds that many narrow kv heads a row ([B, S, K / pack,
+    pack * hd], ``ops.attention.pack_heads``) and ``q`` comes as the model
+    has it: the kernel takes the rows as they lie and the queries padded
+    into their heads' columns."""
     from llm_instance_gateway_tpu.ops.attention import xla_chunk_attention
 
     b, c, h, hd = q.shape
     reason = kernel_reason(
-        chunk_shape_reasons(c, k_cache.shape[1], hd), interpret)
+        chunk_shape_reasons(c, k_cache.shape[1], hd * pack), interpret)
     log_choice(
         "chunk_attend", f"q{tuple(q.shape)} cache{tuple(k_cache.shape)}",
         reason, interpret)
     if reason is not None:
-        return xla_chunk_attention(q, k_cache, v_cache, start, window)
+        return xla_chunk_attention(q, unpack_heads(k_cache, pack),
+                                   unpack_heads(v_cache, pack), start, window)
     s_max = k_cache.shape[1]
-    return chunk_attention_pallas(
-        q, k_cache, v_cache, start,
+    n_kv = k_cache.shape[2] * pack
+    out = chunk_attention_pallas(
+        pad_queries(q, n_kv, pack), k_cache, v_cache, start,
         block_q=BLOCK_Q if c % CHUNK_BLOCK_Q else CHUNK_BLOCK_Q,
         block_k=BLOCK_K if s_max % CHUNK_BLOCK_K else CHUNK_BLOCK_K,
-        interpret=interpret, window=window)
+        interpret=interpret, window=window, scale=1.0 / (hd ** 0.5))
+    return own_values(out, n_kv, pack)
 
 
 def flash_attention(
@@ -392,8 +404,12 @@ def flash_attention(
     v: jax.Array,
     causal: bool = True,
     interpret: bool = False,
+    pack: int = 1,
 ) -> jax.Array:
     """Dispatch: Pallas kernel when shapes allow, XLA reference otherwise.
+    ``pack`` > 1: ``k`` and ``v`` hold that many narrow kv heads a row
+    ([B, S, K / pack, pack * hd], ``ops.attention.pack_heads``), ``q`` is
+    as the model has it.
 
     NOTE: the kernel path is purely causal — use it for right-padded batches
     (pad tokens trail real ones, so causality alone keeps real positions
@@ -402,14 +418,17 @@ def flash_attention(
     """
     b, s, h, hd = q.shape
     reason = kernel_reason(
-        shape_reasons(s, hd), interpret)
+        shape_reasons(s, hd * pack), interpret)
     log_choice(
         "flash_prefill", f"q{tuple(q.shape)} kv{tuple(k.shape)}", reason,
         interpret)
     if reason is not None:
-        return prefill_attention(q, k, v)
-    qt = q.transpose(0, 2, 1, 3)
+        return prefill_attention(q, unpack_heads(k, pack),
+                                 unpack_heads(v, pack))
+    n_kv = k.shape[2] * pack
+    qt = pad_queries(q, n_kv, pack).transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    out = flash_attention_bhsd(qt, kt, vt, causal=causal, interpret=interpret)
-    return out.transpose(0, 2, 1, 3)
+    out = flash_attention_bhsd(qt, kt, vt, causal=causal, interpret=interpret,
+                               scale=1.0 / (hd ** 0.5))
+    return own_values(out.transpose(0, 2, 1, 3), n_kv, pack)

@@ -41,6 +41,9 @@ from llm_instance_gateway_tpu.ops.attention import (
     kernel_reason,
     latent_decode_attention,
     log_choice,
+    own_values,
+    pad_queries,
+    unpack_heads,
 )
 
 NEG_INF = -1e30
@@ -271,7 +274,8 @@ def _pick_block(s_max: int, row_bytes: int = 0) -> int:
 def _pallas_decode_call(q, k_all, v_all, scales, lengths, layer,
                         block_s: int | None, interpret: bool,
                         name: str = "decode_attention",
-                        schedule=None) -> jax.Array:
+                        schedule=None, scale: float | None = None,
+                        ) -> jax.Array:
     """Shared pallas_call builder for the bf16 and int8 variants, over the
     STACKED cache [L, B, S, K, hd] and a layer index: the index rides the
     scalar prefetch into the tiles' index map, so the kernel reads the
@@ -281,7 +285,9 @@ def _pallas_decode_call(q, k_all, v_all, scales, lengths, layer,
     like the cache: only that layer's reach the kernel, as lane vectors — a
     relayout kept to one layer of an array 1/hd the size of the cache.
     ``name`` is the call's name in a device trace: the same body over a
-    window layer's ring lanes runs as ``decode_attention_window``."""
+    window layer's ring lanes runs as ``decode_attention_window``.
+    ``scale``: the softmax's, where it is not 1 / sqrt(hd) of the rows as
+    they lie (packed narrow heads, ``ops.attention.pack_heads``)."""
     if scales is not None:
         scales = [_layer_view(s, layer) for s in scales]
     if layer is None:
@@ -315,7 +321,8 @@ def _pallas_decode_call(q, k_all, v_all, scales, lengths, layer,
         in_specs += [pl.BlockSpec((1, 1, rows), scale_index)] * 2
         operands += [_scale_rows(s) for s in scales]
     kernel = functools.partial(_decode_kernel, block_s=block_s, s_max=s_max,
-                               n_kv=n_kv, scale=float(1.0 / (hd ** 0.5)),
+                               n_kv=n_kv,
+                               scale=float(scale or 1.0 / (hd ** 0.5)),
                                quant=quant)
     out = pl.pallas_call(
         kernel,
@@ -346,9 +353,10 @@ def decode_attention_pallas(
     interpret: bool = False,
     name: str = "decode_attention",
     schedule=None,       # ``decode_schedule`` over ``lane_tiles``, built ahead
+    scale: float | None = None,  # the softmax's, if not 1 / sqrt(hd)
 ) -> jax.Array:
     return _pallas_decode_call(q, k_cache, v_cache, None, lengths, layer,
-                               block_s, interpret, name, schedule)
+                               block_s, interpret, name, schedule, scale)
 
 
 def decode_attention_quant_pallas(
@@ -660,6 +668,7 @@ def mla_decode_attention(
 def decode_attention(
     q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, lengths: jax.Array,
     layer=None, interpret: bool = False, ring: bool = False, schedule=None,
+    pack: int = 1,
 ) -> jax.Array:
     """Dispatch: Pallas kernel when shapes allow, XLA reference otherwise.
     With ``layer`` the caches are the stacked [L, B, S, K, hd] arrays and
@@ -670,7 +679,10 @@ def decode_attention(
     ``decode_attention_window`` so that a trace tells the two apart.
     ``schedule``: the kernel's (``decode_schedule`` over ``lane_tiles``),
     where the caller built it ahead of its layer loop; built here
-    otherwise."""
+    otherwise.  ``pack`` > 1: the caches hold that many narrow kv heads a
+    row ([.., K / pack, pack * hd], ``ops.attention.pack_heads``) and ``q``
+    comes as the model has it, [B, H, hd]: the kernel takes the rows as
+    they lie and the queries padded into their heads' columns."""
     s_max, hd = k_cache.shape[-3], k_cache.shape[-1]
     reason = kernel_reason(
         shape_reasons(s_max, hd, _row_bytes(k_cache)), interpret)
@@ -678,12 +690,16 @@ def decode_attention(
                f"q{tuple(q.shape)} cache{tuple(k_cache.shape)}",
                reason, interpret)
     if reason is not None:
-        return xla_decode(q, _layer_view(k_cache, layer),
-                          _layer_view(v_cache, layer), lengths)
-    return decode_attention_pallas(
-        q, k_cache, v_cache, lengths, layer, interpret=interpret,
+        return xla_decode(q, unpack_heads(_layer_view(k_cache, layer), pack),
+                          unpack_heads(_layer_view(v_cache, layer), pack),
+                          lengths)
+    n_kv = k_cache.shape[-2] * pack
+    out = decode_attention_pallas(
+        pad_queries(q, n_kv, pack), k_cache, v_cache, lengths, layer,
+        interpret=interpret,
         name="decode_attention_window" if ring else "decode_attention",
-        schedule=schedule)
+        schedule=schedule, scale=1.0 / (q.shape[-1] ** 0.5))
+    return own_values(out, n_kv, pack)
 
 
 def decode_attention_quant(

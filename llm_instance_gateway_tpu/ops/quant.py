@@ -31,7 +31,9 @@ QUANT_TARGETS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                  "wq_down", "wq_up", "wkv_down", "wkv_up",
                  "ws_gate", "ws_up", "ws_down",
                  # a state-space mixer's two projections (models/ssm.py)
-                 "ssm_in", "ssm_out")
+                 "ssm_in", "ssm_out",
+                 # a gated short convolution's two (models/shortconv.py)
+                 "conv_in", "conv_out")
 
 
 def quantize_weight(w: jax.Array) -> dict[str, jax.Array]:

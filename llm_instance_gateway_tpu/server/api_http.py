@@ -1542,7 +1542,9 @@ class ModelServer:
                 "routed_scaling_factor", "ssm_d_inner", "ssm_n_heads",
                 "ssm_head_dim", "ssm_d_state", "ssm_n_groups", "ssm_d_conv",
                 "ssm_chunk", "layer_pattern", "sliding_window",
-                "router_pre_attention", "mlp_activation")
+                "router_pre_attention", "mlp_activation", "qk_norm_head",
+                "conv_kernel", "tie_embeddings",
+                "router_gate_eps")
         return web.json_response({
             "model": self.model_name,
             "platform": devices[0].platform,
@@ -1637,6 +1639,12 @@ def main(argv=None) -> None:
                              "long prompts may stream into reserved cache "
                              "lanes at once (fair round-robin); 1 = a "
                              "second long prompt head-of-line waits")
+    parser.add_argument("--stream-burst", type=int, default=1,
+                        help="chunk programs one engine turn may enqueue "
+                             "before its decode block; above 1 a backlog "
+                             "of long prompts leaves the waiting queue "
+                             "sooner and the live rows wait that many "
+                             "chunks for their next token")
     parser.add_argument("--prefill-batch", type=int, default=1,
                         help="group up to P same-bucket queued prompts into "
                              "one prefill program (contiguous-lane cache)")
@@ -1753,9 +1761,11 @@ def main(argv=None) -> None:
             "it with --max-loras 0 and without --mesh")
     if cfg.layer_pattern and (args.max_loras > 0 or args.mesh):
         raise SystemExit(
-            f"{args.model} scans a period of layer kinds over ring lanes "
-            "beside its full lanes: it is served on one device, base model "
-            "only; start it with --max-loras 0 and without --mesh")
+            f"{args.model} scans a period of layer kinds over "
+            + ("a conv state beside the K/V lanes of its attention layers"
+               if cfg.conv_kernel else "ring lanes beside its full lanes")
+            + ": it is served on one device, base model only; start it "
+            "with --max-loras 0 and without --mesh")
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
 
     tokenizer = load_tokenizer(args.tokenizer)
@@ -1856,6 +1866,7 @@ def main(argv=None) -> None:
             decode_steps_per_sync=args.decode_steps,
             adaptive_steps=args.adaptive_steps,
             stream_lanes=args.stream_lanes,
+            stream_burst=args.stream_burst,
             prefill_batch=args.prefill_batch,
             paged_kv_block=args.paged_kv_block,
             paged_kv_blocks=args.paged_kv_blocks,

@@ -274,6 +274,15 @@ class EngineConfig:
     # first.  Lanes beyond the first admit only with KV headroom left for
     # active decode growth (paged pools).
     stream_lanes: int = 1
+    # Chunk programs one loop turn may enqueue before its decode block.
+    # 1 = one chunk a turn (a decode step between any two chunks).  N > 1
+    # drains a backlog of long prompts faster: up to N chunks go out back
+    # to back, a stream that ends in the turn hands its lane to the next
+    # waiting prompt in the same turn, and the live rows wait N chunk
+    # programs for their next token.  The device's work is the same; what
+    # moves is how long a prompt stands in ``num_requests_waiting``, which
+    # a gateway sheds on (queueThresholdCritical).
+    stream_burst: int = 1
     # Prefill-ahead depth: prompts prefilled while all decode slots are busy
     # wait here (KV held off-cache) and insert the instant a slot frees —
     # the decode batch never idles a slot waiting for a prefill, and the
@@ -567,15 +576,24 @@ class _ChunkStream:
 def _refuse_what_lanes_alone_serve(model_cfg, cfg: "EngineConfig",
                                    lora_manager, mesh) -> None:
     """A latent (MLA) cache, a recurrent state beside the K/V lanes (a
-    state-space mixer), and a stack of two kinds of layer with ring lanes
-    for its window layers are served from contiguous lanes on one device,
-    base model only.  Every other way to hold or move a row's state assumes
+    state-space mixer), a stack of two kinds of layer with ring lanes
+    for its window layers, and a stack some of whose layers are no
+    attention and hold a conv state (``models/shortconv.py``) are served
+    from contiguous lanes on one device, base model only.  Every other way to hold or move a row's state assumes
     that it is per-head K and V by position and nothing else, in one stack
     a layer: what a rebuilt, shared, shipped or rolled-back row would need
     of a state that is no function of positions, or of a ring that has
     already overwritten them, is not there.  Each is refused here by name
     rather than run wrong."""
-    if model_cfg.layer_pattern:
+    if model_cfg.conv_kernel:
+        kind = ("keeps a conv state (layers without attention, whose state "
+                "is no function of positions) beside the K/V lanes of its "
+                "attention layers")
+        loras = ("--max-loras above 0 (models/lora.py's buffers are scanned "
+                 "a layer a step, not a period, and name no conv target)")
+        extra = {"--prefill-batch above 1 (a grouped prefill's rows are "
+                 "cut out of one stack of K and V)": cfg.prefill_batch > 1}
+    elif model_cfg.layer_pattern:
         kind = ("scans a period of layer kinds over ring lanes beside its "
                 "full lanes")
         loras = ("--max-loras above 0 (models/lora.py's buffers are scanned "
@@ -651,6 +669,8 @@ class Engine:
         # A stack of two kinds of layer: full lanes and, for its window
         # layers, ring lanes (``transformer.init_decode_cache``).
         self._ringed = bool(model_cfg.layer_pattern)
+        # Layers without attention: a conv state a slot beside the lanes.
+        self._conv = bool(model_cfg.conv_kernel)
         # Positions a window layer's ring holds of a row (0: no window).
         self._window = min(model_cfg.sliding_window, self.cfg.max_seq_len)
         if self._latent or self._recurrent or self._ringed:
@@ -1395,10 +1415,11 @@ class Engine:
                          or self._slot_frequency.any())
         counts = self._counts() if penalized else self._counts_dummy
         self.profiler.note_stage_ops(STAGE_UPLOADS)
-        if self._recurrent:
+        if self._recurrent or self._conv:
             # Every step of the block rewrites the state of every row the
             # host holds (a row that stops mid-block counts on to its end).
-            self.profiler.note_ssm_rows(
+            (self.profiler.note_conv_rows if self._conv
+             else self.profiler.note_ssm_rows)(
                 n_steps * sum(s is not None for s in self.slots))
         # Step j of the block reads position + 1 + j rows of a live row's
         # lane.  With a block still unread the host record is that block's
@@ -1413,7 +1434,7 @@ class Engine:
             self.profiler.note_attn_grid_steps(sum(
                 pda.schedule_steps([p + j for p in at], *self._attn_tiles)
                 for j in range(1, n_steps + 1)))
-        if self._latent or self._window:
+        if self._latent or self._window or self._conv:
             held = sum(at) * n_steps + len(at) * n_steps * (n_steps + 1) // 2
             if self._latent:
                 self.profiler.note_latent_positions(held)
@@ -1741,8 +1762,8 @@ class Engine:
             raise ValueError(
                 f"{self.model_cfg.name}: the handoff wire "
                 "(server/kv_transfer.py) ships one stack of per-head K and "
-                "V only, not a latent cache, a recurrent state or ring "
-                "lanes")
+                "V only, not a latent cache, a recurrent state, a conv "
+                "state or ring lanes")
 
     def attach_prefilled(self, handoff) -> Request:
         """Admit a ``PrefillHandoff`` straight into decode (hop 2): the KV
@@ -3458,6 +3479,19 @@ class Engine:
             self._paged_free_row(st.slot_idx)
         self._finish(st.request, reason)
 
+    def _stream_turn(self) -> None:
+        """The chunk programs of one loop turn: one, or up to
+        ``stream_burst`` of them back to back.  A stream that ends inside a
+        burst frees its lane, so admission runs again and the next waiting
+        prompt's first chunk can go out in the same turn."""
+        for left in range(max(1, self.cfg.stream_burst), 0, -1):
+            if not self._streams:
+                return
+            lanes = len(self._streams)
+            self._stream_step()
+            if left > 1 and len(self._streams) < lanes:
+                self._admit_and_insert()
+
     @_in_phase("admit")
     def _stream_step(self) -> None:
         """Dispatch ONE chunk of ONE in-flight stream — the round-robin
@@ -3798,7 +3832,7 @@ class Engine:
         while self._running:
             did_work = self._admit_and_insert()
             if self._streams:
-                self._stream_step()
+                self._stream_turn()
                 did_work = True
             block = None
             if any(s is not None for s in self.slots):

@@ -181,6 +181,10 @@ class StepProfiler:
         # summed over the steps of the plain decode dispatches.  0 for a
         # model without a mixer.
         self.ssm_rows = 0
+        # Rows whose conv state (a gated short convolution's last inputs)
+        # a decode step shifted and rewrote, summed over the steps of the
+        # plain decode dispatches.  0 for a model without conv layers.
+        self.conv_rows = 0
         # Cache positions the decode steps' attention read of the live
         # rows' lanes, a layer of the kind, by the kind of lane
         # (metrics_registry.KV_LANES): the full lanes' grow with a row, a
@@ -466,6 +470,12 @@ class StepProfiler:
         with self._lock:
             self.ssm_rows += n
 
+    def note_conv_rows(self, n: int) -> None:
+        """Count ``n`` rows whose conv state the steps of one plain decode
+        dispatch rewrote (live rows x the block's steps)."""
+        with self._lock:
+            self.conv_rows += n
+
     def note_kv_positions(self, full: int, window: int) -> None:
         """Count the positions the steps of one plain decode dispatch read
         of the live rows' full lanes and of their rings, a layer of each
@@ -503,6 +513,7 @@ class StepProfiler:
                 "lora_target_reads": self.lora_target_reads,
                 "latent_positions": self.latent_positions,
                 "ssm_rows": self.ssm_rows,
+                "conv_rows": self.conv_rows,
                 "kv_positions": dict(zip(KV_LANES, self.kv_positions)),
                 "attn_grid_steps": self.attn_grid_steps,
                 "blocks_overlapped": self.blocks_overlapped,
@@ -596,6 +607,9 @@ def render_profile(hist: dict) -> list[str]:
     if "ssm_rows" in hist:
         lines += ["# TYPE tpu:ssm_state_rows_total counter",
                   f"tpu:ssm_state_rows_total {hist['ssm_rows']}"]
+    if "conv_rows" in hist:
+        lines += ["# TYPE tpu:conv_state_rows_total counter",
+                  f"tpu:conv_state_rows_total {hist['conv_rows']}"]
     kv_positions = hist.get("kv_positions")
     if kv_positions:
         lines.append("# TYPE tpu:kv_positions_read_total counter")

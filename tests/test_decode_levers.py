@@ -41,7 +41,7 @@ def params():
 
 
 def make_engine(params, *, adaptive=0, device_stops=True, steps=1, lanes=1,
-                slots=2, paged=False, blocks=None, max_seq=64,
+                burst=1, slots=2, paged=False, blocks=None, max_seq=64,
                 buckets=(8, 16)):
     return Engine(
         CFG, params,
@@ -50,6 +50,7 @@ def make_engine(params, *, adaptive=0, device_stops=True, steps=1, lanes=1,
             prefill_buckets=buckets,
             decode_steps_per_sync=steps, adaptive_steps=adaptive,
             device_stops=device_stops, stream_lanes=lanes,
+            stream_burst=burst,
             paged_kv_block=8 if paged else None, paged_kv_blocks=blocks,
         ),
         lora_manager=None, eos_id=None, dtype=jnp.float32,
@@ -346,6 +347,58 @@ class TestStreamLanes:
         assert got == want
         # The second long prompt streamed CONCURRENTLY with the first.
         assert max_active_2 == 2
+
+    @pytest.mark.parametrize("lanes", [1, 2])
+    def test_a_burst_gives_the_tokens_of_one_chunk_a_turn(self, params,
+                                                          lanes):
+        serial = make_engine(params, lanes=lanes, slots=4)
+        serial.start()
+        try:
+            want, _ = self._mixed(serial)
+        finally:
+            serial.stop()
+        burst = make_engine(params, lanes=lanes, burst=4, slots=4)
+        burst.start()
+        try:
+            got, _ = self._mixed(burst)
+        finally:
+            burst.stop()
+        assert got == want
+
+    @pytest.mark.parametrize("burst,first,second", [
+        (1, "one chunk in", "waits"),
+        (2, "two chunks in", "waits"),
+        (3, "done", "waits"),
+        (4, "done", "one chunk in"),
+        (8, "done", "done"),
+    ])
+    def test_the_chunks_of_one_turn(self, params, burst, first, second):
+        """Two prompts of three chunks each, one lane, the loop not
+        running: what one turn's chunk programs leave of them."""
+        eng = make_engine(params, burst=burst, slots=4)
+        rng = np.random.RandomState(3)
+        reqs = [Request(prompt_tokens=list(rng.randint(1, 250,
+                                                       size=self.LONG)),
+                        max_new_tokens=4,
+                        sampling=SamplingParams(temperature=0.0))
+                for _ in range(2)]
+        for r in reqs:
+            eng.submit(r)
+        eng._admit_and_insert()
+        eng._stream_turn()
+
+        def state(req):
+            if any(s is not None and s.request is req for s in eng.slots):
+                return "done"
+            for st in eng._streams:
+                if st.request is req:
+                    return {16: "one chunk in", 32: "two chunks in",
+                            0: "no chunk in"}[st.next_start]
+            return "waits"
+
+        assert [state(r) for r in reqs] == [first, second]
+        # A lane is one stream's at a time, whatever the burst.
+        assert len(eng._streams) <= 1
 
     def test_lane_pressure_gate_under_tiny_pool(self, params):
         """KV-pressure-aware admission: a pool too small for two whole

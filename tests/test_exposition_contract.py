@@ -157,6 +157,8 @@ def server_snapshot() -> dict:
     prof.note_overlapped_block()  # tpu:decode_blocks_overlapped_total
     prof.note_latent_positions(41)  # tpu:latent_kv_positions_total
     prof.note_attn_grid_steps(17)  # tpu:decode_attn_grid_steps_total
+    prof.note_conv_rows(23)  # tpu:conv_state_rows_total
+    prof.note_kv_positions(29, 0)  # tpu:kv_positions_read_total{lanes}
     return {
         "profile": prof.hist_state(),
         "model_name": HOSTILE,
@@ -290,6 +292,10 @@ def test_server_render_contract():
     assert families["tpu:decode_blocks_overlapped_total"][0].value == 1
     assert families["tpu:latent_kv_positions_total"][0].value == 41
     assert families["tpu:decode_attn_grid_steps_total"][0].value == 17
+    assert families["tpu:conv_state_rows_total"][0].value == 23
+    assert {s.labels["lanes"]: s.value
+            for s in families["tpu:kv_positions_read_total"]} == {
+                "full": 29, "window": 0}
     assert families["tpu:decode_stage_ops_total"][0].value == 0
     # Decode fast-path families (adaptive dispatch + stream lanes).
     assert families["tpu:stream_lanes"][0].value == 2

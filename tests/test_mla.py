@@ -544,6 +544,57 @@ def test_lane_kernel_compiles_for_the_v5e_with_its_dynamic_grid(one_chip):
     assert "decode_attention" in text and "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("op", ["decode", "flash", "chunk"])
+def test_packed_head_kernels_compile_for_the_v5e_at_lfm2s_widths(one_chip, op):
+    """LFM2's 64-wide heads (here, beside the other compiles for the chip:
+    one process may describe it): two kv heads to a 128-lane row, the
+    queries padded into their head's columns, the softmax's scale the
+    narrow head's; 64 slots x 8,192 positions over 3 layers, a 1,024-token
+    bucket and a 1,024-token chunk, as the cell runs them."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from llm_instance_gateway_tpu.ops import attention, pallas_attention
+
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    bf16, scale = jnp.bfloat16, 64 ** -0.5
+    pad = lambda q: attention.pad_queries(q, 8, 2)  # noqa: E731
+    own = lambda o: attention.own_values(o, 8, 2)  # noqa: E731
+    if op == "decode":
+        lanes = sd((3, 64, 8192, 4, 128), bf16)
+        fn = jax.jit(lambda q, k, v, lens, layer: own(
+            pda.decode_attention_pallas(pad(q), k, v, lens, layer=layer,
+                                        scale=scale)))
+        shapes = (sd((64, 32, 64), bf16), lanes, lanes, sd((64,), jnp.int32),
+                  sd((), jnp.int32))
+        name = "decode_attention"
+    elif op == "flash":
+        fn = jax.jit(lambda q, k, v: own(pallas_attention.flash_attention_bhsd(
+            pad(q).transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), scale=scale).transpose(0, 2, 1, 3)))
+        kv = sd((1, 1024, 4, 128), bf16)
+        shapes = (sd((1, 1024, 32, 64), bf16), kv, kv)
+        name = "flash_attention"
+    else:
+        fn = jax.jit(lambda q, k, v, start: own(
+            pallas_attention.chunk_attention_pallas(
+                pad(q), k, v, start,
+                block_q=pallas_attention.CHUNK_BLOCK_Q,
+                block_k=pallas_attention.CHUNK_BLOCK_K, scale=scale)))
+        lane = sd((1, 8192, 4, 128), bf16)
+        shapes = (sd((1, 1024, 32, 64), bf16), lane, lane, sd((), jnp.int32))
+        name = "chunk_attention"
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = fn.lower(*shapes).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert name in text and "tpu_custom_call" in text
+
+
 def test_ssm_update_kernel_compiles_for_the_v5e_at_published_widths(one_chip):
     """Falcon-H1-34B's state, 64 slots x 8 layers of 32 x 256 x 128 float32
     (2 GiB), through ``ssm_decode_update`` as the cell runs it (here, beside

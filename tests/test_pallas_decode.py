@@ -6,6 +6,11 @@ import numpy as np
 import pytest
 
 from llm_instance_gateway_tpu.ops.attention import decode_attention as xla_decode
+from llm_instance_gateway_tpu.ops.attention import (
+    own_values,
+    pack_heads,
+    pad_queries,
+)
 from llm_instance_gateway_tpu.ops import pallas_decode_attention as pda
 
 
@@ -18,9 +23,14 @@ def make_inputs(b=4, h=8, kv=2, hd=128, s=256, seed=0):
     return q, k, v, lengths
 
 
-# (query heads, kv heads): qwen2.5-7b's K = 4 at G = 7, mixtral's K = 8, and
-# the small layout the file has always used.
-HEAD_LAYOUTS = [(8, 2), (28, 4), (32, 8)]
+# (query heads, kv heads, head width): qwen2.5-7b's K = 4 at G = 7, mixtral's
+# K = 8, the small layout the file has always used, and LFM2's 64-wide heads
+# (8 kv heads, a group of 4), two to a 128-lane cache row.
+HEAD_LAYOUTS = [(8, 2, 128), (28, 4, 128), (32, 8, 128), (32, 8, 64)]
+
+
+def pack_of(hd):
+    return 128 // hd
 
 
 def stack_at(x, layer, n_layers=3):
@@ -31,33 +41,42 @@ def stack_at(x, layer, n_layers=3):
 
 
 class TestDecodeKernel:
-    @pytest.mark.parametrize("h,kv", HEAD_LAYOUTS)
+    @pytest.mark.parametrize("h,kv,hd", HEAD_LAYOUTS)
     @pytest.mark.parametrize("layer", [None, 0, 2])
-    def test_matches_reference(self, h, kv, layer):
+    def test_matches_reference(self, h, kv, hd, layer):
         """One layer's [B, S, K, hd] cache, and layer ``layer`` of a stacked
         [L, B, S, K, hd] cache read where it lies, equal the XLA reference
-        on that layer's slice."""
-        q, k, v, lengths = make_inputs(h=h, kv=kv)
+        on that layer's slice.  64-wide heads go in as the model hands them
+        over: two to a row, the queries padded into their head's columns,
+        the softmax's scale the narrow head's."""
+        q, k, v, lengths = make_inputs(h=h, kv=kv, hd=hd)
         ref = xla_decode(q, k, v, lengths)
+        pack = pack_of(hd)
+        k, v = pack_heads(k, pack), pack_heads(v, pack)
         if layer is not None:
             k, v = stack_at(k, layer), stack_at(v, layer)
-        got = pda.decode_attention_pallas(
-            q, k, v, lengths, layer=None if layer is None else jnp.int32(layer),
-            interpret=True)
+        got = own_values(pda.decode_attention_pallas(
+            pad_queries(q, kv, pack), k, v, lengths,
+            layer=None if layer is None else jnp.int32(layer),
+            interpret=True, scale=hd ** -0.5), kv, pack)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
                                    rtol=2e-5, atol=2e-5)
 
-    @pytest.mark.parametrize("h,kv", HEAD_LAYOUTS)
-    def test_dispatcher_reads_a_layer_of_the_stack(self, h, kv):
+    @pytest.mark.parametrize("h,kv,hd", HEAD_LAYOUTS)
+    def test_dispatcher_reads_a_layer_of_the_stack(self, h, kv, hd):
         """``decode_attention(layer=)``: the kernel (interpret) and the XLA
-        fallback (this backend) both read layer 1 of the stack."""
-        q, k, v, lengths = make_inputs(h=h, kv=kv, seed=11)
+        fallback (this backend) both read layer 1 of the stack, of packed
+        rows too."""
+        q, k, v, lengths = make_inputs(h=h, kv=kv, hd=hd, seed=11)
         ref = xla_decode(q, k, v, lengths)
-        ks, vs = stack_at(k, 1), stack_at(v, 1)
+        pack = pack_of(hd)
+        ks = stack_at(pack_heads(k, pack), 1)
+        vs = stack_at(pack_heads(v, pack), 1)
+        assert pda.lane_tiles(ks)[1] > 0  # the kernel takes the packed rows
         for interpret in (True, False):
             got = jax.jit(lambda q, ks, vs, lay: pda.decode_attention(
-                q, ks, vs, lengths, layer=lay, interpret=interpret))(
-                    q, ks, vs, jnp.int32(1))
+                q, ks, vs, lengths, layer=lay, interpret=interpret,
+                pack=pack))(q, ks, vs, jnp.int32(1))
             np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
                                        rtol=2e-5, atol=2e-5)
 

@@ -1008,6 +1008,7 @@ class TestXplaneGaps:
         for rel in ("llm_instance_gateway_tpu/models/transformer.py",
                     "llm_instance_gateway_tpu/models/mla.py",
                     "llm_instance_gateway_tpu/models/ssm.py",
+                    "llm_instance_gateway_tpu/models/shortconv.py",
                     "llm_instance_gateway_tpu/models/paged.py",
                     "llm_instance_gateway_tpu/models/lora.py",
                     "llm_instance_gateway_tpu/ops/layers.py",

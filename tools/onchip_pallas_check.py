@@ -321,6 +321,44 @@ def case_chunk(h, n_kv, hd, c, s_max, start):
     return out, ref, TOL_BF16
 
 
+def case_packed(op, h=32, n_kv=8, hd=64, pack=2, s_max=8192, start=3072):
+    """LFM2's 64-wide heads through the DISPATCHERS as the model calls
+    them: two kv heads to a 128-lane row (``xla_att.pack_heads``), the
+    queries as the model has them; against the XLA form on the heads
+    unpacked.  ``op``: "decode" (64 rows over layer 1 of [2, 64, S, 4, 128]
+    lanes), "flash" (a 1,024-token bucket) or "chunk" (a 1,024-token chunk
+    at ``start`` of an ``s_max`` lane)."""
+    kq, kk, kv = _keys(7, 3)
+    if op == "decode":
+        b = 64
+        q = jax.random.normal(kq, (b, h, hd), DTYPE)
+        kc = jax.random.normal(kk, (b, s_max, n_kv, hd), DTYPE)
+        vc = jax.random.normal(kv, (b, s_max, n_kv, hd), DTYPE)
+        lengths = jnp.asarray(_ragged(b, 768, 5632, 4), jnp.int32)
+        stacked = lambda x: jnp.stack([jnp.full_like(x, 100), x])
+        out = jax.jit(lambda q, kc, vc: pdec.decode_attention(
+            q, stacked(xla_att.pack_heads(kc, pack)),
+            stacked(xla_att.pack_heads(vc, pack)), lengths,
+            layer=jnp.int32(1), pack=pack))(q, kc, vc)
+        return out, jax.jit(xla_att.decode_attention)(q, kc, vc, lengths), TOL_BF16
+    s = 1024
+    q = jax.random.normal(kq, (1, s, h, hd), DTYPE)
+    if op == "flash":
+        k = jax.random.normal(kk, (1, s, n_kv, hd), DTYPE)
+        v = jax.random.normal(kv, (1, s, n_kv, hd), DTYPE)
+        out = jax.jit(lambda q, k, v: flash.flash_attention(
+            q, xla_att.pack_heads(k, pack), xla_att.pack_heads(v, pack),
+            pack=pack))(q, k, v)
+        return out, jax.jit(xla_att.prefill_attention)(q, k, v), TOL_BF16
+    kc = jax.random.normal(kk, (1, s_max, n_kv, hd), DTYPE)
+    vc = jax.random.normal(kv, (1, s_max, n_kv, hd), DTYPE)
+    off = jnp.int32(start)
+    out = jax.jit(lambda q, kc, vc, off: flash.chunk_attention(
+        q, xla_att.pack_heads(kc, pack), xla_att.pack_heads(vc, pack), off,
+        pack=pack))(q, kc, vc, off)
+    return out, jax.jit(xla_att.xla_chunk_attention)(q, kc, vc, off), TOL_BF16
+
+
 def case_moe(e, k, n, m, quant):
     """Skewed groups (expert 0 holds a quarter of the rows, one expert
     none) over layer 1 of a stack of two, against the XLA tiles on the same
@@ -577,6 +615,17 @@ def cases():
            pdec.mla_shape_reasons(4096, 640, 512),
            lambda: case_live_rows(20, 1, 640, 4096,
                                   _ragged(32, 300, 2500, 3), latent=512))
+    # LFM2: 64-wide heads, two kv heads to a cache row, by the dispatchers.
+    yield ("live-rows 64/64 x~2100 [lfm2-24b-a2b packed rows 4x128]",
+           pdec.shape_reasons(8192, 128, 4 * 128 * 2),
+           lambda: case_live_rows(32, 4, 128, 8192,
+                                  _ragged(64, 768, 5632, 4), n_layers=3,
+                                  calls=120))
+    for op, gate in (("decode", pdec.shape_reasons(8192, 128, 4 * 128 * 2)),
+                     ("flash", flash.shape_reasons(1024, 128)),
+                     ("chunk", flash.chunk_shape_reasons(1024, 8192, 128))):
+        yield (f"packed-heads {op} [lfm2-24b-a2b g=4 kv=8 hd64]", gate,
+               lambda op=op: case_packed(op))
     for label, h, n_kv, hd in LAYOUTS:
         for s in (128, 1024):
             yield (f"flash s={s} [{label}]", flash.shape_reasons(s, hd),

@@ -213,6 +213,15 @@ def ssm_rows_row(profile: dict) -> dict:
     return _per_decode_dispatch(profile, "ssm_rows", "rows_per_dispatch")
 
 
+def conv_rows_row(profile: dict) -> dict:
+    """Rows whose conv state (a gated short convolution's last inputs) the
+    decode steps rewrote (``tpu:conv_state_rows_total``); empty for a model
+    without conv layers."""
+    if not (profile.get("hist") or {}).get("conv_rows"):
+        return {}
+    return _per_decode_dispatch(profile, "conv_rows", "rows_per_dispatch")
+
+
 def kv_positions_rows(profile: dict) -> list[dict]:
     """Cache positions the decode steps read of the live rows' lanes, by
     the kind of lane (``tpu:kv_positions_read_total``); empty for a model
@@ -260,10 +269,11 @@ def overlap_row(profile: dict) -> dict:
 ANNOTATION_PREFIX = "engine."
 NO_ANNOTATION = "other"  # the bottom of the phase stack is not annotated
 # The jax.named_scope names the model code uses (models/transformer.py,
-# models/mla.py, models/ssm.py, models/paged.py, models/lora.py,
-# server/sampling.py, server/engine.py).
+# models/mla.py, models/ssm.py, models/shortconv.py, models/paged.py,
+# models/lora.py, server/sampling.py, server/engine.py).
 SCOPES = frozenset((
-    "embed", "attn.qkv", "attn.rope", "attn.kv_update", "attn.core",
+    "embed", "attn.qkv", "attn.qk_norm", "attn.rope", "attn.kv_update",
+    "attn.core", "conv.in_proj", "conv.mix", "conv.out_proj",
     "attn.core.window", "attn.out", "attn.q_latent", "attn.kv_latent", "attn.absorb",
     "attn.expand", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.update",
     "ssm.gate_norm", "ssm.out_proj", "mlp", "moe.route", "moe.dispatch",
@@ -721,6 +731,11 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
         out += ["", "Recurrent states rewritten by the decode steps:",
                 _table([recurrent], ("ssm_rows", "decode_dispatches",
                                      "rows_per_dispatch"))]
+    conv = conv_rows_row(profile)
+    if conv:
+        out += ["", "Conv states rewritten by the decode steps:",
+                _table([conv], ("conv_rows", "decode_dispatches",
+                                "rows_per_dispatch"))]
     lanes = kv_positions_rows(profile)
     if lanes:
         out += ["", "Cache positions read by the decode steps, a layer of "
