@@ -496,6 +496,12 @@ CLASSES = (
                              "run with the delta: the engine thread adds at "
                              "each such dispatch, the scrape reads under "
                              "the lock"),
+            SharedField("logprob_steps", LOCK_GUARDED,
+                        writers=("note_logprob_steps",),
+                        note="decode steps staged with a row that asked "
+                             "for logprobs: the engine thread adds at each "
+                             "such dispatch, the scrape reads under the "
+                             "lock"),
             SharedField("blocks_overlapped", LOCK_GUARDED,
                         writers=("note_overlapped_block",),
                         note="decode blocks dispatched over an unread one: "

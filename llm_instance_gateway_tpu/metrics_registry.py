@@ -479,6 +479,15 @@ SERVER_FAMILIES = (
            "targets a delta step reads: 2 where every resident adapter is "
            "on q and v, 7 where one carries them all.",
            SERVER_SURFACE),
+    Family("tpu:logprob_steps_total", "counter", (),
+           "Steps of the plain decode dispatches staged while a held slot's "
+           "request asked for logprobs: the steps whose program may take "
+           "the log-softmax and top-5 over [slots, vocabulary] (it does "
+           "where such a row is still live, for the whole batch; a step "
+           "nobody asked takes neither). Over tpu:dispatch_steps_sum, the "
+           "share of decode steps that pay for logprobs: 0 under traffic "
+           "that asks for none.",
+           SERVER_SURFACE),
     Family("tpu:decode_blocks_overlapped_total", "counter", (),
            "Decode blocks dispatched from the device carry while an earlier "
            "block was still unread: over the decode dispatches "

@@ -64,9 +64,9 @@ from llm_instance_gateway_tpu.tracing import LATENCY_BUCKETS, Histogram
 logger = logging.getLogger(__name__)
 
 # Top-K alternatives computed device-side per step (the OpenAI completions
-# API maximum).  Always computed — one compiled program for the whole batch,
-# and a [B, K] top_k is noise next to the layer matmuls; the host stores
-# values only for requests that asked.
+# API maximum): one compiled program for the whole batch, which computes
+# them in the steps where a live row asked (``_logprobs_if_asked``); the
+# host stores values only for requests that asked.
 LOGPROB_TOPK = 5
 MAX_LOGIT_BIAS = 32  # per-request logit_bias entries (static lanes)
 
@@ -91,6 +91,8 @@ _SLOT_I32 = (
     # row's position, budget and stop history from this buffer and not from
     # the device carry (``_stage_carry``).
     ("fresh", (), 0),
+    # 1 while the row's request asked for logprobs (``_logprobs_if_asked``).
+    ("logprobs", (), 0),
 )
 _SLOT_F32 = (
     ("temp", (), 0.0), ("topp", (), 1.0), ("presence", (), 0.0),
@@ -215,8 +217,7 @@ def _publish(req: "Request") -> None:
     req.stream_event.set()
 
 
-@jax.named_scope("logprobs")
-def _logprob_info(logits, sampled, valid_vocab: int):
+def _logprob_values(logits, sampled, valid_vocab: int):
     """(sampled-token logprob, top-K logprobs, top-K ids) from raw logits.
 
     Model logprobs (pre-temperature), padded-vocab positions masked out —
@@ -229,6 +230,26 @@ def _logprob_info(logits, sampled, valid_vocab: int):
     sampled_lp = jnp.take_along_axis(logp, sampled[..., None], axis=-1)[..., 0]
     top_v, top_i = jax.lax.top_k(logp, LOGPROB_TOPK)
     return sampled_lp, top_v, top_i
+
+
+_logprob_info = jax.named_scope("logprobs")(_logprob_values)
+
+
+@jax.named_scope("logprobs")
+def _logprobs_if_asked(asked, logits, sampled, valid_vocab: int):
+    """``_logprob_info`` where ``asked`` (a scalar on the device: some live
+    row of the step wants logprobs), zeros of its shapes where not.  One
+    ``lax.cond`` inside the caller's program, as ``sample_routed`` chooses
+    its path: a step nobody asked pays no pass over ``[B, V]``, a step one
+    row asked pays for the whole batch, and a row's values never depend on
+    what its neighbours asked."""
+    def values():
+        return _logprob_values(logits, sampled, valid_vocab)
+
+    return jax.lax.cond(
+        asked, values,
+        lambda: jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype),
+                             jax.eval_shape(values)))
 
 
 @dataclass
@@ -871,6 +892,7 @@ class Engine:
         self._slot_stop_lens = i32["stop_lens"]
         self._slot_stop_hist = i32["stop_hist"]
         self._slot_fresh = i32["fresh"]
+        self._slot_logprobs = i32["logprobs"]
         # Count of rows with programmed device stop lanes: excludes
         # speculative dispatch (the spec block does not evaluate the
         # automaton, so its history carry would go stale mid-generation).
@@ -1222,7 +1244,7 @@ class Engine:
         f32 = _slot_views(slots_f32, _SLOT_F32, c0)
         slot_ids, topk, seeds = i32["lora"], i32["topk"], i32["seed"]
         bias_ids, stop_ids = i32["bias_ids"], i32["stop_ids"]
-        stop_lens = i32["stop_lens"]
+        stop_lens, want_lp = i32["stop_lens"], i32["logprobs"] > 0
         temp, topp, bias_vals = f32["temp"], f32["topp"], f32["bias_vals"]
         presence, frequency = f32["presence"], f32["frequency"]
         tokens, positions, remaining, stop_hist = _stage_carry(carry, i32)
@@ -1254,8 +1276,9 @@ class Engine:
                 valid_vocab=model_cfg.vocab_size,
                 seeds=seeds, positions=safe_pos,
                 bias_ids=bias_ids, bias_vals=bias_vals, live=active)
-            lp, top_v, top_i = _logprob_info(
-                logits, sampled, model_cfg.vocab_size)
+            lp, top_v, top_i = _logprobs_if_asked(
+                jnp.any(want_lp & active), logits, sampled,
+                model_cfg.vocab_size)
             valid = active
             # EOS emitted now is a valid token but deactivates the row.
             hit_eos = valid & (sampled == eos_id)
@@ -1453,6 +1476,8 @@ class Engine:
             self.profiler.note_lora_target_reads(n_steps * len(targets))
         elif self.lora is not None:
             self.profiler.note_lora_free_steps(n_steps)
+        if self._slot_logprobs.any():
+            self.profiler.note_logprob_steps(n_steps)
         with self._enqueue("engine.decode.enqueue"):
             (*outs, carry, self._rng, counts, self.cache, moe) = (
                 self._traced(
@@ -2029,6 +2054,7 @@ class Engine:
         # host let the row go (_stage_carry).
         self._slot_remaining[i] = 0
         self._slot_fresh[i] = 0
+        self._slot_logprobs[i] = 0
         if self._slot_stop_lens[i].any():
             self._slot_stop_ids[i] = -1
             self._slot_stop_lens[i] = 0
@@ -3559,6 +3585,7 @@ class Engine:
         self._slot_topk[slot_idx] = sp.top_k
         self._slot_topp[slot_idx] = sp.top_p
         self._slot_seed[slot_idx] = _seed_i32(sp.seed)
+        self._slot_logprobs[slot_idx] = slot.request.logprobs is not None
         self._slot_presence[slot_idx] = sp.presence_penalty
         self._slot_frequency[slot_idx] = sp.frequency_penalty
         (self._slot_bias_ids[slot_idx],
@@ -3969,7 +3996,11 @@ class Engine:
         self._paged_ensure_decode(n_steps)
         ph.to("decode.stage")
         t0 = time.perf_counter()
-        (toks, valid, lps, top_v, top_i, paths), carry, moe = (
+        # A block staged with no row that asked for logprobs computed none
+        # (``_logprobs_if_asked``): its three arrays of zeros stay on the
+        # device, neither fetched nor walked.
+        asked = self._slot_logprobs.any()
+        (toks, valid, *lp, paths), carry, moe = (
             self._enqueue_decode(
                 n_steps, (self._dev_tokens, self._dev_positions,
                           self._dev_remaining, self._dev_stop_hist)))
@@ -3978,7 +4009,8 @@ class Engine:
         # The sampler's paths ride with the routing counts: small arrays
         # the block's one readback brings back beside the tokens.
         tail = [paths, *moe]
-        for arr in (toks, valid, lps, top_v, top_i, *tail):
+        lp = tuple(lp) if asked else ()
+        for arr in (toks, valid, *lp, *tail):
             try:
                 arr.copy_to_host_async()
             except AttributeError:
@@ -3987,9 +4019,7 @@ class Engine:
             "tail": tail,
             "toks": toks,
             "valid": valid,
-            "lps": lps,
-            "top_v": top_v,
-            "top_i": top_i,
+            "lp": lp,  # (lps, top_v, top_i), or () where no row asked
             "rows": list(self.slots),  # request refs valid at dispatch time
             "n_steps": n_steps,
             "t0": t0,
@@ -4055,9 +4085,7 @@ class Engine:
         return {
             "toks": toks,
             "valid": valid,
-            "lps": lps,
-            "top_v": top_v,
-            "top_i": top_i,
+            "lp": (lps, top_v, top_i),
             "rows": list(self.slots),
             "n_steps": n_cycles * (k + 1),
             "t0": t0,
@@ -4079,8 +4107,7 @@ class Engine:
         device step; for a block staged on an idle device it is stage +
         wait."""
         outs = jax.block_until_ready(
-            (blk["toks"], blk["valid"], blk["lps"], blk["top_v"],
-             blk["top_i"], *blk.get("tail", ())))
+            (blk["toks"], blk["valid"], *blk["lp"], *blk.get("tail", ())))
         done = time.perf_counter()
         t0 = max(blk["t0"], self._last_done_pc)
         step_s = done - t0
@@ -4089,10 +4116,11 @@ class Engine:
         # [n_steps, B] each.  One device_get for the lot: the copies start
         # together and the thread waits once, where one np.asarray per
         # array waited in turn (2.3-2.4 ms a step for five on the v5e
-        # host; ledger, PR 25).  A plain block's tail is its sampler
-        # paths, then routing counts; a speculative block has none.
-        toks_np, valid_np, lps_np, top_v_np, top_i_np, *tail = (
-            jax.device_get(outs))
+        # host; ledger, PR 25).  The logprob triplet comes where a row of
+        # the block asked.  A plain block's tail is its sampler paths, then
+        # routing counts; a speculative block has none.
+        toks_np, valid_np, *rest = jax.device_get(outs)
+        lp_np, tail = rest[:len(blk["lp"])], rest[len(blk["lp"]):]
         if tail:
             self.profiler.note_sample_paths(tail[0])
             self._moe_account(tail[1:])
@@ -4122,8 +4150,8 @@ class Engine:
                     continue  # device froze this row (budget/EOS)
                 tok = int(toks_np[k, i])
                 req.output_tokens.append(tok)
-                self._store_logprobs(req, lps_np[k, i], top_v_np[k, i],
-                                     top_i_np[k, i])
+                if lp_np:
+                    self._store_logprobs(req, *(a[k, i] for a in lp_np))
                 # Per-step emission: each token of the fused block is
                 # published to the stream consumer as it lands in the
                 # trim walk, not once per dispatch — an SSE reader wakes

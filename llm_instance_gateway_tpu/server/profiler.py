@@ -173,6 +173,10 @@ class StepProfiler:
         # targets a delta step reads (all seven where a resident adapter
         # carries them all).
         self.lora_target_reads = 0
+        # Steps of the plain decode dispatches staged while a held slot's
+        # request asked for logprobs: the steps whose program may take the
+        # log-softmax and top-K over [B, V] (0 where nobody asks).
+        self.logprob_steps = 0
         # Cache rows the latent (MLA) decode kernel had to read: the live
         # rows' cache lengths, summed over the steps of the plain decode
         # dispatches.  0 for a model with per-head K/V lanes.
@@ -458,6 +462,12 @@ class StepProfiler:
         with self._lock:
             self.lora_target_reads += n
 
+    def note_logprob_steps(self, n: int) -> None:
+        """Count the ``n`` steps of one plain decode dispatch staged with a
+        row whose request asked for logprobs."""
+        with self._lock:
+            self.logprob_steps += n
+
     def note_latent_positions(self, n: int) -> None:
         """Count ``n`` cache positions a latent model's live rows held over
         the steps of one plain decode dispatch."""
@@ -511,6 +521,7 @@ class StepProfiler:
                 "lora_rows": self.lora_rows,
                 "lora_free_steps": self.lora_free_steps,
                 "lora_target_reads": self.lora_target_reads,
+                "logprob_steps": self.logprob_steps,
                 "latent_positions": self.latent_positions,
                 "ssm_rows": self.ssm_rows,
                 "conv_rows": self.conv_rows,
@@ -600,6 +611,9 @@ def render_profile(hist: dict) -> list[str]:
         lines += ["# TYPE tpu:lora_target_reads_total counter",
                   "tpu:lora_target_reads_total "
                   f"{hist['lora_target_reads']}"]
+    if "logprob_steps" in hist:
+        lines += ["# TYPE tpu:logprob_steps_total counter",
+                  f"tpu:logprob_steps_total {hist['logprob_steps']}"]
     if "latent_positions" in hist:
         lines += ["# TYPE tpu:latent_kv_positions_total counter",
                   "tpu:latent_kv_positions_total "

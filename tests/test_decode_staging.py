@@ -516,10 +516,11 @@ def test_mirror_is_a_view_of_its_buffer(idle_engine, name):
 
 
 def test_buffers_hold_the_mirrors_fields_and_nothing_else(idle_engine):
-    # ... but the mark of a row activated since the last block (PR 40),
-    # which no mirror of the parent's stood for.
+    # ... but the mark of a row activated since the last block (PR 40) and
+    # the mark of a row that asked for logprobs (PR 56), which no mirror of
+    # the parent's stood for.
     assert sorted(n for n, _, _ in _SLOT_I32 + _SLOT_F32) == sorted(
-        [*PARENT_MIRRORS, "fresh"])
+        [*PARENT_MIRRORS, "fresh", "logprobs"])
     for fields, buf in ((_SLOT_I32, idle_engine._slots_i32),
                         (_SLOT_F32, idle_engine._slots_f32)):
         assert buf.ndim == 1 and buf.size == SLOTS * sum(

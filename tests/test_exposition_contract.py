@@ -154,6 +154,7 @@ def server_snapshot() -> dict:
     prof.note_lora_rows(3)  # tpu:lora_rows_total
     prof.note_lora_free_steps(5)  # tpu:lora_free_steps_total
     prof.note_lora_target_reads(14)  # tpu:lora_target_reads_total
+    prof.note_logprob_steps(9)  # tpu:logprob_steps_total
     prof.note_overlapped_block()  # tpu:decode_blocks_overlapped_total
     prof.note_latent_positions(41)  # tpu:latent_kv_positions_total
     prof.note_attn_grid_steps(17)  # tpu:decode_attn_grid_steps_total
@@ -289,6 +290,7 @@ def test_server_render_contract():
     assert families["tpu:lora_rows_total"][0].value == 3
     assert families["tpu:lora_free_steps_total"][0].value == 5
     assert families["tpu:lora_target_reads_total"][0].value == 14
+    assert families["tpu:logprob_steps_total"][0].value == 9
     assert families["tpu:decode_blocks_overlapped_total"][0].value == 1
     assert families["tpu:latent_kv_positions_total"][0].value == 41
     assert families["tpu:decode_attn_grid_steps_total"][0].value == 17

@@ -774,6 +774,39 @@ class TestPhaseReport:
                        for ln in render_profile(old["hist"]))
 
 
+    @pytest.mark.parametrize("asked", [(0, 0, 0, 0), (1, 0, 4, 0)],
+                             ids=["nobody-asks", "two-blocks-ask"])
+    def test_report_prints_logprob_steps_per_dispatch(self, asked):
+        """``tpu:logprob_steps_total`` (``note_logprob_steps``): in
+        ``/metrics``, in ``/debug/profile``'s ``hist`` and over the decode
+        dispatches in the report, 0 included; left out for a payload from
+        before the counter."""
+        clock = FakeClock()
+        p = StepProfiler(capacity=8, clock=clock)
+        for steps in asked:
+            if steps:
+                p.note_logprob_steps(steps)
+            p.note_dispatch("decode", clock.now, 0.01, active=3,
+                            total_slots=4)
+            clock.tick(0.02)
+        total = sum(asked)
+        assert p.snapshot()["hist"]["logprob_steps"] == total
+        assert profile_report.logprob_steps_row(p.snapshot()) == {
+            "logprob_steps": total, "decode_dispatches": 4,
+            "logprob_steps_per_dispatch": total / 4}
+        out = profile_report.render_report(p.snapshot())
+        assert "asked for logprobs" in out
+        assert "logprob_steps_per_dispatch" in out
+        lines = render_profile(p.hist_state())
+        assert "# TYPE tpu:logprob_steps_total counter" in lines
+        assert f"tpu:logprob_steps_total {total}" in lines
+        old = p.snapshot()
+        del old["hist"]["logprob_steps"]
+        assert profile_report.logprob_steps_row(old) == {}
+        assert "logprobs" not in profile_report.render_report(old)
+        assert not any("logprob_steps" in ln
+                       for ln in render_profile(old["hist"]))
+
     def test_report_prints_latent_rows_per_dispatch(self):
         """``tpu:latent_kv_positions_total`` (``note_latent_positions``):
         in ``/metrics`` always, in the report only for a latent model."""

@@ -27,7 +27,11 @@ row tile and with two groups in two and three; and the ``moe-dispatch``
 cases what the expert dispatch's way in costs alone (the token rows into the
 expert-grouped layout) by the row scatter and by the gather from the sorted
 layout, at the cells' chunk, prompt and decode shapes: where
-``transformer._SCATTER_MAX_ASSIGN`` comes from.
+``transformer._SCATTER_MAX_ASSIGN`` comes from; and the ``decode-tail``
+cases what a decode step's tail costs alone (the head's logits -> sampler
+-> logprobs) at Falcon-H1's, Qwen's and LFM2's slots x vocabulary, asked for
+and not asked for, and a digest of the asked branch's three outputs beside
+the unconditional call's (the program's before PR 56).
 
     python tools/onchip_pallas_check.py            # on the chip
 """
@@ -59,6 +63,8 @@ from llm_instance_gateway_tpu.ops import pallas_attention as flash
 from llm_instance_gateway_tpu.ops import pallas_decode_attention as pdec
 from llm_instance_gateway_tpu.ops import pallas_moe as pmoe
 from llm_instance_gateway_tpu.ops import pallas_ssm as pssm
+from llm_instance_gateway_tpu.server import engine as engine_lib
+from llm_instance_gateway_tpu.server.sampling import sample_routed
 
 # Parity bound, per element: |kernel - reference| <= TOL * max(1, |reference|).
 # Both sides accumulate in f32 and round the output once to bf16 (half an
@@ -507,6 +513,92 @@ def case_moe_dispatch(t, k, e, d, calls=100, runs=100):
     return jax.jit(gather)(*args), jax.jit(scatter)(*args), 0.0
 
 
+def case_decode_tail(b, v, calls=50):
+    """A decode step's tail alone, as ``Engine._decode_impl`` makes it after
+    ``lm_head``: float32 logits ``[b, v]`` of greedy rows, each with a
+    ``logit_bias`` -> ``sample_routed`` -> the logprobs under
+    ``_logprobs_if_asked``, a program of ``calls`` steps whose logits hang on
+    the step before (materialised once a step behind a barrier, as the head
+    writes them), run with the predicate true and false (one program, the
+    predicate its argument).  Prints us a step, least of five, and the
+    sha256 of the three outputs of one asked call beside the unconditional
+    call's (``_logprob_info``, the program before PR 56): the tokens and the
+    top-K ids have to agree, the log-probabilities to float32 rounding (on
+    the CPU the digests are equal; on the v5e the two programs sum a row's
+    exponentials in another order and differ by ~2e-6 in a value of ~15).
+    The times order the two branches and are no measure of either inside
+    ``jit_decode_block``: asked less not asked read 1.66 ms here at
+    ``[64, 261120]`` where the traced program's ``logprobs`` scope reads
+    0.54 ms a step with every step asked (``PERF.md`` section 6, PR 56),
+    and the unconditional form in this loop 22 ms, so it is not timed.  A
+    tree without ``_logprobs_if_asked`` prints the unconditional call's
+    digest alone."""
+    import hashlib
+
+    kl, kb = _keys(13, 2)
+    f32 = jnp.float32
+    logits = 4.0 * jax.random.normal(kl, (b, v), f32)
+    bias_ids = jax.random.randint(kb, (b, engine_lib.MAX_LOGIT_BIAS), 32, 127)
+    bias_vals = jnp.full(bias_ids.shape, 8.0, f32)
+    rows = jnp.zeros((b,), jnp.int32)
+    gate = getattr(engine_lib, "_logprobs_if_asked", None)
+    forms = {"every step": lambda asked, lg, tok: engine_lib._logprob_info(
+        lg, tok, v)}
+    if gate is not None:
+        forms["gated"] = lambda asked, lg, tok: gate(asked, lg, tok, v)
+
+    def tail(form, asked, lg):
+        tok, _ = sample_routed(
+            lg, jax.random.PRNGKey(0), rows.astype(f32), rows,
+            jnp.ones((b,), f32), valid_vocab=v, seeds=rows - 1,
+            positions=rows, bias_ids=bias_ids, bias_vals=bias_vals,
+            live=rows == 0)
+        return (tok, *form(asked, lg, tok))
+
+    def us_a_step(form, asked):
+        @jax.jit
+        def loop(asked, logits):
+            def body(c, _):  # c stays 0, which the compiler cannot know
+                lg = jax.lax.optimization_barrier(logits + c)
+                tok, lp, top_v, top_i = tail(form, asked, lg)
+                return c + (lp[0] + top_v[0, 0] + tok[0] + top_i[0, 0]
+                            > 3e9).astype(f32), None
+            return jax.lax.scan(body, f32(0), None, length=calls)[0]
+
+        def run():
+            loop(asked, logits).block_until_ready()
+        return _us_a_call(run, calls)[0]
+
+    def digest(form):
+        outs = jax.jit(lambda asked, lg: tail(form, asked, lg))(True, logits)
+        return outs, hashlib.sha256(b"".join(
+            np.asarray(a).tobytes() for a in outs[1:])).hexdigest()[:16]
+
+    want, want_digest = digest(forms["every step"])
+    line = f"TIME   decode-tail [{b}, {v}]:"
+    got = want
+    if gate is not None:
+        got, got_digest = digest(forms["gated"])
+        apart = max(float(jnp.max(jnp.abs(g - w)))
+                    for g, w in zip(got[1:3], want[1:3]))
+        line += (f" asked {us_a_step(forms['gated'], True):.1f} us a step, "
+                 f"not asked {us_a_step(forms['gated'], False):.1f} (least "
+                 f"of five programs of {calls}); sha256 of the asked outputs "
+                 f"{got_digest}, the largest difference of a "
+                 f"log-probability {apart:.3g};")
+        if not (bool(jnp.all(got[0] == want[0]))
+                and bool(jnp.all(got[3] == want[3]))):
+            raise AssertionError("the asked branch's tokens or top-K ids "
+                                 "are not the unconditional call's")
+    print(f"{line} sha256 of the unconditional call's {want_digest}; one "
+          f"pass over the float32 logits at 819 GB/s "
+          f"{b * v * 4 / 819e3:.1f} us", flush=True)
+    # Two compiled programs sum a row's 65-261k exponentials in another
+    # order: float32 rounding of a log-probability of size ~15.
+    return (jnp.concatenate([got[1][:, None], got[2]], axis=1),
+            jnp.concatenate([want[1][:, None], want[2]], axis=1), 1e-5)
+
+
 def case_ssm_update(n_live, b=64, h=32, g=2, n=256, p=128, n_layers=8,
                     calls=160):
     """``ssm_decode_update`` as a decode step of ``b`` slots runs it at the
@@ -574,6 +666,11 @@ def case_ssm_update(n_live, b=64, h=32, g=2, n=256, p=128, n_layers=8,
 
 def cases():
     """(name, gate reasons, thunk) for every kernel x layout x shape."""
+    for label, b, v in (("falcon-h1-34b", 64, 261120),
+                        ("qwen2.5-7b", 32, 152064),
+                        ("lfm2-24b-a2b", 64, 65536)):
+        yield (f"decode-tail [{label} {b}x{v}]", [],
+               lambda b=b, v=v: case_decode_tail(b, v))
     for n_live in (64, 8, 1):
         yield (f"ssm-update {n_live}/64 live [falcon-h1-34b 32x256x128]",
                pssm.shape_reasons(32, 2, 256, 128),

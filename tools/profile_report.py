@@ -195,6 +195,15 @@ def lora_rows_row(profile: dict) -> dict:
     return row
 
 
+def logprob_steps_row(profile: dict) -> dict:
+    """Decode steps staged with a row whose request asked for logprobs
+    (``tpu:logprob_steps_total``): per dispatch is a share of the steps
+    where dispatches are one step long; empty for a payload from before the
+    counter."""
+    return _per_decode_dispatch(profile, "logprob_steps",
+                                "logprob_steps_per_dispatch")
+
+
 def latent_positions_row(profile: dict) -> dict:
     """Cache positions a latent (MLA) model's live rows held, summed over
     the decode steps (``tpu:latent_kv_positions_total``); empty for a model
@@ -721,6 +730,12 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
                                 "lora_target_reads",
                                 "target_reads_per_dispatch")
                     if k in adapter_rows))]
+    asked = logprob_steps_row(profile)
+    if asked:
+        out += ["", "Decode steps staged with a row that asked for "
+                "logprobs:",
+                _table([asked], ("logprob_steps", "decode_dispatches",
+                                 "logprob_steps_per_dispatch"))]
     latent = latent_positions_row(profile)
     if latent:
         out += ["", "Latent cache rows read by the decode steps:",
