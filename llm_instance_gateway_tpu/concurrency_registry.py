@@ -532,10 +532,17 @@ CLASSES = (
                         note="grid steps the decode kernel's schedule held: "
                              "the engine thread adds at each dispatch, the "
                              "scrape reads under the lock"),
+            SharedField("prompt", LOCK_GUARDED,
+                        writers=("note_prompt_program",
+                                 "note_prompt_done"),
+                        note="the prompt programs' counts, positions and "
+                             "seconds by program: the engine thread adds "
+                             "where one is enqueued and where it is seen "
+                             "complete, the scrape reads under the lock"),
             SharedField("_last_end", OWNER_PRIVATE,
-                        writers=("note_dispatch",)),
+                        writers=("_chain",)),
             SharedField("_idle_pending", OWNER_PRIVATE,
-                        writers=("note_dispatch", "note_idle")),
+                        writers=("_chain", "note_idle")),
             SharedField("_prev_active", OWNER_PRIVATE,
                         writers=("note_dispatch",)),
             SharedField("_split_mark", OWNER_PRIVATE,
@@ -685,10 +692,18 @@ CLASSES = (
                         writers=("_loop",),
                         note="the decode block dispatched and not read"),
             SharedField("_last_done_pc", OWNER_PRIVATE,
-                        writers=("_process_block", "_read_first_tokens"),
+                        writers=("_process_block", "_read_first_tokens",
+                                 "_prompt_programs_done", "_see_inflight"),
                         note="when the loop last saw the device complete "
-                             "a block or a prefill: the step clock's "
-                             "anchor"),
+                             "a block or a prompt program: the completion "
+                             "chain's anchor"),
+            SharedField("_prompt_pending", OWNER_PRIVATE,
+                        writers=("_note_prompt_program",
+                                 "_prompt_programs_done"),
+                        note="prompt programs enqueued and not seen "
+                             "complete yet, in the device queue's order"),
+            SharedField("_prompt_enqueued", OWNER_PRIVATE,
+                        writers=("_note_prompt_program",)),
             SharedField("_prev_dispatch_steps", OWNER_PRIVATE,
                         writers=("_loop", "_paged_ensure_decode")),
             SharedField("decode_tps_ema", SWAP_PUBLISHED,

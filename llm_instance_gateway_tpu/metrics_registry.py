@@ -59,6 +59,10 @@ SAMPLE_PATHS = ("argmax", "draw", "filtered")
 # The ``lanes`` label set of ``tpu:kv_positions_read_total``: the two kinds
 # of cache lane a model with window layers keeps (models/transformer.py).
 KV_LANES = ("full", "window")
+# The ``program`` label set of the ``tpu:prompt_*`` families: the jitted
+# programs that compute a prompt (server/engine.py: jit_prefill,
+# jit_prefill_many, jit_prefill_chunk and the sequence-parallel ring).
+PROMPT_PROGRAMS = ("prefill", "prefill_many", "chunk", "ring")
 
 GATEWAY_FAMILIES = (
     Family("gateway_requests_total", "counter", ("model",),
@@ -496,6 +500,27 @@ SERVER_FAMILIES = (
            "before the host read the last. 0: every block was staged with "
            "none in flight (the device had run dry).",
            SERVER_SURFACE),
+    Family("tpu:prompt_programs_total", "counter", ("program",),
+           "Prompt programs enqueued, by the jitted program: prefill (one "
+           "prompt at its bucket) | prefill_many (same-bucket prompts in one "
+           "call) | chunk (a piece of a streamed prompt, or the suffix behind "
+           "a cached prefix) | ring (sequence-parallel); "
+           "metrics_registry.PROMPT_PROGRAMS.", SERVER_SURFACE),
+    Family("tpu:prompt_positions_total", "counter", ("program", "kind"),
+           "Positions the prompt programs computed, counted where each is "
+           "enqueued: kind=real the prompt's tokens, kind=pad the padding up "
+           "to the program's shape (a grouped program: rows x bucket). pad "
+           "over the sum is the share of the prompt programs' work that is "
+           "thrown away.", SERVER_SURFACE),
+    Family("tpu:prompt_program_seconds_total", "counter", ("program",),
+           "Device-queue time of the prompt programs: each one's interval on "
+           "the loop's completion chain, from the later of its enqueue and "
+           "the last completion the loop saw to its own completion (programs "
+           "seen complete together share theirs by positions). With the "
+           "decode blocks' intervals (tpu:dispatch_wall_seconds_sum, phases "
+           "decode and spec) it tiles the time the device's queue was busy; "
+           "over tpu:prompt_programs_total, seconds a program.",
+           SERVER_SURFACE),
     Family("tpu:prefill_seconds", "histogram", ("model", "role"),
            "Prefill compute latency.", SERVER_SURFACE),
     Family("tpu:handoff_seconds", "histogram", ("model", "role"),
@@ -526,8 +551,11 @@ SERVER_FAMILIES = (
            "Slot-seconds decode dispatches ran with empty rows (pool "
            "waste).", SERVER_SURFACE),
     Family("tpu:prefill_padding_tokens_total", "counter", (),
-           "Prompt tokens prefilled as bucket/ring padding and thrown "
-           "away (pool waste).", SERVER_SURFACE),
+           "Prompt positions computed as padding and thrown away (pool "
+           "waste): a bucket's, a group's, a ring's and, since PR 57, the "
+           "chunk stream's (every piece of a streamed prompt goes out at "
+           "the largest bucket). By construction the sum over programs of "
+           "tpu:prompt_positions_total{kind=\"pad\"}.", SERVER_SURFACE),
     Family("tpu:decode_batch_occupancy", "histogram", (),
            "Active-slots / total-slots fraction per decode dispatch.",
            SERVER_SURFACE),

@@ -207,6 +207,11 @@ def _in_phase(name: str, hand_over: bool = False):
     return deco
 
 
+def _is_ready(array) -> bool:
+    """Whether the device has produced ``array``; never waits."""
+    return array.is_ready()
+
+
 def _publish(req: "Request") -> None:
     """Wake the consumer of ``req``'s stream (engine thread: a token was
     appended, or the request finished).  ``t_emit`` keeps the oldest stamp
@@ -474,8 +479,12 @@ class Request:
     t_done: float = 0.0
     # What the engine knows of this request's admission, for the transport's
     # ``engine.prefill`` span: ``prompt_tokens``, ``bucket``, ``rows`` (rows
-    # decoding when it was admitted: the rows it stalls) and, once the
-    # admission is settled, ``stage_s`` / ``wait_s`` / ``emit_s``.
+    # decoding when it was admitted: the rows it stalls); what the prompt
+    # cost the device: ``programs`` enqueued for it, the ``positions`` they
+    # computed (its padding included; of a grouped program its own row) and
+    # ``device_s``, the sum of their intervals on the completion chain; and,
+    # once the admission is settled, ``stage_s`` / ``wait_s`` / ``emit_s``
+    # (a prompt streamed in chunks: summed over its chunks).
     prefill_attrs: dict = field(default_factory=dict)
     done: threading.Event = field(default_factory=threading.Event)
     # Incremental consumption point for streaming responses.
@@ -578,6 +587,18 @@ class _WaitingPrefill:
     # handoff that sat parked past the TTL is abandoned work (the gateway
     # that posted it gave up and rerouted) and is swept instead of slotted.
     t_parked: float = 0.0
+
+
+@dataclass
+class _PromptProgram:
+    """A prompt program on the device's queue that the loop has not seen
+    complete yet (``Engine._note_prompt_program`` / ``_prompt_programs_done``)."""
+
+    program: str  # metrics_registry.PROMPT_PROGRAMS
+    positions: int  # computed, padding included
+    t0: float  # its enqueue, on perf_counter
+    out: object  # a result of it: ready once the program is done
+    reqs: tuple  # the requests whose prompt it computes
 
 
 @dataclass
@@ -963,8 +984,8 @@ class Engine:
         # from the engine loop, so the dispatch/host-sync/idle attribution
         # tiles the engine thread's wall.
         self.profiler = StepProfiler(annotate=jax.profiler.TraceAnnotation)
-        # Requests whose first token is out and whose admission's phase
-        # parts are not booked yet (_settle_admissions).
+        # Requests with prompt work since the phase parts were last handed
+        # out (_settle_admissions): a first token emitted, a chunk staged.
         self._unsettled: list[Request] = []
         # The loop's state.  First tokens still on the device, in the
         # order their prefills were enqueued, as (request, token,
@@ -975,6 +996,12 @@ class Engine:
         # anchor (_process_block).
         self._first_unread: list[tuple] = []
         self._inflight: dict | None = None
+        # Prompt programs enqueued and not seen complete yet, in the
+        # device queue's order, and how many were ever enqueued (the number
+        # a decode block or a queued first token carries: everything up to
+        # it is ahead of that on the queue).
+        self._prompt_pending: list[_PromptProgram] = []
+        self._prompt_enqueued = 0
         # The traces of the decode programs, where the engine holds
         # adapter buffers (_traced, _retarget).  _lora_targets: the LoRA
         # targets whose buffers a block with an adapter row is handed, in
@@ -2372,7 +2399,80 @@ class Engine:
             prompt_tokens=attrs.get("prompt_tokens", 0),
             bucket=attrs.get("bucket", 0))
 
+    def _note_prompt_program(self, program: str, positions: int, real: int,
+                             t0: float, out, *reqs: "Request") -> None:
+        """One prompt program is on the device's queue: ``program`` (of
+        ``metrics_registry.PROMPT_PROGRAMS``), enqueued at ``t0``, computes
+        ``positions`` positions of which ``real`` are prompt tokens of
+        ``reqs``; ``out`` is a result of it.  Counted here
+        (``tpu:prompt_programs_total``, ``tpu:prompt_positions_total``, and
+        the padding in the operator's ``tpu:prefill_padding_tokens_total``),
+        timed where the loop sees it complete (``_prompt_programs_done``)."""
+        pad = positions - real
+        self.profiler.note_prompt_program(program, real, pad)
+        self.usage.charge_padding(pad)
+        for r in reqs:
+            attrs = r.prefill_attrs
+            attrs["programs"] = attrs.get("programs", 0) + 1
+            attrs["positions"] = (attrs.get("positions", 0)
+                                  + positions // len(reqs))
+        self._prompt_pending.append(
+            _PromptProgram(program, positions, t0, out, reqs))
+        self._prompt_enqueued += 1
+        self._see_inflight()
+
+    def _see_inflight(self) -> None:
+        """Staging a prompt program takes the host milliseconds, a burst of
+        them longer than a decode step: a block in flight that is done by
+        now is seen complete HERE (one readiness test, no wait) and not
+        when the thread gets round to reading it, or its step would be
+        booked the host's lateness and the programs behind it as much too
+        little.  ``_process_block`` books what is noted."""
+        blk = self._inflight
+        if blk is None or "done" in blk or not _is_ready(blk["toks"]):
+            return
+        self._prompt_programs_done(blk["prompts"])  # ahead of it: done too
+        blk["start"] = max(blk["t0"], self._last_done_pc)
+        blk["done"] = self._last_done_pc = time.perf_counter()
+
+    def _prompt_programs_done(self, upto: int) -> None:
+        """See the first ``upto`` prompt programs ever enqueued complete:
+        wait for the last of them that is not booked yet (``prefill.wait``;
+        the queue runs in order, so the others are done too) and stamp the
+        completion chain there.  Their interval, from the later of the
+        first one's enqueue and the last completion the loop saw to now, is
+        booked to ``tpu:prompt_program_seconds_total`` and to their
+        requests' ``device_s``, shared by positions where several end
+        together.  Nothing to do where all of them are booked."""
+        pending = self._prompt_pending
+        n = upto - (self._prompt_enqueued - len(pending))
+        if n <= 0:
+            return
+        burst = pending[:n]
+        del pending[:n]
+        with self._phase("prefill.wait"):
+            jax.block_until_ready(burst[-1].out)
+        done = time.perf_counter()
+        t0 = max(burst[0].t0, self._last_done_pc)
+        self._last_done_pc = done
+        positions = sum(p.positions for p in burst)
+        shares = [(p.program, (done - t0) * p.positions / positions)
+                  for p in burst]
+        self.profiler.note_prompt_done(t0, done, shares)
+        for p, (_, share) in zip(burst, shares):
+            for r in p.reqs:
+                r.prefill_attrs["device_s"] = round(
+                    r.prefill_attrs.get("device_s", 0.0) + share, 9)
+
     def _wait_for_work(self) -> None:
+        if self._prompt_pending:
+            # Programs nothing waits for any more (a stream aborted, a
+            # first token nobody reads): booked before the loop sleeps, or
+            # the next burst's interval would run from their enqueue.
+            try:
+                self._prompt_programs_done(self._prompt_enqueued)
+            except Exception:
+                logger.exception("an abandoned prompt program failed")
         self._settle_admissions()
         self.profiler.note_idle()
         with self._phase("idle"), self._work:
@@ -2615,9 +2715,10 @@ class Engine:
                          if self.lora is not None else -1)
             first_token, k, v, lp_info = self._bucket_prefill(
                 req, n, lora_slot)
+            self._prompt_programs_done(self._prompt_enqueued)
             with self._phase("prefill.wait"):
                 # The token, its logprobs and the prompt's KV come to the
-                # host here: the thread waits for the prefill program.
+                # host here, once the prefill program is done.
                 req.handoff = kv_transfer.export_handoff(
                     req, k, v, n, int(first_token),
                     lp_info=tuple(np.asarray(a) for a in lp_info),
@@ -2733,7 +2834,8 @@ class Engine:
             first_token.copy_to_host_async()
         except AttributeError:
             pass
-        self._first_unread.append((req, first_token, lp_info))
+        self._first_unread.append(
+            (req, first_token, lp_info, self._prompt_enqueued))
 
     def _insert_waiting(self, slot_idx: int, w: _WaitingPrefill) -> None:
         """Insert a parked prefill's KV into a freed cache lane."""
@@ -3058,7 +3160,6 @@ class Engine:
         try:
             self._sync_tables()
             c = n - reused
-            self.usage.charge_padding(self._bucket(c) - c)
             last_logits = self._chunk_dispatch(
                 req, req.prompt_tokens[reused:], reused, self._bucket(c),
                 slot_idx, n, lora_slot)
@@ -3098,19 +3199,22 @@ class Engine:
 
         sp = req.sampling
         padded = -(-n // self._ring_pad) * self._ring_pad
-        self.usage.charge_padding(padded - n)
         tokens = np.zeros((1, padded), np.int32)
         tokens[0, :n] = req.prompt_tokens
         positions = np.broadcast_to(
             np.arange(padded, dtype=np.int32), (1, padded))
         tok_d, pos_d = long_context.shard_inputs(
             self.mesh, jnp.asarray(tokens), jnp.asarray(positions))
+        t0 = time.perf_counter()
         logits, k, v = self._ring(
             self.params, tok_d, pos_d,
             lora_bufs=self._lora_buffers(),
             slot_ids=jnp.full((1,), lora_slot, jnp.int32),
         )
         first_token, lp_info = self._sample_first(req, logits[0, n - 1], n)
+        # The token stands for the program: it keeps [padded, V] logits
+        # no longer than the sampler does.
+        self._note_prompt_program("ring", padded, n, t0, first_token, req)
         return first_token, k, v, lp_info
 
     @_in_phase("prefill.stage")
@@ -3126,10 +3230,12 @@ class Engine:
         args = (self.params, self.cache,
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.int32(slot_idx), jnp.int32(lane_end), jnp.int32(c - 1))
+        t0 = time.perf_counter()
         with self._enqueue("engine.prefill.enqueue", req):
             last_logits, self.cache, moe = self._jit_chunk(
                 *args, lora_bufs=self._lora_buffers(),
                 lora_slot=jnp.int32(lora_slot))
+        self._note_prompt_program("chunk", chunk, c, t0, last_logits, req)
         self._moe_keep(moe)
         return last_logits
 
@@ -3149,7 +3255,6 @@ class Engine:
         Returns (first_token device scalar, k, v, lp_info)."""
         sp = req.sampling
         bucket = self._bucket(n)
-        self.usage.charge_padding(bucket - n)
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :n] = req.prompt_tokens
         positions = np.zeros((1, bucket), np.int32)
@@ -3163,8 +3268,10 @@ class Engine:
             jnp.int32(_seed_i32(sp.seed)),
             *map(jnp.asarray, _bias_arrays(sp)),
         )
+        t0 = time.perf_counter()
         with self._enqueue("engine.prefill.enqueue", req):
             *out, moe = self._jit_prefill(*args)
+        self._note_prompt_program("prefill", bucket, n, t0, out[0], req)
         self._moe_keep(moe)
         return out
 
@@ -3174,7 +3281,6 @@ class Engine:
         Returns (first_tokens [P] device, k [L,P,S,...], v, lp_infos)."""
         bucket = self._bucket(max(ns))
         p = len(reqs)
-        self.usage.charge_padding(sum(bucket - n for n in ns))
         tokens = np.zeros((p, bucket), np.int32)
         positions = np.zeros((p, bucket), np.int32)
         for i, (req, n) in enumerate(zip(reqs, ns)):
@@ -3194,8 +3300,11 @@ class Engine:
             *(jnp.asarray(np.stack(arrs))
               for arrs in zip(*(_bias_arrays(sp) for sp in sps))),
         )
+        t0 = time.perf_counter()
         with self._enqueue("engine.prefill.enqueue", *reqs):
             *out, moe = self._jit_prefill_many(*args)
+        self._note_prompt_program(
+            "prefill_many", p * bucket, sum(ns), t0, out[0], *reqs)
         self._moe_keep(moe)
         return out
 
@@ -3556,6 +3665,7 @@ class Engine:
             self._abort_stream(st, "error")
             return
         st.next_start = start + c
+        self._owes_parts(req)  # this chunk's
         if st.next_start < n:
             return  # more chunks; the loop decodes before the next one
         # Final chunk: publish the prompt's full blocks for prefix reuse,
@@ -3652,7 +3762,7 @@ class Engine:
                 max(0.0, req.t_first_token - req.t_prefill_start),
                 active=1, total_slots=self.cfg.decode_slots,
                 n_steps=len(req.prompt_tokens))
-            self._unsettled.append(req)
+            self._owes_parts(req)
 
     def _kv_ledger_sync(self) -> None:
         """Recount the KV ledger's block states from allocator ground
@@ -3739,21 +3849,29 @@ class Engine:
                     prompt_tokens=n, rows=rows,
                     bucket=self._bucket(min(n, self._max_bucket())))
 
+    def _owes_parts(self, req: Request) -> None:
+        """``req`` had prompt work since the last settle: the phase parts
+        handed out next are (also) its."""
+        if req not in self._unsettled:
+            self._unsettled.append(req)
+
     def _settle_admissions(self) -> None:
         """Hand the requests whose first token came since the last call
         what the phase stack charged to ``prefill.*`` meanwhile (engine
         thread, outside any prefill phase: before the next admission, in a
         decode dispatch's accounting, before the loop waits).  Requests
         admitted by one program share its parts; a prompt streamed in
-        chunks carries its last chunk's, the earlier ones having run
-        between decode blocks."""
+        chunks is handed each chunk's as it goes (``_stream_step``) and
+        carries their sum."""
         if self._first_unread:
             # Between an admission's staging and the reading of its first
             # token: its parts are not whole yet.
             return
         parts = self.profiler.take_prefill_split()
         for req in self._unsettled:
-            req.prefill_attrs.update(parts)
+            attrs = req.prefill_attrs
+            for key, seconds in parts.items():
+                attrs[key] = round(attrs.get(key, 0.0) + seconds, 9)
         self._unsettled.clear()
 
     def _store_logprobs(self, req: Request, lp, top_v, top_i) -> None:
@@ -3905,10 +4023,13 @@ class Engine:
         place in decode_wait, back at once; its lane in ``current`` is
         garbage."""
         unread, self._first_unread = self._first_unread, []
-        for req, first_token, lp_info in unread:
+        for req, first_token, lp_info, prompts in unread:
             if req.done.is_set():
                 continue  # cancelled or failed since
             try:
+                # Its prompt programs first: their interval ends where the
+                # last of them does, not where the token's copy lands.
+                self._prompt_programs_done(prompts)
                 with self._phase("prefill.wait"):
                     tok = int(np.asarray(first_token))
                     if lp_info is not None:
@@ -4023,6 +4144,7 @@ class Engine:
             "rows": list(self.slots),  # request refs valid at dispatch time
             "n_steps": n_steps,
             "t0": t0,
+            "prompts": self._prompt_enqueued,
         }
 
     def _dispatch_spec_block(self, ph) -> dict:
@@ -4089,6 +4211,7 @@ class Engine:
             "rows": list(self.slots),
             "n_steps": n_cycles * (k + 1),
             "t0": t0,
+            "prompts": self._prompt_enqueued,
             "spec": True,
         }
 
@@ -4101,17 +4224,26 @@ class Engine:
         The step the block books (``tpu:decode_step_seconds``, the
         profiler's wall) is the interval between two completions on the
         device's queue: from the later of this block's staging and the
-        last thing the loop saw complete (the block before, or a prefill
-        read since) to this block's completion.  With blocks overlapped
+        last thing the loop saw complete (the block before, or a prompt
+        program: a prefill read since, a chunk ahead of this block) to this
+        block's completion.  With blocks overlapped
         that is the cadence at which a row's tokens become available, one
         device step; for a block staged on an idle device it is stage +
         wait."""
+        # The prompt programs enqueued before the block are ahead of it on
+        # the device's queue (a chunk that was not its prompt's last is
+        # awaited nowhere else): the thread waits for them first and in all
+        # no longer, and the block's step starts where they end.
+        self._prompt_programs_done(blk["prompts"])
         outs = jax.block_until_ready(
             (blk["toks"], blk["valid"], *blk["lp"], *blk.get("tail", ())))
-        done = time.perf_counter()
-        t0 = max(blk["t0"], self._last_done_pc)
+        if "done" in blk:  # seen complete while the host staged prompts
+            t0, done = blk["start"], blk["done"]
+        else:
+            done = time.perf_counter()
+            t0 = max(blk["t0"], self._last_done_pc)
+            self._last_done_pc = done
         step_s = done - t0
-        self._last_done_pc = done
         ph.to("decode.readback")
         # [n_steps, B] each.  One device_get for the lot: the copies start
         # together and the thread waits once, where one np.asarray per

@@ -59,6 +59,7 @@ from llm_instance_gateway_tpu.lockwitness import witness_lock
 from llm_instance_gateway_tpu.metrics_registry import (
     ENGINE_PHASES,
     KV_LANES,
+    PROMPT_PROGRAMS,
     SAMPLE_PATHS,
 )
 from llm_instance_gateway_tpu.tracing import Histogram
@@ -206,6 +207,13 @@ class StepProfiler:
         # the last.  Over the decode and spec dispatches: the share of
         # blocks the overlapped loop kept the device ahead for.
         self.blocks_overlapped = 0
+        # The prompt programs, by the jitted program
+        # (metrics_registry.PROMPT_PROGRAMS): how many were enqueued, the
+        # prompt tokens and the padding each computed, and the seconds of
+        # the loop's completion chain they held (``Engine._note_prompt_program``
+        # counts, ``Engine._prompt_programs_done`` times).
+        self.prompt = {p: {"programs": 0, "real": 0, "pad": 0, "seconds": 0.0}
+                       for p in PROMPT_PROGRAMS}
         # End of the previous dispatch on the engine-thread clock; None
         # until the first dispatch (no gap to attribute yet).
         self._last_end: float | None = None
@@ -359,20 +367,28 @@ class StepProfiler:
             if hist is None:
                 hist = self.wall_hist[phase] = Histogram(DISPATCH_BUCKETS)
             hist.observe(wall_s)
-            if self._last_end is not None and t0 > self._last_end:
-                gap = t0 - self._last_end
-                gap_kind = GAP_IDLE if self._idle_pending else GAP_HOST
-                self.gap_seconds[gap_kind] += gap
-                self.gap_hist[gap_kind].observe(gap)
-            self._idle_pending = False
-            if self._last_end is None or t0 + wall_s > self._last_end:
-                self._last_end = t0 + wall_s
+            gap, gap_kind = self._chain(t0, t0 + wall_s)
             self._seq += 1
             churn = active - self._prev_active
             self._prev_active = active
             self._ring.append((self._seq, phase, round(wall_s, 9),
                                round(gap, 9), gap_kind, active, total_slots,
                                n_steps, churn, split))
+
+    def _chain(self, t0: float, end: float) -> tuple[float, str]:
+        """Put the interval ``t0``..``end`` into the gap chain (under the
+        lock): the gap before it, booked by its kind, and the chain's new
+        end."""
+        gap, gap_kind = 0.0, ""
+        if self._last_end is not None and t0 > self._last_end:
+            gap = t0 - self._last_end
+            gap_kind = GAP_IDLE if self._idle_pending else GAP_HOST
+            self.gap_seconds[gap_kind] += gap
+            self.gap_hist[gap_kind].observe(gap)
+        self._idle_pending = False
+        if self._last_end is None or end > self._last_end:
+            self._last_end = end
+        return gap, gap_kind
 
     # -- export (any thread) -------------------------------------------------
     def attribution(self) -> dict:
@@ -506,6 +522,30 @@ class StepProfiler:
         with self._lock:
             self.blocks_overlapped += 1
 
+    def note_prompt_program(self, program: str, real: int, pad: int) -> None:
+        """Count one prompt program enqueued: ``real`` prompt tokens and
+        ``pad`` positions of padding up to the program's shape."""
+        with self._lock:
+            row = self.prompt[program]  # KeyError: not of PROMPT_PROGRAMS
+            row["programs"] += 1
+            row["real"] += real
+            row["pad"] += pad
+
+    def note_prompt_done(self, t0: float, done: float, shares) -> None:
+        """Prompt programs seen complete at ``done`` held the completion
+        chain from ``t0``: ``shares`` is ``(program, seconds)`` of each
+        (its own interval, or its share of a burst's).  The interval sits
+        in the gap chain like a dispatch's: the queue was not empty while
+        a chunk ran that no decode record covers."""
+        with self._lock:
+            for program, seconds in shares:
+                self.prompt[program]["seconds"] += seconds
+            self._chain(t0, done)
+
+    def prompt_state(self) -> dict:
+        with self._lock:
+            return {p: dict(row) for p, row in self.prompt.items()}
+
     def hist_state(self) -> dict:
         """The small copy-out ``Engine.metrics_snapshot()`` embeds — the
         ``tpu:dispatch_wall_seconds`` / ``tpu:dispatch_gap_seconds``
@@ -532,6 +572,7 @@ class StepProfiler:
         out["phases"] = self.phase_seconds()
         out["moe"] = self.moe_state()
         out["sample_steps"] = self.sample_state()
+        out["prompt"] = self.prompt_state()
         return out
 
     def snapshot(self) -> dict:
@@ -638,4 +679,20 @@ def render_profile(hist: dict) -> list[str]:
         lines += ["# TYPE tpu:decode_blocks_overlapped_total counter",
                   "tpu:decode_blocks_overlapped_total "
                   f"{hist['blocks_overlapped']}"]
+    prompt = hist.get("prompt")
+    if prompt:
+        lines.append("# TYPE tpu:prompt_programs_total counter")
+        lines += [
+            f'tpu:prompt_programs_total{{program="{escape_label(p)}"}} '
+            f'{row["programs"]}' for p, row in prompt.items()]
+        lines.append("# TYPE tpu:prompt_positions_total counter")
+        lines += [
+            f'tpu:prompt_positions_total{{program="{escape_label(p)}",'
+            f'kind="{escape_label(kind)}"}} {row[kind]}'
+            for p, row in prompt.items() for kind in ("real", "pad")]
+        lines.append("# TYPE tpu:prompt_program_seconds_total counter")
+        lines += [
+            "tpu:prompt_program_seconds_total"
+            f'{{program="{escape_label(p)}"}} {row["seconds"]:.6f}'
+            for p, row in prompt.items()]
     return lines

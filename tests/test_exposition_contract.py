@@ -160,6 +160,11 @@ def server_snapshot() -> dict:
     prof.note_attn_grid_steps(17)  # tpu:decode_attn_grid_steps_total
     prof.note_conv_rows(23)  # tpu:conv_state_rows_total
     prof.note_kv_positions(29, 0)  # tpu:kv_positions_read_total{lanes}
+    # tpu:prompt_programs_total / tpu:prompt_positions_total /
+    # tpu:prompt_program_seconds_total, every program of the label set
+    prof.note_prompt_program("chunk", 1000, 24)
+    prof.note_prompt_program("prefill_many", 300, 212)
+    prof.note_prompt_done(0.5, 0.625, [("chunk", 0.125)])
     return {
         "profile": prof.hist_state(),
         "model_name": HOSTILE,
@@ -298,6 +303,22 @@ def test_server_render_contract():
     assert {s.labels["lanes"]: s.value
             for s in families["tpu:kv_positions_read_total"]} == {
                 "full": 29, "window": 0}
+    # The prompt programs: the closed label set, zero-valued series included.
+    from llm_instance_gateway_tpu.metrics_registry import PROMPT_PROGRAMS
+
+    assert {s.labels["program"]: s.value
+            for s in families["tpu:prompt_programs_total"]} == {
+                **dict.fromkeys(PROMPT_PROGRAMS, 0),
+                "chunk": 1, "prefill_many": 1}
+    positions = {(s.labels["program"], s.labels["kind"]): s.value
+                 for s in families["tpu:prompt_positions_total"]}
+    assert set(positions) == {(p, k) for p in PROMPT_PROGRAMS
+                              for k in ("real", "pad")}
+    assert positions["chunk", "real"] == 1000
+    assert positions["prefill_many", "pad"] == 212
+    assert {s.labels["program"]: s.value
+            for s in families["tpu:prompt_program_seconds_total"]} == {
+                **dict.fromkeys(PROMPT_PROGRAMS, 0.0), "chunk": 0.125}
     assert families["tpu:decode_stage_ops_total"][0].value == 0
     # Decode fast-path families (adaptive dispatch + stream lanes).
     assert families["tpu:stream_lanes"][0].value == 2

@@ -186,7 +186,12 @@ def test_trace_report_prints_the_tiling(one_request, tmp_path, capsys):
         paths += ["--url", str(path)]
     assert trace_report.main(paths) == 0
     out = capsys.readouterr().out
-    table = out.split("first-token time by part")[1].splitlines()[3:]
+    rest = out.split("first-token time by part")[1]
+    table, cost = rest.split("\n\nwhat a prompt cost the device:\n")
+    table = table.splitlines()[3:]
+    # PR 57: under the table, what the prompt cost in programs and positions
+    assert [ln.split("  ")[0] for ln in cost.splitlines()[2:]] == [
+        label for label, _ in trace_report.PROMPT_COST]
     rows = [next(p for p in trace_report.PARTS if ln.startswith(p))
             for ln in table]
     assert rows == [p for p in trace_report.PARTS  # all, in order

@@ -12,6 +12,10 @@ the engine thread's wall went:
   .emit, decode.plan / .stage / .wait / .readback / .emit / .account, idle,
   other; ``tpu:engine_phase_seconds_total``), and per decode dispatch its
   stage / wait / readback / emit parts;
+- the prompt programs by the jitted program (``tpu:prompt_programs_total``,
+  ``tpu:prompt_positions_total``, ``tpu:prompt_program_seconds_total``): how
+  many, real and padded positions, milliseconds of the device's queue a
+  program, share of the tracked time;
 - a recent-dispatch summary from the record ring (mean batch occupancy,
   mean steps per dispatch, slot churn).
 
@@ -271,6 +275,31 @@ def overlap_row(profile: dict) -> dict:
     over = int(hist["blocks_overlapped"])
     return {"blocks_overlapped": over, "decode_blocks": n,
             "overlapped_pct": round(100.0 * over / n, 2) if n else 0.0}
+
+
+def prompt_program_rows(profile: dict) -> list[dict]:
+    """The prompt programs by the jitted program (``tpu:prompt_programs_total``,
+    ``tpu:prompt_positions_total``, ``tpu:prompt_program_seconds_total``): how
+    many, the prompt tokens and the padding they computed, the milliseconds
+    of the device's queue one held, and their share of the tracked time;
+    the programs that ran only; empty for a payload from before the
+    families."""
+    prompt = (profile.get("hist") or {}).get("prompt") or {}
+    tracked = float((profile.get("attribution") or {}).get(
+        "tracked_seconds", 0.0))
+    rows = []
+    for program, row in prompt.items():
+        n, computed = int(row["programs"]), row["real"] + row["pad"]
+        if not n:
+            continue
+        rows.append({
+            "program": program, "programs": n,
+            "real": int(row["real"]), "pad": int(row["pad"]),
+            "pad_pct": round(100.0 * row["pad"] / computed, 2),
+            "ms_per_program": round(1e3 * row["seconds"] / n, 3),
+            "share_pct": (round(100.0 * row["seconds"] / tracked, 2)
+                          if tracked else 0.0)})
+    return rows
 
 
 # -- a device trace against the engine thread's annotations -----------------
@@ -718,6 +747,12 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
         out += ["", "Decode overlap (blocks dispatched over an unread one):",
                 _table([overlap], ("blocks_overlapped", "decode_blocks",
                                    "overlapped_pct"))]
+    prompts = prompt_program_rows(profile)
+    if prompts:
+        out += ["", "Prompt programs (positions computed, and the time of "
+                "the device's queue they held):",
+                _table(prompts, ("program", "programs", "real", "pad",
+                                 "pad_pct", "ms_per_program", "share_pct"))]
     adapter_rows = lora_rows_row(profile)
     if adapter_rows:
         out += ["", "Adapter rows in the decode steps, the steps run "

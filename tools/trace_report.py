@@ -12,11 +12,15 @@ part**, in the order a streamed request lives them (p50 and p90 over the
 requests that have the part): the gateway before it posts (``pre_s`` of
 ``gateway.stream``), the server's ``server.accept`` -> ``engine.queue_wait``
 -> ``engine.prefill`` (and of it what the engine thread's phase stack
-charged: ``stage_s`` / ``wait_s`` / ``emit_s``) -> ``server.first_write``,
+charged: ``stage_s`` / ``wait_s`` / ``emit_s``, a streamed prompt's summed
+over its chunks, and ``device_s``, what its prompt programs held of the
+device's queue) -> ``server.first_write``,
 the residual between the gateway's ``first_chunk_s`` and those four
 (network and HTTP; where both processes share a clock, split into the way in
 and the way out), the gateway's way out, and their sum
-``ttft_s``; then the per-token lag (``relay_mean_s``, ``write_lag_max_s``).
+``ttft_s``; then the per-token lag (``relay_mean_s``, ``write_lag_max_s``);
+and under the table what a prompt cost in programs and computed positions
+(``programs`` / ``positions`` of ``engine.prefill``, beside its tokens).
 The server's spans of a streamed request live on the replica: give both
 ``/debug/traces`` (``--url`` twice, or ``--replicas``) to see the whole
 table.
@@ -122,6 +126,7 @@ PARTS = (
     "  prefill.stage (stage_s)",
     "  prefill.wait (wait_s)",
     "  prefill.emit (emit_s)",
+    "  on the device's queue (device_s)",
     "server.first_write",
     "network and HTTP (first_chunk_s - the four)",
     "  way in: POST -> handler entry",
@@ -148,6 +153,7 @@ def first_token_parts(trace: dict) -> dict[str, float]:
         "  prefill.stage (stage_s)": prefill.get("stage_s"),
         "  prefill.wait (wait_s)": prefill.get("wait_s"),
         "  prefill.emit (emit_s)": prefill.get("emit_s"),
+        "  on the device's queue (device_s)": prefill.get("device_s"),
         "per token: gateway received -> written (relay_mean_s)":
             stream.get("relay_mean_s"),
         "per token: engine emit -> written, largest (write_lag_max_s)":
@@ -189,6 +195,28 @@ def first_token_table(traces: list[dict]) -> list[dict]:
             rows.append({"part": name, "n": len(xs),
                          "p50_ms": round(percentile(xs, 0.50) * 1e3, 3),
                          "p90_ms": round(percentile(xs, 0.90) * 1e3, 3)})
+    return rows
+
+
+# What a prompt cost, by attribute of ``engine.prefill``: not times, so not
+# rows of the first-token table.
+PROMPT_COST = (("prompt tokens", "prompt_tokens"),
+               ("prompt programs", "programs"),
+               ("positions computed (padding included)", "positions"))
+
+
+def prompt_cost_rows(traces: list[dict]) -> list[dict]:
+    """n, p50 and p90 of each ``PROMPT_COST`` attribute over the requests
+    whose ``engine.prefill`` span says what its prompt cost (none from a
+    replica older than the attributes)."""
+    spans = [(_span_index(t).get("engine.prefill") or {}).get("attrs") or {}
+             for t in traces]
+    spans = [a for a in spans if "programs" in a]
+    rows = []
+    for label, key in PROMPT_COST if spans else ():
+        xs = sorted(a[key] for a in spans)
+        rows.append({"a request's": label, "n": len(xs),
+                     "p50": percentile(xs, 0.50), "p90": percentile(xs, 0.90)})
     return rows
 
 
@@ -256,6 +284,10 @@ def main(argv=None) -> int:
         if parts:
             print("\nfirst-token time by part, and the per-token lag:")
             print(format_table(parts))
+        cost = prompt_cost_rows(doc.get("traces", []))
+        if cost:
+            print("\nwhat a prompt cost the device:")
+            print(format_table(cost))
     return 0 if rows else 1
 
 
