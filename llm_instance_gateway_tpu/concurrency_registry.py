@@ -532,6 +532,11 @@ CLASSES = (
                         note="grid steps the decode kernel's schedule held: "
                              "the engine thread adds at each dispatch, the "
                              "scrape reads under the lock"),
+            SharedField("chunk_attn_grid_steps", LOCK_GUARDED,
+                        writers=("note_prompt_program",),
+                        note="grid steps the chunk programs' attends walk: "
+                             "the engine thread adds where one is enqueued, "
+                             "the scrape reads under the lock"),
             SharedField("prompt", LOCK_GUARDED,
                         writers=("note_prompt_program",
                                  "note_prompt_done"),

@@ -506,6 +506,16 @@ SERVER_FAMILIES = (
            "call) | chunk (a piece of a streamed prompt, or the suffix behind "
            "a cached prefix) | ring (sequence-parallel); "
            "metrics_registry.PROMPT_PROGRAMS.", SERVER_SURFACE),
+    Family("tpu:chunk_attn_grid_steps_total", "counter", (),
+           "Grid steps the chunk-attention kernel walks, summed over the "
+           "attention layers of the chunk programs enqueued: a layer's call "
+           "is kv heads x query tiles x key tiles of its lane (one step "
+           "serves every query head of its kv head; a window layer's lane "
+           "is its ring with the chunk behind it), reckoned on the host from "
+           "the shapes by the dispatcher's own rule. Over "
+           "tpu:prompt_programs_total{program=\"chunk\"}, the steps a chunk "
+           "program. 0 where no kernel takes the shapes (int8 lanes, a "
+           "chunk or a lane the tiles do not divide).", SERVER_SURFACE),
     Family("tpu:prompt_positions_total", "counter", ("program", "kind"),
            "Positions the prompt programs computed, counted where each is "
            "enqueued: kind=real the prompt's tokens, kind=pad the padding up "

@@ -46,6 +46,7 @@ from llm_instance_gateway_tpu.models import lora as lora_lib
 from llm_instance_gateway_tpu.models import paged as paged_lib
 from llm_instance_gateway_tpu.models import transformer
 from llm_instance_gateway_tpu.models.configs import ModelConfig
+from llm_instance_gateway_tpu.ops import pallas_attention
 from llm_instance_gateway_tpu.ops import pallas_decode_attention as pda
 from llm_instance_gateway_tpu.server.sampling import (
     STOP_LEN,
@@ -2409,7 +2410,9 @@ class Engine:
         the padding in the operator's ``tpu:prefill_padding_tokens_total``),
         timed where the loop sees it complete (``_prompt_programs_done``)."""
         pad = positions - real
-        self.profiler.note_prompt_program(program, real, pad)
+        self.profiler.note_prompt_program(
+            program, real, pad,
+            self._chunk_attn_steps(positions) if program == "chunk" else 0)
         self.usage.charge_padding(pad)
         for r in reqs:
             attrs = r.prefill_attrs
@@ -2420,6 +2423,36 @@ class Engine:
             _PromptProgram(program, positions, t0, out, reqs))
         self._prompt_enqueued += 1
         self._see_inflight()
+
+    def _chunk_attn_steps(self, chunk: int) -> int:
+        """Grid steps the chunk attend's kernel walks over the attention
+        layers of one chunk program of ``chunk`` positions
+        (``tpu:chunk_attn_grid_steps_total``), from the cache's shapes by
+        the dispatcher's own rule (``pallas_attention.chunk_grid_steps``): a
+        full layer's lane is the slot's, a window layer's its ring with the
+        chunk behind it (``transformer._ring_chunk``), a paged row's its
+        table's blocks, a latent model's the rows expanded to a key a head.
+        0 where the chunk attend takes the XLA form on every backend (int8
+        lanes, the kernels off or under a mesh) or the shapes."""
+        cfg = self.model_cfg
+        if self._kv_quant or not cfg.use_flash_attention:
+            return 0
+        k = self.cache["k"]
+        item = k.dtype.itemsize
+        if self._latent:
+            return k.shape[0] * pallas_attention.chunk_grid_steps(
+                chunk, k.shape[2], cfg.n_heads, cfg.n_heads,
+                cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, item)
+        lane = (self._block * self._max_blocks_per_seq if self.paged
+                else k.shape[2])
+        steps = k.shape[0] * pallas_attention.chunk_grid_steps(
+            chunk, lane, cfg.n_heads, *k.shape[-2:], item)
+        ring = self.cache.get("k_win")
+        if ring is not None:
+            steps += ring.shape[0] * pallas_attention.chunk_grid_steps(
+                chunk, ring.shape[2] + chunk, cfg.n_heads, *ring.shape[-2:],
+                item)
+        return steps
 
     def _see_inflight(self) -> None:
         """Staging a prompt program takes the host milliseconds, a burst of

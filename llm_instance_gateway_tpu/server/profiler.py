@@ -214,6 +214,10 @@ class StepProfiler:
         # counts, ``Engine._prompt_programs_done`` times).
         self.prompt = {p: {"programs": 0, "real": 0, "pad": 0, "seconds": 0.0}
                        for p in PROMPT_PROGRAMS}
+        # Grid steps the chunk-attention kernel walks over the attention
+        # layers of the chunk programs enqueued, from the shapes
+        # (``Engine._chunk_attn_steps``).  0 where no kernel takes them.
+        self.chunk_attn_grid_steps = 0
         # End of the previous dispatch on the engine-thread clock; None
         # until the first dispatch (no gap to attribute yet).
         self._last_end: float | None = None
@@ -522,14 +526,17 @@ class StepProfiler:
         with self._lock:
             self.blocks_overlapped += 1
 
-    def note_prompt_program(self, program: str, real: int, pad: int) -> None:
+    def note_prompt_program(self, program: str, real: int, pad: int,
+                            attn_steps: int = 0) -> None:
         """Count one prompt program enqueued: ``real`` prompt tokens and
-        ``pad`` positions of padding up to the program's shape."""
+        ``pad`` positions of padding up to the program's shape, and the
+        ``attn_steps`` grid steps its layers' chunk attends walk."""
         with self._lock:
             row = self.prompt[program]  # KeyError: not of PROMPT_PROGRAMS
             row["programs"] += 1
             row["real"] += real
             row["pad"] += pad
+            self.chunk_attn_grid_steps += attn_steps
 
     def note_prompt_done(self, t0: float, done: float, shares) -> None:
         """Prompt programs seen complete at ``done`` held the completion
@@ -567,6 +574,7 @@ class StepProfiler:
                 "conv_rows": self.conv_rows,
                 "kv_positions": dict(zip(KV_LANES, self.kv_positions)),
                 "attn_grid_steps": self.attn_grid_steps,
+                "chunk_attn_grid_steps": self.chunk_attn_grid_steps,
                 "blocks_overlapped": self.blocks_overlapped,
             }
         out["phases"] = self.phase_seconds()
@@ -675,6 +683,10 @@ def render_profile(hist: dict) -> list[str]:
         lines += ["# TYPE tpu:decode_attn_grid_steps_total counter",
                   "tpu:decode_attn_grid_steps_total "
                   f"{hist['attn_grid_steps']}"]
+    if "chunk_attn_grid_steps" in hist:
+        lines += ["# TYPE tpu:chunk_attn_grid_steps_total counter",
+                  "tpu:chunk_attn_grid_steps_total "
+                  f"{hist['chunk_attn_grid_steps']}"]
     if "blocks_overlapped" in hist:
         lines += ["# TYPE tpu:decode_blocks_overlapped_total counter",
                   "tpu:decode_blocks_overlapped_total "

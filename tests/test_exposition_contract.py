@@ -162,7 +162,8 @@ def server_snapshot() -> dict:
     prof.note_kv_positions(29, 0)  # tpu:kv_positions_read_total{lanes}
     # tpu:prompt_programs_total / tpu:prompt_positions_total /
     # tpu:prompt_program_seconds_total, every program of the label set
-    prof.note_prompt_program("chunk", 1000, 24)
+    # ... and tpu:chunk_attn_grid_steps_total, a chunk program's
+    prof.note_prompt_program("chunk", 1000, 24, 1488)
     prof.note_prompt_program("prefill_many", 300, 212)
     prof.note_prompt_done(0.5, 0.625, [("chunk", 0.125)])
     return {
@@ -300,6 +301,7 @@ def test_server_render_contract():
     assert families["tpu:latent_kv_positions_total"][0].value == 41
     assert families["tpu:decode_attn_grid_steps_total"][0].value == 17
     assert families["tpu:conv_state_rows_total"][0].value == 23
+    assert families["tpu:chunk_attn_grid_steps_total"][0].value == 1488
     assert {s.labels["lanes"]: s.value
             for s in families["tpu:kv_positions_read_total"]} == {
                 "full": 29, "window": 0}

@@ -595,6 +595,55 @@ def test_packed_head_kernels_compile_for_the_v5e_at_lfm2s_widths(one_chip, op):
     assert name in text and "tpu_custom_call" in text
 
 
+# (query heads, the lane as the kernel sees it [S, K, hd], window, the tiles
+# ``chunk_blocks`` picks): the chunk attend's layouts in the cells that
+# stream chunk programs, then every other group the presets reach.
+CHUNK_LAYOUTS = {
+    "smallthinker-full": (28, (16384, 4, 128), 0, (256, 1024)),
+    "smallthinker-ring": (28, (4096 + 1024, 4, 128), 4096, (256, 1024)),
+    "qwen7b-doc": (28, (2048, 4, 128), 0, (256, 1024)),
+    "glm-expanded": (20, (4096, 20, 256), 0, (256, 1024)),
+    # two 64-wide kv heads a row: 32 padded query heads over 4 rows, g 8
+    "lfm2-packed": (32, (8192, 4, 128), 0, (256, 1024)),
+    "mixtral-g4": (32, (4096, 8, 128), 0, (256, 1024)),
+    # 8 heads of 256 over one kv head: 256 query rows of them are over a
+    # step's VMEM budget, 128 are not
+    "gemma-2b-g8-hd256": (8, (8192, 1, 256), 0, (128, 1024)),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(CHUNK_LAYOUTS))
+def test_chunk_attend_compiles_for_the_v5e_at_the_cells_layouts(one_chip,
+                                                                layout):
+    """One grid step holds the tiles and the softmax state of ALL the query
+    heads of a kv head: the tiles ``chunk_blocks`` picks from the shapes
+    have to fit a step's VMEM on the chip (here, beside the other compiles
+    for the chip: one process may describe it)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from llm_instance_gateway_tpu.ops import pallas_attention
+
+    h, (s_max, n_kv, hd), window, want = CHUNK_LAYOUTS[layout]
+    block_q, block_k = pallas_attention.chunk_blocks(1024, s_max, h // n_kv,
+                                                     hd)
+    assert (block_q, block_k) == want
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    lane = sd((1, s_max, n_kv, hd), jnp.bfloat16)
+    fn = jax.jit(lambda q, k, v, start: pallas_attention.chunk_attention_pallas(
+        q, k, v, start, block_q=block_q, block_k=block_k, window=window))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = fn.lower(sd((1, 1024, h, hd), jnp.bfloat16), lane, lane,
+                            sd((), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "chunk_attention" in text and "tpu_custom_call" in text
+
+
 def test_ssm_update_kernel_compiles_for_the_v5e_at_published_widths(one_chip):
     """Falcon-H1-34B's state, 64 slots x 8 layers of 32 x 256 x 128 float32
     (2 GiB), through ``ssm_decode_update`` as the cell runs it (here, beside

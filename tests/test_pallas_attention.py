@@ -123,6 +123,141 @@ class TestChunkAttention:
         np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
                                    rtol=2e-5, atol=2e-5)
 
+    # (q heads, kv heads, head dim, pack): the group g of query heads that
+    # share a K/V tile as the KERNEL sees it; the packed lane is LFM2's (two
+    # 64-wide kv heads a row: 16 padded query heads over 2 rows).
+    GROUPS = {"g1": (2, 2, 128, 1), "g4": (8, 2, 128, 1),
+              "g7": (14, 2, 128, 1), "g8-packed": (16, 4, 64, 2)}
+
+    @pytest.mark.parametrize("block_q", [128, 256])
+    @pytest.mark.parametrize("start", [0, 384, 768],
+                             ids=["first", "mid-lane", "last-chunk"])
+    @pytest.mark.parametrize("window", [0, 320], ids=["full", "window"])
+    @pytest.mark.parametrize("layout", list(GROUPS))
+    def test_group_shares_its_tile(self, layout, window, start, block_q):
+        """One grid step a (kv head, query tile, key tile): every query
+        head of the group against the XLA form, with the window's far edge
+        and the diagonal cutting through [block_q, 512] tiles."""
+        from llm_instance_gateway_tpu.ops.attention import (
+            own_values,
+            pad_queries,
+            xla_chunk_attention,
+        )
+
+        h, kv, hd, pack = self.GROUPS[layout]
+        q, kc, vc = self._inputs(c=256, s_max=1024, h=h, kv=kv, hd=hd,
+                                 seed=start + h)
+        ref = xla_chunk_attention(q, kc, vc, start, window)
+        got = own_values(pallas_attention.chunk_attention_pallas(
+            pad_queries(q, kv, pack), pack_heads(kc, pack),
+            pack_heads(vc, pack), jnp.int32(start), block_q=block_q,
+            block_k=512, interpret=True, window=window,
+            scale=1.0 / hd ** 0.5), kv, pack)
+        np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("start", [0, 3072, 4096],
+                             ids=["first", "mid-ring", "ring-full"])
+    def test_a_ring_with_the_chunk_behind_it(self, start):
+        """A window layer's lane is its ring in position order with the
+        chunk behind it, 5,120 positions and no power of two: the dispatcher
+        takes the tiles ``chunk_blocks`` gives it, [256, 1024] over a group
+        of seven heads, and the window's far edge cuts through them."""
+        from llm_instance_gateway_tpu.ops.attention import xla_chunk_attention
+
+        window, c = 4096, 1024
+        assert pallas_attention.chunk_blocks(c, window + c, 7, 128, 4) == (
+            256, 1024)
+        q, kc, vc = self._inputs(c=c, s_max=window + c, h=7, kv=1, seed=start)
+        got = pallas_attention.chunk_attention(
+            q, kc, vc, jnp.int32(start), interpret=True, window=window)
+        np.testing.assert_allclose(
+            np.asarray(xla_chunk_attention(q, kc, vc, start, window)),
+            np.asarray(got), rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("window", [0, 320], ids=["full", "window"])
+    def test_grouped_grid_equals_the_per_head_grid_bit_for_bit(self, window):
+        """The grid this kernel had before its head axis ran over the kv
+        heads (one step a QUERY head, ``hi // g`` its kv head: the index
+        maps are kept here) gives the same float32 numbers: a head's
+        recurrence sees the same tiles in the same order either way."""
+        import functools
+
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        h, kv, hd, c, s_max, block_q, block_k = 14, 2, 128, 256, 1024, 128, 512
+        g = h // kv
+        q, kc, vc = self._inputs(c=c, s_max=s_max, h=h, kv=kv, seed=11)
+        start = jnp.int32(384)
+
+        def q_index(bi, hi, qi, kb, off):
+            return (bi, 0, qi, hi)
+
+        def kv_index(bi, hi, qi, kb, off):
+            q_first = off[0] + qi * block_q
+            tile = jnp.minimum(kb, (q_first + block_q - 1) // block_k)
+            if window:
+                tile = jnp.maximum(
+                    tile, jnp.maximum(q_first - window + 1, 0) // block_k)
+            return (bi, 0, tile, hi // g)
+
+        per_head = pl.pallas_call(
+            functools.partial(pallas_attention._chunk_kernel,
+                              scale=1.0 / hd ** 0.5, window=window),
+            out_shape=jax.ShapeDtypeStruct((1, 1, c, h * hd), q.dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(1, h, c // block_q, s_max // block_k),
+                in_specs=[pl.BlockSpec((1, 1, block_q, hd), q_index),
+                          pl.BlockSpec((1, 1, block_k, hd), kv_index),
+                          pl.BlockSpec((1, 1, block_k, hd), kv_index)],
+                out_specs=pl.BlockSpec((1, 1, block_q, hd), q_index),
+                scratch_shapes=[pltpu.VMEM((1, block_q, 128), jnp.float32),
+                                pltpu.VMEM((1, block_q, 128), jnp.float32),
+                                pltpu.VMEM((1, block_q, hd), jnp.float32)]),
+            interpret=True,
+        )(start.reshape(1), q.reshape(1, 1, c, h * hd),
+          kc.reshape(1, 1, s_max, kv * hd), vc.reshape(1, 1, s_max, kv * hd))
+        grouped = pallas_attention.chunk_attention_pallas(
+            q, kc, vc, start, block_q=block_q, block_k=block_k,
+            interpret=True, window=window)
+        np.testing.assert_array_equal(
+            np.asarray(per_head.reshape(1, c, h, hd)), np.asarray(grouped))
+
+    @pytest.mark.parametrize("g,hd,want", [
+        (1, 128, 256), (7, 128, 256), (8, 128, 256), (1, 256, 256),
+        (16, 128, 128)])
+    def test_query_tile_follows_the_group(self, g, hd, want):
+        """256 query rows a head where the group's tiles and softmax state
+        fit a step's VMEM, else 128; from the shapes alone."""
+        blocks = pallas_attention.chunk_blocks
+        assert blocks(1024, 16384, g, hd) == (want, 1024)
+        # the widest key tile that divides the lane: a ring of 4,096 with a
+        # 512-token chunk behind it, a lane a tile and a bit long
+        assert blocks(1024, 4096 + 512, g, hd)[1] == 512
+        assert blocks(1024, 16384 + 128, g, hd)[1] == 128
+        assert blocks(384, 2048, g, hd)[0] == 128
+        assert pallas_attention.chunk_shape_reasons(1024, 16384, hd, g) == []
+
+    def test_a_group_over_a_steps_vmem_takes_the_xla_form(self):
+        # 71 heads over one kv head (Falcon-7B's): no tile holds the group.
+        reasons = pallas_attention.chunk_shape_reasons(1024, 2048, 128, g=71)
+        assert reasons and "71 heads" in reasons[0]
+        q, kc, vc = self._inputs(c=128, s_max=256, h=71, kv=1, seed=5)
+        from llm_instance_gateway_tpu.ops.attention import xla_chunk_attention
+        got = pallas_attention.chunk_attention(q, kc, vc, 128, interpret=True)
+        np.testing.assert_allclose(
+            np.asarray(xla_chunk_attention(q, kc, vc, 128)), np.asarray(got),
+            rtol=1e-6)
+
+    def test_grid_walks_the_kv_heads(self):
+        # SmallThinker's full lane and its ring with the chunk behind it
+        assert pallas_attention.chunk_grid(1, 1024, 16384, 4, 256, 1024) == (
+            1, 4, 4, 16)
+        assert pallas_attention.chunk_grid(1, 1024, 5120, 4, 256, 1024) == (
+            1, 4, 4, 5)
+
     def test_garbage_past_reach_ignored(self):
         # Cache positions beyond start+i must not perturb outputs (they're
         # previous tenants' garbage the causal mask excludes).

@@ -865,8 +865,27 @@ class TestPhaseReport:
         old = p.snapshot()
         del old["hist"]["attn_grid_steps"]  # a payload from before PR 47
         assert profile_report.attn_grid_steps_row(old) == {}
-        assert not any("grid_steps" in ln
+        assert not any("decode_attn_grid_steps" in ln
                        for ln in render_profile(old["hist"]))
+
+    def test_chunk_programs_add_their_attends_grid_steps(self):
+        """``tpu:chunk_attn_grid_steps_total`` (``note_prompt_program``'s
+        fourth argument): a chunk program adds what the engine reckoned
+        from the shapes, the other prompt programs nothing; in ``/metrics``
+        always, and not rendered from a payload that predates it."""
+        p = StepProfiler(capacity=8, clock=FakeClock())
+        p.note_prompt_program("chunk", 900, 124, 1488)
+        p.note_prompt_program("prefill", 100, 28)
+        p.note_prompt_program("chunk", 1024, 0, 1488)
+        assert p.hist_state()["chunk_attn_grid_steps"] == 2976
+        assert "tpu:chunk_attn_grid_steps_total 2976" in render_profile(
+            p.hist_state())
+        assert "tpu:chunk_attn_grid_steps_total 0" in render_profile(
+            StepProfiler(capacity=8, clock=FakeClock()).hist_state())
+        old = p.hist_state()
+        del old["chunk_attn_grid_steps"]  # a payload from before PR 59
+        assert not any("chunk_attn_grid_steps" in ln
+                       for ln in render_profile(old))
 
 
 class TestXplaneGaps:

@@ -157,6 +157,10 @@ class TestEveryPromptProgramIsCountedWhereItIsEnqueued:
         _, _, _, _, real, computed = COUNTS[counted["kind"]]
         assert pad == computed - real
 
+    def test_no_grid_steps_where_no_kernel_takes_the_shapes(self, counted):
+        # 16-wide heads: the chunk attend is XLA's on every backend
+        assert counted["grew"]["tpu:chunk_attn_grid_steps_total"] == 0
+
     def test_every_program_was_seen_complete(self, counted):
         state = counted["state"][counted["program"]]
         assert state["seconds"] > 0.0
@@ -201,6 +205,80 @@ class TestEveryPromptProgramIsCountedWhereItIsEnqueued:
                 1000.0 * seconds / n, abs=1e-2)
         else:
             assert read["model.chunk_program_ms"] is None
+
+
+# -- the chunk attend's grid steps (PR 59) ------------------------------------
+
+# The cells that stream chunk programs: (n_heads, the cache's leaves as
+# ``init_decode_cache`` lays them, latent (nope, rope) widths, the steps a
+# 1,024-token chunk program): layers x kv heads as the lane has them x query
+# tiles x key tiles, one step for ALL the query heads of a kv head.
+CELL_LAYOUTS = {
+    # 3 full layers x (4 x 4 x 16) + 9 window layers x (4 x 4 x 5); the
+    # per-query-head grid at [256, 512] tiles walked 20,832
+    "smallthinker": (28, {"k": (3, 32, 16384, 4, 128),
+                          "k_win": (9, 32, 4096, 4, 128)}, None, 1488),
+    "qwen7b-doc": (28, {"k": (28, 32, 2048, 4, 128)}, None, 28 * 32),
+    # two 64-wide kv heads a 128-lane row: 4 rows under 32 query heads
+    "lfm2-packed": (32, {"k": (3, 64, 8192, 4, 128)}, None, 3 * 4 * 4 * 8),
+    # a latent row expanded to a 256-wide key a head: a group of one
+    "glm-latent": (20, {"k": (13, 32, 4096, 640)}, (192, 64),
+                   13 * 20 * 4 * 4),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_LAYOUTS))
+def test_a_chunk_programs_grid_steps_from_the_cells_shapes(cell):
+    """``Engine._chunk_attn_steps`` over the cache's shapes alone (no
+    array is made): what ``tpu:chunk_attn_grid_steps_total`` adds a chunk
+    program in each cell that streams its prompts."""
+    import types
+
+    n_heads, leaves, latent, want = CELL_LAYOUTS[cell]
+    nope, rope = latent or (0, 0)
+    eng = types.SimpleNamespace(
+        model_cfg=types.SimpleNamespace(
+            use_flash_attention=True, n_heads=n_heads,
+            qk_nope_head_dim=nope, qk_rope_head_dim=rope),
+        cache={k: jax.ShapeDtypeStruct(v, jnp.bfloat16)
+               for k, v in leaves.items()},
+        _kv_quant=False, _latent=bool(latent), paged=False)
+    assert Engine._chunk_attn_steps(eng, 1024) == want
+    eng._kv_quant = True  # int8 lanes: the dequant fuses into XLA's reads
+    assert Engine._chunk_attn_steps(eng, 1024) == 0
+
+
+def test_a_streamed_prompt_adds_its_chunk_programs_grid_steps():
+    """128-wide heads, two layers, lanes of 2,048: each 256-token chunk
+    program adds layers x n_kv x (c // block_q) x (s_max // block_k) =
+    2 x 2 x 1 x 2 to ``tpu:chunk_attn_grid_steps_total``, whatever the
+    group (4 query heads over 2 kv heads here); a bucket prefill adds none."""
+    import dataclasses
+
+    cfg = dataclasses.replace(TINY_TEST, n_layers=2, n_heads=4, n_kv_heads=2,
+                              head_dim=128, max_seq_len=2048)
+    engine = Engine(
+        cfg, transformer.init_params(cfg, jax.random.PRNGKey(1),
+                                     dtype=jnp.float32),
+        EngineConfig(decode_slots=2, max_seq_len=2048,
+                     prefill_buckets=(128, 256)),
+        eos_id=None, dtype=jnp.float32)
+    assert engine._chunk_attn_steps(256) == (
+        2 * 2 * (256 // 256) * (2048 // 1024))
+    engine.start()
+    try:
+        short = engine.generate(greedy(list(range(3, 90)), 2), timeout_s=180)
+        assert short.error is None, short.error
+        assert engine.profiler.hist_state()["chunk_attn_grid_steps"] == 0
+        out = engine.generate(greedy([3 + i % 50 for i in range(600)], 2),
+                              timeout_s=180)
+        assert out.error is None, out.error
+        text = metrics.render(engine.metrics_snapshot())
+    finally:
+        engine.stop()
+    grew = families(text)
+    assert grew['tpu:prompt_programs_total{program="chunk"}'] == 3
+    assert grew["tpu:chunk_attn_grid_steps_total"] == 3 * 8
 
 
 # -- (b) the completion chain ------------------------------------------------
@@ -549,10 +627,11 @@ def test_profile_report_prints_the_prompt_programs():
     prof = StepProfiler()
     prof.note_dispatch("decode", 0.0, 0.6, active=2, total_slots=4)
     for _ in range(4):
-        prof.note_prompt_program("chunk", 900, 124)
+        prof.note_prompt_program("chunk", 900, 124, 1488)
         prof.note_prompt_done(0.0, 0.1, [("chunk", 0.1)])
     prof.note_prompt_program("prefill", 100, 28)
     rows = profile_report.prompt_program_rows(prof.snapshot())
+    assert [r["attn_grid_steps"] for r in rows] == [0, 1488.0]
     assert [r["program"] for r in rows] == ["prefill", "chunk"]
     chunk = rows[1]
     assert (chunk["programs"], chunk["real"], chunk["pad"]) == (4, 3600, 496)

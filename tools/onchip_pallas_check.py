@@ -31,7 +31,11 @@ layout, at the cells' chunk, prompt and decode shapes: where
 cases what a decode step's tail costs alone (the head's logits -> sampler
 -> logprobs) at Falcon-H1's, Qwen's and LFM2's slots x vocabulary, asked for
 and not asked for, and a digest of the asked branch's three outputs beside
-the unconditional call's (the program's before PR 56).
+the unconditional call's (the program's before PR 56); and the
+``chunk-attend time`` cases what the chunk attend costs a call through its
+dispatcher at SmallThinker's full lane and ring-with-chunk and at LFM2's
+packed rows, a 1,024-token chunk at the start, the middle and the end of
+the mix's prompts, and a digest of the output.
 
     python tools/onchip_pallas_check.py            # on the chip
 """
@@ -321,9 +325,58 @@ def case_chunk(h, n_kv, hd, c, s_max, start):
     kc = jax.random.normal(kk, (1, s_max, n_kv, hd), DTYPE)
     vc = jax.random.normal(kv, (1, s_max, n_kv, hd), DTYPE)
     off = jnp.int32(start)
+    block_q, block_k = flash.chunk_blocks(c, s_max, h // n_kv, hd)
     out = jax.jit(lambda *a: flash.chunk_attention_pallas(
-        *a, interpret=False))(q, kc, vc, off)
+        *a, block_q=block_q, block_k=block_k, interpret=False))(
+            q, kc, vc, off)
     ref = jax.jit(xla_att.xla_chunk_attention)(q, kc, vc, off)
+    return out, ref, TOL_BF16
+
+
+def case_chunk_timed(h, n_kv, hd, s_max, starts, window=0, pack=1, c=1024,
+                     calls=60):
+    """The chunk attend as a chunk program's layers call it, through the
+    dispatcher (``pack`` > 1: narrow kv heads packed to a 128-lane row, the
+    queries as the model has them): a ``c``-token chunk at each of
+    ``starts`` of an ``s_max`` lane (a window layer's: the ring in position
+    order with the chunk behind it, ``start`` at most the ring).  Prints us a
+    call (one program of ``calls`` calls, each hanging on the one before)
+    and a digest of the output, which two trees' kernels share where their
+    tiles are equal (a head's recurrence sees the same tiles in the same
+    order whichever grid walks them); parity at the last of ``starts``."""
+    import hashlib
+
+    kq, kk, kv = _keys(9, 3)
+    q = jax.random.normal(kq, (1, c, h, hd), DTYPE)
+    kc = jax.random.normal(kk, (1, s_max, n_kv, hd), DTYPE)
+    vc = jax.random.normal(kv, (1, s_max, n_kv, hd), DTYPE)
+    kp, vp = xla_att.pack_heads(kc, pack), xla_att.pack_heads(vc, pack)
+
+    def attend(q, off):
+        return flash.chunk_attention(q, kp, vp, off, window=window, pack=pack)
+
+    @jax.jit
+    def loop(q, off):
+        def body(acc, _):
+            out = attend(q + acc.astype(q.dtype), off)
+            return out[..., :1].astype(jnp.float32) * 1e-6, None
+        return jax.lax.scan(body, jnp.zeros((1, c, h, 1), jnp.float32),
+                            None, length=calls)[0]
+
+    once = jax.jit(attend)
+    for start in starts:
+        off = jnp.int32(start)
+        least, median = _us_a_call(
+            lambda: loop(q, off).block_until_ready(), calls)
+        out = once(q, off)
+        digest = hashlib.sha256(
+            np.asarray(out.astype(jnp.float32)).tobytes()).hexdigest()[:12]
+        print(f"TIME   chunk-attend c={c} start={start} h={h} kv={n_kv} "
+              f"hd={hd} pack={pack} s_max={s_max} window={window}: "
+              f"{least:.1f} us a call (median {median:.1f}, {calls} calls a "
+              f"program); output sha256 {digest}", flush=True)
+    ref = jax.jit(lambda q, kc, vc, off: xla_att.xla_chunk_attention(
+        q, kc, vc, off, window))(q, kc, vc, off)
     return out, ref, TOL_BF16
 
 
@@ -723,6 +776,18 @@ def cases():
                      ("chunk", flash.chunk_shape_reasons(1024, 8192, 128))):
         yield (f"packed-heads {op} [lfm2-24b-a2b g=4 kv=8 hd64]", gate,
                lambda op=op: case_packed(op))
+    # Speed 1 of ROADMAP.md: what the chunk attend costs a call at the
+    # layouts whose cells stream their prompts as chunk programs.
+    for label, args, kw in (
+            ("smallthinker-21b-a3b full g=7 s_max=16384",
+             (28, 4, 128, 16384, (0, 3072, 5120)), {}),
+            ("smallthinker-21b-a3b ring+chunk g=7 s_max=5120 window=4096",
+             (28, 4, 128, 5120, (0, 3072, 4096)), {"window": 4096}),
+            ("lfm2-24b-a2b packed rows g=8 s_max=8192",
+             (32, 8, 64, 8192, (0, 3072)), {"pack": 2})):
+        yield (f"chunk-attend time [{label}]",
+               flash.chunk_shape_reasons(1024, args[3], 128),
+               lambda args=args, kw=kw: case_chunk_timed(*args, **kw))
     for label, h, n_kv, hd in LAYOUTS:
         for s in (128, 1024):
             yield (f"flash s={s} [{label}]", flash.shape_reasons(s, hd),

@@ -281,10 +281,13 @@ def prompt_program_rows(profile: dict) -> list[dict]:
     """The prompt programs by the jitted program (``tpu:prompt_programs_total``,
     ``tpu:prompt_positions_total``, ``tpu:prompt_program_seconds_total``): how
     many, the prompt tokens and the padding they computed, the milliseconds
-    of the device's queue one held, and their share of the tracked time;
-    the programs that ran only; empty for a payload from before the
-    families."""
-    prompt = (profile.get("hist") or {}).get("prompt") or {}
+    of the device's queue one held, their share of the tracked time, and
+    the grid steps a chunk program's attends walk
+    (``tpu:chunk_attn_grid_steps_total``; 0 of the other programs, and where
+    no kernel takes the shapes); the programs that ran only; empty for a
+    payload from before the families."""
+    hist = profile.get("hist") or {}
+    prompt = hist.get("prompt") or {}
     tracked = float((profile.get("attribution") or {}).get(
         "tracked_seconds", 0.0))
     rows = []
@@ -298,7 +301,10 @@ def prompt_program_rows(profile: dict) -> list[dict]:
             "pad_pct": round(100.0 * row["pad"] / computed, 2),
             "ms_per_program": round(1e3 * row["seconds"] / n, 3),
             "share_pct": (round(100.0 * row["seconds"] / tracked, 2)
-                          if tracked else 0.0)})
+                          if tracked else 0.0),
+            "attn_grid_steps": (
+                round(hist.get("chunk_attn_grid_steps", 0) / n, 1)
+                if program == "chunk" else 0)})
     return rows
 
 
@@ -752,7 +758,8 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
         out += ["", "Prompt programs (positions computed, and the time of "
                 "the device's queue they held):",
                 _table(prompts, ("program", "programs", "real", "pad",
-                                 "pad_pct", "ms_per_program", "share_pct"))]
+                                 "pad_pct", "ms_per_program", "share_pct",
+                                 "attn_grid_steps"))]
     adapter_rows = lora_rows_row(profile)
     if adapter_rows:
         out += ["", "Adapter rows in the decode steps, the steps run "
