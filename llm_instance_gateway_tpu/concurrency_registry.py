@@ -522,6 +522,11 @@ CLASSES = (
                         note="conv states the decode steps rewrote: the "
                              "engine thread adds at each dispatch, the "
                              "scrape reads under the lock"),
+            SharedField("kda_rows", LOCK_GUARDED,
+                        writers=("note_kda_rows",),
+                        note="delta-rule states the decode steps rewrote: "
+                             "the engine thread adds at each dispatch, the "
+                             "scrape reads under the lock"),
             SharedField("kv_positions", LOCK_GUARDED,
                         writers=("note_kv_positions",),
                         note="cache positions the decode steps read by kind "

@@ -394,16 +394,25 @@ SERVER_FAMILIES = (
            "Sparse layers run, one per layer per decode step or prefill "
            "program (0 for a dense model).", SERVER_SURFACE),
     Family("tpu:moe_assignments_total", "counter", (),
-           "Token-to-expert assignments of live rows, summed over "
-           "layer-steps.", SERVER_SURFACE),
+           "Token-to-expert assignments of live rows that the expert "
+           "matmuls computed, summed over layer-steps: under a share of the "
+           "experts (n_experts_local) those whose expert is held here.",
+           SERVER_SURFACE),
     Family("tpu:moe_experts_touched_total", "counter", (),
            "Experts with at least one live assignment, summed over "
            "layer-steps: over tpu:moe_layer_steps_total, the experts a "
-           "layer reads.", SERVER_SURFACE),
+           "layer reads (under a share: of those held here).",
+           SERVER_SURFACE),
     Family("tpu:moe_tiles_used_total", "counter", (),
            "Row tiles of the expert dispatch that hold a group, summed over "
            "layer-steps: over tpu:moe_experts_touched_total, the tiles a "
            "touched expert's group takes (1.0: every group in one tile).",
+           SERVER_SURFACE),
+    Family("tpu:moe_assignments_routed_total", "counter", (),
+           "Every token-to-expert assignment the router made for a live "
+           "row, held here or not, summed over layer-steps: "
+           "tpu:moe_assignments_total over it is the share of the routing "
+           "this program's experts took (1 without a share).",
            SERVER_SURFACE),
     Family("tpu:sample_steps_total", "counter", ("path",),
            "Decode steps by the sampler's path, as the device took it: "
@@ -437,6 +446,13 @@ SERVER_FAMILIES = (
            "rewrote, summed over the steps of the plain decode dispatches: "
            "over tpu:dispatch_steps_sum, the rows one decode step's conv "
            "layers update, a layer. 0 for a model without conv layers.",
+           SERVER_SURFACE),
+    Family("tpu:kda_state_rows_total", "counter", (),
+           "Rows whose delta-rule matrix state (models/kda.py) a decode "
+           "step rewrote, summed over the steps of the plain decode "
+           "dispatches: over tpu:dispatch_steps_sum, the rows whose states "
+           "one decode step's update kernel reads and writes, a layer. 0 "
+           "for a model without KDA layers.",
            SERVER_SURFACE),
     Family("tpu:kv_positions_read_total", "counter", ("lanes",),
            "Cache positions the attention of the plain decode dispatches' "

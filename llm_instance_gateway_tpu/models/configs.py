@@ -35,15 +35,25 @@ class LayerKind(NamedTuple):
     > 0 lets position i attend j iff 0 <= i - j < window (0: every j <= i),
     ``rope`` says whether q and k get the rotary position encoding at all.
     ``conv``: no attention at all but a gated short convolution
-    (``models/shortconv.py``), whose layer holds no K and V."""
+    (``models/shortconv.py``), whose layer holds no K and V.  ``kda``: no
+    attention either but a delta-rule linear attention (``models/kda.py``),
+    whose layer holds a matrix state a head and a conv tail."""
     window: int = 0
     rope: bool = True
     conv: bool = False
+    kda: bool = False
+
+    @property
+    def operator(self) -> str:
+        """Which operator's leaves the layer runs: "conv", "kda" or "attn"
+        (per-head or latent attention)."""
+        return "conv" if self.conv else "kda" if self.kda else "attn"
 
     @property
     def cache(self) -> str:
         """Which of a decode cache's stacks the layer's rows live in."""
-        return "conv" if self.conv else "ring" if self.window else "full"
+        return ("conv" if self.conv else "kda" if self.kda
+                else "ring" if self.window else "full")
 
 
 MLP_ACTIVATIONS = ("silu", "gelu", "relu")
@@ -151,6 +161,35 @@ class ModelConfig:
     # conv leaves over the conv layers, the rest over all.  Its heads may be
     # narrower than a vreg's 128 lanes (``kv_pack``).
     conv_kernel: int = 0
+    # Delta-rule linear attention IN PLACE of attention (Ling-3.0-flash,
+    # ``bailing_hybrid``; Kimi Delta Attention, ``models/kda.py`` holds the
+    # equations): a "kda" layer of the pattern keeps, a slot and a head, a
+    # float32 matrix state [``kda_head_dim``, ``kda_head_dim``] (key x value)
+    # and the last ``kda_conv`` - 1 inputs of its three short convolutions;
+    # the cache holds them as ``kda`` [L_kda, B, heads, dk, dv] and ``conv``
+    # [L_kda, kda_conv - 1, B, 3 x heads x dk].  The per-channel log decay
+    # lies in (``kda_lower_bound``, 0).  A prompt's recurrence is computed in
+    # blocks of ``kda.BLOCK`` positions (``kda.scan_chunked``).
+    # The pattern's other word, "mla", is a latent-attention layer
+    # (``kv_lora_rank`` > 0): only those layers hold latent rows.
+    # ``mla_head_gate``: a latent layer's heads are gated, one sigmoid a
+    # head from the layer's normed input, before the output projection.
+    kda_n_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_lower_bound: float = -5.0
+    mla_head_gate: bool = False
+    # Experts held as ONE CHIP'S SHARE of a layer's (expert parallelism's
+    # local part): the router scores all ``n_experts``, this program holds
+    # and computes the ``n_experts_local`` from ``expert_first`` on (0: all)
+    # and returns their part of the mix plus the shared expert's.
+    # ``n_group`` > 1: group-limited routing, the experts lie in ``n_group``
+    # equal groups, a group scores the sum of its two largest biased scores
+    # and only the ``topk_group`` best groups' experts can be chosen.
+    n_experts_local: int = 0
+    expert_first: int = 0
+    n_group: int = 1
+    topk_group: int = 1
     # A state-space mixer beside attention in every layer (Falcon-H1,
     # ``falcon_h1``; the Mamba-2 form, ``models/ssm.py`` holds the
     # equations): ``ssm_d_inner`` > 0 makes the block parallel,
@@ -207,9 +246,29 @@ class ModelConfig:
         if self.mlp_activation not in MLP_ACTIVATIONS:
             raise ValueError(f"mlp_activation {self.mlp_activation!r}: one "
                              f"of {MLP_ACTIVATIONS}")
-        unknown = set(self.layer_pattern) - {"full", "nope", "window", "conv"}
+        unknown = set(self.layer_pattern) - {"full", "nope", "window", "conv",
+                                             "kda", "mla"}
         if unknown:
             raise ValueError(f"layer_pattern names {sorted(unknown)}")
+        if ("kda" in self.layer_pattern) != bool(self.kda_n_heads):
+            raise ValueError("a kda layer needs kda_n_heads > 0, and "
+                             "kda_n_heads a kda layer")
+        if ("mla" in self.layer_pattern) != bool(
+                self.layer_pattern and self.latent_width):
+            raise ValueError("an mla layer needs kv_lora_rank > 0, and a "
+                             "latent model's pattern names its mla layers")
+        if self.n_experts_local and not (
+                0 < self.n_experts_local
+                <= self.n_experts - self.expert_first):
+            raise ValueError("n_experts_local from expert_first on is not "
+                             "within n_experts")
+        if self.n_group > 1 and (
+                not self.router_sigmoid or self.n_experts % self.n_group
+                or not 0 < self.topk_group <= self.n_group
+                or self.n_experts // self.n_group < 2):
+            raise ValueError("group-limited routing: a sigmoid router over "
+                             "n_group equal groups of at least 2 experts, "
+                             "topk_group of them kept")
         if ("window" in self.layer_pattern) != bool(self.sliding_window):
             raise ValueError("a window layer needs sliding_window > 0, and "
                              "sliding_window a window layer")
@@ -221,11 +280,12 @@ class ModelConfig:
         # period as a span of its own, ``group_spans``: LFM2's published
         # depth ends half a period in.  Ring lanes are counted in whole
         # periods.)
-        if self.n_layers % period and not self.conv_kernel:
+        if self.n_layers % period and not self.kinds_own_leaves:
             raise ValueError(
                 f"{self.n_layers} layers are not whole periods of {period}")
-        if period > 1 and (self.latent_width or self.ssm_d_inner or (
-                self.first_k_dense and not self.conv_kernel)):
+        if period > 1 and ((self.latent_width and not self.kda_n_heads)
+                           or self.ssm_d_inner or (
+                self.first_k_dense and not self.kinds_own_leaves)):
             raise NotImplementedError(
                 "a period of layer kinds beside a latent cache or a mixer, "
                 "or of attention kinds beside leading dense layers")
@@ -239,8 +299,26 @@ class ModelConfig:
         """The period of the stack, one ``LayerKind`` a layer of it, counted
         from layer 0 of the model."""
         return tuple(LayerKind(self.sliding_window if k == "window" else 0,
-                               k != "nope", k == "conv")
+                               k != "nope", k == "conv", k == "kda")
                      for k in self.layer_pattern or ("full",))
+
+    @property
+    def kinds_own_leaves(self) -> bool:
+        """A stack some of whose layers run no attention (conv or kda
+        layers): each operator's leaves are stacked over ITS layers alone,
+        and a depth may end part of a period in."""
+        return bool(self.conv_kernel or self.kda_n_heads)
+
+    @property
+    def experts_held(self) -> int:
+        """Experts whose weights this program holds (all, or its share)."""
+        return self.n_experts_local or self.n_experts
+
+    @property
+    def kda_conv_dim(self) -> int:
+        """Channels the KDA layers' three short convolutions run over:
+        q | k | v."""
+        return 3 * self.kda_n_heads * self.kda_head_dim
 
     @property
     def n_dense_layers(self) -> int:
@@ -273,7 +351,7 @@ class ModelConfig:
 
     def n_layers_of(self, cache: str) -> int:
         """How many of the model's layers keep their rows in the decode
-        cache's ``cache`` stack ("full", "ring" or "conv")."""
+        cache's ``cache`` stack ("full", "ring", "conv" or "kda")."""
         return sum(self.kind_of(l).cache == cache
                    for l in range(self.n_layers))
 
@@ -633,6 +711,62 @@ TINY_LFM2_TEST = replace(
     LFM2_24B_A2B, name="lfm2-tiny", vocab_size=320, d_model=64, n_layers=10,
     n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128, moe_d_ff=32, n_experts=8,
     n_experts_per_token=2, max_seq_len=256, max_lora_rank=4)
+
+# inclusionAI/Ling-3.0-flash (``bailing_hybrid``): layer l is latent attention
+# (MLA: no query bottleneck, 32 heads of 128 + 64 / 128, a head-wise output
+# gate) iff (l + 1) % 6 == 0 and Kimi Delta Attention otherwise (32 heads, a
+# 128 x 128 float32 state a head, three short convolutions of 4 taps, a
+# per-channel decay in (-5, 0)); layers 0-1 a dense MLP of 6,144, the others
+# 512 experts of 768 in 8 groups (top-8 of the 4 best groups by sigmoid score
+# + bias, gates renormalised and scaled by 2.5) and one shared expert.  The
+# multi-token-prediction layer is a draft head and is not part of the forward.
+LING_3_FLASH = ModelConfig(
+    name="ling-3.0-flash",
+    vocab_size=157_184,
+    d_model=2560,
+    n_layers=42,
+    n_heads=32,
+    n_kv_heads=32,
+    d_ff=6144,
+    head_dim=128,
+    rope_theta=6_000_000.0,
+    norm_eps=1e-6,
+    n_experts=512,
+    n_experts_per_token=8,
+    norm_topk_prob=True,
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    moe_d_ff=768,
+    n_shared_experts=1,
+    first_k_dense=2,
+    router_sigmoid=True,
+    routed_scaling_factor=2.5,
+    n_group=8,
+    topk_group=4,
+    layer_pattern=("kda", "kda", "kda", "kda", "kda", "mla"),
+    kda_n_heads=32,
+    kda_head_dim=128,
+    kda_conv=4,
+    kda_lower_bound=-5.0,
+    mla_head_gate=True,
+    max_seq_len=262_144,
+    max_lora_slots=0,  # adapters are not served over a period-scanned stack
+)
+
+# The CPU's Ling: 2 dense layers and one rotated period (a depth of 12, the
+# cell's, leaves four more layers over: the tests build it from this), 32
+# experts in 4 groups of which the 8 of group 0 are held (one chip's share of
+# four), top-4 of the 2 best groups, 2 KDA heads of 16, a latent of 40 + 8
+# under 4 heads of 24 + 8 / 16.
+TINY_LING_TEST = replace(
+    LING_3_FLASH, name="ling-tiny", vocab_size=320, d_model=64, n_layers=8,
+    n_heads=4, n_kv_heads=4, head_dim=16, d_ff=128, moe_d_ff=32,
+    n_experts=32, n_experts_local=8, n_experts_per_token=4, n_group=4,
+    topk_group=2, kv_lora_rank=40, qk_nope_head_dim=24, qk_rope_head_dim=8,
+    v_head_dim=16, kda_n_heads=2, kda_head_dim=16, max_seq_len=256,
+    max_lora_rank=4)
 
 TINY_TEST = LLAMA3_8B.tiny()
 TINY_MOE_TEST = MIXTRAL_8X7B.tiny()

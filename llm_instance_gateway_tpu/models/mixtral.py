@@ -11,7 +11,10 @@ position encoding and three window layers) is the stack of two kinds.
 LFM2-24B-A2B (64 experts, top-4, GLM's router rule; three layers in four run
 a gated short convolution in attention's place, ``models/shortconv.py``, and
 hold no K and V; 64-wide heads with a per-head QK-norm) is the stack with
-layers that are not attention.
+layers that are not attention.  Ling-3.0-flash (512 experts in 8 groups,
+top-8 of the 4 best groups, held as a chip's share; delta-rule linear
+attention, ``models/kda.py``, in five layers of six and a latent layer closing
+the period) is the stack with a matrix state and a share of the experts.
 
 BASELINE.json's criticality-tiered mixed pool pairs Mixtral-8x7B with
 Gemma-7B on v5e-32.  The MoE MLP lives in ``transformer._moe_mlp``; expert
@@ -26,12 +29,14 @@ from llm_instance_gateway_tpu.models.configs import (
     FALCON_H1_34B,
     GLM_4_7_FLASH,
     LFM2_24B_A2B,
+    LING_3_FLASH,
     MIXTRAL_8X7B,
     OLMOE_1B_7B,
     SMALLTHINKER_21B_A3B,
     TINY_FALCON_H1_TEST,
     TINY_GLM_TEST,
     TINY_LFM2_TEST,
+    TINY_LING_TEST,
     TINY_MOE_TEST,
     TINY_OLMOE_TEST,
     TINY_SMALLTHINKER_TEST,
@@ -44,7 +49,8 @@ CONFIGS = {"mixtral-8x7b": MIXTRAL_8X7B, "mixtral-tiny": TINY_MOE_TEST,
            "falcon-h1-tiny": TINY_FALCON_H1_TEST,
            "smallthinker-21b-a3b": SMALLTHINKER_21B_A3B,
            "smallthinker-tiny": TINY_SMALLTHINKER_TEST,
-           "lfm2-24b-a2b": LFM2_24B_A2B, "lfm2-tiny": TINY_LFM2_TEST}
+           "lfm2-24b-a2b": LFM2_24B_A2B, "lfm2-tiny": TINY_LFM2_TEST,
+           "ling-3.0-flash": LING_3_FLASH, "ling-tiny": TINY_LING_TEST}
 
 init_params = transformer.init_params
 init_decode_cache = transformer.init_decode_cache

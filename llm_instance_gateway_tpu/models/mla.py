@@ -29,6 +29,17 @@ Two forms of the same attention (``tests/test_mla.py`` holds them equal):
 
 The pairing of rope columns is ``ops.layers.apply_rope``'s (split halves),
 for program and reference alike: ``config.json`` does not state it.
+
+Ling-3.0-flash's latent layers (one closing each period of six,
+``layer_pattern``'s "mla"; the cache holds rows for THOSE layers alone) differ
+in three things.  No query bottleneck (``q_lora_rank`` 0): ``q = h W_q``
+directly, one leaf ``wq``.  Heads of 128 + 64 with values of 128: the prefill
+kernels take one head size that is whole 128-lane vregs, so the expanded
+form pads queries, keys and values with zero columns to 256 (``_kernel_heads``:
+scores and values unchanged, the softmax scale kept at 1/sqrt(192) by a
+factor on the queries) and cuts the output back.  A head-wise output gate
+(``mla_head_gate``): ``o_h <- sigmoid(h w_gate,h) * o_h`` before ``W_o``, one
+number a head from the layer's normed input (``gate_heads``).
 """
 
 from __future__ import annotations
@@ -36,10 +47,17 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from llm_instance_gateway_tpu.models.configs import ModelConfig
+from llm_instance_gateway_tpu.models.configs import ModelConfig, pad_to
 from llm_instance_gateway_tpu.ops.attention import prefill_attention
 from llm_instance_gateway_tpu.ops.layers import apply_rope, rms_norm
 from llm_instance_gateway_tpu.ops.quant import is_quantized, matmul as q_matmul
+
+
+# The latent layers' own leaves beside ``wq`` and ``wo``: in a period of
+# kinds they are stacked over the latent layers alone
+# (``transformer._LEAF_OWNER``).
+LEAVES = ("wq_down", "q_latent_norm", "wq_up", "wkv_down", "kv_latent_norm",
+          "wkv_up", "w_head_gate")
 
 
 def leaf_shapes(cfg: ModelConfig) -> dict[str, tuple[tuple[int, ...], int]]:
@@ -47,16 +65,20 @@ def leaf_shapes(cfg: ModelConfig) -> dict[str, tuple[tuple[int, ...], int]]:
     fan_in 0 marks a norm weight (ones)."""
     d, h = cfg.d_model, cfg.n_heads
     qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-    if (cfg.v_head_dim, cfg.resolved_head_dim) != (qk, qk):
+    if cfg.v_head_dim > qk:
         raise NotImplementedError(
-            f"{cfg.name}: the prefill kernels take one head size, so the "
-            f"value head ({cfg.v_head_dim}) and head_dim "
-            f"({cfg.resolved_head_dim}) have to equal the query/key head "
-            f"({qk} = qk_nope_head_dim + qk_rope_head_dim)")
+            f"{cfg.name}: the prefill kernels take one head size, the "
+            f"query/key head's ({qk} = qk_nope_head_dim + qk_rope_head_dim) "
+            f"padded to whole vregs: a value head of {cfg.v_head_dim} does "
+            "not fit it")
+    query = ({"wq_down": ((d, cfg.q_lora_rank), d),
+              "q_latent_norm": ((cfg.q_lora_rank,), 0),
+              "wq_up": ((cfg.q_lora_rank, h * qk), cfg.q_lora_rank)}
+             if cfg.q_lora_rank else {"wq": ((d, h * qk), d)})
+    gate = {"w_head_gate": ((d, h), d)} if cfg.mla_head_gate else {}
     return {
-        "wq_down": ((d, cfg.q_lora_rank), d),
-        "q_latent_norm": ((cfg.q_lora_rank,), 0),
-        "wq_up": ((cfg.q_lora_rank, h * qk), cfg.q_lora_rank),
+        **query,
+        **gate,
         "wkv_down": ((d, cfg.latent_width), d),
         "kv_latent_norm": ((cfg.kv_lora_rank,), 0),
         "wkv_up": ((cfg.kv_lora_rank,
@@ -67,11 +89,12 @@ def leaf_shapes(cfg: ModelConfig) -> dict[str, tuple[tuple[int, ...], int]]:
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> dict:
-    """The latent decode cache: ``k`` holds the rows (keys AND values), and
-    there is no ``v``."""
+    """The latent decode cache: ``k`` holds the rows (keys AND values) of
+    the layers that are latent attention (all, but in a period of kinds),
+    and there is no ``v``."""
     return {
-        "k": jnp.zeros((cfg.n_layers, batch, max_len, cfg.latent_lanes),
-                       dtype),
+        "k": jnp.zeros((cfg.n_layers_of("full"), batch, max_len,
+                        cfg.latent_lanes), dtype),
         "length": jnp.zeros((batch,), jnp.int32),
     }
 
@@ -85,9 +108,13 @@ def project(cfg: ModelConfig, lp, hn, positions):
     nope], q_rope [..., S, H, rope] roped, latent rows [..., S, lanes])."""
     h, nope, rope = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     with jax.named_scope("attn.q_latent"):
-        c_q = rms_norm(q_matmul(hn, lp["wq_down"]), lp["q_latent_norm"],
-                       cfg.norm_eps)
-        q = q_matmul(c_q, lp["wq_up"]).reshape(*hn.shape[:-1], h, nope + rope)
+        if "wq" in lp:  # no bottleneck (``q_lora_rank`` 0)
+            q = q_matmul(hn, lp["wq"])
+        else:
+            c_q = rms_norm(q_matmul(hn, lp["wq_down"]), lp["q_latent_norm"],
+                           cfg.norm_eps)
+            q = q_matmul(c_q, lp["wq_up"])
+        q = q.reshape(*hn.shape[:-1], h, nope + rope)
         q_nope, q_rope = q[..., :nope], q[..., nope:]
     with jax.named_scope("attn.kv_latent"):
         ckv = q_matmul(hn, lp["wkv_down"])
@@ -130,13 +157,48 @@ def expand(cfg: ModelConfig, lp, latent):
             kv[..., nope:])
 
 
+def _kernel_heads(q, k, v):
+    """The expanded form's q, k [..., H, nope + rope] and v [..., H, vd] as
+    the prefill kernels take them: ONE head size of whole 128-lane vregs.
+    Where the model's are that already (GLM's 256 / 256) they pass as they
+    are; else all three get zero columns up to it and q the factor that
+    keeps the softmax's scale the true head's (the kernels scale by the size
+    they see).  Returns (q, k, v, what to cut the output's heads back to:
+    None or vd)."""
+    qk, vd = q.shape[-1], v.shape[-1]
+    wide = pad_to(qk, 128)
+    if (qk, vd) == (wide, wide):
+        return q, k, v, None
+
+    def widen(x):
+        return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, wide - x.shape[-1]),))
+
+    q = q * jnp.asarray((wide / qk) ** 0.5, q.dtype)
+    return widen(q), widen(k), widen(v), vd
+
+
+def gate_heads(cfg: ModelConfig, lp, hn, attn):
+    """The head-wise output gate (``mla_head_gate``): ``attn`` [..., H * vd]
+    times sigmoid(hn w_gate) [..., H], a number a head.  Every other model's
+    output passes as it is."""
+    if "w_head_gate" not in lp:
+        return attn
+    with jax.named_scope("attn.head_gate"):
+        gate = jax.nn.sigmoid(jnp.dot(hn, lp["w_head_gate"],
+                                      preferred_element_type=jnp.float32))
+        heads = attn.reshape(*attn.shape[:-1], cfg.n_heads, -1)
+        return (heads * gate[..., None].astype(attn.dtype)).reshape(
+            attn.shape)
+
+
 def prefill_attend(cfg: ModelConfig, lp, hn, positions, attention_fn=None):
     """Bucketed prefill, expanded form.  ``hn`` [B, S, D] -> (attention
     output [B, S, H * vd], latent rows [B, S, lanes])."""
     b, s, _ = hn.shape
     q_nope, q_rope, latent = project(cfg, lp, hn, positions)
     k, v = expand(cfg, lp, latent)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    q, k, v, cut = _kernel_heads(
+        jnp.concatenate([q_nope, q_rope], axis=-1), k, v)
     with jax.named_scope("attn.core"):
         if attention_fn is not None:
             attn = attention_fn(q, k, v, positions)
@@ -148,7 +210,8 @@ def prefill_attend(cfg: ModelConfig, lp, hn, positions, attention_fn=None):
             attn = flash_attention(q, k, v)
         else:
             attn = prefill_attention(q, k, v, positions)
-    return attn.reshape(b, s, -1), latent
+    attn = attn[..., :cut].reshape(b, s, -1)
+    return gate_heads(cfg, lp, hn, attn), latent
 
 
 @jax.named_scope("attn.absorb")
@@ -193,7 +256,7 @@ def decode_attend(cfg: ModelConfig, lp, hn, positions, kv, at, held,
         o_lat = pda.mla_decode_attention(
             q_lat, rows, lengths, cfg.kv_lora_rank, _scale(cfg), layer=layer,
             use_kernel=cfg.use_pallas_decode, schedule=schedule)
-    return absorb_output(cfg, lp, o_lat), (rows,)
+    return gate_heads(cfg, lp, hn, absorb_output(cfg, lp, o_lat)), (rows,)
 
 
 def chunk_attend(cfg: ModelConfig, lp, hn, positions, kv, layer, slot,
@@ -211,5 +274,10 @@ def chunk_attend(cfg: ModelConfig, lp, hn, positions, kv, layer, slot,
     lane = jax.lax.dynamic_slice(
         rows, (layer, slot, 0, 0), (1, 1, *rows.shape[2:]))[0, 0]
     lane_k, lane_v = expand(cfg, lp, lane.astype(hn.dtype))
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    return chunk_fn(q, lane_k, lane_v, positions[0]), (rows,)
+    q, lane_k, lane_v, cut = _kernel_heads(
+        jnp.concatenate([q_nope, q_rope], axis=-1), lane_k, lane_v)
+    attn = chunk_fn(q, lane_k, lane_v, positions[0])  # [1, C, H * wide]
+    if cut is not None:
+        attn = attn.reshape(*attn.shape[:-1], cfg.n_heads, -1)[
+            ..., :cut].reshape(*attn.shape[:-1], -1)
+    return gate_heads(cfg, lp, hn, attn), (rows,)

@@ -97,6 +97,31 @@ the embedding transposed:
     The leaves of the attention lie stacked over the attention layers of
     their group alone, the conv operator's over its conv layers.
 
+Ling-3.0-flash (``bailing_hybrid``, ``kda_n_heads`` > 0): layer l of the MODEL
+is of the kind ``layer_pattern[l % period]``, "kda" or "mla":
+
+    kind "kda":   [q^ | k^ | v^ | f | z] = x_n W_in (split in this order)
+                  q-, k-, v- = silu(conv(.)), each a causal depthwise conv of
+                      ``kda_conv`` taps, zeros before position 0, no bias
+                  q = dk^(-1/2) q- / |q-|,  k = k- / |k-|   per head, eps 1e-6
+                  g = bound * sigmoid(exp(A_log) * (f + dt_bias))  per channel
+                  beta = sigmoid(x_n w_b)                     per head
+                  S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1}
+                        + beta_t k_t v_t^T;   o_t = S_t^T q_t,  S_{-1} = 0,
+                      one position after another
+                  x = x + [RMSNorm_w(o_t) per head * sigmoid(z_t)] W_out
+    kind "mla":   the latent attention above with q = x_n W_q directly (no
+                  bottleneck), a value head narrower than the query head, and
+                  o_h <- sigmoid(x_n w_gate,h) * o_h before W_o
+    sparse:       GLM's rule, but the experts lie in ``n_group`` groups, a
+                  group scores the sum of its two largest s + b, and only the
+                  ``topk_group`` best groups' experts can be chosen; under a
+                  share (``n_experts_local``) the sum runs over the chosen
+                  experts THIS program holds (``expert_first`` on), the gates
+                  normalised over all chosen; the shared expert once
+    The leaves of the latent attention lie stacked over the latent layers of
+    their group alone, the KDA operator's over its KDA layers.
+
 A LoRA adapter adds ``scale * (z A) B`` to a projection of ``z``.
 
 Departures from the published descriptions, each on purpose:
@@ -180,6 +205,43 @@ def _short_conv(cfg, lp, layer, x_n, states=None):
     return (c * conv) @ _weight(lp["conv_out"], layer)
 
 
+def _kda(cfg, lp, layer, x_n, states=None):
+    """Kimi Delta Attention, position by position; ``layer`` counts the
+    group's KDA layers.  ``states``, a list, gets the layer's (S [heads,
+    dk, dv] after the last position, the convs' last K - 1 inputs
+    [K - 1, 3 inner]) appended."""
+    s, taps = x_n.shape[0], cfg.kda_conv
+    h, dk = cfg.kda_n_heads, cfg.kda_head_dim
+    inner = h * dk
+    proj = x_n @ _weight(lp["kda_in"], layer)
+    qkv, f, z = proj[:, :3 * inner], proj[:, 3 * inner:4 * inner], proj[:, 4 * inner:]
+    w = lp["kda_conv_w"][layer].astype(F32)  # [taps, 3 inner]
+    padded = jnp.concatenate([jnp.zeros((taps - 1, 3 * inner), F32), qkv])
+    conv = jax.nn.silu(sum(w[j] * padded[j:j + s] for j in range(taps)))
+    q, k, v = (t.reshape(s, h, dk) for t in jnp.split(conv, 3, axis=-1))
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) * dk ** -0.5
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+    a = jnp.exp(lp["kda_a_log"][layer].astype(F32))[:, None]
+    g = cfg.kda_lower_bound * jax.nn.sigmoid(
+        a * (f + lp["kda_dt_bias"][layer].astype(F32)).reshape(s, h, dk))
+    beta = jax.nn.sigmoid(x_n @ lp["kda_beta"][layer].astype(F32))  # [S, h]
+    state = jnp.zeros((h, dk, dk), F32)
+    outs = []
+    for t in range(s):
+        state = jnp.exp(g[t])[:, :, None] * state
+        delta = beta[t][:, None] * (
+            v[t] - jnp.einsum("hd,hdv->hv", k[t], state))
+        state = state + k[t][:, :, None] * delta[:, None, :]
+        outs.append(jnp.einsum("hd,hdv->hv", q[t], state))
+    if states is not None:
+        states.append((state, padded[s:]))
+    o = jnp.stack(outs)  # [S, h, dv]
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                          + cfg.norm_eps) * lp["kda_norm"][layer].astype(F32)
+    return (o.reshape(s, inner) * jax.nn.sigmoid(z)) @ _weight(
+        lp["kda_out"], layer)
+
+
 def _attention(cfg, lp, layer, x_n, lora, model_layer=None):
     """``layer`` counts the leaves' stack; ``model_layer`` (where they
     differ: a model whose attention leaves skip its conv layers) names the
@@ -258,9 +320,13 @@ def _latent_attention(cfg, lp, layer, x_n):
     s = x_n.shape[0]
     h, nope, rope = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     rank, vd = cfg.kv_lora_rank, cfg.v_head_dim
-    c_q = _rms_norm(x_n @ _weight(lp["wq_down"], layer),
-                    lp["q_latent_norm"][layer].astype(F32), cfg.norm_eps)
-    q = (c_q @ _weight(lp["wq_up"], layer)).reshape(s, h, nope + rope)
+    if cfg.q_lora_rank:
+        c_q = _rms_norm(x_n @ _weight(lp["wq_down"], layer),
+                        lp["q_latent_norm"][layer].astype(F32), cfg.norm_eps)
+        q = c_q @ _weight(lp["wq_up"], layer)
+    else:  # no bottleneck
+        q = x_n @ _weight(lp["wq"], layer)
+    q = q.reshape(s, h, nope + rope)
     ckv = x_n @ _weight(lp["wkv_down"], layer)
     c = _rms_norm(ckv[:, :rank], lp["kv_latent_norm"][layer].astype(F32),
                   cfg.norm_eps)
@@ -272,8 +338,11 @@ def _latent_attention(cfg, lp, layer, x_n):
               ) / jnp.sqrt(F32(nope + rope))
     causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
     probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-    a = jnp.einsum("hij,jhd->ihd", probs, kv[..., nope:]).reshape(s, -1)
-    return a @ _weight(lp["wo"], layer)
+    a = jnp.einsum("hij,jhd->ihd", probs, kv[..., nope:])
+    if cfg.mla_head_gate:
+        a = a * jax.nn.sigmoid(
+            x_n @ lp["w_head_gate"][layer].astype(F32))[..., None]
+    return a.reshape(s, -1) @ _weight(lp["wo"], layer)
 
 
 _ACT = {"silu": jax.nn.silu, "relu": jax.nn.relu}
@@ -299,6 +368,12 @@ def _mlp(cfg, lp, layer, h_n, lora, x=None):
         pick = p + lp["router_bias"][layer].astype(F32)
     else:
         p = pick = jax.nn.softmax(router, axis=-1)
+    if cfg.n_group > 1:  # only the best groups' experts can be chosen
+        groups = pick.reshape(pick.shape[0], cfg.n_group, -1)
+        score = jnp.sum(jnp.sort(groups, axis=-1)[..., -2:], axis=-1)
+        worst_kept = jnp.sort(score, axis=-1)[:, -cfg.topk_group][:, None]
+        pick = jnp.where((score >= worst_kept)[..., None], groups,
+                         -jnp.inf).reshape(pick.shape)
     kth = jnp.sort(pick, axis=-1)[:, -cfg.n_experts_per_token][:, None]
     w = jnp.where(pick >= kth, p, 0.0)
     if cfg.router_sigmoid:
@@ -310,9 +385,11 @@ def _mlp(cfg, lp, layer, h_n, lora, x=None):
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     wg, wu, wd = (_weight(lp[n], layer) for n in ("w_gate", "w_up", "w_down"))
     y = jnp.zeros_like(h_n)
-    for e in range(cfg.n_experts):
-        y = y + w[:, e: e + 1] * _gated(h_n, wg[e], wu[e], wd[e],
-                                        cfg.mlp_activation)
+    # (a share: the stacks hold the experts from ``expert_first`` on)
+    for e in range(wg.shape[0]):
+        at = cfg.expert_first + e
+        y = y + w[:, at: at + 1] * _gated(h_n, wg[e], wu[e], wd[e],
+                                          cfg.mlp_activation)
     if cfg.n_shared_experts:
         y = y + _gated(h_n, *(_weight(lp[n], layer)
                               for n in ("ws_gate", "ws_up", "ws_down")),
@@ -327,15 +404,17 @@ def forward(cfg, params, tokens, lora=None, states=None):
     the serving LoRA buffers and the slot whose adapter this sequence uses.
     ``states``: None, or a list that gets each layer's recurrent state after
     the last position (a model with a mixer) or each CONV layer's last
-    K - 1 inputs z (a model with conv layers), to hold a cache's against.
+    K - 1 inputs z (a model with conv layers) or each KDA layer's (matrix
+    states, conv history), to hold a cache's against.
     """
     if (cfg.embedding_scale or cfg.norm_plus_one
             or cfg.gelu_mlp or cfg.rope_scaling_factor):
         raise NotImplementedError(
             "the reference covers the Llama/Qwen2/Mixtral/OLMoE/GLM/Falcon-H1/"
-            "SmallThinker/LFM2 block; the "
+            "SmallThinker/LFM2/Ling block; the "
             f"Gemma conventions and rope scaling of {cfg.name} are not in it")
-    if (cfg.kv_lora_rank or cfg.ssm_d_inner or cfg.conv_kernel) and (
+    if (cfg.kv_lora_rank or cfg.ssm_d_inner or cfg.conv_kernel
+            or cfg.kda_n_heads) and (
             lora is not None):
         raise NotImplementedError(
             "no adapter over latent projections, beside a mixer or over "
@@ -347,11 +426,12 @@ def forward(cfg, params, tokens, lora=None, states=None):
     stack = ([(params["dense_layers"], i) for i in range(n_dense)]
              + [(params["layers"], i)
                 for i in range(cfg.n_layers - n_dense)])
-    # A layer's place among the layers of ITS kind (conv or not) of its
-    # group: where a model with conv layers keeps that kind's leaves.
+    # A layer's place among the layers of ITS kind (conv, kda or attention)
+    # of its group: where a model with such layers keeps that kind's leaves.
     of_kind, seen = [], {}
     for l, (lp, _) in enumerate(stack):
-        key = (id(lp), _pattern(cfg, l) == "conv")
+        key = (id(lp), _pattern(cfg, l) in ("conv", "kda")
+               and _pattern(cfg, l))
         of_kind.append(seen.get(key, 0))
         seen[key] = of_kind[-1] + 1
     with jax.default_matmul_precision("highest"):
@@ -360,6 +440,10 @@ def forward(cfg, params, tokens, lora=None, states=None):
             x_n = _rms_norm(x, lp["attn_norm"][layer].astype(F32), cfg.norm_eps)
             if _pattern(cfg, l) == "conv":
                 branches = _short_conv(cfg, lp, of_kind[l], x_n, states)
+            elif _pattern(cfg, l) == "kda":
+                branches = _kda(cfg, lp, of_kind[l], x_n, states)
+            elif cfg.kda_n_heads:
+                branches = _latent_attention(cfg, lp, of_kind[l], x_n)
             elif cfg.conv_kernel:
                 branches = _attention(cfg, lp, of_kind[l], x_n, lora, l)
             else:

@@ -1,4 +1,4 @@
-"""Core decoder-only transformer: one implementation, nine families.
+"""Core decoder-only transformer: one implementation, ten families.
 
 Covers Llama-3 (RoPE+GQA+SwiGLU), Gemma (tied embeddings, sqrt(d) embedding
 scale, GeLU gate, (1+w) RMSNorm, shared KV head), Qwen2 (QKV bias), Mixtral
@@ -16,7 +16,11 @@ and LFM2 (layers WITHOUT attention: a gated short convolution in its place in
 three layers of four, ``models/shortconv.py``, whose per-slot state rides the
 cache and the carry beside the K/V lanes of the attention layers alone; the
 leaves of a period one stack a kind; 64-wide heads, two to a cache row, with
-a per-head QK-norm; leading dense layers before a rotated period)
+a per-head QK-norm; leading dense layers before a rotated period) and
+Ling-3.0-flash (delta-rule linear attention in five layers of six,
+``models/kda.py``, whose float32 matrix state a head rides the cache and the
+carry beside the latent rows of the ONE latent layer that closes each period;
+a group-limited router over experts of which the program holds a chip's share)
 via ``ModelConfig`` flags.
 
 TPU-first structure:
@@ -39,7 +43,8 @@ TPU-first structure:
   attn.kv_latent, attn.absorb, attn.expand; a mixer's ssm.in_proj, ssm.conv,
   ssm.scan, ssm.update, ssm.gate_norm, ssm.out_proj; a conv layer's
   conv.in_proj, conv.mix, conv.out_proj; attn.qk_norm of a per-head
-  QK-norm): the scope is in each
+  QK-norm; a KDA layer's kda.in_proj, kda.conv, kda.gate, kda.scan,
+  kda.update, kda.gate_norm, kda.out_proj; attn.head_gate): the scope is in each
   compiled operation's name, so a device trace says which line of this file
   an operation belongs to.
 """
@@ -52,6 +57,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from llm_instance_gateway_tpu.models import kda
 from llm_instance_gateway_tpu.models import lora as lora_lib
 from llm_instance_gateway_tpu.models import mla
 from llm_instance_gateway_tpu.models import shortconv
@@ -146,12 +152,12 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
 
     def group(layer_sh, n_l: int, sparse: bool, first: int = 0) -> Params:
         """``n_l`` stacked layers with one kind of MLP, from layer ``first``
-        of the model on.  A model with conv layers stacks the attention's
-        leaves over the group's ``n_a`` attention layers alone and the conv
-        operator's over its conv layers (``shortconv.ATTN_LEAVES``,
-        ``CONV_LEAVES``); a group without one of the two has no such
-        leaf."""
-        n_a = n_l - sum(cfg.kind_of(first + j).conv for j in range(n_l))
+        of the model on.  A model with conv or KDA layers stacks the
+        attention's leaves over the group's ``n_a`` attention layers alone
+        and the other operator's over its own layers (``_LEAF_OWNER``); a
+        group without one of the two has no such leaf."""
+        ops = [cfg.kind_of(first + j).operator for j in range(n_l)]
+        n_a, n_kda = ops.count("attn"), ops.count("kda")
         layers: Params = {
             "attn_norm": const(layer_sh, "attn_norm", 1, (n_l, d)),
             "mlp_norm": const(layer_sh, "mlp_norm", 1, (n_l, d)),
@@ -163,10 +169,15 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
                            else const(layer_sh, name, 1, (n, *shape)))
                     for name, (shape, fan_in) in shapes.items()}
 
-        if n_a < n_l:
-            layers.update(drawn(shortconv.leaf_shapes(cfg), n_l - n_a))
+        if "conv" in ops:
+            layers.update(drawn(shortconv.leaf_shapes(cfg),
+                                ops.count("conv")))
+        if n_kda:
+            layers.update(drawn(kda.leaf_shapes(cfg), n_kda))
+            layers.update(kda.init_vectors(cfg, next(keys), n_kda))
         if cfg.latent_width:
-            layers.update(drawn(mla.leaf_shapes(cfg)))
+            if n_a:
+                layers.update(drawn(mla.leaf_shapes(cfg), n_a))
         elif n_a:
             layers["wq"] = rand(layer_sh, "wq", (n_a, d, cfg.n_heads * hd), d)
             layers["wk"] = rand(layer_sh, "wk",
@@ -203,13 +214,16 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
             for name in ("q_norm", "k_norm"):
                 layers[name] = 2 + rand(layer_sh, name, (n_a, hd), 100)
         if sparse:
-            e, f = cfg.n_experts, cfg.expert_d_ff
-            layers["router"] = rand(layer_sh, "router", (n_l, d, e), d)
+            # The router scores every expert; the stacks hold this
+            # program's share of them (``n_experts_local``; all without).
+            e, f = cfg.experts_held, cfg.expert_d_ff
+            layers["router"] = rand(layer_sh, "router",
+                                    (n_l, d, cfg.n_experts), d)
             if cfg.router_sigmoid:
                 # Drawn non-zero (std 0.1 beside scores in (0, 1)): a router
                 # that ignores the selection bias must not pass a test.
                 layers["router_bias"] = rand(layer_sh, "router_bias",
-                                             (n_l, e), 100)
+                                             (n_l, cfg.n_experts), 100)
             layers["w_gate"] = rand(layer_sh, "w_gate", (n_l, e, d, f), d)
             layers["w_up"] = rand(layer_sh, "w_up", (n_l, e, d, f), d)
             layers["w_down"] = rand(layer_sh, "w_down", (n_l, e, f, d), f)
@@ -268,11 +282,17 @@ def init_decode_cache(
     holds exactly its last ``ring`` positions.  A model with conv layers
     holds K and V for its ATTENTION layers alone and for each conv layer a
     slot's last inputs, ``conv`` (``shortconv.init_state``).  ``kv_pack``
-    narrow kv heads share a row: [.., K / pack, pack * hd]."""
+    narrow kv heads share a row: [.., K / pack, pack * hd].  A model with KDA
+    layers (``models/kda.py``) holds latent rows ``k`` for its latent layers
+    alone and for each KDA layer a slot's matrix states ``kda`` (float32)
+    and conv history ``conv`` (``kda.init_state``)."""
     if cfg.latent_width:
         if quantized:
             raise ValueError("a latent (MLA) cache has no int8 form")
-        return mla.init_cache(cfg, batch, max_len, dtype)
+        cache = mla.init_cache(cfg, batch, max_len, dtype)
+        if cfg.kda_n_heads:
+            cache.update(kda.init_state(cfg, batch, dtype))
+        return cache
     hd = cfg.resolved_head_dim
     n_win = cfg.n_window_layers
     if n_win and quantized:
@@ -385,8 +405,10 @@ def _split_carry(kv: tuple, n_rec: int) -> tuple[tuple, tuple]:
 
 def _n_rec(cache: Params) -> int:
     """How many of the carry's last arrays hold a state that is no K or V:
-    a mixer's (ssm, conv), the conv layers' (conv,), else none."""
-    return 2 if "ssm" in cache else 1 if "conv" in cache else 0
+    a mixer's (ssm, conv), the KDA layers' (kda, conv), the conv layers'
+    (conv,), else none."""
+    return (2 if "ssm" in cache or "kda" in cache
+            else 1 if "conv" in cache else 0)
 
 
 @jax.named_scope("attn.out")
@@ -480,9 +502,14 @@ def _route_early(cfg: ModelConfig, lp: Params, h, live):
 
 # The sparse layer's routing counts, one int32 vector a layer-step:
 # (layer-steps, live assignments, experts with at least one, row tiles that
-# hold a group: over the experts touched, the tiles a group takes).  They sum
-# over layers and steps; the engine reads them back with the step.
-MOE_TALLY = ("layer_steps", "assignments", "experts_touched", "tiles_used")
+# hold a group: over the experts touched, the tiles a group takes; every
+# assignment the router made).  The middle three count what the expert
+# matmuls did: under a share (``n_experts_local``) the assignments KEPT and
+# the experts touched OF THOSE HELD; the last counts held here or not, and
+# equals ``assignments`` without a share.  They sum over layers and steps;
+# the engine reads them back with the step.
+MOE_TALLY = ("layer_steps", "assignments", "experts_touched", "tiles_used",
+             "assignments_routed")
 _EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
 
@@ -502,7 +529,13 @@ def _moe_route(cfg: ModelConfig, lp: Params, x, live=None) -> dict:
     the full softmax gives them.  ``router_sigmoid``: f32 sigmoid scores;
     the experts are the k largest of score + ``router_bias``, their weights
     the scores WITHOUT the bias, renormalised over the chosen
-    (``norm_topk_prob``) and scaled by ``routed_scaling_factor``.  dispatch: each of the ``T*k`` assignments
+    (``norm_topk_prob``) and scaled by ``routed_scaling_factor``; under
+    ``n_group`` > 1 only the experts of the ``topk_group`` best groups can be
+    chosen (``_group_limited``).  A share (``n_experts_local``): the router
+    scores and chooses among ALL ``n_experts`` and the gates are normalised
+    over all k chosen, absent ones included; an assignment whose expert
+    another chip holds is then dropped like a dead row's, so the layer
+    returns this chip's part of the mix.  dispatch: each of the ``T*k`` assignments
     gets a row in a layout grouped by expert, every group padded to whole
     tiles of ``tm`` rows (``pallas_moe.tile_rows``: from the mean group
     size, so static); its ``row`` there comes from a one-hot cumsum: no
@@ -534,8 +567,11 @@ def _moe_route(cfg: ModelConfig, lp: Params, x, live=None) -> dict:
     """
     xf = x.reshape(-1, x.shape[-1])
     t = xf.shape[0]
-    e, k = cfg.n_experts, cfg.n_experts_per_token
-    tm = pallas_moe.tile_rows(t * k, e)
+    # ``e`` experts are held here, of the router's ``n_experts``: the tile
+    # from the mean group over ALL experts (what a share's groups see), the
+    # bound on the tiles from those held (all k of a row can land here).
+    e, k = cfg.experts_held, cfg.n_experts_per_token
+    tm = pallas_moe.tile_rows(t * k, cfg.n_experts)
     n_tiles = pallas_moe.n_tiles(t * k, e, tm)
     n_rows = n_tiles * tm
 
@@ -544,8 +580,8 @@ def _moe_route(cfg: ModelConfig, lp: Params, x, live=None) -> dict:
                                 preferred_element_type=jnp.float32)  # [T, E]
         if cfg.router_sigmoid:
             scores = jax.nn.sigmoid(router_logits)
-            _, topi = jax.lax.top_k(
-                scores + lp["router_bias"].astype(jnp.float32), k)
+            _, topi = jax.lax.top_k(_group_limited(
+                cfg, scores + lp["router_bias"].astype(jnp.float32)), k)
             gates = jnp.take_along_axis(scores, topi, axis=-1)  # [T, k]
             if cfg.norm_topk_prob:
                 gates = gates / (jnp.sum(gates, axis=-1, keepdims=True)
@@ -561,8 +597,14 @@ def _moe_route(cfg: ModelConfig, lp: Params, x, live=None) -> dict:
 
     with jax.named_scope("moe.dispatch"):
         expert = topi.reshape(-1)  # [T*k], token-major
+        routed = jnp.asarray(t * k, jnp.int32)
+        if cfg.n_experts_local:  # its place among those held; e: not here
+            expert = expert - cfg.expert_first
+            expert = jnp.where((expert >= 0) & (expert < e), expert, e)
         if live is not None:
-            expert = jnp.where(jnp.repeat(live.reshape(-1), k), expert, e)
+            alive = jnp.repeat(live.reshape(-1), k)
+            expert = jnp.where(alive, expert, e)
+            routed = jnp.sum(alive, dtype=jnp.int32)
         chose = (expert[:, None] == jnp.arange(e)).astype(jnp.int32)  # [T*k, E]
         sizes = jnp.sum(chose, axis=0)  # [E] rows of each group
         # Place of an assignment in its group: assignments before it there.
@@ -577,10 +619,25 @@ def _moe_route(cfg: ModelConfig, lp: Params, x, live=None) -> dict:
         src = (_layout_source(expert, sizes, k, tm, n_rows)
                if _gathers_in(t * k, e) else None)
         tally = jnp.stack([jnp.ones((), jnp.int32), jnp.sum(sizes),
-                           jnp.sum(sizes > 0), n_used])
+                           jnp.sum(sizes > 0), n_used, routed])
     return {"gates": gates, "row": row, "src": src,
             "tile_expert": tile_expert, "n_used": n_used, "tally": tally,
             "tm": tm, "n_rows": n_rows}
+
+
+def _group_limited(cfg: ModelConfig, biased):
+    """Group-limited selection (``n_group`` > 1): ``biased`` [T, E], the
+    scores plus the selection bias, with -inf wherever an expert's group is
+    not among the ``topk_group`` best; a group scores the sum of its two
+    largest entries.  As they are for every other model."""
+    if cfg.n_group <= 1:
+        return biased
+    t, e = biased.shape
+    grouped = biased.reshape(t, cfg.n_group, e // cfg.n_group)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)  # [T, G]
+    _, best = jax.lax.top_k(group_score, cfg.topk_group)
+    kept = jnp.any(best[..., None] == jnp.arange(cfg.n_group), axis=1)
+    return jnp.where(kept[..., None], grouped, -jnp.inf).reshape(t, e)
 
 
 # The most assignments (``T*k``, static) whose rows the SCATTER lays out.
@@ -709,21 +766,28 @@ def _layer_groups(params: Params) -> list[Params]:
     return [params["layers"]]
 
 
+# Which operator's layers a leaf is stacked over in a model some of whose
+# layers run no attention (``ModelConfig.kinds_own_leaves``,
+# ``LayerKind.operator``); a leaf not named here is every layer's.
+_LEAF_OWNER = {**dict.fromkeys(shortconv.CONV_LEAVES, "conv"),
+               **dict.fromkeys(kda.LEAVES, "kda"),
+               **dict.fromkeys(shortconv.ATTN_LEAVES + mla.LEAVES, "attn")}
+
+
 def _period_layer(cfg: ModelConfig, lps: Params, kinds, j: int) -> Params:
     """Layer ``j`` of a period out of the period's leaves ``lps`` (each
     [layers of the period that have it, ...]).  Every leaf is every
-    layer's, but in a model with conv layers: there the attention's leaves
-    are stacked over the period's attention layers alone and the conv
-    operator's over its conv layers, and a layer gets its own kind's."""
-    if not cfg.conv_kernel:
+    layer's, but in a model with conv or KDA layers: there each operator's
+    leaves are stacked over the period's layers of that operator alone
+    (``_LEAF_OWNER``), and a layer gets its own operator's."""
+    if not cfg.kinds_own_leaves:
         return jax.tree.map(lambda a: a[j], lps)
-    conv = kinds[j].conv
-    mine, others = ((shortconv.CONV_LEAVES, shortconv.ATTN_LEAVES) if conv
-                    else (shortconv.ATTN_LEAVES, shortconv.CONV_LEAVES))
-    own = sum(k.conv == conv for k in kinds[:j])  # its place in its kind
+    op = kinds[j].operator
+    own = sum(k.operator == op for k in kinds[:j])  # its place in its kind
     return {name: jax.tree.map(
-                lambda a, i=own if name in mine else j: a[i], leaf)
-            for name, leaf in lps.items() if name not in others}
+                lambda a, i=own if name in _LEAF_OWNER else j: a[i], leaf)
+            for name, leaf in lps.items()
+            if _LEAF_OWNER.get(name, op) == op}
 
 
 def _stack_layers(ys: list):
@@ -743,18 +807,16 @@ def _stack_layers(ys: list):
 def _span_leaves(cfg: ModelConfig, scanned: Params, first: int, start: int,
                  n: int) -> Params:
     """The scanned leaves of the ``n`` layers from layer ``start`` on, out of
-    those of a group with conv layers that starts at layer ``first``: each
-    leaf's rows of those layers, counted among the group's layers that HAVE
-    the leaf (``_period_layer``); a leaf none of them has is left out, as
-    in a group of one kind."""
-    def rows(conv):  # None: a leaf every layer has
+    those of a group with conv or KDA layers that starts at layer ``first``:
+    each leaf's rows of those layers, counted among the group's layers that
+    HAVE the leaf (``_period_layer``); a leaf none of them has is left out,
+    as in a group of one kind."""
+    def rows(op):  # None: a leaf every layer has
         def upto(end):
-            return sum(conv is None or cfg.kind_of(l).conv == conv
+            return sum(op is None or cfg.kind_of(l).operator == op
                        for l in range(first, end))
         return slice(upto(start), upto(start + n))
-    own = {**dict.fromkeys(shortconv.CONV_LEAVES, rows(True)),
-           **dict.fromkeys(shortconv.ATTN_LEAVES, rows(False))}
-    spans = {name: own.get(name, rows(None)) for name in scanned}
+    spans = {name: rows(_LEAF_OWNER.get(name)) for name in scanned}
     return {name: jax.tree.map(lambda a, at=at: a[at], scanned[name])
             for name, at in spans.items() if at.start < at.stop}
 
@@ -794,7 +856,12 @@ def _scan_groups(cfg: ModelConfig, params: Params, lora_bufs: Params | None,
     period is counted from layer 0 of the MODEL, so a group that starts at
     layer f runs it rotated by f, a group all of one kind scans a layer a
     step, and the layers a depth leaves over of a period are a scan of
-    their own (``ModelConfig.group_spans``, ``_layer_spans``)."""
+    their own (``ModelConfig.group_spans``, ``_layer_spans``).  (Such a
+    span's leaves are static slices of its group's, which XLA copies out on
+    every run, ~6% of a traced window at Ling's widths; reading them where
+    they lie behind an ``optimization_barrier`` took the copies away and
+    cost more than they do, 13.4 against 10.1 ms a decode step: my chip
+    runs, PR 60, ``PERF.md`` section 7.)"""
     per_layer_lora = None
     if lora_bufs is not None:
         per_layer_lora, _ = lora_lib.stack_for_scan(lora_bufs)
@@ -913,11 +980,13 @@ def _finish_block(cfg: ModelConfig, lp: Params, h, attn, layer_lora,
 
 def _conv_block(cfg: ModelConfig, lp: Params, h, mix, layer_lora, slot_ids,
                 live):
-    """A conv layer (``LayerKind.conv``): the gated short convolution where
-    another layer has its attention, then the MLP.  ``mix(hn) -> (its
-    output, the operator's new state)`` is the form the program runs
-    (``shortconv.prompt_mix`` / ``decode_mix`` / ``chunk_mix``).  Returns
-    (h, the state, tally)."""
+    """A layer without attention (``LayerKind.conv``, ``LayerKind.kda``):
+    the gated short convolution, or the delta-rule operator, where another
+    layer has its attention, then the MLP.  ``mix(hn) -> (its output, the
+    operator's new state)`` is the form the program runs (``shortconv`` /
+    ``kda`` ``.prompt_mix`` / ``decode_mix`` / ``chunk_mix``; the state a
+    conv layer's array, a KDA layer's pair).  Returns (h, the state,
+    tally)."""
     hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
     mixed, state = mix(hn)
     h = h + mixed
@@ -945,7 +1014,9 @@ def prefill_layer(
     without it), which ``insert_prefill`` installs together.  A model with
     conv layers hands back ``{"v", "conv"}`` there: an attention layer its
     values and no conv state (None), a conv layer no ``k``, no values and
-    the operator's state after each row's last true position.  ``kind`` is
+    the operator's state after each row's last true position; a model with
+    KDA layers ``{"v": None, "kda", "conv"}``, a KDA layer its matrix states
+    and conv history there and a latent layer its rows as ``k``.  ``kind`` is
     the layer's place in a period of kinds: a "nope" layer rotates nothing,
     a window layer of a prompt longer than its window masks by it (the XLA
     form: the flash kernel is causal only, and the serving buckets are
@@ -963,13 +1034,21 @@ def prefill_layer(
             cfg, lp, h, lambda hn: shortconv.prompt_mix(cfg, lp, hn, live),
             layer_lora, slot_ids, live)
         return h, (None, {"v": None, "conv": tail}, tally)
+    if kind.kda:
+        h, (state, tail), tally = _conv_block(
+            cfg, lp, h, lambda hn: kda.prompt_mix(cfg, lp, hn, live),
+            layer_lora, slot_ids, live)
+        return h, (None, {"v": None, "kda": state, "conv": tail}, tally)
     plan = _route_early(cfg, lp, h, live)
     hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps, plus_one=cfg.norm_plus_one)
     if cfg.latent_width:
-        # "k" is the layer's latent rows, keys and values both; "v" is empty.
+        # "k" is the layer's latent rows, keys and values both; "v" is
+        # empty (beside KDA layers: their dict, with nothing of this layer).
         attn, k = mla.prefill_attend(cfg, lp, hn, positions, attention_fn)
+        v = ({"v": None, "kda": None, "conv": None} if cfg.kda_n_heads
+             else k[..., :0])
         return _finish_block(cfg, lp, h, attn, layer_lora, slot_ids, live,
-                             (k, k[..., :0]))
+                             (k, v))
     hd = cfg.resolved_head_dim
     ha = _attn_in(cfg, hn)
     q = _attn_proj(cfg, lp, "q", ha, layer_lora, slot_ids).reshape(b, s, cfg.n_heads, hd)
@@ -1091,8 +1170,8 @@ def _carry_names(cache: Params) -> tuple[str, ...]:
     model's ring lanes (k_win, v_win) [L_window, B, ring, K, hd], k and v
     then being its full layers' alone; a latent cache's rows alone (they
     are keys and values)."""
-    if "v" not in cache:
-        return ("k",)
+    if "v" not in cache:  # latent rows; beside them the KDA layers' state
+        return ("k", "kda", "conv") if "kda" in cache else ("k",)
     if "ssm" in cache:
         return ("k", "v", "ssm", "conv")
     if "conv" in cache:  # the conv layers' state; k and v the others' alone
@@ -1278,7 +1357,8 @@ def decode_step(
     held_full = _held(cfg, attention_fn, read_lengths, cache["k"])
     batch_idx = jnp.arange(b)
     s_max = cache["k"].shape[2]
-    n_rec = _n_rec(cache)  # a mixer's (ssm, conv), the conv layers' (conv,)
+    # a mixer's (ssm, conv), the KDA layers' (kda, conv), the conv layers'
+    n_rec = _n_rec(cache)
     # Scatter address only — rope/masks keep the true positions.  s_max is
     # out of bounds, so inactive rows' updates are dropped whole.
     write_pos = (positions if active is None
@@ -1294,13 +1374,22 @@ def decode_step(
                           jnp.minimum(read_lengths, ring), cache["k_win"])
 
     def latent_layer_fn(h, kv, layer, lp, layer_lora, kind, lane):
+        kv, rec = _split_carry(kv, n_rec)
+        if kind.kda:
+            h, rec, tally = _conv_block(
+                cfg, lp, h, lambda hn: kda.decode_mix(
+                    cfg, lp, hn, rec, lane, active),
+                layer_lora, slot_ids, active)
+            return h, kv + rec, tally
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
+        # (``lane``: the layer's place among the latent layers, which is
+        # the layer itself where every layer is one)
         attn, kv = mla.decode_attend(
-            cfg, lp, hn, positions, kv, (layer, batch_idx, write_pos),
-            held_full, layer)
+            cfg, lp, hn, positions, kv, (lane, batch_idx, write_pos),
+            held_full, lane)
         h, (kv, tally) = _finish_block(cfg, lp, h, attn, layer_lora,
                                        slot_ids, active, (kv,))
-        return h, kv, tally
+        return h, kv + rec, tally
 
     def layer_fn(h, kv, layer, lp, layer_lora, kind, lane):
         kv, rec = _split_carry(kv, n_rec)
@@ -1518,16 +1607,25 @@ def prefill_with_cache(
     quant = "k_scale" in cache
     live = (jnp.arange(c) <= last_index)[None]  # the final chunk's padding
 
+    # a mixer's (ssm, conv), the KDA layers' (kda, conv), the conv layers'
+    n_rec = _n_rec(cache)
+
     def latent_layer_fn(h, kv, layer, lp, layer_lora, kind, lane):
+        kv, rec = _split_carry(kv, n_rec)
+        if kind.kda:
+            # The slot's lane holds what the chunks before this one left.
+            h, rec, tally = _conv_block(
+                cfg, lp, h, lambda hn: kda.chunk_mix(
+                    cfg, lp, hn, rec, lane, slot, positions[0] == 0, live),
+                layer_lora, slot_ids, live)
+            return h, kv + rec, tally
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         attn, kv = mla.chunk_attend(
-            cfg, lp, hn, positions, kv, layer, slot,
+            cfg, lp, hn, positions, kv, lane, slot,
             functools.partial(_chunk_attend, cfg, False))
         h, (kv, tally) = _finish_block(cfg, lp, h, attn, layer_lora,
                                        slot_ids, live, (kv,))
-        return h, kv, tally
-
-    n_rec = _n_rec(cache)  # a mixer's (ssm, conv), the conv layers' (conv,)
+        return h, kv + rec, tally
 
     def layer_fn(h, kv, layer, lp, layer_lora, kind, lane):
         kv, rec = _split_carry(kv, n_rec)
@@ -1611,7 +1709,9 @@ def insert_prefill(
     with p mod ring = s (for a prompt shorter than the ring, position s).
     A model with conv layers brings ``k_prompt`` and ``v_prompt["v"]`` of
     its attention layers and ``v_prompt["conv"]`` [L_conv, 1, K - 1, D],
-    each conv layer's state at the TRUE length (``prefill``).
+    each conv layer's state at the TRUE length (``prefill``); a model with
+    KDA layers its latent layers' rows as ``k_prompt`` and ``v_prompt["kda"]``
+    / ``["conv"]`` of its KDA layers.
     """
     if "k_win" in cache:
         kinds = cfg.layer_kinds
@@ -1633,11 +1733,6 @@ def insert_prefill(
             for name, prompt in (("k_win", k_prompt), ("v_win", v_prompt))}
         return {**lanes, **rings}
     k = cache["k"]
-    if "v" not in cache:  # a latent cache: k_prompt [L, 1, S, lanes]
-        k = jax.lax.dynamic_update_slice(
-            k, k_prompt.astype(k.dtype), (0, slot, 0, 0))
-        return {"k": k, "length": cache["length"].at[slot].set(length)}
-    v = cache["v"]
     if "conv" in cache:  # v_prompt: prefill's {"v", "conv"}, a mixer's "ssm"
         # One insert installs lanes, state, conv history and length
         # together, so a freed slot needs no clearing.
@@ -1647,13 +1742,20 @@ def insert_prefill(
                 cache["conv"], jnp.swapaxes(v_prompt["conv"], 1, 2).astype(
                     cache["conv"].dtype), (0, 0, slot, 0)),
         }
-        if "ssm" in cache:
-            rec["ssm"] = jax.lax.dynamic_update_slice(
-                cache["ssm"], v_prompt["ssm"].astype(cache["ssm"].dtype),
-                (0, slot, 0, 0, 0))
-        lanes = insert_prefill({"k": k, "v": v, "length": cache["length"]},
-                               k_prompt, v_prompt["v"], slot, length)
+        for name in ("ssm", "kda"):  # a float32 state [L, B, H, ., .]
+            if name in cache:
+                rec[name] = jax.lax.dynamic_update_slice(
+                    cache[name], v_prompt[name].astype(cache[name].dtype),
+                    (0, slot, 0, 0, 0))
+        lanes = insert_prefill(
+            {name: cache[name] for name in ("k", "v", "length")
+             if name in cache}, k_prompt, v_prompt["v"], slot, length)
         return {**lanes, **rec}
+    if "v" not in cache:  # a latent cache: k_prompt [L, 1, S, lanes]
+        k = jax.lax.dynamic_update_slice(
+            k, k_prompt.astype(k.dtype), (0, slot, 0, 0))
+        return {"k": k, "length": cache["length"].at[slot].set(length)}
+    v = cache["v"]
     if "k_scale" in cache:
         kq, ks = _kv_quantize(k_prompt)  # [L,1,S,K,hd] -> scales [L,1,S,K]
         vq, vs = _kv_quantize(v_prompt)

@@ -33,7 +33,9 @@ QUANT_TARGETS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                  # a state-space mixer's two projections (models/ssm.py)
                  "ssm_in", "ssm_out",
                  # a gated short convolution's two (models/shortconv.py)
-                 "conv_in", "conv_out")
+                 "conv_in", "conv_out",
+                 # a delta-rule layer's two (models/kda.py)
+                 "kda_in", "kda_out")
 
 
 def quantize_weight(w: jax.Array) -> dict[str, jax.Array]:

@@ -1544,7 +1544,9 @@ class ModelServer:
                 "ssm_chunk", "layer_pattern", "sliding_window",
                 "router_pre_attention", "mlp_activation", "qk_norm_head",
                 "conv_kernel", "tie_embeddings",
-                "router_gate_eps")
+                "router_gate_eps", "n_experts_local", "expert_first",
+                "n_group", "topk_group", "kda_n_heads", "kda_head_dim",
+                "kda_conv", "kda_lower_bound", "mla_head_gate")
         return web.json_response({
             "model": self.model_name,
             "platform": devices[0].platform,
@@ -1763,7 +1765,10 @@ def main(argv=None) -> None:
         raise SystemExit(
             f"{args.model} scans a period of layer kinds over "
             + ("a conv state beside the K/V lanes of its attention layers"
-               if cfg.conv_kernel else "ring lanes beside its full lanes")
+               if cfg.conv_kernel
+               else "a delta-rule state beside the latent rows of its "
+               "latent layers" if cfg.kda_n_heads
+               else "ring lanes beside its full lanes")
             + ": it is served on one device, base model only; start it "
             "with --max-loras 0 and without --mesh")
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32

@@ -620,15 +620,28 @@ def _refuse_what_lanes_alone_serve(model_cfg, cfg: "EngineConfig",
                                    lora_manager, mesh) -> None:
     """A latent (MLA) cache, a recurrent state beside the K/V lanes (a
     state-space mixer), a stack of two kinds of layer with ring lanes
-    for its window layers, and a stack some of whose layers are no
-    attention and hold a conv state (``models/shortconv.py``) are served
-    from contiguous lanes on one device, base model only.  Every other way to hold or move a row's state assumes
+    for its window layers, a stack some of whose layers are no
+    attention and hold a conv state (``models/shortconv.py``), and a stack
+    of delta-rule layers with a matrix state beside the latent rows of its
+    latent layers, its experts held as a chip's share (``models/kda.py``),
+    are served from contiguous lanes on one device, base model only.  Every other way to hold or move a row's state assumes
     that it is per-head K and V by position and nothing else, in one stack
     a layer: what a rebuilt, shared, shipped or rolled-back row would need
     of a state that is no function of positions, or of a ring that has
     already overwritten them, is not there.  Each is refused here by name
     rather than run wrong."""
-    if model_cfg.conv_kernel:
+    if model_cfg.kda_n_heads:
+        kind = ("keeps a delta-rule matrix state (layers without attention, "
+                "whose state is no function of positions) beside the latent "
+                "rows of its latent layers"
+                + (", and holds a share of each layer's experts"
+                   if model_cfg.n_experts_local else ""))
+        loras = ("--max-loras above 0 (models/lora.py's buffers are scanned "
+                 "a layer a step, not a period, and name no delta-rule or "
+                 "latent target)")
+        extra = {"--prefill-batch above 1 (a grouped prefill's rows are "
+                 "cut out of one stack of K and V)": cfg.prefill_batch > 1}
+    elif model_cfg.conv_kernel:
         kind = ("keeps a conv state (layers without attention, whose state "
                 "is no function of positions) beside the K/V lanes of its "
                 "attention layers")
@@ -714,6 +727,8 @@ class Engine:
         self._ringed = bool(model_cfg.layer_pattern)
         # Layers without attention: a conv state a slot beside the lanes.
         self._conv = bool(model_cfg.conv_kernel)
+        # Delta-rule layers: a matrix state a slot and a head.
+        self._kda = bool(model_cfg.kda_n_heads)
         # Positions a window layer's ring holds of a row (0: no window).
         self._window = min(model_cfg.sliding_window, self.cfg.max_seq_len)
         if self._latent or self._recurrent or self._ringed:
@@ -1466,10 +1481,11 @@ class Engine:
                          or self._slot_frequency.any())
         counts = self._counts() if penalized else self._counts_dummy
         self.profiler.note_stage_ops(STAGE_UPLOADS)
-        if self._recurrent or self._conv:
+        if self._recurrent or self._conv or self._kda:
             # Every step of the block rewrites the state of every row the
             # host holds (a row that stops mid-block counts on to its end).
             (self.profiler.note_conv_rows if self._conv
+             else self.profiler.note_kda_rows if self._kda
              else self.profiler.note_ssm_rows)(
                 n_steps * sum(s is not None for s in self.slots))
         # Step j of the block reads position + 1 + j rows of a live row's

@@ -71,7 +71,8 @@ DISPATCH_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3,
                     5e-3, 1e-2, 2.5e-2, 5e-2, 0.1, 0.25, 1.0)
 
 # transformer.MOE_TALLY, restated so that this module imports no JAX.
-MOE_COUNTERS = ("layer_steps", "assignments", "experts_touched", "tiles_used")
+MOE_COUNTERS = ("layer_steps", "assignments", "experts_touched", "tiles_used",
+                "assignments_routed")
 GAP_HOST = "host"
 GAP_IDLE = "idle"
 
@@ -190,6 +191,10 @@ class StepProfiler:
         # a decode step shifted and rewrote, summed over the steps of the
         # plain decode dispatches.  0 for a model without conv layers.
         self.conv_rows = 0
+        # Rows whose delta-rule matrix state (models/kda.py) a decode step
+        # rewrote, summed over the steps of the plain decode dispatches.  0
+        # for a model without KDA layers.
+        self.kda_rows = 0
         # Cache positions the decode steps' attention read of the live
         # rows' lanes, a layer of the kind, by the kind of lane
         # (metrics_registry.KV_LANES): the full lanes' grow with a row, a
@@ -506,6 +511,12 @@ class StepProfiler:
         with self._lock:
             self.conv_rows += n
 
+    def note_kda_rows(self, n: int) -> None:
+        """Count ``n`` rows whose delta-rule state the steps of one plain
+        decode dispatch rewrote (live rows x the block's steps)."""
+        with self._lock:
+            self.kda_rows += n
+
     def note_kv_positions(self, full: int, window: int) -> None:
         """Count the positions the steps of one plain decode dispatch read
         of the live rows' full lanes and of their rings, a layer of each
@@ -572,6 +583,7 @@ class StepProfiler:
                 "latent_positions": self.latent_positions,
                 "ssm_rows": self.ssm_rows,
                 "conv_rows": self.conv_rows,
+                "kda_rows": self.kda_rows,
                 "kv_positions": dict(zip(KV_LANES, self.kv_positions)),
                 "attn_grid_steps": self.attn_grid_steps,
                 "chunk_attn_grid_steps": self.chunk_attn_grid_steps,
@@ -639,8 +651,11 @@ def render_profile(hist: dict) -> list[str]:
                 ("tpu:moe_layer_steps_total", "layer_steps"),
                 ("tpu:moe_assignments_total", "assignments"),
                 ("tpu:moe_experts_touched_total", "experts_touched"),
-                ("tpu:moe_tiles_used_total", "tiles_used")):
-            lines += [f"# TYPE {family} counter", f"{family} {moe[name]}"]
+                ("tpu:moe_tiles_used_total", "tiles_used"),
+                ("tpu:moe_assignments_routed_total", "assignments_routed")):
+            if name in moe:
+                lines += [f"# TYPE {family} counter",
+                          f"{family} {moe[name]}"]
     sample_steps = hist.get("sample_steps")
     if sample_steps:
         lines.append("# TYPE tpu:sample_steps_total counter")
@@ -673,6 +688,9 @@ def render_profile(hist: dict) -> list[str]:
     if "conv_rows" in hist:
         lines += ["# TYPE tpu:conv_state_rows_total counter",
                   f"tpu:conv_state_rows_total {hist['conv_rows']}"]
+    if "kda_rows" in hist:
+        lines += ["# TYPE tpu:kda_state_rows_total counter",
+                  f"tpu:kda_state_rows_total {hist['kda_rows']}"]
     kv_positions = hist.get("kv_positions")
     if kv_positions:
         lines.append("# TYPE tpu:kv_positions_read_total counter")

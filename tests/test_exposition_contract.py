@@ -159,6 +159,7 @@ def server_snapshot() -> dict:
     prof.note_latent_positions(41)  # tpu:latent_kv_positions_total
     prof.note_attn_grid_steps(17)  # tpu:decode_attn_grid_steps_total
     prof.note_conv_rows(23)  # tpu:conv_state_rows_total
+    prof.note_kda_rows(31)  # tpu:kda_state_rows_total
     prof.note_kv_positions(29, 0)  # tpu:kv_positions_read_total{lanes}
     # tpu:prompt_programs_total / tpu:prompt_positions_total /
     # tpu:prompt_program_seconds_total, every program of the label set
@@ -301,6 +302,7 @@ def test_server_render_contract():
     assert families["tpu:latent_kv_positions_total"][0].value == 41
     assert families["tpu:decode_attn_grid_steps_total"][0].value == 17
     assert families["tpu:conv_state_rows_total"][0].value == 23
+    assert families["tpu:kda_state_rows_total"][0].value == 31
     assert families["tpu:chunk_attn_grid_steps_total"][0].value == 1488
     assert {s.labels["lanes"]: s.value
             for s in families["tpu:kv_positions_read_total"]} == {

@@ -544,6 +544,34 @@ def test_lane_kernel_compiles_for_the_v5e_with_its_dynamic_grid(one_chip):
     assert "decode_attention" in text and "tpu_custom_call" in text
 
 
+def test_delta_rule_kernel_compiles_for_the_v5e_at_lings_widths(one_chip):
+    """Ling-3.0-flash's delta-rule state (here, beside the other compiles
+    for the chip: one process may describe it): 64 slots x 32 heads x 128 x
+    128 float32 over 10 layers, rewritten in place: the chip's compiler takes
+    the kernel with its 128 x 128 transpose and its blocks of 16 heads."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from llm_instance_gateway_tpu.ops import pallas_kda
+
+    sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    fn = jax.jit(pallas_kda.kda_decode_update_pallas, donate_argnums=(0,))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        vec = sd((64, 32, 128))
+        compiled = fn.lower(sd((10, 64, 32, 128, 128)), vec, vec, vec, vec,
+                            sd((64, 32)), sd((64,), jnp.bool_),
+                            sd((), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "kda_decode_update" in text and "tpu_custom_call" in text
+    # in place: the state's 1.34 GB is aliased, not copied
+    assert compiled.memory_analysis().alias_size_in_bytes >= 10 * 64 * 2 ** 21
+
+
 @pytest.mark.parametrize("op", ["decode", "flash", "chunk"])
 def test_packed_head_kernels_compile_for_the_v5e_at_lfm2s_widths(one_chip, op):
     """LFM2's 64-wide heads (here, beside the other compiles for the chip:

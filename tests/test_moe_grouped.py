@@ -102,7 +102,7 @@ class TestDroplessMatchesReference:
         none, tally0 = transformer._moe_mlp(
             cfg, layer0(params), x, jnp.zeros((8,), bool))
         assert float(jnp.max(jnp.abs(none))) == 0.0
-        assert [int(v) for v in tally0] == [1, 0, 0, 0]
+        assert [int(v) for v in tally0] == [1, 0, 0, 0, 0]
 
     def test_stacked_leaves_and_layer_index(self, model):
         cfg, params = model
@@ -147,7 +147,8 @@ def test_tally_counts_assignments_and_touched_experts():
     counts = np.bincount(np.asarray(topi).ravel(), minlength=cfg.n_experts)
     tm = pallas_moe.tile_rows(20 * 8, cfg.n_experts)
     assert [int(v) for v in tally] == [
-        1, 20 * 8, int((counts > 0).sum()), int((-(-counts // tm)).sum())]
+        1, 20 * 8, int((counts > 0).sum()), int((-(-counts // tm)).sum()),
+        20 * 8]  # without a share every routed assignment is computed
 
 
 def test_tally_counts_the_tiles_of_a_group_that_outgrows_one():

@@ -1061,6 +1061,7 @@ class TestXplaneGaps:
                     "llm_instance_gateway_tpu/models/mla.py",
                     "llm_instance_gateway_tpu/models/ssm.py",
                     "llm_instance_gateway_tpu/models/shortconv.py",
+                    "llm_instance_gateway_tpu/models/kda.py",
                     "llm_instance_gateway_tpu/models/paged.py",
                     "llm_instance_gateway_tpu/models/lora.py",
                     "llm_instance_gateway_tpu/ops/layers.py",

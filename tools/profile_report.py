@@ -235,6 +235,14 @@ def conv_rows_row(profile: dict) -> dict:
     return _per_decode_dispatch(profile, "conv_rows", "rows_per_dispatch")
 
 
+def kda_rows_row(profile: dict) -> dict:
+    """Rows whose delta-rule matrix state the decode steps rewrote
+    (``tpu:kda_state_rows_total``); empty for a model without KDA layers."""
+    if not (profile.get("hist") or {}).get("kda_rows"):
+        return {}
+    return _per_decode_dispatch(profile, "kda_rows", "rows_per_dispatch")
+
+
 def kv_positions_rows(profile: dict) -> list[dict]:
     """Cache positions the decode steps read of the live rows' lanes, by
     the kind of lane (``tpu:kv_positions_read_total``); empty for a model
@@ -313,11 +321,14 @@ def prompt_program_rows(profile: dict) -> list[dict]:
 ANNOTATION_PREFIX = "engine."
 NO_ANNOTATION = "other"  # the bottom of the phase stack is not annotated
 # The jax.named_scope names the model code uses (models/transformer.py,
-# models/mla.py, models/ssm.py, models/shortconv.py, models/paged.py,
+# models/mla.py, models/ssm.py, models/shortconv.py, models/kda.py,
+# models/paged.py,
 # models/lora.py, server/sampling.py, server/engine.py).
 SCOPES = frozenset((
     "embed", "attn.qkv", "attn.qk_norm", "attn.rope", "attn.kv_update",
     "attn.core", "conv.in_proj", "conv.mix", "conv.out_proj",
+    "kda.in_proj", "kda.conv", "kda.gate", "kda.scan", "kda.update",
+    "kda.gate_norm", "kda.out_proj", "attn.head_gate",
     "attn.core.window", "attn.out", "attn.q_latent", "attn.kv_latent", "attn.absorb",
     "attn.expand", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.update",
     "ssm.gate_norm", "ssm.out_proj", "mlp", "moe.route", "moe.dispatch",
@@ -793,6 +804,11 @@ def render_report(profile: dict, previous: dict | None = None) -> str:
         out += ["", "Conv states rewritten by the decode steps:",
                 _table([conv], ("conv_rows", "decode_dispatches",
                                 "rows_per_dispatch"))]
+    delta = kda_rows_row(profile)
+    if delta:
+        out += ["", "Delta-rule states rewritten by the decode steps:",
+                _table([delta], ("kda_rows", "decode_dispatches",
+                                 "rows_per_dispatch"))]
     lanes = kv_positions_rows(profile)
     if lanes:
         out += ["", "Cache positions read by the decode steps, a layer of "
