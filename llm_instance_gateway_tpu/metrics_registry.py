@@ -414,6 +414,11 @@ SERVER_FAMILIES = (
            "tpu:moe_assignments_total over it is the share of the routing "
            "this program's experts took (1 without a share).",
            SERVER_SURFACE),
+    Family("tpu:moe_tiles_laid_out_total", "counter", (),
+           "Row tiles of the expert dispatch's layout, sized for the worst "
+           "routing (dropless), summed over layer-steps: "
+           "tpu:moe_tiles_used_total over it is the share of the layout "
+           "the expert matmul's grid walks.", SERVER_SURFACE),
     Family("tpu:sample_steps_total", "counter", ("path",),
            "Decode steps by the sampler's path, as the device took it: "
            "argmax (no live row samples: no sort, filter or draw) | draw "

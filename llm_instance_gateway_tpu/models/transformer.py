@@ -503,13 +503,15 @@ def _route_early(cfg: ModelConfig, lp: Params, h, live):
 # The sparse layer's routing counts, one int32 vector a layer-step:
 # (layer-steps, live assignments, experts with at least one, row tiles that
 # hold a group: over the experts touched, the tiles a group takes; every
-# assignment the router made).  The middle three count what the expert
+# assignment the router made; the row tiles of the layout, a constant of the
+# program: ``pallas_moe.n_tiles``).  The middle three count what the expert
 # matmuls did: under a share (``n_experts_local``) the assignments KEPT and
-# the experts touched OF THOSE HELD; the last counts held here or not, and
-# equals ``assignments`` without a share.  They sum over layers and steps;
-# the engine reads them back with the step.
+# the experts touched OF THOSE HELD; the fifth counts held here or not, and
+# equals ``assignments`` without a share; ``tiles_used`` over the sixth is
+# the share of the layout's rectangle the expert matmul's grid walks.  They
+# sum over layers and steps; the engine reads them back with the step.
 MOE_TALLY = ("layer_steps", "assignments", "experts_touched", "tiles_used",
-             "assignments_routed")
+             "assignments_routed", "tiles_laid_out")
 _EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
 
@@ -619,7 +621,8 @@ def _moe_route(cfg: ModelConfig, lp: Params, x, live=None) -> dict:
         src = (_layout_source(expert, sizes, k, tm, n_rows)
                if _gathers_in(t * k, e) else None)
         tally = jnp.stack([jnp.ones((), jnp.int32), jnp.sum(sizes),
-                           jnp.sum(sizes > 0), n_used, routed])
+                           jnp.sum(sizes > 0), n_used, routed,
+                           jnp.asarray(n_tiles, jnp.int32)])
     return {"gates": gates, "row": row, "src": src,
             "tile_expert": tile_expert, "n_used": n_used, "tally": tally,
             "tm": tm, "n_rows": n_rows}
@@ -721,6 +724,10 @@ def _moe_experts(cfg: ModelConfig, lp: Params, x, plan: dict):
             pallas_moe.grouped_matmul, tile_expert=plan["tile_expert"],
             n_used=plan["n_used"], layer=layer, tm=plan["tm"],
             use_kernel=cfg.use_pallas_decode)
+        # The kernel writes the tiles that hold a group and no other
+        # (``pallas_moe``): the rows past them are read by ``gated`` alone,
+        # row by row, into rows ``down`` does not read and ``row`` never
+        # names.
         act = gated(gmm(x_e, stacks["w_gate"]), gmm(x_e, stacks["w_up"]),
                     cfg.mlp_activation)
         out_e = gmm(act, stacks["w_down"])  # [n_rows, D]

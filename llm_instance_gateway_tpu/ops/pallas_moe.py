@@ -32,6 +32,20 @@ Mixtral's nk = 2 and 8 re-fetched it for every tile: PERF.md, PR 44), and
 x is fetched once.  By tile, for long prompts and for many narrow experts
 (OLMoE, GLM-4.7-Flash): a grid step is a row tile, as before.
 
+The layout is sized for the worst routing (``n_tiles``: dropless), and a
+decode step fills a quarter to two thirds of it.  The grid ends where the
+groups end: on the chip its row dimension is bound by ``n_used`` tiles (by
+group: by the touched experts), a traced scalar, as the decode-attention
+kernels walk their schedule (``pallas_decode_attention._walk``), so the
+tiles past the last group cost no grid step and the layout's rows past
+``n_used * tm`` are NOT WRITTEN: nothing may read them but elementwise, row
+by row (``transformer._moe_experts`` reads the output through ``row``
+alone, which lies in a used tile or out of bounds).  A call with nothing
+routed here is a grid of no steps.  The interpreter takes no dynamic bound:
+there, and only there, the grid keeps the layout's static bound, the steps
+past the last group do nothing but write exact zeros, and the whole output
+is defined.
+
 ``grouped_matmul`` dispatches: the kernel on a TPU backend for shapes
 ``shape_reasons`` accepts, else the XLA form of the same tiles (gather each
 tile's int8 block, one batched einsum), and says which when traced.
@@ -132,19 +146,21 @@ def _by_group(rows: int, k: int, n: int, tn: int, itemsize: int) -> bool:
 
 
 def _steps(tile_expert, n_used, n_experts: int, by_group: bool):
-    """The grid's steps over the rows as (first, count, fetch), int32 each: a
-    step multiplies the run of ``count`` row tiles from tile ``first`` of its
-    x block on with the weights of expert ``fetch``.  By tile: a step a
-    tile, the block IS the tile.  By group: step s is the s-th TOUCHED
+    """The grid's steps over the rows as ((first, count, fetch), n_live),
+    int32 each: a step multiplies the run of ``count`` row tiles from tile
+    ``first`` of its x block on with the weights of expert ``fetch``, and
+    the first ``n_live`` steps are those that do something.  By tile: a step
+    a tile, the block IS the tile.  By group: step s is the s-th TOUCHED
     expert's group (groups lie in expert order: ``tile_plan``), the block all
     rows.  Either way the steps that do something come first, so that each
-    one's weights are fetched while the one before multiplies, and the rest
-    name the last one's expert: they move no weights."""
+    one's weights are fetched while the one before multiplies; the grid
+    ends with them on the chip, and where it cannot (the interpreter) the
+    rest name the last one's expert: they move no weights."""
     tiles = tile_expert.shape[0]
     used = jnp.arange(tiles) < n_used
     if not by_group:
         return (jnp.zeros((tiles,), jnp.int32), used.astype(jnp.int32),
-                tile_expert)
+                tile_expert), n_used
     experts = jnp.arange(n_experts, dtype=jnp.int32)
     size = jnp.sum((tile_expert[:, None] == experts) & used[:, None], axis=0,
                    dtype=jnp.int32)  # tiles of each expert's group
@@ -159,18 +175,19 @@ def _steps(tile_expert, n_used, n_experts: int, by_group: bool):
                     for v in (start, experts))
     count = jnp.where(steps < n_groups,
                       jnp.sum(jnp.where(pick, size, 0), axis=1), 0)
-    return first, count.astype(jnp.int32), fetch
+    return (first, count.astype(jnp.int32), fetch), n_groups
 
 
-def _grid(n: int, tn: int, nk: int, tiles: int, n_experts: int,
-          by_group: bool) -> tuple[int, int, int]:
+def _grid(n: int, tn: int, nk: int, steps, by_group: bool) -> tuple:
     # Column blocks outermost.  By group the weight block's index changes at
     # every step that holds a group: each touched expert's block once a
     # call, the next fetched while all tiles of this group are multiplied.
     # By tile it changes with the tile's expert and, with several K blocks,
-    # at every step.
-    return ((n // tn, nk, min(n_experts, tiles)) if by_group
-            else (n // tn, tiles, nk))
+    # at every step.  ``steps`` bounds the walk over the rows: ``_steps``'
+    # ``n_live`` (traced: Mosaic lowers a dynamic bound and still fetches
+    # the next step's blocks under this step's matmul) or, for the
+    # interpreter, the length of its vectors.
+    return (n // tn, nk, steps) if by_group else (n // tn, steps, nk)
 
 
 def _index_maps(nk: int, by_group: bool) -> dict:
@@ -185,8 +202,9 @@ def _index_maps(nk: int, by_group: bool) -> dict:
             "o": lambda j, kk, s, first, count, fetch, layer: (0, j)}
 
     def kk_of(t, kk, count):
-        # A tile past the last group parks on the block the sweep ended on:
-        # unchanged block index, no DMA.
+        # A tile past the last group (the interpreter's walk alone reaches
+        # one) parks on the block the sweep ended on: unchanged block index,
+        # no DMA.
         return jnp.where(count[t] > 0, kk, nk - 1)
 
     return {  # (j, t, kk)
@@ -214,8 +232,11 @@ def _gmm_kernel(first_ref, count_ref, fetch_ref, layer_ref, x_ref, w_ref,
     last = kk == pl.num_programs(k_axis) - 1
     count = count_ref[s]
 
-    # Rows no group holds: defined, never gathered.  By group the whole
-    # column block, before the first group stores into it.
+    # Rows no group holds, never gathered.  By group the whole column block,
+    # before the first group stores into it (a call that touches no expert
+    # has no first step and writes nothing).  By tile a step past the last
+    # group: the interpreter's static walk has them and leaves exact zeros,
+    # the chip's grid ends before them and leaves those rows unwritten.
     @pl.when((kk == 0) & (s == 0) if by_group else (count == 0) & last)
     def _blank():
         o_ref[...] = jnp.zeros_like(o_ref)
@@ -248,8 +269,11 @@ def _gmm_kernel(first_ref, count_ref, fetch_ref, layer_ref, x_ref, w_ref,
 def grouped_matmul_pallas(x, w: Any, tile_expert, n_used, layer, *, tm: int,
                           interpret: bool = False) -> jax.Array:
     """x [n_tiles*tm, K]; w the stacked leaf [L, E, K, N] (array or int8
-    ``{"q", "s"}``); tile_expert [n_tiles] int32 (tiles >= n_used repeat
-    the last used tile's expert, so they move no weights); -> [rows, N]."""
+    ``{"q", "s"}``); tile_expert [n_tiles] int32; n_used (traced) the tiles
+    that hold a group, the grid's bound on the chip; -> [rows, N], of which
+    the rows past ``n_used * tm`` are unwritten on the chip (exact zeros
+    from the interpreter, whose walk is the static one: tiles >= n_used
+    repeat the last used tile's expert, so they move no weights there)."""
     quant = is_quantized(w)
     wq = w["q"] if quant else w
     rows, k = x.shape
@@ -257,7 +281,7 @@ def grouped_matmul_pallas(x, w: Any, tile_expert, n_used, layer, *, tm: int,
     tk, tn = _blocks(k, n, wq.dtype.itemsize)
     nk = k // tk
     by_group = _by_group(rows, k, n, tn, wq.dtype.itemsize)
-    steps = _steps(tile_expert, n_used, n_experts, by_group)
+    steps, n_live = _steps(tile_expert, n_used, n_experts, by_group)
     index = _index_maps(nk, by_group)
     held = rows if by_group else tm  # rows of one x, out and acc block
 
@@ -277,7 +301,8 @@ def grouped_matmul_pallas(x, w: Any, tile_expert, n_used, layer, *, tm: int,
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=_grid(n, tn, nk, rows // tm, n_experts, by_group),
+            grid=_grid(n, tn, nk,
+                       steps[0].shape[0] if interpret else n_live, by_group),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((held, tn), index["o"]),
             scratch_shapes=[pltpu.VMEM((held, tn), jnp.float32)],

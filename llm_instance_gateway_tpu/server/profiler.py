@@ -72,7 +72,7 @@ DISPATCH_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3,
 
 # transformer.MOE_TALLY, restated so that this module imports no JAX.
 MOE_COUNTERS = ("layer_steps", "assignments", "experts_touched", "tiles_used",
-                "assignments_routed")
+                "assignments_routed", "tiles_laid_out")
 GAP_HOST = "host"
 GAP_IDLE = "idle"
 
@@ -652,7 +652,8 @@ def render_profile(hist: dict) -> list[str]:
                 ("tpu:moe_assignments_total", "assignments"),
                 ("tpu:moe_experts_touched_total", "experts_touched"),
                 ("tpu:moe_tiles_used_total", "tiles_used"),
-                ("tpu:moe_assignments_routed_total", "assignments_routed")):
+                ("tpu:moe_assignments_routed_total", "assignments_routed"),
+                ("tpu:moe_tiles_laid_out_total", "tiles_laid_out")):
             if name in moe:
                 lines += [f"# TYPE {family} counter",
                           f"{family} {moe[name]}"]

@@ -707,27 +707,34 @@ def test_ssm_update_kernel_compiles_for_the_v5e_at_published_widths(one_chip):
     assert mem.temp_size_in_bytes < state_bytes // 100
 
 
-@pytest.mark.parametrize("k,n,assignments,by_group", [
-    (4096, 14336, 64, True),     # gate/up of a decode step: 32 rows x top-2
-    (14336, 4096, 64, True),     # down of the same
-    (4096, 14336, 1024, True),   # gate/up of a 512-token prompt: the most
-                                 # rows the group order holds in VMEM
-    (14336, 4096, 2048, False),  # down of a 1,024-token prompt: by tile
+@pytest.mark.parametrize("e,k,n,assignments,by_group", [
+    (8, 4096, 14336, 64, True),     # Mixtral gate/up of a decode step: 32
+                                    # rows x top-2
+    (8, 14336, 4096, 64, True),     # down of the same
+    (8, 4096, 14336, 1024, True),   # gate/up of a 512-token prompt: the most
+                                    # rows the group order holds in VMEM
+    (8, 14336, 4096, 2048, False),  # down of a 1,024-token prompt: by tile
+    (128, 2560, 768, 512, False),   # Ling-3.0-flash gate/up, 64 rows x top-8
+    (128, 768, 2560, 512, False),   # ... down
+    (64, 2048, 1536, 128, False),   # GLM-4.7-Flash gate/up, 32 rows x top-4
+    (64, 2560, 768, 6144, False),   # SmallThinker gate/up, a 1,024-token chunk
 ])
-def test_expert_matmul_compiles_for_the_v5e_at_mixtrals_widths(
-        one_chip, k, n, assignments, by_group):
-    """``moe_gmm_int8`` over a stack of 6 layers of Mixtral-8x7B's 8 experts
-    (here for the reason above): the chip's compiler takes the kernel in both
-    of its orders, with all rows' x, output block and accumulators in VMEM
-    beside the double-buffered weight block."""
+def test_expert_matmul_compiles_for_the_v5e_at_the_cells_widths(
+        one_chip, e, k, n, assignments, by_group):
+    """``moe_gmm_int8`` over a stack of 4 layers of Mixtral-8x7B's 8 wide
+    experts and of the narrow ones of Ling, GLM and SmallThinker (here for the
+    reason above): the chip's compiler takes the kernel in both of its orders,
+    the rows' grid dimension bound by a traced scalar (the last one by group,
+    the MIDDLE one by tile), with all rows' x, output block and accumulators
+    in VMEM beside the double-buffered weight block."""
     from jax.experimental.compilation_cache import compilation_cache
 
     from llm_instance_gateway_tpu.ops import pallas_moe
 
     sd = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
-    tm = pallas_moe.tile_rows(assignments, 8)
-    tiles = pallas_moe.n_tiles(assignments, 8, tm)
+    tm = pallas_moe.tile_rows(assignments, e)
+    tiles = pallas_moe.n_tiles(assignments, e, tm)
     tk, tn = pallas_moe._blocks(k, n, 1)
     assert pallas_moe._by_group(tiles * tm, k, n, tn, 1) == by_group
     fn = jax.jit(lambda x, w, te, used, layer: pallas_moe.grouped_matmul_pallas(
@@ -737,7 +744,7 @@ def test_expert_matmul_compiles_for_the_v5e_at_mixtrals_widths(
     try:
         compiled = fn.lower(
             sd((tiles * tm, k), jnp.bfloat16),
-            {"q": sd((6, 8, k, n), jnp.int8), "s": sd((6, 8, n), jnp.float32)},
+            {"q": sd((4, e, k, n), jnp.int8), "s": sd((4, e, n), jnp.float32)},
             sd((tiles,), jnp.int32), sd((), jnp.int32),
             sd((), jnp.int32)).compile()
     finally:

@@ -102,7 +102,10 @@ class TestDroplessMatchesReference:
         none, tally0 = transformer._moe_mlp(
             cfg, layer0(params), x, jnp.zeros((8,), bool))
         assert float(jnp.max(jnp.abs(none))) == 0.0
-        assert [int(v) for v in tally0] == [1, 0, 0, 0, 0]
+        tiles = pallas_moe.n_tiles(
+            8 * cfg.n_experts_per_token, cfg.n_experts, pallas_moe.tile_rows(
+                8 * cfg.n_experts_per_token, cfg.n_experts))
+        assert [int(v) for v in tally0] == [1, 0, 0, 0, 0, tiles]
 
     def test_stacked_leaves_and_layer_index(self, model):
         cfg, params = model
@@ -148,7 +151,8 @@ def test_tally_counts_assignments_and_touched_experts():
     tm = pallas_moe.tile_rows(20 * 8, cfg.n_experts)
     assert [int(v) for v in tally] == [
         1, 20 * 8, int((counts > 0).sum()), int((-(-counts // tm)).sum()),
-        20 * 8]  # without a share every routed assignment is computed
+        20 * 8,  # without a share every routed assignment is computed
+        pallas_moe.n_tiles(20 * 8, cfg.n_experts, tm)]  # the layout's bound
 
 
 def test_tally_counts_the_tiles_of_a_group_that_outgrows_one():
@@ -256,40 +260,154 @@ def test_both_orders_add_over_k_alike(nk, quant, monkeypatch):
         assert float(jnp.max(jnp.abs(out))) == 0.0
 
 
-def weight_fetches(k, n, itemsize, te, n_used, n_experts, tm):
-    """Walk the kernel's grid in its order through the weight operand's
-    index map and count the steps whose block index differs from the step
-    before's: each is one DMA of a [tk, tn] block."""
+def walk(k, n, itemsize, te, n_used, n_experts, tm, interpret=False):
+    """Walk the kernel's grid in its order, under the bound it has on the
+    chip (``_steps``' live steps) or under the interpreter's static one,
+    through the index maps: ([(column block, row step, K block) of every
+    step that multiplies something], the steps whose weight block index
+    differs from the step before's: each is one DMA of a [tk, tn] block)."""
     tk, tn = pallas_moe._blocks(k, n, itemsize)
     by_group = pallas_moe._by_group(te.shape[0] * tm, k, n, tn, itemsize)
-    steps = pallas_moe._steps(te, n_used, n_experts, by_group)
+    steps, n_live = pallas_moe._steps(te, n_used, n_experts, by_group)
     w_index = pallas_moe._index_maps(k // tk, by_group)["w"]
-    grid = pallas_moe._grid(n, tn, k // tk, te.shape[0], n_experts, by_group)
+    bound = steps[0].shape[0] if interpret else int(n_live)
+    grid = pallas_moe._grid(n, tn, k // tk, bound, by_group)
     layer = jnp.ones((1,), jnp.int32)
-    fetches, before = 0, None
+    count = np.asarray(steps[1])
+    visits, fetches, before = [], 0, None
     for j, a, b in np.ndindex(*grid):
+        s, kk = (b, a) if by_group else (a, b)
+        if count[s]:
+            visits.append((j, s, kk))
         block = tuple(int(v) for v in w_index(j, a, b, *steps, layer))
         fetches += block != before
         before = block
-    return fetches
+    return visits, fetches
 
 
+@pytest.mark.parametrize("interpret", [False, True])
 @pytest.mark.parametrize("nk,by_group", [(1, True), (2, True), (4, True),
                                          (1, False), (2, False), (4, False)])
 def test_a_touched_experts_block_is_fetched_once_a_call(nk, by_group,
+                                                        interpret,
                                                         monkeypatch):
     """The invariant of PR 44, without a chip: by group the weight
     operand's block index changes touched experts x N/tn x nk times a call
     whatever the tiles a group takes; by tile, once K is cut, used tiles x
-    N/tn x nk times: every tile of a group fetches the column again."""
+    N/tn x nk times: every tile of a group fetches the column again.  Under
+    the chip's bound (the live steps) as under the interpreter's (the whole
+    layout, whose steps past the last group park)."""
     k, n = 512, 768  # three column blocks of 256
     cut_k_in(monkeypatch, k, n, 1, nk, by_group)
     _, te, n_used = skewed_plan(k)
     touched = sum(size > 0 for size in SKEWED_SIZES)
     assert (touched, int(n_used)) == (4, 7)
-    got = weight_fetches(k, n, 1, te, n_used, len(SKEWED_SIZES), SKEWED_TM)
+    _, got = walk(k, n, 1, te, n_used, len(SKEWED_SIZES), SKEWED_TM,
+                  interpret)
     tiles_fetch = int(n_used) if nk > 1 and not by_group else touched
     assert got == tiles_fetch * 3 * nk
+
+
+@pytest.mark.parametrize("nk,by_group", [(1, True), (2, True), (4, True),
+                                         (1, False), (2, False), (4, False)])
+def test_the_grid_ends_at_the_last_step_that_holds_a_group(nk, by_group,
+                                                           monkeypatch):
+    """Under its bound on the chip the grid IS the live steps: every
+    (column block, tile or touched group, K block) is visited once, in the
+    order the static walk visits them, and no step lies past ``n_used``
+    tiles / the touched groups; the static walk (the interpreter's) has the
+    same live steps among the layout's."""
+    k, n = 512, 768
+    cut_k_in(monkeypatch, k, n, 1, nk, by_group)
+    _, te, n_used = skewed_plan(k)
+    live = 4 if by_group else 7  # touched experts | tiles that hold a group
+    args = (k, n, 1, te, n_used, len(SKEWED_SIZES), SKEWED_TM)
+    tk, tn = pallas_moe._blocks(k, n, 1)
+    steps, n_live = pallas_moe._steps(te, n_used, len(SKEWED_SIZES),
+                                      by_group)
+    assert int(n_live) == live
+    grid = pallas_moe._grid(n, tn, nk, int(n_live), by_group)
+    assert grid == ((3, nk, live) if by_group else (3, live, nk))
+    visits, _ = walk(*args)
+    assert len(visits) == int(np.prod(grid)) == 3 * live * nk
+    assert sorted(visits) == [(j, s, kk) for j in range(3)
+                              for s in range(live) for kk in range(nk)]
+    static, _ = walk(*args, interpret=True)
+    assert static == visits
+    layout = pallas_moe._grid(n, tn, nk, steps[0].shape[0], by_group)
+    assert int(np.prod(layout)) == 3 * nk * (5 if by_group else SKEWED_TILES)
+    first, count = np.asarray(steps[0]), np.asarray(steps[1])
+    assert count[:live].all() and not count[live:].any()
+    if by_group:  # every tile of the plan is in exactly one step's run
+        runs = [t for s in range(live)
+                for t in range(first[s], first[s] + count[s])]
+        assert runs == list(range(int(n_used)))
+
+
+@pytest.mark.parametrize("by_group", [True, False])
+def test_nothing_used_is_a_grid_of_no_steps(by_group, monkeypatch):
+    """A block whose rows are all frozen, a step none of whose rows routes
+    to the held experts: ``n_used`` 0 bounds a grid of zero steps, nothing
+    is visited and no weight block moves; the interpreter's static walk
+    multiplies nothing either."""
+    k, n = 512, 768
+    cut_k_in(monkeypatch, k, n, 1, 2, by_group)
+    _, te, n_used = pallas_moe.tile_plan(
+        jnp.zeros((len(SKEWED_SIZES),), jnp.int32), SKEWED_TM, SKEWED_TILES)
+    assert int(n_used) == 0
+    steps, n_live = pallas_moe._steps(te, n_used, len(SKEWED_SIZES),
+                                      by_group)
+    assert int(n_live) == 0 and not np.asarray(steps[1]).any()
+    grid = pallas_moe._grid(n, 256, 2, int(n_live), by_group)
+    assert int(np.prod(grid)) == 0
+    args = (k, n, 1, te, n_used, len(SKEWED_SIZES), SKEWED_TM)
+    assert walk(*args) == ([], 0)
+    assert walk(*args, interpret=True)[0] == []
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("by_group", [True, False])
+def test_the_interpreter_alone_keeps_the_layouts_bound(by_group, monkeypatch):
+    """The program for the chip bounds ONE grid dimension by a traced scalar
+    (the rows': tiles by tile, touched groups by group); the interpreter,
+    which takes no dynamic bound, walks the layout's static one, and its
+    steps past the last group still leave exact zeros in rows that held
+    something else before."""
+    k, n = 512, 256
+    cut_k_in(monkeypatch, k, n, 4, 2, by_group)
+    w = stack_of_two(k, n, False)
+    x, te, n_used = skewed_plan(k)
+    grids = {}
+    for interpret in (True, False):
+        jaxpr = jax.make_jaxpr(
+            lambda x, w, te, used: pallas_moe.grouped_matmul_pallas(
+                x, w, te, used, 1, tm=SKEWED_TM, interpret=interpret))(
+                    x, w, te, n_used)
+        (call,) = _pallas_calls(jaxpr.jaxpr)
+        grids[interpret] = call.params["grid_mapping"]
+    rows_axis = 2 if by_group else 1
+    static = [1, 2, 5] if by_group else [1, SKEWED_TILES, 2]
+    assert list(grids[True].grid) == static
+    assert grids[True].num_dynamic_grid_bounds == 0
+    assert grids[False].num_dynamic_grid_bounds == 1
+    assert [d for i, d in enumerate(grids[False].grid) if i != rows_axis] == [
+        d for i, d in enumerate(static) if i != rows_axis]
+    assert not isinstance(grids[False].grid[rows_axis], int)
+    # every row of x non-zero: what the tiles past the last group would give
+    # if they were multiplied is not zeros
+    full = jnp.where(x == 0, 1.0, x)
+    got = pallas_moe.grouped_matmul_pallas(full, w, te, n_used, 1,
+                                           tm=SKEWED_TM, interpret=True)
+    used = int(n_used) * SKEWED_TM
+    assert float(jnp.min(jnp.max(jnp.abs(got[:used]), axis=1))) > 0.0
+    assert float(jnp.max(jnp.abs(got[used:]))) == 0.0
 
 
 @pytest.mark.parametrize("e,k,n,assignments,by_group", [

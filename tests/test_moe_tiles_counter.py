@@ -3,11 +3,14 @@ line: the family is registered and rendered beside the three it joins, the
 metric ``moe.tiles_per_expert_mean.batch`` reads it over the touched experts
 in the two sparse closed-loop cells, a program without the counter (the
 parent) gives the reader nothing to read and no error, and the on-chip
-tool's two layouts of the same rows take the tiles it says they take."""
+tool's two layouts of the same rows take the tiles it says they take.
+``tpu:moe_tiles_laid_out_total`` (PR 61) beside it: the layout's static tile
+count a layer-step, of which the expert matmul's grid walks the used."""
 
 import os
 import sys
 
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -43,6 +46,61 @@ def test_the_family_is_registered_and_rendered_with_the_tally():
     text = "\n".join(profiler.render_profile(hist))
     assert "tpu:moe_tiles_used_total 51" in text
     assert "tpu:moe_experts_touched_total 42" in text
+
+
+def test_the_laid_out_family_is_registered_and_rendered_beside_the_used():
+    families = {f.name for f in metrics_registry.SERVER_FAMILIES}
+    assert "tpu:moe_tiles_laid_out_total" in families
+    assert profiler.MOE_COUNTERS[5] == "tiles_laid_out"
+    hist = {"moe": dict(zip(profiler.MOE_COUNTERS,
+                            (6, 384, 42, 51, 384, 6 * 76)))}
+    text = "\n".join(profiler.render_profile(hist)) + "\n"
+    assert "# TYPE tpu:moe_tiles_laid_out_total counter\n" in text
+    assert "tpu:moe_tiles_laid_out_total 456\n" in text
+    assert "tpu:moe_tiles_used_total 51\n" in text
+    # a program from before the counter renders the five it has
+    old = {"moe": dict(zip(profiler.MOE_COUNTERS[:5], (6, 384, 42, 51, 384)))}
+    assert "tiles_laid_out" not in "\n".join(profiler.render_profile(old))
+
+
+@pytest.mark.parametrize("program,tokens", [("decode", 4), ("prompt", 7),
+                                            ("prompt", 40)])
+def test_the_sixth_count_is_the_layouts_tiles_times_the_layer_steps(
+        program, tokens):
+    """A decode step over ``tokens`` slots (one of them dead) and a prompt of
+    ``tokens`` positions: every sparse layer lays out ``n_tiles`` tiles
+    whatever the routing, and uses at most that."""
+    import jax
+    import jax.numpy as jnp
+
+    from llm_instance_gateway_tpu.models import transformer
+    from llm_instance_gateway_tpu.models.configs import TINY_OLMOE_TEST as cfg
+
+    assert transformer.MOE_TALLY[5] == "tiles_laid_out"
+    params = transformer.init_params(cfg, jax.random.PRNGKey(3),
+                                     dtype=jnp.float32)
+    ids = (jnp.arange(tokens, dtype=jnp.int32) * 7 + 3) % cfg.vocab_size
+    if program == "decode":
+        cache = transformer.with_moe_tally(cfg, transformer.init_decode_cache(
+            cfg, tokens, 16, dtype=jnp.float32))
+        active = jnp.arange(tokens) > 0
+        _, cache = transformer.decode_step(
+            cfg, params, cache, ids, jnp.zeros((tokens,), jnp.int32),
+            active=active)
+        tally = cache["moe"]
+    else:
+        tally = transformer.prefill(
+            cfg, params, ids[None], jnp.arange(tokens, dtype=jnp.int32)[None],
+            lengths=jnp.asarray([tokens], jnp.int32), moe_tally=True)[-1]
+    k, e = cfg.n_experts_per_token, cfg.n_experts
+    tiles = pallas_moe.n_tiles(tokens * k, e,
+                               pallas_moe.tile_rows(tokens * k, e))
+    counts = dict(zip(transformer.MOE_TALLY, (int(v) for v in tally)))
+    assert counts["layer_steps"] == cfg.n_layers
+    assert counts["tiles_laid_out"] == tiles * cfg.n_layers
+    assert 0 < counts["tiles_used"] <= counts["tiles_laid_out"]
+    live = tokens - (program == "decode")
+    assert counts["assignments"] == live * k * cfg.n_layers
 
 
 def test_the_metric_reads_tiles_over_touched_experts():
@@ -93,20 +151,52 @@ def tool():
     return onchip_pallas_check
 
 
-@pytest.mark.parametrize("shape", range(4))
+@pytest.mark.parametrize("shape", range(11))
 def test_the_tools_two_layouts_hold_the_same_rows_in_more_tiles(tool, shape):
     """``moe-reuse``: every touched group in one tile, and the same rows
-    with two groups in three and two tiles."""
-    _, e, k, n, m, touched = tool.MOE_REUSE_SHAPES[shape]
+    with two groups in three and two tiles, in the layout the assignments
+    are sized for (Ling's holds a quarter of them); the last shape touches
+    nothing and uses no tile."""
+    _, e, k, n, m, touched, held = tool.MOE_REUSE_SHAPES[shape]
     assert not pallas_moe.shape_reasons(k, n)
     tm = pallas_moe.tile_rows(m, e)
     tiles = pallas_moe.n_tiles(m, e, tm)
     used = []
     for skewed in (False, True):
-        sizes = tool._group_sizes(m, e, touched, tm, skewed)
-        assert int(sizes.sum()) == m and int((sizes > 0).sum()) == touched
-        used.append(int(pallas_moe.tile_plan(sizes, tm, tiles)[2]))
-    assert used == [touched, touched + 3] and used[1] <= tiles
+        sizes = tool._group_sizes(held, e, touched, tm, skewed)
+        assert int(sizes.sum()) == held <= m
+        assert int((sizes > 0).sum()) == touched
+        _, te, n_used = pallas_moe.tile_plan(sizes, tm, tiles)
+        used.append(int(n_used))
+    assert used == ([touched, touched + 3] if touched else [0, 0])
+    assert used[1] <= tiles
+    # the steps the tool prints are the kernel's own: its grid under the
+    # chip's bound and under the interpreter's
+    tk, tn = pallas_moe._blocks(k, n, 1)
+    by_group = pallas_moe._by_group(tiles * tm, k, n, tn, 1)
+    steps, n_live = pallas_moe._steps(te, n_used, e, by_group)
+    assert tool._moe_grid_steps(k, n, e, tm, tiles, used[1], touched) == tuple(
+        int(np.prod(pallas_moe._grid(n, tn, k // tk, bound, by_group)))
+        for bound in (int(n_live), steps[0].shape[0]))
+
+
+def test_the_tools_shapes_are_the_sparse_cells_decode_layouts(tool):
+    """The layouts the issue's table reckons with: Ling's 152 tiles of 16,
+    SmallThinker's 72, GLM's 68, LFM2's and OLMoE's 76, Mixtral's 11; and
+    every one by tile but Mixtral's."""
+    assert len(tool.MOE_REUSE_SHAPES) == 11
+    layouts = {}
+    for label, e, k, n, m, _, _ in tool.MOE_REUSE_SHAPES:
+        tm = pallas_moe.tile_rows(m, e)
+        tiles = pallas_moe.n_tiles(m, e, tm)
+        tk, tn = pallas_moe._blocks(k, n, 1)
+        assert pallas_moe._by_group(tiles * tm, k, n, tn, 1) == (
+            label.startswith("mixtral"))
+        layouts[label.split()[0]] = (tiles, tm)
+    assert layouts == {
+        "mixtral": (11, 16), "olmoe": (76, 16), "glm-4.7-flash": (68, 16),
+        "ling-3.0-flash": (152, 16), "smallthinker": (72, 16),
+        "lfm2": (76, 16)}
 
 
 # ``moe-dispatch``: (assignments, layout rows, d_model) of each shape the

@@ -22,8 +22,10 @@ the ``ssm-update`` cases what the state-space decode update costs a layer at
 64 slots of Falcon-H1-34B's state (32 x 256 x 128 float32) with 64, 8 and 1
 of them live, beside the time its bytes would take at the chip's bandwidth;
 and the ``moe-reuse`` cases what the int8 expert matmul costs a call at
-Mixtral's, OLMoE's and GLM's decode shapes with every touched group in one
-row tile and with two groups in two and three; and the ``moe-dispatch``
+Mixtral's, OLMoE's, GLM's, Ling's, SmallThinker's and LFM2's decode shapes
+with every touched group in one row tile and with two groups in two and
+three, beside the steps its grid walks of the layout's, and with nothing
+touched, and a digest of the used rows' output; and the ``moe-dispatch``
 cases what the expert dispatch's way in costs alone (the token rows into the
 expert-grouped layout) by the row scatter and by the gather from the sorted
 layout, at the cells' chunk, prompt and decode shapes: where
@@ -108,15 +110,28 @@ MOE_SHAPES = (
     ("mixtral down prefill 1024x2", 8, 14336, 4096, 2048),
 )
 
-# What a second and third row tile of one expert cost the grouped matmul:
-# (label, E, K, N, assignments, experts touched), decode-sized, at the shapes
-# whose column is cut into several K blocks (Mixtral: nk 2 and 8) and at two
-# whose column is one block (OLMoE, GLM-4.7-Flash).
+# What a second and third row tile of one expert cost the grouped matmul,
+# and what the tiles past the last group cost it: (label, E, K, N,
+# assignments the layout is sized for, experts touched, rows held: fewer than
+# the assignments where the chip holds a share of the experts), decode-sized,
+# at the shapes whose column is cut into several K blocks (Mixtral: nk 2 and
+# 8), at those whose column is one block (OLMoE, GLM-4.7-Flash, LFM2) and at
+# the narrow experts of Ling (a quarter of the routing lands on the 128 held
+# here) and SmallThinker, whose layouts are mostly empty; and a step none of
+# whose rows routes here.
 MOE_REUSE_SHAPES = (
-    ("mixtral gate/up decode 32x2", 8, 4096, 14336, 64, 7),
-    ("mixtral down decode 32x2", 8, 14336, 4096, 64, 7),
-    ("olmoe gate/up decode 32x8", 64, 2048, 1024, 256, 36),
-    ("glm-4.7-flash gate/up decode 32x4", 64, 2048, 1536, 128, 27),
+    ("mixtral gate/up decode 32x2", 8, 4096, 14336, 64, 7, 64),
+    ("mixtral down decode 32x2", 8, 14336, 4096, 64, 7, 64),
+    ("olmoe gate/up decode 32x8", 64, 2048, 1024, 256, 36, 256),
+    ("glm-4.7-flash gate/up decode 32x4", 64, 2048, 1536, 128, 27, 128),
+    ("glm-4.7-flash down decode 32x4", 64, 1536, 2048, 128, 27, 128),
+    ("ling-3.0-flash gate/up decode 64x8", 128, 2560, 768, 512, 30, 128),
+    ("ling-3.0-flash down decode 64x8", 128, 768, 2560, 512, 30, 128),
+    ("smallthinker gate/up decode 32x6", 64, 2560, 768, 192, 14, 192),
+    ("smallthinker down decode 32x6", 64, 768, 2560, 192, 14, 192),
+    ("lfm2 gate/up decode 64x4", 64, 2048, 1536, 256, 48, 256),
+    ("ling-3.0-flash gate/up decode, nothing held", 128, 2560, 768, 512, 0,
+     0),
 )
 
 # The expert dispatch's way in (``transformer._lay_out``): (label, tokens,
@@ -152,6 +167,8 @@ def _lengths(b, s_max):
 def _scaled_err(out, ref):
     out = np.asarray(out, np.float32)
     ref = np.asarray(ref, np.float32)
+    if not out.size:  # a call that used no row: nothing to compare
+        return 0.0
     if not np.all(np.isfinite(out)):
         return float("inf")
     return float(np.max(np.abs(out - ref) / np.maximum(1.0, np.abs(ref))))
@@ -437,8 +454,8 @@ def case_moe(e, k, n, m, quant):
         x, w, te, nu, 1, tm=tm))(x, w, te, n_used)
     ref = jax.jit(lambda x, w, te: pmoe.grouped_matmul_xla(
         x, w, te, 1, tm=tm))(x, w, te)
-    live = (jnp.arange(n_tiles * tm) < n_used * tm)[:, None]
-    return out * live, ref * live, TOL_BF16
+    used = int(n_used) * tm  # the rest the kernel does not write
+    return out[:used], ref[:used], TOL_BF16
 
 
 def _group_sizes(m, e, touched, tm, skewed):
@@ -447,6 +464,8 @@ def _group_sizes(m, e, touched, tm, skewed):
     evenly, every group inside one tile; or the same rows skewed so that
     the first group takes three tiles and the second two, the others still
     one each."""
+    if not touched:
+        return jnp.zeros((e,), jnp.int32)
     heads = [2 * tm + 1, tm + 1] if skewed else []
     rest, left = touched - len(heads), m - sum(heads)
     sizes = heads + [left // rest + (i < left % rest) for i in range(rest)]
@@ -455,15 +474,35 @@ def _group_sizes(m, e, touched, tm, skewed):
     return jnp.zeros((e,), jnp.int32).at[where].set(jnp.asarray(sizes))
 
 
-def case_moe_reuse(e, k, n, m, touched, n_layers=4, calls=100):
-    """The int8 grouped matmul as a decode step's layer loop calls it: ``m``
-    assignment rows over ``touched`` experts, once with every group in one
-    tile and once skewed (``_group_sizes``), through a stack of ``n_layers``
-    layers, ``calls`` calls a program.  Prints us a call (host clock, least of
-    five) and the bytes/s that each TOUCHED expert's [K, N] int8 matrix read
-    ONCE a call implies: what a tile more of the same expert costs is the
-    difference of the two lines.  Parity of the skewed plan against the XLA
-    tiles on the same weights."""
+def _moe_grid_steps(k, n, e, tm, tiles, n_used, touched):
+    """(the steps ``moe_gmm_int8``'s grid walks on the chip for a plan that
+    holds ``touched`` experts' groups in ``n_used`` of ``tiles`` tiles, the
+    steps of the whole layout: what it walked before PR 61 and what the
+    interpreter walks).  Reckoned here and not asked of the kernel's
+    ``_steps`` and ``_grid``, so that this file still runs when copied into
+    an older tree to time it (``tests/test_moe_tiles_counter.py`` holds the
+    two equal)."""
+    tk, tn = pmoe._blocks(k, n, 1)
+    by_group = pmoe._by_group(tiles * tm, k, n, tn, 1)
+    a_step = (n // tn) * (k // tk)
+    return (a_step * (touched if by_group else n_used),
+            a_step * (min(e, tiles) if by_group else tiles))
+
+
+def case_moe_reuse(e, k, n, m, touched, held, n_layers=4, calls=100):
+    """The int8 grouped matmul as a decode step's layer loop calls it, in a
+    layout sized for ``m`` assignments: ``held`` rows over ``touched``
+    experts, once with every group in one tile and once skewed
+    (``_group_sizes``), through a stack of ``n_layers`` layers, ``calls``
+    calls a program.  Prints us a call (host clock, least of five), the
+    grid's steps beside the whole layout's, and the bytes/s that each
+    TOUCHED expert's [K, N] int8 matrix read ONCE a call implies: what a
+    tile more of the same expert costs is the difference of the two lines.
+    Parity of the skewed plan against the XLA tiles on the same weights,
+    over the rows of the used tiles (the rest the kernel does not write),
+    and a digest of those rows, which two trees' kernels must share."""
+    import hashlib
+
     kw, ks, kx = _keys(8, 3)
     # one [K, N] matrix a draw: a draw of the whole stack would take four
     # times its bytes in 32-bit words
@@ -482,28 +521,36 @@ def case_moe_reuse(e, k, n, m, touched, n_layers=4, calls=100):
     def loop(x, w, te, n_used):
         def body(nu, layer):
             out = pmoe.grouped_matmul_pallas(x, w, te, nu, layer, tm=tm)
-            # never true; ties each call to the one before
-            return nu + (out[0, 0] > 1e30).astype(jnp.int32), None
+            # never true; ties each call to the one before (row 0 is
+            # unwritten, and may hold anything, only where nothing is used)
+            return nu + ((out[0, 0] > 1e30) & (nu > 0)).astype(jnp.int32), None
         layers = jnp.arange(calls, dtype=jnp.int32) % n_layers
         return jax.lax.scan(body, n_used, layers)[0]
 
-    for skewed in (False, True):
+    for skewed in (False, True) if touched else (False,):
         _, te, n_used = pmoe.tile_plan(
-            _group_sizes(m, e, touched, tm, skewed), tm, n_tiles)
+            _group_sizes(held, e, touched, tm, skewed), tm, n_tiles)
         least, median = _us_a_call(
             lambda: loop(x, w, te, n_used).block_until_ready(), calls)
+        walked, laid_out = _moe_grid_steps(k, n, e, tm, n_tiles, int(n_used),
+                                           touched)
         print(f"TIME   moe-reuse K={k} N={n} blocks={pmoe._blocks(k, n, 1)} "
-              f"{m} rows, {touched} experts in {int(n_used)} tiles of {tm}: "
-              f"{least:.1f} us a call (median {median:.1f}, "
-              f"{calls} calls a program): "
+              f"{held} rows, {touched} experts in {int(n_used)} tiles of {tm} "
+              f"of the layout's {n_tiles}: {least:.1f} us a call (median "
+              f"{median:.1f}, {calls} calls a program), {walked} grid steps "
+              f"of the layout's {laid_out}: "
               f"{touched * k * n / least / 1e3:.0f} GB/s of the touched "
               f"experts' bytes read once", flush=True)
     out = jax.jit(lambda x, w, te, nu: pmoe.grouped_matmul_pallas(
         x, w, te, nu, 1, tm=tm))(x, w, te, n_used)
     ref = jax.jit(lambda x, w, te: pmoe.grouped_matmul_xla(
         x, w, te, 1, tm=tm))(x, w, te)
-    live = (jnp.arange(n_tiles * tm) < n_used * tm)[:, None]
-    return out * live, ref * live, TOL_BF16
+    used = int(n_used) * tm
+    digest = hashlib.sha256(np.asarray(
+        out[:used].astype(jnp.float32)).tobytes()).hexdigest()[:12]
+    print(f"       moe-reuse K={k} N={n}: the {used} used rows' output "
+          f"sha256 {digest}", flush=True)
+    return out[:used], ref[:used], TOL_BF16
 
 
 def case_moe_dispatch(t, k, e, d, calls=100, runs=100):
@@ -737,10 +784,10 @@ def cases():
     for label, t, k, e, d in MOE_DISPATCH_SHAPES:
         yield (f"moe-dispatch [{label}]", [],
                lambda t=t, k=k, e=e, d=d: case_moe_dispatch(t, k, e, d))
-    for label, e, k, n, m, touched in MOE_REUSE_SHAPES:
+    for label, e, k, n, m, touched, held in MOE_REUSE_SHAPES:
         yield (f"moe-reuse [{label}]", pmoe.shape_reasons(k, n),
-               lambda e=e, k=k, n=n, m=m, touched=touched: case_moe_reuse(
-                   e, k, n, m, touched))
+               lambda e=e, k=k, n=n, m=m, touched=touched, held=held:
+               case_moe_reuse(e, k, n, m, touched, held))
     # Speed 2 of ROADMAP.md: what the steps of the decode kernels' grid
     # cost, at the open-loop cells' layouts with few rows live and with all,
     # and at the closed-loop cells' ragged rows.
